@@ -8,11 +8,14 @@ volume — used to recompile every template from scratch.  This module
 turns on JAX's persistent compilation cache and scopes it so the disk
 artifacts are shared exactly as widely as they are valid:
 
-- **Location**: ``$KOLIBRIE_COMPILE_CACHE_DIR``, else
-  ``<data_dir>/compile_cache`` where ``data_dir`` is the durability root
-  (``$KOLIBRIE_DATA_DIR`` for the HTTP server).  No directory → cache
-  stays off (library embedders opt in explicitly).
-- **Keying**: entries are namespaced under
+- **Location**: where ``$JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it
+  itself and this module sets NO directory — it only records that one as
+  active, so whoever runs the process places the cache.  Otherwise
+  ``$KOLIBRIE_COMPILE_CACHE_DIR``, else ``<data_dir>/compile_cache``
+  where ``data_dir`` is the durability root (``$KOLIBRIE_DATA_DIR`` for
+  the HTTP server).  No directory → cache stays off (library embedders
+  opt in explicitly).
+- **Keying**: entries this module places are namespaced under
   ``<root>/<jax-version>-<backend>/`` so a jax upgrade or a backend
   switch (cpu ↔ tpu) never replays a stale binary.  *Within* the
   namespace the key is XLA's own hash of the lowered HLO — and because
@@ -72,6 +75,9 @@ _MISSES = _metrics.counter(
 
 _lock = threading.Lock()
 _active_dir: Optional[str] = None
+# where the pre-warm manifest lives: the configured root (the cache
+# directory itself when the environment placed it)
+_active_root: Optional[str] = None
 _listener_installed = False
 # raw event tallies, independent of the obs registry being enabled —
 # the restart regression test asserts on these
@@ -83,12 +89,7 @@ def cache_namespace() -> str:
     long as (jax version, backend kind) both match."""
     import jax
 
-    try:
-        backend = jax.default_backend()
-    # kolint: ignore[KL601] backend init failure: namespace stays well-formed
-    except Exception:
-        backend = "unknown"
-    return f"jax{jax.__version__}-{backend}"
+    return f"jax{jax.__version__}-{jax.default_backend()}"
 
 
 def _on_event(event: str, **kwargs) -> None:
@@ -119,37 +120,44 @@ def enable(
 ) -> Optional[str]:
     """Idempotently enable the persistent compilation cache.
 
-    Resolution order: ``explicit_dir`` argument →
-    ``$KOLIBRIE_COMPILE_CACHE_DIR`` → ``<data_dir>/compile_cache``.
-    Returns the active namespaced directory, or ``None`` when no
-    location is configured (cache left untouched).  Must run before the
-    first lowering it should capture; durability recovery calls it
-    before WAL replay so even the replay's own dispatches hit disk.
+    Where ``$JAX_COMPILATION_CACHE_DIR`` is set it wins over every
+    argument: JAX already reads it, so no directory is set here and no
+    sub-directory appended — the call only records it as active, drops
+    the two thresholds and installs the hit/miss listener.  Otherwise the
+    resolution order is ``explicit_dir`` argument →
+    ``$KOLIBRIE_COMPILE_CACHE_DIR`` → ``<data_dir>/compile_cache``, each
+    namespaced by :func:`cache_namespace`.  Returns the active directory,
+    or ``None`` when no location is configured (cache left untouched).
+    Must run before the first lowering it should capture; durability
+    recovery calls it before WAL replay so even the replay's own
+    dispatches hit disk.
     """
-    global _active_dir
-    root = explicit_dir or os.environ.get("KOLIBRIE_COMPILE_CACHE_DIR")
-    if not root and data_dir:
-        root = os.path.join(data_dir, "compile_cache")
-    if not root:
-        return None
-    target = os.path.join(os.path.abspath(root), cache_namespace())
+    global _active_dir, _active_root
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        root = target = os.path.abspath(env_dir)
+    else:
+        root = explicit_dir or os.environ.get("KOLIBRIE_COMPILE_CACHE_DIR")
+        if not root and data_dir:
+            root = os.path.join(data_dir, "compile_cache")
+        if not root:
+            return None
+        root = os.path.abspath(root)
+        target = os.path.join(root, cache_namespace())
     with _lock:
         if _active_dir == target:
             return _active_dir
         import jax
 
-        os.makedirs(target, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", target)
+        if not env_dir:
+            os.makedirs(target, exist_ok=True)
+            jax.config.update("jax_compilation_cache_dir", target)
         # the tail is many SMALL compiles: cache all of them
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        try:
-            jax.config.update("jax_enable_compilation_cache", True)
-        # kolint: ignore[KL601] older jax: the cache-dir config alone enables it
-        except Exception:
-            pass
+        jax.config.update("jax_enable_compilation_cache", True)
         _install_listener()
-        _active_dir = target
+        _active_dir, _active_root = target, root
     return target
 
 
@@ -220,11 +228,9 @@ class suppress_recording:
 def manifest_path(root: Optional[str] = None) -> Optional[str]:
     """The manifest lives at the cache ROOT (not the versioned
     namespace): query texts replay across jax upgrades just fine."""
-    base = root or _active_dir
+    base = root or _active_root
     if base is None:
         return None
-    if base == _active_dir:
-        base = os.path.dirname(base)  # strip the namespace segment
     return os.path.join(base, _MANIFEST_NAME)
 
 

@@ -1,0 +1,212 @@
+"""Ask the TPU compiler, without a TPU: the served path's Pallas kernels at
+real widths, and one whole plan, compiled for a DESCRIBED v5e chip.
+
+Nothing runs — a compile that passes is not a chip run and says nothing
+about results or times.  What it catches is what interpret mode cannot:
+a kernel Mosaic refuses (misaligned slice, too much VMEM), a plan that
+does not fit, a program whose kernel silently fell out (no
+``tpu_custom_call``).
+
+The topology is described inside a module fixture (one process may load
+libtpu at a time — see /opt/skills/guides/on-chip-measurement §2), the
+persistent compilation cache is off around the compiles (a described-chip
+executable cannot be read back), and ``pallas_kernels._interpret`` is
+patched HERE: the program takes its CPU branch off-TPU and gets no option
+to do otherwise.
+"""
+
+import os
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+from kolibrie_tpu.ops import pallas_kernels as pk  # noqa: E402
+
+K128 = 131072
+M1 = 1 << 20
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """SingleDeviceSharding on a described v5e chip, with the kernels'
+    interpret switch forced off and the persistent cache disabled."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    interpret_was = pk._interpret
+    pk._interpret = lambda: False
+    # traces made by earlier modules baked interpret=True into the jitted
+    # entry points' caches; ours must not leak the other way either
+    jax.clear_caches()
+    try:
+        yield SingleDeviceSharding(topo.devices[0])
+    finally:
+        pk._interpret = interpret_was
+        jax.clear_caches()
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        cc.reset_cache()
+
+
+def _compile(fn, one_chip, *args, lead=(), want_kernel=True, **static):
+    """Lower + compile jitted ``fn`` for the described chip from shapes
+    alone (``lead`` = leading static positionals); asserts the Mosaic
+    kernel is in the program."""
+    shapes = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        args,
+    )
+    lowered = fn.lower(*lead, *shapes, **static)
+    if want_kernel:
+        assert "tpu_custom_call" in lowered.as_text(), "Pallas kernel missing"
+    return lowered.compile()
+
+
+def _u32(n):
+    return jax.ShapeDtypeStruct((n,), jnp.uint32)
+
+
+def _i32(n):
+    return jax.ShapeDtypeStruct((n,), jnp.int32)
+
+
+def _f32(n):
+    return jax.ShapeDtypeStruct((n,), jnp.float32)
+
+
+def _bool(n):
+    return jax.ShapeDtypeStruct((n,), jnp.bool_)
+
+
+# ------------------------------------------------------------------ kernels
+
+
+@pytest.mark.parametrize("x64", [False, True])
+def test_merge_join_128k(one_chip, x64):
+    with jax.enable_x64(x64):
+        _compile(pk.merge_join_indices, one_chip, _u32(K128), _u32(K128),
+                 cap=K128)
+
+
+def test_merge_join_single_launch_limit(one_chip):
+    n = pk._PALLAS_MAX_LEFT_ROWS
+    _compile(pk.merge_join_indices, one_chip, _u32(n), _u32(n), cap=n)
+
+
+def test_merge_join_chunked_1m(one_chip):
+    with jax.enable_x64(True):
+        _compile(pk.merge_join_indices, one_chip, _u32(M1), _u32(M1),
+                 cap=M1, chunk_out=pk._CHUNK_OUT)
+
+
+def test_ranked_merge_join(one_chip):
+    u64 = jax.ShapeDtypeStruct((K128,), jnp.uint64)
+    with jax.enable_x64(True):
+        _compile(pk.ranked_merge_join_indices, one_chip, u64, u64, cap=K128)
+
+
+def test_lex_probe_select(one_chip):
+    acc = tuple((_i32(M1), _u32(M1), _u32(M1), _u32(M1), _u32(M1))
+                for _ in range(2))
+    _compile(jax.jit(pk.lex_probe_select), one_chip,
+             _i32(M1), _i32(M1), _bool(M1), acc)
+
+
+def test_lex_probe_validate(one_chip):
+    acc = tuple(tuple(_i32(M1) for _ in range(7)) for _ in range(2))
+    _compile(jax.jit(pk.lex_probe_validate), one_chip,
+             _bool(M1), _bool(M1), _i32(M1), acc)
+
+
+def test_filter_mask(one_chip):
+    consts = jax.ShapeDtypeStruct((8,), jnp.int32)
+    _compile(pk._filter_mask_jit, one_chip, consts,
+             _u32(4 * M1), _u32(4 * M1), _u32(4 * M1))
+
+
+def test_tag_combine(one_chip):
+    _compile(pk.tag_combine, one_chip, _f32(M1), _f32(M1), op="noisy_or")
+
+
+# --------------------------------------------------------------- whole plan
+
+
+@pytest.fixture(scope="module")
+def lubm_db():
+    """A small real device-mode store; its argument tree gives the whole
+    plan its shapes."""
+    from benches import lubm
+    from kolibrie_tpu.query.sparql_database import SparqlDatabase
+
+    db = SparqlDatabase()
+    s, p, o = lubm.generate_fast(4, db.dictionary)
+    db.store.add_batch(s, p, o)
+    db.execution_mode = "device"
+    return db
+
+
+def _lower_bgp(db, sparql):
+    """The LoweredPlan the batched dispatch builds for a plain SELECT
+    (``executor.execute_queries_batched``), without executing anything."""
+    from kolibrie_tpu.optimizer.device_engine import lower_plan
+    from kolibrie_tpu.query import executor as ex
+
+    db.register_prefixes_from_query(sparql)
+    ent, _slot = ex._plan_cache_entry(db, sparql)
+    _q, w = ex._batchable_select(db, ent["cq"])
+    resolved = [ex.resolve_pattern(db, p) for p in w.patterns]
+    logical = ex.build_logical_plan(resolved, list(w.filters), [], None)
+    plan = ex.Streamertail(db.get_or_build_stats()).find_best_plan(logical)
+    return lower_plan(db, plan)
+
+
+def test_whole_plan_lubm_q9(one_chip, lubm_db):
+    from benches import lubm
+    from kolibrie_tpu.optimizer import device_engine as de
+
+    low = _lower_bgp(lubm_db, lubm.LUBM_Q9)
+    low.build()
+    low.calibrate_host()
+    spec, args = low.build()
+    with jax.enable_x64(True):
+        _compile(de._run_plan, one_chip, *args, lead=(spec, True))
+
+
+def test_whole_plan_batch_8_variants(one_chip, lubm_db):
+    import chip_smoke
+    from kolibrie_tpu.optimizer import device_engine as de
+
+    _solo, variants = chip_smoke.smoke_queries(4, 0, False)
+    lows = [_lower_bgp(lubm_db, v) for v in variants]
+    built = [lp.build() for lp in lows]
+    spec0, (order_arrays, _sc, masks, values, numf, quoted, _pp) = built[0]
+    assert all(spec == spec0 for spec, _ in built)
+    with jax.enable_x64(True):
+        scal = np.stack([np.asarray(lp._scan_ranges_np) for lp in lows])
+        params_b = (
+            np.stack([np.asarray(lp.u_params or [0], np.uint32) for lp in lows]),
+            np.stack([np.asarray(lp.f_params or [0.0], np.float64) for lp in lows]),
+        )
+        # the vmap entry always takes the XLA join formulation (Pallas
+        # kernels do not vmap): what must hold is that it compiles
+        _compile(de._run_plan_batch, one_chip, order_arrays, scal, masks,
+                 values, numf, quoted, params_b, lead=(spec0,),
+                 want_kernel=False)

@@ -21,6 +21,13 @@ from kolibrie_tpu.query import compile_cache
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
+@pytest.fixture(autouse=True)
+def _own_cache_dirs(monkeypatch):
+    """These cases place cache directories of their own: an externally
+    placed cache (``JAX_COMPILATION_CACHE_DIR``) would win over them."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+
+
 # --------------------------------------------------------------- unit layer
 
 
@@ -46,6 +53,30 @@ def test_enable_resolution_and_idempotence(tmp_path, monkeypatch):
     monkeypatch.setenv("KOLIBRIE_COMPILE_CACHE_DIR", str(tmp_path / "env"))
     d2 = compile_cache.enable(data_dir=str(tmp_path / "data"))
     assert str(tmp_path / "env") in d2
+
+
+def test_env_placed_cache_is_recorded_not_set(tmp_path, monkeypatch):
+    """With JAX_COMPILATION_CACHE_DIR set, JAX reads the directory itself:
+    enable() records it as active and never sets one in code — whatever
+    arguments it was given."""
+    import jax
+
+    monkeypatch.setattr(compile_cache, "_active_dir", None)
+    monkeypatch.setattr(compile_cache, "_active_root", None)
+    placed = str(tmp_path / "placed")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+    before = jax.config.jax_compilation_cache_dir
+    got = compile_cache.enable(
+        data_dir=str(tmp_path / "data"), explicit_dir=str(tmp_path / "arg")
+    )
+    assert got == placed == compile_cache.enabled_dir()
+    assert jax.config.jax_compilation_cache_dir == before
+    assert not os.path.exists(tmp_path / "data")
+    assert not os.path.exists(tmp_path / "arg")
+    # the manifest sits beside the entries, no namespace segment between
+    assert compile_cache.manifest_path() == os.path.join(
+        placed, "prewarm_manifest.json"
+    )
 
 
 def test_manifest_roundtrip(tmp_path, monkeypatch):
@@ -138,6 +169,7 @@ def _run_proc(root: str, phase: str) -> dict:
     env = dict(os.environ)
     env.pop("KOLIBRIE_PLAN_INTERP", None)
     env.pop("KOLIBRIE_COMPILE_CACHE_DIR", None)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run(
         [sys.executable, "-c", _PROC.format(repo=REPO, root=root, phase=phase)],
