@@ -13,7 +13,6 @@ Prints ONE JSON line.
 """
 
 import json
-import os
 import sys
 import time
 from pathlib import Path
@@ -66,9 +65,6 @@ def build_db():
 
 def main():
     import jax
-
-    if os.environ.get("KOLIBRIE_BENCH_CPU"):
-        jax.config.update("jax_platforms", "cpu")
 
     from kolibrie_tpu.optimizer.device_engine import PreparedQuery
     from kolibrie_tpu.query.executor import execute_query_volcano

@@ -16,7 +16,6 @@ Prints ONE JSON line.
 """
 
 import json
-import os
 import sys
 import time
 from pathlib import Path
@@ -61,9 +60,6 @@ def _pct(samples, q):
 
 def main():
     import jax
-
-    if os.environ.get("KOLIBRIE_BENCH_CPU"):
-        jax.config.update("jax_platforms", "cpu")
 
     from kolibrie_tpu.optimizer.device_engine import device_compile_stats
     from kolibrie_tpu.query.executor import (

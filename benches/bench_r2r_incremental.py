@@ -11,18 +11,12 @@ Prints one JSON line per window size.  CityBench-style workload: sparse
 knows-graph, 2-hop reach rule.
 """
 import json
-import os
 import random
 import sys
 import time
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
-
-if os.environ.get("KOLIBRIE_BENCH_CPU"):
-    import jax as _jax
-
-    _jax.config.update("jax_platforms", "cpu")
 
 from kolibrie_tpu.rsp.r2r import IncrementalR2R, SimpleR2R  # noqa: E402
 from kolibrie_tpu.rsp.s2r import WindowTriple  # noqa: E402

@@ -24,6 +24,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import resource
 import sys
 import threading
 import time
@@ -45,6 +46,11 @@ LOAD_CHUNK_BYTES = 48 * 1024 * 1024  # under the 64 MiB request limit
 
 
 def say(**obj) -> None:
+    if "phase" in obj or "query" in obj:
+        # host memory high-water mark, MiB (ru_maxrss is KiB on Linux)
+        obj["host_peak_rss_mib"] = (
+            resource.getrusage(resource.RUSAGE_SELF).ru_maxrss // 1024
+        )
     print(json.dumps(obj, sort_keys=True), flush=True)
 
 
@@ -176,8 +182,8 @@ def smoke_queries(n_universities: int, seed: int, mesh: bool):
         ]
     variants = [
         PREFIXES
-        + "SELECT ?x ?y WHERE { "
-        f"?x ub:memberOf <{d}> . ?x ub:advisor ?y }}"
+        + "SELECT ?x ?y ?c WHERE { "
+        f"?x ub:memberOf <{d}> . ?x ub:advisor ?y . ?y ub:teacherOf ?c }}"
         for d in depts
     ]
     return solo, variants

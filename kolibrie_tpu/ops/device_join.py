@@ -25,7 +25,6 @@ from functools import partial, wraps
 from typing import Sequence, Tuple
 
 import jax
-from kolibrie_tpu.ops.jax_compat import enable_x64 as _enable_x64
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -45,7 +44,7 @@ def _x64(fn):
 
     @wraps(fn)
     def wrapper(*args, **kwargs):
-        with _enable_x64(True):
+        with jax.enable_x64(True):
             return fn(*args, **kwargs)
 
     return wrapper

@@ -60,7 +60,6 @@ from functools import lru_cache, partial
 from typing import Optional, Tuple
 
 import jax
-from kolibrie_tpu.ops.jax_compat import enable_x64 as _enable_x64, typeof as _typeof
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -101,7 +100,7 @@ def _pallas_call(*args, **kwargs):
     inner = pl.pallas_call(*args, **kwargs)
 
     def launch(*operands):
-        with _enable_x64(False):
+        with jax.enable_x64(False):
             return inner(*operands)
 
     return launch
@@ -271,7 +270,7 @@ def _join_prepass(lkey_u, lval, rkey_u):
     low = jnp.searchsorted(rkey_u, lkey_u, side="left").astype(jnp.int32)
     high = jnp.searchsorted(rkey_u, lkey_u, side="right").astype(jnp.int32)
     counts = high - low
-    with _enable_x64(True):
+    with jax.enable_x64(True):
         total64 = jnp.sum(counts.astype(jnp.int64))
     # Compact to rows with ≥1 match (stable: False sorts before True).
     order = jnp.argsort(counts == 0, stable=True)
@@ -369,7 +368,7 @@ def _pallas_join_core(
     # rejects the kernel's internal dynamic_slice), making this branch
     # dormant — it exists so the escape hatch can be dropped the moment
     # jax accepts pallas_call under vma checking.
-    vma = getattr(_typeof(lkey_u), "vma", None)
+    vma = getattr(jax.typeof(lkey_u), "vma", None)
     kwargs = {"vma": vma} if vma else {}
     out_shape = [
         jax.ShapeDtypeStruct((n_tiles, TILE), jnp.int32, **kwargs)
@@ -464,7 +463,7 @@ def _pallas_join_core_chunked(
         out_specs=[out_block] * 4,
         scratch_shapes=[pltpu.VMEM((2 * BW, _NCOLS), jnp.int32)],
     )
-    vma = getattr(_typeof(lkey_u), "vma", None)
+    vma = getattr(jax.typeof(lkey_u), "vma", None)
     kwargs = {"vma": vma} if vma else {}
     out_shape = [
         jax.ShapeDtypeStruct((t_c, TILE), jnp.int32, **kwargs)
@@ -821,7 +820,7 @@ def _lex_probe_call(kernel, ops, p: int, n_out: int):
     n_chunks, chunk_rows, rows = _probe_grid(p)
     ops2d = [_probe2d(o, rows) for o in ops]
     block = pl.BlockSpec((chunk_rows, TILE), lambda i: (i, 0))
-    vma = getattr(_typeof(ops[0]), "vma", None)
+    vma = getattr(jax.typeof(ops[0]), "vma", None)
     kwargs = {"vma": vma} if vma else {}
     out_shape = [
         jax.ShapeDtypeStruct((rows, TILE), jnp.int32, **kwargs)

@@ -110,10 +110,22 @@ class ExecutionEngine:
         if isinstance(op, P.WcojNode):
             # host fallback: binary joins give the same bindings (set
             # semantics); the worst-case-optimal evaluation is the DEVICE
-            # lowering's concern
+            # lowering's concern.  Scans join in a CONNECTED order — the
+            # next one shares a variable with what is already joined
+            # whenever any does — because textual order can put two
+            # disjoint patterns first (LUBM Q2) and materialize their
+            # cross product.
+            pending = [self.execute_with_ids(scan) for scan in op.scans]
             wout: Optional[BindingTable] = None
-            for scan in op.scans:
-                t = self.execute_with_ids(scan)
+            while pending:
+                k = 0
+                if wout is not None:
+                    k = next(
+                        (i for i, t in enumerate(pending)
+                         if not wout.keys().isdisjoint(t)),
+                        0,
+                    )
+                t = pending.pop(k)
                 wout = t if wout is None else equi_join_tables(wout, t)
             return wout if wout is not None else {}
         if isinstance(op, P.PhysFilter):

@@ -558,8 +558,6 @@ def _stacked_segments(lowered):
 
 
 def _dispatch(lowered, prog: InterpProgram, args):
-    from kolibrie_tpu.ops.jax_compat import enable_x64 as _enable_x64
-
     _order_arrays, scalars, _masks, _values, numf, _quoted, params = args
     B, D, DEL = _stacked_segments(lowered)
     sc = np.zeros((_bucket(scalars.shape[0], 4), 4), dtype=np.int32)
@@ -567,7 +565,7 @@ def _dispatch(lowered, prog: InterpProgram, args):
     nf_len = int(numf.shape[0])
     nfb = _bucket(nf_len, 8)
     code = jnp.asarray(prog.code)
-    with _enable_x64(True):
+    with jax.enable_x64(True):
         numf_p = jnp.concatenate(
             [numf, jnp.full((nfb - nf_len,), jnp.nan, dtype=numf.dtype)]
         )

@@ -29,7 +29,6 @@ from functools import partial
 from typing import List, Optional, Tuple
 
 import jax
-from kolibrie_tpu.ops.jax_compat import shard_map as _shard_map
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -339,7 +338,7 @@ class DistributedReasoner:
             bucket_cap=bucket_cap,
         )
         self._round = jax.jit(
-            _shard_map(
+            jax.shard_map(
                 lambda *state: body(state),
                 mesh=mesh,
                 check_vma=_dist_check_vma(),

@@ -39,7 +39,6 @@ from functools import lru_cache, partial
 from typing import Dict, List, Optional, Tuple
 
 import jax
-from kolibrie_tpu.ops.jax_compat import shard_map as _shard_map
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -353,7 +352,7 @@ class DistGeneralReasoner:
         rep = P()
         n_masks = len(self.bank.exprs)
         return jax.jit(
-            _shard_map(
+            jax.shard_map(
                 lambda state, masks: body(state, masks),
                 mesh=self.mesh,
                 check_vma=_dist_check_vma(),

@@ -55,7 +55,6 @@ from functools import lru_cache, partial
 from typing import Dict, List, Optional, Tuple
 
 import jax
-from kolibrie_tpu.ops.jax_compat import enable_x64 as _enable_x64, shard_map as _shard_map
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -1075,7 +1074,7 @@ class DistProvenanceReasoner:
         rep = P()
         n_masks = len(self.bank.exprs)
         return jax.jit(
-            _shard_map(
+            jax.shard_map(
                 lambda state, masks, one, gtags: body(
                     state, masks, one, gtags
                 ),
@@ -1123,7 +1122,7 @@ class DistProvenanceReasoner:
             seen_cap=seen_cap,
         )
         return jax.jit(
-            _shard_map(
+            jax.shard_map(
                 lambda state, seen, n_seen, masks, one, gtag: body(
                     state, seen, n_seen, masks, one, gtag
                 ),
@@ -1174,7 +1173,7 @@ class DistProvenanceReasoner:
     def _try_infer(self, s, p, o, tags0, one_enc, max_rounds):
         n = self.n
         sh = NamedSharding(self.mesh, P(self.axis, None))
-        with _enable_x64(True):
+        with jax.enable_x64(True):
             try:
                 (ss, sp, so, stg), sv = partition_rows(
                     (s, p, o, tags0), s, n, self.fact_cap

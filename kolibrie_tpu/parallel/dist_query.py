@@ -59,7 +59,6 @@ from functools import lru_cache, partial
 from typing import Dict, List, Optional, Tuple
 
 import jax
-from kolibrie_tpu.ops.jax_compat import enable_x64 as _enable_x64, shard_map as _shard_map
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -575,7 +574,7 @@ def _query_fn(
     )
     spec = P(axis, None)
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             lambda state, masks, numf, vals, dranks, qranks: body(
                 state, masks, numf, vals, dranks, qranks
             ),
@@ -1104,7 +1103,7 @@ class DistQueryExecutor:
                 self.union_specs,
                 self.optional_specs,
             )
-            with _enable_x64(True):
+            with jax.enable_x64(True):
                 outs, valid, total, overflow, nan_flag = fn(
                     state, masks, numf, vals, dranks, qranks
                 )

@@ -53,10 +53,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from kolibrie_tpu.obs import analyze as _analyze
 from kolibrie_tpu.obs import metrics as _m
 from kolibrie_tpu.obs.spans import span
-from kolibrie_tpu.ops.jax_compat import (
-    enable_x64 as _enable_x64,
-    shard_map as _shard_map,
-)
 from kolibrie_tpu.parallel.dist_general import _exchange_table
 from kolibrie_tpu.parallel.dist_join import (
     _LPAD32 as _JLPAD,
@@ -348,7 +344,7 @@ def _get_batched_fn(
     spec = P(axis, None)
     bspec = P(None, axis, None)
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             lambda state, masks, params: body(state, masks, params),
             mesh=mesh,
             check_vma=_dist_check_vma(),
@@ -821,7 +817,7 @@ class ShardedDatabase:
                         bucket_cap,
                         b_pad,
                     )
-                    with _enable_x64(True):
+                    with jax.enable_x64(True):
                         outs, valid, overflow, shard_stats = fn(
                             state, masks, params
                         )

@@ -44,7 +44,6 @@ from functools import partial
 from typing import Dict, List, Optional, Set, Tuple
 
 import jax
-from kolibrie_tpu.ops.jax_compat import enable_x64 as _enable_x64
 import numpy as np
 
 from kolibrie_tpu.ops import round_cap as _round_cap
@@ -1184,7 +1183,7 @@ def infer_provenance_device(
     d_t = eff0[didx]
     nd0 = len(d_s)
 
-    with _enable_x64(True):
+    with jax.enable_x64(True):
         st = {
             "fs": _pad_u32(s, 0),
             "fp": _pad_u32(p, 0),
@@ -1668,7 +1667,7 @@ def _drive_addmult(
 
     nd0 = int(didx0.size)
 
-    with _enable_x64(True):
+    with jax.enable_x64(True):
         st = {
             "fs": _pad_u32(s, 0),
             "fp": _pad_u32(p, 0),

@@ -24,7 +24,7 @@ Faults a rule can inject:
 
 - ``error=ExcClass``  — raise (simulated compile failure, device OOM,
   window-thread crash; pass any exception class or factory)
-- ``latency_s=0.2``   — sleep (simulated slow kernel / tunnel stall)
+- ``latency_s=0.2``   — sleep (simulated slow kernel / device stall)
 
 Usage::
 

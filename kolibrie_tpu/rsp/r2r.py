@@ -588,9 +588,7 @@ def _window_maintain(*args):
     # keys whose LITERALS (shift amounts, pad sentinels) are canonicalized
     # at lowering time by the ambient config — outside the scope they drop
     # to u32 and fail the stablehlo verifier against the u64 operands
-    from kolibrie_tpu.ops.jax_compat import enable_x64 as _enable_x64
-
-    with _enable_x64(True):
+    with jax.enable_x64(True):
         return _window_maintain_jit(*args)
 
 

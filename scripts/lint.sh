@@ -54,10 +54,6 @@ echo "== compileall =="
 # -q: names only on failure; PYTHONDONTWRITEBYTECODE keeps the tree clean
 PYTHONDONTWRITEBYTECODE=1 python -m compileall -q kolibrie_tpu/ tests/ || rc=1
 
-echo "== bench gate (smoke) =="
-# schema + comparator + timeline-ring self-check; no live bench run
-python scripts/bench_gate.py --smoke || rc=1
-
 echo "== bytecode-free tree =="
 tracked=$(git ls-files | grep -E '(__pycache__|\.pyc$)' || true)
 if [ -n "$tracked" ]; then

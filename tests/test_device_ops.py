@@ -5,7 +5,6 @@ import numpy as np
 import pytest
 
 import jax
-from kolibrie_tpu.ops.jax_compat import enable_x64 as _enable_x64
 import jax.numpy as jnp
 
 from kolibrie_tpu.ops import device_join as dj
@@ -114,7 +113,7 @@ class TestScansAndFilters:
 
     def test_prefix_range_scan(self, rng):
         s = np.sort(rng.integers(1, 20, 64)).astype(np.uint64)
-        with _enable_x64(True):
+        with jax.enable_x64(True):
             key = jnp.asarray(s << np.uint64(32))
         (out,), valid, n = dj.prefix_range_scan(
             key, (key,), np.uint64(5 << 32), np.uint64(9 << 32), cap=64

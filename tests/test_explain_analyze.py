@@ -10,10 +10,7 @@ timeline ring's delta/quantile math and the bench gate's comparator.
 
 from __future__ import annotations
 
-import importlib.util
-import os
 import time
-from pathlib import Path
 
 import numpy as np
 import pytest
@@ -360,59 +357,3 @@ def test_registry_snapshot_shape():
     hchild = snap["h_lat"]["children"][()]
     assert hchild["count"] == 1 and hchild["sum"] == 0.5
     assert hchild["cumulative"][-1][1] == 1
-
-
-# -------------------------------------------------------------- bench gate
-
-
-def _gate():
-    path = Path(__file__).resolve().parent.parent / "scripts" / "bench_gate.py"
-    spec = importlib.util.spec_from_file_location("bench_gate", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_bench_gate_comparator_directions():
-    gate = _gate()
-    traj = [
-        {
-            "metric": "m",
-            "value": 100.0,
-            "secondary": {"x_ms": 10.0, "y_qps": 50.0, "rows": 5},
-        }
-    ]
-    # same numbers: clean
-    regs, checked = gate.compare(traj[0], traj)
-    assert not regs and len(checked) == 3  # rows is skipped
-    # slower headline + slower ms both flagged
-    bad = {"metric": "m", "value": 70.0, "secondary": {"x_ms": 20.0}}
-    regs, _ = gate.compare(bad, traj)
-    assert len(regs) == 2
-    # different metric name: nothing to gate (cpu run vs tpu bar)
-    other = {"metric": "other", "value": 1.0}
-    regs, checked = gate.compare(other, traj)
-    assert not regs and not checked
-
-
-def test_bench_gate_tolerates_unparsed_rounds():
-    # the committed trajectory HAS null-parsed rounds; loading must drop
-    # exactly those and keep the rest usable
-    import glob
-    import json
-
-    gate = _gate()
-    raw = sorted(glob.glob(os.path.join(gate.REPO, "BENCH_r*.json")))
-    with_parse = 0
-    for p in raw:
-        with open(p) as f:
-            if json.load(f)["parsed"] is not None:
-                with_parse += 1
-    traj = gate.load_trajectory()
-    assert len(raw) > with_parse >= 1  # the fixture premise holds
-    assert len(traj) == with_parse
-    assert all("metric" in b and "_path" in b for b in traj)
-
-
-def test_bench_gate_smoke_runs():
-    _gate().smoke()

@@ -11,7 +11,6 @@ import numpy as np
 import pytest
 import jax.numpy as jnp
 
-from kolibrie_tpu.ops.jax_compat import enable_x64 as _enable_x64
 from kolibrie_tpu.ops.pallas_kernels import (
     TILE,
     filter_mask,
@@ -355,7 +354,7 @@ class TestX64TraceSafety:
 
         lkey = jnp.arange(256, dtype=jnp.uint32)
         rkey = jnp.arange(256, dtype=jnp.uint32)
-        with _enable_x64(True):
+        with jax.enable_x64(True):
             jaxpr = jax.make_jaxpr(
                 lambda a, b: merge_join_indices(
                     a, b, cap=2048, chunk_out=chunk_out
@@ -368,7 +367,7 @@ class TestX64TraceSafety:
 
         s = jnp.arange(256, dtype=jnp.uint32)
         t = jnp.ones(256, jnp.float32)
-        with _enable_x64(True):
+        with jax.enable_x64(True):
             j1 = jax.make_jaxpr(
                 lambda a: filter_mask(a, a, a, o_op=2, o_cmp=7)
             )(s)
