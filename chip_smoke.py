@@ -79,11 +79,13 @@ class Client:
             )
         return body
 
-    def post(self, path: str, payload: dict) -> dict:
+    def post(self, path: str, payload: dict, trace_id: str = "") -> dict:
+        headers = {"Content-Type": "application/json"}
+        if trace_id:
+            headers["X-Kolibrie-Trace-Id"] = trace_id
         req = urllib.request.Request(
-            self.base + path,
-            data=json.dumps(payload).encode(),
-            headers={"Content-Type": "application/json"},
+            self.base + path, data=json.dumps(payload).encode(),
+            headers=headers,
         )
         return json.loads(self._open(req))
 
@@ -93,14 +95,23 @@ class Client:
     def get_text(self, path: str) -> str:
         return self._open(urllib.request.Request(self.base + path)).decode()
 
-    def query(self, store_id: str, sparql: str):
+    def query(self, store_id: str, sparql: str, trace_id: str = ""):
         """(rows, wall ms) of one ``/store/query`` round trip."""
         t0 = time.perf_counter()
         body = self.post(
             "/store/query",
             {"store_id": store_id, "sparql": sparql, "deadline_ms": DEADLINE_MS},
+            trace_id,
         )
         return body["data"], (time.perf_counter() - t0) * 1000.0
+
+    def span_ms(self, trace_id: str) -> dict:
+        """The server's own spans of one traced request: name -> total ms."""
+        out: dict = {}
+        for line in self.get_text(f"/debug/traces?trace_id={trace_id}").splitlines():
+            sp = json.loads(line)
+            out[sp["name"]] = round(out.get(sp["name"], 0.0) + sp["dur_ms"], 3)
+        return out
 
 
 def metric(text: str, name: str, labels: str = "") -> float:
@@ -262,6 +273,7 @@ def run(args, n_univ, device, httpd, check) -> None:
     from kolibrie_tpu.query import compile_cache
     from kolibrie_tpu.query.executor import execute_query_volcano
     from kolibrie_tpu.query.sparql_database import SparqlDatabase
+    from kolibrie_tpu.frontends.rules import strip_hash_comments
 
     cl = Client(httpd.server_address[1])
 
@@ -297,20 +309,22 @@ def run(args, n_univ, device, httpd, check) -> None:
     metrics0 = cl.get_text("/metrics")
     n_sent = 0
 
-    def compare(name, sparql, rows_by_pass, ms_by_pass) -> None:
+    def compare(name, sparql, rows_by_pass, ms_by_pass, **extra) -> None:
         want = multiset(execute_query_volcano(sparql, host_db))
         equal = all(multiset(rows) == want for rows in rows_by_pass)
         say(query=name, rows=sum(want.values()), rows_equal_host=equal,
             cold_wall_ms_smoke=round(ms_by_pass[0], 3),
-            warm_wall_ms_smoke=round(ms_by_pass[1], 3))
+            warm_wall_ms_smoke=round(ms_by_pass[1], 3), **extra)
         check(f"rows_equal_host:{name}", equal)
         check(f"rows_nonempty:{name}", sum(want.values()) > 0)
 
     # ---- solo queries, each sent twice (cold, warm)
     for name, sparql in solo:
-        got = [cl.query(sid, sparql) for _ in range(2)]
+        got = [cl.query(sid, sparql, f"smoke-{name}-{tag}")
+               for tag in ("cold", "warm")]
         n_sent += 2
-        compare(name, sparql, [g[0] for g in got], [g[1] for g in got])
+        compare(name, sparql, [g[0] for g in got], [g[1] for g in got],
+                warm_span_ms_smoke=cl.span_ms(f"smoke-{name}-warm"))
 
     # ---- 8 constant-variants of one template, concurrently, twice: the
     # batcher stacks them into one vmap (or, with --mesh, shard_map) dispatch
@@ -375,11 +389,22 @@ def run(args, n_univ, device, httpd, check) -> None:
     check("no_sticky_lowering_failure",
           stats["plan_cache"]["sticky_failures"] == 0,
           sticky_failures=stats["plan_cache"]["sticky_failures"])
-    sources = {fp: t["source"]
+    # which engine produced each template's last solo dispatch: "compiled"
+    # / "disk" = the specialized jit (real compile / persistent-cache hit);
+    # the variant template is None when its last dispatch was the batch
+    names = {}
+    for name, text in solo + [("variants", v) for v in variants]:
+        ent = db.__dict__["_plan_cache"].get(strip_hash_comments(text))
+        names.setdefault((ent or {}).get("fp"), name)
+    sources = {names.get(fp, fp): t["source"]
                for fp, t in stats["plan_cache"]["per_template"].items()}
-    check("templates_served_by_compiled_plans",
-          all(s in ("compiled", "disk", None) for s in sources.values())
-          and any(s in ("compiled", "disk") for s in sources.values()),
+    # (the fused on-device GROUP BY dispatches run()/converge() itself and
+    # leaves no source: None is its device signature, a host fallback
+    # would have left a sticky failure or a degraded count above)
+    check("solo_templates_served_by_compiled_plans",
+          all(sources.get(name) in ("compiled", "disk")
+              or (name == "group_count" and sources.get(name) is None)
+              for name, _ in solo),
           sources=sources)
     bad = {fp: b for fp, b in stats["breakers"].items()
            if b["state"] != "closed" or b["total_failures"]}
