@@ -367,7 +367,7 @@ def run(args, n_univ, device, httpd, check) -> None:
     on_device = delta("kolibrie_query_seconds_count", '{path="device"}')
     degraded = delta("kolibrie_query_seconds_count", '{path="degraded"}')
     batched = delta("kolibrie_query_batched_total")
-    mem = dev0_memory(jax)
+    mem = jax.devices()[0].memory_stats() or {}
     say(phase="observations", sent=n_sent, path_device=on_device,
         path_degraded=degraded, batched=batched,
         device_compile_stats=compiles1,
@@ -415,26 +415,25 @@ def run(args, n_univ, device, httpd, check) -> None:
           compiles1["run_plan"] > compiles0["run_plan"],
           before=compiles0["run_plan"], after=compiles1["run_plan"])
     if args.mesh:
-        check_mesh(jax, db, stats, metrics1, check)
+        check_mesh(db, stats, metrics1, check)
     else:
         check("run_plan_batch_compiled",
               compiles1["run_plan_batch"] > compiles0["run_plan_batch"],
               before=compiles0["run_plan_batch"],
               after=compiles1["run_plan_batch"])
-        check_q9_lowering(jax, db, solo, pallas_kernels, check)
+        check_q9_lowering(db, dict(solo)["lubm_q9"], check)
 
 
-def dev0_memory(jax) -> dict:
-    return jax.devices()[0].memory_stats() or {}
-
-
-def check_q9_lowering(jax, db, solo, pallas_kernels, check) -> None:
+def check_q9_lowering(db, q9: str, check) -> None:
     """The Q9 plan, lowered exactly as ``LoweredPlan.run`` dispatches it,
     must carry the Mosaic kernel (``tpu_custom_call``)."""
+    import jax
+
+    from kolibrie_tpu.ops import pallas_kernels
     from kolibrie_tpu.optimizer import device_engine as de
     from kolibrie_tpu.query.executor import _plan_cache_entry
 
-    _ent, slot = _plan_cache_entry(db, dict(solo)["lubm_q9"])
+    _ent, slot = _plan_cache_entry(db, q9)
     lowered = slot.get("lowered")
     if not lowered:
         check("q9_plan_has_tpu_custom_call", False, reason="no lowered plan")
@@ -448,7 +447,9 @@ def check_q9_lowering(jax, db, solo, pallas_kernels, check) -> None:
           lowered_chars=len(text))
 
 
-def check_mesh(jax, db, stats, metrics_text, check) -> None:
+def check_mesh(db, stats, metrics_text, check) -> None:
+    import jax
+
     sh = db.__dict__.get("_sharded_serving")
     check("four_devices", jax.device_count() == 4, count=jax.device_count())
     check("store_has_sharded_attachment", sh is not None)
