@@ -579,10 +579,10 @@ _window_maintain_jit = None
 def _window_maintain(*args):
     """Lazily-jitted :func:`_window_maintain_impl` — keeps this module
     importable without jax (the host-only RSP paths never touch it)."""
+    import jax
+
     global _window_maintain_jit
     if _window_maintain_jit is None:
-        import jax
-
         _window_maintain_jit = jax.jit(_window_maintain_impl)
     # call (= lowering point) under x64: set_difference_rows packs u64
     # keys whose LITERALS (shift amounts, pad sentinels) are canonicalized
