@@ -99,3 +99,17 @@ def test_mesh_rehearsal_on_four_virtual_devices():
     checks = {ln["check"]: ln for ln in lines if "check" in ln}
     assert checks["dispatches_recorded_sharded"]["ok"]
     assert checks["shard_arrays_span_four_devices"]["min_devices"] == 4
+
+
+def test_bench_is_one_process_and_fails_off_tpu():
+    """``python bench.py`` has no probe child, retry, replay or CPU
+    fallback: off-TPU it says so and exits non-zero at once."""
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "bench.py")],
+        capture_output=True, text=True, timeout=120, env=env, cwd=REPO,
+    )
+    assert proc.returncode != 0
+    assert proc.stdout.strip() == ""  # no result line of any kind
+    assert "TPU" in proc.stderr and "cpu" in proc.stderr
