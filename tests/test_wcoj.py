@@ -260,3 +260,35 @@ def test_no_recompile_across_16_triangle_variants(monkeypatch):
         _check_modes_agree(db, variant(h), f"variant {h}")
     after = dict(device_compile_stats())
     assert after == base, f"recompile across variants: {base} -> {after}"
+
+
+def test_host_fallback_joins_in_connected_order(monkeypatch):
+    """The host engine's WcojNode fallback must not join in textual order:
+    LUBM Q2's first two patterns (?x a GraduateStudent / ?y a University)
+    share no variable, and their cross product is what killed a
+    3.8M-triple host run.  No intermediate may outgrow the largest scan."""
+    import sys
+    from pathlib import Path
+
+    sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "benches"))
+    import lubm
+
+    from kolibrie_tpu.ops.join import table_len
+    from kolibrie_tpu.optimizer import engine as host_engine
+
+    db = SparqlDatabase()
+    s, p, o = lubm.generate_fast(2, db.dictionary)
+    db.store.add_batch(s, p, o)
+    db.execution_mode = "host"
+    sizes = []
+    real = host_engine.equi_join_tables
+
+    def spy(left, right):
+        out = real(left, right)
+        sizes.append(table_len(out))
+        return out
+
+    monkeypatch.setattr(host_engine, "equi_join_tables", spy)
+    rows = execute_query_volcano(lubm.LUBM_Q2, db)
+    members = 2 * lubm.DEPTS_PER_UNIV * lubm.STUDENTS_PER_DEPT  # memberOf scan
+    assert rows and sizes and max(sizes) <= members, sizes
