@@ -1,0 +1,333 @@
+"""One run of one cell: set-up, window, comparison, result line.
+
+One process, the only one that touches JAX.  The real server runs in a
+thread; the client is this thread.  Everything that belongs to one cell is
+found by name under ``benchmark/`` (see README.md).
+"""
+
+import json
+import os
+import shutil
+import sys
+import threading
+import time
+
+from . import compare, e2e, layers, xplane
+from . import data as files
+from .client import Client
+from .traffic import Traffic
+
+RING_CAPACITY = 2_000_000  # spans; the program's default is 4096
+NO_CHIP_EXIT = 3
+
+
+def say(**obj) -> None:
+    print(json.dumps(obj, sort_keys=True, default=str), flush=True)
+
+
+def _counters(cl, device_compile_stats, compile_cache) -> dict:
+    """Every counter a reader may ask for, under one flat naming."""
+    out = {}
+    for line in cl.get_text("/metrics").splitlines():
+        if line and not line.startswith("#"):
+            key, _, value = line.rpartition(" ")
+            try:
+                out["metrics." + key] = float(value)
+            except ValueError:
+                pass
+    for k, v in device_compile_stats().items():
+        out["compile." + k] = float(v)
+    for k, v in compile_cache.counters().items():
+        out["compile_cache." + k] = float(v)
+    return out
+
+
+def _send_cycle(cl, sid, cycle_no, steps, traced, requests, tamper):
+    """Send one cycle; returns ``(ms, trace ids)``."""
+    import jax
+
+    ids, t0 = [], time.perf_counter()
+    for i, (template, text) in enumerate(steps):
+        trace_id = f"bench-{cycle_no}-{i}" if traced is not None else ""
+        wall = time.time()
+        if traced:
+            with jax.profiler.TraceAnnotation("bench.request"):
+                status, body, ms = cl.query(sid, text, trace_id)
+        else:
+            status, body, ms = cl.query(sid, text, trace_id)
+        if tamper is not None:
+            body = tamper(len(requests), body)
+        requests.append({"cycle": cycle_no, "template": template, "text": text,
+                         "status": status, "body": body, "ms": ms,
+                         "wall": wall, "in_trace": bool(traced)})
+        ids.append(trace_id)
+    return (time.perf_counter() - t0) * 1000.0, ids
+
+
+def run_cell(workload, seed, seconds, trace, t_start, scale=None,
+             waive=frozenset(), tamper=None, control=False):
+    """Returns ``(result line or None, exit code)``.
+
+    ``scale`` is the rehearsal's override of the configuration's scale: with
+    it a run is never ``correct``.  ``waive`` (checks left out of
+    ``correct``) and ``tamper`` (alters a response body where the client
+    receives it) are for the tests under ``benchmark/tests`` only; nothing on
+    the command line or in the environment reaches them.
+    """
+    bench = files.read_json(os.pardir, "BENCHMARK.json")
+    cell = files.read_json("workloads", workload + ".json")
+    config = files.read_json("configs", cell["config"] + ".json")
+    os.environ.update(cell.get("env", {}))
+    if files.REPO_DIR not in sys.path:
+        sys.path.insert(0, files.REPO_DIR)
+
+    import jax
+
+    from kolibrie_tpu.frontends import http_server
+    from kolibrie_tpu.obs import spans as prog_spans
+    from kolibrie_tpu.ops import pallas_kernels
+    from kolibrie_tpu.optimizer.device_engine import device_compile_stats
+    from kolibrie_tpu.query import compile_cache
+
+    # before the first lowering; where JAX_COMPILATION_CACHE_DIR is set the
+    # program records it and sets no other
+    cache_dir = compile_cache.enable(explicit_dir=files.path(".jax_cache"))
+    devs = jax.devices()
+    device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+              "count": len(devs)}
+    on_chip = device["platform"] == "tpu" and device["count"] >= cell["chips"]
+    if not on_chip and scale is None and "platform_is_tpu" not in waive:
+        print(f"benchmark: cell {workload} needs {cell['chips']} TPU chip(s); "
+              f"JAX found {device}", file=sys.stderr)
+        return None, NO_CHIP_EXIT
+    if on_chip:
+        peaks = files.read_json("data", "peaks.json")["peaks"]
+        if device["kind"] not in peaks:
+            raise SystemExit(f"benchmark: no peaks for device {device['kind']!r}")
+    say(phase="start", workload=workload, seed=seed, seconds=seconds, trace=trace,
+        device=device, jax=jax.__version__, compile_cache_dir=cache_dir,
+        scale_override=scale)
+
+    checks = []
+
+    def check(name, value, limit, ok):
+        checks.append({"check": name, "value": value, "limit": limit,
+                       "ok": bool(ok), "waived": name in waive})
+        say(**checks[-1])
+
+    phases, quantities = {}, {}
+    httpd = http_server.make_server("127.0.0.1", 0, quiet=True, data_dir=None)
+    server = threading.Thread(target=httpd.serve_forever, daemon=True)
+    server.start()
+    try:
+        traffic_name = cell["traffic"]
+        t0 = time.perf_counter()
+        data = files.load_module("generators", config["generator"]).generate(
+            config, seed, scale)
+        chunks = files.ntriples_chunks(data)
+        n_triples = len(data["s"])
+        phases["generate"] = time.perf_counter() - t0
+        traffic = Traffic(traffic_name, data["domains"], seed)
+        cl = Client(httpd.server_address[1], traffic.deadline_ms)
+
+        sid, t0 = "bench", time.perf_counter()
+        for text in chunks:
+            body = cl.post("/store/load",
+                           {"store_id": sid, "rdf": text, "format": "ntriples"})
+        phases["load"] = time.perf_counter() - t0
+        quantities["triples"] = n_triples
+        del chunks
+        db = httpd.RequestHandlerClass.state.stores[sid].db
+        say(phase="load", triples=body["triples"], generated=n_triples,
+            generate_s=phases["generate"], load_s=phases["load"])
+        check("load_acknowledged_all_triples", body["triples"], n_triples,
+              body["triples"] == n_triples)
+        check("store_mode", db.execution_mode, config["store_mode"],
+              db.execution_mode == config["store_mode"])
+        check("scale_as_configured", scale, None, scale is None)
+
+        # warm every template of the cycle, as often as the traffic file
+        # says, so the cap advisor's re-runs and every compile are over
+        t0, warm = time.perf_counter(), []
+        for k in range(traffic.warmup_cycles):
+            ms, _ = _send_cycle(cl, sid, -1 - k, traffic.cycle(k, "warmup"),
+                                None, warm, None)
+            say(phase="warmup", cycle=k, ms=ms,
+                statuses=sorted({r["status"] for r in warm}))
+        phases["warmup"] = time.perf_counter() - t0
+        del warm
+
+        trace_dir = files.path(".traces", f"{workload}-{seed}")
+        if trace:
+            shutil.rmtree(trace_dir, ignore_errors=True)
+            prog_spans.set_ring_capacity(RING_CAPACITY)
+            prog_spans.clear()
+        counters0 = _counters(cl, device_compile_stats, compile_cache)
+
+        # ---- the window: whole cycles, one client, closed loop
+        requests, cycles = [], []
+        tracing, traced_s, t_trace = "before", 0.0, 0.0
+        setup_s = time.perf_counter() - t_start
+        t_open = time.perf_counter()
+        k = 0
+        while time.perf_counter() - t_open < seconds:
+            if trace and tracing == "before" and k >= 1:
+                opts = jax.profiler.ProfileOptions()
+                opts.python_tracer_level = 0
+                jax.profiler.start_trace(trace_dir, profiler_options=opts)
+                tracing, t_trace = "on", time.perf_counter()
+            n_before = len(requests)
+            ms, ids = _send_cycle(cl, sid, k, traffic.cycle(k),
+                                  (tracing == "on") if trace else None,
+                                  requests, tamper)
+            cycles.append({"k": k, "ms": ms, "trace_ids": ids, "whole": True,
+                           "n": len(requests) - n_before})
+            k += 1
+            if tracing == "on" and (
+                time.perf_counter() - t_trace >= traffic.trace_min_seconds
+            ):
+                traced_s = time.perf_counter() - t_trace
+                jax.profiler.stop_trace()
+                tracing = "done"
+        window_s = time.perf_counter() - t_open
+        if tracing == "on":
+            traced_s = time.perf_counter() - t_trace
+            jax.profiler.stop_trace()
+            tracing = "done"
+        counters1 = _counters(cl, device_compile_stats, compile_cache)
+        span_list = prog_spans.spans_snapshot() if trace else []
+        stats = cl.get_json("/stats")["stores"][sid]
+        mem = [d.memory_stats() or {} for d in jax.local_devices()]
+        device["memory_peak_bytes"] = max(
+            (m.get("peak_bytes_in_use", 0) for m in mem), default=0)
+    finally:
+        httpd.shutdown()
+        httpd.server_close()
+        server.join(timeout=30)
+
+    # ---- outside the window and outside set-up: the plain reference
+    from benchmark.reference.sparql_subset import Reference
+
+    t0 = time.perf_counter()
+    ref = Reference(data["terms"], data["s"], data["p"], data["o"])
+    bad, want = compare.wrong_answers(requests, ref.query)
+    reference_s = time.perf_counter() - t0
+    non_200 = sum(1 for r in requests if r["status"] != 200)
+    empty = sorted({r["template"] for r in requests if not want.get(r["text"])})
+    say(phase="compare", requests=len(requests), distinct_texts=len(want),
+        reference_s=reference_s, rows_by_template={
+            r["template"]: sum(want[r["text"]].values())
+            for r in requests if r["text"] in want})
+    check("wrong_or_failed_answers", len(bad), 0, not bad)
+    check("templates_with_empty_reference_answer", empty, [], not empty)
+    check("whole_cycles_in_window", len(cycles), ">=1", len(cycles) >= 1)
+    if control:
+        _control(config, data, seed, want, Reference)
+
+    def delta(name):
+        key = "metrics." + name
+        return counters1.get(key, 0.0) - counters0.get(key, 0.0)
+
+    on_device = delta('kolibrie_query_seconds_count{path="device"}')
+    batched = delta("kolibrie_query_batched_total")
+    degraded = counters1.get('metrics.kolibrie_query_seconds_count{path="degraded"}', 0.0)
+    host_path = delta('kolibrie_query_seconds_count{path="host"}')
+    check("platform_is_tpu", device["platform"], "tpu", device["platform"] == "tpu")
+    check("chips", device["count"], cell["chips"], device["count"] >= cell["chips"])
+    check("pallas_enabled_not_interpreted",
+          [pallas_kernels.pallas_enabled(), pallas_kernels._interpret()],
+          [True, False],
+          pallas_kernels.pallas_enabled() and not pallas_kernels._interpret())
+    check("window_requests_on_device_path", on_device + batched, len(requests),
+          on_device + batched == len(requests))
+    check("none_degraded_or_on_host", [degraded, host_path], [0, 0],
+          degraded == 0 and host_path == 0)
+    check("no_sticky_lowering_failure", stats["plan_cache"]["sticky_failures"], 0,
+          stats["plan_cache"]["sticky_failures"] == 0)
+    bad_breakers = {fp: b for fp, b in stats["breakers"].items()
+                    if b["state"] != "closed" or b["total_failures"]}
+    check("no_breaker_open_or_failed", bad_breakers, {}, not bad_breakers)
+    statuses = {str(s): n for s, n in sorted(cl.statuses.items())}
+    check("only_http_200", statuses, "only 200", set(cl.statuses) == {200})
+
+    run = {"cycles": cycles, "requests": requests, "setup_s": setup_s}
+    lat = sorted(r["ms"] for r in requests)
+    say(phase="window", window_s=window_s, whole_cycles=len(cycles),
+        requests=len(requests), latency_samples=len(lat),
+        cycle_ms_median=(sorted(c["ms"] for c in cycles)[len(cycles) // 2]
+                         if cycles else None),
+        cycle_ms_all=[c["ms"] for c in cycles][:64],
+        latency_ms_min_median_max=(
+            [lat[0], lat[len(lat) // 2], lat[-1]] if lat else None),
+        phases=phases, reference_s=reference_s,
+        compile_counters={k: v for k, v in counters1.items()
+                          if k.startswith("compile")})
+
+    units = {m["name"]: m["unit"] for m in bench["end_to_end"] + bench["per_layer"]}
+    metrics, breakdown = {}, None
+    if not trace:
+        for m in layers.declared(bench, "end_to_end", workload):
+            value = e2e.METRICS[m["name"]](run)
+            if value is not None:
+                metrics[m["name"]] = {"value": value, "unit": units[m["name"]]}
+    else:
+        reduced = None
+        if tracing == "done":
+            reduced = xplane.reduce(xplane.load(xplane.find_trace(trace_dir)))
+            shutil.rmtree(trace_dir, ignore_errors=True)
+        by_trace = {}
+        for sp in span_list:
+            by_trace.setdefault(sp["trace_id"], []).append(sp)
+        ctx = {"cycles": cycles, "spans_by_trace": by_trace,
+               "counters0": counters0, "counters1": counters1,
+               "phases": phases, "quantities": quantities, "trace": reduced}
+        metrics = layers.read_all(bench, workload, ctx)
+        if reduced and reduced["devices"]:
+            device["busy_s"] = reduced["busy_s"]
+            device["window_s"] = reduced["window_s"]
+            walls = [r["wall"] for r in requests if r["in_trace"]]
+            starts = [a for a, _ in reduced["annotations"]]
+            offset = 0.0
+            if walls and len(walls) == len(starts):
+                diffs = sorted(w - a / 1e9 for w, a in zip(walls, starts))
+                offset = diffs[len(diffs) // 2]
+            breakdown = {
+                "device_ops": [[n, s] for n, s in sorted(
+                    reduced["top"].items(), key=lambda kv: -kv[1])[:10]],
+                "idle_gaps": xplane.name_gaps(
+                    reduced["gaps"], span_list, offset, reduced["annotations"]),
+            }
+            say(phase="trace", traced_s=traced_s, busy_s=reduced["busy_s"],
+                window_s=reduced["window_s"], devices=reduced["devices"],
+                device_lines=reduced["lines"],
+                self_time_top=sorted(reduced["self"].items(),
+                                     key=lambda kv: -kv[1])[:25])
+        check("device_ran_ops_in_trace", device.get("busy_s", 0.0), ">0",
+              device.get("busy_s", 0.0) > 0)
+
+    failed = [c["check"] for c in checks if not c["ok"] and not c["waived"]]
+    if failed:
+        say(phase="failed_checks", checks=failed)
+    result = {"correct": not failed, "attempted": len(requests),
+              "failed": len(bad), "metrics": metrics, "device": device}
+    if breakdown is not None:
+        result["breakdown"] = breakdown
+    return result, 0 if not failed else 1
+
+
+def _control(config, data, seed, want, Reference):
+    """The control: the reference, put in the program's place, with one
+    stated guarantee broken -- it answers from a store that lacks a share of
+    the acknowledged triples (a stale read).  It has to come out wrong."""
+    import numpy as np
+
+    share = config["control"]["stale_share"]
+    keep = np.random.default_rng([int(seed), 7]).random(len(data["s"])) >= share
+    stale = Reference(data["terms"], data["s"][keep], data["p"][keep],
+                      data["o"][keep])
+    wrong = sum(1 for text, rows in want.items()
+                if compare.multiset(stale.query(text)) != rows)
+    say(phase="control", guarantee="a read sees every acknowledged triple",
+        stale_share=share, triples_missing=int((~keep).sum()),
+        distinct_texts=len(want), texts_answered_wrongly=wrong, limit=0,
+        control_correct=wrong == 0)
