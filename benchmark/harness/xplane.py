@@ -26,6 +26,19 @@ def find_trace(trace_dir: str) -> str:
     return found[-1]
 
 
+_TARGET = re.compile(r'custom_call_target="([^"]+)"')
+
+
+def op_name(text: str) -> str:
+    """The trace prints a device op as its whole HLO instruction
+    (``%while.167 = (u32[], ...) while(...)``): keep the instruction's name,
+    and for a custom call its target (``custom-call.3:tpu_custom_call`` is a
+    Mosaic kernel)."""
+    name = text.split(" = ", 1)[0].lstrip("%")
+    target = _TARGET.search(text)
+    return f"{name}:{target.group(1)}" if target else name
+
+
 def load(path: str):
     """The trace as plain lists: ``[(plane, [(line, [(name, start_ns,
     dur_ns)])])]``."""
@@ -34,7 +47,7 @@ def load(path: str):
     data = ProfileData.from_file(path)
     return [
         (plane.name, [
-            (line.name, [(e.name, float(e.start_ns), float(e.duration_ns))
+            (line.name, [(op_name(e.name), float(e.start_ns), float(e.duration_ns))
                          for e in line.events])
             for line in plane.lines
         ])

@@ -1,0 +1,125 @@
+"""Tests of the benchmark itself, at a size a test run can hold (CPU).
+
+Run with ``JAX_PLATFORMS=cpu python -m pytest benchmark/tests -q``.  They are
+not part of the repo's tier-1 suite (``tests/``).
+
+* the plain reference agrees with the program's host engine;
+* the control -- the reference answering from a store that lacks 1 % of the
+  acknowledged triples -- comes out not correct, on several seeds;
+* a sound run, with the look for a chip waived, is ``correct``; the same run
+  with the timed path's answer altered where the client receives it is not;
+* off the chip, the command prints no result and exits non-zero.
+"""
+
+import json
+import os
+import subprocess
+import sys
+import time
+
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+sys.path.insert(0, REPO)
+
+from benchmark.harness import compare, runner  # noqa: E402
+from benchmark.harness import data as files  # noqa: E402
+from benchmark.harness.traffic import Traffic  # noqa: E402
+from benchmark.reference.sparql_subset import Reference  # noqa: E402
+
+CELLS = {"lubm200.triangles": 2, "lubm200.lookups": 2, "employee100k.join": 25000}
+CHIP_LOOK = frozenset({"platform_is_tpu", "pallas_enabled_not_interpreted",
+                       "scale_as_configured"})
+
+
+def _cell(workload, seed, scale):
+    cell = files.read_json("workloads", workload + ".json")
+    config = files.read_json("configs", cell["config"] + ".json")
+    data = files.load_module("generators", config["generator"]).generate(
+        config, seed, scale)
+    return config, data, Traffic(cell["traffic"], data["domains"], seed)
+
+
+@pytest.mark.parametrize("workload", sorted(CELLS))
+def test_reference_agrees_with_the_host_engine(workload):
+    from kolibrie_tpu.query.executor import execute_query_volcano
+    from kolibrie_tpu.query.sparql_database import SparqlDatabase
+
+    _, data, traffic = _cell(workload, 11, CELLS[workload])
+    db = SparqlDatabase()
+    for text in files.ntriples_chunks(data):
+        db.parse_ntriples(text)
+    db.execution_mode = "host"
+    ref = Reference(data["terms"], data["s"], data["p"], data["o"])
+    for _, text in traffic.cycle(0) + traffic.cycle(1):
+        want = compare.multiset(execute_query_volcano(text, db))
+        assert sum(want.values()) > 0
+        assert compare.multiset(ref.query(text)) == want
+
+
+@pytest.mark.parametrize("workload", sorted(CELLS))
+@pytest.mark.parametrize("seed", [3, 2**31 + 5, 77])
+def test_control_comes_out_not_correct(workload, seed, capsys):
+    config, data, traffic = _cell(workload, seed, CELLS[workload])
+    ref = Reference(data["terms"], data["s"], data["p"], data["o"])
+    want = {text: compare.multiset(ref.query(text))
+            for k in range(20) for _, text in traffic.cycle(k)}
+    runner._control(config, data, seed, want, Reference)
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert line["texts_answered_wrongly"] > 0 and not line["control_correct"]
+
+
+def test_lubm_seed_0_is_the_repos_generator():
+    pytest.importorskip("benches.lubm")
+    from benches import lubm
+    from kolibrie_tpu.core.dictionary import Dictionary
+
+    d = Dictionary()
+    s, p, o = lubm.generate_fast(3, d)
+    theirs = sorted(zip((d.id_to_str[i] for i in s), (d.id_to_str[i] for i in p),
+                        (d.id_to_str[i] for i in o)))
+    _, data, _ = _cell("lubm200.triangles", 0, 3)
+    t = np.array([x[1:-1] for x in data["terms"]], object)
+    assert sorted(zip(t[data["s"]], t[data["p"]], t[data["o"]])) == theirs
+    _, other, _ = _cell("lubm200.triangles", 1, 3)
+    assert (other["o"] != data["o"]).any()
+
+
+@pytest.mark.parametrize("workload", sorted(CELLS))
+def test_sound_run_is_correct_and_broken_answers_are_not(workload):
+    def run(tamper=None):
+        result, code = runner.run_cell(
+            workload, 2**31 + 9, 1.0, False, time.perf_counter(),
+            scale=CELLS[workload], waive=CHIP_LOOK, tamper=tamper)
+        return result, code
+
+    result, code = run()
+    assert result["correct"] and code == 0 and result["failed"] == 0
+    assert result["metrics"]["cycle_ms"]["value"] > 0
+
+    def alter_one_value(i, body):
+        if i != 1:
+            return body
+        rows = json.loads(body)["data"]
+        rows[0][0] += "x"
+        return json.dumps({"data": rows}).encode()
+
+    def drop_one_row(i, body):
+        rows = json.loads(body)["data"]
+        return json.dumps({"data": rows[1:]}).encode() if i == 0 else body
+
+    for tamper in (alter_one_value, drop_one_row):
+        result, code = run(tamper)
+        assert not result["correct"] and code == 1 and result["failed"] == 1
+
+
+def test_off_the_chip_no_result_and_non_zero_exit():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("KOLIBRIE_BENCH_REHEARSAL_SCALE", None)
+    p = subprocess.run(
+        [sys.executable, os.path.join(REPO, "benchmark", "run.py"), "--workload",
+         "employee100k.join", "--seed", "1", "--seconds", "1", "--trace", "0"],
+        env=env, capture_output=True, text=True, timeout=300)
+    assert p.returncode != 0
+    assert '"correct"' not in p.stdout
