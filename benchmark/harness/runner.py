@@ -6,19 +6,23 @@ found by name under ``benchmark/`` (see README.md).
 """
 
 import json
+import multiprocessing
 import os
 import shutil
 import sys
 import threading
 import time
+from collections import Counter
 
-from . import compare, e2e, layers, xplane
+import numpy as np
+
+from . import e2e, layers, loadgen, xplane
 from . import data as files
 from .client import Client
-from .traffic import Traffic
 
 RING_CAPACITY = 2_000_000  # spans; the program's default is 4096
 NO_CHIP_EXIT = 3
+ANSWER_TIMEOUT_S = 1200  # longer than any request's deadline
 
 
 def say(**obj) -> None:
@@ -42,44 +46,49 @@ def _counters(cl, device_compile_stats, compile_cache) -> dict:
     return out
 
 
-def _send_cycle(cl, sid, cycle_no, steps, traced, requests, tamper):
-    """Send one cycle; returns ``(ms, trace ids)``."""
-    import jax
-
-    ids, t0 = [], time.perf_counter()
-    for i, (template, text) in enumerate(steps):
-        trace_id = f"bench-{cycle_no}-{i}" if traced is not None else ""
-        wall = time.time()
-        if traced:
-            with jax.profiler.TraceAnnotation("bench.request"):
-                status, body, ms = cl.query(sid, text, trace_id)
-        else:
-            status, body, ms = cl.query(sid, text, trace_id)
-        if tamper is not None:
-            body = tamper(len(requests), body)
-        requests.append({"cycle": cycle_no, "template": template, "text": text,
-                         "status": status, "body": body, "ms": ms,
-                         "wall": wall, "in_trace": bool(traced)})
-        ids.append(trace_id)
-    return (time.perf_counter() - t0) * 1000.0, ids
-
-
 def run_cell(workload, seed, seconds, trace, t_start, scale=None,
              waive=frozenset(), tamper=None, control=False):
     """Returns ``(result line or None, exit code)``.
 
     ``scale`` is the rehearsal's override of the configuration's scale: with
     it a run is never ``correct``.  ``waive`` (checks left out of
-    ``correct``) and ``tamper`` (alters a response body where the client
-    receives it) are for the tests under ``benchmark/tests`` only; nothing on
-    the command line or in the environment reaches them.
+    ``correct``) and ``tamper`` (``(request index, "drop_row" |
+    "alter_value")``: a response altered where the client receives it) are
+    for the tests under ``benchmark/tests`` only; nothing on the command line
+    or in the environment reaches them.
     """
-    bench = files.read_json(os.pardir, "BENCHMARK.json")
     cell = files.read_json("workloads", workload + ".json")
     config = files.read_json("configs", cell["config"] + ".json")
     os.environ.update(cell.get("env", {}))
     if files.REPO_DIR not in sys.path:
         sys.path.insert(0, files.REPO_DIR)
+    # the load generator is a process of its own (see loadgen.py); it starts
+    # generating while this one starts JAX
+    ctx = multiprocessing.get_context("spawn")
+    conn, child_end = ctx.Pipe()
+    child = ctx.Process(
+        target=loadgen.main, daemon=True,
+        args=(child_end, config, cell["traffic"], seed, scale, tamper))
+    child.start()
+    child_end.close()
+    try:
+        conn.send(("generate",))
+        return _serve_and_measure(conn, workload, cell, config, seed, seconds,
+                                  trace, t_start, scale, waive, control)
+    finally:
+        try:
+            conn.send(("quit",))
+        except (OSError, ValueError):
+            pass
+        child.join(30)
+        if child.is_alive():
+            child.kill()
+            child.join()
+
+
+def _serve_and_measure(conn, workload, cell, config, seed, seconds, trace,
+                       t_start, scale, waive, control):
+    bench = files.read_json(os.pardir, "BENCHMARK.json")
 
     import jax
 
@@ -115,48 +124,45 @@ def run_cell(workload, seed, seconds, trace, t_start, scale=None,
                        "ok": bool(ok), "waived": name in waive})
         say(**checks[-1])
 
+    def answer():
+        if not conn.poll(ANSWER_TIMEOUT_S):
+            raise RuntimeError("the load generator did not answer")
+        return conn.recv()
+
+    def ask(*cmd):
+        conn.send(cmd)
+        return answer()
+
     phases, quantities = {}, {}
     httpd = http_server.make_server("127.0.0.1", 0, quiet=True, data_dir=None)
     server = threading.Thread(target=httpd.serve_forever, daemon=True)
     server.start()
     try:
-        traffic_name = cell["traffic"]
-        t0 = time.perf_counter()
-        data = files.load_module("generators", config["generator"]).generate(
-            config, seed, scale)
-        chunks = files.ntriples_chunks(data)
-        n_triples = len(data["s"])
-        phases["generate"] = time.perf_counter() - t0
-        traffic = Traffic(traffic_name, data["domains"], seed)
-        cl = Client(httpd.server_address[1], traffic.deadline_ms)
-
-        sid, t0 = "bench", time.perf_counter()
-        for text in chunks:
-            body = cl.post("/store/load",
-                           {"store_id": sid, "rdf": text, "format": "ntriples"})
-        phases["load"] = time.perf_counter() - t0
-        quantities["triples"] = n_triples
-        del chunks
-        db = httpd.RequestHandlerClass.state.stores[sid].db
-        say(phase="load", triples=body["triples"], generated=n_triples,
+        port = httpd.server_address[1]
+        generated = answer()  # to the "generate" sent before JAX started
+        phases["generate"] = generated["seconds"]
+        quantities["triples"] = n_triples = generated["triples"]
+        loaded = ask("load", port)
+        phases["load"] = loaded["seconds"]
+        db = httpd.RequestHandlerClass.state.stores[loadgen.STORE_ID].db
+        say(phase="load", triples=loaded["acknowledged"], generated=n_triples,
             generate_s=phases["generate"], load_s=phases["load"])
-        check("load_acknowledged_all_triples", body["triples"], n_triples,
-              body["triples"] == n_triples)
+        check("load_acknowledged_all_triples", loaded["acknowledged"], n_triples,
+              loaded["acknowledged"] == n_triples)
         check("store_mode", db.execution_mode, config["store_mode"],
               db.execution_mode == config["store_mode"])
         check("scale_as_configured", scale, None, scale is None)
 
         # warm every template of the cycle, as often as the traffic file
         # says, so the cap advisor's re-runs and every compile are over
-        t0, warm = time.perf_counter(), []
-        for k in range(traffic.warmup_cycles):
-            ms, _ = _send_cycle(cl, sid, -1 - k, traffic.cycle(k, "warmup"),
-                                None, warm, None)
-            say(phase="warmup", cycle=k, ms=ms,
-                statuses=sorted({r["status"] for r in warm}))
+        t0 = time.perf_counter()
+        for k in range(generated["warmup_cycles"]):
+            got = ask("cycle", k, "warmup", False)
+            say(phase="warmup", cycle=k, ms=got["ms"],
+                statuses=sorted({r["status"] for r in got["requests"]}))
         phases["warmup"] = time.perf_counter() - t0
-        del warm
 
+        cl = Client(port, 60_000)  # this process reads /metrics and /stats only
         trace_dir = files.path(".traces", f"{workload}-{seed}")
         if trace:
             shutil.rmtree(trace_dir, ignore_errors=True)
@@ -165,7 +171,7 @@ def run_cell(workload, seed, seconds, trace, t_start, scale=None,
         counters0 = _counters(cl, device_compile_stats, compile_cache)
 
         # ---- the window: whole cycles, one client, closed loop
-        requests, cycles = [], []
+        requests, cycles, anchors = [], [], []
         tracing, traced_s, t_trace = "before", 0.0, 0.0
         setup_s = time.perf_counter() - t_start
         t_open = time.perf_counter()
@@ -176,15 +182,20 @@ def run_cell(workload, seed, seconds, trace, t_start, scale=None,
                 opts.python_tracer_level = 0
                 jax.profiler.start_trace(trace_dir, profiler_options=opts)
                 tracing, t_trace = "on", time.perf_counter()
-            n_before = len(requests)
-            ms, ids = _send_cycle(cl, sid, k, traffic.cycle(k),
-                                  (tracing == "on") if trace else None,
-                                  requests, tamper)
-            cycles.append({"k": k, "ms": ms, "trace_ids": ids, "whole": True,
-                           "n": len(requests) - n_before})
+            if tracing == "on":
+                # the traced window, and the anchor between the wall clock
+                # and the trace's clock
+                anchors.append(time.time())
+                with jax.profiler.TraceAnnotation(xplane.ANNOTATION):
+                    got = ask("cycle", k, "window", True)
+            else:
+                got = ask("cycle", k, "window", trace)
+            requests += got["requests"]
+            cycles.append({"k": k, "ms": got["ms"], "whole": True,
+                           "trace_ids": [r["trace_id"] for r in got["requests"]]})
             k += 1
             if tracing == "on" and (
-                time.perf_counter() - t_trace >= traffic.trace_min_seconds
+                time.perf_counter() - t_trace >= generated["trace_min_seconds"]
             ):
                 traced_s = time.perf_counter() - t_trace
                 jax.profiler.stop_trace()
@@ -196,33 +207,29 @@ def run_cell(workload, seed, seconds, trace, t_start, scale=None,
             tracing = "done"
         counters1 = _counters(cl, device_compile_stats, compile_cache)
         span_list = prog_spans.spans_snapshot() if trace else []
-        stats = cl.get_json("/stats")["stores"][sid]
+        stats = cl.get_json("/stats")["stores"][loadgen.STORE_ID]
         mem = [d.memory_stats() or {} for d in jax.local_devices()]
         device["memory_peak_bytes"] = max(
             (m.get("peak_bytes_in_use", 0) for m in mem), default=0)
+        statuses = Counter(ask("statuses")) + cl.statuses
     finally:
         httpd.shutdown()
         httpd.server_close()
         server.join(timeout=30)
 
-    # ---- outside the window and outside set-up: the plain reference
-    from benchmark.reference.sparql_subset import Reference
-
-    t0 = time.perf_counter()
-    ref = Reference(data["terms"], data["s"], data["p"], data["o"])
-    bad, want = compare.wrong_answers(requests, ref.query)
-    reference_s = time.perf_counter() - t0
-    non_200 = sum(1 for r in requests if r["status"] != 200)
-    empty = sorted({r["template"] for r in requests if not want.get(r["text"])})
-    say(phase="compare", requests=len(requests), distinct_texts=len(want),
-        reference_s=reference_s, rows_by_template={
-            r["template"]: sum(want[r["text"]].values())
-            for r in requests if r["text"] in want})
+    # ---- outside the window and outside set-up: the plain reference, in the
+    # load generator's process (it kept the bodies)
+    compared = ask("compare", control)
+    bad, reference_s = compared["wrong"], compared["reference_s"]
+    say(phase="compare", requests=len(requests), reference_s=reference_s,
+        distinct_texts=compared["distinct_texts"],
+        rows_by_template=compared["rows_by_template"])
     check("wrong_or_failed_answers", len(bad), 0, not bad)
-    check("templates_with_empty_reference_answer", empty, [], not empty)
+    check("templates_with_empty_reference_answer", compared["empty"], [],
+          not compared["empty"])
     check("whole_cycles_in_window", len(cycles), ">=1", len(cycles) >= 1)
     if control:
-        _control(config, data, seed, want, Reference)
+        say(phase="control", **compared["control"])
 
     def delta(name):
         key = "metrics." + name
@@ -247,11 +254,14 @@ def run_cell(workload, seed, seconds, trace, t_start, scale=None,
     bad_breakers = {fp: b for fp, b in stats["breakers"].items()
                     if b["state"] != "closed" or b["total_failures"]}
     check("no_breaker_open_or_failed", bad_breakers, {}, not bad_breakers)
-    statuses = {str(s): n for s, n in sorted(cl.statuses.items())}
-    check("only_http_200", statuses, "only 200", set(cl.statuses) == {200})
+    check("only_http_200", {str(s): n for s, n in sorted(statuses.items())},
+          "only 200", set(statuses) == {200})
 
     run = {"cycles": cycles, "requests": requests, "setup_s": setup_s}
     lat = sorted(r["ms"] for r in requests)
+    by_template = {}
+    for r in requests:
+        by_template.setdefault(r["template"], []).append(r["ms"])
     say(phase="window", window_s=window_s, whole_cycles=len(cycles),
         requests=len(requests), latency_samples=len(lat),
         cycle_ms_median=(sorted(c["ms"] for c in cycles)[len(cycles) // 2]
@@ -259,6 +269,9 @@ def run_cell(workload, seed, seconds, trace, t_start, scale=None,
         cycle_ms_all=[c["ms"] for c in cycles][:64],
         latency_ms_min_median_max=(
             [lat[0], lat[len(lat) // 2], lat[-1]] if lat else None),
+        latency_ms_by_template={
+            name: [len(v)] + [float(x) for x in np.percentile(v, [0, 25, 50, 75, 95, 100])]
+            for name, v in by_template.items()},
         phases=phases, reference_s=reference_s,
         compile_counters={k: v for k, v in counters1.items()
                           if k.startswith("compile")})
@@ -285,11 +298,10 @@ def run_cell(workload, seed, seconds, trace, t_start, scale=None,
         if reduced and reduced["devices"]:
             device["busy_s"] = reduced["busy_s"]
             device["window_s"] = reduced["window_s"]
-            walls = [r["wall"] for r in requests if r["in_trace"]]
             starts = [a for a, _ in reduced["annotations"]]
             offset = 0.0
-            if walls and len(walls) == len(starts):
-                diffs = sorted(w - a / 1e9 for w, a in zip(walls, starts))
+            if anchors and len(anchors) == len(starts):
+                diffs = sorted(w - a / 1e9 for w, a in zip(anchors, starts))
                 offset = diffs[len(diffs) // 2]
             breakdown = {
                 "device_ops": [[n, s] for n, s in sorted(
@@ -300,6 +312,7 @@ def run_cell(workload, seed, seconds, trace, t_start, scale=None,
             say(phase="trace", traced_s=traced_s, busy_s=reduced["busy_s"],
                 window_s=reduced["window_s"], devices=reduced["devices"],
                 device_lines=reduced["lines"],
+                custom_calls={n: s for n, s in reduced["any"].items() if ":" in n},
                 self_time_top=sorted(reduced["self"].items(),
                                      key=lambda kv: -kv[1])[:25])
         check("device_ran_ops_in_trace", device.get("busy_s", 0.0), ">0",
@@ -313,21 +326,3 @@ def run_cell(workload, seed, seconds, trace, t_start, scale=None,
     if breakdown is not None:
         result["breakdown"] = breakdown
     return result, 0 if not failed else 1
-
-
-def _control(config, data, seed, want, Reference):
-    """The control: the reference, put in the program's place, with one
-    stated guarantee broken -- it answers from a store that lacks a share of
-    the acknowledged triples (a stale read).  It has to come out wrong."""
-    import numpy as np
-
-    share = config["control"]["stale_share"]
-    keep = np.random.default_rng([int(seed), 7]).random(len(data["s"])) >= share
-    stale = Reference(data["terms"], data["s"][keep], data["p"][keep],
-                      data["o"][keep])
-    wrong = sum(1 for text, rows in want.items()
-                if compare.multiset(stale.query(text)) != rows)
-    say(phase="control", guarantee="a read sees every acknowledged triple",
-        stale_share=share, triples_missing=int((~keep).sum()),
-        distinct_texts=len(want), texts_answered_wrongly=wrong, limit=0,
-        control_correct=wrong == 0)

@@ -23,7 +23,7 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, REPO)
 
-from benchmark.harness import compare, runner  # noqa: E402
+from benchmark.harness import compare, loadgen, runner  # noqa: E402
 from benchmark.harness import data as files  # noqa: E402
 from benchmark.harness.traffic import Traffic  # noqa: E402
 from benchmark.reference.sparql_subset import Reference  # noqa: E402
@@ -60,14 +60,14 @@ def test_reference_agrees_with_the_host_engine(workload):
 
 @pytest.mark.parametrize("workload", sorted(CELLS))
 @pytest.mark.parametrize("seed", [3, 2**31 + 5, 77])
-def test_control_comes_out_not_correct(workload, seed, capsys):
+def test_control_comes_out_not_correct(workload, seed):
     config, data, traffic = _cell(workload, seed, CELLS[workload])
-    ref = Reference(data["terms"], data["s"], data["p"], data["o"])
-    want = {text: compare.multiset(ref.query(text))
-            for k in range(20) for _, text in traffic.cycle(k)}
-    runner._control(config, data, seed, want, Reference)
-    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert line["texts_answered_wrongly"] > 0 and not line["control_correct"]
+    requests = [{"template": name, "text": text, "status": 200, "body": b""}
+                for k in range(20) for name, text in traffic.cycle(k)]
+    out = loadgen._compare(config, data, seed, requests, control=True)
+    assert len(out["wrong"]) == len(requests)  # empty bodies: all wrong
+    assert out["control"]["texts_answered_wrongly"] > 0
+    assert not out["control"]["control_correct"]
 
 
 def test_lubm_seed_0_is_the_repos_generator():
@@ -98,18 +98,7 @@ def test_sound_run_is_correct_and_broken_answers_are_not(workload):
     assert result["correct"] and code == 0 and result["failed"] == 0
     assert result["metrics"]["cycle_ms"]["value"] > 0
 
-    def alter_one_value(i, body):
-        if i != 1:
-            return body
-        rows = json.loads(body)["data"]
-        rows[0][0] += "x"
-        return json.dumps({"data": rows}).encode()
-
-    def drop_one_row(i, body):
-        rows = json.loads(body)["data"]
-        return json.dumps({"data": rows[1:]}).encode() if i == 0 else body
-
-    for tamper in (alter_one_value, drop_one_row):
+    for tamper in ((1, "alter_value"), (0, "drop_row")):
         result, code = run(tamper)
         assert not result["correct"] and code == 1 and result["failed"] == 1
 
