@@ -7,7 +7,7 @@ import numpy as np
 def cycle_ms(run):
     """Client wall time of one whole pass over the cell's cycle: all the time
     of the window's whole cycles over their number."""
-    whole = [c["ms"] for c in run["cycles"] if c["whole"]]
+    whole = [c["ms"] for c in run["cycles"]]
     return sum(whole) / len(whole) if whole else None
 
 

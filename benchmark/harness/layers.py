@@ -12,13 +12,16 @@ def per_cycle(ctx, value_of_trace):
     ``value_of_trace(spans of one request)``."""
     vals = []
     for cyc in ctx["cycles"]:
-        if not cyc["whole"]:
-            continue
         groups = [ctx["spans_by_trace"].get(t) for t in cyc["trace_ids"]]
         if any(g is None for g in groups):
             continue  # the ring lost this cycle's spans
         vals.append(sum(value_of_trace(g) for g in groups))
     return median(vals) if vals else None
+
+
+def has_span(ctx, names) -> bool:
+    return any(sp["name"] in names
+               for group in ctx["spans_by_trace"].values() for sp in group)
 
 
 def declared(bench: dict, kind: str, workload: str):

@@ -29,8 +29,11 @@ def say(**obj) -> None:
     print(json.dumps(obj, sort_keys=True, default=str), flush=True)
 
 
-def _counters(cl, device_compile_stats, compile_cache) -> dict:
+def _counters(cl) -> dict:
     """Every counter a reader may ask for, under one flat naming."""
+    from kolibrie_tpu.optimizer.device_engine import device_compile_stats
+    from kolibrie_tpu.query import compile_cache
+
     out = {}
     for line in cl.get_text("/metrics").splitlines():
         if line and not line.startswith("#"):
@@ -95,7 +98,6 @@ def _serve_and_measure(conn, workload, cell, config, seed, seconds, trace,
     from kolibrie_tpu.frontends import http_server
     from kolibrie_tpu.obs import spans as prog_spans
     from kolibrie_tpu.ops import pallas_kernels
-    from kolibrie_tpu.optimizer.device_engine import device_compile_stats
     from kolibrie_tpu.query import compile_cache
 
     # before the first lowering; where JAX_COMPILATION_CACHE_DIR is set the
@@ -168,7 +170,7 @@ def _serve_and_measure(conn, workload, cell, config, seed, seconds, trace,
             shutil.rmtree(trace_dir, ignore_errors=True)
             prog_spans.set_ring_capacity(RING_CAPACITY)
             prog_spans.clear()
-        counters0 = _counters(cl, device_compile_stats, compile_cache)
+        counters0 = _counters(cl)
 
         # ---- the window: whole cycles, one client, closed loop
         requests, cycles, anchors = [], [], []
@@ -191,21 +193,17 @@ def _serve_and_measure(conn, workload, cell, config, seed, seconds, trace,
             else:
                 got = ask("cycle", k, "window", trace)
             requests += got["requests"]
-            cycles.append({"k": k, "ms": got["ms"], "whole": True,
+            cycles.append({"k": k, "ms": got["ms"],
                            "trace_ids": [r["trace_id"] for r in got["requests"]]})
             k += 1
-            if tracing == "on" and (
-                time.perf_counter() - t_trace >= generated["trace_min_seconds"]
-            ):
+            out_of_time = time.perf_counter() - t_open >= seconds
+            if tracing == "on" and (out_of_time or time.perf_counter() - t_trace
+                                    >= generated["trace_min_seconds"]):
                 traced_s = time.perf_counter() - t_trace
                 jax.profiler.stop_trace()
                 tracing = "done"
         window_s = time.perf_counter() - t_open
-        if tracing == "on":
-            traced_s = time.perf_counter() - t_trace
-            jax.profiler.stop_trace()
-            tracing = "done"
-        counters1 = _counters(cl, device_compile_stats, compile_cache)
+        counters1 = _counters(cl)
         span_list = prog_spans.spans_snapshot() if trace else []
         stats = cl.get_json("/stats")["stores"][loadgen.STORE_ID]
         mem = [d.memory_stats() or {} for d in jax.local_devices()]
@@ -258,19 +256,16 @@ def _serve_and_measure(conn, workload, cell, config, seed, seconds, trace,
           "only 200", set(statuses) == {200})
 
     run = {"cycles": cycles, "requests": requests, "setup_s": setup_s}
-    lat = sorted(r["ms"] for r in requests)
     by_template = {}
     for r in requests:
         by_template.setdefault(r["template"], []).append(r["ms"])
     say(phase="window", window_s=window_s, whole_cycles=len(cycles),
-        requests=len(requests), latency_samples=len(lat),
+        requests=len(requests),
         cycle_ms_median=(sorted(c["ms"] for c in cycles)[len(cycles) // 2]
                          if cycles else None),
         cycle_ms_all=[c["ms"] for c in cycles][:64],
-        latency_ms_min_median_max=(
-            [lat[0], lat[len(lat) // 2], lat[-1]] if lat else None),
-        latency_ms_by_template={
-            name: [len(v)] + [float(x) for x in np.percentile(v, [0, 25, 50, 75, 95, 100])]
+        latency_ms_count_min_p25_p50_p75_p95_max={
+            name: [len(v)] + np.percentile(v, [0, 25, 50, 75, 95, 100]).tolist()
             for name, v in by_template.items()},
         phases=phases, reference_s=reference_s,
         compile_counters={k: v for k, v in counters1.items()

@@ -5,8 +5,8 @@ Only ``jax.profiler.ProfileData`` is needed to read the file.  A device
 plane is one named ``/device:TPU:<n>``; its ``XLA Ops`` line holds the
 operations, nested in time where one (a ``while``) runs others.  Busy time is
 the union of that line's intervals, clipped to the window; the window is the
-span from the first to the last ``bench.request`` annotation the client wrote
-into the trace, so starting and stopping the profiler is not counted as idle.
+span from the first to the last ``bench.request`` annotation the harness wrote
+into the trace, one around each traced cycle, so starting and stopping the profiler is not counted as idle.
 """
 
 import glob
@@ -15,7 +15,7 @@ import re
 
 DEVICE_PLANE = re.compile(r"^/device:(TPU|GPU):(\d+)$")
 OPS_LINES = ("XLA Ops", "XLA Modules")
-ANNOTATION = "bench.request"
+ANNOTATION = "bench.request"  # one a traced cycle; the recorded fixture has this name
 
 
 def find_trace(trace_dir: str) -> str:
@@ -146,8 +146,9 @@ def name_gaps(gaps, spans, offset_s: float, annotations, limit: int = 10):
 
     ``spans`` are the program's (``start_s`` on the wall clock, ``dur_ms``);
     ``offset_s`` is wall time minus trace time.  A gap is named by the
-    shortest program span that covers its midpoint; outside every request it
-    is the client's own time between requests.
+    shortest program span that covers its midpoint; inside a cycle but outside
+    every span it is the client's or the wire's time, outside every cycle the
+    harness's.
     """
     timed = [((sp["start_s"] - offset_s) * 1e9, sp["dur_ms"] * 1e6, sp["name"])
              for sp in spans]
@@ -158,8 +159,8 @@ def name_gaps(gaps, spans, offset_s: float, annotations, limit: int = 10):
         if cover:
             name = min(cover)[1]
         elif any(a <= mid <= b for a, b in annotations):
-            name = "client: request in flight, outside the server's spans"
+            name = "client or wire: in a cycle, outside the server's spans"
         else:
-            name = "client: between requests"
+            name = "harness: between cycles"
         out.append([name, dur / 1e9])
     return out
