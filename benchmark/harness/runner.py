@@ -157,7 +157,7 @@ def _serve_and_measure(conn, workload, cell, config, seed, seconds, trace,
 
         # warm every template of the cycle, as often as the traffic file
         # says, so the cap advisor's re-runs and every compile are over
-        t0 = time.perf_counter()
+        t0, got = time.perf_counter(), {"ms": 0.0}
         for k in range(generated["warmup_cycles"]):
             got = ask("cycle", k, "warmup", False)
             say(phase="warmup", cycle=k, ms=got["ms"],
@@ -175,11 +175,14 @@ def _serve_and_measure(conn, workload, cell, config, seed, seconds, trace,
         # ---- the window: whole cycles, one client, closed loop
         requests, cycles, anchors = [], [], []
         tracing, traced_s, t_trace = "before", 0.0, 0.0
+        # trace from the window's second cycle, or from its first where the
+        # window holds no second one
+        first_traced = 1 if 2 * got["ms"] < seconds * 1000.0 else 0
         setup_s = time.perf_counter() - t_start
         t_open = time.perf_counter()
         k = 0
         while time.perf_counter() - t_open < seconds:
-            if trace and tracing == "before" and k >= 1:
+            if trace and tracing == "before" and k >= first_traced:
                 opts = jax.profiler.ProfileOptions()
                 opts.python_tracer_level = 0
                 jax.profiler.start_trace(trace_dir, profiler_options=opts)

@@ -6,7 +6,8 @@ plane is one named ``/device:TPU:<n>``; its ``XLA Ops`` line holds the
 operations, nested in time where one (a ``while``) runs others.  Busy time is
 the union of that line's intervals, clipped to the window; the window is the
 span from the first to the last ``bench.request`` annotation the harness wrote
-into the trace, one around each traced cycle, so starting and stopping the profiler is not counted as idle.
+into the trace, one around each traced cycle, so starting and stopping the
+profiler is not counted as idle.
 """
 
 import glob
@@ -15,7 +16,8 @@ import re
 
 DEVICE_PLANE = re.compile(r"^/device:(TPU|GPU):(\d+)$")
 OPS_LINES = ("XLA Ops", "XLA Modules")
-ANNOTATION = "bench.request"  # one a traced cycle; the recorded fixture has this name
+# one annotation a traced cycle; the recorded fixture carries this name
+ANNOTATION = "bench.request"
 
 
 def find_trace(trace_dir: str) -> str:
@@ -95,7 +97,8 @@ def reduce(planes, window=None) -> dict:
             devices[pname] = by_name[line]
     lines_seen = [[pname, [[ln, len(ev)] for ln, ev in lines]]
                   for pname, lines in planes if not pname.startswith("/host:")]
-    empty = {"lines": lines_seen, "devices": 0, "busy_s": 0.0, "window_s": 0.0, "top": {}, "any": {},
+    empty = {"lines": lines_seen, "devices": 0, "busy_s": 0.0, "window_s": 0.0,
+             "top": {}, "any": {},
              "self": {}, "gaps": [], "annotations": annotations}
     if not devices:
         return empty

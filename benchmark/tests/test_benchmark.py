@@ -11,7 +11,6 @@ not part of the repo's tier-1 suite (``tests/``).
 * off the chip, the command prints no result and exits non-zero.
 """
 
-import json
 import os
 import subprocess
 import sys
