@@ -5,7 +5,6 @@ body for comparison happens later and outside any latency.
 """
 
 import json
-import threading
 import time
 import urllib.error
 import urllib.request
@@ -19,7 +18,6 @@ class Client:
         self.base = f"http://127.0.0.1:{port}"
         self.deadline_ms = int(deadline_ms)
         self.statuses: Counter = Counter()
-        self._lock = threading.Lock()
 
     def raw(self, path: str, payload=None, trace_id: str = ""):
         """``(status, body bytes, wall ms)`` of one round trip; never raises
@@ -38,8 +36,7 @@ class Client:
         except (urllib.error.URLError, TimeoutError, ConnectionError) as e:
             status, body = 0, repr(e).encode()
         ms = (time.perf_counter() - t0) * 1000.0
-        with self._lock:
-            self.statuses[status] += 1
+        self.statuses[status] += 1
         return status, body, ms
 
     def _ok(self, path, payload=None):
@@ -66,11 +63,3 @@ class Client:
             trace_id,
         )
 
-
-def metric(text: str, name: str, labels: str = "") -> float:
-    """One sample of the Prometheus exposition (0 when absent)."""
-    key = name + labels
-    for line in text.splitlines():
-        if line.startswith(key + " "):
-            return float(line.split()[-1])
-    return 0.0

@@ -5,10 +5,14 @@ import numpy as np
 
 
 def cycle_ms(run):
-    """Client wall time of one whole pass over the cell's cycle: all the time
-    of the window's whole cycles over their number."""
-    whole = [c["ms"] for c in run["cycles"]]
-    return sum(whole) / len(whole) if whole else None
+    """Client wall time of one whole pass over the cell's cycle: the time
+    from the window's first send to its last response, on the client's
+    clock, over the number of whole cycles -- what lies between two cycles
+    is in it."""
+    whole = run["cycles"]
+    if not whole:
+        return None
+    return (whole[-1]["t1"] - whole[0]["t0"]) * 1000.0 / len(whole)
 
 
 def latency_p95_ms(run):

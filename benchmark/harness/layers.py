@@ -1,6 +1,6 @@
-"""Per-layer metrics: each is a declaration file
-(``benchmark/layer_metrics/<name>.json``) naming a reader
-(``benchmark/readers/<kind>.py``) and its arguments."""
+"""Per-layer metrics: each has a file (``benchmark/layer_metrics/<name>.json``)
+naming its reader (``benchmark/readers/<kind>.py``) and the reader's
+arguments; unit, layer and the rest are ``BENCHMARK.json``'s to say."""
 
 from statistics import median
 
@@ -39,5 +39,5 @@ def read_all(bench: dict, workload: str, ctx: dict) -> dict:
         reader = files.load_module("readers", args.pop("kind"))
         value = reader.read(ctx, **args)
         if value is not None:
-            out[m["name"]] = {"value": value, "unit": decl["unit"]}
+            out[m["name"]] = {"value": value, "unit": m["unit"]}
     return out

@@ -60,9 +60,11 @@ def run_cell(workload, seed, seconds, trace, t_start, scale=None,
     for the tests under ``benchmark/tests`` only; nothing on the command line
     or in the environment reaches them.
     """
-    cell = files.read_json("workloads", workload + ".json")
+    bench = files.read_json(os.pardir, "BENCHMARK.json")
+    cell = next(w for w in bench["workloads"] if w["name"] == workload)
     config = files.read_json("configs", cell["config"] + ".json")
-    os.environ.update(cell.get("env", {}))
+    # the cell's own file holds what BENCHMARK.json has no key for
+    os.environ.update(files.read_json("workloads", workload + ".json")["env"])
     if files.REPO_DIR not in sys.path:
         sys.path.insert(0, files.REPO_DIR)
     # the load generator is a process of its own (see loadgen.py); it starts
@@ -76,7 +78,7 @@ def run_cell(workload, seed, seconds, trace, t_start, scale=None,
     child_end.close()
     try:
         conn.send(("generate",))
-        return _serve_and_measure(conn, workload, cell, config, seed, seconds,
+        return _serve_and_measure(conn, bench, cell, config, seed, seconds,
                                   trace, t_start, scale, waive, control)
     finally:
         try:
@@ -89,9 +91,9 @@ def run_cell(workload, seed, seconds, trace, t_start, scale=None,
             child.join()
 
 
-def _serve_and_measure(conn, workload, cell, config, seed, seconds, trace,
+def _serve_and_measure(conn, bench, cell, config, seed, seconds, trace,
                        t_start, scale, waive, control):
-    bench = files.read_json(os.pardir, "BENCHMARK.json")
+    workload = cell["name"]
 
     import jax
 
@@ -172,16 +174,19 @@ def _serve_and_measure(conn, workload, cell, config, seed, seconds, trace,
             prog_spans.clear()
         counters0 = _counters(cl)
 
-        # ---- the window: whole cycles, one client, closed loop
+        # ---- the window: whole cycles, one client, closed loop.  The first
+        # cycle is always sent; a further one only if the longest cycle seen
+        # so far would still end inside ``seconds``
         requests, cycles, anchors = [], [], []
         tracing, traced_s, t_trace = "before", 0.0, 0.0
+        longest_ms = got["ms"]
         # trace from the window's second cycle, or from its first where the
         # window holds no second one
-        first_traced = 1 if 2 * got["ms"] < seconds * 1000.0 else 0
+        first_traced = 1 if 2 * longest_ms < seconds * 1000.0 else 0
         setup_s = time.perf_counter() - t_start
         t_open = time.perf_counter()
         k = 0
-        while time.perf_counter() - t_open < seconds:
+        while k == 0 or time.perf_counter() - t_open + longest_ms / 1000.0 < seconds:
             if trace and tracing == "before" and k >= first_traced:
                 opts = jax.profiler.ProfileOptions()
                 opts.python_tracer_level = 0
@@ -196,15 +201,19 @@ def _serve_and_measure(conn, workload, cell, config, seed, seconds, trace,
             else:
                 got = ask("cycle", k, "window", trace)
             requests += got["requests"]
-            cycles.append({"k": k, "ms": got["ms"],
+            cycles.append({"k": k, "ms": got["ms"], "t0": got["t0"], "t1": got["t1"],
                            "trace_ids": [r["trace_id"] for r in got["requests"]]})
+            longest_ms = max(longest_ms, got["ms"]) if k else got["ms"]
             k += 1
-            out_of_time = time.perf_counter() - t_open >= seconds
-            if tracing == "on" and (out_of_time or time.perf_counter() - t_trace
-                                    >= generated["trace_min_seconds"]):
+            if tracing == "on" and time.perf_counter() - t_trace >= generated[
+                    "trace_min_seconds"]:
                 traced_s = time.perf_counter() - t_trace
                 jax.profiler.stop_trace()
                 tracing = "done"
+        if tracing == "on":
+            traced_s = time.perf_counter() - t_trace
+            jax.profiler.stop_trace()
+            tracing = "done"
         window_s = time.perf_counter() - t_open
         counters1 = _counters(cl)
         span_list = prog_spans.spans_snapshot() if trace else []
@@ -264,8 +273,8 @@ def _serve_and_measure(conn, workload, cell, config, seed, seconds, trace,
         by_template.setdefault(r["template"], []).append(r["ms"])
     say(phase="window", window_s=window_s, whole_cycles=len(cycles),
         requests=len(requests),
-        cycle_ms_median=(sorted(c["ms"] for c in cycles)[len(cycles) // 2]
-                         if cycles else None),
+        seconds=seconds, cycle_ms_median=(
+            sorted(c["ms"] for c in cycles)[len(cycles) // 2] if cycles else None),
         cycle_ms_all=[c["ms"] for c in cycles][:64],
         latency_ms_count_min_p25_p50_p75_p95_max={
             name: [len(v)] + np.percentile(v, [0, 25, 50, 75, 95, 100]).tolist()

@@ -4,8 +4,8 @@
    dropped row, an altered value and a non-200.
 2. The trace reduction gives the known busy, window and per-op totals on the
    small recorded trace kept under ``benchmark/data``.
-3. Every file ``BENCHMARK.json`` names exists, every name and unit uses only
-   the allowed characters, and the declaration files agree with it.
+3. Every file ``BENCHMARK.json`` names exists and every name and unit uses
+   only the allowed characters.
 """
 
 import json
@@ -22,7 +22,7 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 def check_comparison(problems):
     from benchmark.reference.sparql_subset import Reference
 
-    config = files.read_json("configs", "lubm-mini-200.json")
+    config = files.read_json("configs", "lubm-5.json")
     data = files.load_module("generators", config["generator"]).generate(
         config, seed=5, scale=1)
     ref = Reference(data["terms"], data["s"], data["p"], data["o"])
@@ -87,20 +87,12 @@ def check_files(problems):
         if need("configs", c["name"] + ".json"):
             conf = files.read_json("configs", c["name"] + ".json")
             need("generators", conf["generator"] + ".py")
-            if c["file"] != f"benchmark/configs/{c['name']}.json":
-                problems.append(f"config {c['name']}: file is {c['file']}")
-            if sorted(conf["reduced"]) != sorted(c["reduced"]):
-                problems.append(f"config {c['name']}: reduced differs from its file")
     cells = set()
     for w in bench["workloads"]:
         cells.add(w["name"])
         for key in ("name", "config", "traffic"):
             name_ok("workload " + key, w[key])
-        if need("workloads", w["name"] + ".json"):
-            cell = files.read_json("workloads", w["name"] + ".json")
-            for key in ("config", "traffic", "chips"):
-                if cell[key] != w[key]:
-                    problems.append(f"cell {w['name']}: {key} differs from its file")
+        need("workloads", w["name"] + ".json")
         if need("traffic", w["traffic"] + ".json"):
             for step in files.read_json("traffic", w["traffic"] + ".json")["cycle"]:
                 need("templates", step["template"] + ".rq")
@@ -122,9 +114,6 @@ def check_files(problems):
         if need("layer_metrics", m["name"] + ".json"):
             decl = files.read_json("layer_metrics", m["name"] + ".json")
             need("readers", decl["reader"]["kind"] + ".py")
-            for key in ("name", "unit", "better", "source", "layer", "moves"):
-                if decl[key] != m[key]:
-                    problems.append(f"metric {m['name']}: {key} differs from its file")
     peaks = files.read_json("data", "peaks.json")
     if not peaks["peaks"] or not peaks["source"]:
         problems.append("peaks table is empty or names no source")

@@ -3,9 +3,9 @@
 It imports nothing of the program and takes nothing the program has made: it
 reads the generator's id columns and term table and answers the subset of
 SPARQL the benchmark's templates use -- ``PREFIX``, ``SELECT`` of variables
-and ``(COUNT(?x) AS ?n)``, a basic graph pattern, ``FILTER(?v <op> term)``
-with ``= != < <= > >=`` and ``GROUP BY`` -- by sort-merge joins of whole
-columns.  Anything else raises, so a template outside the subset cannot pass
+and ``(COUNT(?x) AS ?n)``, a basic graph pattern, a nested ``{ SELECT ... }``
+of variables, ``FILTER(?v <op> term)`` with ``= != < <= > >=`` and ``GROUP
+BY`` -- by sort-merge joins of whole columns.  Anything else raises, so a template outside the subset cannot pass
 unnoticed.  Rows come back as the server renders them: an IRI without its
 angle brackets, a plain literal without its quotes, a count as a decimal
 string.  Numeric comparison reads a plain literal's text as a number, as the
@@ -76,6 +76,14 @@ class _Parser:
             self.take()
             pre = self.take("pname").rstrip(":")
             self.prefixes[pre] = self.take("iri")[1:-1]
+        query = self.select_query()
+        if self.peek()[1] is not None:
+            raise ValueError(f"reference: unsupported clause at {self.peek()[1]!r}")
+        return query
+
+    def select_query(self):
+        """``(select, patterns, filters, nested, group)``; ``nested`` holds
+        the sub-SELECTs of the group, each such a tuple itself."""
         self.take("word", "SELECT")
         select = []  # ("var", name) | ("count", var, alias)
         while self.peek()[1].upper() != "WHERE":
@@ -92,9 +100,13 @@ class _Parser:
                 select.append(("var", self.take("var")))
         self.take("word", "WHERE")
         self.take("punct", "{")
-        patterns, filters = [], []
+        patterns, filters, nested = [], [], []
         while self.peek() != ("punct", "}"):
-            if self.peek()[0] == "word":
+            if self.peek() == ("punct", "{"):
+                self.take()
+                nested.append(self.select_query())
+                self.take("punct", "}")
+            elif self.peek()[0] == "word":
                 self.take("word", "FILTER")
                 self.take("punct", "(")
                 left, op, right = self.term(), self.take("punct"), self.term()
@@ -106,14 +118,12 @@ class _Parser:
                 self.take()
         self.take("punct", "}")
         group = []
-        if self.peek()[1] is not None:
+        if self.peek()[1] is not None and self.peek()[1].upper() == "GROUP":
             self.take("word", "GROUP")
             self.take("word", "BY")
             while self.peek()[0] == "var":
                 group.append(self.take("var"))
-        if self.peek()[1] is not None:
-            raise ValueError(f"reference: unsupported clause at {self.peek()[1]!r}")
-        return select, patterns, filters, group
+        return select, patterns, filters, nested, group
 
 
 def render(term: str) -> str:
@@ -216,37 +226,52 @@ class Reference:
             return {"=": a == b, "!=": a != b, "<": a < b, "<=": a <= b,
                     ">": a > b, ">=": a >= b}[op]
 
-    def query(self, sparql: str):
-        """The answer as a list of rows of strings (a multiset: order free)."""
-        select, patterns, filters, group = _Parser(sparql).parse()
-        scans = [self._scan(pt) for pt in patterns]
-        todo = sorted(range(len(scans)),
-                      key=lambda i: len(next(iter(scans[i].values()), ())))
-        table = scans[todo.pop(0)]
+    def _solutions(self, query):
+        """Id columns of a (sub-)SELECT's solutions, one per selected name."""
+        select, patterns, filters, nested, group = query
+        # a sub-SELECT joins on the variables it projects; the others are
+        # its own
+        tables = [self._scan(pt) for pt in patterns]
+        tables += [self._solutions(sub) for sub in nested]
+        todo = sorted(range(len(tables)),
+                      key=lambda i: len(next(iter(tables[i].values()), ())))
+        table = tables[todo.pop(0)]
         while todo:
-            # a connected pattern next, the smallest first; a cross product
+            # a connected table next, the smallest first; a cross product
             # only where nothing connects
-            nxt = next((i for i in todo if set(scans[i]) & set(table)), todo[0])
+            nxt = next((i for i in todo if set(tables[i]) & set(table)), todo[0])
             todo.remove(nxt)
-            table = self._join(table, scans[nxt])
+            table = self._join(table, tables[nxt])
         for flt in filters:
             keep = self._compare(table, flt)
             table = {v: col[keep] for v, col in table.items()}
-        if self._rendered is None:
-            self._rendered = np.array([render(t) for t in self.terms], object)
         if any(item[0] == "count" for item in select):
             key = self._key(table, group)
             _, first, counts = np.unique(key, return_index=True, return_counts=True)
-            cols = []
+            out = {}
             for item in select:
                 if item[0] == "var":
                     if item[1] not in group:
                         raise ValueError("reference: selected variable not grouped")
-                    cols.append(self._rendered[table[item[1]][first]])
+                    out[item[1]] = table[item[1]][first]
                 else:
-                    cols.append(np.array([str(c) for c in counts], object))
-        elif group:
+                    out[item[2]] = ("count", counts)
+            return out
+        if group:
             raise ValueError("reference: GROUP BY without an aggregate")
-        else:
-            cols = [self._rendered[table[item[1]]] for item in select]
+        return {item[1]: table[item[1]] for item in select}
+
+    def query(self, sparql: str):
+        """The answer as a list of rows of strings (a multiset: order free)."""
+        query = _Parser(sparql).parse()
+        table = self._solutions(query)
+        if self._rendered is None:
+            self._rendered = np.array([render(t) for t in self.terms], object)
+        cols = []
+        for item in query[0]:
+            col = table[item[-1]]
+            if isinstance(col, tuple):  # a count
+                cols.append(np.array([str(c) for c in col[1]], object))
+            else:
+                cols.append(self._rendered[col])
         return [list(r) for r in zip(*(c.tolist() for c in cols))]

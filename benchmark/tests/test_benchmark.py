@@ -27,13 +27,14 @@ from benchmark.harness import data as files  # noqa: E402
 from benchmark.harness.traffic import Traffic  # noqa: E402
 from benchmark.reference.sparql_subset import Reference  # noqa: E402
 
-CELLS = {"lubm200.triangles": 2, "lubm200.lookups": 2, "employee100k.join": 25000}
+CELLS = {"lubm5.triangles": 1, "lubm5.lookups": 1, "employee100k.upstream": 25000}
 CHIP_LOOK = frozenset({"platform_is_tpu", "pallas_enabled_not_interpreted",
                        "scale_as_configured"})
 
 
 def _cell(workload, seed, scale):
-    cell = files.read_json("workloads", workload + ".json")
+    bench = files.read_json(os.pardir, "BENCHMARK.json")
+    cell = next(w for w in bench["workloads"] if w["name"] == workload)
     config = files.read_json("configs", cell["config"] + ".json")
     data = files.load_module("generators", config["generator"]).generate(
         config, seed, scale)
@@ -69,20 +70,32 @@ def test_control_comes_out_not_correct(workload, seed):
     assert not out["control"]["control_correct"]
 
 
-def test_lubm_seed_0_is_the_repos_generator():
-    pytest.importorskip("benches.lubm")
-    from benches import lubm
-    from kolibrie_tpu.core.dictionary import Dictionary
+def test_lubm_generator_keeps_ubas_shape():
+    """Counts per department inside UBA's ranges, data changing with the
+    seed, about 10^5 asserted triples a university."""
+    _, data, _ = _cell("lubm5.triangles", 2**31 + 1, 1)
+    terms = np.array(data["terms"], object)
+    ub = "<http://swat.cse.lehigh.edu/onto/univ-bench.owl#%s>"
+    p_type = data["terms"].index(
+        "<http://www.w3.org/1999/02/22-rdf-syntax-ns#type>")
 
-    d = Dictionary()
-    s, p, o = lubm.generate_fast(3, d)
-    theirs = sorted(zip((d.id_to_str[i] for i in s), (d.id_to_str[i] for i in p),
-                        (d.id_to_str[i] for i in o)))
-    _, data, _ = _cell("lubm200.triangles", 0, 3)
-    t = np.array([x[1:-1] for x in data["terms"]], object)
-    assert sorted(zip(t[data["s"]], t[data["p"]], t[data["o"]])) == theirs
-    _, other, _ = _cell("lubm200.triangles", 1, 3)
-    assert (other["o"] != data["o"]).any()
+    def count(cls):
+        return int(((data["p"] == p_type)
+                    & (data["o"] == data["terms"].index(ub % cls))).sum())
+
+    depts, faculty = count("Department"), count("Faculty")
+    assert 15 <= depts <= 25
+    assert 30 * depts <= faculty <= 42 * depts
+    assert 8 * faculty <= count("UndergraduateStudent") <= 14 * faculty
+    assert 3 * faculty <= count("GraduateStudent") <= 4 * faculty
+    assert count("Student") == count("UndergraduateStudent") + count("GraduateStudent")
+    closure = sum(count(c) for c in ("Professor", "Faculty", "Student", "Person"))
+    closure += count("GraduateCourse")
+    assert 5500 * depts <= len(data["s"]) - closure <= 8500 * depts
+    assert len(set(zip(data["s"], data["p"], data["o"]))) == len(data["s"])
+    _, other, _ = _cell("lubm5.triangles", 2**31 + 2, 1)
+    assert len(other["s"]) != len(data["s"]) or (other["o"] != data["o"]).any()
+    assert terms[data["s"]].tolist()  # every id has a term
 
 
 @pytest.mark.parametrize("workload", sorted(CELLS))
