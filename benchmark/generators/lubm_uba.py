@@ -15,7 +15,10 @@ as literals.  About 10^5 triples a university, as UBA's (LUBM(1,0): 103,397).
 
 Not UBA's stream of random numbers (that is Java's), so LUBM(N, seed) here is
 the same distribution and not the same file; courses are numbered in the order
-of their teachers (UBA draws their numbers from a pool of 100).  What a store
+of their teachers (UBA draws their numbers from a pool of 100); the
+universities' department counts are the N evenly spaced values of 15-25 in an
+order drawn from the seed (UBA draws each at random), so that every seed's
+data set is of one size.  What a store
 without a reasoner needs is materialised, as the configuration's ``assumed``
 says: ``rdf:type`` up univ-bench's class hierarchy to Professor, Faculty,
 Student, Course and Person.
@@ -127,13 +130,18 @@ def generate(config: dict, seed: int, scale=None) -> dict:
         st.add(who, pred["telephone"], telephone)
         return who
 
+    # 15-25 departments a university, as UBA's, but stratified: every seed
+    # gets the same department counts in another order, so that the size of
+    # the data set -- the work of a run -- does not change with the seed
+    n_depts = rng.permutation(
+        15 + ((np.arange(universities) + 0.5) * 11 / universities).astype(int))
     univ_iri, dept_iri = [], []
     for u in range(universities):
         univ_iri.append(f"http://www.University{u}.edu")
         univ = degree_univ[u]
         degree_seen[u] = True
         st.add([univ], pred["name"], st.literals([f"University{u}"]))
-        for d in range(int(rng.integers(15, 26))):
+        for d in range(int(n_depts[u])):
             host = f"Department{d}.University{u}.edu"
             base = "http://www." + host
             dept_iri.append(base)

@@ -2,8 +2,8 @@
 
 A client in the server's process shares its interpreter lock: after a large
 response the server thread's clean-up delayed the client's wake-up by a
-switch interval, and the full join's latency came out in two modes 9 ms apart
-(my chip runs, PR 24).  A deployment's clients are other processes, so this
+switch interval, and the full join's latency spread 9.5 % (my chip run 3, PR
+24).  A deployment's clients are other processes, so this
 one is too.  It never imports JAX or the program: it generates the data from
 the seed, loads it through ``POST /store/load``, sends cycles on command,
 keeps the window's response bodies, and after the window compares them with

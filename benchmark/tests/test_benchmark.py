@@ -8,7 +8,9 @@ not part of the repo's tier-1 suite (``tests/``).
   acknowledged triples -- comes out not correct, on several seeds;
 * a sound run, with the look for a chip waived, is ``correct``; the same run
   with the timed path's answer altered where the client receives it is not;
-* off the chip, the command prints no result and exits non-zero.
+* off the chip, the command prints no result and exits non-zero;
+* the generator keeps UBA's shape, the traffic walks each domain in a
+  shuffled order, and ``cycle_ms`` holds the time between cycles.
 """
 
 import os
@@ -22,7 +24,7 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, REPO)
 
-from benchmark.harness import compare, loadgen, runner  # noqa: E402
+from benchmark.harness import compare, e2e, loadgen, runner  # noqa: E402
 from benchmark.harness import data as files  # noqa: E402
 from benchmark.harness.traffic import Traffic  # noqa: E402
 from benchmark.reference.sparql_subset import Reference  # noqa: E402
@@ -96,6 +98,27 @@ def test_lubm_generator_keeps_ubas_shape():
     _, other, _ = _cell("lubm5.triangles", 2**31 + 2, 1)
     assert len(other["s"]) != len(data["s"]) or (other["o"] != data["o"]).any()
     assert terms[data["s"]].tolist()  # every id has a term
+
+
+def test_traffic_walks_each_domain_in_a_shuffled_order():
+    domains = {"department": [f"d{i}" for i in range(7)],
+               "university": [f"u{i}" for i in range(5)]}
+    a, b = Traffic("lookups", domains, 2**31 + 3), Traffic("lookups", domains, 4)
+    for stream in ("warmup", "window"):
+        for step, domain in ((0, "department"), (4, "university")):
+            n = len(domains[domain])
+            texts = [a.cycle(k, stream)[step][1] for k in range(2 * n)]
+            # every value once before any comes twice, in both halves
+            assert len(set(texts[:n])) == n and len(set(texts[n:])) == n
+    assert a.cycle(3) == Traffic("lookups", domains, 2**31 + 3).cycle(3)
+    assert [a.cycle(k) for k in range(5)] != [b.cycle(k) for k in range(5)]
+    assert a.cycle(0, "warmup") != a.cycle(0, "window") or a.cycle(1, "warmup") != a.cycle(1)
+
+
+def test_cycle_ms_holds_the_time_between_cycles():
+    cycles = [{"t0": 10.0, "t1": 10.4, "ms": 400.0}, {"t0": 10.6, "t1": 11.0, "ms": 400.0}]
+    assert e2e.cycle_ms({"cycles": cycles}) == pytest.approx(500.0)
+    assert e2e.cycle_ms({"cycles": []}) is None
 
 
 @pytest.mark.parametrize("workload", sorted(CELLS))
