@@ -61,10 +61,10 @@ def main(conn, config, traffic_name, seed, scale, tamper):
                 conn.send({"acknowledged": body["triples"],
                            "seconds": time.perf_counter() - t0})
             elif cmd == "cycle":
-                k, stream, with_ids = args
+                k, stream = args
                 sent, t0 = [], time.perf_counter()
                 for i, (template, text) in enumerate(traffic.cycle(k, stream)):
-                    trace_id = f"bench-{k}-{i}" if with_ids else ""
+                    trace_id = f"bench-{stream}-{k}-{i}"
                     wall = time.time()
                     status, body, ms = cl.query(STORE_ID, text, trace_id)
                     meta = {"cycle": k, "template": template, "status": status,

@@ -5,6 +5,7 @@ thread; the client is this thread.  Everything that belongs to one cell is
 found by name under ``benchmark/`` (see README.md).
 """
 
+import gc
 import json
 import multiprocessing
 import os
@@ -161,7 +162,7 @@ def _serve_and_measure(conn, bench, cell, config, seed, seconds, trace,
         # says, so the cap advisor's re-runs and every compile are over
         t0, got = time.perf_counter(), {"ms": 0.0}
         for k in range(generated["warmup_cycles"]):
-            got = ask("cycle", k, "warmup", False)
+            got = ask("cycle", k, "warmup")
             say(phase="warmup", cycle=k, ms=got["ms"],
                 statuses=sorted({r["status"] for r in got["requests"]}))
         phases["warmup"] = time.perf_counter() - t0
@@ -183,6 +184,7 @@ def _serve_and_measure(conn, bench, cell, config, seed, seconds, trace,
         # trace from the window's second cycle, or from its first where the
         # window holds no second one
         first_traced = 1 if 2 * longest_ms < seconds * 1000.0 else 0
+        gc_runs = [s["collections"] for s in gc.get_stats()]
         setup_s = time.perf_counter() - t_start
         t_open = time.perf_counter()
         k = 0
@@ -197,9 +199,9 @@ def _serve_and_measure(conn, bench, cell, config, seed, seconds, trace,
                 # and the trace's clock
                 anchors.append(time.time())
                 with jax.profiler.TraceAnnotation(xplane.ANNOTATION):
-                    got = ask("cycle", k, "window", True)
+                    got = ask("cycle", k, "window")
             else:
-                got = ask("cycle", k, "window", trace)
+                got = ask("cycle", k, "window")
             requests += got["requests"]
             cycles.append({"k": k, "ms": got["ms"], "t0": got["t0"], "t1": got["t1"],
                            "trace_ids": [r["trace_id"] for r in got["requests"]]})
@@ -216,7 +218,11 @@ def _serve_and_measure(conn, bench, cell, config, seed, seconds, trace,
             tracing = "done"
         window_s = time.perf_counter() - t_open
         counters1 = _counters(cl)
-        span_list = prog_spans.spans_snapshot() if trace else []
+        # every request carries a trace id, so the program's ring holds the
+        # spans of the window's last requests in any run (all of them in a
+        # traced run, whose ring is large)
+        span_list = prog_spans.spans_snapshot()
+        gc_runs = [s["collections"] - n for s, n in zip(gc.get_stats(), gc_runs)]
         stats = cl.get_json("/stats")["stores"][loadgen.STORE_ID]
         mem = [d.memory_stats() or {} for d in jax.local_devices()]
         device["memory_peak_bytes"] = max(
@@ -282,6 +288,16 @@ def _serve_and_measure(conn, bench, cell, config, seed, seconds, trace,
         phases=phases, reference_s=reference_s,
         compile_counters={k: v for k, v in counters1.items()
                           if k.startswith("compile")})
+
+    # where a far-off request spent its time: the spans of the window's
+    # slowest request, where the ring still holds them
+    slowest = max(requests, key=lambda r: r["ms"])
+    say(phase="slowest_request", template=slowest["template"], cycle=slowest["cycle"],
+        ms=slowest["ms"], cap_retries_in_window=delta(
+            'kolibrie_cap_retries_total{engine="device"}'),
+        server_gc_collections_in_window=gc_runs,
+        spans=[[sp["name"], sp["dur_ms"]] for sp in span_list
+               if sp["trace_id"] == slowest["trace_id"]])
 
     units = {m["name"]: m["unit"] for m in bench["end_to_end"] + bench["per_layer"]}
     metrics, breakdown = {}, None
