@@ -33,6 +33,7 @@ small mutations.
 from __future__ import annotations
 
 import itertools
+import time
 from typing import Iterator, Optional, Tuple
 
 import numpy as np
@@ -64,9 +65,43 @@ try:  # obs is stdlib-only and imports nothing from the engine (no cycle)
         "kolibrie_store_delta_rows",
         "Current delta occupancy (add rows + tombstones vs the base segment).",
     )
+    # Where a start's seconds go, counted where the work happens.  Every
+    # label child exists from import, so a start that did none of it reads 0.
+    _H2D_SECONDS = _obs_counter(
+        "kolibrie_store_h2d_seconds_total",
+        "Wall seconds of the store's host->device segment uploads, to the "
+        "transfer's completion, by segment kind.",
+        labels=("segment",),
+    )
+    _ORDER_BUILD_SECONDS = _obs_counter(
+        "kolibrie_store_order_build_seconds_total",
+        "Wall seconds of host sorts building a sort order, by order.",
+        labels=("order",),
+    )
+    LOAD_SECONDS = _obs_counter(
+        "kolibrie_store_load_seconds_total",
+        "Wall seconds of ingest: text to ids (phase=parse, counted by the "
+        "/store/load handler) and folding pending rows into the sorted "
+        "columns (phase=compact).",
+        labels=("phase",),
+    )
+    for _seg in ("base", "delta"):
+        _H2D_SECONDS.labels(_seg)
+    for _name in ("spo", "pos", "osp", "pso", "ops", "sop"):
+        _ORDER_BUILD_SECONDS.labels(_name)
+    for _phase in ("parse", "compact"):
+        LOAD_SECONDS.labels(_phase)
 # kolint: ignore[KL601] import-time obs registration must never block the store; the None sentinels disable instrumentation and every call site guards on them
 except Exception:  # pragma: no cover
     _H2D_BYTES = _DELTA_MERGES = _ORDER_REBUILDS = _DELTA_ROWS = None
+    _H2D_SECONDS = _ORDER_BUILD_SECONDS = LOAD_SECONDS = None
+
+
+def h2d_bytes_total() -> float:
+    """Bytes the store has uploaded so far, every segment kind together."""
+    if _H2D_BYTES is None:
+        return 0.0
+    return sum(child.value for _labels, child in _H2D_BYTES.children())
 
 
 def _lex_sort_rows(s: np.ndarray, p: np.ndarray, o: np.ndarray):
@@ -446,6 +481,12 @@ class ColumnarTripleStore:
     def compact(self) -> None:
         if not self._pending_add and not self._pending_del:
             return
+        t0 = time.perf_counter()
+        self._compact_pending()
+        if LOAD_SECONDS is not None:
+            LOAD_SECONDS.labels("compact").inc(time.perf_counter() - t0)
+
+    def _compact_pending(self) -> None:
         parts_s = []
         parts_p = []
         parts_o = []
@@ -736,15 +777,24 @@ class ColumnarTripleStore:
                 _H2D_BYTES.labels("order").inc(3 * cap * 4)
         return cached
 
+    def _sort_order(self, name: str, s, p, o) -> SortedOrder:
+        """Build order ``name`` over the given columns (a host sort unless
+        ``spo``, which the canonical columns already are)."""
+        t0 = time.perf_counter()
+        so = SortedOrder(
+            self._ORDER_PERMS[name],
+            {"s": s, "p": p, "o": o},
+            presorted=(name == "spo"),
+        )
+        if _ORDER_BUILD_SECONDS is not None:
+            _ORDER_BUILD_SECONDS.labels(name).inc(time.perf_counter() - t0)
+        return so
+
     def order(self, name: str) -> SortedOrder:
         self.compact()
         so = self._orders.get(name)
         if so is None:
-            so = SortedOrder(
-                self._ORDER_PERMS[name],
-                {"s": self._s, "p": self._p, "o": self._o},
-                presorted=(name == "spo"),
-            )
+            so = self._sort_order(name, self._s, self._p, self._o)
             self._orders[name] = so
         return so
 
@@ -761,10 +811,8 @@ class ColumnarTripleStore:
             if not self._delta_add_set and not self._delta_del_set:
                 so = self.order(name)  # base == live: share the object
             else:
-                so = SortedOrder(
-                    self._ORDER_PERMS[name],
-                    {"s": self._base_s, "p": self._base_p, "o": self._base_o},
-                    presorted=(name == "spo"),
+                so = self._sort_order(
+                    name, self._base_s, self._base_p, self._base_o
                 )
             self._base_orders[name] = so
         return so
@@ -877,13 +925,21 @@ class ColumnarTripleStore:
 
             canon = {bo.perm[0]: bo.c0, bo.perm[1]: bo.c1, bo.perm[2]: bo.c2}
             # One batched transfer: device_put on a list issues a single
-            # host->device round trip instead of three.
+            # host->device round trip instead of three.  Waited for here (a
+            # base uploads once per merge), so the seconds are the
+            # transfer's and not the next program's.
+            t0 = time.perf_counter()
             base = tuple(
-                jax.device_put([host(canon["s"]), host(canon["p"]), host(canon["o"])])
+                jax.block_until_ready(
+                    jax.device_put(
+                        [host(canon["s"]), host(canon["p"]), host(canon["o"])]
+                    )
+                )
             )
             self._device_segments[name] = base
             if _H2D_BYTES is not None:
                 _H2D_BYTES.labels("base").inc(3 * cap * 4)
+                _H2D_SECONDS.labels("base").inc(time.perf_counter() - t0)
         delta = self._device_delta.get(name)
         if delta is None:
             import jax
@@ -897,18 +953,19 @@ class ColumnarTripleStore:
 
             do_ = self.delta_order(name)
             canon = {do_.perm[0]: do_.c0, do_.perm[1]: do_.c1, do_.perm[2]: do_.c2}
-            ds, dp, do2, dl = jax.device_put(
-                [
-                    host(canon["s"]),
-                    host(canon["p"]),
-                    host(canon["o"]),
-                    host(self.delta_del_positions(name)),
-                ]
-            )
+            cols = [
+                host(canon["s"]),
+                host(canon["p"]),
+                host(canon["o"]),
+                host(self.delta_del_positions(name)),
+            ]
+            t0 = time.perf_counter()
+            ds, dp, do2, dl = jax.block_until_ready(jax.device_put(cols))
             delta = ((ds, dp, do2), dl)
             self._device_delta[name] = delta
             if _H2D_BYTES is not None:
                 _H2D_BYTES.labels("delta").inc(4 * dcap * 4)
+                _H2D_SECONDS.labels("delta").inc(time.perf_counter() - t0)
         return base, delta[0], delta[1]
 
     def contains(self, s: int, p: int, o: int) -> bool:

@@ -1154,9 +1154,11 @@ class KolibrieHandler(BaseHTTPRequestHandler):
         /query (fresh database per request), the store survives across
         requests so repeat queries hit the warm plan-template cache and
         concurrent same-template queries micro-batch."""
+        from kolibrie_tpu.core.store import LOAD_SECONDS
         from kolibrie_tpu.query.sparql_database import SparqlDatabase
 
-        req = self._read_json()
+        with span("http.read_body"):
+            req = self._read_json()
         state = self.state
         sid = str(req.get("store_id") or "")
         with state.lock:
@@ -1176,9 +1178,12 @@ class KolibrieHandler(BaseHTTPRequestHandler):
             with batcher.dispatch_lock:
                 if req.get("mode"):
                     batcher.db.execution_mode = req["mode"]
+                t0 = time.perf_counter()
                 n = _load_rdf_into(
                     batcher.db, req.get("rdf") or "", req.get("format", "ntriples")
                 )
+                if LOAD_SECONDS is not None:
+                    LOAD_SECONDS.labels("parse").inc(time.perf_counter() - t0)
                 # eager mirror upload while we already hold the lock: the
                 # first query after a load pays dispatch, not partitioning
                 _maybe_attach_sharded(batcher.db)
@@ -1196,7 +1201,8 @@ class KolibrieHandler(BaseHTTPRequestHandler):
             # replication/primary.py)
             seg, off = state.durability.wal.position()
             body["watermark"] = {"segment": seg, "offset": off}
-        self._send_json(body)
+        with span("http.respond"):
+            self._send_json(body)
 
     def _handle_store_query(self):
         """Query a persistent store through the template batcher:
@@ -1210,7 +1216,8 @@ class KolibrieHandler(BaseHTTPRequestHandler):
         per-operator records (device / interp / sharded)."""
         from urllib.parse import parse_qs
 
-        req = self._read_json()
+        with span("http.read_body"):
+            req = self._read_json()
         if not req.get("sparql"):
             raise BadRequest("No query provided")
         explain = (
@@ -1255,7 +1262,8 @@ class KolibrieHandler(BaseHTTPRequestHandler):
         }
         if analysis is not None:
             body["explain"] = analysis
-        self._send_json(body)
+        with span("http.respond"):
+            self._send_json(body)
 
     def _handle_stats(self):
         """Serving metrics per store: request/dedup/batch counters, per-

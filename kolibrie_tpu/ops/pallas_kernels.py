@@ -851,11 +851,12 @@ def lex_probe_select(kk, ch, in_range, accessors):
     ops = [kk, ch, in_range]
     for t in accessors:
         ops.extend(t)
-    val, ok, isb = _lex_probe_call(
-        _lex_probe_select_kernel(len(accessors)), ops, kk.shape[0], 3
-    )
-    val = lax.bitcast_convert_type(val, jnp.uint32)
-    return val, ok != 0, isb != 0
+    with jax.named_scope("lex_probe_select"):
+        val, ok, isb = _lex_probe_call(
+            _lex_probe_select_kernel(len(accessors)), ops, kk.shape[0], 3
+        )
+        val = lax.bitcast_convert_type(val, jnp.uint32)
+        return val, ok != 0, isb != 0
 
 
 def lex_probe_validate(ok, is_base, ch, accessors):
@@ -867,10 +868,11 @@ def lex_probe_validate(ok, is_base, ch, accessors):
     ops = [ok, is_base, ch]
     for t in accessors:
         ops.extend(t)
-    (v,) = _lex_probe_call(
-        _lex_probe_validate_kernel(len(accessors)), ops, ok.shape[0], 1
-    )
-    return v != 0
+    with jax.named_scope("lex_probe_validate"):
+        (v,) = _lex_probe_call(
+            _lex_probe_validate_kernel(len(accessors)), ops, ok.shape[0], 1
+        )
+        return v != 0
 
 
 # ---------------------------------------------------------------------------
@@ -970,17 +972,18 @@ def _filter_mask_jit(consts, s, p, o) -> jnp.ndarray:
         return x.reshape(rows, TILE)
 
     block = pl.BlockSpec((_CHUNK_ROWS, TILE), lambda i, *_: (i, 0))
-    mask2d = _pallas_call(
-        _filter_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(n_chunks,),
-            in_specs=[block] * 3,
-            out_specs=block,
-        ),
-        out_shape=jax.ShapeDtypeStruct((rows, TILE), jnp.bool_),
-        interpret=_interpret(),
-    )(consts, shape2d(s), shape2d(p), shape2d(o))
+    with jax.named_scope("filter"):
+        mask2d = _pallas_call(
+            _filter_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(n_chunks,),
+                in_specs=[block] * 3,
+                out_specs=block,
+            ),
+            out_shape=jax.ShapeDtypeStruct((rows, TILE), jnp.bool_),
+            interpret=_interpret(),
+        )(consts, shape2d(s), shape2d(p), shape2d(o))
     return mask2d.reshape(-1)[:n]
 
 

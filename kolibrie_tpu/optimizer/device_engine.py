@@ -383,6 +383,46 @@ def fetch_counters() -> Dict[str, int]:
     return dict(_FETCHES)
 
 
+# The children of ``device.dispatch``, shared by the solo and the stacked
+# path so both split the same way (docs/OBSERVABILITY.md "Span taxonomy").
+
+
+def _build_traced(lowered, tag: int):
+    """``lowered.build(tag)`` under ``device.build``; ``h2d_bytes`` where the
+    store uploaded a segment (an order's first use after a base merge)."""
+    from kolibrie_tpu.core.store import h2d_bytes_total
+
+    with _obs_span("device.build") as sp:
+        before = h2d_bytes_total() if sp is not None else 0
+        built = lowered.build(tag)
+        if sp is not None and h2d_bytes_total() > before:
+            sp.attrs["h2d_bytes"] = int(h2d_bytes_total() - before)
+    return built
+
+
+def _enqueue_traced(entry, *args):
+    """Call a jit entry point under ``device.enqueue``: Python dispatch and
+    argument handling until the call returns (asynchronously), plus the trace
+    and the compile or cache load where the shape is new (``compiled=1``)."""
+    with _obs_span("device.enqueue") as sp:
+        before = _jit_entries(entry) if sp is not None else 0
+        out = entry(*args)
+        if sp is not None:
+            sp.attrs["compiled"] = int(_jit_entries(entry) > before)
+    return out
+
+
+def _read_counts(out, counts, attempt: int, to_host):
+    """Wait for the program, then read the join counts back.  The wait is its
+    own span so device time and the readback round trips (one a join) read
+    apart; one executable's outputs become ready together, so it adds no
+    synchronization the readback did not already imply."""
+    with _obs_span("device.wait", attempt=attempt):
+        jax.block_until_ready(out)
+    with _obs_span("device.counts", attempt=attempt, n=len(counts)):
+        return [to_host(c) for c in counts]
+
+
 def _pack_key(cols: List, valid, pad_sentinel):
     import jax.numpy as jnp
 
@@ -497,7 +537,37 @@ def _plan_body(
             return m
         raise TypeError(f"unknown filter spec {expr!r}")
 
+    def node_key(node) -> str:
+        """The node's EXPLAIN ANALYZE key, assigned at node entry."""
+        if isinstance(node, ScanSpec):
+            return f"scan{node.scan_idx}"
+        if isinstance(node, ValuesSpec):
+            return f"values{node.values_idx}"
+        if isinstance(node, JoinSpec):
+            return f"join{node.join_idx}"
+        if isinstance(node, LeftOuterSpec):
+            return f"optional{node.join_idx}"
+        if isinstance(node, WcojSpec):
+            return f"wcoj{node.levels[0].join_idx}" if node.levels else "wcoj"
+        for kind, cls in (
+            ("filter", FilterSpec),
+            ("anti", AntiJoinSpec),
+            ("union", UnionSpec),
+            ("quoted", QuotedExpandSpec),
+        ):
+            if isinstance(node, cls):
+                seq[kind] += 1
+                return f"{kind}{seq[kind] - 1}"
+        raise TypeError(f"unknown plan spec node {node!r}")
+
     def eval_node(node):
+        # the key names the node's ops in a device profile too: the scope
+        # path is each op's ``tf_op`` stat (metadata only, the HLO is the same)
+        skey = node_key(node)
+        with jax.named_scope(skey):
+            return eval_op(node, skey)
+
+    def eval_op(node, skey):
         if isinstance(node, ScanSpec):
             # Two-segment scan: a window over the FROZEN base order (with
             # tombstoned rows masked out) merged with a window over the
@@ -568,13 +638,11 @@ def _plan_body(
                 valid = valid & (raw[a] == raw[b])
             cols = {var: raw[pos] for var, pos in node.out_vars}
             n = jnp.sum(valid)
-            stats[f"scan{node.scan_idx}"] = n
+            stats[skey] = n
             return cols, valid, n
         if isinstance(node, QuotedExpandSpec):
             from kolibrie_tpu.core.dictionary import QUOTED_BIT
 
-            skey = f"quoted{seq['quoted']}"
-            seq["quoted"] += 1
             cols, valid, _ = eval_node(node.child)
             qid_sorted, qs, qp, qo = quoted
             qcol = cols.pop(node.qvar)
@@ -598,7 +666,7 @@ def _plan_body(
         if isinstance(node, ValuesSpec):
             cols = {v: values[node.values_idx][i] for i, v in enumerate(node.vars)}
             valid = jnp.ones(node.n, dtype=bool)
-            stats[f"values{node.values_idx}"] = jnp.int32(node.n)
+            stats[skey] = jnp.int32(node.n)
             return cols, valid, jnp.int32(node.n)
         if isinstance(node, JoinSpec):
             from kolibrie_tpu.ops.device_join import join_indices_presorted
@@ -654,7 +722,7 @@ def _plan_body(
                 else:
                     li, ri, valid, total = join_indices(lkey, rkey, node.cap)
             counts.append(total)
-            stats[f"join{node.join_idx}"] = jnp.sum(valid)
+            stats[skey] = jnp.sum(valid)
             out = {}
             for v, c in lcols.items():
                 out[v] = jnp.where(valid, c[li], 0)
@@ -663,8 +731,6 @@ def _plan_body(
                     out[v] = jnp.where(valid, c[ri], 0)
             return out, valid, total
         if isinstance(node, FilterSpec):
-            skey = f"filter{seq['filter']}"
-            seq["filter"] += 1
             cols, valid, _ = eval_node(node.child)
             mask = eval_expr(node.expr, cols, valid)
             valid = valid & mask
@@ -672,8 +738,6 @@ def _plan_body(
             stats[skey] = n
             return cols, valid, n
         if isinstance(node, AntiJoinSpec):
-            skey = f"anti{seq['anti']}"
-            seq["anti"] += 1
             lcols, lvalid, _ = eval_node(node.left)
             rcols, rvalid, _ = eval_node(node.right)
             lc = [lcols[v] for v in node.key_vars]
@@ -692,8 +756,6 @@ def _plan_body(
             stats[skey] = n
             return lcols, valid, n
         if isinstance(node, UnionSpec):
-            skey = f"union{seq['union']}"
-            seq["union"] += 1
             parts = [eval_node(ch) for ch in node.children]
             cols = {}
             for v in node.vars:
@@ -740,7 +802,7 @@ def _plan_body(
                     )
             valid = jnp.concatenate([mvalid, keep])
             n = jnp.sum(valid)
-            stats[f"optional{node.join_idx}"] = n
+            stats[skey] = n
             return out, valid, n
         if isinstance(node, WcojSpec):
             # Variable-at-a-time leapfrog over the two-tier sorted orders.
@@ -760,171 +822,179 @@ def _plan_body(
             SENT = jnp.uint32(0xFFFFFFFF)
             wcols: Dict = {}
             wvalid = jnp.ones(1, dtype=bool)
-            for lv in node.levels:
-                pcap = wvalid.shape[0]
-                segs = [order_arrays[a.order_idx] for a in lv.accessors]
-                probes = []
-                for a, (bcols, dcols, del_pos) in zip(lv.accessors, segs):
-                    keys = []
-                    sent = jnp.zeros(pcap, dtype=bool)
-                    for src in a.key_srcs:
-                        if src[0] == "u":
-                            k = jnp.broadcast_to(uparams[src[1]], (pcap,))
+            def eval_level(lv, wcols, wvalid):
+                with jax.named_scope("probe"):
+                    pcap = wvalid.shape[0]
+                    segs = [order_arrays[a.order_idx] for a in lv.accessors]
+                    probes = []
+                    for a, (bcols, dcols, del_pos) in zip(lv.accessors, segs):
+                        keys = []
+                        sent = jnp.zeros(pcap, dtype=bool)
+                        for src in a.key_srcs:
+                            if src[0] == "u":
+                                k = jnp.broadcast_to(uparams[src[1]], (pcap,))
+                            else:
+                                k = wcols[src[1]]
+                            sent = sent | (k == SENT)
+                            keys.append(k)
+                        if keys:
+                            kt = tuple(keys)
+                            bsort = tuple(bcols[p] for p in a.key_pos)
+                            dsort = tuple(dcols[p] for p in a.key_pos)
+                            # fused lo+hi search: bit-identical to the former
+                            # left/right lex_searchsorted pairs, half the
+                            # gathers (shared by both the XLA and Pallas paths)
+                            bl, bh = lex_range(bsort, kt)
+                            dl, dh = lex_range(dsort, kt)
                         else:
-                            k = wcols[src[1]]
-                        sent = sent | (k == SENT)
-                        keys.append(k)
-                    if keys:
-                        kt = tuple(keys)
-                        bsort = tuple(bcols[p] for p in a.key_pos)
-                        dsort = tuple(dcols[p] for p in a.key_pos)
-                        # fused lo+hi search: bit-identical to the former
-                        # left/right lex_searchsorted pairs, half the
-                        # gathers (shared by both the XLA and Pallas paths)
-                        bl, bh = lex_range(bsort, kt)
-                        dl, dh = lex_range(dsort, kt)
-                    else:
-                        # unbound accessor: the whole live prefix (padding
-                        # is all-sentinel and sorts last; the order was
-                        # picked so the level variable IS the first column)
-                        bl = jnp.zeros(pcap, dtype=jnp.int32)
-                        dl = jnp.zeros(pcap, dtype=jnp.int32)
-                        nb0 = jnp.searchsorted(
-                            bcols[a.val_pos], SENT, side="left"
-                        ).astype(jnp.int32)
-                        nd0 = jnp.searchsorted(
-                            dcols[a.val_pos], SENT, side="left"
-                        ).astype(jnp.int32)
-                        bh = jnp.broadcast_to(nb0, (pcap,))
-                        dh = jnp.broadcast_to(nd0, (pcap,))
-                    probes.append((keys, sent, bl, bh, dl, dh))
-                cntm = jnp.stack(
-                    [
-                        jnp.where(sent, 0, (bh - bl) + (dh - dl))
-                        for (_k, sent, bl, bh, dl, dh) in probes
-                    ]
-                )
-                choice = jnp.argmin(cntm, axis=0)
-                cnt = jnp.where(wvalid, jnp.min(cntm, axis=0), 0)
-                total = jnp.sum(cnt.astype(jnp.int64))
-                counts.append(total)
-                stats[f"wcoj{lv.join_idx}:cand"] = total
-                cap = lv.cap
-                cum = jnp.cumsum(cnt)
-                slot = jnp.arange(cap, dtype=jnp.int32)
-                row = jnp.searchsorted(cum, slot, side="right").astype(
-                    jnp.int32
-                )
-                row_c = jnp.clip(row, 0, pcap - 1)
-                kk = slot - (cum[row_c] - cnt[row_c])
-                in_range = slot.astype(jnp.int64) < total
-                ch = choice[row_c]
-                # per-accessor slot operands (XLA gathers — shared by both
-                # formulations below)
-                sel = []
-                for a, (bcols, dcols, _dp), (keys, sent, bl, bh, dl, dh) in zip(
-                    lv.accessors, segs, probes
-                ):
-                    bv, dv = bcols[a.val_pos], dcols[a.val_pos]
-                    nb = bh[row_c] - bl[row_c]
-                    bidx = jnp.clip(bl[row_c] + kk, 0, bv.shape[0] - 1)
-                    didx = jnp.clip(dl[row_c] + (kk - nb), 0, dv.shape[0] - 1)
-                    bval, dval = bv[bidx], dv[didx]
-                    bprev = bv[jnp.clip(bidx - 1, 0, bv.shape[0] - 1)]
-                    dprev = dv[jnp.clip(didx - 1, 0, dv.shape[0] - 1)]
-                    sel.append((nb, bval, dval, bprev, dprev))
-                if use_pallas:
-                    # fused VPU expansion: merge-by-rank select, dedup and
-                    # accessor choice in one VMEM-resident kernel (bit-
-                    # identical to the XLA branch — see ops/pallas_kernels)
-                    from kolibrie_tpu.ops.pallas_kernels import (
-                        lex_probe_select,
-                        lex_probe_validate,
-                    )
-
-                    val, new_valid, is_base = lex_probe_select(
-                        kk.astype(jnp.int32),
-                        ch.astype(jnp.int32),
-                        in_range,
+                            # unbound accessor: the whole live prefix (padding
+                            # is all-sentinel and sorts last; the order was
+                            # picked so the level variable IS the first column)
+                            bl = jnp.zeros(pcap, dtype=jnp.int32)
+                            dl = jnp.zeros(pcap, dtype=jnp.int32)
+                            nb0 = jnp.searchsorted(
+                                bcols[a.val_pos], SENT, side="left"
+                            ).astype(jnp.int32)
+                            nd0 = jnp.searchsorted(
+                                dcols[a.val_pos], SENT, side="left"
+                            ).astype(jnp.int32)
+                            bh = jnp.broadcast_to(nb0, (pcap,))
+                            dh = jnp.broadcast_to(nd0, (pcap,))
+                        probes.append((keys, sent, bl, bh, dl, dh))
+                    cntm = jnp.stack(
                         [
-                            (nb.astype(jnp.int32), bval, dval, bprev, dprev)
-                            for nb, bval, dval, bprev, dprev in sel
-                        ],
+                            jnp.where(sent, 0, (bh - bl) + (dh - dl))
+                            for (_k, sent, bl, bh, dl, dh) in probes
+                        ]
                     )
-                else:
-                    vals_l, first_l, isb_l = [], [], []
-                    for nb, bval, dval, bprev, dprev in sel:
-                        isb = kk < nb
-                        vals_l.append(jnp.where(isb, bval, dval))
-                        first_l.append(
-                            jnp.where(
-                                isb,
-                                (kk == 0) | (bprev != bval),
-                                (kk == nb) | (dprev != dval),
-                            )
+                    choice = jnp.argmin(cntm, axis=0)
+                    cnt = jnp.where(wvalid, jnp.min(cntm, axis=0), 0)
+                    total = jnp.sum(cnt.astype(jnp.int64))
+                    counts.append(total)
+                    stats[f"wcoj{lv.join_idx}:cand"] = total
+                with jax.named_scope("expand"):
+                    cap = lv.cap
+                    cum = jnp.cumsum(cnt)
+                    slot = jnp.arange(cap, dtype=jnp.int32)
+                    row = jnp.searchsorted(cum, slot, side="right").astype(
+                        jnp.int32
+                    )
+                    row_c = jnp.clip(row, 0, pcap - 1)
+                    kk = slot - (cum[row_c] - cnt[row_c])
+                    in_range = slot.astype(jnp.int64) < total
+                    ch = choice[row_c]
+                    # per-accessor slot operands (XLA gathers — shared by both
+                    # formulations below)
+                    sel = []
+                    for a, (bcols, dcols, _dp), (keys, sent, bl, bh, dl, dh) in zip(
+                        lv.accessors, segs, probes
+                    ):
+                        bv, dv = bcols[a.val_pos], dcols[a.val_pos]
+                        nb = bh[row_c] - bl[row_c]
+                        bidx = jnp.clip(bl[row_c] + kk, 0, bv.shape[0] - 1)
+                        didx = jnp.clip(dl[row_c] + (kk - nb), 0, dv.shape[0] - 1)
+                        bval, dval = bv[bidx], dv[didx]
+                        bprev = bv[jnp.clip(bidx - 1, 0, bv.shape[0] - 1)]
+                        dprev = dv[jnp.clip(didx - 1, 0, dv.shape[0] - 1)]
+                        sel.append((nb, bval, dval, bprev, dprev))
+                with jax.named_scope("dedup"):
+                    if use_pallas:
+                        # fused VPU expansion: merge-by-rank select, dedup and
+                        # accessor choice in one VMEM-resident kernel (bit-
+                        # identical to the XLA branch — see ops/pallas_kernels)
+                        from kolibrie_tpu.ops.pallas_kernels import (
+                            lex_probe_select,
+                            lex_probe_validate,
                         )
-                        isb_l.append(isb)
-                    val = jnp.stack(vals_l)[ch, slot]
-                    first = jnp.stack(first_l)[ch, slot]
-                    is_base = jnp.stack(isb_l)[ch, slot]
-                    new_valid = in_range & (val != SENT) & first
-                # dedup count: distinct candidate values BEFORE the
-                # liveness/base-representative probes (both formulations
-                # agree at this point — lex_probe_select's new_valid is
-                # the same pre-liveness predicate)
-                stats[f"wcoj{lv.join_idx}:dedup"] = jnp.sum(new_valid)
-                ex = []
-                for a, (bcols, dcols, del_pos), (keys, sent, *_r) in zip(
-                    lv.accessors, segs, probes
-                ):
-                    fkeys = tuple(k[row_c] for k in keys) + (val,)
-                    bsf = tuple(bcols[p] for p in a.key_pos) + (
-                        bcols[a.val_pos],
-                    )
-                    dsf = tuple(dcols[p] for p in a.key_pos) + (
-                        dcols[a.val_pos],
-                    )
-                    fl, fh = lex_range(bsf, fkeys)
-                    dl2, dh2 = lex_range(dsf, fkeys)
-                    # tombstoned copies inside [fl, fh): del_pos holds
-                    # sorted base-row positions (sentinel-padded)
-                    tl = jnp.searchsorted(del_pos, fl.astype(jnp.uint32))
-                    th = jnp.searchsorted(del_pos, fh.astype(jnp.uint32))
-                    ex.append((fl, fh, tl, th, dl2, dh2, sent[row_c]))
-                if use_pallas:
-                    new_valid = lex_probe_validate(
-                        new_valid,
-                        is_base,
-                        ch.astype(jnp.int32),
-                        [
-                            (
-                                fl,
-                                fh,
-                                tl.astype(jnp.int32),
-                                th.astype(jnp.int32),
-                                dl2,
-                                dh2,
-                                sent_r,
+
+                        val, new_valid, is_base = lex_probe_select(
+                            kk.astype(jnp.int32),
+                            ch.astype(jnp.int32),
+                            in_range,
+                            [
+                                (nb.astype(jnp.int32), bval, dval, bprev, dprev)
+                                for nb, bval, dval, bprev, dprev in sel
+                            ],
+                        )
+                    else:
+                        vals_l, first_l, isb_l = [], [], []
+                        for nb, bval, dval, bprev, dprev in sel:
+                            isb = kk < nb
+                            vals_l.append(jnp.where(isb, bval, dval))
+                            first_l.append(
+                                jnp.where(
+                                    isb,
+                                    (kk == 0) | (bprev != bval),
+                                    (kk == nb) | (dprev != dval),
+                                )
                             )
-                            for fl, fh, tl, th, dl2, dh2, sent_r in ex
-                        ],
-                    )
-                else:
-                    braw_l = []
-                    for fl, fh, tl, th, dl2, dh2, sent_r in ex:
-                        blive = (fh - fl) - (th - tl).astype(jnp.int32)
-                        live = (blive + (dh2 - dl2)) > 0
-                        new_valid = new_valid & live & ~sent_r
-                        braw_l.append((fh - fl) > 0)
-                    braw = jnp.stack(braw_l)[ch, slot]
-                    new_valid = new_valid & (is_base | ~braw)
-                stats[f"wcoj{lv.join_idx}:live"] = jnp.sum(new_valid)
+                            isb_l.append(isb)
+                        val = jnp.stack(vals_l)[ch, slot]
+                        first = jnp.stack(first_l)[ch, slot]
+                        is_base = jnp.stack(isb_l)[ch, slot]
+                        new_valid = in_range & (val != SENT) & first
+                    # dedup count: distinct candidate values BEFORE the
+                    # liveness/base-representative probes (both formulations
+                    # agree at this point — lex_probe_select's new_valid is
+                    # the same pre-liveness predicate)
+                    stats[f"wcoj{lv.join_idx}:dedup"] = jnp.sum(new_valid)
+                with jax.named_scope("live"):
+                    ex = []
+                    for a, (bcols, dcols, del_pos), (keys, sent, *_r) in zip(
+                        lv.accessors, segs, probes
+                    ):
+                        fkeys = tuple(k[row_c] for k in keys) + (val,)
+                        bsf = tuple(bcols[p] for p in a.key_pos) + (
+                            bcols[a.val_pos],
+                        )
+                        dsf = tuple(dcols[p] for p in a.key_pos) + (
+                            dcols[a.val_pos],
+                        )
+                        fl, fh = lex_range(bsf, fkeys)
+                        dl2, dh2 = lex_range(dsf, fkeys)
+                        # tombstoned copies inside [fl, fh): del_pos holds
+                        # sorted base-row positions (sentinel-padded)
+                        tl = jnp.searchsorted(del_pos, fl.astype(jnp.uint32))
+                        th = jnp.searchsorted(del_pos, fh.astype(jnp.uint32))
+                        ex.append((fl, fh, tl, th, dl2, dh2, sent[row_c]))
+                    if use_pallas:
+                        new_valid = lex_probe_validate(
+                            new_valid,
+                            is_base,
+                            ch.astype(jnp.int32),
+                            [
+                                (
+                                    fl,
+                                    fh,
+                                    tl.astype(jnp.int32),
+                                    th.astype(jnp.int32),
+                                    dl2,
+                                    dh2,
+                                    sent_r,
+                                )
+                                for fl, fh, tl, th, dl2, dh2, sent_r in ex
+                            ],
+                        )
+                    else:
+                        braw_l = []
+                        for fl, fh, tl, th, dl2, dh2, sent_r in ex:
+                            blive = (fh - fl) - (th - tl).astype(jnp.int32)
+                            live = (blive + (dh2 - dl2)) > 0
+                            new_valid = new_valid & live & ~sent_r
+                            braw_l.append((fh - fl) > 0)
+                        braw = jnp.stack(braw_l)[ch, slot]
+                        new_valid = new_valid & (is_base | ~braw)
+                    stats[f"wcoj{lv.join_idx}:live"] = jnp.sum(new_valid)
                 wcols = {
                     v: jnp.where(new_valid, c[row_c], 0)
                     for v, c in wcols.items()
                 }
                 wcols[lv.var] = jnp.where(new_valid, val, 0)
-                wvalid = new_valid
+                return wcols, new_valid
+
+            for level, lv in enumerate(node.levels):
+                with jax.named_scope(f"{skey}.L{level}"):
+                    wcols, wvalid = eval_level(lv, wcols, wvalid)
             return wcols, wvalid, jnp.sum(wvalid)
         raise TypeError(f"unknown plan spec node {node!r}")
 
@@ -975,20 +1045,24 @@ def _run_plan_batch(
     return jax.vmap(one, in_axes=(0, (0, 0)))(scalars_b, params_b)
 
 
+def _jit_entries(fn) -> int:
+    """Size of one jit entry point's cache (a compile or a cache load on a
+    shape's first sight adds an entry)."""
+    try:
+        return int(fn._cache_size())
+    # kolint: ignore[KL601] jax version probe; -1 is the sentinel the stats endpoint documents for "cache API absent"
+    except Exception:
+        return -1
+
+
 def device_compile_stats() -> Dict[str, int]:
     """Per-entry-point jit cache sizes — the compile counter the template
     tests/bench assert on (a recompile ⇒ a new cache entry)."""
-    out = {}
-    for name, fn in (
-        ("run_plan", _run_plan),
-        ("run_plan_k", _run_plan_k),
-        ("run_plan_batch", _run_plan_batch),
-    ):
-        try:
-            out[name] = int(fn._cache_size())
-        # kolint: ignore[KL601] jax version probe; -1 is the sentinel the stats endpoint documents for "cache API absent"
-        except Exception:
-            out[name] = -1
+    out = {
+        "run_plan": _jit_entries(_run_plan),
+        "run_plan_k": _jit_entries(_run_plan_k),
+        "run_plan_batch": _jit_entries(_run_plan_batch),
+    }
     from kolibrie_tpu.optimizer.plan_interp import interp_compile_stats
 
     out["run_interp"] = interp_compile_stats()
@@ -1007,12 +1081,7 @@ def _classify_source(jit_before: int, cc_before: Dict[str, int]) -> str:
     """Classify a specialized dispatch after the fact: a jit-cache entry
     appeared and every persistent-cache lookup hit disk → ``disk``;
     otherwise (fresh XLA compile, or warm replay) → ``compiled``."""
-    try:
-        grew = jit_before >= 0 and int(_run_plan._cache_size()) > jit_before
-    # kolint: ignore[KL601] same jax cache-API probe as device_compile_stats
-    except Exception:
-        grew = False
-    if not grew:
+    if not 0 <= jit_before < _jit_entries(_run_plan):
         return "compiled"
     after = _cc_counters()
     if after["hits"] > cc_before.get("hits", 0) and after[
@@ -2523,9 +2592,9 @@ class LoweredPlan:
         stats) — all device-resident."""
         from kolibrie_tpu.ops.pallas_kernels import pallas_enabled
 
-        spec, args = self.build(tag)
+        spec, args = _build_traced(self, tag)
         with jax.enable_x64(True):
-            return _run_plan(spec, pallas_enabled(), *args)
+            return _enqueue_traced(_run_plan, spec, pallas_enabled(), *args)
 
     def run_k(self, k: int, tag: int = 0):
         """``k`` plan executions amortized into one dispatch (see
@@ -2561,14 +2630,19 @@ class LoweredPlan:
         same template — on a fresh db, after a ``cap_key`` change from
         store growth, or post-restart-within-process — start from the
         high-water mark instead of re-walking the doubling ladder."""
-        from kolibrie_tpu.query.template import cap_advisor
+        from kolibrie_tpu.query.template import cap_advisor, cap_retry_seconds
 
         fp = _get_baggage("template", "unknown")
-        for _attempt in range(max_attempts):
+        t_retry = None
+        for attempt in range(max_attempts):
             out_cols, valid, counts, stats = out
             self._last_stats = stats  # device-resident; fetched only on analyze
-            counts_h = [int(c) for c in counts]
+            counts_h = _read_counts(out, counts, attempt, int)
             _note_fetch("converge.counts")
+            if t_retry is not None:
+                cap_retry_seconds.labels("device").inc(
+                    _time.perf_counter() - t_retry
+                )
             overflow = [
                 i for i, c in enumerate(counts_h) if c > self._join_caps[i]
             ]
@@ -2592,6 +2666,7 @@ class LoweredPlan:
             for i in overflow:
                 self._join_caps[i] = _round_cap(2 * counts_h[i])
             self._store_caps()
+            t_retry = _time.perf_counter()
             out = self.run()
         raise RuntimeError("device plan capacities failed to converge")
 
@@ -3171,13 +3246,13 @@ def _execute_plan_batch(
             results[i] = lp.empty_table()
     if not live:
         return results
-    for _attempt in range(max_attempts):
+    for attempt in range(max_attempts):
         spec0 = None
         base_args = None
         scal, ups, fps = [], [], []
         for i in live:
             lp = lowereds[i]
-            spec, args = lp.build(tag=0)
+            spec, args = _build_traced(lp, 0)
             if spec0 is None:
                 spec0, base_args = spec, args
             elif spec != spec0:
@@ -3193,7 +3268,8 @@ def _execute_plan_batch(
                 jnp.asarray(np.stack(ups)),
                 jnp.asarray(np.stack(fps), dtype=jnp.float64),
             )
-            out_cols, valid, counts, bstats = _run_plan_batch(
+            out = _enqueue_traced(
+                _run_plan_batch,
                 spec0,
                 order_arrays,
                 jnp.asarray(np.stack(scal)),
@@ -3203,9 +3279,12 @@ def _execute_plan_batch(
                 quoted,
                 params_b,
             )
+        out_cols, valid, counts, bstats = out
         lp0 = lowereds[live[0]]
         caps = lp0._join_caps
-        maxc = [int(np.max(np.asarray(c))) for c in counts]
+        maxc = _read_counts(
+            out, counts, attempt, lambda c: int(np.max(np.asarray(c)))
+        )
         over = [j for j, c in enumerate(maxc) if c > caps[j]]
         if not over:
             break

@@ -73,6 +73,18 @@ _MISSES = _metrics.counter(
     "persistent compilation cache misses (real XLA compile + write)",
 )
 
+# seconds inside jax's compile-or-load step, by where the executable came
+# from: XLA compiled it, or the persistent cache held it on disk
+_COMPILE_SECONDS = _metrics.counter(
+    "kolibrie_device_compile_seconds_total",
+    "wall seconds in the backend compile step (source=compile: XLA compiled "
+    "the executable; source=disk: the persistent cache held it)",
+    labels=("source",),
+)
+_COMPILE_SECONDS.labels("compile")
+_COMPILE_SECONDS.labels("disk")
+_tls = threading.local()  # .hit: this thread's compile in flight hit disk
+
 _lock = threading.Lock()
 _active_dir: Optional[str] = None
 # where the pre-warm manifest lives: the configured root (the cache
@@ -101,6 +113,17 @@ def _on_event(event: str, **kwargs) -> None:
         _MISSES.inc()
 
 
+def _on_duration(event: str, duration_secs: float, **kwargs) -> None:
+    # jax records a hit's retrieval time inside the backend-compile step and
+    # that step's whole duration when it ends, both on the compiling thread
+    if event == "/jax/compilation_cache/cache_retrieval_time_sec":
+        _tls.hit = True
+    elif event == "/jax/core/compile/backend_compile_duration":
+        source = "disk" if getattr(_tls, "hit", False) else "compile"
+        _tls.hit = False
+        _COMPILE_SECONDS.labels(source).inc(duration_secs)
+
+
 def _install_listener() -> None:
     global _listener_installed
     if _listener_installed:
@@ -109,6 +132,7 @@ def _install_listener() -> None:
         from jax._src import monitoring
 
         monitoring.register_event_listener(_on_event)
+        monitoring.register_event_duration_secs_listener(_on_duration)
         _listener_installed = True
     # kolint: ignore[KL601] private-API drift: cache still works, only the counters go dark
     except Exception:
@@ -133,6 +157,9 @@ def enable(
     dispatches hit disk.
     """
     global _active_dir, _active_root
+    with _lock:
+        # compile seconds are counted with or without a cache directory
+        _install_listener()
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env_dir:
         root = target = os.path.abspath(env_dir)
@@ -156,7 +183,6 @@ def enable(
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         jax.config.update("jax_enable_compilation_cache", True)
-        _install_listener()
         _active_dir, _active_root = target, root
     return target
 

@@ -779,6 +779,14 @@ def _display_ranks(db, disp, result_rows: int = 1 << 62):
 def format_results(
     db, table: BindingTable, q: SelectQuery, sort_rows: bool = False
 ) -> Rows:
+    """:func:`_decode_rows` under the span ``query.decode``."""
+    with span("query.decode"):
+        return _decode_rows(db, table, q, sort_rows)
+
+
+def _decode_rows(
+    db, table: BindingTable, q: SelectQuery, sort_rows: bool = False
+) -> Rows:
     """Final ID→string decode (engine.rs:34-50 parity).
 
     Plain-term columns decode by fancy-indexing the db-level display cache;

@@ -218,6 +218,16 @@ _CAP_RETRIES = metrics.counter(
 # in /metrics as an explicit 0, not an absent family
 _CAP_RETRIES.labels("device")
 _CAP_RETRIES.labels("sharded")
+# what those re-runs cost: build-to-counts wall time of each re-dispatch,
+# incremented by the overflow protocol itself (LoweredPlan.converge)
+cap_retry_seconds = metrics.counter(
+    "kolibrie_cap_retry_seconds_total",
+    "wall seconds spent in doubled-capacity re-runs (build to count "
+    "readback), by engine",
+    labels=("engine",),
+)
+cap_retry_seconds.labels("device")
+cap_retry_seconds.labels("sharded")
 
 
 def cap_advisor_enabled() -> bool:

@@ -433,3 +433,195 @@ def test_trace_id_reaches_interpreter_spans(server, monkeypatch):
     names = {s["name"] for s in spans}
     assert "interp.dispatch" in names, names
     assert all(s["trace_id"] == "trace-interp-1" for s in spans)
+
+
+# ------------------------------------- inside device.dispatch (ISSUE 25)
+
+GRAPH_NT = "\n".join(
+    line
+    for i in range(40)
+    for line in (
+        f"<http://g/n{i}> <http://g/knows> <http://g/n{(i + 1) % 40}> .",
+        f"<http://g/n{i}> <http://g/likes> <http://g/n{(i + 2) % 40}> .",
+        f"<http://g/n{(i + 1) % 40}> <http://g/likes> <http://g/n{(i + 2) % 40}> .",
+        f'<http://g/n{i}> <http://g/name> "n{i}" .',
+    )
+)
+JOIN_Q = "SELECT ?a ?n WHERE { ?a <http://g/knows> ?b . ?a <http://g/name> ?n }"
+TRIANGLE_Q = (
+    "SELECT ?a ?b ?c WHERE { ?a <http://g/knows> ?b . "
+    "?b <http://g/likes> ?c . ?a <http://g/likes> ?c }"
+)
+DISPATCH_CHILDREN = ("device.build", "device.enqueue", "device.wait", "device.counts")
+
+
+def graph_db():
+    from kolibrie_tpu import SparqlDatabase
+
+    db = SparqlDatabase()
+    db.execution_mode = "device"
+    db.parse_ntriples(GRAPH_NT)
+    return db
+
+
+def children_of(spans, parent_name):
+    parents = [s for s in spans if s["name"] == parent_name]
+    return parents, {
+        p["span_id"]: [s for s in spans if s["parent_id"] == p["span_id"]]
+        for p in parents
+    }
+
+
+def counter_values(prefix):
+    """Every sample of the families whose name starts with ``prefix``."""
+    out = {}
+    for line in obs_export.render_prometheus().splitlines():
+        if line.startswith(prefix):
+            key, _, value = line.rpartition(" ")
+            out[key] = float(value)
+    return out
+
+
+def test_device_dispatch_child_spans():
+    from kolibrie_tpu import execute_query_volcano
+
+    db = graph_db()
+    obs_spans.clear()
+    assert len(execute_query_volcano(JOIN_Q, db)) == 40
+    spans = obs_spans.spans_snapshot()
+    (dispatch,), kids = children_of(spans, "device.dispatch")
+    mine = kids[dispatch["span_id"]]
+    assert [s["name"] for s in mine] == list(DISPATCH_CHILDREN)
+    assert sum(s["dur_ms"] for s in mine) <= dispatch["dur_ms"]
+    by_name = {s["name"]: s for s in mine}
+    assert by_name["device.build"]["attrs"]["h2d_bytes"] > 0  # first use
+    assert by_name["device.enqueue"]["attrs"]["compiled"] in (0, 1)
+    assert by_name["device.wait"]["attrs"] == {"attempt": 0}
+    assert by_name["device.counts"]["attrs"] == {"attempt": 0, "n": 1}
+    # the executor's decode is a child of its own request span
+    (execute,), kids = children_of(spans, "query.execute")
+    assert "query.decode" in {s["name"] for s in kids[execute["span_id"]]}
+
+
+def test_cap_overflow_repeats_children_and_counts_seconds():
+    from kolibrie_tpu import execute_query_volcano
+    from kolibrie_tpu.query.template import cap_advisor
+
+    family = 'kolibrie_cap_retry_seconds_total{engine="device"}'
+    db = graph_db()
+    rows = execute_query_volcano(JOIN_Q, db)
+    # forget what the first run learned: the next starts from a capacity
+    # its 40 matches overflow, and has to re-run with a doubled one
+    caps = db.__dict__["_device_cap_cache"]
+    for key in caps:
+        caps[key] = tuple(8 for _ in caps[key])
+    cap_advisor.reset()
+    obs_spans.clear()
+    before = counter_values("kolibrie_cap_retry_seconds_total")[family]
+    assert execute_query_volcano(JOIN_Q, db) == rows
+    (dispatch,), kids = children_of(obs_spans.spans_snapshot(), "device.dispatch")
+    mine = kids[dispatch["span_id"]]
+    assert [s["name"] for s in mine] == list(DISPATCH_CHILDREN) * 2
+    assert [s["attrs"]["attempt"] for s in mine if s["name"] == "device.wait"] == [0, 1]
+    assert sum(s["dur_ms"] for s in mine) <= dispatch["dur_ms"]
+    grew = counter_values("kolibrie_cap_retry_seconds_total")[family] - before
+    rerun_ms = sum(s["dur_ms"] for s in mine[4:])
+    assert rerun_ms / 1000.0 <= grew <= dispatch["dur_ms"] / 1000.0
+
+
+def test_dispatch_children_off_when_disabled():
+    from kolibrie_tpu import execute_query_volcano
+
+    db = graph_db()
+    names = ("kolibrie_cap_retry_seconds", "kolibrie_store_", "kolibrie_device_compile_seconds")
+    obs_spans.clear()
+    before = {n: counter_values(n) for n in names}
+    obs_runtime.set_enabled(False)
+    try:
+        assert len(execute_query_volcano(TRIANGLE_Q, db)) == 40
+    finally:
+        obs_runtime.set_enabled(True)
+    assert obs_spans.spans_snapshot() == []
+    assert {n: counter_values(n) for n in names} == before
+
+
+SETUP_COUNTERS = (
+    ['kolibrie_store_load_seconds_total{phase="%s"}' % p for p in ("parse", "compact")]
+    + ['kolibrie_store_order_build_seconds_total{order="%s"}' % o
+       for o in ("spo", "pos", "osp", "pso", "ops", "sop")]
+    + ['kolibrie_store_h2d_seconds_total{segment="%s"}' % s for s in ("base", "delta")]
+    + ['kolibrie_device_compile_seconds_total{source="%s"}' % s
+       for s in ("compile", "disk")]
+    + ['kolibrie_cap_retry_seconds_total{engine="%s"}' % e
+       for e in ("device", "sharded")]
+)
+
+
+def setup_counters():
+    values = counter_values("kolibrie_")
+    return {k: values[k] for k in SETUP_COUNTERS}  # every child exists, at 0
+
+
+def test_load_then_query_grows_setup_counters(server):
+    before = setup_counters()
+    obs_spans.clear()
+    post(server, "/store/load",
+         {"store_id": "obs_setup", "rdf": GRAPH_NT, "format": "ntriples",
+          "mode": "device"})
+    # a shape no other test of this module compiles
+    headers, out = post(
+        server, "/store/query",
+        {"store_id": "obs_setup", "sparql":
+         "SELECT ?a ?c ?n WHERE { ?a <http://g/knows> ?b . "
+         "?b <http://g/knows> ?c . ?c <http://g/name> ?n }"},
+        headers={"X-Kolibrie-Trace-Id": "trace-setup-1"})
+    assert len(out["data"]) == 40
+    grew = {k: v - before[k] for k, v in setup_counters().items() if v > before[k]}
+    assert 'kolibrie_store_load_seconds_total{phase="parse"}' in grew
+    assert 'kolibrie_store_load_seconds_total{phase="compact"}' in grew
+    assert 'kolibrie_store_h2d_seconds_total{segment="base"}' in grew
+    assert 'kolibrie_store_h2d_seconds_total{segment="delta"}' in grew
+    assert any(k.startswith("kolibrie_store_order_build_seconds_total")
+               and "spo" not in k for k in grew), grew
+    # XLA compiled the new shape, or the persistent cache held it
+    assert any(k.startswith("kolibrie_device_compile_seconds_total") for k in grew)
+    # the front door's two halves, under the request's span
+    _, body = get(server, "/debug/traces?trace_id=trace-setup-1")
+    spans = [json.loads(l) for l in body.splitlines() if l]
+    (request,), kids = children_of(spans, "http.request")
+    names = [s["name"] for s in kids[request["span_id"]]]
+    assert names[0] == "http.read_body" and names[-1] == "http.respond"
+
+
+@pytest.mark.parametrize(
+    "sparql, use_pallas, scopes",
+    [
+        (JOIN_Q, False, ("scan0", "scan1", "join0")),
+        (TRIANGLE_Q, False,
+         ("wcoj0/wcoj0.L0/probe", "wcoj0/wcoj0.L1/expand", "wcoj0/wcoj0.L1/dedup",
+          "wcoj0/wcoj0.L2/live")),
+        (TRIANGLE_Q, True,
+         ("wcoj0.L1/dedup/lex_probe_select", "wcoj0.L1/live/lex_probe_validate")),
+    ],
+)
+def test_lowered_plan_names_its_operators(sparql, use_pallas, scopes):
+    """The EXPLAIN ANALYZE keys are the scope path of each operator's ops --
+    what a device profile shows as an op's ``tf_op``."""
+    import jax
+
+    from kolibrie_tpu.optimizer import device_engine as de
+    from kolibrie_tpu.query import executor as ex
+
+    db = graph_db()
+    db.register_prefixes_from_query(sparql)
+    entry, _slot = ex._plan_cache_entry(db, sparql)
+    _q, where = ex._batchable_select(db, entry["cq"])
+    logical = ex.build_logical_plan(
+        [ex.resolve_pattern(db, p) for p in where.patterns], [], [], None)
+    plan = ex.Streamertail(db.get_or_build_stats()).find_best_plan(logical)
+    spec, args = de.lower_plan(db, plan).build()
+    with jax.enable_x64(True):
+        text = de._run_plan.lower(spec, use_pallas, *args).as_text(debug_info=True)
+    paths = set(re.findall(r'"(jit\(_run_plan\)/[^"]*)"', text))
+    for scope in scopes:
+        assert any(f"/{scope}/" in p for p in paths), (scope, sorted(paths)[:40])
