@@ -78,7 +78,7 @@ try:  # obs is stdlib-only and imports nothing from the engine (no cycle)
         "Wall seconds of host sorts building a sort order, by order.",
         labels=("order",),
     )
-    LOAD_SECONDS = _obs_counter(
+    _LOAD_SECONDS = _obs_counter(
         "kolibrie_store_load_seconds_total",
         "Wall seconds of ingest: text to ids (phase=parse, counted by the "
         "/store/load handler) and folding pending rows into the sorted "
@@ -90,11 +90,23 @@ try:  # obs is stdlib-only and imports nothing from the engine (no cycle)
     for _name in ("spo", "pos", "osp", "pso", "ops", "sop"):
         _ORDER_BUILD_SECONDS.labels(_name)
     for _phase in ("parse", "compact"):
-        LOAD_SECONDS.labels(_phase)
+        _LOAD_SECONDS.labels(_phase)
 # kolint: ignore[KL601] import-time obs registration must never block the store; the None sentinels disable instrumentation and every call site guards on them
 except Exception:  # pragma: no cover
     _H2D_BYTES = _DELTA_MERGES = _ORDER_REBUILDS = _DELTA_ROWS = None
-    _H2D_SECONDS = _ORDER_BUILD_SECONDS = LOAD_SECONDS = None
+    _H2D_SECONDS = _ORDER_BUILD_SECONDS = _LOAD_SECONDS = None
+
+
+def _add_seconds(family, label: str, t0: float) -> None:
+    """Count the wall time since ``t0`` (``time.perf_counter()``)."""
+    if family is not None:
+        family.labels(label).inc(time.perf_counter() - t0)
+
+
+def add_load_seconds(phase: str, t0: float) -> None:
+    """``kolibrie_store_load_seconds_total{phase}``; the ``/store/load``
+    handler counts ``parse`` around the text's way to ids."""
+    _add_seconds(_LOAD_SECONDS, phase, t0)
 
 
 def h2d_bytes_total() -> float:
@@ -483,8 +495,7 @@ class ColumnarTripleStore:
             return
         t0 = time.perf_counter()
         self._compact_pending()
-        if LOAD_SECONDS is not None:
-            LOAD_SECONDS.labels("compact").inc(time.perf_counter() - t0)
+        add_load_seconds("compact", t0)
 
     def _compact_pending(self) -> None:
         parts_s = []
@@ -786,8 +797,7 @@ class ColumnarTripleStore:
             {"s": s, "p": p, "o": o},
             presorted=(name == "spo"),
         )
-        if _ORDER_BUILD_SECONDS is not None:
-            _ORDER_BUILD_SECONDS.labels(name).inc(time.perf_counter() - t0)
+        _add_seconds(_ORDER_BUILD_SECONDS, name, t0)
         return so
 
     def order(self, name: str) -> SortedOrder:
@@ -939,7 +949,7 @@ class ColumnarTripleStore:
             self._device_segments[name] = base
             if _H2D_BYTES is not None:
                 _H2D_BYTES.labels("base").inc(3 * cap * 4)
-                _H2D_SECONDS.labels("base").inc(time.perf_counter() - t0)
+            _add_seconds(_H2D_SECONDS, "base", t0)
         delta = self._device_delta.get(name)
         if delta is None:
             import jax
@@ -965,7 +975,7 @@ class ColumnarTripleStore:
             self._device_delta[name] = delta
             if _H2D_BYTES is not None:
                 _H2D_BYTES.labels("delta").inc(4 * dcap * 4)
-                _H2D_SECONDS.labels("delta").inc(time.perf_counter() - t0)
+            _add_seconds(_H2D_SECONDS, "delta", t0)
         return base, delta[0], delta[1]
 
     def contains(self, s: int, p: int, o: int) -> bool:

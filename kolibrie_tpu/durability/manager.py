@@ -67,9 +67,6 @@ _RECOVERY_TRUNCATED = obs_metrics.counter(
 _SNAPSHOT_GEN = obs_metrics.gauge(
     "kolibrie_snapshot_generation", "latest committed snapshot generation"
 )
-_SNAPSHOTS = obs_metrics.counter(
-    "kolibrie_snapshots_total", "snapshot generations committed"
-)
 _SNAPSHOT_LAT = obs_metrics.histogram(
     "kolibrie_snapshot_seconds", "snapshot capture+commit wall time"
 )
@@ -670,7 +667,6 @@ class DurabilityManager:
                     except OSError:
                         pass
             fsync_dir(self.wal_dir)
-        _SNAPSHOTS.inc()
         _SNAPSHOT_GEN.set(gen)
         _SNAPSHOT_LAT.observe(time.perf_counter() - t0)
         return gen

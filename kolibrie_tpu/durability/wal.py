@@ -79,9 +79,6 @@ _WAL_APPEND_LAT = obs_metrics.histogram(
 _WAL_FSYNC_LAT = obs_metrics.histogram(
     "kolibrie_wal_fsync_seconds", "WAL fsync wall time"
 )
-_WAL_FSYNCS = obs_metrics.counter(
-    "kolibrie_wal_fsyncs_total", "WAL fsync calls"
-)
 _WAL_GROUP_FSYNC_ERRORS = obs_metrics.counter(
     "kolibrie_wal_group_fsync_errors_total",
     "background group-commit fsyncs that failed (retried at next flush)",
@@ -209,7 +206,6 @@ class WalWriter:
                 continue
             with self._lock:
                 self._last_fsync = time.monotonic()
-            _WAL_FSYNCS.inc()
             _WAL_FSYNC_LAT.observe(time.perf_counter() - t0)
 
     def _open_segment(self, index: int) -> None:  # kolint: holds[_lock]
@@ -281,7 +277,6 @@ class WalWriter:
         os.fsync(self._fh.fileno())
         self._last_fsync = time.monotonic()
         self._dirty = False
-        _WAL_FSYNCS.inc()
         _WAL_FSYNC_LAT.observe(time.perf_counter() - t0)
 
     def flush(self) -> None:

@@ -1154,7 +1154,7 @@ class KolibrieHandler(BaseHTTPRequestHandler):
         /query (fresh database per request), the store survives across
         requests so repeat queries hit the warm plan-template cache and
         concurrent same-template queries micro-batch."""
-        from kolibrie_tpu.core.store import LOAD_SECONDS
+        from kolibrie_tpu.core.store import add_load_seconds
         from kolibrie_tpu.query.sparql_database import SparqlDatabase
 
         with span("http.read_body"):
@@ -1182,8 +1182,7 @@ class KolibrieHandler(BaseHTTPRequestHandler):
                 n = _load_rdf_into(
                     batcher.db, req.get("rdf") or "", req.get("format", "ntriples")
                 )
-                if LOAD_SECONDS is not None:
-                    LOAD_SECONDS.labels("parse").inc(time.perf_counter() - t0)
+                add_load_seconds("parse", t0)
                 # eager mirror upload while we already hold the lock: the
                 # first query after a load pays dispatch, not partitioning
                 _maybe_attach_sharded(batcher.db)

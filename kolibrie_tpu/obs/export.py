@@ -195,11 +195,6 @@ _store_shards_gauge = metrics.gauge(
     "mesh shard count serving a store (0 rows absent = single-device)",
     labels=("store",),
 )
-_store_shard_imbalance_gauge = metrics.gauge(
-    "kolibrie_store_shard_imbalance",
-    "per-store max/mean shard row occupancy",
-    labels=("store",),
-)
 _plan_cache_gauges = {
     "parse_entries": metrics.gauge(
         "kolibrie_plan_cache_parse_entries",
@@ -230,10 +225,6 @@ def refresh_server_gauges(state) -> None:
         if sharded is not None:
             sh_stats = sharded.stats()
             _store_shards_gauge.labels(sid).set(sh_stats["shards"])
-            if "imbalance" in sh_stats:
-                _store_shard_imbalance_gauge.labels(sid).set(
-                    sh_stats["imbalance"]
-                )
     # follower watermark/lag SLO gauges refresh at scrape time so a
     # wedged poll loop cannot freeze the lag /metrics reports — the
     # follower owns the gauge families; primaries (ShipServer) have no

@@ -151,8 +151,8 @@ _NOOP = _NoopScope()
 
 class _SpanScope:
     """Hand-rolled context manager: the span enter/exit pair sits on the
-    per-query hot path, where ``@contextmanager`` generator machinery is
-    measurable (bench.py's obs overhead budget is 3%)."""
+    per-query hot path, a dozen and more pairs a request, where
+    ``@contextmanager`` generator machinery is measurable."""
 
     __slots__ = ("name", "attrs", "ctx", "sp", "implicit")
 

@@ -395,8 +395,9 @@ def _build_traced(lowered, tag: int):
     with _obs_span("device.build") as sp:
         before = h2d_bytes_total() if sp is not None else 0
         built = lowered.build(tag)
-        if sp is not None and h2d_bytes_total() > before:
-            sp.attrs["h2d_bytes"] = int(h2d_bytes_total() - before)
+        uploaded = h2d_bytes_total() - before if sp is not None else 0
+        if uploaded:
+            sp.attrs["h2d_bytes"] = int(uploaded)
     return built
 
 
@@ -548,7 +549,7 @@ def _plan_body(
         if isinstance(node, LeftOuterSpec):
             return f"optional{node.join_idx}"
         if isinstance(node, WcojSpec):
-            return f"wcoj{node.levels[0].join_idx}" if node.levels else "wcoj"
+            return f"wcoj{node.levels[0].join_idx}"
         for kind, cls in (
             ("filter", FilterSpec),
             ("anti", AntiJoinSpec),

@@ -226,8 +226,7 @@ cap_retry_seconds = metrics.counter(
     "readback), by engine",
     labels=("engine",),
 )
-cap_retry_seconds.labels("device")
-cap_retry_seconds.labels("sharded")
+cap_retry_seconds.labels("device")  # the mesh path counts retries, not yet their seconds
 
 
 def cap_advisor_enabled() -> bool:

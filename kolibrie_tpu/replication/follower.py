@@ -65,12 +65,6 @@ from kolibrie_tpu.replication.protocol import (
 _GEN_PREFIX = "gen-"
 _GEN_TMP_PREFIX = ".tmp-gen-"
 
-_SEGS_APPLIED = obs_metrics.counter(
-    "kolibrie_repl_segments_applied_total", "shipped segments applied"
-)
-_RECORDS_APPLIED = obs_metrics.counter(
-    "kolibrie_repl_records_applied_total", "WAL records replayed from ship"
-)
 _POLL_ERRORS = obs_metrics.counter(
     "kolibrie_repl_poll_errors_total",
     "poll-loop failures (timeouts, tears, desyncs) — each one reconnects",
@@ -86,9 +80,6 @@ _LAG_RECORDS = obs_metrics.gauge(
     "kolibrie_repl_lag_records",
     "primary-appended WAL records not yet applied here "
     "(same-epoch estimate, re-baselined at bootstrap)",
-)
-_APPLIED_SEGMENT = obs_metrics.gauge(
-    "kolibrie_repl_applied_segment", "highest fully-applied segment index"
 )
 _APPLIED_RECORDS = obs_metrics.gauge(
     "kolibrie_repl_applied_records",
@@ -237,7 +228,6 @@ class ReplicationFollower:
         with self._lock:
             self.applied_records += len(records)
             total = self.applied_records
-        _RECORDS_APPLIED.inc(len(records))
         _APPLIED_RECORDS.set(total)
 
     def _advance_from_local(self) -> None:
@@ -266,8 +256,6 @@ class ReplicationFollower:
                 self.applied_segment = nxt
                 self.last_applied_unix = time.time()
                 self.stats_counters["segments_applied"] += 1
-            _SEGS_APPLIED.inc()
-            _APPLIED_SEGMENT.set(nxt)
 
     # --------------------------------------------------------- bootstrap
 
@@ -455,7 +443,6 @@ class ReplicationFollower:
         _LAG_SEGMENTS.set(self.lag_segments())
         _LAG_RECORDS.set(self.lag_records())
         with self._lock:
-            _APPLIED_SEGMENT.set(self.applied_segment)
             _APPLIED_RECORDS.set(self.applied_records)
 
     def watermark(self) -> dict:

@@ -40,21 +40,8 @@ from kolibrie_tpu.replication.protocol import (
     send_msg,
 )
 
-_SEGS_SHIPPED = obs_metrics.counter(
-    "kolibrie_repl_segments_shipped_total", "sealed WAL segments shipped"
-)
 _SHIP_BYTES = obs_metrics.counter(
     "kolibrie_repl_ship_bytes_total", "bytes shipped (segments + snapshots)"
-)
-_SEALS = obs_metrics.counter(
-    "kolibrie_repl_seals_total", "poll-driven seals of the active segment"
-)
-_POLLS = obs_metrics.counter(
-    "kolibrie_repl_polls_total", "follower poll requests served"
-)
-_SNAP_FILES_SHIPPED = obs_metrics.counter(
-    "kolibrie_repl_snapshot_files_shipped_total",
-    "snapshot generation files shipped to bootstrapping followers",
 )
 
 
@@ -133,7 +120,6 @@ class ShipServer:
         if t == "manifest":
             send_msg(conn, self._manifest_meta(q))
         elif t == "poll":
-            _POLLS.inc()
             self._maybe_seal()
             send_msg(conn, self._poll_meta(q, int(meta.get("after", 0))))
         elif t == "file":
@@ -195,8 +181,7 @@ class ShipServer:
             if now - self._last_seal < self.seal_interval_s:
                 return
             self._last_seal = now
-        if wal.seal_if_dirty() is not None:
-            _SEALS.inc()
+        wal.seal_if_dirty()
 
     def _poll_meta(self, q, after: int) -> dict:
         sealed, wal_start, pos, records = self._wal_state()
@@ -222,7 +207,6 @@ class ShipServer:
         except OSError as exc:
             send_msg(conn, {"t": "err", "q": q, "reason": repr(exc)})
             return
-        _SNAP_FILES_SHIPPED.inc()
         _SHIP_BYTES.inc(len(data))
         send_msg(
             conn,
@@ -245,7 +229,6 @@ class ShipServer:
         except OSError as exc:
             send_msg(conn, {"t": "err", "q": q, "reason": repr(exc)})
             return
-        _SEGS_SHIPPED.inc()
         _SHIP_BYTES.inc(len(data))
         send_msg(
             conn,

@@ -7,6 +7,7 @@ import json
 import os
 import socket
 import threading
+import time
 import urllib.error
 import urllib.request
 
@@ -349,11 +350,20 @@ def fleet(tmp_path):
 
 
 def _traces_for(base, tid):
-    st, body, _ = _get(base, f"/debug/traces?trace_id={tid}")
-    assert st == 200
-    return [
-        json.loads(ln) for ln in body.decode().splitlines() if ln.strip()
-    ]
+    # a node's reply is on the wire before it leaves ``http.request``: wait
+    # for that span to land in its ring
+    deadline = time.monotonic() + 5.0
+    while True:
+        st, body, _ = _get(base, f"/debug/traces?trace_id={tid}")
+        assert st == 200
+        recs = [
+            json.loads(ln) for ln in body.decode().splitlines() if ln.strip()
+        ]
+        if any(r["name"] == "http.request" for r in recs) or (
+            time.monotonic() > deadline
+        ):
+            return recs
+        time.sleep(0.01)
 
 
 def _sparql_with_home(core, home, fallback):
