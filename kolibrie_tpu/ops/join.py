@@ -16,7 +16,7 @@ the device variant in :mod:`kolibrie_tpu.ops.device_join` runs it on TPU.
 
 from __future__ import annotations
 
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -78,14 +78,24 @@ def equi_join_tables(
     return out
 
 
-def join_indices(lkey: np.ndarray, rkey: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
-    """Row-index pairs (li, ri) with lkey[li] == rkey[ri] — sort-based."""
+class JoinTooLarge(Exception):
+    """``join_indices`` counted more pairs than its caller allowed."""
+
+
+def join_indices(
+    lkey: np.ndarray, rkey: np.ndarray, max_rows: Optional[int] = None
+) -> Tuple[np.ndarray, np.ndarray]:
+    """Row-index pairs (li, ri) with lkey[li] == rkey[ri] — sort-based.
+    With ``max_rows`` a larger result raises :class:`JoinTooLarge` after
+    the count and before any pair is materialized."""
     order = np.argsort(rkey, kind="stable")
     rsorted = rkey[order]
     lo = np.searchsorted(rsorted, lkey, side="left")
     hi = np.searchsorted(rsorted, lkey, side="right")
     counts = hi - lo
     total = int(counts.sum())
+    if max_rows is not None and total > max_rows:
+        raise JoinTooLarge(total)
     if total == 0:
         z = np.empty(0, dtype=np.int64)
         return z, z

@@ -46,7 +46,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from functools import partial
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -144,6 +144,38 @@ _WCOJ_PROBES = _obs_metrics.counter(
 
 class Unsupported(Exception):
     """Plan construct the device path cannot express (host fallback)."""
+
+
+# ---------------------------------------------------------------------------
+# The capacity rule (docs/COMPILE_CACHE.md "Capacity protocol").  A join or
+# WCOJ level is compiled for the rows the template has been seen to produce,
+# with headroom, and never for more than the inputs' capacities suggest: the
+# search loops, the compaction sorts and the readback all cost slots, not
+# rows.  Overflow (a variant with more than _CAP_HEADROOM x the calibrated
+# rows) is the protocol's business, not the rule's.
+# ---------------------------------------------------------------------------
+_CAP_HEADROOM = 4
+_CAP_FLOOR = 1024
+# The numpy twin gives up past this many rows in one scan, join or WCOJ
+# level (the guard of dist_query's calibration): materializing more on the
+# host just to size device buffers costs the memory static capacities exist
+# to avoid.  The first device run's counts calibrate instead.
+_CALIBRATE_ROW_LIMIT = 8_000_000
+
+
+class _CalibrationTooLarge(Exception):
+    """An intermediate of the host calibration pass exceeded the row limit."""
+
+
+def fit_join_caps(heuristic: Sequence[int], counts: Sequence[int]) -> List[int]:
+    """THE capacity rule, per join and per WCOJ level:
+    ``min(heuristic, round_cap(max(H x count, FLOOR)))``.  Every path that
+    sizes a join from counts (the calibrated start, the tighten-once
+    fallback, ``calibrate_host``) goes through here."""
+    return [
+        min(int(h), _round_cap(max(_CAP_HEADROOM * int(c), _CAP_FLOOR)))
+        for h, c in zip(heuristic, counts)
+    ]
 
 
 # ---------------------------------------------------------------------------
@@ -2063,10 +2095,11 @@ class LoweredPlan:
             return join_caps[node.levels[-1].join_idx]
         raise TypeError(node)
 
-    def _initial_join_caps(self, scan_caps) -> List[int]:
-        cached = self.db.__dict__.setdefault("_device_cap_cache", {}).get(self.cap_key)
-        if cached is not None and len(cached) == self.join_count:
-            return list(cached)
+    def _heuristic_join_caps(self, scan_caps) -> List[int]:
+        """Capacities from the inputs' capacities alone: the ceiling of
+        :func:`fit_join_caps` and the start where nothing has been counted
+        yet.  Not a bound (a join can produce left x right); the overflow
+        protocol in :meth:`converge` is what keeps answers exact."""
         caps: List[int] = [0] * self.join_count
 
         def walk(node) -> int:
@@ -2091,10 +2124,9 @@ class LoweredPlan:
             if isinstance(node, (FilterSpec, QuotedExpandSpec)):
                 return walk(node.child)  # fill caps of joins under wrappers
             if isinstance(node, WcojSpec):
-                # optimistic start: each level no larger than its tightest
-                # accessor's largest key-group (template property) or the
-                # previous level, whichever wins; convergence doubles on
-                # real overflow — and totals are exact even when a level
+                # each level no larger than its tightest accessor's largest
+                # key-group (template property) or the previous level,
+                # whichever wins; totals are exact even when a level
                 # overflows, so each retry fixes a level for good
                 prev = 1
                 for lv in node.levels:
@@ -2112,30 +2144,66 @@ class LoweredPlan:
             return self._node_cap(node, scan_caps, caps)
 
         walk(self.root)
-        # db-cache miss (fresh db, or the cap_key moved because store
-        # growth changed a scan cap bucket): seed from the process-wide
-        # advisor's high-water mark for this template, so steady state
-        # skips the heuristic→double→retry ladder entirely.  The baggage
-        # fingerprint is "unknown" for direct engine construction (tests,
-        # EXPLAIN) — skipped, so unrelated callers never cross-pollinate.
+        return caps
+
+    def _initial_join_caps(self, scan_caps) -> List[int]:
+        """Join and WCOJ-level capacities for this dispatch, in order of
+        preference: what the template already converged to on this db
+        (every variant shares it: one executable a template); the
+        process-wide advisor's high-water mark for the fingerprint (a
+        fresh db, or a ``cap_key`` that moved with store growth); else a
+        calibrated start, the rows this variant produces with headroom
+        (:func:`fit_join_caps`), counted by the numpy twin before the
+        first executable is chosen.  The baggage fingerprint is "unknown"
+        for direct engine construction (tests, EXPLAIN): no advice then,
+        so unrelated callers never cross-pollinate."""
+        cache = self.db.__dict__.setdefault("_device_cap_cache", {})
+        cached = cache.get(self.cap_key)
+        if cached is not None and len(cached) == self.join_count:
+            return list(cached)
+        heuristic = self._heuristic_join_caps(scan_caps)
         from kolibrie_tpu.query.template import cap_advisor
 
         fp = _get_baggage("template", "unknown")
         if fp != "unknown":
             advised = cap_advisor.advise("device", fp)
-            if advised is not None and len(advised) == len(caps):
-                caps = [max(c, a) for c, a in zip(caps, advised)]
+            if advised is not None and len(advised) == len(heuristic):
+                return list(advised)
+        if max(heuristic, default=0) <= _CAP_FLOOR:
+            return heuristic  # nothing the rule could tighten
+        counts = self._calibration_counts()
+        if counts is None:
+            # the host pass would be too large: run once at the heuristic
+            # and let converge() tighten from the counts that run reads
+            self.db.__dict__.setdefault("_device_cap_provisional", set()).add(
+                self.cap_key
+            )
+            return heuristic
+        caps = fit_join_caps(heuristic, counts)
+        cache[self.cap_key] = tuple(caps)
         return caps
 
-    def build(self, tag: int = 0) -> Tuple[PlanSpec, tuple]:
-        """Assemble (spec, array_args) for the current store/capacities."""
-        self._refresh_masks()
-        scan_ranges = self._scan_ranges()
-        # scan capacities are a TEMPLATE property: the largest key-group of
-        # the order's bound-column prefix bounds the live range for ANY
-        # constant, so every variant assembles the same ScanSpec.cap (the
-        # variant's true range rides in the traced scalars)
-        scan_caps = {
+    def _calibration_counts(self) -> Optional[List[int]]:
+        """Exact per-join counts of this variant from the numpy twin (no
+        device I/O), or ``None`` where an intermediate would pass
+        ``_CALIBRATE_ROW_LIMIT`` rows."""
+        from kolibrie_tpu.query.template import cap_calibrate_seconds
+
+        t0 = _time.perf_counter()
+        try:
+            _table, counts = self.host_execute(row_limit=_CALIBRATE_ROW_LIMIT)
+            outcome = "counted"
+        except _CalibrationTooLarge:
+            counts, outcome = None, "too_large"
+        cap_calibrate_seconds.labels(outcome).inc(_time.perf_counter() - t0)
+        return counts
+
+    def _template_scan_caps(self) -> Dict[int, int]:
+        """Scan capacities are a TEMPLATE property: the largest key-group
+        of the order's bound-column prefix bounds the live range for ANY
+        constant, so every variant assembles the same ScanSpec.cap (the
+        variant's true range rides in the traced scalars)."""
+        return {
             i: _round_cap(
                 template_scan_cap(
                     self.db,
@@ -2145,6 +2213,12 @@ class LoweredPlan:
             )
             for i, (name, consts) in enumerate(self.scan_descs)
         }
+
+    def build(self, tag: int = 0) -> Tuple[PlanSpec, tuple]:
+        """Assemble (spec, array_args) for the current store/capacities."""
+        self._refresh_masks()
+        scan_ranges = self._scan_ranges()
+        scan_caps = self._template_scan_caps()
         join_caps = self._initial_join_caps(scan_caps)
         self._scan_ranges_np = scan_ranges
         self._scan_caps = scan_caps
@@ -2201,11 +2275,16 @@ class LoweredPlan:
 
     # ------------------------------------------------------- host evaluation
 
-    def host_execute(self) -> Tuple[BindingTable, List[int]]:
+    def host_execute(
+        self, row_limit: Optional[int] = None
+    ) -> Tuple[BindingTable, List[int]]:
         """Evaluate the lowered IR with numpy — the executable-free reference
         semantics.  Returns (table, exact join counts).  Used to calibrate
         join capacities without any device readback (benchmarks time a
-        never-read executable) and as the oracle in spec-semantics tests."""
+        never-read executable) and as the oracle in spec-semantics tests.
+        With ``row_limit`` a scan, join or WCOJ level of more rows raises
+        :class:`_CalibrationTooLarge` before it is materialized."""
+        from kolibrie_tpu.ops.join import JoinTooLarge
         from kolibrie_tpu.ops.join import join_indices as host_join_indices
 
         if not self.const_ok():
@@ -2220,6 +2299,16 @@ class LoweredPlan:
         # EXPLAIN ANALYZE oracle tests assert exact agreement
         hstats: Dict[str, int] = {}
         hseq = {"filter": 0, "anti": 0, "union": 0, "quoted": 0}
+
+        def check_rows(n: int) -> None:
+            if row_limit is not None and n > row_limit:
+                raise _CalibrationTooLarge(n)
+
+        def join_pairs(lkey, rkey):
+            try:
+                return host_join_indices(lkey, rkey, max_rows=row_limit)
+            except JoinTooLarge as exc:
+                raise _CalibrationTooLarge(*exc.args) from None
 
         def eval_expr(expr, cols) -> np.ndarray:
             if isinstance(expr, MaskRef):
@@ -2292,6 +2381,7 @@ class LoweredPlan:
                 order_name, _consts = self.scan_descs[node.scan_idx]
                 order = self.db.store.order(order_name)
                 lo, n = (int(x) for x in scan_ranges[node.scan_idx])
+                check_rows(n)
                 canon = order.slice_rows(lo, lo + n)
                 raw = {0: canon["s"], 1: canon["p"], 2: canon["o"]}
                 mask = None
@@ -2322,7 +2412,7 @@ class LoweredPlan:
                     list(node.key_vars),
                     len(next(iter(lcols.values()))),
                 )
-                li, ri = host_join_indices(lkey, rkey)
+                li, ri = join_pairs(lkey, rkey)
                 counts[node.join_idx] = len(li)
                 hstats[f"join{node.join_idx}"] = len(li)
                 out = {v: c[li] for v, c in lcols.items()}
@@ -2401,7 +2491,7 @@ class LoweredPlan:
                 lkey, rkey = _pack_shared_keys(
                     lcols, rcols, list(node.key_vars), ln
                 )
-                li, ri = host_join_indices(lkey, rkey)
+                li, ri = join_pairs(lkey, rkey)
                 counts[node.join_idx] = len(li)
                 matched = np.zeros(ln, dtype=bool)
                 matched[li] = True
@@ -2497,6 +2587,7 @@ class LoweredPlan:
                 choice = np.argmin(cntm, axis=0)
                 cnt = np.min(cntm, axis=0)
                 total = int(cnt.sum())
+                check_rows(total)
                 counts[lv.join_idx] = total
                 hstats[f"wcoj{lv.join_idx}:cand"] = total
                 rows = np.repeat(np.arange(nrows), cnt)
@@ -2570,16 +2661,16 @@ class LoweredPlan:
         return table, counts
 
     def calibrate_host(self) -> List[int]:
-        """Set exact join capacities from a host evaluation (no device I/O);
-        returns the exact per-join match counts (EXPLAIN annotates with
-        them)."""
+        """Size the join capacities from a host evaluation (no device I/O)
+        by the one rule, publish them (max-merge: an earlier, larger
+        variant's caps stay); returns the exact per-join match counts
+        (EXPLAIN annotates with them)."""
         self._scan_ranges_np = self._scan_ranges()
         _table, counts = self.host_execute()
-        self._join_caps = [_round_cap(c) for c in counts]
-        self._store_caps()
-        self._join_caps = list(
-            self.db.__dict__["_device_cap_cache"][self.cap_key]
+        self._join_caps = fit_join_caps(
+            self._heuristic_join_caps(self._template_scan_caps()), counts
         )
+        self._store_caps()
         # calibration counts are EXACT per-join match counts: feed the
         # stats advisor before the first dispatch so a misrouted cold
         # template can already replan on its second execution
@@ -2631,7 +2722,11 @@ class LoweredPlan:
         same template — on a fresh db, after a ``cap_key`` change from
         store growth, or post-restart-within-process — start from the
         high-water mark instead of re-walking the doubling ladder."""
-        from kolibrie_tpu.query.template import cap_advisor, cap_retry_seconds
+        from kolibrie_tpu.query.template import (
+            cap_advisor,
+            cap_retry_seconds,
+            note_cap_occupancy,
+        )
 
         fp = _get_baggage("template", "unknown")
         t_retry = None
@@ -2644,19 +2739,20 @@ class LoweredPlan:
                 cap_retry_seconds.labels("device").inc(
                     _time.perf_counter() - t_retry
                 )
+            note_cap_occupancy("device", sum(self._join_caps), sum(counts_h))
             overflow = [
                 i for i, c in enumerate(counts_h) if c > self._join_caps[i]
             ]
             if not overflow:
                 self._last_counts = counts_h
-                self._store_caps()
+                published = self._publish_converged(counts_h)
                 self._emit_wcoj_obs(counts_h)
                 self._advise(counts_h)
                 if fp != "unknown":
                     cap_advisor.observe(
                         "device",
                         fp,
-                        tuple(self._join_caps),
+                        published,
                         base_version=getattr(
                             self.db.store, "base_version", None
                         ),
@@ -2670,6 +2766,22 @@ class LoweredPlan:
             t_retry = _time.perf_counter()
             out = self.run()
         raise RuntimeError("device plan capacities failed to converge")
+
+    def _publish_converged(self, counts_h: List[int]) -> Tuple[int, ...]:
+        """Publish the capacities a run converged at; returns them.  Where
+        that run started from the heuristic because the host calibration
+        was too large, its counts tighten the template's caps, once (the
+        next dispatch takes the smaller executable; ``_join_caps`` stays
+        what this one ran with); everywhere else the merge stays the
+        monotonic max of :meth:`_store_caps`."""
+        provisional = self.db.__dict__.get("_device_cap_provisional")
+        if provisional and self.cap_key in provisional:
+            provisional.discard(self.cap_key)
+            caps = tuple(fit_join_caps(self._join_caps, counts_h))
+            self.db.__dict__["_device_cap_cache"][self.cap_key] = caps
+            return caps
+        self._store_caps()
+        return tuple(self._join_caps)
 
     def _emit_wcoj_obs(self, counts_h: List[int]) -> None:
         """Per-level WCOJ instrumentation from the converged host-read
@@ -3228,6 +3340,8 @@ def _execute_plan_batch(
     doubles the shared template cap for everyone."""
     import jax.numpy as jnp
 
+    from kolibrie_tpu.query.template import note_cap_occupancy
+
     if not lowereds:
         return []
     check_deadline("device.batch")
@@ -3283,8 +3397,10 @@ def _execute_plan_batch(
         out_cols, valid, counts, bstats = out
         lp0 = lowereds[live[0]]
         caps = lp0._join_caps
-        maxc = _read_counts(
-            out, counts, attempt, lambda c: int(np.max(np.asarray(c)))
+        counts_b = _read_counts(out, counts, attempt, np.asarray)
+        maxc = [int(np.max(c)) for c in counts_b]
+        note_cap_occupancy(
+            "device", len(live) * sum(caps), sum(int(np.sum(c)) for c in counts_b)
         )
         over = [j for j, c in enumerate(maxc) if c > caps[j]]
         if not over:

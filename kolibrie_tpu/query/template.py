@@ -50,6 +50,7 @@ __all__ = [
     "CapAdvisor",
     "cap_advisor",
     "cap_advisor_enabled",
+    "note_cap_occupancy",
     "occupancy_pct",
 ]
 
@@ -227,6 +228,40 @@ cap_retry_seconds = metrics.counter(
     labels=("engine",),
 )
 cap_retry_seconds.labels("device")  # the mesh path counts retries, not yet their seconds
+# how the capacity rule engages: per dispatch, the join and WCOJ-level
+# slots its executable was compiled for and the rows the counts read back
+# (rows / slots is the occupancy; optimizer/device_engine.py fit_join_caps)
+_CAP_SLOTS = metrics.counter(
+    "kolibrie_device_cap_slots_total",
+    "join and WCOJ-level slots the dispatched executables were compiled "
+    "for, summed over dispatches, by engine",
+    labels=("engine",),
+)
+_JOIN_ROWS = metrics.counter(
+    "kolibrie_device_join_rows_total",
+    "rows the join and WCOJ-level counts read back, summed over "
+    "dispatches, by engine",
+    labels=("engine",),
+)
+_CAP_SLOTS.labels("device")
+_JOIN_ROWS.labels("device")
+# what a template's calibrated start costs: wall seconds of the numpy twin
+# on a template's first sight, by whether it counted or gave up at the
+# row limit (then the first device run's counts calibrate)
+cap_calibrate_seconds = metrics.counter(
+    "kolibrie_cap_calibrate_seconds_total",
+    "wall seconds of host calibration passes (a template's first sight "
+    "on a db), by outcome",
+    labels=("outcome",),
+)
+cap_calibrate_seconds.labels("counted")
+cap_calibrate_seconds.labels("too_large")
+
+
+def note_cap_occupancy(engine: str, slots: int, rows: int) -> None:
+    """One dispatch ran ``slots`` join slots and counted ``rows`` rows."""
+    _CAP_SLOTS.labels(engine).inc(slots)
+    _JOIN_ROWS.labels(engine).inc(rows)
 
 
 def cap_advisor_enabled() -> bool:

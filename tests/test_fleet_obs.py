@@ -420,9 +420,16 @@ def test_e2e_trace_propagation_router_replica_primary(fleet):
     assert headers["X-Kolibrie-Replica"] == "fol"
     assert headers["X-Kolibrie-Trace-Id"] == tid
 
-    # the router's own ring: request span + one forward span per rung
-    router_spans = spans_snapshot(tid)
-    names = [s["name"] for s in router_spans]
+    # the router's own ring: request span + one forward span per rung.
+    # The router writes the reply before it leaves ``router.request``, so
+    # the second request's span may still be open: wait for it to close
+    deadline = time.monotonic() + 5.0
+    while True:
+        router_spans = spans_snapshot(tid)
+        names = [s["name"] for s in router_spans]
+        if names.count("router.request") >= 2 or time.monotonic() > deadline:
+            break
+        time.sleep(0.01)
     assert names.count("router.request") == 2
     forwards = [s for s in router_spans if s["name"] == "router.forward"]
     by_attempt = {
