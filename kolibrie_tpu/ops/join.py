@@ -78,15 +78,15 @@ def equi_join_tables(
     return out
 
 
-class JoinTooLarge(Exception):
-    """``join_indices`` counted more pairs than its caller allowed."""
+class RowLimitExceeded(Exception):
+    """A host evaluation counted more rows than its caller allowed."""
 
 
 def join_indices(
     lkey: np.ndarray, rkey: np.ndarray, max_rows: Optional[int] = None
 ) -> Tuple[np.ndarray, np.ndarray]:
     """Row-index pairs (li, ri) with lkey[li] == rkey[ri] — sort-based.
-    With ``max_rows`` a larger result raises :class:`JoinTooLarge` after
+    With ``max_rows`` a larger result raises :class:`RowLimitExceeded` after
     the count and before any pair is materialized."""
     order = np.argsort(rkey, kind="stable")
     rsorted = rkey[order]
@@ -95,7 +95,7 @@ def join_indices(
     counts = hi - lo
     total = int(counts.sum())
     if max_rows is not None and total > max_rows:
-        raise JoinTooLarge(total)
+        raise RowLimitExceeded(total)
     if total == 0:
         z = np.empty(0, dtype=np.int64)
         return z, z

@@ -163,10 +163,6 @@ _CAP_FLOOR = 1024
 _CALIBRATE_ROW_LIMIT = 8_000_000
 
 
-class _CalibrationTooLarge(Exception):
-    """An intermediate of the host calibration pass exceeded the row limit."""
-
-
 def fit_join_caps(heuristic: Sequence[int], counts: Sequence[int]) -> List[int]:
     """THE capacity rule, per join and per WCOJ level:
     ``min(heuristic, round_cap(max(H x count, FLOOR)))``.  Every path that
@@ -2187,13 +2183,14 @@ class LoweredPlan:
         """Exact per-join counts of this variant from the numpy twin (no
         device I/O), or ``None`` where an intermediate would pass
         ``_CALIBRATE_ROW_LIMIT`` rows."""
+        from kolibrie_tpu.ops.join import RowLimitExceeded
         from kolibrie_tpu.query.template import cap_calibrate_seconds
 
         t0 = _time.perf_counter()
         try:
             _table, counts = self.host_execute(row_limit=_CALIBRATE_ROW_LIMIT)
             outcome = "counted"
-        except _CalibrationTooLarge:
+        except RowLimitExceeded:
             counts, outcome = None, "too_large"
         cap_calibrate_seconds.labels(outcome).inc(_time.perf_counter() - t0)
         return counts
@@ -2283,8 +2280,9 @@ class LoweredPlan:
         join capacities without any device readback (benchmarks time a
         never-read executable) and as the oracle in spec-semantics tests.
         With ``row_limit`` a scan, join or WCOJ level of more rows raises
-        :class:`_CalibrationTooLarge` before it is materialized."""
-        from kolibrie_tpu.ops.join import JoinTooLarge
+        :class:`kolibrie_tpu.ops.join.RowLimitExceeded` before it is
+        materialized."""
+        from kolibrie_tpu.ops.join import RowLimitExceeded
         from kolibrie_tpu.ops.join import join_indices as host_join_indices
 
         if not self.const_ok():
@@ -2302,13 +2300,7 @@ class LoweredPlan:
 
         def check_rows(n: int) -> None:
             if row_limit is not None and n > row_limit:
-                raise _CalibrationTooLarge(n)
-
-        def join_pairs(lkey, rkey):
-            try:
-                return host_join_indices(lkey, rkey, max_rows=row_limit)
-            except JoinTooLarge as exc:
-                raise _CalibrationTooLarge(*exc.args) from None
+                raise RowLimitExceeded(n)
 
         def eval_expr(expr, cols) -> np.ndarray:
             if isinstance(expr, MaskRef):
@@ -2412,7 +2404,7 @@ class LoweredPlan:
                     list(node.key_vars),
                     len(next(iter(lcols.values()))),
                 )
-                li, ri = join_pairs(lkey, rkey)
+                li, ri = host_join_indices(lkey, rkey, max_rows=row_limit)
                 counts[node.join_idx] = len(li)
                 hstats[f"join{node.join_idx}"] = len(li)
                 out = {v: c[li] for v, c in lcols.items()}
@@ -2491,7 +2483,7 @@ class LoweredPlan:
                 lkey, rkey = _pack_shared_keys(
                     lcols, rcols, list(node.key_vars), ln
                 )
-                li, ri = join_pairs(lkey, rkey)
+                li, ri = host_join_indices(lkey, rkey, max_rows=row_limit)
                 counts[node.join_idx] = len(li)
                 matched = np.zeros(ln, dtype=bool)
                 matched[li] = True
