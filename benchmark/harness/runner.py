@@ -1,14 +1,16 @@
 """One run of one cell: set-up, window, comparison, result line.
 
 One process, the only one that touches JAX.  The real server runs in a
-thread; the client is this thread.  Everything that belongs to one cell is
-found by name under ``benchmark/`` (see README.md).
+thread; the clients are the load generator's, a process of its own.
+Everything that belongs to one cell is found by name under ``benchmark/``
+(see README.md).
 """
 
 import gc
 import json
 import multiprocessing
 import os
+import re
 import shutil
 import sys
 import threading
@@ -24,6 +26,7 @@ from .client import Client
 RING_CAPACITY = 2_000_000  # spans; the program's default is 4096
 NO_CHIP_EXIT = 3
 ANSWER_TIMEOUT_S = 1200  # longer than any request's deadline
+COLLECTIVE = re.compile(r"^(all|collective|reduce[-_]scatter|send|recv)")  # op names
 
 
 def say(**obj) -> None:
@@ -47,7 +50,53 @@ def _counters(cl) -> dict:
         out["compile." + k] = float(v)
     for k, v in compile_cache.counters().items():
         out["compile_cache." + k] = float(v)
+    # the mesh layer's executables, where the program has loaded that layer
+    # (a store served from one chip never does)
+    mesh_layer = sys.modules.get("kolibrie_tpu.parallel.sharded_serving")
+    if mesh_layer is not None:
+        for k, v in mesh_layer.sharded_compile_stats().items():
+            out["compile.sharded." + k] = float(v)
     return out
+
+
+def _mesh_proof(check, db, stats, config, requests, counters0, counters1):
+    """For a cell on several chips: the mesh, and not device 0 alone, served
+    the window (from ``chip_smoke.py``'s ``check_mesh``)."""
+
+    def total(counters, name):
+        return sum(v for k, v in counters.items()
+                   if k.startswith("metrics." + name))
+
+    def grew(name):
+        return total(counters1, name) - total(counters0, name)
+
+    chips = config["chips"]
+    sh = db.__dict__.get("_sharded_serving")
+    spans_chips = int(sh.mesh.devices.size) if sh is not None else 0
+    check("store_attached_to_a_mesh_over_the_chips", spans_chips, chips,
+          spans_chips == chips)
+    errors = total(counters1, "kolibrie_shard_attach_errors_total")
+    check("shard_attach_errors", errors, 0, errors == 0)
+    on_devices = 0
+    if sh is not None and sh.view is not None:
+        view = sh.view
+        arrays = [*view.by_subj, view.by_subj_valid, *view.by_obj, view.by_obj_valid]
+        on_devices = min(len({s.device.id for s in a.addressable_shards
+                              if s.data.size}) for a in arrays)
+    check("mirror_arrays_on_distinct_devices", on_devices, chips,
+          on_devices == chips)
+    fallbacks = grew("kolibrie_shard_fallback_total")
+    check("shard_fallbacks_in_window", fallbacks, 0, fallbacks == 0)
+    # the rest are stragglers that missed the batcher's window and were
+    # served alone from device 0; a store without the mesh serves 0
+    served = grew("kolibrie_shard_queries_total")
+    least = config["layout"]["served_by_the_mesh_at_least"] * len(requests)
+    check("window_requests_served_by_the_mesh", served, f">={least:g}",
+          served >= least)
+    # not part of ``correct``: a capacity retry leaves the answers exact
+    say(phase="mesh", dispatches_in_window=grew("kolibrie_shard_dispatch_total"),
+        cap_hits_in_window=grew("kolibrie_shard_exchange_cap_hits_total"),
+        stats=stats.get("sharding"))
 
 
 def run_cell(workload, seed, seconds, trace, t_start, scale=None,
@@ -158,12 +207,14 @@ def _serve_and_measure(conn, bench, cell, config, seed, seconds, trace,
               db.execution_mode == config["store_mode"])
         check("scale_as_configured", scale, None, scale is None)
 
-        # warm every template of the cycle, as often as the traffic file
-        # says, so the cap advisor's re-runs and every compile are over
+        # warm every template of the cycle, as often and with as many
+        # clients as the traffic file says, so the cap advisor's re-runs and
+        # every compile are over
+        clients = generated["clients"]
         t0, got = time.perf_counter(), {"ms": 0.0}
-        for k in range(generated["warmup_cycles"]):
-            got = ask("cycle", k, "warmup")
-            say(phase="warmup", cycle=k, ms=got["ms"],
+        for k, n in enumerate(generated["warmup_counts"]):
+            got = ask("cycle", k, "warmup", n)
+            say(phase="warmup", cycle=k, clients=n, ms=got["ms"],
                 statuses=sorted({r["status"] for r in got["requests"]}))
         phases["warmup"] = time.perf_counter() - t0
 
@@ -175,9 +226,10 @@ def _serve_and_measure(conn, bench, cell, config, seed, seconds, trace,
             prog_spans.clear()
         counters0 = _counters(cl)
 
-        # ---- the window: whole cycles, one client, closed loop.  The first
-        # cycle is always sent; a further one only if the longest cycle seen
-        # so far would still end inside ``seconds``
+        # ---- the window: whole cycles, closed loop.  One client: the first
+        # cycle is always sent, a further one only if the longest cycle seen
+        # so far would still end inside ``seconds``.  Several clients run
+        # free, each by that rule (``loadgen.free_run``)
         requests, cycles, anchors = [], [], []
         tracing, traced_s, t_trace = "before", 0.0, 0.0
         longest_ms = got["ms"]
@@ -185,37 +237,61 @@ def _serve_and_measure(conn, bench, cell, config, seed, seconds, trace,
         # window holds no second one
         first_traced = 1 if 2 * longest_ms < seconds * 1000.0 else 0
         gc_runs = [s["collections"] for s in gc.get_stats()]
+
+        def start_trace():
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(trace_dir, profiler_options=opts)
+            # the anchor between the wall clock and the trace's clock
+            anchors.append(time.time())
+            return "on", time.perf_counter()
+
+        def note(got):
+            requests.extend(got["requests"])
+            if got["t1"] is not None:  # a whole cycle
+                cycles.append({"k": len(cycles), "ms": got["ms"], "t0": got["t0"],
+                               "t1": got["t1"],
+                               "trace_ids": [r["trace_id"] for r in got["requests"]]})
+
+        def stop_trace():
+            jax.profiler.stop_trace()
+            return "done", time.perf_counter() - t_trace
+
         setup_s = time.perf_counter() - t_start
         t_open = time.perf_counter()
-        k = 0
-        while k == 0 or time.perf_counter() - t_open + longest_ms / 1000.0 < seconds:
-            if trace and tracing == "before" and k >= first_traced:
-                opts = jax.profiler.ProfileOptions()
-                opts.python_tracer_level = 0
-                jax.profiler.start_trace(trace_dir, profiler_options=opts)
-                tracing, t_trace = "on", time.perf_counter()
-            if tracing == "on":
-                # the traced window, and the anchor between the wall clock
-                # and the trace's clock
-                anchors.append(time.time())
+        if clients > 1:
+            # the clients run free for the whole window; the trace holds its
+            # first ``trace_min_seconds``, or all of it
+            if trace:
+                tracing, t_trace = start_trace()
+            conn.send(("free", "window", seconds))
+            if trace:
                 with jax.profiler.TraceAnnotation(xplane.ANNOTATION):
-                    got = ask("cycle", k, "window")
+                    conn.poll(generated["trace_min_seconds"])
+                tracing, traced_s = stop_trace()
+            for got in answer():
+                note(got)
+        k = 0
+        while clients == 1 and (k == 0 or time.perf_counter() - t_open
+                                + longest_ms / 1000.0 < seconds):
+            if trace and tracing == "before" and k >= first_traced:
+                tracing, t_trace = start_trace()
+            elif tracing == "on":
+                anchors.append(time.time())
+            if tracing == "on":
+                # the traced window: one annotation a traced cycle
+                with jax.profiler.TraceAnnotation(xplane.ANNOTATION):
+                    got = ask("cycle", k, "window", clients)
             else:
-                got = ask("cycle", k, "window")
-            requests += got["requests"]
-            cycles.append({"k": k, "ms": got["ms"], "t0": got["t0"], "t1": got["t1"],
-                           "trace_ids": [r["trace_id"] for r in got["requests"]]})
+                got = ask("cycle", k, "window", clients)
+            note(got)
             longest_ms = max(longest_ms, got["ms"]) if k else got["ms"]
             k += 1
             if tracing == "on" and time.perf_counter() - t_trace >= generated[
                     "trace_min_seconds"]:
-                traced_s = time.perf_counter() - t_trace
-                jax.profiler.stop_trace()
-                tracing = "done"
+                tracing, traced_s = stop_trace()
         if tracing == "on":
-            traced_s = time.perf_counter() - t_trace
-            jax.profiler.stop_trace()
-            tracing = "done"
+            tracing, traced_s = stop_trace()
         window_s = time.perf_counter() - t_open
         counters1 = _counters(cl)
         # every request carries a trace id, so the program's ring holds the
@@ -272,13 +348,15 @@ def _serve_and_measure(conn, bench, cell, config, seed, seconds, trace,
     check("no_breaker_open_or_failed", bad_breakers, {}, not bad_breakers)
     check("only_http_200", {str(s): n for s, n in sorted(statuses.items())},
           "only 200", set(statuses) == {200})
+    if cell["chips"] > 1:
+        _mesh_proof(check, db, stats, config, requests, counters0, counters1)
 
     run = {"cycles": cycles, "requests": requests, "setup_s": setup_s}
     by_template = {}
     for r in requests:
         by_template.setdefault(r["template"], []).append(r["ms"])
     say(phase="window", window_s=window_s, whole_cycles=len(cycles),
-        requests=len(requests),
+        clients=clients, requests=len(requests),
         seconds=seconds, cycle_ms_median=(
             sorted(c["ms"] for c in cycles)[len(cycles) // 2] if cycles else None),
         cycle_ms_all=[c["ms"] for c in cycles][:64],
@@ -288,6 +366,23 @@ def _serve_and_measure(conn, bench, cell, config, seed, seconds, trace,
         phases=phases, reference_s=reference_s,
         compile_counters={k: v for k, v in counters1.items()
                           if k.startswith("compile")})
+
+    if clients > 1:
+        # how the batcher grouped each cycle's clients, where the ring still
+        # holds the spans: the sizes of its dispatches, and the time from the
+        # first to the last of the cycle's first requests reaching it
+        groups, arrived = {}, {}
+        for sp in span_list:
+            if not sp["trace_id"].startswith("bench-window-"):
+                continue
+            k, step = sp["trace_id"].split("-")[2:4]
+            if sp["name"] == "batcher.dispatch":
+                groups.setdefault(int(k), []).append(sp.get("attrs", {}).get("batch"))
+            elif sp["name"] == "batcher.submit" and step == "0":
+                arrived.setdefault(int(k), []).append(sp["start_s"])
+        say(phase="groups", dispatch_sizes_by_cycle=groups,
+            first_requests_arrive_within_ms={
+                k: (max(v) - min(v)) * 1000.0 for k, v in arrived.items()})
 
     # where a far-off request spent its time: the spans of the window's
     # slowest request, where the ring still holds them
@@ -336,6 +431,8 @@ def _serve_and_measure(conn, bench, cell, config, seed, seconds, trace,
                 window_s=reduced["window_s"], devices=reduced["devices"],
                 device_lines=reduced["lines"],
                 custom_calls={n: s for n, s in reduced["any"].items() if ":" in n},
+                collectives={n: s for n, s in reduced["any"].items()
+                             if COLLECTIVE.search(n)},
                 self_time_top=sorted(reduced["self"].items(),
                                      key=lambda kv: -kv[1])[:25])
         check("device_ran_ops_in_trace", device.get("busy_s", 0.0), ">0",
@@ -348,4 +445,13 @@ def _serve_and_measure(conn, bench, cell, config, seed, seconds, trace,
               "failed": len(bad), "metrics": metrics, "device": device}
     if breakdown is not None:
         result["breakdown"] = breakdown
+    # each number compared beside its limit: last in the result line, and
+    # the last lines on standard error
+    result["checks"] = {c["check"]: {"value": c["value"], "limit": c["limit"],
+                                     "ok": c["ok"]} for c in checks}
+    for c in checks:
+        print(f"check {c['check']}: {json.dumps(c['value'], default=str)} "
+              f"(limit {json.dumps(c['limit'], default=str)}) "
+              f"{'ok' if c['ok'] else 'NOT OK'}{' (waived)' if c['waived'] else ''}",
+              file=sys.stderr, flush=True)
     return result, 0 if not failed else 1

@@ -5,7 +5,9 @@
 2. The trace reduction gives the known busy, window and per-op totals on the
    small recorded trace kept under ``benchmark/data``.
 3. Every file ``BENCHMARK.json`` names exists and every name and unit uses
-   only the allowed characters.
+   only the allowed characters; a traffic file's ``clients`` and
+   ``warmup_ramp`` agree; a cell's ``chips`` is 1 or 4 and its
+   configuration's, and at most half the cells (one always) take four.
 """
 
 import json
@@ -14,6 +16,7 @@ import re
 
 from . import compare, xplane
 from . import data as files
+from .traffic import ramp_of
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
@@ -94,8 +97,24 @@ def check_files(problems):
             name_ok("workload " + key, w[key])
         need("workloads", w["name"] + ".json")
         if need("traffic", w["traffic"] + ".json"):
-            for step in files.read_json("traffic", w["traffic"] + ".json")["cycle"]:
+            spec = files.read_json("traffic", w["traffic"] + ".json")
+            for step in spec["cycle"]:
                 need("templates", step["template"] + ".rq")
+            try:
+                ramp_of(w["traffic"], spec)
+            except ValueError as e:
+                problems.append(str(e))
+        if w["chips"] not in (1, 4):
+            problems.append(f"cell {w['name']}: chips {w['chips']} is not 1 or 4")
+        if os.path.exists(files.path("configs", w["config"] + ".json")):
+            stated = files.read_json("configs", w["config"] + ".json").get("chips")
+            if stated != w["chips"]:
+                problems.append(f"cell {w['name']}: chips {w['chips']}, but its "
+                                f"configuration states {stated}")
+    four = [w["name"] for w in bench["workloads"] if w["chips"] == 4]
+    if len(four) > max(1, len(bench["workloads"]) // 2):
+        problems.append(f"{len(four)} of {len(bench['workloads'])} cells take four "
+                        f"chips, more than half: {four}")
     e2e_names = {m["name"] for m in bench["end_to_end"]}
     for m in bench["end_to_end"] + bench["per_layer"]:
         name_ok("metric", m["name"])

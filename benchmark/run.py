@@ -5,9 +5,9 @@
 
 Starts the real server in a thread, generates the configuration's data from
 ``--seed``, loads it through ``POST /store/load``, warms the cell's cycle,
-measures for ``--seconds`` with one client in a closed loop, compares every
-answer of the window with the plain reference and proves that the device
-served them.  The last line of standard output is the result; earlier lines
+measures for ``--seconds`` with the traffic file's clients in a closed loop,
+compares every answer of the window with the plain reference and proves that
+the device (for a cell on four chips: the mesh) served them.  The last line of standard output is the result; earlier lines
 are JSON too and free-form.  Without a TPU (or with fewer chips than the cell
 asks for) it prints no result and exits 3.
 

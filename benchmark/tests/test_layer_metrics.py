@@ -18,8 +18,10 @@ sys.path.insert(0, REPO)
 
 from benchmark.harness import data as files  # noqa: E402
 from benchmark.readers import counter_at_open  # noqa: E402
+from benchmark.tests import mesh4_entries  # noqa: E402
 
-PER_LAYER = files.read_json(os.pardir, "BENCHMARK.json")["per_layer"]
+# with the metrics that wait for the cell ``lubm5.mesh4`` (mesh4_entries.py)
+PER_LAYER = mesh4_entries.bench()["per_layer"]
 EMPTY_RUN = {"cycles": [], "spans_by_trace": {}, "counters0": {}, "counters1": {},
              "phases": {}, "quantities": {}, "trace": None}
 
@@ -51,3 +53,14 @@ def test_counter_at_open_sums_the_prefixed_counters_at_window_open():
     # a counter that never grew reads 0; one the program lacks reads nothing
     assert counter_at_open.read(ctx, [orders + '{order="osp"}']) == 0.0
     assert counter_at_open.read(ctx, ["metrics.kolibrie_cap_retry_seconds"]) is None
+
+
+def test_all_to_all_pct_matches_the_name_jax_gives_the_instruction():
+    """On the chip the instruction is ``%all_to_all.7 = ... all-to-all(...)``:
+    the trace reduction keeps the name, which has underscores."""
+    args = dict(files.read_json("layer_metrics", "all_to_all_pct.json")["reader"])
+    reader = files.load_module("readers", args.pop("kind"))
+    trace = {"busy_s": 10.0, "top": {"while.183": 10.0},
+             "any": {"while.183": 10.0, "all_to_all.7": 0.5, "all-to-all.2": 0.25,
+                     "all-reduce.27": 0.1, "fusion.226": 4.0}}
+    assert reader.read({"trace": trace}, **args) == 7.5
