@@ -12,7 +12,8 @@ grew.  Off-TPU every phase still runs (rehearsal), the last line says
 ``"ok": false`` and the exit code is 1 — no option turns that into a pass.
 
 ``--mesh`` (four chips, run by hand) runs ONLY the sharded-serving path and
-what it is compared with.
+what it is compared with: every request, alone or in a group, has to be
+served from the partitioned store.
 
 Every output line is one JSON object.  Times are smoke observations taken
 on the host clock around whole HTTP requests, not benchmark numbers.  The
@@ -401,22 +402,27 @@ def run(args, n_univ, device, httpd, check) -> None:
     # (the fused on-device GROUP BY dispatches run()/converge() itself and
     # leaves no source: None is its device signature, a host fallback
     # would have left a sticky failure or a degraded count above)
-    check("solo_templates_served_by_compiled_plans",
-          all(sources.get(name) in ("compiled", "disk")
-              or (name == "group_count" and sources.get(name) is None)
-              for name, _ in solo),
-          sources=sources)
+    # (under an attached mesh every request of a supported shape is served
+    # from the partitioned store, alone or in a group: no single-device
+    # plan runs, so none is asked for; check_mesh counts them instead)
+    if not args.mesh:
+        check("solo_templates_served_by_compiled_plans",
+              all(sources.get(name) in ("compiled", "disk")
+                  or (name == "group_count" and sources.get(name) is None)
+                  for name, _ in solo),
+              sources=sources)
     bad = {fp: b for fp, b in stats["breakers"].items()
            if b["state"] != "closed" or b["total_failures"]}
     check("no_breaker_open_or_failed", not bad, breakers=bad)
     check("only_http_200", set(cl.statuses) == {200},
           statuses={str(k): v for k, v in cl.statuses.items()})
-    check("run_plan_compiled",
-          compiles1["run_plan"] > compiles0["run_plan"],
-          before=compiles0["run_plan"], after=compiles1["run_plan"])
     if args.mesh:
-        check_mesh(db, stats, metrics1, check)
+        check_mesh(db, stats, metrics1, check, n_sent,
+                   delta("kolibrie_shard_queries_total"))
     else:
+        check("run_plan_compiled",
+              compiles1["run_plan"] > compiles0["run_plan"],
+              before=compiles0["run_plan"], after=compiles1["run_plan"])
         check("run_plan_batch_compiled",
               compiles1["run_plan_batch"] > compiles0["run_plan_batch"],
               before=compiles0["run_plan_batch"],
@@ -447,9 +453,11 @@ def check_q9_lowering(db, q9: str, check) -> None:
           lowered_chars=len(text))
 
 
-def check_mesh(db, stats, metrics_text, check) -> None:
+def check_mesh(db, stats, metrics_text, check, n_sent, served) -> None:
     import jax
 
+    check("every_query_served_by_the_mesh", served == n_sent,
+          served=served, sent=n_sent)
     sh = db.__dict__.get("_sharded_serving")
     check("four_devices", jax.device_count() == 4, count=jax.device_count())
     check("store_has_sharded_attachment", sh is not None)

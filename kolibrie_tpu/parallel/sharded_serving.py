@@ -3,8 +3,9 @@
 ROADMAP item 1 closes here: the serving path (http_server -> TemplateBatcher
 -> executor) gains a :class:`ShardedDatabase` that keeps the two-tier store
 (frozen base + delta segment + tombstones, ``core/store.py``) hash-partitioned
-across the device mesh and device-RESIDENT, so a batched same-template query
-group becomes ONE ``shard_map`` dispatch instead of B single-device programs.
+across the device mesh and device-RESIDENT, so a same-template query group —
+of one member or of many — becomes ONE ``shard_map`` dispatch instead of B
+single-device programs.
 
 Three design rules, inherited from the systems this reproduces (MapSQ's
 partition-match-merge split, arXiv:1702.03484; GPU Datalog's resident
@@ -17,13 +18,22 @@ relations + delta-only transfer, arXiv:2311.02206):
    reassembled on device (:func:`_assemble`), so shapes — and therefore every
    compiled serving program — survive sustained insert/delete traffic with
    ZERO recompiles.
-2. **One dispatch per template group.**  Same-template queries differ only in
-   constants (``query/template.py``); the batched program moves those
-   constants into a traced ``[B, n_slots]`` parameter matrix and evaluates the
-   whole group with ``lax.map`` INSIDE one ``shard_map`` body — per member:
+2. **One dispatch per template group, one executable per template.**
+   Same-template queries differ only in constants (``query/template.py``);
+   the batched program moves those constants into a traced
+   ``[slots, n_slots]`` parameter matrix and takes the number of live
+   members beside it as a traced scalar, replicated over the mesh.  A loop
+   INSIDE one ``shard_map`` body runs the live members only — per member:
    shard-local seed scan, fixed-cap ``all_to_all`` binding-table exchange,
-   local joins, replicated filter masks.  The host merge
-   (``_finish_select_table``) is deterministic and identical to the solo path.
+   local joins, replicated filter masks — so a dispatch costs what its
+   live members cost, and a group of one is a group like any other: under
+   an attached mesh the executor sends every request of a supported shape
+   here (no device holds the whole store in the deployment this stands
+   for).  ``slots`` is a class (a power of two, not below 8), not the
+   group size, so a template has one executable per capacity pair for
+   every group up to the class.  The host merge pulls the live members'
+   rows only and is deterministic and identical to the solo path
+   (``_finish_select_table``).
 3. **Cross-cutting layers ride the shard hop.**  Deadlines are checked before
    dispatch (``shard.dispatch`` is also a fault-injection site), per-template
    breakers gate the group in the executor, per-shard span children and
@@ -61,6 +71,7 @@ from kolibrie_tpu.parallel.dist_join import (
 )
 from kolibrie_tpu.parallel.mesh import make_mesh
 from kolibrie_tpu.parallel.sharded_store import ShardedTripleStore, shard_of
+from kolibrie_tpu.query.template import cap_advisor, fingerprint_query
 from kolibrie_tpu.resilience.deadline import check_deadline
 from kolibrie_tpu.resilience.faultinject import fault_point
 from kolibrie_tpu.reasoner.device_fixpoint import Unsupported
@@ -77,11 +88,17 @@ __all__ = [
 # ------------------------------------------------------------------ metrics
 _SHARD_DISPATCH = _m.counter(
     "kolibrie_shard_dispatch_total",
-    "Mesh serving dispatches by path",
+    "Mesh serving dispatches by path (batched: a group of several; lone: "
+    "a group of one; solo: ShardedDatabase.execute)",
     labels=("path",),
 )
 _SHARD_QUERIES = _m.counter(
     "kolibrie_shard_queries_total", "Queries served through the mesh path"
+)
+_SHARD_MEMBER_SLOTS = _m.counter(
+    "kolibrie_shard_member_slots_total",
+    "Member slots the dispatched mesh executables were compiled for "
+    "(queries_total over this is the member loop's occupancy)",
 )
 _SHARD_ROWS = _m.counter(
     "kolibrie_shard_rows_scanned_total",
@@ -188,6 +205,7 @@ def _batched_body(
     state,
     masks,
     params,
+    live,
     *,
     premises,
     seed,
@@ -199,18 +217,24 @@ def _batched_body(
     join_cap,
     bucket_cap,
 ):
-    """One template group in one mesh program: ``lax.map`` over the
-    ``[B, n_slots]`` constant matrix, each member running the shard-local
-    scan -> routed-join -> filter pipeline of ``dist_query._query_body``.
-    Premise ``consts`` here hold SLOT INDICES into the parameter vector
-    (the template's constant-free twin), so every constant-variant of the
-    template shares this one executable."""
+    """One template group in one mesh program: a loop over the first
+    ``live`` rows of the ``[slots, n_slots]`` constant matrix, each member
+    running the shard-local scan -> routed-join -> filter pipeline of
+    ``dist_query._query_body`` and writing its outputs into row ``i`` of
+    preallocated ``[slots, ...]`` buffers.  ``live`` is a traced scalar,
+    replicated over the mesh: every shard runs the same trips, so the
+    ``all_to_all`` / ``psum`` inside the loop stay matched, and a group of
+    any size up to ``slots`` shares this one executable at the cost of its
+    live members.  Rows past ``live`` stay invalid (zero) and are never
+    read.  Premise ``consts`` here hold SLOT INDICES into the parameter
+    vector (the template's constant-free twin), so every constant-variant
+    of the template shares the executable too."""
     fs, fp, fo, fv, gs, gp, go, gv = (a[0] for a in state)
     masks = tuple(masks)
     fcols = (fs, fp, fo)
 
-    # Hoisted per-step side sorts: every lax.map member joins against the
-    # same resident mirror, so the right-side argsort is loop-invariant —
+    # Hoisted per-step side sorts: every member joins against the same
+    # resident mirror, so the right-side argsort is loop-invariant —
     # sort once per dispatch, not once per member.  The side premise's
     # constant filters (which DO vary per member) apply post-join at the
     # matched rows instead of pre-masking the sort input.
@@ -243,7 +267,7 @@ def _batched_body(
         ov = jnp.int32(0)
         table, valid = scan_param(premises[seed], fcols, fv, prm)
         # Per-operator stats, SHARD-LOCAL (no psum: the host sees the
-        # [B, n, n_stats] block and can read imbalance per shard or sum
+        # [slots, n, n_stats] block and can read imbalance per shard or sum
         # across shards).  Layout: [seed rows, (exchange rows, join
         # rows) per step, final rows] — exchange slot stays 0 when the
         # step's all-to-all is elided by co-partitioning.
@@ -306,7 +330,24 @@ def _batched_body(
         outs = tuple(jnp.where(valid, table[v], 0) for v in out_vars)
         return outs, valid, ov, jnp.stack(svec)
 
-    outs, valid, ovs, svecs = lax.map(one, params)
+    # The live-member loop.  The carry is typed from one member's outputs
+    # (shapes, dtypes and which of them vary over the mesh axis), so the
+    # zero buffers enter the loop as what the body writes back.
+    def buffer(aval):
+        buf = jnp.zeros((params.shape[0], *aval.shape), aval.dtype)
+        vary = tuple(getattr(aval, "vma", ()) or ())
+        return lax.pcast(buf, vary, to="varying") if vary else buf
+
+    def member(i, bufs):
+        return jax.tree.map(
+            lambda buf, x: lax.dynamic_update_index_in_dim(buf, x, i, 0),
+            bufs,
+            one(lax.dynamic_index_in_dim(params, i, 0, keepdims=False)),
+        )
+
+    outs, valid, ovs, svecs = lax.fori_loop(
+        0, live, member, jax.tree.map(buffer, jax.eval_shape(one, params[0]))
+    )
     overflow = jnp.sum(ovs)  # each member's ov is already a global psum
     return (
         tuple(o[:, None] for o in outs),
@@ -317,14 +358,25 @@ def _batched_body(
 
 
 # Memoized program factory (the sanctioned jit-factory pattern) — the key
-# is the template's constant-free shape, so constant-variants and mutation
-# epochs share one executable.
+# is the template's constant-free shape, its capacity pair and the slot
+# class, so constant-variants, mutation epochs and every group size up to
+# ``slots`` share one executable.
+
+_MIN_SLOTS = 8
+
+
+def _slot_class(members: int) -> int:
+    """Rows of the parameter matrix a group of ``members`` is dispatched
+    in: a power of two, not below :data:`_MIN_SLOTS`.  A class costs
+    memory (the ``[slots, ...]`` output buffers), not time — the loop runs
+    the live members only."""
+    return max(_MIN_SLOTS, 1 << max(members - 1, 0).bit_length())
 
 
 @lru_cache(maxsize=64)
 def _get_batched_fn(
     mesh, premises, seed, steps, filters, out_vars, n_masks, join_cap,
-    bucket_cap, b_pad,
+    bucket_cap, slots,
 ):
     _compile_stats["batched_programs"] += 1
     axis = mesh.axis_names[0]
@@ -345,12 +397,24 @@ def _get_batched_fn(
     bspec = P(None, axis, None)
     return jax.jit(
         jax.shard_map(
-            lambda state, masks, params: body(state, masks, params),
+            body,
             mesh=mesh,
             check_vma=_dist_check_vma(),
-            in_specs=((spec,) * 8, (P(),) * n_masks, P()),
+            in_specs=((spec,) * 8, (P(),) * n_masks, P(), P()),
             out_specs=((bspec,) * len(out_vars), bspec, P(axis), bspec),
         )
+    )
+
+
+@jax.jit
+def _member_rows(arrays, i):
+    """Row ``i`` of each ``[slots, ...]`` output.  The host merge pulls the
+    live members' rows one by one, so the transfer follows the live
+    members as the loop does (a whole slot class is 300 MB at LUBM(5)'s
+    capacities, whatever the group).  ``i`` is traced: one executable a
+    capacity pair."""
+    return jax.tree.map(
+        lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False), arrays
     )
 
 
@@ -643,15 +707,23 @@ class ShardedDatabase:
 
     def warm(self, sparql: str) -> bool:
         """Pre-compile the mesh program for one template off the request
-        path (the background warmer's entry point).  A solo dispatch
-        lowers and jits the same parameterized shard_map program
-        ``execute_batch`` will run — with the persistent compilation
-        cache enabled the XLA work is a disk load on every process after
-        the first.  Returns False (instead of raising) for templates the
-        distributed lowering declines: the warmer treats that as "this
-        template serves single-device" and moves on."""
+        path (the background warmer's entry point).  The query is
+        dispatched as a group of one through :meth:`execute_batch`, so it
+        lowers and jits the very executable that every later request of
+        the template runs, alone or in a group (one per template and
+        capacity pair), and the capacities settle here too — with the
+        persistent compilation cache enabled the XLA work is a disk load
+        on every process after the first.  Returns False (instead of
+        raising) for templates the mesh lowering declines: the warmer
+        treats that as "this template serves single-device" and moves
+        on."""
+        from kolibrie_tpu.query.parser import parse_combined_query
+
+        fp, _ = fingerprint_query(
+            parse_combined_query(sparql, self.db.prefixes)
+        )
         try:
-            self.execute(sparql)
+            self.execute_batch(fp, [(0, sparql)])
         except Unsupported:
             return False
         with self.lock:
@@ -665,258 +737,289 @@ class ShardedDatabase:
     ) -> Dict[int, List[List[str]]]:
         """One template group -> one mesh dispatch.  ``items`` is
         ``[(caller_index, sparql), ...]`` of same-fingerprint plain
-        SELECTs; returns ``{caller_index: rows}`` with rows identical to
-        the solo host path.  Raises :class:`Unsupported` when the group
-        cannot ride the parameterized program (the caller falls through
-        to the single-device vmap path), and lets device faults /
-        deadline misses propagate for the breaker protocol."""
+        SELECTs, one member or many; returns ``{caller_index: rows}``
+        with rows identical to the solo host path.  The group is
+        dispatched in the slot class of its size (:func:`_slot_class`) and
+        costs what its live members cost.  Raises :class:`Unsupported`
+        when the group cannot ride the parameterized program (the caller
+        falls through to the single-device paths), and lets device faults
+        / deadline misses propagate for the breaker protocol."""
+        with self.lock:
+            self.refresh()
+            check_deadline("shard.dispatch")
+            live = len(items)
+            t0 = time.perf_counter()
+            with span(
+                "shard.dispatch", shards=self.n, batch=live, template=fp
+            ):
+                with span("shard.build"):
+                    group = self._build_group(fp, items)
+                fault_point("shard.dispatch")
+                with span(
+                    "shard.wait", slots=group["params"].shape[0], live=live
+                ) as sp:
+                    device_out = self._run_group(fp, group, sp)
+                with span("shard.merge"):
+                    results = self._merge_group(fp, items, group, device_out)
+            _SHARD_DISPATCH_LAT.observe(time.perf_counter() - t0)
+            self._count_dispatch(fp, group)
+            return results
+
+    def _decline(self, reason: str) -> None:  # kolint: holds[lock]
+        self.stats_counters["fallbacks"] += 1
+        _SHARD_FALLBACKS.labels(reason).inc()
+
+    def _build_group(self, fp: str, items) -> dict:  # kolint: holds[lock]
+        """Host side of a dispatch before the program: the members'
+        lowerings, their structural agreement, the ``[slots, n_slots]``
+        parameter matrix and the replicated filter masks."""
         from kolibrie_tpu.parallel.dist_query import (
             DistQueryExecutor,
             _materialize_masks,
         )
         from kolibrie_tpu.reasoner.device_fixpoint import LoweredPremise
 
-        from kolibrie_tpu.query.template import cap_advisor
-
-        with self.lock:
-            self.refresh()
-            check_deadline("shard.dispatch")
-            caps = self._pinned_caps(fp)
-            if caps is None:
-                # base-version bump dropped the pinned caps (mutation
-                # workloads do this constantly) — start from the advisor's
-                # process-wide high-water mark instead of the static
-                # defaults, so steady state re-dispatches without a single
-                # doubled-cap retry
-                advised = cap_advisor.advise("sharded", fp)
-                if advised is not None and len(advised) == 2:
-                    caps = (int(advised[0]), int(advised[1]))
-            kw = (
-                {"join_cap": caps[0], "bucket_cap": caps[1]}
-                if caps
-                else {}
+        caps = self._pinned_caps(fp)
+        if caps is None:
+            # base-version bump dropped the pinned caps (mutation
+            # workloads do this constantly) — start from the advisor's
+            # process-wide high-water mark instead of the static
+            # defaults, so steady state re-dispatches without a single
+            # doubled-cap retry
+            advised = cap_advisor.advise("sharded", fp)
+            if advised is not None and len(advised) == 2:
+                caps = (int(advised[0]), int(advised[1]))
+        kw = {"join_cap": caps[0], "bucket_cap": caps[1]} if caps else {}
+        try:
+            exemplar = DistQueryExecutor(
+                self.mesh, self.db, items[0][1], store=self.view, **kw
             )
-            try:
-                exemplar = DistQueryExecutor(
-                    self.mesh, self.db, items[0][1], store=self.view, **kw
+        except Unsupported:
+            self._decline("unsupported")
+            raise
+        if (
+            exemplar.agg_items
+            or exemplar.query.group_by
+            or exemplar.binds
+            or exemplar.union_specs
+            or exemplar.optional_specs
+            or exemplar.anti
+            or exemplar.values_var is not None
+            or exemplar.query.order_by
+        ):
+            # _batchable_select should have filtered these; belt and
+            # braces for direct callers
+            self._decline("shape")
+            raise Unsupported("clause shape stays on the vmap path")
+        execs = [exemplar]
+        for _idx, text in items[1:]:
+            execs.append(
+                DistQueryExecutor(
+                    self.mesh,
+                    self.db,
+                    text,
+                    store=self.view,
+                    join_cap=exemplar.join_cap,
+                    bucket_cap=exemplar.bucket_cap,
                 )
-            except Unsupported:
-                self.stats_counters["fallbacks"] += 1
-                _SHARD_FALLBACKS.labels("unsupported").inc()
-                raise
-            if (
-                exemplar.agg_items
-                or exemplar.query.group_by
-                or exemplar.binds
-                or exemplar.union_specs
-                or exemplar.optional_specs
-                or exemplar.anti
-                or exemplar.values_var is not None
-                or exemplar.query.order_by
-            ):
-                # _batchable_select should have filtered these; belt and
-                # braces for direct callers
-                self.stats_counters["fallbacks"] += 1
-                _SHARD_FALLBACKS.labels("shape").inc()
-                raise Unsupported("clause shape stays on the vmap path")
-            execs = [exemplar]
-            for _idx, text in items[1:]:
-                execs.append(
-                    DistQueryExecutor(
-                        self.mesh,
-                        self.db,
-                        text,
-                        store=self.view,
-                        join_cap=exemplar.join_cap,
-                        bucket_cap=exemplar.bucket_cap,
+            )
+        # structural agreement: the group shares one constant-free
+        # shape; filter constants must MATCH (the single-device vmap
+        # path parameterizes those — this path parameterizes pattern
+        # constants, by far the common serving variation)
+        def shape_of(ex):
+            return (
+                tuple(
+                    (
+                        tuple(c is not None for c in pr.consts),
+                        pr.vars,
+                        pr.eq_pairs,
                     )
-                )
-            # structural agreement: the group shares one constant-free
-            # shape; filter constants must MATCH (the single-device vmap
-            # path parameterizes those — this path parameterizes pattern
-            # constants, by far the common serving variation)
-            def shape_of(ex):
-                return (
-                    tuple(
-                        (
-                            tuple(c is not None for c in pr.consts),
-                            pr.vars,
-                            pr.eq_pairs,
-                        )
-                        for pr in ex.premises
-                    ),
-                    ex.seed,
-                    ex.steps,
-                    ex.filters,
-                    ex.mask_exprs,
-                    ex.out_vars,
-                )
-
-            shape0 = shape_of(exemplar)
-            if any(shape_of(ex) != shape0 for ex in execs[1:]):
-                self.stats_counters["fallbacks"] += 1
-                _SHARD_FALLBACKS.labels("divergent").inc()
-                raise Unsupported(
-                    "group members diverge beyond pattern constants"
-                )
-            # constant slots -> parameter matrix [B, n_slots]
-            slots = [
-                (i, pos)
-                for i, pr in enumerate(exemplar.premises)
-                for pos in range(3)
-                if pr.consts[pos] is not None
-            ]
-            slot_idx = {sp: k for k, sp in enumerate(slots)}
-            param_premises = tuple(
-                LoweredPremise(
-                    tuple(
-                        slot_idx[(i, pos)] if c is not None else None
-                        for pos, c in enumerate(pr.consts)
-                    ),
-                    pr.vars,
-                    pr.eq_pairs,
-                )
-                for i, pr in enumerate(exemplar.premises)
-            )
-            b = len(execs)
-            b_pad = max(2, 1 << max(b - 1, 1).bit_length())
-            params = np.zeros((b_pad, max(len(slots), 1)), dtype=np.uint32)
-            for r, ex in enumerate(execs):
-                for k, (i, pos) in enumerate(slots):
-                    params[r, k] = np.uint32(ex.premises[i].consts[pos])
-            params[b:] = params[0]  # pad rows re-run member 0, discarded
-            masks = tuple(
-                jnp.asarray(_pad_pow2_mask(np.asarray(m)))
-                for m in _materialize_masks(self.db, exemplar.mask_exprs)
-            )
-            state = (
-                *self.view.by_subj,
-                self.view.by_subj_valid,
-                *self.view.by_obj,
-                self.view.by_obj_valid,
-            )
-            fault_point("shard.dispatch")
-            join_cap, bucket_cap = exemplar.join_cap, exemplar.bucket_cap
-            t0 = time.perf_counter()
-            with span(
-                "shard.dispatch",
-                shards=self.n,
-                batch=b,
-                template=fp,
-            ):
-                for _attempt in range(8):
-                    fn = _get_batched_fn(
-                        self.mesh,
-                        param_premises,
-                        exemplar.seed,
-                        exemplar.steps,
-                        exemplar.filters,
-                        exemplar.out_vars,
-                        len(masks),
-                        join_cap,
-                        bucket_cap,
-                        b_pad,
-                    )
-                    with jax.enable_x64(True):
-                        outs, valid, overflow, shard_stats = fn(
-                            state, masks, params
-                        )
-                    if int(np.asarray(overflow)[0]) == 0:
-                        break
-                    join_cap *= 2
-                    bucket_cap *= 2
-                    self.stats_counters["cap_hits"] += 1
-                    self.stats_counters["last_cap_hit"] = time.time()
-                    _SHARD_CAP_HITS.inc()
-                    cap_advisor.observe_retry("sharded", fp)
-                else:
-                    raise RuntimeError(
-                        "sharded batch capacities failed to converge"
-                    )
-                valid_np = np.asarray(valid)
-                out_np = [np.asarray(o) for o in outs]
-                cap_rec = _analyze.active()
-                if cap_rec is not None:
-                    # stats ride the result transfer; materialized ONLY
-                    # under an active analyze capture
-                    stats_np = np.asarray(shard_stats)[:b]
-                    stat_names = ["seed"]
-                    for k in range(len(exemplar.steps)):
-                        stat_names += [f"exchange{k}", f"join{k}"]
-                    stat_names.append("final")
-                    for r in range(b):
-                        cap_rec.record(
-                            "sharded",
-                            member=r,
-                            template=fp,
-                            shards=self.n,
-                            steps=[
-                                (j, kv)
-                                for (j, kv, _kp, _ex) in exemplar.steps
-                            ],
-                            stat_names=stat_names,
-                            per_shard=stats_np[r].T.tolist(),
-                            operators={
-                                name: int(stats_np[r, :, i].sum())
-                                for i, name in enumerate(stat_names)
-                            },
-                            caps=[join_cap, bucket_cap],
-                        )
-                # per-shard span children: surviving rows per shard across
-                # the group (observable imbalance of THIS dispatch)
-                per_shard = valid_np[:b].sum(axis=(0, 2))
-                for sh in range(self.n):
-                    with span(
-                        "shard.partition", shard=sh, rows=int(per_shard[sh])
-                    ):
-                        pass
-            _SHARD_DISPATCH_LAT.observe(time.perf_counter() - t0)
-            bv = self._sig[0]
-            self._caps[(fp, bv)] = (join_cap, bucket_cap)
-            cap_advisor.observe(
-                "sharded", fp, (join_cap, bucket_cap), base_version=bv
-            )
-            occ_total = int(self._subj.occupancy().sum())
-            n_scans = 1 + len(exemplar.steps)
-            _SHARD_ROWS.inc(occ_total * n_scans * b)
-            width = len(
-                {v for v, _ in exemplar.premises[exemplar.seed].vars}
-            )
-            xbytes = 0
-            # mirror _batched_body's elision: co-partitioned steps move
-            # no bytes
-            part = next(
-                (
-                    v
-                    for v, pos in exemplar.premises[exemplar.seed].vars
-                    if pos == 0
+                    for pr in ex.premises
                 ),
-                None,
+                ex.seed,
+                ex.steps,
+                ex.filters,
+                ex.mask_exprs,
+                ex.out_vars,
             )
-            for (j, kv, _kpos, _extra) in exemplar.steps:
-                if self.n > 1 and kv != part:
-                    xbytes += width * self.n * self.n * bucket_cap * 4
-                part = kv
-                width += len(
-                    {v for v, _ in exemplar.premises[j].vars}
-                )
-            _SHARD_XBYTES.inc(xbytes * b)
-            _SHARD_DISPATCH.labels("batched").inc()
-            _SHARD_QUERIES.inc(b)
-            self.stats_counters["dispatches"] += 1
-            self.stats_counters["batched_queries"] += b
-            # host merge: per member, identical post-pass to the solo path
-            from kolibrie_tpu.query.executor import _finish_select_table
 
-            results: Dict[int, List[List[str]]] = {}
-            for r, ((idx, _text), ex) in enumerate(zip(items, execs)):
-                v = valid_np[r].ravel()
-                table = {
-                    var: out_np[k][r].ravel()[v].astype(np.uint32)
-                    for k, var in enumerate(exemplar.out_vars)
-                }
-                results[idx] = _finish_select_table(self.db, ex.query, table)
-            return results
+        shape0 = shape_of(exemplar)
+        if any(shape_of(ex) != shape0 for ex in execs[1:]):
+            self._decline("divergent")
+            raise Unsupported("group members diverge beyond pattern constants")
+        # constant slots -> parameter matrix [slots, n_slots]: the live
+        # members fill the first rows, the rest of the slot class stays
+        # zero and is never run
+        consts = [
+            (i, pos)
+            for i, pr in enumerate(exemplar.premises)
+            for pos in range(3)
+            if pr.consts[pos] is not None
+        ]
+        const_idx = {ip: k for k, ip in enumerate(consts)}
+        param_premises = tuple(
+            LoweredPremise(
+                tuple(
+                    const_idx[(i, pos)] if c is not None else None
+                    for pos, c in enumerate(pr.consts)
+                ),
+                pr.vars,
+                pr.eq_pairs,
+            )
+            for i, pr in enumerate(exemplar.premises)
+        )
+        params = np.zeros(
+            (_slot_class(len(execs)), max(len(consts), 1)), dtype=np.uint32
+        )
+        for r, ex in enumerate(execs):
+            for k, (i, pos) in enumerate(consts):
+                params[r, k] = np.uint32(ex.premises[i].consts[pos])
+        masks = tuple(
+            jnp.asarray(_pad_pow2_mask(np.asarray(m)))
+            for m in _materialize_masks(self.db, exemplar.mask_exprs)
+        )
+        return {
+            "execs": execs,
+            "premises": param_premises,
+            "params": params,
+            "masks": masks,
+            "caps": (exemplar.join_cap, exemplar.bucket_cap),
+        }
+
+    def _run_group(self, fp: str, group: dict, sp):  # kolint: holds[lock]
+        """The program, until its outputs are ready: one jit call, and
+        one more at doubled capacities for each overflow (a ``retry<k>``
+        attribute on the ``shard.wait`` span ``sp``).  Leaves the
+        capacities that held in ``group["caps"]``."""
+
+        exemplar = group["execs"][0]
+        state = (
+            *self.view.by_subj,
+            self.view.by_subj_valid,
+            *self.view.by_obj,
+            self.view.by_obj_valid,
+        )
+        live = np.int32(len(group["execs"]))
+        join_cap, bucket_cap = group["caps"]
+        for attempt in range(8):
+            fn = _get_batched_fn(
+                self.mesh,
+                group["premises"],
+                exemplar.seed,
+                exemplar.steps,
+                exemplar.filters,
+                exemplar.out_vars,
+                len(group["masks"]),
+                join_cap,
+                bucket_cap,
+                group["params"].shape[0],
+            )
+            with jax.enable_x64(True):
+                outs, valid, overflow, shard_stats = fn(
+                    state, group["masks"], group["params"], live
+                )
+            if int(np.asarray(overflow)[0]) == 0:
+                break
+            if sp is not None:
+                sp.attrs[f"retry{attempt}"] = [join_cap, bucket_cap]
+            join_cap *= 2
+            bucket_cap *= 2
+            self.stats_counters["cap_hits"] += 1
+            self.stats_counters["last_cap_hit"] = time.time()
+            _SHARD_CAP_HITS.inc()
+            cap_advisor.observe_retry("sharded", fp)
+        else:
+            raise RuntimeError("sharded batch capacities failed to converge")
+        group["caps"] = (join_cap, bucket_cap)
+        return jax.block_until_ready((outs, valid, shard_stats))
+
+    def _merge_group(self, fp: str, items, group: dict, device_out):  # kolint: holds[lock]
+        """Host side of a dispatch after the program: the analyze
+        records, per live member its rows to the host and the post-pass of
+        the solo path, then the per-shard span children."""
+        from kolibrie_tpu.query.executor import _finish_select_table
+
+        execs = group["execs"]
+        exemplar, live = execs[0], len(execs)
+        outs, valid, shard_stats = device_out
+        cap_rec = _analyze.active()
+        if cap_rec is not None:
+            # stats ride the result transfer; materialized ONLY under an
+            # active analyze capture
+            stats_np = np.asarray(shard_stats)[:live]
+            stat_names = ["seed"]
+            for k in range(len(exemplar.steps)):
+                stat_names += [f"exchange{k}", f"join{k}"]
+            stat_names.append("final")
+            for r in range(live):
+                cap_rec.record(
+                    "sharded",
+                    member=r,
+                    template=fp,
+                    shards=self.n,
+                    steps=[(j, kv) for (j, kv, _kp, _ex) in exemplar.steps],
+                    stat_names=stat_names,
+                    per_shard=stats_np[r].T.tolist(),
+                    operators={
+                        name: int(stats_np[r, :, i].sum())
+                        for i, name in enumerate(stat_names)
+                    },
+                    caps=list(group["caps"]),
+                )
+        # host merge: per member, identical post-pass to the solo path
+        results: Dict[int, List[List[str]]] = {}
+        per_shard = np.zeros(self.n, dtype=np.int64)
+        for r, ((idx, _text), ex) in enumerate(zip(items, execs)):
+            out_r, valid_r = jax.device_get(
+                _member_rows((outs, valid), np.int32(r))
+            )
+            per_shard += valid_r.sum(axis=1)
+            v = valid_r.ravel()
+            table = {
+                var: out_r[k].ravel()[v].astype(np.uint32)
+                for k, var in enumerate(exemplar.out_vars)
+            }
+            results[idx] = _finish_select_table(self.db, ex.query, table)
+        # per-shard span children: surviving rows per shard across the
+        # group (observable imbalance of THIS dispatch)
+        for sh in range(self.n):
+            with span("shard.partition", shard=sh, rows=int(per_shard[sh])):
+                pass
+        return results
+
+    def _count_dispatch(self, fp: str, group: dict) -> None:  # kolint: holds[lock]
+        """Pin the capacities that held and count the dispatch: live
+        members beside the slots it was compiled for (their ratio is the
+        loop's occupancy), rows scanned, static exchange bytes."""
+
+        exemplar, live = group["execs"][0], len(group["execs"])
+        join_cap, bucket_cap = group["caps"]
+        bv = self._sig[0]
+        self._caps[(fp, bv)] = (join_cap, bucket_cap)
+        cap_advisor.observe(
+            "sharded", fp, (join_cap, bucket_cap), base_version=bv
+        )
+        occ_total = int(self._subj.occupancy().sum())
+        n_scans = 1 + len(exemplar.steps)
+        _SHARD_ROWS.inc(occ_total * n_scans * live)
+        seed_vars = exemplar.premises[exemplar.seed].vars
+        width = len({v for v, _ in seed_vars})
+        xbytes = 0
+        # mirror _batched_body's elision: co-partitioned steps move no
+        # bytes
+        part = next((v for v, pos in seed_vars if pos == 0), None)
+        for (j, kv, _kpos, _extra) in exemplar.steps:
+            if self.n > 1 and kv != part:
+                xbytes += width * self.n * self.n * bucket_cap * 4
+            part = kv
+            width += len({v for v, _ in exemplar.premises[j].vars})
+        _SHARD_XBYTES.inc(xbytes * live)
+        _SHARD_DISPATCH.labels("lone" if live == 1 else "batched").inc()
+        _SHARD_QUERIES.inc(live)
+        _SHARD_MEMBER_SLOTS.inc(group["params"].shape[0])
+        self.stats_counters["dispatches"] += 1
+        self.stats_counters["batched_queries"] += live
 
     # -------------------------------------------------------------- health
 

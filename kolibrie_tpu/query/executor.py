@@ -1395,9 +1395,12 @@ def execute_queries_batched(db, queries: List[str]) -> List[Rows]:
     device runs every member of a template group in a single jit call
     instead of one dispatch per query.  Everything else — singleton
     templates, aggregates, ordered queries, updates — falls back to
-    ``execute_query_volcano`` per query.  Results come back in input
-    order; per-query host post-processing (DISTINCT, LIMIT/OFFSET,
-    formatting) is identical to the solo path."""
+    ``execute_query_volcano`` per query.  With a mesh attached
+    (``db._sharded_serving``) every template group, singletons too, goes
+    to ``ShardedDatabase.execute_batch`` first, and the single-device
+    paths serve only what the mesh lowering declines.  Results come back
+    in input order; per-query host post-processing (DISTINCT,
+    LIMIT/OFFSET, formatting) is identical to the solo path."""
     from kolibrie_tpu.optimizer.device_engine import (
         Unsupported,
         execute_plan_batch,
@@ -1425,7 +1428,7 @@ def execute_queries_batched(db, queries: List[str]) -> List[Rows]:
             groups.setdefault(ent["fp"], []).append(i)
     _, _, stats = _plan_caches(db)
     for fp, idxs in groups.items():
-        if len(idxs) < 2:
+        if len(idxs) < 2 and sharded is None:
             continue  # solo dispatch is already optimal for singletons
         if not board.allow(fp):
             continue  # breaker open: members fall to the solo degraded path
@@ -1438,10 +1441,13 @@ def execute_queries_batched(db, queries: List[str]) -> List[Rows]:
         set_baggage("template", fp)
         _sa_set_current_fp(fp)
         if sharded is not None:
-            # mesh-first: the whole template group rides one shard_map
-            # dispatch (parallel/sharded_serving.py); on Unsupported or a
-            # device fault the group degrades to the single-device paths
-            # below, with the breaker counting mesh trips
+            # mesh-first: the whole template group, a group of one like
+            # any other, rides one shard_map dispatch
+            # (parallel/sharded_serving.py): under an attached mesh no
+            # device holds the whole store in the deployment this stands
+            # for.  On Unsupported or a device fault the group degrades
+            # to the single-device paths below, with the breaker counting
+            # mesh trips
             from kolibrie_tpu.parallel.sharded_serving import (
                 Unsupported as _MeshUnsupported,
             )
@@ -1468,8 +1474,10 @@ def execute_queries_batched(db, queries: List[str]) -> List[Rows]:
                 for i in idxs:
                     results[i] = got[i]
                 continue
-        if not _device_routed(db):
-            continue  # mesh declined and no single-device jit routing
+        if len(idxs) < 2 or not _device_routed(db):
+            # mesh declined: a singleton runs solo, and so does a group
+            # without single-device jit routing
+            continue
         lowereds, ok = [], True
         for i in idxs:
             ent, slot, q, w = members[i]

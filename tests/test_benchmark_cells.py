@@ -1,0 +1,173 @@
+"""The benchmark's cells as ``BENCHMARK.json`` lists them, checked without
+JAX: the texts one client sends are what they were before there were clients,
+the clients of ``mesh_q7`` never share a constant, the cell ``lubm5.mesh4``
+is in with the entries PR 27 wrote for it, every file a cell or a
+per-layer metric names is there, and a program that lacks what a cell
+requires of it (``benchmark/requires``) is refused before anything starts.
+
+Imports ``benchmark.harness`` (``traffic``, ``data``; numpy; the generators
+are found by name through ``data.load_module``) and, of the program,
+``kolibrie_tpu.obs.metrics``.
+"""
+
+import hashlib
+import json
+import os
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+from benchmark.harness import data as files  # noqa: E402
+from benchmark.harness.traffic import Traffic  # noqa: E402
+
+BENCH = files.read_json(os.pardir, "BENCHMARK.json")
+CELLS = {w["name"]: w for w in BENCH["workloads"]}
+# the scale the digests were recorded at: 1 university, 25,000 employees
+ONE_CLIENT = {"lubm5.triangles": 1, "lubm5.lookups": 1, "employee100k.upstream": 25000}
+DIGESTS = files.read_json("data", "traffic_digests.json")["digests"]
+MESH4 = files.read_json("data", "lubm5.mesh4.entries.json")
+
+
+def generated(workload, seed, scale):
+    config = files.read_json("configs", CELLS[workload]["config"] + ".json")
+    generator = files.load_module("generators", config["generator"])
+    return generator.generate(config, seed, scale)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("workload", sorted(ONE_CLIENT))
+def test_one_client_sends_the_texts_it_always_sent(workload, seed):
+    data = generated(workload, seed, ONE_CLIENT[workload])
+    traffic = Traffic(CELLS[workload]["traffic"], data["domains"], seed)
+    assert traffic.clients == 1 and traffic.warmup_ramp == [1]
+    h = hashlib.sha256()
+    for stream, n in (("warmup", len(traffic.warmup_counts())), ("window", 8)):
+        for k in range(n):
+            for name, text in traffic.cycle(k, stream):
+                h.update(f"{stream}\0{k}\0{name}\0{text}\0".encode())
+    assert h.hexdigest() == DIGESTS[f"{workload}:{seed}"]
+
+
+@pytest.fixture(scope="module")
+def departments():
+    return generated("lubm5.mesh4", 2**31 + 5, 1)["domains"]
+
+
+@pytest.mark.parametrize("clients", [1, 2, 8])
+def test_no_two_clients_of_mesh_q7_ever_draw_one_constant(
+        departments, clients, monkeypatch):
+    spec = files.read_json("traffic", "mesh_q7.json")
+    assert spec["clients"] == 8 and spec["warmup_ramp"] == [1, 2, 4, 8]
+    monkeypatch.setattr(
+        files, "read_json",
+        lambda *parts: dict(spec, clients=clients, warmup_ramp=[clients]))
+    traffic = Traffic("mesh_q7", departments, 2**31 + 5)
+    assert traffic.clients == clients
+    domain = departments["department"]
+    assert len(domain) >= 15  # a LUBM university has 15 to 25 departments
+    for stream in ("window", "warmup"):
+        # however far the clients drift apart: any cycle of one against any
+        # cycle of another, over more cycles than a client has values
+        own = [{traffic.cycle(k, stream, c)[0][1] for k in range(2 * len(domain))}
+               for c in range(clients)]
+        assert sum(len(o) for o in own) == len(set().union(*own)) == len(domain)
+
+
+def test_benchmark_json_has_the_mesh_cell_as_its_entries_file_states_it():
+    for key in ("configs", "workloads", "per_layer"):
+        listed = {e["name"]: e for e in BENCH[key]}
+        for entry in MESH4[key]:
+            assert listed[entry["name"]] == entry, (key, entry["name"])
+    cell = CELLS["lubm5.mesh4"]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "lubm-5-mesh4", "mesh_q7", 4)
+    config = files.read_json("configs", "lubm-5-mesh4.json")
+    assert config["chips"] == 4 and config["layout"]["partitions"] == 4
+    assert files.read_json("workloads", "lubm5.mesh4.json") == {
+        "env": {"KOLIBRIE_SHARDED": "1"}}
+    # what PR 28 adds to the mesh's layer reads this cell and moves cycle_ms
+    added = {m["name"]: m for m in BENCH["per_layer"]
+             if m["name"] in ("shard_build_ms", "shard_wait_ms", "shard_merge_ms",
+                              "shard_lone_in_window",
+                              "shard_member_slots_in_window")}
+    assert len(added) == 5
+    for m in added.values():
+        assert (m["layer"], m["moves"], m["workloads"]) == (
+            "mesh serving", "cycle_ms", ["lubm5.mesh4"])
+
+
+@pytest.mark.parametrize("workload", sorted(CELLS))
+def test_every_file_a_cell_names_is_there(workload):
+    cell = CELLS[workload]
+    config_entry = next(c for c in BENCH["configs"] if c["name"] == cell["config"])
+    assert os.path.exists(os.path.join(REPO, config_entry["file"]))
+    config = files.read_json("configs", cell["config"] + ".json")
+    assert config.get("chips", 1) == cell["chips"]
+    assert set(config_entry["reduced"]) == set(config["reduced"])
+    files.load_module("generators", config["generator"])
+    assert "env" in files.read_json("workloads", workload + ".json")
+    traffic = files.read_json("traffic", cell["traffic"] + ".json")
+    for step in traffic["cycle"]:
+        assert files.template_text(step["template"])
+
+
+def test_every_per_layer_metric_has_its_file_and_its_reader():
+    names = [m["name"] for m in BENCH["per_layer"]]
+    assert len(names) == len(set(names))
+    end_to_end = {m["name"] for m in BENCH["end_to_end"]}
+    for m in BENCH["per_layer"]:
+        reader = files.read_json("layer_metrics", m["name"] + ".json")["reader"]
+        assert os.path.exists(files.path("readers", reader["kind"] + ".py")), m
+        assert m["moves"] in end_to_end
+        assert set(m.get("workloads", CELLS)) <= set(CELLS)
+
+
+def test_at_most_half_the_cells_take_four_chips():
+    four = [w["name"] for w in BENCH["workloads"] if w["chips"] == 4]
+    assert four == ["lubm5.mesh4"]
+    assert len(four) <= max(1, len(CELLS) // 2)
+    assert json.dumps(BENCH).count('"chips": 4') == 1
+
+
+# ---- what a cell requires of the program (``benchmark/requires``)
+
+def test_every_requirement_belongs_to_a_cell_and_names_a_documented_metric():
+    with open(os.path.join(REPO, "docs", "OBSERVABILITY.md"), encoding="utf-8") as f:
+        catalog = f.read()
+    found = sorted(os.listdir(files.path("requires")))
+    assert found == ["lubm5.mesh4.json"]
+    for name in found:
+        assert name[:-len(".json")] in CELLS
+        need = files.read_json("requires", name)
+        assert set(need) == {"module", "registers", "why"}
+        assert os.path.exists(
+            os.path.join(REPO, *need["module"].split(".")) + ".py")
+        assert f"`{need['registers']}`" in catalog
+
+
+@pytest.mark.parametrize("registered", [True, False])
+def test_a_program_without_the_required_metric_is_refused_at_once(
+        tmp_path, monkeypatch, registered):
+    from benchmark import harness
+    from kolibrie_tpu.obs import metrics
+
+    for folder, body in (
+            ("requires", {"module": "kolibrie_tpu.obs.metrics",
+                          "registers": "kolibrie_test_required_total",
+                          "why": "a test"}),
+            ("workloads", {"env": {"KOLIBRIE_TEST_REQUIRES": "1"}})):
+        (tmp_path / folder).mkdir()
+        (tmp_path / folder / "a.cell.json").write_text(json.dumps(body))
+    monkeypatch.setenv("KOLIBRIE_TEST_REQUIRES", "0")
+    monkeypatch.setattr(metrics, "REGISTRY", metrics.Registry())
+    if registered:
+        metrics.REGISTRY.counter("kolibrie_test_required_total", "a test")
+        harness._requires("a.cell", str(tmp_path))
+        assert os.environ["KOLIBRIE_TEST_REQUIRES"] == "1"
+    else:
+        with pytest.raises(SystemExit, match="cannot run cell a.cell"):
+            harness._requires("a.cell", str(tmp_path))
+    harness._requires("a.cell.without.the.file", str(tmp_path))  # requires nothing

@@ -36,9 +36,9 @@ M1 = 1 << 20
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """SingleDeviceSharding on a described v5e chip, with the kernels'
-    interpret switch forced off and the persistent cache disabled."""
+def topo():
+    """A described v5e:2x2 host, with the kernels' interpret switch forced
+    off and the persistent cache disabled."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
@@ -58,12 +58,18 @@ def one_chip():
     # entry points' caches; ours must not leak the other way either
     jax.clear_caches()
     try:
-        yield SingleDeviceSharding(topo.devices[0])
+        yield topo
     finally:
         pk._interpret = interpret_was
         jax.clear_caches()
         jax.config.update("jax_enable_compilation_cache", cache_was)
         cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """SingleDeviceSharding on one chip of the described host."""
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _compile(fn, one_chip, *args, lead=(), want_kernel=True, **static):
@@ -210,3 +216,59 @@ def test_whole_plan_batch_8_variants(one_chip, lubm_db):
         _compile(de._run_plan_batch, one_chip, order_arrays, scal, masks,
                  values, numf, quoted, params_b, lead=(spec0,),
                  want_kernel=False)
+
+
+def test_mesh_program_lubm_q7_four_chips(topo, mesh8):
+    """The mesh serving program (``sharded_serving._batched_body``: the
+    live-member loop with its ``all_to_all`` inside) for the four chips
+    of the described host, at the widths the cell ``lubm5.mesh4`` runs:
+    262,144-slot shards, the capacities LUBM(5) settles at, slot class 8.
+    The template's lowering comes from a small store on CPU devices."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from benches import lubm
+    from benchmark.harness import data as bench_files
+    from kolibrie_tpu.parallel import make_mesh
+    from kolibrie_tpu.parallel import sharded_serving as ss
+    from kolibrie_tpu.query.executor import _plan_cache_entry
+    from kolibrie_tpu.query.sparql_database import SparqlDatabase
+
+    db = SparqlDatabase()
+    s, p, o = lubm.generate_fast(1, db.dictionary)
+    db.store.add_batch(s, p, o)
+    db.execution_mode = "host"
+    sh = ss.attach_sharded(db, make_mesh(4))
+    sh.refresh()
+    dept = "http://www.Department0.University0.edu"
+    text = bench_files.template_text("lubm_q7").replace("@department@", dept)
+    db.register_prefixes_from_query(text)
+    fp = _plan_cache_entry(db, text)[0]["fp"]
+    with sh.lock:
+        group = sh._build_group(fp, [(0, text)])
+    ex = group["execs"][0]
+    assert any(kv != "x" for (_j, kv, _kp, _e) in ex.steps)  # an exchange
+    mesh = Mesh(np.array(topo.devices).reshape(4), (sh.axis,))
+    fn = ss._get_batched_fn(
+        mesh, group["premises"], ex.seed, ex.steps, ex.filters, ex.out_vars,
+        len(group["masks"]), M1, M1, ss._slot_class(1),
+    )
+    rows = NamedSharding(mesh, P(sh.axis, None))
+    everywhere = NamedSharding(mesh, P())
+
+    def shape(a, sharding, dims=None):
+        return jax.ShapeDtypeStruct(dims or a.shape, a.dtype, sharding=sharding)
+
+    shard = (4, 262144 + 1024)  # base blocks + delta blocks
+    state = (*sh.view.by_subj, sh.view.by_subj_valid,
+             *sh.view.by_obj, sh.view.by_obj_valid)
+    with jax.enable_x64(True):
+        compiled = fn.lower(
+            tuple(shape(a, rows, shard) for a in state),
+            tuple(shape(m, everywhere) for m in group["masks"]),
+            shape(group["params"], everywhere),
+            jax.ShapeDtypeStruct((), np.int32, sharding=everywhere),
+        ).compile()
+    text = compiled.as_text()
+    assert " all-to-all(" in text and " while(" in text
+    per_chip = compiled.memory_analysis()
+    assert per_chip.output_size_in_bytes + per_chip.temp_size_in_bytes < 2**30

@@ -21,7 +21,9 @@ from kolibrie_tpu.parallel.sharded_serving import (
     detach_sharded,
     sharded_compile_stats,
 )
+from kolibrie_tpu.obs import export as obs_export
 from kolibrie_tpu.query.executor import (
+    _plan_cache_entry,
     _plan_caches,
     execute_queries_batched,
     execute_query_volcano,
@@ -37,6 +39,13 @@ PREFIX = "PREFIX ub: <http://swat.cse.lehigh.edu/onto/univ-bench.owl#>\n"
 TEMPLATE = (
     PREFIX
     + "SELECT ?x ?c WHERE {{ ?x ub:worksFor <{dept}> . ?x ub:teacherOf ?c . }}"
+)
+# a plain SELECT the batcher admits and the mesh lowering declines (a
+# filter that compares two variables): it stays on the single-device copy
+DECLINED = (
+    PREFIX
+    + "SELECT ?x ?c WHERE {{ ?x ub:worksFor <{dept}> . ?x ub:teacherOf ?c . "
+    "FILTER(?x != ?c) }}"
 )
 DEPTS_Q = PREFIX + "SELECT DISTINCT ?d WHERE { ?x ub:worksFor ?d . }"
 WORKS_Q = PREFIX + "SELECT ?x ?d WHERE { ?x ub:worksFor ?d . }"
@@ -54,6 +63,35 @@ def _template_group(db, k=4):
     deps = execute_query_volcano(DEPTS_Q, db)
     assert len(deps) >= k
     return [TEMPLATE.format(dept=d[0]) for d in deps[:k]]
+
+
+def _metric(prefix, text=None):
+    """Sum of the ``/metrics`` samples whose name and labels start with
+    ``prefix`` (a labelled counter has no line until it first grows)."""
+    if text is None:
+        text = obs_export.render_prometheus()
+    return sum(
+        float(line.rpartition(" ")[2])
+        for line in text.splitlines()
+        if line.startswith(prefix)
+    )
+
+
+_ROUTES = {
+    "mesh_queries": "kolibrie_shard_queries_total",
+    "lone": 'kolibrie_shard_dispatch_total{path="lone"}',
+    "batched": "kolibrie_query_batched_total",
+    "single_device": "kolibrie_query_seconds_count",
+    "fallbacks": "kolibrie_shard_fallback_total",
+}
+
+
+def _routes(text=None):
+    return {k: _metric(name, text) for k, name in _ROUTES.items()}
+
+
+def _grew(before, after):
+    return {k: after[k] - before[k] for k in before}
 
 
 @pytest.fixture(scope="module")
@@ -108,6 +146,115 @@ def test_batched_group_matches_oracle(sharded_db):
     got = execute_queries_batched(db, texts)
     assert got == oracle
     assert sh.stats_counters["batched_queries"] >= 4
+
+
+@pytest.mark.parametrize("size", [1, 2, 3, 5, 8])
+def test_group_runs_its_live_members_in_one_executable(sharded_db, size):
+    db, sh = sharded_db
+    texts = _template_group(db, 8)
+    fp = _plan_cache_entry(db, texts[0])[0]["fp"]
+    # a group of one settles the template's capacities ...
+    sh.execute_batch(fp, [(0, texts[0])])
+    programs = sharded_compile_stats()["batched_programs"]
+    items = list(enumerate(texts[:size]))
+    got = sh.execute_batch(fp, items)
+    assert [got[i] for i, _ in items] == [
+        execute_query_volcano(t, db) for t in texts[:size]
+    ]
+    assert all(len(got[i]) > 0 for i, _ in items)
+    # ... and no further size builds another program
+    assert sharded_compile_stats()["batched_programs"] == programs
+    # the program itself: rows past the live count were never written
+    with sh.lock:
+        group = sh._build_group(fp, items)
+        outs, valid, _stats = sh._run_group(fp, group, None)
+    assert group["params"].shape[0] == 8
+    valid = np.asarray(valid)
+    assert valid[:size].any(axis=(1, 2)).all()
+    assert not valid[size:].any()
+    assert all(not np.asarray(o)[size:].any() for o in outs)
+    assert sharded_compile_stats()["batched_programs"] == programs
+
+
+def test_larger_group_takes_the_next_slot_class(sharded_db):
+    from kolibrie_tpu.parallel.sharded_serving import _slot_class
+
+    assert [_slot_class(b) for b in (1, 2, 7, 8, 9, 16, 17)] == [
+        8, 8, 8, 8, 16, 16, 32,
+    ]
+    db, sh = sharded_db
+    texts = _template_group(db, 9)
+    fp = _plan_cache_entry(db, texts[0])[0]["fp"]
+    slots = _metric("kolibrie_shard_member_slots_total")
+    got = sh.execute_batch(fp, list(enumerate(texts)))
+    assert [got[i] for i in range(9)] == [
+        execute_query_volcano(t, db) for t in texts
+    ]
+    assert _metric("kolibrie_shard_member_slots_total") - slots == 16
+
+
+def test_lone_request_is_served_by_the_mesh(sharded_db):
+    db, sh = sharded_db
+    text = _template_group(db, 6)[5]
+    before = _routes()
+    assert execute_queries_batched(db, [text]) == [
+        execute_query_volcano(text, db)
+    ]
+    # the oracle call above is the one single-device execution
+    assert _grew(before, _routes()) == {
+        "mesh_queries": 1,
+        "lone": 1,
+        "batched": 1,
+        "single_device": 1,
+        "fallbacks": 0,
+    }
+
+
+def test_declined_shape_answers_from_the_single_device_copy(sharded_db):
+    db, sh = sharded_db
+    dept = execute_query_volcano(DEPTS_Q, db)[0][0]
+    text = DECLINED.format(dept=dept)
+    oracle = execute_query_volcano(text, db)
+    assert len(oracle) > 0
+    before = _routes()
+    assert execute_queries_batched(db, [text]) == [oracle]
+    assert _grew(before, _routes()) == {
+        "mesh_queries": 0,
+        "lone": 0,
+        "batched": 0,
+        "single_device": 1,
+        "fallbacks": 1,
+    }
+
+
+def test_the_program_has_what_the_cell_lubm5_mesh4_requires(monkeypatch):
+    """``benchmark/requires/lubm5.mesh4.json``: ``run.py`` refuses a program
+    without it before anything starts (the parent of PR 28 is one)."""
+    import os
+
+    from benchmark import harness
+
+    monkeypatch.setenv("KOLIBRIE_SHARDED", "")  # the cell's env is undone after
+    harness._requires("lubm5.mesh4")
+    assert os.environ["KOLIBRIE_SHARDED"] == "1"
+
+
+def test_warm_builds_the_executable_requests_run(mesh8):
+    db = _lubm_db(1)
+    sh = attach_sharded(db, mesh8)
+    sh.refresh()
+    texts = _template_group(db, 4)
+    assert sh.warm(texts[0]) is True
+    assert sh.stats_counters["prewarmed"] == 1
+    programs = sharded_compile_stats()["batched_programs"]
+    hits = sh.stats_counters["cap_hits"]
+    assert execute_queries_batched(db, texts[1:]) == [
+        execute_query_volcano(t, db) for t in texts[1:]
+    ]
+    assert sharded_compile_stats()["batched_programs"] == programs
+    assert sh.stats_counters["cap_hits"] == hits
+    # a template the mesh lowering declines serves single-device
+    assert sh.warm(DECLINED.format(dept="http://nowhere/d")) is False
 
 
 def test_solo_mesh_execute_matches_oracle(sharded_db):
@@ -332,9 +479,20 @@ def test_dispatch_emits_shard_spans(sharded_db):
     names = [s["name"] for s in spans]
     assert "executor.sharded" in names
     assert "shard.dispatch" in names
+    # build, wait and merge are the dispatch's children and cover it
+    dispatch = next(s for s in spans if s["name"] == "shard.dispatch")
+    parts = {
+        s["name"]: s for s in spans if s["parent_id"] == dispatch["span_id"]
+    }
+    assert sorted(parts) == ["shard.build", "shard.merge", "shard.wait"]
+    assert parts["shard.wait"]["attrs"] == {"slots": 8, "live": 3}
+    assert sum(p["dur_ms"] for p in parts.values()) <= dispatch["dur_ms"]
     kids = [s for s in spans if s["name"] == "shard.partition"]
     assert len(kids) == 8  # one child per shard, occupancy attached
     assert all("rows" in k["attrs"] for k in kids)
+    assert all(
+        k["parent_id"] == parts["shard.merge"]["span_id"] for k in kids
+    )
 
 
 # ----------------------------------------------------------- HTTP serving
@@ -393,6 +551,51 @@ def test_http_sharded_store_end_to_end(sharded_server):
     assert "kolibrie_store_shards" in metrics
 
 
+def test_http_lone_request_is_served_by_the_mesh(sharded_server):
+    base = sharded_server
+    db = _lubm_db(1)
+    sid = _post(
+        base,
+        "/store/load",
+        {"rdf": db.to_ntriples(), "format": "ntriples", "mode": "host"},
+    )["store_id"]
+
+    def metrics():
+        with urllib.request.urlopen(base + "/metrics", timeout=60) as resp:
+            return _routes(resp.read().decode())
+
+    def ask(text):
+        got = _post(base, "/store/query", {"store_id": sid, "sparql": text})
+        return sorted(map(tuple, got["data"]))
+
+    lone, declined = (
+        t.format(dept=execute_query_volcano(DEPTS_Q, db)[0][0])
+        for t in (TEMPLATE, DECLINED)
+    )
+    oracle = [
+        sorted(map(tuple, execute_query_volcano(t, db)))
+        for t in (lone, declined)
+    ]
+    before = metrics()
+    assert ask(lone) == oracle[0]
+    assert _grew(before, metrics()) == {
+        "mesh_queries": 1,
+        "lone": 1,
+        "batched": 1,
+        "single_device": 0,
+        "fallbacks": 0,
+    }
+    before = metrics()
+    assert ask(declined) == oracle[1]
+    assert _grew(before, metrics()) == {
+        "mesh_queries": 0,
+        "lone": 0,
+        "batched": 0,
+        "single_device": 1,
+        "fallbacks": 1,
+    }
+
+
 # ------------------------------------------------ EXPLAIN ANALYZE (ISSUE 14)
 
 
@@ -425,12 +628,9 @@ def test_batched_analyze_matches_oracle(sharded_db):
 
 def test_trace_id_reaches_shard_spans(sharded_server):
     # satellite: a client trace id must survive the HTTP front door into
-    # the PR-8 shard_map dispatch's per-shard span children.  The mesh
-    # only takes GROUPS (>= 2 same-template members in one 5 ms batch
-    # window), so two members post concurrently under ONE trace id — the
-    # batch leader's dispatch then lands the shard spans under it.
-    from kolibrie_tpu.obs import spans as obs_spans
-
+    # the PR-8 shard_map dispatch's per-shard span children.  One request
+    # is enough: the mesh serves a group of one like any other (before
+    # PR 28 it took two members meeting in the batcher's 5 ms window).
     base = sharded_server
     db = _lubm_db(1)
     out = _post(
@@ -439,31 +639,17 @@ def test_trace_id_reaches_shard_spans(sharded_server):
         {"rdf": db.to_ntriples(), "format": "ntriples", "mode": "host"},
     )
     sid = out["store_id"]
-    texts = _template_group(db, 2)
-    spans = []
-    for attempt in range(8):  # the 5 ms window makes co-arrival racy
-        tid = f"trace-shard-http-{attempt}"
-        obs_spans.clear()
-        threads = [
-            threading.Thread(
-                target=_post,
-                args=(base, "/store/query", {"store_id": sid, "sparql": t}),
-                kwargs={"headers": {"X-Kolibrie-Trace-Id": tid}},
-            )
-            for t in texts
-        ]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        with urllib.request.urlopen(
-            base + f"/debug/traces?trace_id={tid}", timeout=60
-        ) as resp:
-            spans = [
-                json.loads(l) for l in resp.read().decode().splitlines() if l
-            ]
-        if any(s["name"] == "shard.dispatch" for s in spans):
-            break
+    tid = "trace-shard-http"
+    _post(
+        base,
+        "/store/query",
+        {"store_id": sid, "sparql": _template_group(db, 1)[0]},
+        headers={"X-Kolibrie-Trace-Id": tid},
+    )
+    with urllib.request.urlopen(
+        base + f"/debug/traces?trace_id={tid}", timeout=60
+    ) as resp:
+        spans = [json.loads(l) for l in resp.read().decode().splitlines() if l]
     assert spans and all(s["trace_id"] == tid for s in spans)
     names = {s["name"] for s in spans}
     assert "executor.sharded" in names, names
