@@ -1009,14 +1009,10 @@ class ColumnarTripleStore:
 
     # ---------------------------------------------------------------- match
 
-    def match(
-        self,
-        s: Optional[int] = None,
-        p: Optional[int] = None,
-        o: Optional[int] = None,
-    ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """Pattern scan: None = wildcard.  Returns (s, p, o) column arrays of
-        matching triples.  Dispatch by bound combination mirrors
+    def _match_range(self, s, p, o):
+        """``(order, lo, hi)`` of a pattern's rows in the sorted order its
+        bound positions select (None = wildcard), or None where nothing is
+        bound.  Dispatch by bound combination mirrors
         ``UnifiedIndex::query`` (``index_manager.rs:253-340``)."""
         self.compact()
         if s is not None and p is not None and o is not None:
@@ -1041,13 +1037,31 @@ class ColumnarTripleStore:
             order = self.order("osp")
             lo, hi = order.range0(o)
         else:
+            return None
+        return order, lo, hi
+
+    def match(
+        self,
+        s: Optional[int] = None,
+        p: Optional[int] = None,
+        o: Optional[int] = None,
+    ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """Pattern scan: None = wildcard.  Returns (s, p, o) column arrays of
+        matching triples."""
+        found = self._match_range(s, p, o)
+        if found is None:
             return self._s, self._p, self._o
+        order, lo, hi = found
         cols = order.slice_rows(lo, hi)
         return cols["s"], cols["p"], cols["o"]
 
     def count(self, s=None, p=None, o=None) -> int:
-        ms, _, _ = self.match(s, p, o)
-        return len(ms)
+        """Rows :meth:`match` would return: a range count on the sorted
+        order, nothing materialised."""
+        found = self._match_range(s, p, o)
+        if found is None:
+            return len(self._s)
+        return found[2] - found[1]
 
     def clone(self) -> "ColumnarTripleStore":
         """O(1) copy-on-write clone.  Column arrays and built sort orders are

@@ -67,7 +67,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from kolibrie_tpu.ops import round_cap
 from kolibrie_tpu.parallel.dist_general import _exchange_table, _plan_rule_dist
 from kolibrie_tpu.parallel.dist_join import _dist_check_vma, local_join_u32
-from kolibrie_tpu.parallel.sharded_store import ShardedTripleStore
+from kolibrie_tpu.parallel.sharded_store import (
+    ShardedTripleStore,
+    _mix32,
+    shard_of,
+)
 from kolibrie_tpu.query import ast as A
 from kolibrie_tpu.reasoner.device_fixpoint import (
     LoweredFilter,
@@ -108,6 +112,45 @@ def _lower_query_pattern(resolved) -> LoweredPremise:
         else:
             raise Unsupported(f"pattern term kind {t.kind!r}")
     return LoweredPremise(tuple(consts), tuple(out_vars), tuple(eq_pairs))
+
+
+def _most_constants(premises) -> int:
+    """The seed rule that needs no count: the premise with the most constant
+    positions, ties to the lowest index."""
+    return max(
+        range(len(premises)),
+        key=lambda i: (sum(c is not None for c in premises[i].consts), -i),
+    )
+
+
+def exchanged_steps(premises, seed, steps, n) -> Tuple[bool, ...]:
+    """Per join step, whether ``sharded_serving._batched_body`` exchanges
+    the binding table before it.  The seed scans the subject-partitioned
+    mirror, so rows start partitioned by the seed's subject variable, and
+    every step leaves them partitioned by its join key: a step keyed by
+    the variable the rows are already partitioned by is co-located and its
+    ``all_to_all`` would be an identity.  One definition for the program,
+    the host count and the dispatch counters."""
+    part = next((v for v, pos in premises[seed].vars if pos == 0), None)
+    out = []
+    for _j, kv, _kpos, _extra in steps:
+        out.append(n > 1 and kv != part)
+        part = kv
+    return tuple(out)
+
+
+def _largest(step_rows, buckets) -> Tuple[int, int]:
+    """What the two capacities have to hold of a counted chain: the largest
+    per-shard join step and the largest exchange bucket."""
+    return (
+        max((int(s.max()) for s in step_rows), default=0),
+        max(buckets, default=0),
+    )
+
+
+class _Uncounted(Exception):
+    """A host chain walk given up: over the best plan so far, or past
+    ``_CALIBRATE_ROW_LIMIT``."""
 
 
 def _mirror(op: str) -> str:
@@ -603,6 +646,15 @@ class DistQueryExecutor:
     ``store`` may be a prebuilt :class:`ShardedTripleStore` (reused across
     queries — the benchmark path); otherwise one is partitioned from the
     database's columns on first :meth:`run`.
+
+    The plan — the seed premise, with the step order ``_plan_rule_dist``
+    gives that seed, and the two capacities — comes from one host count
+    of what the program will count (:meth:`_counted_plan`); a caller that
+    holds a template's plan passes ``seed``, ``join_cap`` and
+    ``bucket_cap`` and nothing is counted.  ``batched`` says which body
+    the plan is for: ``sharded_serving._batched_body`` joins against the
+    whole mirror block, :func:`_query_body` against the side premise's
+    own scan.
     """
 
     def __init__(
@@ -613,6 +665,8 @@ class DistQueryExecutor:
         store: Optional[ShardedTripleStore] = None,
         join_cap: Optional[int] = None,
         bucket_cap: Optional[int] = None,
+        seed: Optional[int] = None,
+        batched: bool = False,
     ):
         from kolibrie_tpu.optimizer.engine import resolve_pattern
         from kolibrie_tpu.query.parser import parse_combined_query
@@ -797,13 +851,7 @@ class DistQueryExecutor:
             )
             mask_exprs.extend(bexprs)
             bplans = dict(_plan_rule_dist(bprem))
-            bseed = max(
-                range(len(bprem)),
-                key=lambda i: (
-                    sum(c is not None for c in bprem[i].consts),
-                    -i,
-                ),
-            )
+            bseed = _most_constants(bprem)
             return bprem, bseed, bplans[bseed], bfilters
 
         unions_l = []
@@ -834,134 +882,232 @@ class DistQueryExecutor:
             )
         self.anti = tuple(anti)
         self.mask_exprs = tuple(mask_exprs)
-        plans = _plan_rule_dist(self.premises)
-        # seed at the most selective premise (most constant positions)
-        self.seed = max(
-            range(len(self.premises)),
-            key=lambda i: (
-                sum(c is not None for c in self.premises[i].consts),
-                -i,
-            ),
-        )
-        self.steps = dict(plans)[self.seed]
+        plans = dict(_plan_rule_dist(self.premises))
         self.query = q
         self.store = store
-        if join_cap is None or bucket_cap is None:
-            est = self._calibrated_caps_cached()
+        self.batched = batched
+        self.plan_source = "pinned"  # where the seed came from
+        if seed is None or join_cap is None or bucket_cap is None:
+            counted = self._counted_plan_cached(plans, seed)
+            if seed is None:
+                seed, self.plan_source = counted[:2]
             if join_cap is None:
-                join_cap = est[0]
+                join_cap = counted[2]
             if bucket_cap is None:
-                bucket_cap = est[1]
+                bucket_cap = counted[3]
+        self.seed = seed
+        self.steps = plans[seed]
         self.join_cap = join_cap
         self.bucket_cap = bucket_cap
 
-    # Calibration bails to the store-size heuristic past this many
-    # intermediate rows: materializing bigger host joins just to size the
-    # device buffers would cost the host memory the static-capacity design
-    # exists to avoid.
+    # A host count bails to the store-size heuristic (and the seed to the
+    # most-constants rule) past this many intermediate rows: materializing
+    # bigger host joins just to plan and size the device buffers would cost
+    # the host memory the static-capacity design exists to avoid.
     _CALIBRATE_ROW_LIMIT = 8_000_000
 
-    def _calibrated_caps_cached(self) -> Tuple[int, int]:
-        """Per-database memo of :meth:`_calibrate_caps` keyed on (query
-        shape, mesh size), valid for ONE store version: one-shot
-        ``execute_query_distributed`` calls of a repeated query must not
-        pay the host chain pass every time.  A store mutation drops the
-        whole memo (stale-version entries must not accumulate for the
-        life of a long-running database)."""
+    def _counted_plan_cached(self, plans, pinned) -> Tuple[int, str, int, int]:
+        """Per-database memo of :meth:`_counted_plan` keyed on (query
+        shape, the body it sizes, mesh size), valid for ONE store version:
+        one-shot ``execute_query_distributed`` calls of a repeated query
+        must not pay the host chain pass every time.  A store mutation
+        drops the whole memo (stale-version entries must not accumulate
+        for the life of a long-running database)."""
         version = self.db.store.version
-        cache = self.db.__dict__.get("_dist_cap_cache")
+        cache = self.db.__dict__.get("_dist_plan_cache")
         if cache is None or cache["version"] != version:
-            cache = {"version": version, "caps": {}}
-            self.db.__dict__["_dist_cap_cache"] = cache
+            cache = {"version": version, "plans": {}}
+            self.db.__dict__["_dist_plan_cache"] = cache
         key = (
             self.premises,
-            self.seed,
-            self.steps,
             self.anti,
             self.union_specs,
             self.optional_specs,
+            bool(self.query.distinct),
+            self.batched,
+            pinned,
             self.n,
         )
-        caps = cache["caps"].get(key)
-        if caps is None:
-            caps = self._calibrate_caps()
-            cache["caps"][key] = caps
-        return caps
+        plan = cache["plans"].get(key)
+        if plan is None:
+            plan = self._counted_plan(plans, pinned)
+            cache["plans"][key] = plan
+        return plan
 
-    def _calibrate_caps(self) -> Tuple[int, int]:
-        """Size the per-shard join/bucket capacities from a HOST pass over
-        the actual premise chain instead of a blind multiple of the store
-        size — the static shapes the mesh program sorts and exchanges are
-        then proportional to the query's true intermediate cardinalities.
-        Premise scans go through the store's sorted orders
-        (``store.match``), each step's join size is COUNTED before any
-        index materialization, and the indices reuse the same
-        searchsorted bounds; a blow-up past ``_CALIBRATE_ROW_LIMIT``
-        falls back to the heuristic.  Skew headroom 4x; the
-        overflow/retry protocol still backstops underestimates."""
+    def _counted_plan(self, plans, pinned) -> Tuple[int, str, int, int]:
+        """``(seed, source, join_cap, bucket_cap)`` from ONE host count of
+        what the mesh program will count.  The candidate seeds (the
+        pinned one alone where a template has one) are walked in the
+        order of their constant scans' sizes — range counts on the
+        store's sorted orders — each through the step order
+        ``_plan_rule_dist`` gives it, and the seed whose chain's largest
+        per-shard join step is smallest is kept (``source`` "counted"); a
+        walk is abandoned at the first step that exceeds the best so far,
+        so a plan that would join millions of rows is never materialised.
+        The two capacities follow the single-device rule
+        (``device_engine.fit_join_caps``), each from its own count: the
+        largest per-shard join step and the largest (source,
+        destination) exchange bucket, never above the store-size
+        heuristic; the overflow/retry protocol backstops a constant with
+        more than 4x the counted rows.  Where nothing can be counted
+        (every walk past ``_CALIBRATE_ROW_LIMIT``) the most-constants
+        seed and the heuristic stand (``source`` "constants")."""
+        from kolibrie_tpu.optimizer.device_engine import fit_join_caps
+
         heuristic = round_cap(
             4 * max(1, -(-len(self.db.store) // self.n)), 256
         )
+        if pinned is not None:
+            candidates = [pinned]
+        else:
+            scan = {
+                i: self.db.store.count(*pr.consts)
+                for i, pr in enumerate(self.premises)
+            }
+            first = _most_constants(self.premises)
+            candidates = sorted(plans, key=lambda i: (scan[i], i != first, i))
+        best = None
+        for i in candidates:
+            try:
+                steps, buckets, table, shard = self._count_chain(
+                    self.premises,
+                    i,
+                    plans[i],
+                    limit=None if best is None else best[0],
+                )
+            except _Uncounted:
+                continue
+            size = _largest(steps, buckets)
+            if best is None or size < best[:2]:
+                best = (*size, i, table, shard)
+        if best is not None:
+            step, bucket, seed, table, shard = best
+            try:
+                cstep, cbucket = self._count_clauses(table, shard)
+            except _Uncounted:
+                best = None
+        if best is None:
+            fallback = pinned if pinned is not None else _most_constants(
+                self.premises
+            )
+            return fallback, "constants", heuristic, heuristic
+        join_cap, bucket_cap = fit_join_caps(
+            [heuristic, heuristic], [max(step, cstep), max(bucket, cbucket)]
+        )
+        return seed, "counted", join_cap, bucket_cap
+
+    def _count_chain(self, premises, seed, steps, limit=None):
+        """Host twin of one premise chain as the mesh program runs it:
+        ``(each join step's rows per shard, each exchange's largest
+        bucket, final table, final rows' shards)`` — the same walk for
+        the main BGP and every clause branch, told by ``self.batched``
+        which body it sizes.  Rows start on the shard that owns their triple's subject
+        (the seed scans the subject mirror) and move to the owner of each
+        step's key.  A join step's size is what the program's join counts
+        before any mask, on the largest shard: for the solo
+        ``_query_body`` the left rows' matches in the side premise's
+        scan (its constants pre-mask the side), for the batched body
+        their matches in the WHOLE mirror block that owns the key, by
+        subject or by object (the side sort is hoisted out of the member
+        loop, so the side premise's constants apply after the join).  A
+        bucket's size is the largest (source, destination) pair of an
+        exchange; the batched body elides the exchange of a step whose
+        key the rows are already partitioned by.  Counted before anything
+        is materialised: a step over ``limit`` on some shard or a join
+        past ``_CALIBRATE_ROW_LIMIT`` raises :class:`_Uncounted`."""
+        st = self.db.store
+        n = self.n
 
         def table_of(prem):
-            scan = self.db.store.match(
-                s=prem.consts[0], p=prem.consts[1], o=prem.consts[2]
-            )
+            scan = st.match(*prem.consts)
             m = np.ones(len(scan[0]), dtype=bool)
             for a, b in prem.eq_pairs:
                 m &= scan[a] == scan[b]
-            return {v: scan[pos][m] for v, pos in prem.vars}
+            return {v: scan[pos][m] for v, pos in prem.vars}, scan[0][m]
 
-        class _Blowup(Exception):
-            pass
-
-        def walk_chain(premises, seed, steps):
-            """(max intermediate rows, final table) of one premise chain —
-            the same machinery for the main BGP and every clause branch."""
-            table = table_of(premises[seed])
-            n_rows = len(next(iter(table.values()))) if table else 0
-            max_rows = n_rows
-            for j, kv, kpos, extra in steps:
-                ptab = table_of(premises[j])
-                lk, rk = table[kv], ptab[kv]
-                order = np.argsort(rk, kind="stable")
-                rs = rk[order]
-                lo = np.searchsorted(rs, lk, side="left")
-                counts = np.searchsorted(rs, lk, side="right") - lo
-                total = int(counts.sum())
-                if total > self._CALIBRATE_ROW_LIMIT:
-                    raise _Blowup
-                # expand (li, ri) straight from the bounds already in hand
-                li = np.repeat(np.arange(len(lk)), counts)
-                offs = np.concatenate(([0], np.cumsum(counts[:-1]))) if len(
-                    counts
-                ) else np.zeros(0, dtype=np.int64)
-                pos = np.arange(total) - np.repeat(offs, counts) + np.repeat(
-                    lo, counts
+        table, subj = table_of(premises[seed])
+        shard = shard_of(subj, n)
+        exchanged = (
+            exchanged_steps(premises, seed, steps, n)
+            if self.batched
+            else (n > 1,) * len(steps)
+        )
+        step_rows, buckets = [], []
+        for (j, kv, kpos, extra), routed in zip(steps, exchanged):
+            ptab, _ = table_of(premises[j])
+            lk, rk = table[kv], ptab[kv]
+            if routed:
+                dest = shard_of(lk, n)
+                buckets.append(
+                    int(np.bincount(shard * n + dest, minlength=1).max())
                 )
-                ri = order[pos]
-                new_table = {v: c[li] for v, c in table.items()}
-                keep = np.ones(total, dtype=bool)
-                for v, c in ptab.items():
-                    if v not in new_table:
-                        new_table[v] = c[ri]
-                    elif v in extra:
-                        keep &= new_table[v] == c[ri]
-                # pre-mask size is what the static join output must hold;
-                # masked rows stay in the buffer as invalid
-                max_rows = max(max_rows, total)
-                table = {v: c[keep] for v, c in new_table.items()}
-            return max_rows, table
+                shard = dest
+            order = np.argsort(rk, kind="stable")
+            rs = rk[order]
+            lo = np.searchsorted(rs, lk, side="left")
+            counts = np.searchsorted(rs, lk, side="right") - lo
+            if self.batched:
+                block = st.order("spo" if kpos == 0 else "osp").c0
+                matched = np.searchsorted(
+                    block, lk, side="right"
+                ) - np.searchsorted(block, lk, side="left")
+            else:
+                matched = counts
+            per_shard = np.bincount(
+                shard, weights=matched, minlength=n
+            ).astype(np.int64)
+            total = int(counts.sum())
+            if (
+                limit is not None and per_shard.max() > limit
+            ) or total > self._CALIBRATE_ROW_LIMIT:
+                raise _Uncounted
+            step_rows.append(per_shard)
+            # expand (li, ri) straight from the bounds already in hand
+            li = np.repeat(np.arange(len(lk)), counts)
+            offs = np.concatenate(([0], np.cumsum(counts[:-1]))) if len(
+                counts
+            ) else np.zeros(0, dtype=np.int64)
+            pos = np.arange(total) - np.repeat(offs, counts) + np.repeat(
+                lo, counts
+            )
+            ri = order[pos]
+            new_table = {v: c[li] for v, c in table.items()}
+            keep = np.ones(total, dtype=bool)
+            for v, c in ptab.items():
+                if v not in new_table:
+                    new_table[v] = c[ri]
+                elif v in extra:
+                    keep &= new_table[v] == c[ri]
+            table = {v: c[keep] for v, c in new_table.items()}
+            shard = shard[li][keep]
+        return step_rows, buckets, table, shard
+
+    def _count_clauses(self, table, shard) -> Tuple[int, int]:
+        """The clause stages' share of the two counts, from the main
+        chain's final ``table``: they run through the SAME static
+        buffers, so their chain intermediates, their clause-join totals
+        and the grown post-OPTIONAL tables all have to fit, or the first
+        dispatch overflows and pays recompiles at doubled caps.  A clause
+        join and its routes are counted as a shard's even share of the
+        rows (they hash on shared-key tuples); the DISTINCT exchange by
+        its largest (source, destination) pair."""
+        n = self.n
+        max_step = max_bucket = 0
+
+        def rows(t):
+            return len(next(iter(t.values()))) if t else 0
+
+        def share(total):
+            return -(-total // n)
 
         def count_and_join(table, btable, keys):
-            """Clause join on the mesh program's shared-key route: returns
+            """Clause join on the mesh program's shared-key route:
             (pre-mask join total, joined table restricted to the host
             emulation's needs) — sizes the ``join_cap`` the ``_dj`` of
             this clause must hold."""
             from kolibrie_tpu.ops.join import _pack_shared_keys, join_indices
 
-            ln = len(next(iter(table.values()))) if table else 0
-            rn = len(next(iter(btable.values()))) if btable else 0
+            ln, rn = rows(table), rows(btable)
             if ln == 0 or rn == 0:
                 return 0, {
                     v: np.empty(0, dtype=np.uint32)
@@ -971,74 +1117,79 @@ class DistQueryExecutor:
             li, ri = join_indices(lk, rk)
             total = len(li)
             if total > self._CALIBRATE_ROW_LIMIT:
-                raise _Blowup
+                raise _Uncounted
             out = {v: c[li] for v, c in table.items()}
             for v, c in btable.items():
                 if v not in out:
                     out[v] = c[ri]
             return total, out
 
-        try:
-            max_rows, table = walk_chain(self.premises, self.seed, self.steps)
-            # Clause pipelines run through the SAME static buffers: their
-            # chain intermediates, their clause-join totals, and the
-            # grown post-OPTIONAL tables all have to fit, or the first
-            # dispatch overflows and pays recompiles at doubled caps.
-            for branches, gvars, gkeys in self.union_specs:
-                parts = []
-                for bprem, bseed, bsteps, _bf in branches:
-                    bmax, btab = walk_chain(bprem, bseed, bsteps)
-                    max_rows = max(max_rows, bmax)
-                    parts.append(btab)
-                un = sum(
-                    len(next(iter(t.values()))) if t else 0 for t in parts
+        def branch(bprem, bseed, bsteps):
+            nonlocal max_step, max_bucket
+            bsteps_rows, bbuckets, btab, _ = self._count_chain(
+                bprem, bseed, bsteps
+            )
+            bstep, bbucket = _largest(bsteps_rows, bbuckets)
+            max_step = max(max_step, bstep)
+            max_bucket = max(max_bucket, bbucket)
+            return btab
+
+        def routed(*tables):
+            nonlocal max_bucket
+            if n > 1:
+                max_bucket = max(
+                    [max_bucket] + [share(rows(t)) for t in tables]
                 )
-                ucols = {}
-                for v in gvars:
-                    ucols[v] = np.concatenate(
-                        [
-                            t[v]
-                            if v in t
-                            else np.zeros(
-                                len(next(iter(t.values()))) if t else 0,
-                                dtype=np.uint32,
-                            )
-                            for t in parts
-                        ]
-                    ) if parts else np.empty(0, dtype=np.uint32)
-                max_rows = max(max_rows, un)
-                total, table = count_and_join(table, ucols, gkeys)
-                max_rows = max(max_rows, total)
-            for oprem, oseed, osteps, _of, ovars, okeys in self.optional_specs:
-                bmax, btab = walk_chain(oprem, oseed, osteps)
-                max_rows = max(max_rows, bmax)
-                total, joined = count_and_join(table, btab, okeys)
-                # OPTIONAL output = matches + every left row (mesh concat)
-                grown = total + (
-                    len(next(iter(table.values()))) if table else 0
+
+        for branches, gvars, gkeys in self.union_specs:
+            parts = [
+                branch(bprem, bseed, bsteps)
+                for bprem, bseed, bsteps, _bf in branches
+            ]
+            ucols = {
+                v: np.concatenate(
+                    [
+                        t[v] if v in t else np.zeros(rows(t), dtype=np.uint32)
+                        for t in parts
+                    ]
                 )
-                if grown > self._CALIBRATE_ROW_LIMIT:
-                    raise _Blowup
-                max_rows = max(max_rows, grown)
-                n_l = len(next(iter(table.values()))) if table else 0
-                out = {}
-                for v in set(table) | set(joined):
-                    left_part = table.get(
-                        v, np.zeros(n_l, dtype=np.uint32)
-                    )
-                    join_part = joined.get(
-                        v, np.zeros(total, dtype=np.uint32)
-                    )
-                    out[v] = np.concatenate([join_part, left_part])
-                table = out
-            for bprem, bseed, bsteps, _bf, bkeys in self.anti:
-                bmax, _btab = walk_chain(bprem, bseed, bsteps)
-                max_rows = max(max_rows, bmax)  # anti only shrinks the main
-        except _Blowup:
-            return heuristic, heuristic
-        per_shard = -(-max(max_rows, 1) // self.n)
-        cap = round_cap(4 * per_shard, 256)
-        return cap, cap
+                for v in gvars
+            }
+            routed(table, ucols)
+            total, table = count_and_join(table, ucols, gkeys)
+            max_step = max(max_step, share(total))
+        for oprem, oseed, osteps, _of, ovars, okeys in self.optional_specs:
+            btab = branch(oprem, oseed, osteps)
+            routed(table, btab)
+            total, joined = count_and_join(table, btab, okeys)
+            # OPTIONAL output = matches + every left row (mesh concat)
+            n_l = rows(table)
+            if total + n_l > self._CALIBRATE_ROW_LIMIT:
+                raise _Uncounted
+            max_step = max(max_step, share(total + n_l))
+            table = {
+                v: np.concatenate(
+                    [
+                        joined.get(v, np.zeros(total, dtype=np.uint32)),
+                        table.get(v, np.zeros(n_l, dtype=np.uint32)),
+                    ]
+                )
+                for v in set(table) | set(joined)
+            }
+        for bprem, bseed, bsteps, _bf, _bkeys in self.anti:
+            routed(table, branch(bprem, bseed, bsteps))  # anti only shrinks
+        if self.query.distinct and n > 1 and rows(table):
+            if self.union_specs or self.optional_specs:
+                routed(table)  # a clause re-routed the rows
+            else:
+                h = table[self.out_vars[0]]
+                for v in self.out_vars[1:]:
+                    h = _mix32(h) ^ table[v]
+                max_bucket = max(
+                    max_bucket,
+                    int(np.bincount(shard * n + shard_of(h, n)).max()),
+                )
+        return max_step, max_bucket
 
     def _ensure_store(self) -> ShardedTripleStore:
         if self.store is None:

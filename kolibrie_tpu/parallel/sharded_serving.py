@@ -31,9 +31,14 @@ relations + delta-only transfer, arXiv:2311.02206):
    here (no device holds the whole store in the deployment this stands
    for).  ``slots`` is a class (a power of two, not below 8), not the
    group size, so a template has one executable per capacity pair for
-   every group up to the class.  The host merge pulls the live members'
-   rows only and is deterministic and identical to the solo path
-   (``_finish_select_table``).
+   every group up to the class.  A member's plan — the seed premise, the
+   step order it gives and the two capacities — is counted once on the
+   host from the store's sorted orders, on the template's first sight,
+   as the rows THIS body will count (``DistQueryExecutor._counted_plan``
+   with ``batched``), and pinned with the template: the members of every
+   later group are lowered with it.  The host merge pulls the live
+   members' rows only and is deterministic and identical to the solo
+   path (``_finish_select_table``).
 3. **Cross-cutting layers ride the shard hop.**  Deadlines are checked before
    dispatch (``shard.dispatch`` is also a fault-injection site), per-template
    breakers gate the group in the executor, per-shard span children and
@@ -125,6 +130,25 @@ _SHARD_CAP_HITS = _m.counter(
     "kolibrie_shard_exchange_cap_hits_total",
     "Dispatches that overflowed a join/exchange capacity and retried doubled",
 )
+_SHARD_PLANS = _m.counter(
+    "kolibrie_shard_plan_total",
+    "Templates planned for the mesh on their first sight, by where the "
+    "seed came from (counted: the host count of the chains; constants: "
+    "the most-constants rule, nothing could be counted)",
+    labels=("source",),
+)
+_SHARD_CAP_SLOTS = _m.counter(
+    "kolibrie_shard_cap_slots_total",
+    "Join and exchange slots the dispatched mesh executables were compiled "
+    "for: live members x shards x (join steps x join_cap + exchanges x "
+    "shards x bucket_cap)",
+)
+_SHARD_JOIN_ROWS = _m.counter(
+    "kolibrie_shard_join_rows_total",
+    "Rows the mesh programs counted into those slots: per live member and "
+    "shard the key matches of each join step and the rows each exchange "
+    "delivered (over cap_slots_total: the mesh path's occupancy)",
+)
 _SHARD_FALLBACKS = _m.counter(
     "kolibrie_shard_fallback_total",
     "Template groups the mesh path declined",
@@ -176,11 +200,13 @@ def _strmask_verdict(col, masks, f):
 def _join_presorted(lkey, lvalid, rsorted, order, cap):
     """:func:`dist_join.local_join_u32` against a PRE-sorted right side:
     identical ``(li, ri, valid, total)`` contract, minus the per-call
-    ``argsort`` — the batched body joins every ``lax.map`` member against
-    the same resident mirror, so the sort is loop-invariant and hoisted
-    to once per dispatch.  ``total`` counts UNFILTERED key matches (the
-    side premise's constant filters apply post-join), so the overflow
-    retry doubles against that looser bound."""
+    ``argsort`` — the batched body joins every live member of the loop
+    against the same resident mirror, so the sort is loop-invariant and
+    hoisted to once per dispatch.  ``total`` counts UNFILTERED key matches
+    (the side premise's constant filters apply post-join): that is the
+    number the host count sizes ``join_cap`` from
+    (``DistQueryExecutor._count_chain`` with ``batched``) and the overflow
+    retry doubles against."""
     ln, rn = lkey.shape[0], rsorted.shape[0]
     lk = jnp.where(lvalid, lkey.astype(jnp.uint32), _JLPAD)
     lo = jnp.searchsorted(rsorted, lk, side="left")
@@ -229,9 +255,17 @@ def _batched_body(
     read.  Premise ``consts`` here hold SLOT INDICES into the parameter
     vector (the template's constant-free twin), so every constant-variant
     of the template shares the executable too."""
+    from kolibrie_tpu.parallel.dist_query import exchanged_steps
+
     fs, fp, fo, fv, gs, gp, go, gv = (a[0] for a in state)
     masks = tuple(masks)
     fcols = (fs, fp, fo)
+    # Exchange elision (a trace-time decision; the program cache key
+    # covers seed/steps): a step whose join key the rows are already
+    # partitioned by is co-located and skips its all-to-all.
+    # Subject-keyed star joins — the dominant serving templates —
+    # exchange nothing.
+    exchanged = exchanged_steps(premises, seed, steps, n)
 
     # Hoisted per-step side sorts: every member joins against the same
     # resident mirror, so the right-side argsort is loop-invariant —
@@ -268,24 +302,17 @@ def _batched_body(
         table, valid = scan_param(premises[seed], fcols, fv, prm)
         # Per-operator stats, SHARD-LOCAL (no psum: the host sees the
         # [slots, n, n_stats] block and can read imbalance per shard or sum
-        # across shards).  Layout: [seed rows, (exchange rows, join
-        # rows) per step, final rows] — exchange slot stays 0 when the
-        # step's all-to-all is elided by co-partitioning.
+        # across shards).  Layout: [seed rows, (exchange rows, key
+        # matches, join rows) per step, final rows] — exchange slot stays
+        # 0 when the step's all-to-all is elided by co-partitioning; the
+        # key matches are what ``join_cap`` has to hold, the join rows
+        # what the side premise's constants leave of them.
         svec = [jnp.sum(valid).astype(jnp.int32)]
-        # Partition tracking for exchange elision: the seed scans the
-        # subject-partitioned mirror, so rows start partitioned by the
-        # seed's subject var; the side mirrors are partitioned by their
-        # probe key, so a step whose join key equals the current
-        # partition var is already co-located and the all-to-all is an
-        # identity permutation — skip it (trace-time decision; the
-        # program cache key covers seed/steps).  Subject-keyed star
-        # joins — the dominant serving templates — exchange nothing.
-        part = next((v for v, pos in premises[seed].vars if pos == 0), None)
-        for (j, kv, kpos, extra), (side_cols, order, rsorted) in zip(
-            steps, sides
+        for (j, kv, kpos, extra), routed, (side_cols, order, rsorted) in zip(
+            steps, exchanged, sides
         ):
             prem = premises[j]
-            if n > 1 and kv != part:
+            if routed:
                 table, valid, dropped = _exchange_table(
                     table, valid, kv, n, axis, bucket_cap
                 )
@@ -293,13 +320,13 @@ def _batched_body(
                 svec.append(jnp.sum(valid).astype(jnp.int32))
             else:
                 svec.append(jnp.int32(0))
-            part = kv
             li, ri, jvalid, total = _join_presorted(
                 table[kv], valid, rsorted, order, join_cap
             )
             ov = ov + lax.psum(
                 jnp.maximum(total - join_cap, 0).astype(jnp.int32), axis
             )
+            svec.append(total.astype(jnp.int32))
             # side premise filters, post-join at the matched rows
             for c, col in zip(prem.consts, side_cols):
                 if c is not None:
@@ -546,7 +573,8 @@ class ShardedDatabase:
 
     Owns the two hash mirrors (subject- and object-partitioned), the
     combined :class:`ShardedTripleStore` view the distributed executors
-    scan, per-template pinned capacities, and the batched dispatch path.
+    scan, per-template pinned plans (seed and capacities), and the batched
+    dispatch path.
     All mutating entry points hold :attr:`lock`; the executor calls them
     under the HTTP batcher's ``dispatch_lock`` as well."""
 
@@ -566,7 +594,10 @@ class ShardedDatabase:
         self._base_cap_s = 0
         self._base_cap_o = 0
         self._delta_cap = 0
-        self._caps: Dict[tuple, Tuple[int, int]] = {}  # guarded by: lock
+        # (fingerprint, base version) -> (seed, join_cap, bucket_cap): the
+        # plan a template got on its first sight, and the capacities that
+        # held since
+        self._plans: Dict[tuple, Tuple[int, int, int]] = {}  # guarded by: lock
         self.stats_counters = {
             "base_rebuilds": 0,
             "delta_refreshes": 0,
@@ -676,11 +707,11 @@ class ShardedDatabase:
 
     # ------------------------------------------------------------ execution
 
-    def _pinned_caps(self, fp: str) -> Optional[Tuple[int, int]]:  # kolint: holds[lock]
+    def _pinned_plan(self, fp: str) -> Optional[Tuple[int, int, int]]:  # kolint: holds[lock]
         bv = self._sig[0] if self._sig else None
-        for k in [k for k in self._caps if k[1] != bv]:
-            self._caps.pop(k)
-        return self._caps.get((fp, bv))
+        for k in [k for k in self._plans if k[1] != bv]:
+            self._plans.pop(k)
+        return self._plans.get((fp, bv))
 
     def execute(self, sparql: str) -> List[List[str]]:
         """Solo mesh execution of one SELECT (bench/diagnostic path; the
@@ -779,24 +810,43 @@ class ShardedDatabase:
         )
         from kolibrie_tpu.reasoner.device_fixpoint import LoweredPremise
 
-        caps = self._pinned_caps(fp)
-        if caps is None:
-            # base-version bump dropped the pinned caps (mutation
-            # workloads do this constantly) — start from the advisor's
-            # process-wide high-water mark instead of the static
-            # defaults, so steady state re-dispatches without a single
-            # doubled-cap retry
-            advised = cap_advisor.advise("sharded", fp)
-            if advised is not None and len(advised) == 2:
-                caps = (int(advised[0]), int(advised[1]))
-        kw = {"join_cap": caps[0], "bucket_cap": caps[1]} if caps else {}
+        # The plan is the template's, not the member's: on a template's
+        # first sight (or after a base-version bump dropped the pin) the
+        # first member's constants are counted on the host — the seed, its
+        # step order and both capacities, for the joins ``_batched_body``
+        # runs — and every member of this and every later group is
+        # lowered with that plan, so the group shares one shape and the
+        # template one executable a capacity pair.
+        plan = self._pinned_plan(fp)
+        kw = (
+            {"seed": plan[0], "join_cap": plan[1], "bucket_cap": plan[2]}
+            if plan
+            else {}
+        )
         try:
             exemplar = DistQueryExecutor(
-                self.mesh, self.db, items[0][1], store=self.view, **kw
+                self.mesh,
+                self.db,
+                items[0][1],
+                store=self.view,
+                batched=True,
+                **kw,
             )
         except Unsupported:
             self._decline("unsupported")
             raise
+        if plan is None:
+            _SHARD_PLANS.labels(exemplar.plan_source).inc()
+            # capacities never start below the advisor's process-wide
+            # high-water mark (mutation workloads bump the base version
+            # constantly), so steady state re-dispatches without a single
+            # doubled-cap retry
+            advised = cap_advisor.advise("sharded", fp)
+            if advised is not None and len(advised) == 2:
+                exemplar.join_cap = max(exemplar.join_cap, int(advised[0]))
+                exemplar.bucket_cap = max(
+                    exemplar.bucket_cap, int(advised[1])
+                )
         if (
             exemplar.agg_items
             or exemplar.query.group_by
@@ -819,8 +869,10 @@ class ShardedDatabase:
                     self.db,
                     text,
                     store=self.view,
+                    seed=exemplar.seed,
                     join_cap=exemplar.join_cap,
                     bucket_cap=exemplar.bucket_cap,
+                    batched=True,
                 )
             )
         # structural agreement: the group shares one constant-free
@@ -943,14 +995,15 @@ class ShardedDatabase:
         execs = group["execs"]
         exemplar, live = execs[0], len(execs)
         outs, valid, shard_stats = device_out
+        # the per-operator counts ride the result transfer (about 1 KB a
+        # dispatch): the occupancy counters read them on every dispatch,
+        # an analyze capture records them per member
+        stats_np = group["stats"] = np.asarray(shard_stats)[:live]
         cap_rec = _analyze.active()
         if cap_rec is not None:
-            # stats ride the result transfer; materialized ONLY under an
-            # active analyze capture
-            stats_np = np.asarray(shard_stats)[:live]
             stat_names = ["seed"]
             for k in range(len(exemplar.steps)):
-                stat_names += [f"exchange{k}", f"join{k}"]
+                stat_names += [f"exchange{k}", f"matches{k}", f"join{k}"]
             stat_names.append("final")
             for r in range(live):
                 cap_rec.record(
@@ -958,6 +1011,7 @@ class ShardedDatabase:
                     member=r,
                     template=fp,
                     shards=self.n,
+                    seed=exemplar.seed,
                     steps=[(j, kv) for (j, kv, _kp, _ex) in exemplar.steps],
                     stat_names=stat_names,
                     per_shard=stats_np[r].T.tolist(),
@@ -989,32 +1043,45 @@ class ShardedDatabase:
         return results
 
     def _count_dispatch(self, fp: str, group: dict) -> None:  # kolint: holds[lock]
-        """Pin the capacities that held and count the dispatch: live
-        members beside the slots it was compiled for (their ratio is the
-        loop's occupancy), rows scanned, static exchange bytes."""
+        """Pin the plan with the capacities that held and count the
+        dispatch: live members beside the member slots it was compiled
+        for (their ratio is the loop's occupancy), the rows the program
+        counted beside its join and exchange slots (the capacities'
+        occupancy), rows scanned, static exchange bytes."""
+        from kolibrie_tpu.parallel.dist_query import exchanged_steps
 
         exemplar, live = group["execs"][0], len(group["execs"])
         join_cap, bucket_cap = group["caps"]
         bv = self._sig[0]
-        self._caps[(fp, bv)] = (join_cap, bucket_cap)
+        self._plans[(fp, bv)] = (exemplar.seed, join_cap, bucket_cap)
         cap_advisor.observe(
             "sharded", fp, (join_cap, bucket_cap), base_version=bv
         )
         occ_total = int(self._subj.occupancy().sum())
         n_scans = 1 + len(exemplar.steps)
         _SHARD_ROWS.inc(occ_total * n_scans * live)
-        seed_vars = exemplar.premises[exemplar.seed].vars
-        width = len({v for v, _ in seed_vars})
+        width = len({v for v, _ in exemplar.premises[exemplar.seed].vars})
         xbytes = 0
-        # mirror _batched_body's elision: co-partitioned steps move no
-        # bytes
-        part = next((v for v, pos in seed_vars if pos == 0), None)
-        for (j, kv, _kpos, _extra) in exemplar.steps:
-            if self.n > 1 and kv != part:
+        # co-partitioned steps move no bytes
+        exchanged = exchanged_steps(
+            exemplar.premises, exemplar.seed, exemplar.steps, self.n
+        )
+        for (j, _kv, _kpos, _extra), routed in zip(exemplar.steps, exchanged):
+            if routed:
                 xbytes += width * self.n * self.n * bucket_cap * 4
-            part = kv
             width += len({v for v, _ in exemplar.premises[j].vars})
         _SHARD_XBYTES.inc(xbytes * live)
+        _SHARD_CAP_SLOTS.inc(
+            live
+            * self.n
+            * (
+                len(exemplar.steps) * join_cap
+                + sum(exchanged) * self.n * bucket_cap
+            )
+        )
+        # stats layout: [seed, (exchange, matches, join) a step, final]
+        counted = group["stats"][:, :, 1:-1].reshape(live, self.n, -1, 3)
+        _SHARD_JOIN_ROWS.inc(int(counted[..., :2].sum()))
         _SHARD_DISPATCH.labels("lone" if live == 1 else "batched").inc()
         _SHARD_QUERIES.inc(live)
         _SHARD_MEMBER_SLOTS.inc(group["params"].shape[0])

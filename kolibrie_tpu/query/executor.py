@@ -1119,7 +1119,7 @@ def _plan_cache_entry(db, sparql: str):
         # stale-base-version slots pin device-resident copies of OLD store
         # orders (a LoweredPlan holds full sorted-store copies): drop
         # them, keeping only the live base's udf/mode variants (same
-        # policy as dist_query's _dist_cap_cache)
+        # policy as dist_query's _dist_plan_cache)
         for k in [k for k in tent["by_state"] if k[0] != version]:
             tent["by_state"].pop(k)
         slot = {

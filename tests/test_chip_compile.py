@@ -222,35 +222,41 @@ def test_mesh_program_lubm_q7_four_chips(topo, mesh8):
     """The mesh serving program (``sharded_serving._batched_body``: the
     live-member loop with its ``all_to_all`` inside) for the four chips
     of the described host, at the widths the cell ``lubm5.mesh4`` runs:
-    262,144-slot shards, the capacities LUBM(5) settles at, slot class 8.
-    The template's lowering comes from a small store on CPU devices."""
+    262,144-slot shards, the plan and the capacities the host count gives
+    Q7 (from the professor, 1,024 · 1,024), slot class 8.  The template's
+    lowering comes from LUBM(1) of the cell's generator on CPU devices."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from benches import lubm
     from benchmark.harness import data as bench_files
     from kolibrie_tpu.parallel import make_mesh
     from kolibrie_tpu.parallel import sharded_serving as ss
     from kolibrie_tpu.query.executor import _plan_cache_entry
     from kolibrie_tpu.query.sparql_database import SparqlDatabase
 
+    config = bench_files.read_json("configs", "lubm-5-mesh4.json")
+    data = bench_files.load_module("generators", config["generator"]).generate(
+        config, 7, 1)
     db = SparqlDatabase()
-    s, p, o = lubm.generate_fast(1, db.dictionary)
-    db.store.add_batch(s, p, o)
+    ids = np.array([db.dictionary.encode(t[1:-1] if t.startswith("<") else t)
+                    for t in data["terms"]], dtype=np.uint32)
+    db.store.add_batch(ids[data["s"]], ids[data["p"]], ids[data["o"]])
     db.execution_mode = "host"
     sh = ss.attach_sharded(db, make_mesh(4))
     sh.refresh()
-    dept = "http://www.Department0.University0.edu"
-    text = bench_files.template_text("lubm_q7").replace("@department@", dept)
+    text = bench_files.template_text("lubm_q7").replace(
+        "@department@", data["domains"]["department"][0])
     db.register_prefixes_from_query(text)
     fp = _plan_cache_entry(db, text)[0]["fp"]
     with sh.lock:
         group = sh._build_group(fp, [(0, text)])
     ex = group["execs"][0]
+    assert ex.plan_source == "counted" and group["caps"] == (1024, 1024)
+    assert ex.premises[ex.seed].consts[0] is not None  # the professor
     assert any(kv != "x" for (_j, kv, _kp, _e) in ex.steps)  # an exchange
     mesh = Mesh(np.array(topo.devices).reshape(4), (sh.axis,))
     fn = ss._get_batched_fn(
         mesh, group["premises"], ex.seed, ex.steps, ex.filters, ex.out_vars,
-        len(group["masks"]), M1, M1, ss._slot_class(1),
+        len(group["masks"]), *group["caps"], ss._slot_class(1),
     )
     rows = NamedSharding(mesh, P(sh.axis, None))
     everywhere = NamedSharding(mesh, P())

@@ -124,6 +124,28 @@ class TestColumnarStore:
         assert got() == set(rows)
         assert got(s=7) == set()
 
+    @pytest.mark.parametrize(
+        "bound",
+        [{}, {"s": 1}, {"p": 10}, {"o": 100}, {"s": 1, "p": 10},
+         {"s": 2, "o": 102}, {"p": 10, "o": 100},
+         {"s": 1, "p": 10, "o": 101}, {"s": 7}],
+        ids=lambda b: "".join(sorted(b)) or "none",
+    )
+    def test_count_is_the_size_of_the_match(self, bound, monkeypatch):
+        from kolibrie_tpu.core.store import SortedOrder
+
+        rows = [(1, 10, 100), (1, 10, 101), (1, 11, 100), (2, 10, 100), (2, 12, 102)]
+        st = ColumnarTripleStore()
+        for r in rows:
+            st.add(*r)
+        want = sum(
+            all(r["spo".index(k)] == v for k, v in bound.items()) for r in rows
+        )
+        assert len(st.match(**bound)[0]) == want
+        # a range count on the sorted order: no row is sliced out
+        monkeypatch.setattr(SortedOrder, "slice_rows", None)
+        assert st.count(**bound) == want
+
     def test_bulk_batch(self):
         st = ColumnarTripleStore()
         n = 10_000

@@ -16,6 +16,7 @@ from kolibrie_tpu.parallel import make_mesh
 from kolibrie_tpu.parallel.dist_query import (
     DistQueryExecutor,
     Unsupported,
+    _largest,
     execute_query_distributed,
 )
 from kolibrie_tpu.query.executor import execute_query_volcano
@@ -55,6 +56,35 @@ def test_lubm_q9_agreement(mesh, lubm_db):
     dist = execute_query_distributed(lubm.LUBM_Q9, lubm_db, mesh)
     assert len(host) > 0
     assert dist == host
+
+
+Q7_SHAPED = """PREFIX rdf: <http://www.w3.org/1999/02/22-rdf-syntax-ns#>
+PREFIX ub: <http://swat.cse.lehigh.edu/onto/univ-bench.owl#>
+SELECT ?x ?y WHERE {
+    ?x rdf:type ub:UndergraduateStudent .
+    ?y rdf:type ub:Course .
+    ?x ub:takesCourse ?y .
+    <http://www.Department0.University0.edu/FullProfessor0> ub:teacherOf ?y
+}"""
+
+
+@pytest.mark.parametrize("seed", [None, 0, 3])
+def test_the_seed_is_counted_and_any_seed_answers_alike(mesh, lubm_db, seed):
+    host = execute_query_volcano(Q7_SHAPED, lubm_db)
+    assert len(host) > 0
+    ex = DistQueryExecutor(mesh, lubm_db, Q7_SHAPED, seed=seed)
+    if seed is None:
+        # one professor's course, not every undergraduate: the premise
+        # the host count makes cheapest, where most-constants takes 0
+        assert (ex.seed, ex.plan_source) == (3, "counted")
+        step, bucket = _largest(
+            *ex._count_chain(ex.premises, ex.seed, ex.steps)[:2]
+        )
+        assert 0 < 4 * step <= ex.join_cap == 1024
+        assert 0 < 4 * bucket <= ex.bucket_cap == 1024
+    else:
+        assert ex.seed == seed  # a caller's pin stands; its caps are counted
+    assert ex.run() == host
 
 
 def test_filter_and_distinct_agreement(mesh):

@@ -596,6 +596,295 @@ def test_http_lone_request_is_served_by_the_mesh(sharded_server):
     }
 
 
+# ------------------------------- a member's plan from counted rows (PR 29)
+
+Q7 = (Path(__file__).resolve().parent.parent
+      / "benchmark" / "templates" / "lubm_q7.rq").read_text()
+# a template whose cheapest seed depends on its constants: the advisees of
+# a professor against the takers of a course
+TWO_KEYS = (
+    PREFIX
+    + "SELECT ?x WHERE {{ ?x ub:advisor <{prof}> . ?x ub:takesCourse <{course}> . }}"
+)
+ADVISED_Q = (
+    PREFIX
+    + "SELECT ?x ?p ?c WHERE { ?x ub:advisor ?p . ?x ub:takesCourse ?c . }"
+)
+
+
+@pytest.fixture(scope="module")
+def uba(mesh8):
+    """LUBM(1) in UBA's distribution (the benchmark's generator, as the
+    cell ``lubm5.mesh4`` loads it) on the 8-device mesh, and Q7's text for
+    each of its departments."""
+    from benchmark.harness import data as files
+
+    config = files.read_json("configs", "lubm-5-mesh4.json")
+    data = files.load_module("generators", config["generator"]).generate(
+        config, 7, 1
+    )
+    db = SparqlDatabase()
+    ids = np.fromiter(
+        (
+            db.dictionary.encode(t[1:-1] if t.startswith("<") else t)
+            for t in data["terms"]
+        ),
+        dtype=np.uint32,
+        count=len(data["terms"]),
+    )
+    db.store.add_batch(ids[data["s"]], ids[data["p"]], ids[data["o"]])
+    db.execution_mode = "host"
+    sh = attach_sharded(db, mesh8)
+    sh.refresh()
+    texts = [
+        Q7.replace("@department@", d) for d in data["domains"]["department"]
+    ]
+    return db, sh, texts
+
+
+def _fp(db, text):
+    return _plan_cache_entry(db, text)[0]["fp"]
+
+
+def _plan_of(sh, fp):
+    with sh.lock:
+        return sh._pinned_plan(fp)
+
+
+def _slots(sh, ex, live, join_cap, bucket_cap):
+    """What ``kolibrie_shard_cap_slots_total`` counts for one dispatch."""
+    from kolibrie_tpu.parallel.dist_query import exchanged_steps
+
+    exchanges = sum(exchanged_steps(ex.premises, ex.seed, ex.steps, sh.n))
+    return live * sh.n * (
+        len(ex.steps) * join_cap + exchanges * sh.n * bucket_cap
+    )
+
+
+def test_q7_starts_from_the_professor_and_settles_in_one_program(uba):
+    db, sh, texts = uba
+    fp = _fp(db, texts[0])
+    programs = sharded_compile_stats()["batched_programs"]
+    hits = sh.stats_counters["cap_hits"]
+    counted = _metric('kolibrie_shard_plan_total{source="counted"}')
+    got = sh.execute_batch(fp, [(0, texts[0])])
+    oracle = execute_query_volcano(texts[0], db)
+    assert got[0] == oracle and len(oracle) > 0
+    seed, join_cap, bucket_cap = _plan_of(sh, fp)
+    with sh.lock:
+        exemplar = sh._build_group(fp, [(0, texts[0])])["execs"][0]
+    # the premise with the professor as its subject: 2-4 rows, where the
+    # most-constants rule starts from every student
+    assert exemplar.premises[seed].consts[0] is not None
+    assert [j for j, *_ in exemplar.steps] == [1, 2, 0]
+    assert (join_cap, bucket_cap) == (1024, 1024)
+    assert sharded_compile_stats()["batched_programs"] == programs + 1
+    assert sh.stats_counters["cap_hits"] == hits
+    assert _metric('kolibrie_shard_plan_total{source="counted"}') == counted + 1
+    # every other department runs the plan and the executable of the first
+    items = list(enumerate(texts[1:9]))
+    got = sh.execute_batch(fp, items)
+    assert [got[i] for i, _ in items] == [
+        execute_query_volcano(t, db) for _, t in items
+    ]
+    assert _plan_of(sh, fp) == (seed, 1024, 1024)
+    assert sharded_compile_stats()["batched_programs"] == programs + 1
+    assert sh.stats_counters["cap_hits"] == hits
+    assert _metric('kolibrie_shard_plan_total{source="counted"}') == counted + 1
+
+
+@pytest.mark.parametrize(
+    "body, seed", [("batched", None), ("batched", 0), ("solo", None)]
+)
+def test_the_host_counts_what_the_program_counts(uba, body, seed):
+    """Capacities set to the host's two counts exactly hold the program's
+    rows, and one slot fewer in either overflows: the largest per-shard
+    join step (unfiltered key matches for the batched body, the side
+    premise's own rows for the solo one) and the largest (source,
+    destination) bucket."""
+    from kolibrie_tpu.parallel.dist_query import DistQueryExecutor
+    from kolibrie_tpu.parallel.sharded_serving import _get_batched_fn
+    import jax
+
+    db, sh, texts = uba
+    text = texts[3]
+    ex = DistQueryExecutor(
+        sh.mesh, db, text, store=sh.view, seed=seed, batched=body == "batched"
+    )
+    from kolibrie_tpu.parallel.dist_query import _largest
+
+    step_rows, buckets, _table, _shard = ex._count_chain(
+        ex.premises, ex.seed, ex.steps
+    )
+    step, bucket = _largest(step_rows, buckets)
+    assert step > 1 and bucket > 1
+    if seed == 0:
+        # from every student the last step meets every triple that has a
+        # course as its object: three orders of magnitude over the answer
+        assert step > 1000 * len(execute_query_volcano(text, db))
+
+    def overflows(join_cap, bucket_cap):
+        if body == "solo":
+            ex.join_cap, ex.bucket_cap = join_cap, bucket_cap
+            try:
+                ex.run_device(max_attempts=1)
+            except RuntimeError:
+                return True, None
+            return False, None
+        with sh.lock:
+            group = sh._build_group(_fp(db, text), [(0, text)])
+        state = (
+            *sh.view.by_subj, sh.view.by_subj_valid,
+            *sh.view.by_obj, sh.view.by_obj_valid,
+        )
+        fn = _get_batched_fn(
+            sh.mesh, group["premises"], ex.seed, ex.steps, ex.filters,
+            ex.out_vars, len(group["masks"]), join_cap, bucket_cap, 8,
+        )
+        with jax.enable_x64(True):
+            _outs, _valid, overflow, stats = fn(
+                state, group["masks"], group["params"], np.int32(1)
+            )
+        return int(np.asarray(overflow)[0]) > 0, np.asarray(stats)[0]
+
+    over, stats = overflows(step, bucket)
+    assert not over
+    if stats is not None:
+        # [seed, (exchange, matches, join) a step, final], per shard
+        for k, per_shard in enumerate(step_rows):
+            assert stats[:, 2 + 3 * k].tolist() == per_shard.tolist()
+    assert overflows(step - 1, bucket)[0]
+    assert overflows(step, bucket - 1)[0]
+
+
+def test_members_that_alone_would_seed_differently_share_one_plan(uba):
+    from kolibrie_tpu.parallel.dist_query import DistQueryExecutor
+
+    db, sh, _ = uba
+    rows = execute_query_volcano(ADVISED_Q, db)
+    texts, alone = [], []
+    for _x, prof, course in rows[:: max(1, len(rows) // 60)]:
+        text = TWO_KEYS.format(prof=prof, course=course)
+        if text not in texts:
+            texts.append(text)
+            alone.append(
+                DistQueryExecutor(
+                    sh.mesh, db, text, store=sh.view, batched=True
+                ).seed
+            )
+    first = texts[0]
+    other = texts[[s != alone[0] for s in alone].index(True)]
+    rest = [t for t in texts if t not in (first, other)][:4]
+    fp = _fp(db, first)
+    programs = sharded_compile_stats()["batched_programs"]
+    before = _routes()
+    got = sh.execute_batch(fp, [(0, first), (1, other)])
+    assert [got[0], got[1]] == [
+        execute_query_volcano(t, db) for t in (first, other)
+    ]
+    assert len(got[0]) > 0 and len(got[1]) > 0
+    # one executable, the first member's seed, nothing declined
+    assert _plan_of(sh, fp)[0] == alone[0]
+    assert sharded_compile_stats()["batched_programs"] == programs + 1
+    assert _grew(before, _routes())["fallbacks"] == 0
+    # a later group, other constants: the pinned plan, no program
+    got = sh.execute_batch(fp, list(enumerate(rest)))
+    assert [got[i] for i in range(len(rest))] == [
+        execute_query_volcano(t, db) for t in rest
+    ]
+    assert sharded_compile_stats()["batched_programs"] == programs + 1
+    assert _grew(before, _routes())["fallbacks"] == 0
+
+
+def test_a_constant_over_four_times_the_count_retries_doubled(mesh8):
+    # the smaller capacity is sound only because the overflow retry stands
+    # behind it: <small> calibrates the floor, <big> counts 12 times it
+    db = SparqlDatabase()
+    ex = "http://example.org/"
+    lines = [f"<{ex}small> <{ex}p1> <{ex}y0> ."]
+    for i in range(3000):
+        lines.append(f"<{ex}big> <{ex}p1> <{ex}y{i}> .")
+        for j in range(4):
+            lines.append(f"<{ex}y{i}> <{ex}p2> <{ex}z{i}_{j}> .")
+    db.parse_ntriples("\n".join(lines))
+    db.execution_mode = "host"
+    sh = attach_sharded(db, mesh8)
+    sh.refresh()
+    text = "SELECT ?y ?z WHERE {{ <%s{}> <%sp1> ?y . ?y <%sp2> ?z . }}" % (
+        ex, ex, ex
+    )
+    small, big = text.format("small"), text.format("big")
+    fp = _fp(db, small)
+    assert sh.execute_batch(fp, [(0, small)])[0] == execute_query_volcano(
+        small, db
+    )
+    seed, join_cap, bucket_cap = _plan_of(sh, fp)
+    assert (join_cap, bucket_cap) == (1024, 1024)
+    hits = sh.stats_counters["cap_hits"]
+    got = sh.execute_batch(fp, [(0, big)])[0]
+    assert got == execute_query_volcano(big, db) and len(got) == 12000
+    assert sh.stats_counters["cap_hits"] > hits
+    grown = _plan_of(sh, fp)
+    assert grown[0] == seed and grown[1] >= 2 * join_cap
+    assert grown[1] * 8 >= 12000
+    # the capacities that held stay: the small constant builds no program
+    programs = sharded_compile_stats()["batched_programs"]
+    assert sh.execute_batch(fp, [(0, small)])[0] == execute_query_volcano(
+        small, db
+    )
+    assert _plan_of(sh, fp) == grown
+    assert sharded_compile_stats()["batched_programs"] == programs
+
+
+@pytest.mark.parametrize("source", ["counted", "constants"])
+def test_plan_and_occupancy_counters(uba, source, monkeypatch):
+    from kolibrie_tpu.obs import analyze as obs_analyze
+    from kolibrie_tpu.parallel.dist_query import DistQueryExecutor
+
+    db, sh, texts = uba
+    if source == "constants":
+        # nothing can be counted: the most-constants seed, the heuristic
+        monkeypatch.setattr(DistQueryExecutor, "_CALIBRATE_ROW_LIMIT", 0)
+    with sh.lock:
+        sh._plans.clear()
+        db.__dict__.pop("_dist_plan_cache", None)
+    items = list(enumerate(texts[10:13]))
+    fp = _fp(db, items[0][1])
+    names = {
+        "plans": 'kolibrie_shard_plan_total{source="%s"}' % source,
+        "all_plans": "kolibrie_shard_plan_total",
+        "slots": "kolibrie_shard_cap_slots_total",
+        "rows": "kolibrie_shard_join_rows_total",
+    }
+    before = {k: _metric(v) for k, v in names.items()}
+    with obs_analyze.capture() as cap:
+        got = sh.execute_batch(fp, items)
+    grew = {k: _metric(v) - before[k] for k, v in names.items()}
+    assert [got[i] for i, _ in items] == [
+        execute_query_volcano(t, db) for _, t in items
+    ]
+    seed, join_cap, bucket_cap = _plan_of(sh, fp)
+    with sh.lock:
+        exemplar = sh._build_group(fp, items[:1])["execs"][0]
+    assert exemplar.seed == seed
+    assert (seed == 0) == (source == "constants")
+    assert grew["plans"] == grew["all_plans"] == 1
+    assert grew["slots"] == _slots(sh, exemplar, 3, join_cap, bucket_cap)
+    recs = [r for r in cap.records if r["kind"] == "sharded"]
+    assert [r["seed"] for r in recs] == [seed] * 3
+    assert grew["rows"] == sum(
+        count
+        for r in recs
+        for name, count in r["operators"].items()
+        if name.startswith(("exchange", "matches"))
+    ) > 0
+    # the occupancy the two counters give: a few per cent at the floor,
+    # and what the most-constants plan honestly fills its slots with
+    assert 0 < grew["rows"] <= grew["slots"]
+    with sh.lock:
+        sh._plans.clear()  # the next test plans for itself
+
+
 # ------------------------------------------------ EXPLAIN ANALYZE (ISSUE 14)
 
 
