@@ -17,7 +17,6 @@ import sys
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
-sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "benches"))
 
 flags = os.environ.get("XLA_FLAGS", "")
 if "host_platform_device_count" not in flags:

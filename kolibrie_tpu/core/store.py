@@ -410,7 +410,8 @@ class ColumnarTripleStore:
         self.delta_threshold = self.DELTA_THRESHOLD_DEFAULT
         #: Kill switch: False forces every compaction down the full
         #: rebuild-and-merge path (pre-segmentation behavior; every batch
-        #: bumps base_version).  The ingest bench uses it as the oracle.
+        #: bumps base_version).  ``tests/test_store_delta.py`` uses it as
+        #: the oracle.
         self.incremental = True
 
     # ------------------------------------------------------------- mutation

@@ -73,22 +73,6 @@ def _declare(lib: ctypes.CDLL) -> ctypes.CDLL:
         "kn_ttl_prefixes_len": ([ptr], i64),
         "kn_ttl_prefixes": ([ptr, c.c_char_p], None),
         "kn_ttl_free": ([ptr], None),
-        "kn_join_u32": (
-            [
-                c.POINTER(c.c_uint32),
-                i64,
-                c.POINTER(c.c_uint32),
-                i64,
-                c.POINTER(c.c_uint32),
-                c.POINTER(c.c_uint32),
-                i64,
-            ],
-            i64,
-        ),
-        "kn_gather_u32": (
-            [c.POINTER(c.c_uint32), c.POINTER(c.c_uint32), i64, c.POINTER(c.c_uint32)],
-            None,
-        ),
     }
     for name, (argtypes, restype) in sigs.items():
         fn = getattr(lib, name)

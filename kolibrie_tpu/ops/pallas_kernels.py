@@ -126,29 +126,18 @@ def pallas_mode() -> str:
 
     The mode participates in the template fingerprint and the executor's
     ``env_sig`` exactly like ``KOLIBRIE_WCOJ`` / ``KOLIBRIE_PLAN_INTERP``:
-    a mode flip lands in a fresh plan slot, never a stale replay.
-
-    DEPRECATED: the former per-subsystem ``KOLIBRIE_PALLAS_JOIN`` (0/1)
-    and ``KOLIBRIE_PALLAS_DIST`` flags are honored as shims when
-    ``KOLIBRIE_PALLAS`` is unset — ``_JOIN=1`` maps to ``force``,
-    ``_JOIN=0`` to ``off`` — and will be removed; set ``KOLIBRIE_PALLAS``
-    instead.  An unrecognized value falls back to ``auto``.
+    a mode flip lands in a fresh plan slot, never a stale replay.  An
+    unrecognized value falls back to ``auto``.
     """
     import os
 
-    env = os.environ.get("KOLIBRIE_PALLAS")
-    if env is not None:
-        v = env.strip().lower()
-        if v in _PALLAS_MODES:
-            return v
-        if v in ("0", "false"):
-            return "off"
-        if v in ("1", "true"):
-            return "force"
-        return "auto"
-    legacy = os.environ.get("KOLIBRIE_PALLAS_JOIN")
-    if legacy is not None:  # deprecated shim (see docstring)
-        return "force" if legacy != "0" else "off"
+    v = os.environ.get("KOLIBRIE_PALLAS", "auto").strip().lower()
+    if v in _PALLAS_MODES:
+        return v
+    if v in ("0", "false"):
+        return "off"
+    if v in ("1", "true"):
+        return "force"
     return "auto"
 
 
@@ -161,12 +150,6 @@ def pallas_enabled() -> bool:
     if mode == "off":
         return False
     return jax.default_backend() == "tpu"
-
-
-def pallas_join_enabled() -> bool:
-    """DEPRECATED alias of :func:`pallas_enabled` (pre-unification name;
-    kept for external callers of the old per-subsystem switch)."""
-    return pallas_enabled()
 
 
 # ---------------------------------------------------------------------------

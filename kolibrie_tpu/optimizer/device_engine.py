@@ -36,10 +36,7 @@ table, which is the right split — results are small next to the store.)
 
 Capacity / readback protocol: join
 capacities are estimated, validated by reading the true match counts once,
-and cached per plan shape on the database.  ``PreparedQuery`` additionally
-separates ``calibrate()`` (readback allowed, runs a distinct calibration
-executable) from ``run()`` (dispatch only) so benchmarks can time a
-never-read executable, then ``fetch()`` results afterwards.
+and cached per plan shape on the database.
 """
 
 from __future__ import annotations
@@ -70,7 +67,6 @@ __all__ = [
     "Unsupported",
     "lower_plan",
     "try_device_execute",
-    "PreparedQuery",
     "execute_plan_batch",
     "device_compile_stats",
     "template_scan_cap",
@@ -1086,10 +1082,10 @@ def _jit_entries(fn) -> int:
 
 def device_compile_stats() -> Dict[str, int]:
     """Per-entry-point jit cache sizes — the compile counter the template
-    tests/bench assert on (a recompile ⇒ a new cache entry)."""
+    tests and ``compiles_in_window`` read (a recompile ⇒ a new cache
+    entry)."""
     out = {
         "run_plan": _jit_entries(_run_plan),
-        "run_plan_k": _jit_entries(_run_plan_k),
         "run_plan_batch": _jit_entries(_run_plan_batch),
     }
     from kolibrie_tpu.optimizer.plan_interp import interp_compile_stats
@@ -1118,42 +1114,6 @@ def _classify_source(jit_before: int, cc_before: Dict[str, int]) -> str:
     ] == cc_before.get("misses", 0):
         return "disk"
     return "compiled"
-
-
-@partial(jax.jit, static_argnames=("spec", "k", "use_pallas"))
-def _run_plan_k(
-    spec: PlanSpec,
-    k: int,
-    use_pallas: bool,
-    order_arrays,
-    scalars,
-    masks,
-    values,
-    numf,
-    quoted,
-    params,
-):
-    """Execute the SAME compiled plan body ``k`` times in one dispatch with a
-    loop-carried dependency (benchmark amortization: per-dispatch latency
-    otherwise swamps sub-millisecond plans).  Returns
-    per-iteration checksums + row counts; the materialized result columns are
-    produced inside every iteration."""
-    import jax.numpy as jnp
-    from jax import lax
-
-    def body(carry, _):
-        # carry >= 0 always, so the shift is 0 at runtime — but XLA cannot
-        # hoist the iteration body because scalars depends on the carry
-        sc = scalars + (carry >> jnp.int64(62)).astype(scalars.dtype)
-        out, valid, _counts, _stats = _plan_body(
-            spec, order_arrays, sc, masks, values, numf, quoted, params, use_pallas
-        )
-        checksum = sum(c.astype(jnp.uint64).sum() for c in out)
-        nrows = jnp.sum(valid).astype(jnp.int64)
-        return nrows, (checksum, nrows)
-
-    _, (sums, rows) = lax.scan(body, jnp.int64(0), None, length=k)
-    return sums, rows
 
 
 # ---------------------------------------------------------------------------
@@ -2277,8 +2237,8 @@ class LoweredPlan:
     ) -> Tuple[BindingTable, List[int]]:
         """Evaluate the lowered IR with numpy — the executable-free reference
         semantics.  Returns (table, exact join counts).  Used to calibrate
-        join capacities without any device readback (benchmarks time a
-        never-read executable) and as the oracle in spec-semantics tests.
+        join capacities without any device readback and as the oracle in
+        spec-semantics tests.
         With ``row_limit`` a scan, join or WCOJ level of more rows raises
         :class:`kolibrie_tpu.ops.join.RowLimitExceeded` before it is
         materialized."""
@@ -2679,15 +2639,6 @@ class LoweredPlan:
         spec, args = _build_traced(self, tag)
         with jax.enable_x64(True):
             return _enqueue_traced(_run_plan, spec, pallas_enabled(), *args)
-
-    def run_k(self, k: int, tag: int = 0):
-        """``k`` plan executions amortized into one dispatch (see
-        :func:`_run_plan_k`); returns (checksums, row counts), no readback."""
-        from kolibrie_tpu.ops.pallas_kernels import pallas_enabled
-
-        spec, args = self.build(tag)
-        with jax.enable_x64(True):
-            return _run_plan_k(spec, k, pallas_enabled(), *args)
 
     def _store_caps(self) -> None:
         """Publish join capacities to the per-db template cache.  Merge is
@@ -4031,101 +3982,3 @@ def try_device_execute_ordered(db, q, cache_entry=None) -> Optional[List[List[st
     rows = format_results(db, table, q)
     start = q.offset or 0
     return rows[start : start + q.limit]
-
-
-# ---------------------------------------------------------------------------
-# Prepared queries (bench / repeated-execution API)
-# ---------------------------------------------------------------------------
-
-
-class PreparedQuery:
-    """Parse + plan + lower a SELECT once; execute on device many times.
-
-    ``calibrate()`` validates join capacities (reads counts from a separate
-    calibration executable), ``run()`` dispatches the real executable without
-    any host readback, ``fetch(out)`` decodes a run's results to rows.
-    """
-
-    def __init__(self, db, sparql: str):
-        from kolibrie_tpu.optimizer.planner import Streamertail, build_logical_plan
-        from kolibrie_tpu.optimizer.engine import resolve_pattern
-        from kolibrie_tpu.query.parser import parse_combined_query
-
-        db.register_prefixes_from_query(sparql)
-        cq = parse_combined_query(sparql, db.prefixes)
-        if cq.select is None:
-            raise Unsupported("prepared queries must be SELECTs")
-        self.db = db
-        self.query = cq.select
-        from kolibrie_tpu.query.ast import WhereClause
-        from kolibrie_tpu.query.executor import _branch_plan
-        from kolibrie_tpu.query.subquery_inline import inline_subqueries
-
-        # plain sub-SELECTs fold into the BGP (the rewrite every execution
-        # path applies), so e.g. the reference's nested-select benchmark
-        # shape (my_benchmark.rs:55-113) prepares as one device program;
-        # UNION/OPTIONAL/MINUS/NOT fuse as clause branches like the
-        # executor's device path
-        where = inline_subqueries(cq.select.where)
-        if where.subqueries or where.binds or where.window_blocks:
-            raise Unsupported("prepared device queries support BGP+FILTER only")
-        if not where.patterns:
-            raise Unsupported("prepared clause-only groups unsupported")
-        planner = Streamertail(db.get_or_build_stats())
-        union_groups, optional_plans, anti_plans = [], [], []
-        for groups in where.unions:
-            g = [_branch_plan(db, planner, bw) for bw in groups]
-            if any(bp is None for bp in g):
-                raise Unsupported("non-BGP UNION branch in prepared query")
-            union_groups.append(tuple(g))
-        for ow in where.optionals:
-            bp = _branch_plan(db, planner, ow)
-            if bp is None:
-                raise Unsupported("non-BGP OPTIONAL branch in prepared query")
-            optional_plans.append(bp)
-        for bw in list(where.minus) + [
-            WhereClause(patterns=nb.patterns) for nb in where.not_blocks
-        ]:
-            bp = _branch_plan(db, planner, bw)
-            if bp is None:
-                raise Unsupported("non-BGP MINUS/NOT branch in prepared query")
-            anti_plans.append(bp)
-        resolved = [resolve_pattern(db, p) for p in where.patterns]
-        logical = build_logical_plan(resolved, where.filters, [], where.values)
-        self.plan = planner.find_best_plan(logical)
-        self.lowered = lower_plan(
-            db,
-            self.plan,
-            tuple(anti_plans),
-            tuple(union_groups),
-            tuple(optional_plans),
-        )
-        if self.lowered.const_checks:
-            # run() is dispatch-only by contract; a store-dependent host
-            # guard between dispatches would break its timing semantics
-            raise Unsupported("prepared query with fully-constant pattern")
-
-    def calibrate(self) -> None:
-        """Converge join capacities via a host evaluation — zero device
-        readbacks, so subsequent ``run()`` dispatches stay unpoisoned."""
-        self.lowered.calibrate_host()
-
-    def run(self):
-        """Dispatch the production executable; NO host readback."""
-        return self.lowered.run(tag=0)
-
-    def run_amortized(self, k: int):
-        """One dispatch executing the plan ``k`` times (loop-carried scan);
-        returns (checksums, per-iteration row counts), no readback."""
-        return self.lowered.run_k(k)
-
-    def fetch(self, out) -> List[List[str]]:
-        """Decode a ``run()`` result to sorted string rows (readback here).
-
-        Join counts are validated against the capacities the run used; on
-        overflow (store grew past the calibrated caps) the capacities are
-        doubled and the query re-runs — no silent truncation."""
-        from kolibrie_tpu.query.executor import format_results
-
-        table = self.lowered.to_table(*self.lowered.converge(out))
-        return format_results(self.db, table, self.query, sort_rows=True)

@@ -39,7 +39,7 @@ def wcoj_mode() -> str:
     ``auto`` (default) routes CYCLIC basic graph patterns to the WCOJ
     node and keeps acyclic chains on the Volcano binary-join path;
     ``off`` disables WCOJ; ``force`` routes every eligible connected
-    group of >= 2 patterns (test/bench hook).  Read per planning call —
+    group of >= 2 patterns (test hook).  Read per planning call —
     the template fingerprint folds the mode in, so flipping it never
     replays a plan cached under the other strategy."""
     mode = os.environ.get("KOLIBRIE_WCOJ", "auto").strip().lower()
@@ -274,7 +274,7 @@ class Streamertail:
         variables, at least one variable each), the join graph connected,
         and — in ``auto`` mode — GYO-cyclic, the shapes where Volcano
         binary-join intermediates exceed the AGM output bound.  ``force``
-        mode (tests/benches) relaxes to any connected group of >= 2."""
+        mode (tests) relaxes to any connected group of >= 2."""
         mode = wcoj_mode()
         if mode == "off":
             return None
@@ -357,7 +357,7 @@ class Streamertail:
             # not a shape rule — the AGM-misrouted cyclic queries (LUBM
             # q9) come back to the binary-join path when the measured
             # funnel volume says so.  Auto mode only; ``force`` stays a
-            # test/bench override and cold templates keep the structural
+            # test override and cold templates keep the structural
             # routing (zero change vs the static router).
             if self.learned and wcoj_mode() == "auto":
                 alt = self._binary_join_plan(scans)

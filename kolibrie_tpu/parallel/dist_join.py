@@ -52,15 +52,7 @@ def dist_pallas_enabled() -> bool:
     shard_map+Pallas composition is validated on real hardware (see
     COVERAGE.md "remaining gaps").  EXPERIMENTAL — read at TRACE time, so
     the mode must be set before the first round program of a process is
-    built (the compiled-program caches do not key on it).
-
-    DEPRECATED shim: ``KOLIBRIE_PALLAS_DIST=1``/``0`` still wins when
-    set, for callers of the pre-unification flag."""
-    import os
-
-    legacy = os.environ.get("KOLIBRIE_PALLAS_DIST")
-    if legacy is not None:
-        return legacy == "1"
+    built (the compiled-program caches do not key on it)."""
     from kolibrie_tpu.ops.pallas_kernels import pallas_mode
 
     return pallas_mode() == "force"

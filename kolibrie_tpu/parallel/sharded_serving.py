@@ -94,7 +94,7 @@ __all__ = [
 _SHARD_DISPATCH = _m.counter(
     "kolibrie_shard_dispatch_total",
     "Mesh serving dispatches by path (batched: a group of several; lone: "
-    "a group of one; solo: ShardedDatabase.execute)",
+    "a group of one)",
     labels=("path",),
 )
 _SHARD_QUERIES = _m.counter(
@@ -712,29 +712,6 @@ class ShardedDatabase:
         for k in [k for k in self._plans if k[1] != bv]:
             self._plans.pop(k)
         return self._plans.get((fp, bv))
-
-    def execute(self, sparql: str) -> List[List[str]]:
-        """Solo mesh execution of one SELECT (bench/diagnostic path; the
-        serving integration dispatches template GROUPS via
-        :meth:`execute_batch`).  Raises :class:`Unsupported` for queries
-        the distributed lowering declines."""
-        from kolibrie_tpu.parallel.dist_query import DistQueryExecutor
-
-        with self.lock:
-            self.refresh()
-            check_deadline("shard.dispatch")
-            fault_point("shard.dispatch")
-            ex = DistQueryExecutor(
-                self.mesh, self.db, sparql, store=self.view
-            )
-            t0 = time.perf_counter()
-            with span("shard.dispatch", shards=self.n, batch=1):
-                rows = ex.run()
-            _SHARD_DISPATCH_LAT.observe(time.perf_counter() - t0)
-            _SHARD_DISPATCH.labels("solo").inc()
-            _SHARD_QUERIES.inc()
-            self.stats_counters["dispatches"] += 1
-            return rows
 
     def warm(self, sparql: str) -> bool:
         """Pre-compile the mesh program for one template off the request

@@ -769,47 +769,6 @@ class DeviceFixpoint:
             join=_round_cap(4 * n_facts, 1024),
         )
 
-    def run_raw(self, caps: Optional[_Caps] = None):
-        """One fixpoint dispatch with NO host readback.
-
-        Benchmark/timing API: returns the raw device outputs
-        ``(fs, fp, fo, n_facts, rounds, code)``; the caller must check
-        ``code == 0`` AFTER timing.
-        """
-        import jax.numpy as jnp
-
-        s, p, o = self.reasoner.facts.columns()
-        n0 = len(s)
-        caps = caps if caps is not None else self._caps(n0)
-        if not self.rules:
-            return (
-                jnp.asarray(s),
-                jnp.asarray(p),
-                jnp.asarray(o),
-                jnp.int32(n0),
-                jnp.int32(0),
-                jnp.int32(0),
-            )
-        masks = tuple(jnp.asarray(m) for m in self.bank.materialize()) or (
-            jnp.zeros(1, dtype=bool),
-        )
-
-        def pad(x):
-            return jnp.concatenate(
-                [
-                    jnp.asarray(x, dtype=jnp.uint32),
-                    jnp.zeros(caps.fact - len(x), dtype=jnp.uint32),
-                ]
-            )
-
-        from kolibrie_tpu.ops.pallas_kernels import pallas_join_enabled
-
-        with jax.enable_x64(True):
-            return _device_fixpoint(
-                self.rules, caps, pad(s), pad(p), pad(o), jnp.int32(n0), masks,
-                pallas_join_enabled(),
-            )
-
     def infer_padded(
         self,
         fs,
@@ -838,9 +797,9 @@ class DeviceFixpoint:
         masks = tuple(jnp.asarray(m) for m in self.bank.materialize()) or (
             jnp.zeros(1, dtype=bool),
         )
-        from kolibrie_tpu.ops.pallas_kernels import pallas_join_enabled
+        from kolibrie_tpu.ops.pallas_kernels import pallas_enabled
 
-        use_pallas = pallas_join_enabled()
+        use_pallas = pallas_enabled()
         for _attempt in range(max_attempts):
 
             def pad(x):
@@ -924,7 +883,6 @@ class DeviceFixpoint:
         join_cap: Optional[int] = None,
         delta_cap: Optional[int] = None,
         max_attempts: int = 64,
-        writeback: bool = True,
     ) -> int:
         """Host-driven per-round fixpoint for inputs past the one-dispatch
         program's toolchain-safe join capacity.
@@ -956,10 +914,10 @@ class DeviceFixpoint:
             # parameter on warm retraces, which the dispatch fast path
             # fails to feed once two capacity keys coexist (observed on
             # jax 0.9: "Executable expected parameter 0 of size 4...").
-            from kolibrie_tpu.ops.pallas_kernels import pallas_join_enabled
+            from kolibrie_tpu.ops.pallas_kernels import pallas_enabled
 
             return _device_round_chunk(
-                self.rules, caps, *dyn, use_pallas=pallas_join_enabled()
+                self.rules, caps, *dyn, use_pallas=pallas_enabled()
             )
 
         on_tpu = jax.default_backend() == "tpu"
@@ -1083,24 +1041,12 @@ class DeviceFixpoint:
             self.last_rounds = _round  # productive rounds (final is empty)
             _FIXPOINT_ROUNDS.observe(_round)
             self.converged_caps = _Caps(F, D, J)
-            # device-resident result; ``writeback=False`` lets callers (and
-            # benches) defer the bulk device→host transfer, which would
-            # otherwise sit inside the timed window
-            self._last_state = (fs, fp, fo, n_facts, n0)
-            if writeback:
-                return self.materialize_to_host()
+            if n_facts > n0:
+                s_h = np.asarray(fs[:n_facts])
+                p_h = np.asarray(fp[:n_facts])
+                o_h = np.asarray(fo[:n_facts])
+                r.facts.add_batch(s_h[n0:], p_h[n0:], o_h[n0:])
             return n_facts - n0
-
-    def materialize_to_host(self) -> int:
-        """Copy facts derived by the last ``infer_chunked(writeback=False)``
-        run into ``reasoner.facts``; returns the derived count."""
-        fs, fp, fo, n_facts, n0 = self._last_state
-        if n_facts > n0:
-            s_h = np.asarray(fs[:n_facts])
-            p_h = np.asarray(fp[:n_facts])
-            o_h = np.asarray(fo[:n_facts])
-            self.reasoner.facts.add_batch(s_h[n0:], p_h[n0:], o_h[n0:])
-        return n_facts - n0
 
 
 # Largest join capacity verified stable on the Mosaic toolchain it was

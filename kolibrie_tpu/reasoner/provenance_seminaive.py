@@ -141,9 +141,9 @@ def infer_with_provenance(
     # idempotent scalar semirings (minmax/boolean/expiration) above the
     # size threshold run the whole tagged fixpoint on device (tags as an
     # f64 column, ⊕=max ⊗=min); None → host loop below.  Auto-routing is
-    # TPU-only: the XLA CPU backend's sorts lose to the numpy host loop
-    # (see benches/bench_device_provenance.py), so CPU callers must opt in
-    # via infer_provenance_device directly.
+    # TPU-only: the XLA CPU backend's sorts lost to the numpy host loop
+    # (CPU sweep, before PR 22; not measured on the chip), so CPU callers
+    # must opt in via infer_provenance_device directly.
     from kolibrie_tpu.reasoner import device_provenance
 
     if (

@@ -89,12 +89,12 @@ class CrossWindowReasoningMode:
     AUTO = "auto"
 
 
-# AUTO threshold.  Measured sweep (benches/bench_cross_window.py +
-# bench_family_tree.py, recorded in PERF_r03.md): incremental wins
-# 1.4-2x at 1-2% updates and is break-even at the 10% points (speedup
-# 0.89-1.05), losing badly by 50%.  0.08 sits just under the measured
-# break-even; points between 10% and 50% were not measured, so the
-# threshold is conservative rather than interpolated.
+# AUTO threshold.  From a CPU sweep before PR 22 (cross-window and
+# family-tree rules at 1-50% updates; not measured on the chip):
+# incremental won at 1-2% updates, broke even at the 10% points and lost
+# badly by 50%.  0.08 sits just under that break-even; points between 10%
+# and 50% were not measured, so the threshold is conservative rather than
+# interpolated.
 _AUTO_MAX_CHURN = 0.08
 
 

@@ -1852,121 +1852,4 @@ void kn_ttl_prefixes(void *session, char *out) {
 
 void kn_ttl_free(void *session) { delete (TtlSession *)session; }
 
-// ─────────────────────── host join twin (baseline floor) ─────────────────
-//
-// Native twin of the host engine's sort-based equi-join
-// (kolibrie_tpu/ops/join.py::join_indices) — a threaded C++ floor for what
-// the reference's SIMD+rayon join loop
-// (shared/src/join_algorithm.rs:19-131) achieves on one node, so the
-// benchmark's "vs_baseline" divides by the strongest host engine in-repo
-// (max of the numpy engine and this) instead of numpy alone.
-//
-// Protocol: returns the TOTAL match count; (li, ri) are filled up to
-// ``cap`` pairs (row-index pairs with lk[li] == rk[ri], right-major order
-// within a left row, stable in the right's original order).  A return
-// value > cap means the caller's buffers were too small — retry bigger.
-
-int64_t kn_join_u32(const uint32_t *lk, int64_t ln, const uint32_t *rk,
-                    int64_t rn, uint32_t *li, uint32_t *ri, int64_t cap) {
-  if (ln == 0 || rn == 0) return 0;
-  // LSD radix sort (two 16-bit passes) of the right row indices by key —
-  // stable, matching np.argsort(kind="stable"); O(n) vs comparison sort
-  std::vector<uint32_t> perm((size_t)rn), tmp((size_t)rn);
-  {
-    std::vector<int64_t> hist(1 << 16);
-    // pass 1: low 16 bits
-    std::fill(hist.begin(), hist.end(), 0);
-    for (int64_t i = 0; i < rn; i++) hist[rk[i] & 0xFFFF]++;
-    int64_t run = 0;
-    for (auto &h : hist) { int64_t c = h; h = run; run += c; }
-    for (int64_t i = 0; i < rn; i++) tmp[hist[rk[i] & 0xFFFF]++] = (uint32_t)i;
-    // pass 2: high 16 bits
-    std::fill(hist.begin(), hist.end(), 0);
-    for (int64_t i = 0; i < rn; i++) hist[rk[i] >> 16]++;
-    run = 0;
-    for (auto &h : hist) { int64_t c = h; h = run; run += c; }
-    for (int64_t i = 0; i < rn; i++) perm[hist[rk[tmp[i]] >> 16]++] = tmp[i];
-  }
-  std::vector<uint32_t> rsorted((size_t)rn);
-  for (int64_t i = 0; i < rn; i++) rsorted[(size_t)i] = rk[perm[(size_t)i]];
-
-  unsigned hw = std::thread::hardware_concurrency();
-  int64_t nthreads = std::max<int64_t>(
-      1, std::min<int64_t>({(int64_t)(hw ? hw : 1), 16, 1 + ln / 8192}));
-  int64_t chunk = (ln + nthreads - 1) / nthreads;
-  // one search pass: store each left row's sorted-right span (lo, count)
-  std::vector<uint32_t> row_lo((size_t)ln), row_cnt((size_t)ln);
-  std::vector<int64_t> counts((size_t)nthreads, 0);
-  auto search_span = [&](int64_t lo_row, int64_t hi_row) {
-    int64_t c = 0;
-    const uint32_t *rs = rsorted.data();
-    for (int64_t i = lo_row; i < hi_row; i++) {
-      const uint32_t *a = std::lower_bound(rs, rs + rn, lk[i]);
-      const uint32_t *b = std::upper_bound(a, rs + rn, lk[i]);
-      row_lo[(size_t)i] = (uint32_t)(a - rs);
-      row_cnt[(size_t)i] = (uint32_t)(b - a);
-      c += b - a;
-    }
-    return c;
-  };
-  if (nthreads == 1) {
-    counts[0] = search_span(0, ln);
-  } else {
-    std::vector<std::thread> ts;
-    for (int64_t t = 0; t < nthreads; t++) {
-      ts.emplace_back([&, t] {
-        counts[(size_t)t] =
-            search_span(t * chunk, std::min(ln, (t + 1) * chunk));
-      });
-    }
-    for (auto &th : ts) th.join();
-  }
-  int64_t total = 0;
-  std::vector<int64_t> offsets((size_t)nthreads, 0);
-  for (int64_t t = 0; t < nthreads; t++) {
-    offsets[(size_t)t] = total;
-    total += counts[(size_t)t];
-  }
-  if (total > cap) return total;  // caller retries with bigger buffers
-  auto fill = [&](int64_t lo_row, int64_t hi_row, int64_t w) {
-    for (int64_t i = lo_row; i < hi_row; i++) {
-      uint32_t lo = row_lo[(size_t)i], cnt = row_cnt[(size_t)i];
-      for (uint32_t k = 0; k < cnt; k++) {
-        li[w] = (uint32_t)i;
-        ri[w] = perm[lo + k];
-        w++;
-      }
-    }
-  };
-  if (nthreads == 1) {
-    fill(0, ln, 0);
-  } else {
-    std::vector<std::thread> ts;
-    for (int64_t t = 0; t < nthreads; t++) {
-      ts.emplace_back([&, t] {
-        fill(t * chunk, std::min(ln, (t + 1) * chunk), offsets[(size_t)t]);
-      });
-    }
-    for (auto &th : ts) th.join();
-  }
-  return total;
-}
-
-// Threaded u32 gather: out[i] = src[idx[i]] (column materialization).
-void kn_gather_u32(const uint32_t *src, const uint32_t *idx, int64_t n,
-                   uint32_t *out) {
-  unsigned hw = std::thread::hardware_concurrency();
-  int64_t nthreads = std::max<int64_t>(1, std::min<int64_t>(hw ? hw : 1, 16));
-  if (n < 1 << 14) nthreads = 1;
-  int64_t chunk = (n + nthreads - 1) / nthreads;
-  std::vector<std::thread> ts;
-  for (int64_t t = 0; t < nthreads; t++) {
-    ts.emplace_back([&, t] {
-      int64_t lo = t * chunk, hi = std::min(n, lo + chunk);
-      for (int64_t i = lo; i < hi; i++) out[i] = src[idx[i]];
-    });
-  }
-  for (auto &th : ts) th.join();
-}
-
 }  // extern "C"

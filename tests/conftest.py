@@ -3,8 +3,8 @@ multi-chip sharding paths compile and execute without TPU hardware.
 
 The suite runs on XLA's CPU backend (the driver sets ``JAX_PLATFORMS=cpu``;
 the ``jax_platforms`` update below holds it there for a bare ``pytest``
-too).  The chip is reached only through ``chip_smoke.py``, one process per
-chip; ``tests/test_chip_compile.py`` asks the TPU *compiler* about a
+too).  The chip is reached only through ``benchmark/run.py``, one cell a
+process; ``tests/test_chip_compile.py`` asks the TPU *compiler* about a
 described chip and needs no device.
 """
 

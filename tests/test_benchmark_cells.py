@@ -2,8 +2,10 @@
 JAX: the texts one client sends are what they were before there were clients,
 the clients of ``mesh_q7`` never share a constant, the cell ``lubm5.mesh4``
 is in with the entries PR 27 wrote for it, every file a cell or a
-per-layer metric names is there, and a program that lacks what a cell
-requires of it (``benchmark/requires``) is refused before anything starts.
+per-layer metric names is there, a program that lacks what a cell
+requires of it (``benchmark/requires``) is refused before anything starts,
+and ``run.py`` itself, started off the chip, prints no result and exits 3
+(a child process: this one stays off JAX).
 
 Imports ``benchmark.harness`` (``traffic``, ``data``; numpy; the generators
 are found by name through ``data.load_module``) and, of the program,
@@ -171,3 +173,21 @@ def test_a_program_without_the_required_metric_is_refused_at_once(
         with pytest.raises(SystemExit, match="cannot run cell a.cell"):
             harness._requires("a.cell", str(tmp_path))
     harness._requires("a.cell.without.the.file", str(tmp_path))  # requires nothing
+
+
+def test_off_the_chip_run_py_prints_no_result_and_exits_3(tmp_path):
+    """A number from a CPU run is never written as a result: without a TPU
+    ``benchmark/run.py`` says on standard error what it found, prints
+    nothing on standard output and exits 3 (``NO_CHIP_EXIT``)."""
+    import subprocess
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jax_cache"))
+    env.pop("KOLIBRIE_BENCH_REHEARSAL_SCALE", None)
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "benchmark", "run.py"),
+         "--workload", "employee100k.upstream", "--seconds", "1"],
+        cwd=str(tmp_path), env=env, capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 3, proc.stderr[-2000:]
+    assert proc.stdout.strip() == ""
+    assert "needs 1 TPU chip(s)" in proc.stderr

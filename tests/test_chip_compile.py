@@ -159,7 +159,7 @@ def test_tag_combine(one_chip):
 def lubm_db():
     """A small real device-mode store; its argument tree gives the whole
     plan its shapes."""
-    from benches import lubm
+    from examples import lubm
     from kolibrie_tpu.query.sparql_database import SparqlDatabase
 
     db = SparqlDatabase()
@@ -185,7 +185,7 @@ def _lower_bgp(db, sparql):
 
 
 def test_whole_plan_lubm_q9(one_chip, lubm_db):
-    from benches import lubm
+    from examples import lubm
     from kolibrie_tpu.optimizer import device_engine as de
 
     low = _lower_bgp(lubm_db, lubm.LUBM_Q9)
@@ -197,10 +197,16 @@ def test_whole_plan_lubm_q9(one_chip, lubm_db):
 
 
 def test_whole_plan_batch_8_variants(one_chip, lubm_db):
-    import chip_smoke
+    from examples import lubm
     from kolibrie_tpu.optimizer import device_engine as de
 
-    _solo, variants = chip_smoke.smoke_queries(4, 0, False)
+    variants = [
+        f"PREFIX ub: <{lubm.UB}>\n"
+        "SELECT ?x ?y ?c WHERE { "
+        f"?x ub:memberOf <http://www.Department{k}.University{k % 4}.edu> . "
+        "?x ub:advisor ?y . ?y ub:teacherOf ?c }"
+        for k in range(8)
+    ]
     lows = [_lower_bgp(lubm_db, v) for v in variants]
     built = [lp.build() for lp in lows]
     spec0, (order_arrays, _sc, masks, values, numf, quoted, _pp) = built[0]

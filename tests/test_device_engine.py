@@ -10,7 +10,6 @@ import numpy as np
 import pytest
 
 from kolibrie_tpu.optimizer.device_engine import (
-    PreparedQuery,
     Unsupported,
     lower_plan,
     try_device_execute,
@@ -213,7 +212,26 @@ def test_capacity_doubling_converges():
     assert len(next(iter(table.values()))) == 500
 
 
-def test_prepared_query_roundtrip():
+def _request_lowering(db, sparql):
+    """The LoweredPlan a request for ``sparql`` runs: the one the executor's
+    plan cache keeps for the template on this store state."""
+    from kolibrie_tpu.query.executor import _plan_cache_entry
+
+    _ent, slot = _plan_cache_entry(db, sparql)
+    return slot["lowered"]
+
+
+def _rows(db, low, sparql):
+    """One dispatch of ``low`` read back as a request's rows are: counts
+    validated, table pulled, ids decoded."""
+    from kolibrie_tpu.query.executor import format_results
+
+    table = low.to_table(*low.converge(low.run()))
+    q = parse_sparql_query(sparql)
+    return format_results(db, table, q, sort_rows=True)
+
+
+def test_lowered_plan_run_converge_roundtrip():
     db = employee_db()
     q = PREFIXES + """
     SELECT ?e ?w ?s WHERE {
@@ -221,33 +239,32 @@ def test_prepared_query_roundtrip():
         ?e ex:salary ?s .
         FILTER(?s > 50000)
     }"""
-    prep = PreparedQuery(db, q)
-    prep.calibrate()
-    out = prep.run()
-    rows = prep.fetch(out)
-    db.execution_mode = "host"
-    host_rows = execute_query_volcano(q, db)
-    assert rows == sorted(host_rows)
+    dev, host = run_both(db, q)
+    assert sorted(dev) == sorted(host)
+    assert _rows(db, _request_lowering(db, q), q) == sorted(host)
 
 
-def test_prepared_query_mask_refresh_after_dict_growth():
-    """New dictionary IDs after prepare must not clamp onto old mask entries
+def test_lowered_plan_mask_refresh_after_dict_growth():
+    """New dictionary IDs after lowering must not clamp onto old mask entries
     — and join-capacity overflow after store growth must re-run, not
-    truncate."""
+    truncate.  The template's plan slot survives a mutation under the delta
+    threshold, so both requests run ONE lowered program."""
     db = employee_db()
     q = PREFIXES + "SELECT ?e ?s WHERE { ?e ex:salary ?s . FILTER(?s > 50000) }"
-    prep = PreparedQuery(db, q)
-    prep.calibrate()
-    rows1 = prep.fetch(prep.run())
+    rows1 = execute_query_volcano(q, db)
+    low = _request_lowering(db, q)
     # a brand-new literal (new ID beyond the old mask) that passes the filter
     db.parse_ntriples(
         '<http://example.org/new> <http://example.org/salary> "123456" .'
     )
-    rows2 = prep.fetch(prep.run())
+    rows2 = execute_query_volcano(q, db)
+    assert _request_lowering(db, q) is low
     db.execution_mode = "host"
     host = execute_query_volcano(q, db)
-    assert rows2 == sorted(host)
+    db.execution_mode = "device"
+    assert sorted(rows2) == sorted(host)
     assert len(rows2) == len(rows1) + 1
+    assert _rows(db, low, q) == sorted(host)
 
 
 def test_store_mutation_between_executions():
@@ -391,13 +408,8 @@ def test_device_aggregation_infinite_literal():
 def test_pallas_join_path_agreement(monkeypatch):
     """Forced Pallas merge-join tile kernel (interpret mode off-TPU) must
     agree with the host engine AND with the XLA join formulation on the
-    identical plan — the engine's production join on real TPU hardware.
-
-    Deliberately drives the DEPRECATED ``KOLIBRIE_PALLAS_JOIN`` alias
-    end-to-end (1 → force, 0 → off) so the backward-compat shim keeps
-    working; everything else uses the unified ``KOLIBRIE_PALLAS``."""
-    monkeypatch.delenv("KOLIBRIE_PALLAS", raising=False)
-    monkeypatch.setenv("KOLIBRIE_PALLAS_JOIN", "1")
+    identical plan — the engine's production join on real TPU hardware."""
+    monkeypatch.setenv("KOLIBRIE_PALLAS", "force")
     db = employee_db(200)
     q = PREFIXES + """
     SELECT ?e ?w ?s WHERE {
@@ -416,7 +428,7 @@ def test_pallas_join_path_agreement(monkeypatch):
     }"""
     dev, host = run_both(db, qf)
     assert sorted(dev) == sorted(host)
-    monkeypatch.setenv("KOLIBRIE_PALLAS_JOIN", "0")
+    monkeypatch.setenv("KOLIBRIE_PALLAS", "off")
     xla_rows = execute_query_volcano(qf, db)
     assert sorted(xla_rows) == sorted(dev)
 
@@ -1272,11 +1284,12 @@ def test_union_then_optional_clause_only():
     assert sorted(dev) == sorted(host)
 
 
-def test_prepared_query_with_clauses():
-    """PreparedQuery accepts the fused clause surface: calibrate,
-    dispatch-only runs, amortized runs, and fetch all work with
-    union/optional/anti branches in the program."""
-    import jax
+def test_request_with_clauses_is_one_fused_program():
+    """UNION, OPTIONAL and MINUS branches ride in the request's one device
+    program: the lowering the plan cache keeps is the fused one, a second
+    request replays it without a compile, and a bare dispatch of it reads
+    back the host engine's rows."""
+    from kolibrie_tpu.optimizer.device_engine import device_compile_stats
 
     db = employee_db()
     q = PREFIXES + """
@@ -1286,20 +1299,15 @@ def test_prepared_query_with_clauses():
         OPTIONAL { ?e ex:knows ?y }
         MINUS { ?e foaf:workplaceHomepage <http://company3.example/> }
     }"""
-    prep = PreparedQuery(db, q)
-    prep.calibrate()
-    out = prep.run()
-    jax.block_until_ready(out)
-    rows = prep.fetch(out)
-    db.execution_mode = "host"
-    host = execute_query_volcano(q, db)
-    db.execution_mode = "device"
-    assert rows == sorted(host)
-    assert len(rows) > 0
-    sums, counts = prep.run_amortized(4)
-    import numpy as np
-
-    assert int(np.asarray(counts)[0]) == len(host)
+    dev, host = run_both(db, q)
+    assert len(host) > 0
+    assert sorted(dev) == sorted(host)
+    low = _request_lowering(db, q)
+    assert low.fused_clauses
+    before = device_compile_stats()
+    assert sorted(execute_query_volcano(q, db)) == sorted(host)
+    assert _rows(db, low, q) == sorted(host)
+    assert device_compile_stats() == before
 
 
 def test_group_concat_over_minus_uses_fused_prebuilt():

@@ -284,7 +284,7 @@ def test_predicate_position_join_unsupported(mesh):
 
 
 def test_dist_pallas_join_composition():
-    """KOLIBRIE_PALLAS_DIST=1: the shard-local joins run through the
+    """KOLIBRIE_PALLAS=force: the shard-local joins run through the
     Pallas kernel INSIDE shard_map (interpret mode on the CPU mesh).
     Subprocess-isolated: the flag is read at trace time and the compiled
     round programs are cached per process."""
@@ -296,7 +296,7 @@ def test_dist_pallas_join_composition():
 import os
 os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + \
     " --xla_force_host_platform_device_count=8"
-os.environ["KOLIBRIE_PALLAS_DIST"] = "1"
+os.environ["KOLIBRIE_PALLAS"] = "force"
 import jax; jax.config.update("jax_platforms", "cpu")
 import kolibrie_tpu.parallel.dist_join as dj
 from kolibrie_tpu.parallel import DistGeneralReasoner, make_mesh

@@ -25,7 +25,7 @@ from kolibrie_tpu.query.sparql_database import SparqlDatabase
 import sys
 from pathlib import Path
 
-sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "benches"))
+sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "examples"))
 import lubm  # noqa: E402
 
 

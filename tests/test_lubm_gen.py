@@ -4,7 +4,7 @@ triple set — every LUBM benchmark number rests on this equivalence."""
 import sys
 from pathlib import Path
 
-sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "benches"))
+sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "examples"))
 
 from kolibrie_tpu.core.dictionary import Dictionary
 

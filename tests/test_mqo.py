@@ -236,7 +236,6 @@ def test_no_new_specialized_compiles(monkeypatch):
             execute_query_volcano(q_filter(th), db)
     after = de.device_compile_stats()
     assert after["run_plan"] == before["run_plan"]
-    assert after["run_plan_k"] == before["run_plan_k"]
     assert after["run_plan_batch"] == before["run_plan_batch"]
     assert after["run_interp"] == before["run_interp"]
     st = mqo.stats(db)

@@ -734,67 +734,3 @@ def test_parser_parity_fuzz():
         doc = xml_doc()
         got, want = load_both(doc, "parse_rdf", "_parse_rdf_native")
         assert got == want, (trial, doc[:400], got[0], want[0])
-
-
-# ---------------------------------------------------------------- join twin
-
-
-class TestNativeJoin:
-    """kn_join_u32 / kn_gather_u32 — the threaded C++ twin of
-    ops.join.join_indices (the benchmark's host-baseline floor)."""
-
-    def test_parity_random_shapes(self):
-        import numpy as np
-
-        from kolibrie_tpu.native.join_native import (
-            gather_native,
-            join_indices_native,
-        )
-        from kolibrie_tpu.ops.join import join_indices
-
-        rng = np.random.default_rng(11)
-        shapes = [
-            (0, 5, 3),
-            (5, 0, 3),
-            (1, 1, 1),
-            (7, 3, 4),
-            (1000, 1000, 50),      # heavy duplication
-            (20000, 20000, 20000), # near 1:1
-            (30000, 10000, 700),   # skewed
-        ]
-        for ln, rn, kspace in shapes:
-            lk = rng.integers(0, max(kspace, 1), ln, dtype=np.uint32)
-            rk = rng.integers(0, max(kspace, 1), rn, dtype=np.uint32)
-            li_n, ri_n = join_indices_native(lk, rk)
-            li, ri = join_indices(lk, rk)
-            assert np.array_equal(li_n, li), (ln, rn, kspace)
-            assert np.array_equal(ri_n, ri), (ln, rn, kspace)
-            if len(ri):
-                assert np.array_equal(gather_native(rk, ri_n), rk[ri])
-
-    def test_buffer_regrow_on_fanout(self):
-        import numpy as np
-
-        from kolibrie_tpu.native.join_native import join_indices_native
-        from kolibrie_tpu.ops.join import join_indices
-
-        # every left row matches every right row: output 300*300 >> the
-        # initial 2*max(n) guess, forcing the retry path
-        lk = np.full(300, 9, dtype=np.uint32)
-        rk = np.full(300, 9, dtype=np.uint32)
-        li_n, ri_n = join_indices_native(lk, rk)
-        li, ri = join_indices(lk, rk)
-        assert len(li_n) == 90_000
-        assert np.array_equal(li_n, li) and np.array_equal(ri_n, ri)
-
-    def test_extreme_key_values(self):
-        import numpy as np
-
-        from kolibrie_tpu.native.join_native import join_indices_native
-        from kolibrie_tpu.ops.join import join_indices
-
-        lk = np.array([0, 0xFFFFFFFF, 0x7FFFFFFF, 0x80000000], dtype=np.uint32)
-        rk = np.array([0xFFFFFFFF, 0x80000000, 0, 123], dtype=np.uint32)
-        li_n, ri_n = join_indices_native(lk, rk)
-        li, ri = join_indices(lk, rk)
-        assert np.array_equal(li_n, li) and np.array_equal(ri_n, ri)

@@ -94,7 +94,6 @@ def _rows(db, query, mode):
 
 def test_pallas_mode_parsing(monkeypatch):
     monkeypatch.delenv("KOLIBRIE_PALLAS", raising=False)
-    monkeypatch.delenv("KOLIBRIE_PALLAS_JOIN", raising=False)
     assert pallas_mode() == "auto"
     for val, want in (
         ("off", "off"), ("0", "off"), ("false", "off"),
@@ -105,16 +104,21 @@ def test_pallas_mode_parsing(monkeypatch):
         assert pallas_mode() == want, val
 
 
-def test_pallas_legacy_join_flag_shim(monkeypatch):
-    """Deprecated ``KOLIBRIE_PALLAS_JOIN`` maps 1 → force / 0 → off while
-    ``KOLIBRIE_PALLAS`` is unset, and loses to the unified flag."""
+@pytest.mark.parametrize("suffix", ["_JOIN", "_DIST"])
+@pytest.mark.parametrize("value", ["0", "1"])
+def test_pallas_legacy_flags_have_no_effect(monkeypatch, suffix, value):
+    """The per-subsystem names that predate ``KOLIBRIE_PALLAS`` were shims
+    until PR 30: set, they now change neither the mode nor the mesh's
+    shard-local join route."""
+    from kolibrie_tpu.parallel.dist_join import dist_pallas_enabled
+
     monkeypatch.delenv("KOLIBRIE_PALLAS", raising=False)
-    monkeypatch.setenv("KOLIBRIE_PALLAS_JOIN", "1")
+    monkeypatch.setenv("KOLIBRIE_PALLAS" + suffix, value)
+    assert pallas_mode() == "auto"
+    assert dist_pallas_enabled() is False
+    monkeypatch.setenv("KOLIBRIE_PALLAS", "force")
     assert pallas_mode() == "force"
-    monkeypatch.setenv("KOLIBRIE_PALLAS_JOIN", "0")
-    assert pallas_mode() == "off"
-    monkeypatch.setenv("KOLIBRIE_PALLAS", "auto")
-    assert pallas_mode() == "auto"  # unified flag wins
+    assert dist_pallas_enabled() is True
 
 
 # ------------------------------------------------------ lex_range fuzz

@@ -30,7 +30,7 @@ from kolibrie_tpu.query.executor import (
 )
 from kolibrie_tpu.query.sparql_database import SparqlDatabase
 
-sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "benches"))
+sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "examples"))
 import lubm  # noqa: E402
 
 PREFIX = "PREFIX ub: <http://swat.cse.lehigh.edu/onto/univ-bench.owl#>\n"
@@ -63,6 +63,12 @@ def _template_group(db, k=4):
     deps = execute_query_volcano(DEPTS_Q, db)
     assert len(deps) >= k
     return [TEMPLATE.format(dept=d[0]) for d in deps[:k]]
+
+
+def _lone(sh, text):
+    """``text`` served by the mesh as a group of one: what a request that
+    meets no other in the batcher gets."""
+    return sh.execute_batch(_fp(sh.db, text), [(0, text)])[0]
 
 
 def _metric(prefix, text=None):
@@ -257,11 +263,6 @@ def test_warm_builds_the_executable_requests_run(mesh8):
     assert sh.warm(DECLINED.format(dept="http://nowhere/d")) is False
 
 
-def test_solo_mesh_execute_matches_oracle(sharded_db):
-    db, sh = sharded_db
-    assert sh.execute(lubm.LUBM_Q2) == execute_query_volcano(lubm.LUBM_Q2, db)
-
-
 def test_plan_cache_state_key_carries_mesh_signature(sharded_db):
     db, sh = sharded_db
     execute_queries_batched(db, _template_group(db, 2))
@@ -395,7 +396,7 @@ def test_recovery_rebuilds_sharded_mirrors(mesh8, tmp_path):
     assert len(rdb.store) == len(db.store)
     s, p, o = rebuilt["s1"].view.gather_host()
     assert len(s) == len(rdb.store)
-    assert sorted(rebuilt["s1"].execute(q)) == sorted(oracle)
+    assert sorted(_lone(rebuilt["s1"], q)) == sorted(oracle)
 
 
 def test_checkpoint_restore_then_refresh(mesh8, tmp_path):
@@ -411,7 +412,7 @@ def test_checkpoint_restore_then_refresh(mesh8, tmp_path):
     sh2 = attach_sharded(db2, mesh8)
     sh2.refresh()
     q = _template_group(db, 1)[0]
-    assert sh2.execute(q) == execute_query_volcano(q, db)
+    assert _lone(sh2, q) == execute_query_volcano(q, db)
 
 
 # ------------------------------------------------------------- resilience
@@ -446,7 +447,7 @@ def test_mesh_deadline_propagates(mesh8):
     sh.refresh()
     with pytest.raises(DeadlineExceeded):
         with deadline_scope(Deadline(0.0)):
-            sh.execute(_template_group(db, 1)[0])
+            _lone(sh, _template_group(db, 1)[0])
 
 
 def test_detach_restores_single_device_key(mesh8):

@@ -42,7 +42,7 @@ from kolibrie_tpu.query.parser import parse_combined_query
 from kolibrie_tpu.query.sparql_database import SparqlDatabase
 from kolibrie_tpu.query.template import fingerprint_query
 
-sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "benches"))
+sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "examples"))
 import lubm  # noqa: E402
 
 PREFIX = "PREFIX ub: <http://swat.cse.lehigh.edu/onto/univ-bench.owl#>\n"

@@ -270,7 +270,7 @@ def test_host_fallback_joins_in_connected_order(monkeypatch):
     import sys
     from pathlib import Path
 
-    sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "benches"))
+    sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "examples"))
     import lubm
 
     from kolibrie_tpu.ops.join import table_len
