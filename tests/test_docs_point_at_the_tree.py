@@ -8,6 +8,7 @@ the repo's own directories (or is a bare ``*.py``) exists, and each
 ``kolibrie_tpu/`` or ``benchmark/``.
 """
 
+import functools
 import glob
 import os
 import re
@@ -51,6 +52,7 @@ def _path_of(word):
     return None
 
 
+@functools.lru_cache(maxsize=None)
 def _env_names_read():
     names = set()
     for root in ("kolibrie_tpu", "benchmark"):
