@@ -462,10 +462,28 @@ def _pack_key(cols: List, valid, pad_sentinel):
 
 
 
+def _base_window(col, lo, cap: int):
+    """Rows ``lo .. lo + cap`` of a base column as one ``dynamic_slice`` (a
+    copy, where a gather of the same rows costs 0.26 us a row on a v5e:
+    PERF.md section 6, PR 31).  ``dynamic_slice`` clamps its start so the
+    window fits the column; a window that would run past the padded end is
+    rotated back into place, and what it wraps around to lies beyond the
+    scan's rows, which the caller masks."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    n = col.shape[0]
+    if cap > n:
+        col, n = jnp.pad(col, (0, cap - n)), cap
+    start = jnp.minimum(lo, n - cap)
+    return jnp.roll(lax.dynamic_slice(col, (start,), (cap,)), start - lo)
+
+
 def _plan_body(
     spec: PlanSpec,
     order_arrays,
     scalars,
+    tiers,
     masks,
     values,
     numf,
@@ -474,11 +492,42 @@ def _plan_body(
     use_pallas=False,
 ):
     import jax.numpy as jnp
+    from jax import lax
 
     from kolibrie_tpu.ops.device_join import _LPAD, _RPAD, join_indices
 
     uparams, fparams = params
     counts: List = []
+
+    def delta_holds_nothing(order_idx):
+        """An empty delta tier is not searched: where an order's delta
+        segment holds no row and no tombstone, its scans and WCOJ probes
+        read the base alone (``lax.cond`` on this predicate; both sites
+        give what the two-tier branch gives for an empty delta, bit for
+        bit).  ``tiers[i]`` is order i's delta rows + tombstones as the
+        host assembled them with the segments (``LoweredPlan._assemble``),
+        a traced operand and neither a shape nor a static argument:
+
+        - one executable a template, as before: a static flag or a
+          zero-length delta shape would double the executables and put a
+          compile into the first request after the first write; here that
+          write flips a scalar;
+        - it survives ``vmap``: ``_run_plan_batch`` maps over ``scalars``
+          and ``params``, so a predicate made from a request's own ``n_d``
+          would be batched and the ``cond`` a ``select`` that runs both
+          branches; the order's entry is a store operand, not batched;
+        - no option: it is one algorithm whose second input is empty, and
+          the code sees that in its input.
+        """
+        return tiers[order_idx] == 0
+
+    def delta_or_zeros(order_idx, like, probe):
+        """``probe()``, the ranges a WCOJ accessor finds in its order's delta
+        rows and tombstones, shaped like the base's ranges ``like``: all
+        zero, and not searched, where the tier holds nothing."""
+        zeros = jax.tree.map(jnp.zeros_like, like)
+        return lax.cond(delta_holds_nothing(order_idx), lambda: zeros, probe)
+
     # EXPLAIN ANALYZE operator stats: key -> device scalar, computed from
     # sums the operators already materialize, so the vector rides the
     # result transfer for free.  Keys are stable across the device walk,
@@ -611,54 +660,75 @@ def _plan_body(
             cap = node.cap
             dcap = del_pos.shape[0]
             ar = jnp.arange(cap, dtype=jnp.int32)
-            ard = jnp.arange(dcap, dtype=jnp.int32)
-            src_b = jnp.clip(lo_b + ar, 0, bcols[0].shape[0] - 1)
-            src_d = jnp.clip(lo_d + ard, 0, dcap - 1)
             inb = ar < n_b
-            ind = ard < n_d
-            # tombstone check: sorted membership of the base ROW POSITION
-            # (one u32 word) instead of matching a 96-bit triple
-            sbu = src_b.astype(jnp.uint32)
-            jd = jnp.clip(jnp.searchsorted(del_pos, sbu), 0, dcap - 1)
-            is_del = (del_pos[jd] == sbu) & inb
-            bvalid = inb & ~is_del
-            k0, k1 = node.key_pos
-            sent = jnp.uint64(0xFFFFFFFFFFFFFFFF)
-            bkey = (bcols[k0][src_b].astype(jnp.uint64) << jnp.uint64(32)) | (
-                bcols[k1][src_b].astype(jnp.uint64)
-            )
-            # deleted rows KEEP their real key (preserves sortedness and
-            # the rank arithmetic); only rows beyond the window go sentinel
-            bkey = jnp.where(inb, bkey, sent)
-            dkey = (dcols[k0][src_d].astype(jnp.uint64) << jnp.uint64(32)) | (
-                dcols[k1][src_d].astype(jnp.uint64)
-            )
-            dkey = jnp.where(ind, dkey, sent)
-            pos_b = (jnp.cumsum(bvalid.astype(jnp.int32)) - 1) + (
-                jnp.searchsorted(dkey, bkey, side="left").astype(jnp.int32)
-            )
-            cdel = jnp.concatenate(
-                [jnp.zeros(1, jnp.int32), jnp.cumsum(is_del.astype(jnp.int32))]
-            )
-            ib = jnp.searchsorted(bkey, dkey, side="right").astype(jnp.int32)
-            pos_d = ard + ib - cdel[ib]
-            n_live = (n_b - cdel[-1]) + n_d
-            valid = ar < n_live
-            dst_b = jnp.where(bvalid, pos_b, cap)
-            dst_d = jnp.where(ind, pos_d, cap)
-            raw = {}
             need = {pos for _, pos in node.out_vars}
             for a, b in node.eq_pairs:
                 need.add(a)
                 need.add(b)
-            for pos in need:
-                raw[pos] = (
-                    jnp.zeros(cap, dtype=jnp.uint32)
-                    .at[dst_b]
-                    .set(bcols[pos][src_b], mode="drop")
-                    .at[dst_d]
-                    .set(dcols[pos][src_d], mode="drop")
+            need = sorted(need)
+
+            def base_only():
+                # the merge's answer for an empty delta: rows lo_b ..
+                # lo_b + n_b of the base, in place, zeros beyond them
+                return (
+                    tuple(
+                        jnp.where(inb, _base_window(bcols[pos], lo_b, cap), 0)
+                        for pos in need
+                    ),
+                    n_b,
                 )
+
+            def two_tier():
+                src_b = jnp.clip(lo_b + ar, 0, bcols[0].shape[0] - 1)
+                ard = jnp.arange(dcap, dtype=jnp.int32)
+                src_d = jnp.clip(lo_d + ard, 0, dcap - 1)
+                ind = ard < n_d
+                # tombstone check: sorted membership of the base ROW
+                # POSITION (one u32 word) instead of matching a 96-bit triple
+                sbu = src_b.astype(jnp.uint32)
+                jd = jnp.clip(jnp.searchsorted(del_pos, sbu), 0, dcap - 1)
+                is_del = (del_pos[jd] == sbu) & inb
+                bvalid = inb & ~is_del
+                k0, k1 = node.key_pos
+                sent = jnp.uint64(0xFFFFFFFFFFFFFFFF)
+                bkey = (
+                    bcols[k0][src_b].astype(jnp.uint64) << jnp.uint64(32)
+                ) | (bcols[k1][src_b].astype(jnp.uint64))
+                # deleted rows KEEP their real key (preserves sortedness and
+                # the rank arithmetic); only rows beyond the window go
+                # sentinel
+                bkey = jnp.where(inb, bkey, sent)
+                dkey = (
+                    dcols[k0][src_d].astype(jnp.uint64) << jnp.uint64(32)
+                ) | (dcols[k1][src_d].astype(jnp.uint64))
+                dkey = jnp.where(ind, dkey, sent)
+                pos_b = (jnp.cumsum(bvalid.astype(jnp.int32)) - 1) + (
+                    jnp.searchsorted(dkey, bkey, side="left").astype(jnp.int32)
+                )
+                cdel = jnp.concatenate(
+                    [jnp.zeros(1, jnp.int32), jnp.cumsum(is_del.astype(jnp.int32))]
+                )
+                ib = jnp.searchsorted(bkey, dkey, side="right").astype(jnp.int32)
+                pos_d = ard + ib - cdel[ib]
+                dst_b = jnp.where(bvalid, pos_b, cap)
+                dst_d = jnp.where(ind, pos_d, cap)
+                return (
+                    tuple(
+                        jnp.zeros(cap, dtype=jnp.uint32)
+                        .at[dst_b]
+                        .set(bcols[pos][src_b], mode="drop")
+                        .at[dst_d]
+                        .set(dcols[pos][src_d], mode="drop")
+                        for pos in need
+                    ),
+                    (n_b - cdel[-1]) + n_d,
+                )
+
+            merged, n_live = lax.cond(
+                delta_holds_nothing(node.order_idx), base_only, two_tier
+            )
+            raw = dict(zip(need, merged))
+            valid = ar < n_live
             for a, b in node.eq_pairs:
                 valid = valid & (raw[a] == raw[b])
             cols = {var: raw[pos] for var, pos in node.out_vars}
@@ -870,7 +940,11 @@ def _plan_body(
                             # left/right lex_searchsorted pairs, half the
                             # gathers (shared by both the XLA and Pallas paths)
                             bl, bh = lex_range(bsort, kt)
-                            dl, dh = lex_range(dsort, kt)
+                            dl, dh = delta_or_zeros(
+                                a.order_idx,
+                                (bl, bh),
+                                lambda dsort=dsort, kt=kt: lex_range(dsort, kt),
+                            )
                         else:
                             # unbound accessor: the whole live prefix (padding
                             # is all-sentinel and sorts last; the order was
@@ -880,9 +954,13 @@ def _plan_body(
                             nb0 = jnp.searchsorted(
                                 bcols[a.val_pos], SENT, side="left"
                             ).astype(jnp.int32)
-                            nd0 = jnp.searchsorted(
-                                dcols[a.val_pos], SENT, side="left"
-                            ).astype(jnp.int32)
+                            nd0 = delta_or_zeros(
+                                a.order_idx,
+                                nb0,
+                                lambda dv=dcols[a.val_pos]: jnp.searchsorted(
+                                    dv, SENT, side="left"
+                                ).astype(jnp.int32),
+                            )
                             bh = jnp.broadcast_to(nb0, (pcap,))
                             dh = jnp.broadcast_to(nd0, (pcap,))
                         probes.append((keys, sent, bl, bh, dl, dh))
@@ -976,11 +1054,25 @@ def _plan_body(
                             dcols[a.val_pos],
                         )
                         fl, fh = lex_range(bsf, fkeys)
-                        dl2, dh2 = lex_range(dsf, fkeys)
-                        # tombstoned copies inside [fl, fh): del_pos holds
-                        # sorted base-row positions (sentinel-padded)
-                        tl = jnp.searchsorted(del_pos, fl.astype(jnp.uint32))
-                        th = jnp.searchsorted(del_pos, fh.astype(jnp.uint32))
+
+                        def delta_live(
+                            dsf=dsf, fkeys=fkeys, del_pos=del_pos, fl=fl, fh=fh
+                        ):
+                            dl2, dh2 = lex_range(dsf, fkeys)
+                            # tombstoned copies inside [fl, fh): del_pos holds
+                            # sorted base-row positions (sentinel-padded)
+                            tl = jnp.searchsorted(del_pos, fl.astype(jnp.uint32))
+                            th = jnp.searchsorted(del_pos, fh.astype(jnp.uint32))
+                            return (
+                                tl.astype(jnp.int32),
+                                th.astype(jnp.int32),
+                                dl2,
+                                dh2,
+                            )
+
+                        tl, th, dl2, dh2 = delta_or_zeros(
+                            a.order_idx, (fl, fh, fl, fh), delta_live
+                        )
                         ex.append((fl, fh, tl, th, dl2, dh2, sent[row_c]))
                     if use_pallas:
                         new_valid = lex_probe_validate(
@@ -991,8 +1083,8 @@ def _plan_body(
                                 (
                                     fl,
                                     fh,
-                                    tl.astype(jnp.int32),
-                                    th.astype(jnp.int32),
+                                    tl,
+                                    th,
                                     dl2,
                                     dh2,
                                     sent_r,
@@ -1034,6 +1126,7 @@ def _run_plan(
     use_pallas: bool,
     order_arrays,
     scalars,
+    tiers,
     masks,
     values,
     numf,
@@ -1041,7 +1134,8 @@ def _run_plan(
     params,
 ):
     return _plan_body(
-        spec, order_arrays, scalars, masks, values, numf, quoted, params, use_pallas
+        spec, order_arrays, scalars, tiers, masks, values, numf, quoted, params,
+        use_pallas,
     )
 
 
@@ -1050,6 +1144,7 @@ def _run_plan_batch(
     spec: PlanSpec,
     order_arrays,
     scalars_b,
+    tiers,
     masks,
     values,
     numf,
@@ -1058,13 +1153,16 @@ def _run_plan_batch(
 ):
     """Stacked-parameter dispatch: ONE executable evaluating the same plan
     template for a whole batch of constant-variants (vmap over the scan
-    ranges and the packed parameter vectors; store operands broadcast).
-    The serving layer's micro-batcher lands here.  Pallas kernels don't
-    vmap, so the batch always takes the pure-XLA join formulation."""
+    ranges and the packed parameter vectors; store operands, ``tiers``
+    among them, broadcast, so the body's conditionals on an empty delta
+    tier stay conditionals).  The serving layer's micro-batcher lands here.
+    Pallas kernels don't vmap, so the batch always takes the pure-XLA join
+    formulation."""
 
     def one(scalars, params):
         return _plan_body(
-            spec, order_arrays, scalars, masks, values, numf, quoted, params, False
+            spec, order_arrays, scalars, tiers, masks, values, numf, quoted,
+            params, False,
         )
 
     return jax.vmap(one, in_axes=(0, (0, 0)))(scalars_b, params_b)
@@ -1330,9 +1428,11 @@ class LoweredPlan:
         re-picking (each order is a full device-resident copy of the store —
         uploading unused ones would be a real cost at scale)."""
         used: List[int] = []
+        sites: List[int] = []  # the order of each scan and WCOJ accessor
 
         def collect(node):
             if isinstance(node, ScanSpec):
+                sites.append(node.order_idx)
                 if node.order_idx not in used:
                     used.append(node.order_idx)
             elif isinstance(node, (JoinSpec, AntiJoinSpec, LeftOuterSpec)):
@@ -1346,11 +1446,13 @@ class LoweredPlan:
             elif isinstance(node, WcojSpec):
                 for lv in node.levels:
                     for a in lv.accessors:
+                        sites.append(a.order_idx)
                         if a.order_idx not in used:
                             used.append(a.order_idx)
 
         collect(self.root)
         remap = {old: new for new, old in enumerate(sorted(used))}
+        self._tier_sites = tuple(remap[o] for o in sites)
         if len(remap) == len(self.order_names) and all(
             o == n for o, n in remap.items()
         ):
@@ -2206,13 +2308,26 @@ class LoweredPlan:
         else:
             numf = jnp.zeros(1, dtype=jnp.float32)
         scalars = jnp.asarray(self._scan_ranges_np)
+        # what each order's delta tier holds right now (rows + tombstones),
+        # read with the segments above so both are one delta epoch's: the
+        # plan body reads the base alone where an entry is 0
+        self._tiers_np = np.asarray(
+            [
+                len(store.delta_order(name)) + len(store.delta_del_positions(name))
+                for name in self.order_names
+            ],
+            dtype=np.int32,
+        )
+        tiers = jnp.asarray(self._tiers_np)
         quoted = (
             device_quoted(self.db)
             if self.need_quoted
             else tuple(jnp.zeros(1, dtype=jnp.uint32) for _ in range(4))
         )
         params = self.device_params()
-        return spec, (order_arrays, scalars, masks, values, numf, quoted, params)
+        return spec, (
+            order_arrays, scalars, tiers, masks, values, numf, quoted, params
+        )
 
     def device_params(self):
         """Pack the query constants as the (uparams, fparams) traced
@@ -2640,6 +2755,17 @@ class LoweredPlan:
         with jax.enable_x64(True):
             return _enqueue_traced(_run_plan, spec, pallas_enabled(), *args)
 
+    def _note_scan_tiers(self, members: int = 1) -> None:
+        """Count the dispatch just assembled: its scans and WCOJ accessors by
+        the branch their order's entry of ``tiers`` selects in the plan body
+        (``members``: the variants a stacked dispatch runs them for)."""
+        from kolibrie_tpu.query.template import note_scan_tiers
+
+        base_only = sum(1 for o in self._tier_sites if self._tiers_np[o] == 0)
+        note_scan_tiers(
+            members * base_only, members * (len(self._tier_sites) - base_only)
+        )
+
     def _store_caps(self) -> None:
         """Publish join capacities to the per-db template cache.  Merge is
         a MONOTONIC max: the cache is shared by every constant variant of
@@ -2683,6 +2809,7 @@ class LoweredPlan:
                     _time.perf_counter() - t_retry
                 )
             note_cap_occupancy("device", sum(self._join_caps), sum(counts_h))
+            self._note_scan_tiers()
             overflow = [
                 i for i, c in enumerate(counts_h) if c > self._join_caps[i]
             ]
@@ -3320,7 +3447,7 @@ def _execute_plan_batch(
             scal.append(np.asarray(lp._scan_ranges_np))
             ups.append(np.asarray(lp.u_params or [0], dtype=np.uint32))
             fps.append(np.asarray(lp.f_params or [0.0], dtype=np.float64))
-        order_arrays, _sc, masks, values, numf, quoted, _pp = base_args
+        order_arrays, _sc, tiers, masks, values, numf, quoted, _pp = base_args
         with jax.enable_x64(True):
             params_b = (
                 jnp.asarray(np.stack(ups)),
@@ -3331,6 +3458,7 @@ def _execute_plan_batch(
                 spec0,
                 order_arrays,
                 jnp.asarray(np.stack(scal)),
+                tiers,
                 masks,
                 values,
                 numf,
@@ -3345,6 +3473,7 @@ def _execute_plan_batch(
         note_cap_occupancy(
             "device", len(live) * sum(caps), sum(int(np.sum(c)) for c in counts_b)
         )
+        lp0._note_scan_tiers(len(live))
         over = [j for j, c in enumerate(maxc) if c > caps[j]]
         if not over:
             break

@@ -558,7 +558,7 @@ def _stacked_segments(lowered):
 
 
 def _dispatch(lowered, prog: InterpProgram, args):
-    _order_arrays, scalars, _masks, _values, numf, _quoted, params = args
+    _order_arrays, scalars, _tiers, _masks, _values, numf, _quoted, params = args
     B, D, DEL = _stacked_segments(lowered)
     sc = np.zeros((_bucket(scalars.shape[0], 4), 4), dtype=np.int32)
     sc[: scalars.shape[0]] = np.asarray(scalars, dtype=np.int32)

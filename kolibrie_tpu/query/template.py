@@ -51,6 +51,7 @@ __all__ = [
     "cap_advisor",
     "cap_advisor_enabled",
     "note_cap_occupancy",
+    "note_scan_tiers",
     "occupancy_pct",
 ]
 
@@ -262,6 +263,26 @@ def note_cap_occupancy(engine: str, slots: int, rows: int) -> None:
     """One dispatch ran ``slots`` join slots and counted ``rows`` rows."""
     _CAP_SLOTS.labels(engine).inc(slots)
     _JOIN_ROWS.labels(engine).inc(rows)
+
+
+# how often an empty delta tier is not searched: per dispatch, one a scan
+# and one a WCOJ accessor, by the branch the plan body takes for its order
+# (optimizer/device_engine.py _plan_body: the same host entries it uploads)
+_SCAN_TIER = metrics.counter(
+    "kolibrie_device_scan_tier_total",
+    "scans and WCOJ accessors dispatched, by whether their order's delta "
+    "tier held nothing (base_only: the base is read alone) or a row or a "
+    "tombstone (two_tier: base and delta are merged)",
+    labels=("tier",),
+)
+_SCAN_TIER.labels("base_only")
+_SCAN_TIER.labels("two_tier")
+
+
+def note_scan_tiers(base_only: int, two_tier: int) -> None:
+    """One dispatch ran ``base_only`` + ``two_tier`` scans and accessors."""
+    _SCAN_TIER.labels("base_only").inc(base_only)
+    _SCAN_TIER.labels("two_tier").inc(two_tier)
 
 
 def cap_advisor_enabled() -> bool:

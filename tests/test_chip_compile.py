@@ -209,7 +209,7 @@ def test_whole_plan_batch_8_variants(one_chip, lubm_db):
     ]
     lows = [_lower_bgp(lubm_db, v) for v in variants]
     built = [lp.build() for lp in lows]
-    spec0, (order_arrays, _sc, masks, values, numf, quoted, _pp) = built[0]
+    spec0, (order_arrays, _sc, tiers, masks, values, numf, quoted, _pp) = built[0]
     assert all(spec == spec0 for spec, _ in built)
     with jax.enable_x64(True):
         scal = np.stack([np.asarray(lp._scan_ranges_np) for lp in lows])
@@ -219,7 +219,7 @@ def test_whole_plan_batch_8_variants(one_chip, lubm_db):
         )
         # the vmap entry always takes the XLA join formulation (Pallas
         # kernels do not vmap): what must hold is that it compiles
-        _compile(de._run_plan_batch, one_chip, order_arrays, scal, masks,
+        _compile(de._run_plan_batch, one_chip, order_arrays, scal, tiers, masks,
                  values, numf, quoted, params_b, lead=(spec0,),
                  want_kernel=False)
 
