@@ -464,11 +464,12 @@ def _pack_key(cols: List, valid, pad_sentinel):
 
 def _base_window(col, lo, cap: int):
     """Rows ``lo .. lo + cap`` of a base column as one ``dynamic_slice`` (a
-    copy, where a gather of the same rows costs 0.26 us a row on a v5e:
-    PERF.md section 6, PR 31).  ``dynamic_slice`` clamps its start so the
-    window fits the column; a window that would run past the padded end is
-    rotated back into place, and what it wraps around to lies beyond the
-    scan's rows, which the caller masks."""
+    copy; gathering the same rows by index took 68 of the 184 ms of device
+    time a LUBM lookups cycle had left on a v5e: PERF.md section 6, PR 31).
+    ``dynamic_slice`` clamps its start so the window fits the column; a
+    window that would run past the padded end is rotated back into place,
+    and what it wraps around to lies beyond the scan's rows, which the
+    caller masks."""
     import jax.numpy as jnp
     from jax import lax
 
@@ -661,11 +662,10 @@ def _plan_body(
             dcap = del_pos.shape[0]
             ar = jnp.arange(cap, dtype=jnp.int32)
             inb = ar < n_b
-            need = {pos for _, pos in node.out_vars}
-            for a, b in node.eq_pairs:
-                need.add(a)
-                need.add(b)
-            need = sorted(need)
+            need = sorted(
+                {pos for _, pos in node.out_vars}
+                | {pos for pair in node.eq_pairs for pos in pair}
+            )
 
             def base_only():
                 # the merge's answer for an empty delta: rows lo_b ..
