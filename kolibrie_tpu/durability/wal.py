@@ -320,6 +320,17 @@ class WalWriter:
         with self._lock:
             return self.segment, self._size
 
+    def last_record_segment(self) -> int:
+        """Index of the newest segment that holds a record: the active
+        one, or the one before it while the active one is still empty (a
+        seal or a size rotation ran after the last append).  A write's
+        read-your-writes token names this segment: an empty segment seals
+        only with the next write, so a follower would never apply a token
+        that named it."""
+        with self._lock:
+            empty = self._size <= len(SEG_MAGIC)
+            return self.segment - 1 if empty else self.segment
+
     def _rotate_locked(self) -> None:  # kolint: holds[_lock]
         self._fh.flush()
         if self.fsync_policy != "never":

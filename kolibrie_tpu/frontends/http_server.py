@@ -335,9 +335,11 @@ class TemplateBatcher:
     Handler threads call :meth:`submit`; requests that land within the
     batching window ride one dispatch.  Inside a dispatch, identical
     query texts are deduplicated (one execution, shared result) and
-    same-template queries are stacked into a single vmap program by
-    ``execute_queries_batched`` — under load, N constant-variants of one
-    query shape cost one device call, not N.
+    same-template queries ride one device program
+    (``execute_queries_batched``: a loop over the group's live members in
+    the slot class of its size) — under load, N constant-variants of one
+    query shape cost one device call, not N, and no group size compiles
+    a program of its own.
 
     The first waiter whose window expires claims ``dispatch_lock`` and
     drains the whole pending list (leader election); followers just wait
@@ -1197,9 +1199,15 @@ class KolibrieHandler(BaseHTTPRequestHandler):
         if state.durability is not None and state.durability.wal is not None:
             # read-your-writes token: a follower that has applied this
             # segment holds this write (segments seal whole — see
-            # replication/primary.py)
-            seg, off = state.durability.wal.position()
-            body["watermark"] = {"segment": seg, "offset": off}
+            # replication/primary.py); where the shipper sealed the
+            # write's segment before this line, that one and not the
+            # empty one after it
+            wal = state.durability.wal
+            _seg, off = wal.position()
+            body["watermark"] = {
+                "segment": wal.last_record_segment(),
+                "offset": off,
+            }
         with span("http.respond"):
             self._send_json(body)
 

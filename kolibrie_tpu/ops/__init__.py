@@ -18,6 +18,7 @@ __all__ = [
     "equi_join_tables",
     "multi_key_pack",
     "round_cap",
+    "slot_class",
     "unique_rows",
     *_LAZY_KERNELS,
 ]
@@ -31,6 +32,18 @@ def round_cap(n: int, lo: int = 128) -> int:
     while c < n:
         c <<= 1
     return c
+
+
+MIN_SLOTS = 8
+
+
+def slot_class(members: int) -> int:
+    """Rows of the parameter matrix a template group of ``members`` is
+    dispatched in, on the mesh and on one chip alike: a power of two, not
+    below :data:`MIN_SLOTS`.  A class costs memory (the ``[slots, ...]``
+    output buffers), not time: the member loop runs the live members only,
+    so one executable serves every group size up to its class."""
+    return round_cap(members, MIN_SLOTS)
 
 
 def __getattr__(name):

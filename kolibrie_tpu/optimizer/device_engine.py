@@ -117,8 +117,24 @@ _COLLECT_LAT = _obs_metrics.histogram(
 )
 _DEVICE_BATCH_SIZE = _obs_metrics.histogram(
     "kolibrie_device_batch_size",
-    "members per stacked-parameter batch dispatch",
+    "members per one-chip template group dispatch",
     buckets=_obs_metrics.DEFAULT_COUNT_BUCKETS,
+)
+# One-chip template groups (``execute_plan_batch``), once a group served:
+# members over slots is the slot class's occupancy, members over dispatches
+# the mean group.
+_BATCH_DISPATCHES = _obs_metrics.counter(
+    "kolibrie_device_batch_dispatch_total",
+    "one-chip template groups served by the batch executable",
+)
+_BATCH_MEMBERS = _obs_metrics.counter(
+    "kolibrie_device_batch_members_total",
+    "live members of the one-chip template groups served",
+)
+_BATCH_MEMBER_SLOTS = _obs_metrics.counter(
+    "kolibrie_device_batch_member_slots_total",
+    "member slots the batch executables of the groups served were compiled "
+    "for (the slot class of each group's live members)",
 )
 # Worst-case-optimal join instrumentation (emitted once per converged
 # execution, from the host-read counts — no extra device traffic)
@@ -407,18 +423,19 @@ def fetch_counters() -> Dict[str, int]:
     return dict(_FETCHES)
 
 
-# The children of ``device.dispatch``, shared by the solo and the stacked
+# The children of ``device.dispatch``, shared by the solo and the group
 # path so both split the same way (docs/OBSERVABILITY.md "Span taxonomy").
 
 
-def _build_traced(lowered, tag: int):
-    """``lowered.build(tag)`` under ``device.build``; ``h2d_bytes`` where the
-    store uploaded a segment (an order's first use after a base merge)."""
+def _build_traced(lowered, tag: int, operands: bool = True):
+    """``lowered.build(tag, operands)`` under ``device.build``; ``h2d_bytes``
+    where the store uploaded a segment (an order's first use after a base
+    merge)."""
     from kolibrie_tpu.core.store import h2d_bytes_total
 
     with _obs_span("device.build") as sp:
         before = h2d_bytes_total() if sp is not None else 0
-        built = lowered.build(tag)
+        built = lowered.build(tag, operands)
         uploaded = h2d_bytes_total() - before if sp is not None else 0
         if uploaded:
             sp.attrs["h2d_bytes"] = int(uploaded)
@@ -513,10 +530,9 @@ def _plan_body(
           zero-length delta shape would double the executables and put a
           compile into the first request after the first write; here that
           write flips a scalar;
-        - it survives ``vmap``: ``_run_plan_batch`` maps over ``scalars``
-          and ``params``, so a predicate made from a request's own ``n_d``
-          would be batched and the ``cond`` a ``select`` that runs both
-          branches; the order's entry is a store operand, not batched;
+        - a template group shares it: ``_run_plan_batch`` loops over the
+          members' ``scalars`` and ``params`` and hands every member the
+          one store, its segments and this entry with them;
         - no option: it is one algorithm whose second input is empty, and
           the code sees that in its input.
         """
@@ -1139,11 +1155,13 @@ def _run_plan(
     )
 
 
-@partial(jax.jit, static_argnames=("spec",))
+@partial(jax.jit, static_argnames=("spec", "use_pallas"))
 def _run_plan_batch(
     spec: PlanSpec,
+    use_pallas: bool,
     order_arrays,
     scalars_b,
+    live,
     tiers,
     masks,
     values,
@@ -1151,21 +1169,58 @@ def _run_plan_batch(
     quoted,
     params_b,
 ):
-    """Stacked-parameter dispatch: ONE executable evaluating the same plan
-    template for a whole batch of constant-variants (vmap over the scan
-    ranges and the packed parameter vectors; store operands, ``tiers``
-    among them, broadcast, so the body's conditionals on an empty delta
-    tier stay conditionals).  The serving layer's micro-batcher lands here.
-    Pallas kernels don't vmap, so the batch always takes the pure-XLA join
-    formulation."""
+    """One template group on one chip: ONE executable a template, capacity
+    set and slot class (``scalars_b`` and ``params_b`` have a row a slot,
+    ``ops.slot_class`` of the group's size), whatever the group's size.
+    ``live`` is traced: the loop runs the first ``live`` rows through the
+    solo body (:func:`_plan_body`, Pallas kernels and the delta tier's
+    conditionals as a lone request has them) and writes each member's
+    outputs at its slot, so a padded slot joins nothing and reads as it
+    was made: no row valid, every count 0.  The serving layer's
+    micro-batcher lands here.
+
+    Returns ``(rows, counts, stats)``: ``rows`` a tuple with one
+    ``[len(out_vars) + 1, cap]`` uint32 block a slot (the output columns,
+    then the valid mask), separate buffers so that the host reads the live
+    members' and no other; ``counts`` and ``stats`` as the solo body gives
+    them, each leaf ``[slots]``."""
+    import jax.numpy as jnp
+    from jax import lax
 
     def one(scalars, params):
-        return _plan_body(
+        out, valid, counts, stats = _plan_body(
             spec, order_arrays, scalars, tiers, masks, values, numf, quoted,
-            params, False,
+            params, use_pallas,
+        )
+        block = jnp.stack(
+            [*(c.astype(jnp.uint32) for c in out), valid.astype(jnp.uint32)]
+        )
+        return block, counts, stats
+
+    def row(i):
+        return jax.tree.map(
+            lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
+            (scalars_b, params_b),
         )
 
-    return jax.vmap(one, in_axes=(0, (0, 0)))(scalars_b, params_b)
+    def member(i, bufs):
+        return jax.tree.map(
+            lambda buf, x: lax.dynamic_update_index_in_dim(buf, x, i, 0),
+            bufs,
+            one(*row(i)),
+        )
+
+    slots = scalars_b.shape[0]
+    blocks, counts, stats = lax.fori_loop(
+        0,
+        live,
+        member,
+        jax.tree.map(
+            lambda a: jnp.zeros((slots, *a.shape), a.dtype),
+            jax.eval_shape(one, *row(0)),
+        ),
+    )
+    return tuple(blocks[b] for b in range(slots)), counts, stats
 
 
 def _jit_entries(fn) -> int:
@@ -2273,8 +2328,11 @@ class LoweredPlan:
             for i, (name, consts) in enumerate(self.scan_descs)
         }
 
-    def build(self, tag: int = 0) -> Tuple[PlanSpec, tuple]:
-        """Assemble (spec, array_args) for the current store/capacities."""
+    def build(self, tag: int = 0, operands: bool = True) -> Tuple[PlanSpec, tuple]:
+        """Assemble (spec, array_args) for the current store/capacities;
+        without ``operands`` the spec alone, ``(spec, None)``, and nothing
+        goes to the device: what a batch asks of its members after the
+        first, whose store operands it shares."""
         self._refresh_masks()
         scan_ranges = self._scan_ranges()
         scan_caps = self._template_scan_caps()
@@ -2282,14 +2340,16 @@ class LoweredPlan:
         self._scan_ranges_np = scan_ranges
         self._scan_caps = scan_caps
         self._join_caps = join_caps
-        return self._assemble(tag)
+        return self._assemble(tag, operands)
 
-    def _assemble(self, tag: int):
+    def _assemble(self, tag: int, operands: bool = True):
         import jax.numpy as jnp
 
         store = self.db.store
         root = self._with_caps(self.root, self._scan_caps, self._join_caps)
         spec = PlanSpec(root, self.out_vars, tuple(self.order_names), tag)
+        if not operands:
+            return spec, None
         order_arrays = tuple(
             store.device_segment(name) for name in self.order_names
         )
@@ -2758,7 +2818,7 @@ class LoweredPlan:
     def _note_scan_tiers(self, members: int = 1) -> None:
         """Count the dispatch just assembled: its scans and WCOJ accessors by
         the branch their order's entry of ``tiers`` selects in the plan body
-        (``members``: the variants a stacked dispatch runs them for)."""
+        (``members``: the live members a group's dispatch runs them for)."""
         from kolibrie_tpu.query.template import note_scan_tiers
 
         base_only = sum(1 for o in self._tier_sites if self._tiers_np[o] == 0)
@@ -3379,39 +3439,23 @@ def lower_plan(db, plan, anti_plans=(), union_groups=(), optional_plans=()) -> L
 def execute_plan_batch(
     lowereds: List[LoweredPlan], max_attempts: int = 12
 ) -> List[BindingTable]:
-    """Instrumented wrapper over :func:`_execute_plan_batch`: one
-    ``device.dispatch`` span + per-template timing for the whole stacked
-    dispatch."""
-    if not lowereds:
-        return []
-    tpl = _get_baggage("template", "unknown")
-    _DEVICE_BATCH_SIZE.observe(len(lowereds))
-    t0 = _time.perf_counter()
-    with _obs_span("device.dispatch", template=tpl, batch=len(lowereds)):
-        out = _execute_plan_batch(lowereds, max_attempts)
-    _DISPATCH_LAT.labels(tpl).observe(_time.perf_counter() - t0)
-    return out
-
-
-def _execute_plan_batch(
-    lowereds: List[LoweredPlan], max_attempts: int = 12
-) -> List[BindingTable]:
-    """Run MANY constant-variants of ONE plan template as a single
-    stacked-parameter device dispatch (:func:`_run_plan_batch`): the scan
-    ranges and packed parameter vectors stack along a batch axis, the
-    store operands broadcast.  Returns one host table per input, each
-    identical to that plan's own ``execute()``.
+    """Run MANY constant-variants of ONE plan template as a single device
+    dispatch (:func:`_run_plan_batch`): the members' scan ranges and packed
+    parameter vectors are the first rows of matrices as long as the slot
+    class of their number (``ops.slot_class``), the store operands are the
+    first member's, and the program runs the live rows only.  Returns one
+    host table per input, each identical to that plan's own ``execute()``.
 
     Every member must have lowered to the same template (equal assembled
     spec — guaranteed when they share a fingerprint); members with string
     masks must carry identical patterns, and VALUES templates are not
     batchable (their rows are per-variant constants outside the parameter
-    ABI).  Join-capacity convergence is max-over-batch: one overflow
-    doubles the shared template cap for everyone."""
-    import jax.numpy as jnp
+    ABI).  Capacities, occupancy and the readback see the live members
+    only: a padded slot counts nothing and is not read.
 
-    from kolibrie_tpu.query.template import note_cap_occupancy
-
+    Spans and timings as a lone ``execute()`` has them: ``device.dispatch``
+    (build to the counts read back, capacity re-runs in it), then
+    ``device.collect``."""
     if not lowereds:
         return []
     check_deadline("device.batch")
@@ -3422,6 +3466,8 @@ def _execute_plan_batch(
             raise Unsupported("batch members differ in string-mask patterns")
         if lp.values_tables or base.values_tables:
             raise Unsupported("VALUES templates are not batchable")
+    tpl = _get_baggage("template", "unknown")
+    _DEVICE_BATCH_SIZE.observe(len(lowereds))
     results: List[Optional[BindingTable]] = [None] * len(lowereds)
     live = []
     for i, lp in enumerate(lowereds):
@@ -3431,60 +3477,14 @@ def _execute_plan_batch(
             results[i] = lp.empty_table()
     if not live:
         return results
-    for attempt in range(max_attempts):
-        spec0 = None
-        base_args = None
-        scal, ups, fps = [], [], []
-        for i in live:
-            lp = lowereds[i]
-            spec, args = _build_traced(lp, 0)
-            if spec0 is None:
-                spec0, base_args = spec, args
-            elif spec != spec0:
-                raise Unsupported(
-                    "batch members lowered to different templates"
-                )
-            scal.append(np.asarray(lp._scan_ranges_np))
-            ups.append(np.asarray(lp.u_params or [0], dtype=np.uint32))
-            fps.append(np.asarray(lp.f_params or [0.0], dtype=np.float64))
-        order_arrays, _sc, tiers, masks, values, numf, quoted, _pp = base_args
-        with jax.enable_x64(True):
-            params_b = (
-                jnp.asarray(np.stack(ups)),
-                jnp.asarray(np.stack(fps), dtype=jnp.float64),
-            )
-            out = _enqueue_traced(
-                _run_plan_batch,
-                spec0,
-                order_arrays,
-                jnp.asarray(np.stack(scal)),
-                tiers,
-                masks,
-                values,
-                numf,
-                quoted,
-                params_b,
-            )
-        out_cols, valid, counts, bstats = out
-        lp0 = lowereds[live[0]]
-        caps = lp0._join_caps
-        counts_b = _read_counts(out, counts, attempt, np.asarray)
-        maxc = [int(np.max(c)) for c in counts_b]
-        note_cap_occupancy(
-            "device", len(live) * sum(caps), sum(int(np.sum(c)) for c in counts_b)
-        )
-        lp0._note_scan_tiers(len(live))
-        over = [j for j, c in enumerate(maxc) if c > caps[j]]
-        if not over:
-            break
-        for j in over:
-            lp0._join_caps[j] = _round_cap(2 * maxc[j])
-        lp0._store_caps()
-    else:
-        raise RuntimeError("batched plan capacities failed to converge")
+    members = [lowereds[i] for i in live]
+    t0 = _time.perf_counter()
+    with _obs_span("device.dispatch", template=tpl, batch=len(members)):
+        blocks, bstats = _converge_plan_batch(members, tpl, max_attempts)
+    _DISPATCH_LAT.labels(tpl).observe(_time.perf_counter() - t0)
     cap = _analyze.active()
     if cap is not None:
-        # batched stats leaves are [batch, ...] — one fetch, sliced per member
+        # batched stats leaves are [slots]: one fetch, sliced per member
         bstats_h = {k: np.asarray(v) for k, v in jax.device_get(bstats).items()}
         _note_fetch("analyze.batch_stats")
         for b, i in enumerate(live):
@@ -3492,18 +3492,111 @@ def _execute_plan_batch(
                 "device_batch",
                 member=i,
                 operators={k: int(v[b]) for k, v in bstats_h.items()},
-                caps=list(lowereds[live[0]]._join_caps),
+                caps=list(members[0]._join_caps),
             )
-    cols_h = [np.asarray(c) for c in out_cols]
-    valid_h = np.asarray(valid)
-    for b, i in enumerate(live):
-        lp = lowereds[i]
-        v = valid_h[b]
-        results[i] = {
-            var: ch[b][v].astype(np.uint32)
-            for var, ch in zip(lp.out_vars, cols_h)
-        }
+    t1 = _time.perf_counter()
+    with _obs_span("device.collect", members=len(members)):
+        _note_fetch("batch.rows")
+        blocks_h = jax.device_get(list(blocks))
+    _COLLECT_LAT.observe(_time.perf_counter() - t1)
+    for i, lp, block in zip(live, members, blocks_h):
+        v = block[-1] != 0
+        results[i] = {var: col[v] for var, col in zip(lp.out_vars, block)}
     return results
+
+
+def _converge_plan_batch(members: List[LoweredPlan], fp: str, max_attempts: int):
+    """Dispatch the group in the slot class of its size until every live
+    member's join counts fit the template's capacities: one overflow
+    doubles the shared cap for everyone and re-runs the group (one
+    executable a capacity set, whatever the group).  Returns the live
+    members' row blocks and the ``[slots]`` stats, device-resident."""
+    import jax.numpy as jnp
+
+    from kolibrie_tpu.ops import slot_class
+    from kolibrie_tpu.ops.pallas_kernels import pallas_enabled
+    from kolibrie_tpu.query.template import (
+        cap_advisor,
+        cap_retry_seconds,
+        note_cap_occupancy,
+    )
+
+    lp0 = members[0]
+    n, slots = len(members), slot_class(len(members))
+
+    def rows(of, dtype):
+        """The members' ``of`` as the first rows of a ``[slots, ...]`` matrix."""
+        live_rows = np.asarray([of(lp) for lp in members], dtype=dtype)
+        mat = np.zeros((slots, *live_rows.shape[1:]), dtype=dtype)
+        mat[:n] = live_rows
+        return mat
+
+    t_retry = None
+    for attempt in range(max_attempts):
+        spec0, base_args = _build_traced(lp0, 0)
+        for lp in members[1:]:
+            spec, _ = _build_traced(lp, 0, operands=False)
+            if spec != spec0:
+                raise Unsupported(
+                    "batch members lowered to different templates"
+                )
+        order_arrays, _sc, tiers, masks, values, numf, quoted, _pp = base_args
+        with jax.enable_x64(True):
+            params_b = (
+                jnp.asarray(rows(lambda lp: lp.u_params or [0], np.uint32)),
+                jnp.asarray(
+                    rows(lambda lp: lp.f_params or [0.0], np.float64),
+                    dtype=jnp.float64,
+                ),
+            )
+            out = _enqueue_traced(
+                _run_plan_batch,
+                spec0,
+                pallas_enabled(),
+                order_arrays,
+                jnp.asarray(rows(lambda lp: lp._scan_ranges_np, np.int32)),
+                np.int32(n),
+                tiers,
+                masks,
+                values,
+                numf,
+                quoted,
+                params_b,
+            )
+        blocks, counts, bstats = out
+        caps = lp0._join_caps
+        counts_b = _read_counts(out, counts, attempt, np.asarray)
+        if t_retry is not None:
+            cap_retry_seconds.labels("device").inc(
+                _time.perf_counter() - t_retry
+            )
+        maxc = [int(np.max(c)) for c in counts_b]
+        note_cap_occupancy(
+            "device", n * sum(caps), sum(int(np.sum(c)) for c in counts_b)
+        )
+        lp0._note_scan_tiers(n)
+        over = [j for j, c in enumerate(maxc) if c > caps[j]]
+        if not over:
+            break
+        if fp != "unknown":
+            cap_advisor.observe_retry("device", fp)
+        for j in over:
+            lp0._join_caps[j] = _round_cap(2 * maxc[j])
+        lp0._store_caps()
+        t_retry = _time.perf_counter()
+    else:
+        raise RuntimeError("batched plan capacities failed to converge")
+    if fp != "unknown":
+        cap_advisor.observe(
+            "device",
+            fp,
+            tuple(lp0._join_caps),
+            base_version=getattr(lp0.db.store, "base_version", None),
+        )
+    _BATCH_DISPATCHES.inc()
+    _BATCH_MEMBERS.inc(n)
+    _BATCH_MEMBER_SLOTS.inc(slots)
+    return blocks[:n], bstats
 
 
 def try_device_execute(

@@ -68,6 +68,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from kolibrie_tpu.obs import analyze as _analyze
 from kolibrie_tpu.obs import metrics as _m
 from kolibrie_tpu.obs.spans import span
+from kolibrie_tpu.ops import slot_class as _slot_class
 from kolibrie_tpu.parallel.dist_general import _exchange_table
 from kolibrie_tpu.parallel.dist_join import (
     _LPAD32 as _JLPAD,
@@ -387,18 +388,8 @@ def _batched_body(
 # Memoized program factory (the sanctioned jit-factory pattern) — the key
 # is the template's constant-free shape, its capacity pair and the slot
 # class, so constant-variants, mutation epochs and every group size up to
-# ``slots`` share one executable.
-
-_MIN_SLOTS = 8
-
-
-def _slot_class(members: int) -> int:
-    """Rows of the parameter matrix a group of ``members`` is dispatched
-    in: a power of two, not below :data:`_MIN_SLOTS`.  A class costs
-    memory (the ``[slots, ...]`` output buffers), not time — the loop runs
-    the live members only."""
-    return max(_MIN_SLOTS, 1 << max(members - 1, 0).bit_length())
-
+# ``slots`` share one executable (the class is ``ops.slot_class``, the
+# one-chip batch's rule too).
 
 @lru_cache(maxsize=64)
 def _get_batched_fn(

@@ -75,7 +75,7 @@ _PLAN_CACHE_EVENTS = obs_metrics.counter(
 )
 _BATCHED_QUERIES = obs_metrics.counter(
     "kolibrie_query_batched_total",
-    "queries served by a stacked-parameter batch dispatch",
+    "queries served in a template group (one chip's batch or the mesh)",
 )
 # fixed-label children hoisted out of the per-query hot path
 _QUERY_LAT_DEVICE = _QUERY_LAT.labels("device")
@@ -1389,24 +1389,70 @@ def _finish_select_table(db, q: SelectQuery, table: BindingTable) -> Rows:
     return _apply_limit_offset(rows, q)
 
 
-def execute_queries_batched(db, queries: List[str]) -> List[Rows]:
-    """Execute a batch of queries, dispatching same-template plain SELECTs
-    as ONE stacked-parameter vmap program (``execute_plan_batch``): the
-    device runs every member of a template group in a single jit call
-    instead of one dispatch per query.  Everything else — singleton
-    templates, aggregates, ordered queries, updates — falls back to
-    ``execute_query_volcano`` per query.  With a mesh attached
-    (``db._sharded_serving``) every template group, singletons too, goes
-    to ``ShardedDatabase.execute_batch`` first, and the single-device
-    paths serve only what the mesh lowering declines.  Results come back
-    in input order; per-query host post-processing (DISTINCT,
-    LIMIT/OFFSET, formatting) is identical to the solo path."""
+def _serve_group_on_one_chip(db, fp: str, group, board):
+    """One one-chip template group, under the span ``executor.batch``
+    (attrs ``template``, ``batch`` = live members, ``slots``): its members'
+    lowerings, one ``execute_plan_batch`` dispatch in the slot class of
+    its live members, each member's finished rows.  ``group``: ``(index,
+    entry, slot, query, where)`` a member.  ``None`` where the group
+    cannot ride one dispatch (a shape the lowering declines, a divergence
+    inside the group, a device fault the breaker has counted): its
+    members then run solo."""
     from kolibrie_tpu.optimizer.device_engine import (
         Unsupported,
         execute_plan_batch,
         lower_plan,
     )
+    from kolibrie_tpu.ops import slot_class
 
+    with span("executor.batch", template=fp, batch=len(group)) as sp:
+        try:
+            lowereds = []
+            for _i, _ent, _slot, _q, w in group:
+                resolved = [resolve_pattern(db, p) for p in w.patterns]
+                logical = build_logical_plan(resolved, list(w.filters), [], None)
+                planner = Streamertail(db.get_or_build_stats())
+                plan = planner.find_best_plan(logical)
+                lowereds.append((plan, lower_plan(db, plan)))
+            if sp is not None:  # what only the lowerings tell
+                live = sum(low.const_ok() for _, low in lowereds)
+                sp.attrs.update(batch=live, slots=slot_class(live) if live else 0)
+            tables = execute_plan_batch([low for _, low in lowereds])
+        except Unsupported:
+            return None  # shape/plan divergence inside the group: solo path
+        except DeadlineExceeded:
+            board.record_failure(fp)
+            raise
+        except Exception as e:
+            if not is_device_fault(e):
+                raise
+            # transient compile or device fault: count it, hand the whole
+            # group to the solo path (which degrades per the breaker)
+            board.record_failure(fp)
+            return None
+        board.record_success(fp)
+        out = []
+        for (i, ent, slot, q, _w), (plan, lowered), table in zip(
+            group, lowereds, tables
+        ):
+            if slot["params"] == ent["params"] and slot["lowered"] is None:
+                slot["plan"], slot["lowered"] = plan, lowered
+            out.append((i, _finish_select_table(db, q, table)))
+        return out
+
+
+def execute_queries_batched(db, queries: List[str]) -> List[Rows]:
+    """Execute a batch of queries, dispatching same-template plain SELECTs
+    as ONE device program (``execute_plan_batch``): the device runs every
+    member of a template group in a single jit call, in the slot class of
+    the group's size, instead of one dispatch per query.  Everything else
+    — singleton templates, aggregates, ordered queries, updates — falls
+    back to ``execute_query_volcano`` per query.  With a mesh attached
+    (``db._sharded_serving``) every template group, singletons too, goes
+    to ``ShardedDatabase.execute_batch`` first, and the single-device
+    paths serve only what the mesh lowering declines.  Results come back
+    in input order; per-query host post-processing (DISTINCT,
+    LIMIT/OFFSET, formatting) is identical to the solo path."""
     check_deadline("executor.batch")
     results: List[Optional[Rows]] = [None] * len(queries)
     for text in queries:
@@ -1434,7 +1480,7 @@ def execute_queries_batched(db, queries: List[str]) -> List[Rows]:
             continue  # breaker open: members fall to the solo degraded path
         if _interp_mode() == "force":
             # forced interpreter routing: the mesh shard_map program and
-            # the stacked-batch jit are exactly the per-template compiles
+            # the one-chip group jit are exactly the per-template compiles
             # the mode exists to avoid — members run solo through the
             # single-device interpreter instead (docs/COMPILE_CACHE.md)
             continue
@@ -1478,53 +1524,16 @@ def execute_queries_batched(db, queries: List[str]) -> List[Rows]:
             # mesh declined: a singleton runs solo, and so does a group
             # without single-device jit routing
             continue
-        lowereds, ok = [], True
-        for i in idxs:
-            ent, slot, q, w = members[i]
-            try:
-                resolved = [resolve_pattern(db, p) for p in w.patterns]
-                logical = build_logical_plan(resolved, list(w.filters), [], None)
-                planner = Streamertail(db.get_or_build_stats())
-                plan = planner.find_best_plan(logical)
-                lowered = lower_plan(db, plan)
-            except Unsupported:
-                ok = False
-                break
-            except DeadlineExceeded:
-                board.record_failure(fp)
-                raise
-            except Exception as e:
-                if not is_device_fault(e):
-                    raise
-                # transient compile fault: count it, hand the whole group
-                # to the solo path (which degrades per the breaker)
-                board.record_failure(fp)
-                ok = False
-                break
-            lowereds.append((i, q, plan, lowered))
-        if not ok:
-            continue
-        try:
-            tables = execute_plan_batch([low for _, _, _, low in lowereds])
-        except Unsupported:
-            continue  # shape/plan divergence inside the group: solo path
-        except DeadlineExceeded:
-            board.record_failure(fp)
-            raise
-        except Exception as e:
-            if not is_device_fault(e):
-                raise
-            board.record_failure(fp)
-            continue
-        board.record_success(fp)
+        got = _serve_group_on_one_chip(
+            db, fp, [(i, *members[i]) for i in idxs], board
+        )
+        if got is None:
+            continue  # the members fall to the solo path
         stats["batched"] += len(idxs)
         stats["batch_groups"] += 1
         _BATCHED_QUERIES.inc(len(idxs))
-        for (i, q, plan, lowered), table in zip(lowereds, tables):
-            ent, slot, _, _ = members[i]
-            if slot["params"] == ent["params"] and slot["lowered"] is None:
-                slot["plan"], slot["lowered"] = plan, lowered
-            results[i] = _finish_select_table(db, q, table)
+        for i, rows in got:
+            results[i] = rows
     # multi-query sharing for the solo tail: register every still-pending
     # member's prefix fingerprint as a transient beneficiary, so the MQO
     # layer sees the dispatch's full fan-out before the first member runs

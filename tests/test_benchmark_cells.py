@@ -1,7 +1,9 @@
 """The benchmark's cells as ``BENCHMARK.json`` lists them, checked without
 JAX: the texts one client sends are what they were before there were clients,
-the clients of ``mesh_q7`` never share a constant, the cell ``lubm5.mesh4``
-is in with the entries PR 27 wrote for it, every file a cell or a
+the clients of ``mesh_q7`` never share a constant (in ``lubm5.mesh4`` and in
+``lubm5.batch8``, which sends the same traffic to one chip), the cell
+``lubm5.mesh4`` is in with the entries PR 27 wrote for it and ``lubm5.batch8``
+as ISSUE 32 states it, every file a cell or a
 per-layer metric names is there, a program that lacks what a cell
 requires of it (``benchmark/requires``) is refused before anything starts,
 and ``run.py`` itself, started off the chip, prints no result and exits 3
@@ -53,9 +55,13 @@ def test_one_client_sends_the_texts_it_always_sent(workload, seed):
     assert h.hexdigest() == DIGESTS[f"{workload}:{seed}"]
 
 
-@pytest.fixture(scope="module")
-def departments():
-    return generated("lubm5.mesh4", 2**31 + 5, 1)["domains"]
+MESH_Q7_CELLS = ["lubm5.mesh4", "lubm5.batch8"]
+
+
+@pytest.fixture(scope="module", params=MESH_Q7_CELLS)
+def departments(request):
+    assert CELLS[request.param]["traffic"] == "mesh_q7"
+    return generated(request.param, 2**31 + 5, 1)["domains"]
 
 
 @pytest.mark.parametrize("clients", [1, 2, 8])
@@ -101,6 +107,51 @@ def test_benchmark_json_has_the_mesh_cell_as_its_entries_file_states_it():
             "mesh serving", "cycle_ms", ["lubm5.mesh4"])
 
 
+BATCH8_METRICS = {
+    "executor_batch_ms": ("span_total", "one-chip batch"),
+    "batch_dispatch_ms": ("span_total", "device dispatch"),
+    "batch_device_wait_ms": ("span_total", "device dispatch"),
+    "batch_dispatches_in_window": ("counter_delta", "one-chip batch"),
+    "batch_members_in_window": ("counter_delta", "one-chip batch"),
+    "batch_member_slots_in_window": ("counter_delta", "one-chip batch"),
+    "batch_programs_in_window": ("counter_delta", "one-chip batch"),
+}
+
+
+def test_benchmark_json_has_the_one_chip_cell_of_eight_clients():
+    cell = CELLS["lubm5.batch8"]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "lubm-5-clients8", "mesh_q7", 1)
+    assert BENCH["workloads"][-1] == cell  # appended, nothing before it moved
+    config = files.read_json("configs", "lubm-5-clients8.json")
+    one_client = files.read_json("configs", "lubm-5.json")
+    assert (config["chips"], config["universities"], config["store_mode"]) == (
+        1, 5, "device")
+    assert "layout" not in config  # one chip holds the whole store
+    # lubm-5's deployment asked by 8 clients: the same data, guarantees and
+    # cut, and one guarantee more, which a group must not break
+    for key in ("generator", "universities", "control", "reduced"):
+        assert config[key] == one_client[key], key
+    assert config["guarantees"].items() >= one_client["guarantees"].items()
+    assert "whatever group" in config["guarantees"]["served_in_a_group"]
+    assert config["assumed"][:-1] == one_client["assumed"]
+    assert files.read_json("workloads", "lubm5.batch8.json") == {"env": {}}
+    # its per-layer metrics: data files with readers that exist, listing
+    # this cell alone, all moving cycle_ms
+    added = {m["name"]: m for m in BENCH["per_layer"] if m["name"] in BATCH8_METRICS}
+    assert sorted(added) == sorted(BATCH8_METRICS)
+    for name, (kind, layer) in BATCH8_METRICS.items():
+        m = added[name]
+        assert (m["layer"], m["moves"], m["workloads"]) == (
+            layer, "cycle_ms", ["lubm5.batch8"])
+        assert files.read_json("layer_metrics", name + ".json")["reader"][
+            "kind"] == kind
+    # no standing metric's list was edited to take the cell in
+    for m in BENCH["per_layer"]:
+        if m["name"] not in BATCH8_METRICS:
+            assert "lubm5.batch8" not in m.get("workloads", [])
+
+
 @pytest.mark.parametrize("workload", sorted(CELLS))
 def test_every_file_a_cell_names_is_there(workload):
     cell = CELLS[workload]
@@ -129,7 +180,7 @@ def test_every_per_layer_metric_has_its_file_and_its_reader():
 
 def test_at_most_half_the_cells_take_four_chips():
     four = [w["name"] for w in BENCH["workloads"] if w["chips"] == 4]
-    assert four == ["lubm5.mesh4"]
+    assert four == ["lubm5.mesh4"] and len(CELLS) == 5
     assert len(four) <= max(1, len(CELLS) // 2)
     assert json.dumps(BENCH).count('"chips": 4') == 1
 
@@ -140,7 +191,7 @@ def test_every_requirement_belongs_to_a_cell_and_names_a_documented_metric():
     with open(os.path.join(REPO, "docs", "OBSERVABILITY.md"), encoding="utf-8") as f:
         catalog = f.read()
     found = sorted(os.listdir(files.path("requires")))
-    assert found == ["lubm5.mesh4.json"]
+    assert found == ["lubm5.batch8.json", "lubm5.mesh4.json"]
     for name in found:
         assert name[:-len(".json")] in CELLS
         need = files.read_json("requires", name)
@@ -151,14 +202,20 @@ def test_every_requirement_belongs_to_a_cell_and_names_a_documented_metric():
 
 
 @pytest.mark.parametrize("registered", [True, False])
+@pytest.mark.parametrize(
+    "family", ["kolibrie_test_required_total"] + [
+        files.read_json("requires", cell + ".json")["registers"]
+        for cell in ("lubm5.batch8", "lubm5.mesh4")])
 def test_a_program_without_the_required_metric_is_refused_at_once(
-        tmp_path, monkeypatch, registered):
+        tmp_path, monkeypatch, registered, family):
+    """Each cell's own family too, asked of a program whose registry is
+    empty or holds it (the module is the registry's, so this stays off JAX)."""
     from benchmark import harness
     from kolibrie_tpu.obs import metrics
 
     for folder, body in (
             ("requires", {"module": "kolibrie_tpu.obs.metrics",
-                          "registers": "kolibrie_test_required_total",
+                          "registers": family,
                           "why": "a test"}),
             ("workloads", {"env": {"KOLIBRIE_TEST_REQUIRES": "1"}})):
         (tmp_path / folder).mkdir()
@@ -166,7 +223,7 @@ def test_a_program_without_the_required_metric_is_refused_at_once(
     monkeypatch.setenv("KOLIBRIE_TEST_REQUIRES", "0")
     monkeypatch.setattr(metrics, "REGISTRY", metrics.Registry())
     if registered:
-        metrics.REGISTRY.counter("kolibrie_test_required_total", "a test")
+        metrics.REGISTRY.counter(family, "a test")
         harness._requires("a.cell", str(tmp_path))
         assert os.environ["KOLIBRIE_TEST_REQUIRES"] == "1"
     else:
@@ -175,10 +232,14 @@ def test_a_program_without_the_required_metric_is_refused_at_once(
     harness._requires("a.cell.without.the.file", str(tmp_path))  # requires nothing
 
 
-def test_off_the_chip_run_py_prints_no_result_and_exits_3(tmp_path):
+@pytest.mark.parametrize("workload", ["employee100k.upstream", "lubm5.batch8"])
+def test_off_the_chip_run_py_prints_no_result_and_exits_3(tmp_path, workload):
     """A number from a CPU run is never written as a result: without a TPU
     ``benchmark/run.py`` says on standard error what it found, prints
-    nothing on standard output and exits 3 (``NO_CHIP_EXIT``)."""
+    nothing on standard output and exits 3 (``NO_CHIP_EXIT``).  For
+    ``lubm5.batch8`` that also says this program passed what the cell
+    requires of it (``benchmark/requires``): a program that does not is
+    refused with exit 1 before it looks for a chip."""
     import subprocess
 
     env = dict(os.environ, JAX_PLATFORMS="cpu",
@@ -186,7 +247,7 @@ def test_off_the_chip_run_py_prints_no_result_and_exits_3(tmp_path):
     env.pop("KOLIBRIE_BENCH_REHEARSAL_SCALE", None)
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "benchmark", "run.py"),
-         "--workload", "employee100k.upstream", "--seconds", "1"],
+         "--workload", workload, "--seconds", "1"],
         cwd=str(tmp_path), env=env, capture_output=True, text=True, timeout=300)
     assert proc.returncode == 3, proc.stderr[-2000:]
     assert proc.stdout.strip() == ""

@@ -196,7 +196,10 @@ def test_whole_plan_lubm_q9(one_chip, lubm_db):
         _compile(de._run_plan, one_chip, *args, lead=(spec, True))
 
 
-def test_whole_plan_batch_8_variants(one_chip, lubm_db):
+def test_whole_plan_batch_slot_class_8(one_chip, lubm_db):
+    """The one-chip group program (``_run_plan_batch``: the live-member loop
+    whose body is the solo plan body) for a class of 8: the Pallas
+    merge-join kernels stay in it, inside the ``while``."""
     from examples import lubm
     from kolibrie_tpu.optimizer import device_engine as de
 
@@ -217,11 +220,10 @@ def test_whole_plan_batch_8_variants(one_chip, lubm_db):
             np.stack([np.asarray(lp.u_params or [0], np.uint32) for lp in lows]),
             np.stack([np.asarray(lp.f_params or [0.0], np.float64) for lp in lows]),
         )
-        # the vmap entry always takes the XLA join formulation (Pallas
-        # kernels do not vmap): what must hold is that it compiles
-        _compile(de._run_plan_batch, one_chip, order_arrays, scal, tiers, masks,
-                 values, numf, quoted, params_b, lead=(spec0,),
-                 want_kernel=False)
+        compiled = _compile(
+            de._run_plan_batch, one_chip, order_arrays, scal, np.int32(8),
+            tiers, masks, values, numf, quoted, params_b, lead=(spec0, True))
+    assert "while" in compiled.as_text()
 
 
 def test_mesh_program_lubm_q7_four_chips(topo, mesh8):

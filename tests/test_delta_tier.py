@@ -7,7 +7,8 @@ read the base alone, on a traced operand (``tiers``), so
 - ``kolibrie_device_scan_tier_total`` says which branch each scan and
   accessor took;
 - a write flips a scalar and compiles nothing: one executable a template;
-- the stacked dispatch keeps a conditional (the predicate is not batched).
+- the group dispatch keeps a conditional: its member loop runs the solo
+  body, and the predicate is a store operand.
 """
 
 import numpy as np
@@ -200,7 +201,10 @@ def _stacked(db, variants):
         np.stack([np.asarray(lp.u_params or [0], np.uint32) for lp in lows]),
         np.stack([np.asarray(lp.f_params or [0.0], np.float64) for lp in lows]),
     )
-    return lows, spec, (orders, scal, tiers, masks, values, numf, quoted, params)
+    live = np.int32(len(lows))
+    return lows, spec, (
+        orders, scal, live, tiers, masks, values, numf, quoted, params
+    )
 
 
 def _eqns(jaxpr):
@@ -215,9 +219,10 @@ def _eqns(jaxpr):
 
 @pytest.mark.parametrize("delta", ["empty", "live"])
 def test_the_batch_keeps_a_conditional(delta):
-    """``vmap`` over scan ranges and parameters leaves the scan's ``cond``
-    a ``cond`` (its predicate comes from a store operand), and the batch's
-    rows are the single dispatches'."""
+    """The group's program is a loop over its live members whose body is
+    the solo plan body: the scan's ``cond`` stays a ``cond`` on a scalar
+    store operand, one member wide, and the batch's rows are the single
+    dispatches'."""
     import jax
 
     db = _graph_db()
@@ -230,18 +235,18 @@ def test_the_batch_keeps_a_conditional(delta):
     lows, spec, args = _stacked(db, variants)
     with jax.enable_x64(True):
         jaxpr = jax.make_jaxpr(
-            lambda *a: de._run_plan_batch(spec, *a)
+            lambda *a: de._run_plan_batch(spec, False, *a)
         )(*args)
     eqns = list(_eqns(jaxpr.jaxpr))
+    assert [e for e in eqns if e.primitive.name == "while"]
     conds = [e for e in eqns if e.primitive.name == "cond"]
     n_scans = _count(lows[0].root, de.ScanSpec)
     assert n_scans == 2 and len(conds) == n_scans
     for e in conds:
-        # an unbatched predicate: a scalar, and each branch still maps the
-        # whole batch (a batched one would have turned the cond into both
-        # branches and a select over their outputs)
+        # a scalar predicate, and each branch one member wide: nothing in
+        # the loop's body carries the group's axis
         assert e.invars[0].aval.shape == ()
-        assert all(v.aval.shape[0] == len(variants) for v in e.outvars)
+        assert all(v.aval.ndim <= 1 for v in e.outvars)
     cap = max(s.cap for s in _nodes(lows[0].root, de.ScanSpec))
     assert not [
         e for e in eqns

@@ -94,6 +94,24 @@ def test_wal_segment_rotation(tmp_path):
     assert stats.segments >= 4
 
 
+def test_a_write_token_names_the_segment_that_holds_the_record(tmp_path):
+    """A seal between a write and its read-your-writes token leaves the
+    active segment empty: the token names the sealed segment, which a
+    follower applies, not the empty one, which seals only with the next
+    write (a follower waiting for it would wait for that write)."""
+    w = wal.WalWriter(wal_dir(tmp_path), fsync_policy="always")
+    assert w.last_record_segment() == w.segment - 1  # nothing written yet
+    w.append({"i": 0})
+    assert w.last_record_segment() == w.segment
+    sealed = w.seal_if_dirty()
+    assert w.position() == (sealed + 1, len(wal.SEG_MAGIC))
+    assert w.last_record_segment() == sealed
+    assert w.seal_if_dirty() is None  # an empty segment never seals
+    w.append({"i": 1})
+    assert w.last_record_segment() == w.segment == sealed + 1
+    w.close()
+
+
 # ----------------------------------------------- torn / corrupt truncation
 
 
