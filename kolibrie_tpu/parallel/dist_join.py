@@ -157,6 +157,30 @@ def bucketize(
     return tuple(bufs), bvalid, dropped
 
 
+def compact(
+    cols: Sequence[jnp.ndarray],
+    valid: jnp.ndarray,
+    cap: int,
+) -> Tuple[Tuple[jnp.ndarray, ...], jnp.ndarray, jnp.ndarray]:
+    """Gather the valid rows, in their order, into the first slots of a
+    table of ``cap`` rows.
+
+    For a scan that selects a few rows of a wide block: what runs at the
+    block's width is one prefix count of the mask; the positions are ``cap``
+    searches into it and the columns ``cap``-row gathers, so nothing
+    downstream sorts, searches or scatters at the block's width.  Rows
+    beyond ``cap`` are DROPPED and counted, as :func:`bucketize` counts
+    them, for the host's grow-and-retry."""
+    cum = jnp.cumsum(valid.astype(jnp.int32))
+    total = cum[-1]
+    idx = jnp.arange(cap, dtype=jnp.int32)
+    pos = jnp.searchsorted(cum, idx + 1, side="left")
+    ok = idx < total
+    pos = jnp.where(ok, pos, 0)
+    out = tuple(jnp.where(ok, c[pos], 0) for c in cols)
+    return out, ok, jnp.maximum(total - cap, 0)
+
+
 def exchange(
     cols: Sequence[jnp.ndarray],
     valid: jnp.ndarray,

@@ -946,8 +946,10 @@ class DistQueryExecutor:
         so a plan that would join millions of rows is never materialised.
         The two capacities follow the single-device rule
         (``device_engine.fit_join_caps``), each from its own count: the
-        largest per-shard join step and the largest (source,
-        destination) exchange bucket, never above the store-size
+        largest per-shard join step (for the batched body the seed's rows
+        a shard among them: it compacts them into ``join_cap`` slots) and
+        the largest (source, destination) exchange bucket, never above the
+        store-size
         heuristic; the overflow/retry protocol backstops a constant with
         more than 4x the counted rows.  Where nothing can be counted
         (every walk past ``_CALIBRATE_ROW_LIMIT``) the most-constants
@@ -1003,7 +1005,10 @@ class DistQueryExecutor:
         the main BGP and every clause branch, told by ``self.batched``
         which body it sizes.  Rows start on the shard that owns their triple's subject
         (the seed scans the subject mirror) and move to the owner of each
-        step's key.  A join step's size is what the program's join counts
+        step's key.  The batched body compacts its seed scan into a table
+        of ``join_cap`` rows, so for it the seed's rows per shard stand in
+        front of the join steps'.  A join step's size is what the program's
+        join counts
         before any mask, on the largest shard: for the solo
         ``_query_body`` the left rows' matches in the side premise's
         scan (its constants pre-mask the side), for the batched body
@@ -1033,6 +1038,11 @@ class DistQueryExecutor:
             else (n > 1,) * len(steps)
         )
         step_rows, buckets = [], []
+        if self.batched:
+            seed_rows = np.bincount(shard, minlength=n).astype(np.int64)
+            if limit is not None and seed_rows.max() > limit:
+                raise _Uncounted
+            step_rows.append(seed_rows)
         for (j, kv, kpos, extra), routed in zip(steps, exchanged):
             ptab, _ = table_of(premises[j])
             lk, rk = table[kv], ptab[kv]

@@ -24,9 +24,10 @@ relations + delta-only transfer, arXiv:2311.02206):
    ``[slots, n_slots]`` parameter matrix and takes the number of live
    members beside it as a traced scalar, replicated over the mesh.  A loop
    INSIDE one ``shard_map`` body runs the live members only — per member:
-   shard-local seed scan, fixed-cap ``all_to_all`` binding-table exchange,
-   local joins, replicated filter masks — so a dispatch costs what its
-   live members cost, and a group of one is a group like any other: under
+   shard-local seed scan compacted to ``join_cap`` rows, fixed-cap
+   ``all_to_all`` binding-table exchange, local joins, replicated filter
+   masks — so a dispatch costs what its live members cost, and a group
+   of one is a group like any other: under
    an attached mesh the executor sends every request of a supported shape
    here (no device holds the whole store in the deployment this stands
    for).  ``slots`` is a class (a power of two, not below 8), not the
@@ -74,6 +75,7 @@ from kolibrie_tpu.parallel.dist_join import (
     _LPAD32 as _JLPAD,
     _RPAD32 as _JRPAD,
     _dist_check_vma,
+    compact,
 )
 from kolibrie_tpu.parallel.mesh import make_mesh
 from kolibrie_tpu.parallel.sharded_store import ShardedTripleStore, shard_of
@@ -143,6 +145,16 @@ _SHARD_CAP_SLOTS = _m.counter(
     "Join and exchange slots the dispatched mesh executables were compiled "
     "for: live members x shards x (join steps x join_cap + exchanges x "
     "shards x bucket_cap)",
+)
+_SHARD_SEED_SLOTS = _m.counter(
+    "kolibrie_shard_seed_slots_total",
+    "Slots of the compacted seed tables the dispatched mesh executables "
+    "were compiled for: live members x shards x join_cap",
+)
+_SHARD_SEED_ROWS = _m.counter(
+    "kolibrie_shard_seed_rows_total",
+    "Rows the mesh programs' seed scans counted, per live member and shard "
+    "(over seed_slots_total: the seed tables' occupancy)",
 )
 _SHARD_JOIN_ROWS = _m.counter(
     "kolibrie_shard_join_rows_total",
@@ -248,7 +260,12 @@ def _batched_body(
     ``live`` rows of the ``[slots, n_slots]`` constant matrix, each member
     running the shard-local scan -> routed-join -> filter pipeline of
     ``dist_query._query_body`` and writing its outputs into row ``i`` of
-    preallocated ``[slots, ...]`` buffers.  ``live`` is a traced scalar,
+    preallocated ``[slots, ...]`` buffers.  Unlike that body, a member
+    compacts its seed scan to ``join_cap`` rows (:func:`dist_join.compact`)
+    before the first step, so its binding table is never wider than
+    ``max(join_cap, n * bucket_cap)``: the seed's mask and one prefix count
+    of it are all a member does at the shard's width.  ``live`` is a
+    traced scalar,
     replicated over the mesh: every shard runs the same trips, so the
     ``all_to_all`` / ``psum`` inside the loop stay matched, and a group of
     any size up to ``slots`` shares this one executable at the cost of its
@@ -299,63 +316,76 @@ def _batched_body(
         return table, m
 
     def one(prm):
-        ov = jnp.int32(0)
-        table, valid = scan_param(premises[seed], fcols, fv, prm)
+        with jax.named_scope("seed"):
+            table, valid = scan_param(premises[seed], fcols, fv, prm)
+        # The seed's few rows leave the shard's width here: everything
+        # below runs at ``join_cap`` or ``n * bucket_cap``, and rows past
+        # ``join_cap`` on a shard overflow like any join's.
+        with jax.named_scope("compact"):
+            names = sorted(table)
+            cols, valid, dropped = compact(
+                tuple(table[v] for v in names), valid, join_cap
+            )
+            table = dict(zip(names, cols))
+            ov = lax.psum(dropped, axis)
         # Per-operator stats, SHARD-LOCAL (no psum: the host sees the
         # [slots, n, n_stats] block and can read imbalance per shard or sum
         # across shards).  Layout: [seed rows, (exchange rows, key
         # matches, join rows) per step, final rows] — exchange slot stays
         # 0 when the step's all-to-all is elided by co-partitioning; the
-        # key matches are what ``join_cap`` has to hold, the join rows
-        # what the side premise's constants leave of them.
-        svec = [jnp.sum(valid).astype(jnp.int32)]
-        for (j, kv, kpos, extra), routed, (side_cols, order, rsorted) in zip(
-            steps, exchanged, sides
+        # seed rows and the key matches are what ``join_cap`` has to hold,
+        # the join rows what the side premise's constants leave of them.
+        svec = [jnp.sum(valid).astype(jnp.int32) + dropped]
+        for k, ((j, kv, kpos, extra), routed, (side_cols, order, rsorted)) in (
+            enumerate(zip(steps, exchanged, sides))
         ):
             prem = premises[j]
             if routed:
-                table, valid, dropped = _exchange_table(
-                    table, valid, kv, n, axis, bucket_cap
-                )
-                ov = ov + dropped.astype(jnp.int32)
-                svec.append(jnp.sum(valid).astype(jnp.int32))
+                with jax.named_scope(f"exchange{k}"):
+                    table, valid, dropped = _exchange_table(
+                        table, valid, kv, n, axis, bucket_cap
+                    )
+                    ov = ov + dropped.astype(jnp.int32)
+                    svec.append(jnp.sum(valid).astype(jnp.int32))
             else:
                 svec.append(jnp.int32(0))
-            li, ri, jvalid, total = _join_presorted(
-                table[kv], valid, rsorted, order, join_cap
-            )
-            ov = ov + lax.psum(
-                jnp.maximum(total - join_cap, 0).astype(jnp.int32), axis
-            )
-            svec.append(total.astype(jnp.int32))
-            # side premise filters, post-join at the matched rows
-            for c, col in zip(prem.consts, side_cols):
-                if c is not None:
-                    jvalid = jvalid & (col[ri] == prm[c])
-            for a, b in prem.eq_pairs:
-                jvalid = jvalid & (side_cols[a][ri] == side_cols[b][ri])
-            ptable = {v: side_cols[pos] for v, pos in prem.vars}
-            new_table = {v: c[li] for v, c in table.items()}
-            for v, c in ptable.items():
-                if v not in new_table:
-                    new_table[v] = c[ri]
-                elif v in extra:
-                    jvalid = jvalid & (new_table[v] == c[ri])
-            table, valid = new_table, jvalid
+            with jax.named_scope(f"join{k}"):
+                li, ri, jvalid, total = _join_presorted(
+                    table[kv], valid, rsorted, order, join_cap
+                )
+                ov = ov + lax.psum(
+                    jnp.maximum(total - join_cap, 0).astype(jnp.int32), axis
+                )
+                svec.append(total.astype(jnp.int32))
+                # side premise filters, post-join at the matched rows
+                for c, col in zip(prem.consts, side_cols):
+                    if c is not None:
+                        jvalid = jvalid & (col[ri] == prm[c])
+                for a, b in prem.eq_pairs:
+                    jvalid = jvalid & (side_cols[a][ri] == side_cols[b][ri])
+                ptable = {v: side_cols[pos] for v, pos in prem.vars}
+                new_table = {v: c[li] for v, c in table.items()}
+                for v, c in ptable.items():
+                    if v not in new_table:
+                        new_table[v] = c[ri]
+                    elif v in extra:
+                        jvalid = jvalid & (new_table[v] == c[ri])
+                table, valid = new_table, jvalid
+                svec.append(jnp.sum(valid).astype(jnp.int32))
+        with jax.named_scope("filters"):
+            for f in filters:
+                col = table[f.var]
+                if f.kind == "eq":
+                    valid = valid & (col == jnp.uint32(f.const_id))
+                elif f.kind == "ne":
+                    valid = valid & (col != jnp.uint32(f.const_id))
+                elif f.kind == "strmask":
+                    valid = valid & _strmask_verdict(col, masks, f)
+                else:
+                    m = masks[f.mask_idx]
+                    valid = valid & m[jnp.minimum(col, m.shape[0] - 1)]
             svec.append(jnp.sum(valid).astype(jnp.int32))
-        for f in filters:
-            col = table[f.var]
-            if f.kind == "eq":
-                valid = valid & (col == jnp.uint32(f.const_id))
-            elif f.kind == "ne":
-                valid = valid & (col != jnp.uint32(f.const_id))
-            elif f.kind == "strmask":
-                valid = valid & _strmask_verdict(col, masks, f)
-            else:
-                m = masks[f.mask_idx]
-                valid = valid & m[jnp.minimum(col, m.shape[0] - 1)]
-        svec.append(jnp.sum(valid).astype(jnp.int32))
-        outs = tuple(jnp.where(valid, table[v], 0) for v in out_vars)
+            outs = tuple(jnp.where(valid, table[v], 0) for v in out_vars)
         return outs, valid, ov, jnp.stack(svec)
 
     # The live-member loop.  The carry is typed from one member's outputs
@@ -1014,7 +1044,7 @@ class ShardedDatabase:
         """Pin the plan with the capacities that held and count the
         dispatch: live members beside the member slots it was compiled
         for (their ratio is the loop's occupancy), the rows the program
-        counted beside its join and exchange slots (the capacities'
+        counted beside its seed, join and exchange slots (the capacities'
         occupancy), rows scanned, static exchange bytes."""
         from kolibrie_tpu.parallel.dist_query import exchanged_steps
 
@@ -1048,6 +1078,8 @@ class ShardedDatabase:
             )
         )
         # stats layout: [seed, (exchange, matches, join) a step, final]
+        _SHARD_SEED_SLOTS.inc(live * self.n * join_cap)
+        _SHARD_SEED_ROWS.inc(int(group["stats"][:, :, 0].sum()))
         counted = group["stats"][:, :, 1:-1].reshape(live, self.n, -1, 3)
         _SHARD_JOIN_ROWS.inc(int(counted[..., :2].sum()))
         _SHARD_DISPATCH.labels("lone" if live == 1 else "batched").inc()

@@ -29,6 +29,7 @@ from kolibrie_tpu.query.executor import (
     execute_query_volcano,
 )
 from kolibrie_tpu.query.sparql_database import SparqlDatabase
+from kolibrie_tpu.query.template import cap_advisor
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "examples"))
 import lubm  # noqa: E402
@@ -705,6 +706,7 @@ def test_the_host_counts_what_the_program_counts(uba, body, seed):
     destination) bucket."""
     from kolibrie_tpu.parallel.dist_query import DistQueryExecutor
     from kolibrie_tpu.parallel.sharded_serving import _get_batched_fn
+    from kolibrie_tpu.parallel.sharded_store import shard_of
     import jax
 
     db, sh, texts = uba
@@ -719,10 +721,20 @@ def test_the_host_counts_what_the_program_counts(uba, body, seed):
     )
     step, bucket = _largest(step_rows, buckets)
     assert step > 1 and bucket > 1
+    if body == "batched":
+        # the batched body compacts its seed into ``join_cap`` slots, so
+        # the seed's rows a shard are among what the plan's largest step
+        # has to cover
+        scan = db.store.match(*ex.premises[ex.seed].consts)
+        seed_rows = np.bincount(shard_of(scan[0], sh.n), minlength=sh.n)
+        assert len(step_rows) == 1 + len(ex.steps)
+        assert step_rows[0].tolist() == seed_rows.tolist()
+        assert step >= seed_rows.max() > 0
     if seed == 0:
         # from every student the last step meets every triple that has a
         # course as its object: three orders of magnitude over the answer
         assert step > 1000 * len(execute_query_volcano(text, db))
+        assert seed_rows.max() > 1000
 
     def overflows(join_cap, bucket_cap):
         if body == "solo":
@@ -752,10 +764,129 @@ def test_the_host_counts_what_the_program_counts(uba, body, seed):
     assert not over
     if stats is not None:
         # [seed, (exchange, matches, join) a step, final], per shard
-        for k, per_shard in enumerate(step_rows):
+        assert stats[:, 0].tolist() == step_rows[0].tolist()
+        for k, per_shard in enumerate(step_rows[1:]):
             assert stats[:, 2 + 3 * k].tolist() == per_shard.tolist()
     assert overflows(step - 1, bucket)[0]
     assert overflows(step, bucket - 1)[0]
+
+
+@pytest.mark.parametrize(
+    "width, cap, rows",
+    [
+        (64, 8, 0), (64, 8, 1), (64, 8, 7), (64, 8, 8), (64, 8, 9),
+        (64, 8, 64), (4, 8, 3),
+    ],
+)
+def test_compact_keeps_the_first_cap_valid_rows_in_order(width, cap, rows):
+    import jax
+
+    from kolibrie_tpu.parallel.dist_join import compact
+
+    rng = np.random.default_rng(rows)
+    valid = np.zeros(width, dtype=bool)
+    valid[rng.choice(width, rows, replace=False)] = True
+    cols = (
+        np.arange(1, width + 1, dtype=np.uint32) * 3,
+        rng.integers(1, 1 << 31, width).astype(np.uint32),
+    )
+    out, ok, dropped = jax.jit(compact, static_argnums=2)(cols, valid, cap)
+    pos = np.flatnonzero(valid)[:cap]
+    assert np.asarray(ok).tolist() == [True] * len(pos) + [False] * (
+        cap - len(pos)
+    )
+    for col, got in zip(cols, out):
+        assert np.asarray(got).tolist() == col[pos].tolist() + [0] * (
+            cap - len(pos)
+        )
+    assert int(dropped) == max(rows - cap, 0)
+
+
+def _sub_jaxprs(eqn):
+    for v in eqn.params.values():
+        for j in v if isinstance(v, (tuple, list)) else (v,):
+            j = getattr(j, "jaxpr", j)
+            if hasattr(j, "eqns"):
+                yield j
+
+
+def _eqns(jaxpr, inside=()):
+    """Every equation under ``jaxpr`` with the primitives it is nested in."""
+    for eqn in jaxpr.eqns:
+        yield eqn, inside
+        for sub in _sub_jaxprs(eqn):
+            yield from _eqns(sub, inside + (eqn.primitive.name,))
+
+
+def test_nothing_in_the_member_loop_indexes_at_the_shards_width(uba):
+    """Inside the live-member loop of Q7's mesh program no gather or
+    scatter takes indices, and no search (a nested ``while``) carries
+    keys, of a mirror block's width, and one sort or prefix sum at most
+    runs there: the seed's compaction.  A member's cost follows its
+    capacities, not the shard."""
+    import jax
+
+    from kolibrie_tpu.parallel.sharded_serving import _get_batched_fn
+
+    db, sh, texts = uba
+    fp = _fp(db, texts[0])
+    with sh.lock:
+        group = sh._build_group(fp, [(0, texts[0])])
+    ex = group["execs"][0]
+    join_cap, bucket_cap = group["caps"]
+    wide = {sh._base_cap_s + sh._delta_cap, sh._base_cap_o + sh._delta_cap}
+    assert not wide & {join_cap, bucket_cap, sh.n * bucket_cap}
+    assert min(wide) > sh.n * bucket_cap
+    state = (
+        *sh.view.by_subj, sh.view.by_subj_valid,
+        *sh.view.by_obj, sh.view.by_obj_valid,
+    )
+    assert {a.shape[1] for a in state} == wide
+    fn = _get_batched_fn(
+        sh.mesh, group["premises"], ex.seed, ex.steps, ex.filters,
+        ex.out_vars, len(group["masks"]), join_cap, bucket_cap, 8,
+    )
+    with jax.enable_x64(True):
+        program = jax.make_jaxpr(fn)(
+            state, group["masks"], group["params"], np.int32(1)
+        )
+
+    def is_wide(var):
+        return bool(wide & set(getattr(var.aval, "shape", ())))
+
+    loops = [
+        eqn
+        for eqn, inside in _eqns(program.jaxpr)
+        if eqn.primitive.name == "while"
+        and "while" not in inside
+        and any(
+            e.primitive.name == "all_to_all"
+            for e, _ in _eqns(eqn.params["body_jaxpr"].jaxpr)
+        )
+    ]
+    assert len(loops) == 1  # the member loop: Q7 exchanges once a member
+    seen = {"gather": 0, "search": 0, "wide_order": 0}
+    for eqn, _ in _eqns(loops[0].params["body_jaxpr"].jaxpr):
+        name = eqn.primitive.name
+        if name == "gather" or name.startswith("scatter"):
+            seen["gather"] += 1
+            assert not is_wide(eqn.invars[1]), eqn
+        elif name in ("while", "scan"):
+            # a ``searchsorted``: its carry (the bounds) is as wide as its
+            # keys, the sorted side it searches is a constant of the loop
+            seen["search"] += 1
+            consts = (
+                eqn.params["num_consts"]
+                if name == "scan"
+                else eqn.params["cond_nconsts"] + eqn.params["body_nconsts"]
+            )
+            assert not any(is_wide(v) for v in eqn.invars[consts:]), eqn
+        elif name == "sort" or name.startswith(("cum", "reduce_window")):
+            seen["wide_order"] += any(is_wide(v) for v in eqn.invars)
+    # the walk met the member's gathers and searches, and the one
+    # shard-wide prefix count is the compaction's
+    assert seen["gather"] > 0 and seen["search"] > 0
+    assert seen["wide_order"] == 1
 
 
 def test_members_that_alone_would_seed_differently_share_one_plan(uba):
@@ -797,9 +928,13 @@ def test_members_that_alone_would_seed_differently_share_one_plan(uba):
     assert _grew(before, _routes())["fallbacks"] == 0
 
 
-def test_a_constant_over_four_times_the_count_retries_doubled(mesh8):
+@pytest.mark.parametrize("hot", ["big", "mid"])
+def test_a_constant_over_four_times_the_count_retries_doubled(mesh8, hot):
     # the smaller capacity is sound only because the overflow retry stands
-    # behind it: <small> calibrates the floor, <big> counts 12 times it
+    # behind it: <small> calibrates the floor; <big> counts 12 times it in
+    # its joins and 3,000 seed rows on its subject's shard; of <mid> only
+    # the SEED overflows ``join_cap`` (1,500 rows on one shard, 10 of which
+    # join anything)
     db = SparqlDatabase()
     ex = "http://example.org/"
     lines = [f"<{ex}small> <{ex}p1> <{ex}y0> ."]
@@ -807,6 +942,10 @@ def test_a_constant_over_four_times_the_count_retries_doubled(mesh8):
         lines.append(f"<{ex}big> <{ex}p1> <{ex}y{i}> .")
         for j in range(4):
             lines.append(f"<{ex}y{i}> <{ex}p2> <{ex}z{i}_{j}> .")
+    for i in range(1500):
+        lines.append(f"<{ex}mid> <{ex}p1> <{ex}m{i}> .")
+    for i in range(10):
+        lines.append(f"<{ex}m{i}> <{ex}p2> <{ex}w{i}> .")
     db.parse_ntriples("\n".join(lines))
     db.execution_mode = "host"
     sh = attach_sharded(db, mesh8)
@@ -814,20 +953,30 @@ def test_a_constant_over_four_times_the_count_retries_doubled(mesh8):
     text = "SELECT ?y ?z WHERE {{ <%s{}> <%sp1> ?y . ?y <%sp2> ?z . }}" % (
         ex, ex, ex
     )
-    small, big = text.format("small"), text.format("big")
+    small, other = text.format("small"), text.format(hot)
     fp = _fp(db, small)
+    # the advisor's process-wide high-water mark would start this store's
+    # capacities where the other case's left them
+    cap_advisor.reset()
     assert sh.execute_batch(fp, [(0, small)])[0] == execute_query_volcano(
         small, db
     )
     seed, join_cap, bucket_cap = _plan_of(sh, fp)
     assert (join_cap, bucket_cap) == (1024, 1024)
     hits = sh.stats_counters["cap_hits"]
-    got = sh.execute_batch(fp, [(0, big)])[0]
-    assert got == execute_query_volcano(big, db) and len(got) == 12000
-    assert sh.stats_counters["cap_hits"] > hits
+    got = sh.execute_batch(fp, [(0, other)])[0]
+    assert got == execute_query_volcano(other, db)
     grown = _plan_of(sh, fp)
-    assert grown[0] == seed and grown[1] >= 2 * join_cap
-    assert grown[1] * 8 >= 12000
+    if hot == "big":
+        assert len(got) == 12000
+        assert sh.stats_counters["cap_hits"] > hits
+        assert grown[0] == seed and grown[1] >= 2 * join_cap
+        assert grown[1] * 8 >= 12000
+    else:
+        # one retry, both capacities doubled, the oracle's rows
+        assert len(got) == 10
+        assert sh.stats_counters["cap_hits"] == hits + 1
+        assert grown == (seed, 2 * join_cap, 2 * bucket_cap)
     # the capacities that held stay: the small constant builds no program
     programs = sharded_compile_stats()["batched_programs"]
     assert sh.execute_batch(fp, [(0, small)])[0] == execute_query_volcano(
@@ -856,6 +1005,8 @@ def test_plan_and_occupancy_counters(uba, source, monkeypatch):
         "all_plans": "kolibrie_shard_plan_total",
         "slots": "kolibrie_shard_cap_slots_total",
         "rows": "kolibrie_shard_join_rows_total",
+        "seed_slots": "kolibrie_shard_seed_slots_total",
+        "seed_rows": "kolibrie_shard_seed_rows_total",
     }
     before = {k: _metric(v) for k, v in names.items()}
     with obs_analyze.capture() as cap:
@@ -882,6 +1033,13 @@ def test_plan_and_occupancy_counters(uba, source, monkeypatch):
     # the occupancy the two counters give: a few per cent at the floor,
     # and what the most-constants plan honestly fills its slots with
     assert 0 < grew["rows"] <= grew["slots"]
+    # the compacted seed tables: ``join_cap`` slots a member and shard
+    # beside the rows the seed scans counted (every student of the store
+    # under the most-constants seed, 2-4 courses under the professor's)
+    assert grew["seed_slots"] == 3 * sh.n * join_cap
+    assert grew["seed_rows"] == sum(r["operators"]["seed"] for r in recs)
+    assert 0 < grew["seed_rows"] <= grew["seed_slots"]
+    assert (grew["seed_rows"] > 3000) == (source == "constants")
     with sh.lock:
         sh._plans.clear()  # the next test plans for itself
 
