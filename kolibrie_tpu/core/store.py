@@ -32,6 +32,7 @@ small mutations.
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import time
 from typing import Iterator, Optional, Tuple
@@ -39,6 +40,7 @@ from typing import Iterator, Optional, Tuple
 import numpy as np
 
 from kolibrie_tpu.core.triple import Triple
+from kolibrie_tpu.obs.spans import current_trace_id, span
 
 _EMPTY = np.empty(0, dtype=np.uint32)
 
@@ -85,16 +87,41 @@ try:  # obs is stdlib-only and imports nothing from the engine (no cycle)
         "columns (phase=compact).",
         labels=("phase",),
     )
+    # a family of its own: a reader that sums the phases above (the
+    # benchmark's setup_parse_s) would count these seconds twice
+    _PARSE_SECONDS = _obs_counter(
+        "kolibrie_store_parse_seconds_total",
+        "Where the text's way to ids is spent: the tokenizer's pass over "
+        "the text (step=tokenize) and the terms' way into the dictionary "
+        "and the id columns (step=intern).",
+        labels=("step",),
+    )
+    _DEVICE_BYTES = _obs_gauge(
+        "kolibrie_store_device_bytes",
+        "Bytes of the base segments the store that last uploaded one holds "
+        "on the device (three uint32 columns a sorted order, padded).",
+    )
+    _BASE_ROWS = _obs_gauge(
+        "kolibrie_store_base_rows",
+        "Rows of that store's base (of=rows) beside the slots a device "
+        "segment pads them to (of=slots).",
+        labels=("of",),
+    )
     for _seg in ("base", "delta"):
         _H2D_SECONDS.labels(_seg)
     for _name in ("spo", "pos", "osp", "pso", "ops", "sop"):
         _ORDER_BUILD_SECONDS.labels(_name)
     for _phase in ("parse", "compact"):
         _LOAD_SECONDS.labels(_phase)
+    for _step in ("tokenize", "intern"):
+        _PARSE_SECONDS.labels(_step)
+    for _of in ("rows", "slots"):
+        _BASE_ROWS.labels(_of)
 # kolint: ignore[KL601] import-time obs registration must never block the store; the None sentinels disable instrumentation and every call site guards on them
 except Exception:  # pragma: no cover
     _H2D_BYTES = _DELTA_MERGES = _ORDER_REBUILDS = _DELTA_ROWS = None
     _H2D_SECONDS = _ORDER_BUILD_SECONDS = _LOAD_SECONDS = None
+    _PARSE_SECONDS = _DEVICE_BYTES = _BASE_ROWS = None
 
 
 def _add_seconds(family, label: str, t0: float) -> None:
@@ -107,6 +134,26 @@ def add_load_seconds(phase: str, t0: float) -> None:
     """``kolibrie_store_load_seconds_total{phase}``; the ``/store/load``
     handler counts ``parse`` around the text's way to ids."""
     _add_seconds(_LOAD_SECONDS, phase, t0)
+
+
+@contextlib.contextmanager
+def load_phase(phase: str):
+    """One phase of a load: its seconds (``compact`` in
+    ``kolibrie_store_load_seconds_total{phase}``; ``tokenize`` and
+    ``intern``, the steps of ``parse``, in
+    ``kolibrie_store_parse_seconds_total{step}``) and, inside a trace (a
+    request's), a span ``store.<phase>``.  Outside one it opens none: a
+    fixpoint's compaction a round would fill the ring."""
+    t0 = time.perf_counter()
+    try:
+        if current_trace_id() is None:
+            yield
+        else:
+            with span("store." + phase):
+                yield
+    finally:
+        family = _LOAD_SECONDS if phase == "compact" else _PARSE_SECONDS
+        _add_seconds(family, phase, t0)
 
 
 def h2d_bytes_total() -> float:
@@ -494,9 +541,8 @@ class ColumnarTripleStore:
     def compact(self) -> None:
         if not self._pending_add and not self._pending_del:
             return
-        t0 = time.perf_counter()
-        self._compact_pending()
-        add_load_seconds("compact", t0)
+        with load_phase("compact"):
+            self._compact_pending()
 
     def _compact_pending(self) -> None:
         parts_s = []
@@ -569,10 +615,22 @@ class ColumnarTripleStore:
                 pos,
                 [(self._s, a_s), (self._p, a_p), (self._o, a_o), (key01, ak)],
             )
-            ins_set = set(zip(a_s.tolist(), a_p.tolist(), a_o.tolist()))
         else:
             s, p, o = self._s, self._p, self._o
-            ins_set = set()
+        if not dels and len(a_s) - len(self._delta_del_set) > self.delta_threshold:
+            # A bulk append (one chunk of a load): whatever it re-adds of
+            # the tombstoned base rows, the delta ends past its threshold
+            # and folds into the base below, so the per-row bookkeeping
+            # (Python sets of tuples, 0.4 s a 200,000-row chunk) would be
+            # built to be thrown away.  The fresh rows are lexsorted and
+            # unique: they are the insert columns as they stand.
+            self._install_incremental(s, p, o, key01, (a_s, a_p, a_o), None)
+            self._triples_set_cache = None
+            self._merge_base()
+            if _DELTA_MERGES is not None:
+                _DELTA_MERGES.inc()
+            return
+        ins_set = set(zip(a_s.tolist(), a_p.tolist(), a_o.tolist()))
         drop_set = set()
         if dels and len(s):
             dl = np.asarray(sorted(dels), dtype=np.uint32)
@@ -600,12 +658,6 @@ class ColumnarTripleStore:
         if del_eff:
             da = np.asarray(sorted(del_eff), dtype=np.uint32)
             del_cols = (da[:, 0], da[:, 1], da[:, 2])
-        new_orders = {}
-        for name, so in self._orders.items():
-            if name == "spo":
-                new_orders[name] = SortedOrder.from_parts(so.perm, s, p, o, key01)
-            else:
-                new_orders[name] = _updated_order(so, ins_cols, del_cols)
         # delta bookkeeping — copy-then-replace so snapshots sharing the
         # old sets stay intact (COW invariant)
         add_set = set(self._delta_add_set)
@@ -620,17 +672,9 @@ class ColumnarTripleStore:
                 add_set.discard(t)  # delta add deleted again
             else:
                 del_set.add(t)  # tombstone over a base row
-        self._s, self._p, self._o = s, p, o
-        self._orders = new_orders
-        self._device_cols = None
-        self._device_orders = {}
-        self._delta_orders = {}
-        self._delta_del_pos = {}
-        self._device_delta = {}
+        self._install_incremental(s, p, o, key01, ins_cols, del_cols)
         self._delta_add_set = add_set
         self._delta_del_set = del_set
-        self._delta_epoch += 1
-        self._version = next(_VERSION_COUNTER)
         cached = self._triples_set_cache
         if cached is not None and cached[0] == old_version:
             # incremental membership-set maintenance: copy the memo and
@@ -645,6 +689,29 @@ class ColumnarTripleStore:
                 _DELTA_MERGES.inc()
         elif _DELTA_ROWS is not None:
             _DELTA_ROWS.set(len(add_set) + len(del_set))
+
+    def _install_incremental(self, s, p, o, key01, ins_cols, del_cols) -> None:
+        """The live state after an incremental compaction: the merged
+        canonical columns (with their packed key, which IS the spo order),
+        every other built order maintained by merge-insert, a new delta
+        epoch and version."""
+        new_orders = {
+            name: _updated_order(so, ins_cols, del_cols)
+            for name, so in self._orders.items()
+            if name != "spo"
+        }
+        new_orders["spo"] = SortedOrder.from_parts(
+            self._ORDER_PERMS["spo"], s, p, o, key01
+        )
+        self._s, self._p, self._o = s, p, o
+        self._orders = new_orders
+        self._device_cols = None
+        self._device_orders = {}
+        self._delta_orders = {}
+        self._delta_del_pos = {}
+        self._device_delta = {}
+        self._delta_epoch += 1
+        self._version = next(_VERSION_COUNTER)
 
     def _compact_full(self, a_s, a_p, a_o, dels) -> None:
         """Full rebuild: concat + lexsort + unique, then one vectorized
@@ -951,6 +1018,11 @@ class ColumnarTripleStore:
             if _H2D_BYTES is not None:
                 _H2D_BYTES.labels("base").inc(3 * cap * 4)
             _add_seconds(_H2D_SECONDS, "base", t0)
+            if _DEVICE_BYTES is not None:
+                # every order of one base has its rows and so its slots
+                _DEVICE_BYTES.set(len(self._device_segments) * 3 * cap * 4)
+                _BASE_ROWS.labels("rows").set(n)
+                _BASE_ROWS.labels("slots").set(cap)
         delta = self._device_delta.get(name)
         if delta is None:
             import jax

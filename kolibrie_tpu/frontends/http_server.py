@@ -216,11 +216,11 @@ def _maybe_attach_sharded(db) -> None:
 
 
 def _load_rdf_into(db, data: str, fmt: str) -> int:
-    data = data or ""
-    if not data.strip():
+    """RDF text into ``db``.  ``#`` comments are the tokenizers' business,
+    the native ones and the Python one alike (``rdf_parsers._TOKEN_RE``'s
+    ``comment`` group): the text is not walked for them here first."""
+    if not data or data.isspace():  # no copy of a 34 MB chunk to find out
         return 0
-    if fmt in ("ntriples", "turtle"):
-        data = strip_hash_comments(data)
     if fmt == "ntriples":
         return db.parse_ntriples(data)
     if fmt == "turtle":
@@ -754,7 +754,7 @@ def _build_rsp_engine(
     engine = builder.build()
     if static_rdf and static_rdf.strip():
         if static_format == "turtle":
-            engine.static_db.parse_turtle(strip_hash_comments(static_rdf))
+            engine.static_db.parse_turtle(static_rdf)
         else:
             tmp = SparqlDatabase()
             _load_rdf_into(tmp, static_rdf, static_format)
@@ -816,10 +816,9 @@ def _push_event(engine, stream: str, timestamp: int, ntriples: str) -> int:
     from kolibrie_tpu.query.rdf_parsers import parse_ntriples
     from kolibrie_tpu.rsp.s2r import WindowTriple
 
-    cleaned = strip_hash_comments(ntriples)
-    if not cleaned.strip():
+    triples = parse_ntriples(ntriples)  # its tokenizer skips # comments
+    if not triples:  # blank, or comments only
         return 0
-    triples = parse_ntriples(cleaned)
     for s, p, o in triples:
         engine.add_to_stream(
             stream,
@@ -1176,26 +1175,28 @@ class KolibrieHandler(BaseHTTPRequestHandler):
                     # attach before the first mutation: every add/delete
                     # from here on lands in the WAL as a "mut" record
                     state.durability.attach(sid, db)
-        try:
-            with batcher.dispatch_lock:
-                if req.get("mode"):
-                    batcher.db.execution_mode = req["mode"]
-                t0 = time.perf_counter()
-                n = _load_rdf_into(
-                    batcher.db, req.get("rdf") or "", req.get("format", "ntriples")
-                )
-                add_load_seconds("parse", t0)
-                # eager mirror upload while we already hold the lock: the
-                # first query after a load pays dispatch, not partitioning
-                _maybe_attach_sharded(batcher.db)
-        except Exception as e:
-            raise BadRequest(f"RDF parse error: {e}") from e
+        fmt = req.get("format", "ntriples")
+        with span("store.load", format=fmt) as sp:
+            try:
+                with batcher.dispatch_lock:
+                    if req.get("mode"):
+                        batcher.db.execution_mode = req["mode"]
+                    t0 = time.perf_counter()
+                    n = _load_rdf_into(batcher.db, req.get("rdf") or "", fmt)
+                    add_load_seconds("parse", t0)
+                    # eager mirror upload while we already hold the lock:
+                    # the first query after a load pays dispatch, not
+                    # partitioning
+                    _maybe_attach_sharded(batcher.db)
+            except Exception as e:
+                raise BadRequest(f"RDF parse error: {e}") from e
+            # the exact deduplicated count: folds the batch into the sorted
+            # columns (span store.compact) where nothing above has
+            triples = len(batcher.db.store)
+            if sp is not None:
+                sp.attrs.update(loaded=n, triples=triples)
         _maybe_snapshot(state)
-        body = {
-            "store_id": sid,
-            "loaded": n,
-            "triples": len(batcher.db.store),
-        }
+        body = {"store_id": sid, "loaded": n, "triples": triples}
         if state.durability is not None and state.durability.wal is not None:
             # read-your-writes token: a follower that has applied this
             # segment holds this write (segments seal whole — see

@@ -16,7 +16,7 @@ import numpy as np
 
 from kolibrie_tpu.core.dictionary import Dictionary, QUOTED_BIT
 from kolibrie_tpu.core.quoted import QuotedTripleStore
-from kolibrie_tpu.core.store import ColumnarTripleStore
+from kolibrie_tpu.core.store import ColumnarTripleStore, load_phase
 from kolibrie_tpu.core.triple import Triple
 from kolibrie_tpu.query import rdf_parsers
 from kolibrie_tpu.query.rdf_parsers import ParsedTerm, format_term_nt
@@ -125,22 +125,24 @@ class SparqlDatabase:
         if not parsed:
             return 0
         n = len(parsed)
-        s = np.empty(n, dtype=np.uint32)
-        p = np.empty(n, dtype=np.uint32)
-        o = np.empty(n, dtype=np.uint32)
-        enc = self.encode_parsed_term
-        for i, (ts, tp, to) in enumerate(parsed):
-            s[i] = enc(ts)
-            p[i] = enc(tp)
-            o[i] = enc(to)
-        self.store.add_batch(s, p, o)
+        with load_phase("intern"):
+            s = np.empty(n, dtype=np.uint32)
+            p = np.empty(n, dtype=np.uint32)
+            o = np.empty(n, dtype=np.uint32)
+            enc = self.encode_parsed_term
+            for i, (ts, tp, to) in enumerate(parsed):
+                s[i] = enc(ts)
+                p[i] = enc(tp)
+                o[i] = enc(to)
+            self.store.add_batch(s, p, o)
         return n
 
     def parse_turtle(self, data: str) -> int:
         native = self._parse_turtle_native(data)
         if native is not None:
             return native
-        triples, prefixes = rdf_parsers.parse_turtle(data, self.prefixes)
+        with load_phase("tokenize"):
+            triples, prefixes = rdf_parsers.parse_turtle(data, self.prefixes)
         self.prefixes.update(prefixes)
         return self._ingest(triples)
 
@@ -153,7 +155,8 @@ class SparqlDatabase:
             from kolibrie_tpu.native.ttl_native import bulk_parse_turtle
         except ImportError:
             return None
-        result = bulk_parse_turtle(data, self.prefixes)
+        with load_phase("tokenize"):
+            result = bulk_parse_turtle(data, self.prefixes)
         if result is None:
             return None
         ids, terms, prefixes_out = result
@@ -161,7 +164,8 @@ class SparqlDatabase:
         return self._ingest_native_session(ids, terms)
 
     def parse_n3(self, data: str) -> int:
-        triples, prefixes = rdf_parsers.parse_n3(data, self.prefixes)
+        with load_phase("tokenize"):
+            triples, prefixes = rdf_parsers.parse_n3(data, self.prefixes)
         self.prefixes.update(prefixes)
         return self._ingest(triples)
 
@@ -169,17 +173,22 @@ class SparqlDatabase:
         native = self._parse_ntriples_native(data)
         if native is not None:
             return native
-        return self._ingest(rdf_parsers.parse_ntriples(data))
+        with load_phase("tokenize"):
+            parsed = rdf_parsers.parse_ntriples(data)
+        return self._ingest(parsed)
 
     def _ingest_native_session(self, ids: np.ndarray, terms) -> int:
         """Shared tail of every native bulk parse: intern the session's
         UNIQUE terms once (``encode_batch``), then remap the (n, 3)
         1-based id matrix with one vectorized gather into the store.
         ``remap[0]`` is intentionally never read (ids are 1-based)."""
-        remap = np.empty(len(terms) + 1, dtype=np.uint32)
-        remap[1:] = self.dictionary.encode_batch(terms)
-        cols = remap[ids]
-        self.store.add_batch(cols[:, 0], cols[:, 1], cols[:, 2])
+        if not len(ids):
+            return 0  # comments and blank lines only: no batch, no journal record
+        with load_phase("intern"):
+            remap = np.empty(len(terms) + 1, dtype=np.uint32)
+            remap[1:] = self.dictionary.encode_batch(terms)
+            cols = remap[ids]
+            self.store.add_batch(cols[:, 0], cols[:, 1], cols[:, 2])
         return int(ids.shape[0])
 
     def _parse_ntriples_native(self, data: str) -> Optional[int]:
@@ -190,7 +199,8 @@ class SparqlDatabase:
             from kolibrie_tpu.native.nt_native import bulk_parse_ntriples
         except ImportError:
             return None
-        result = bulk_parse_ntriples(data)
+        with load_phase("tokenize"):
+            result = bulk_parse_ntriples(data)
         if result is None:
             return None
         return self._ingest_native_session(*result)
@@ -385,7 +395,9 @@ class SparqlDatabase:
         native = self._parse_rdf_native(data)
         if native is not None:
             return native
-        return self._ingest(rdf_parsers.parse_rdf_xml(data))
+        with load_phase("tokenize"):
+            parsed = rdf_parsers.parse_rdf_xml(data)
+        return self._ingest(parsed)
 
     def _parse_rdf_native(self, data: str) -> Optional[int]:
         """Bulk fast path: streaming C++ RDF/XML parser + unique-term
@@ -395,7 +407,8 @@ class SparqlDatabase:
             from kolibrie_tpu.native.nt_native import bulk_parse_rdf_xml
         except ImportError:
             return None
-        result = bulk_parse_rdf_xml(data)
+        with load_phase("tokenize"):
+            result = bulk_parse_rdf_xml(data)
         if result is None:
             return None
         return self._ingest_native_session(*result)

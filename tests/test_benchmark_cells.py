@@ -2,8 +2,9 @@
 JAX: the texts one client sends are what they were before there were clients,
 the clients of ``mesh_q7`` never share a constant (in ``lubm5.mesh4`` and in
 ``lubm5.batch8``, which sends the same traffic to one chip), the cell
-``lubm5.mesh4`` is in with the entries PR 27 wrote for it and ``lubm5.batch8``
-as ISSUE 32 states it, every file a cell or a
+``lubm5.mesh4`` is in with the entries PR 27 wrote for it, ``lubm5.batch8``
+as ISSUE 32 states it and ``lubm50.triangles`` (``lubm-50``, nothing cut) as
+ISSUE 34 does, every file a cell or a
 per-layer metric names is there, a program that lacks what a cell
 requires of it (``benchmark/requires``) is refused before anything starts,
 and ``run.py`` itself, started off the chip, prints no result and exits 3
@@ -122,7 +123,7 @@ def test_benchmark_json_has_the_one_chip_cell_of_eight_clients():
     cell = CELLS["lubm5.batch8"]
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "lubm-5-clients8", "mesh_q7", 1)
-    assert BENCH["workloads"][-1] == cell  # appended, nothing before it moved
+    assert BENCH["workloads"][4] == cell  # appended, nothing before it moved
     config = files.read_json("configs", "lubm-5-clients8.json")
     one_client = files.read_json("configs", "lubm-5.json")
     assert (config["chips"], config["universities"], config["store_mode"]) == (
@@ -150,6 +151,84 @@ def test_benchmark_json_has_the_one_chip_cell_of_eight_clients():
     for m in BENCH["per_layer"]:
         if m["name"] not in BATCH8_METRICS:
             assert "lubm5.batch8" not in m.get("workloads", [])
+
+
+LUBM50_METRICS = {
+    # name: (reader kind, layer, the end-to-end metric it moves)
+    "setup_tokenize_s": ("counter_at_open", "store and ingest", "setup_s"),
+    "setup_intern_s": ("counter_at_open", "store and ingest", "setup_s"),
+    "setup_compact_s": ("counter_at_open", "store and ingest", "setup_s"),
+    "setup_h2d_mb": ("counter_at_open", "store and ingest", "setup_s"),
+    "store_device_mb": ("counter_at_open", "store and ingest", "setup_s"),
+    "wcoj_probes_in_window": ("counter_delta", "device dispatch", "cycle_ms"),
+}
+
+
+def test_benchmark_json_has_lubm_50_uncut_and_its_cell():
+    cell = CELLS["lubm50.triangles"]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "lubm-50", "triangles", 1)
+    assert BENCH["workloads"][-1] == cell  # appended, nothing before it moved
+    entry = BENCH["configs"][-1]
+    assert (entry["name"], entry["file"], entry["reduced"]) == (
+        "lubm-50", "benchmark/configs/lubm-50.json", [])
+    assert "LUBM(50, seed), the largest the paper reports" in entry["source"]
+    assert len(entry["source"]) <= 200 and len(cell["why"]) <= 200
+    config = files.read_json("configs", "lubm-50.json")
+    small = files.read_json("configs", "lubm-5.json")
+    # the paper's own scale: nothing is cut, and everything else is lubm-5's
+    assert (config["universities"], config["reduced"], config["chips"]) == (50, {}, 1)
+    assert config["source"] == entry["source"]
+    for key in ("generator", "store_mode", "guarantees", "control"):
+        assert config[key] == small[key], key
+    assert set(config) == set(small)
+    assert len(config["assumed"]) == len(small["assumed"])
+    assert config["assumed"][1] == small["assumed"][1]
+    assert config["assumed"][3] == small["assumed"][3]
+    assert "LUBM(50,0): 6,890,933" in config["assumed"][0]
+    assert files.read_json("workloads", "lubm50.triangles.json") == {"env": {}}
+    # the traffic is lubm5.triangles', as it stands
+    assert CELLS["lubm5.triangles"]["traffic"] == cell["traffic"]
+    # its per-layer metrics: data files of readers that exist, listing this
+    # cell alone, at the end of the list
+    added = BENCH["per_layer"][-len(LUBM50_METRICS):]
+    assert [m["name"] for m in added] == list(LUBM50_METRICS)
+    for m in added:
+        kind, layer, moves = LUBM50_METRICS[m["name"]]
+        assert (m["layer"], m["moves"], m["workloads"], m["source"]) == (
+            layer, moves, ["lubm50.triangles"], "program_counter")
+        reader = files.read_json("layer_metrics", m["name"] + ".json")["reader"]
+        assert reader["kind"] == kind
+        assert os.path.exists(files.path("readers", kind + ".py"))
+    # it is added to no list that was there; it reports what has no list
+    for m in BENCH["per_layer"] + BENCH["end_to_end"]:
+        if m["name"] not in LUBM50_METRICS:
+            assert "lubm50.triangles" not in m.get("workloads", [])
+    reported = {m["name"] for m in BENCH["end_to_end"] if "workloads" not in m}
+    assert reported == {"cycle_ms", "setup_s"}
+
+
+@pytest.mark.parametrize("name", sorted(LUBM50_METRICS))
+def test_a_new_metric_reads_its_family_and_nothing_of_a_program_without_it(name):
+    """The readers run on the parent's checkout too: where the program has no
+    such counter or gauge the metric is left out and nothing raises."""
+    from kolibrie_tpu.core import store  # noqa: F401  (registers its families)
+    from kolibrie_tpu.obs import metrics
+
+    args = dict(files.read_json("layer_metrics", name + ".json")["reader"])
+    reader = files.load_module("readers", args.pop("kind"))
+    sample = args.get("prefix") or args["prefixes"][0]
+    family = sample[len("metrics."):].partition("{")[0]
+    with open(os.path.join(REPO, "docs", "OBSERVABILITY.md"), encoding="utf-8") as f:
+        assert f"`{family}`" in f.read()
+    if family.startswith("kolibrie_store_"):
+        assert metrics.REGISTRY.get(family) is not None
+    there = {"counters0": {sample: 5e6, "metrics.kolibrie_other_total": 1.0},
+             "counters1": {sample: 7e6, "metrics.kolibrie_other_total": 3.0}}
+    want = 2e6 if name == "wcoj_probes_in_window" else 5e6 * args.get("scale", 1.0)
+    assert reader.read(there, **args) == pytest.approx(want)
+    lacking = {key: {"metrics.kolibrie_other_total": 1.0} for key in there}
+    assert reader.read(lacking, **args) is None
 
 
 @pytest.mark.parametrize("workload", sorted(CELLS))
@@ -180,7 +259,7 @@ def test_every_per_layer_metric_has_its_file_and_its_reader():
 
 def test_at_most_half_the_cells_take_four_chips():
     four = [w["name"] for w in BENCH["workloads"] if w["chips"] == 4]
-    assert four == ["lubm5.mesh4"] and len(CELLS) == 5
+    assert four == ["lubm5.mesh4"] and len(CELLS) == 6
     assert len(four) <= max(1, len(CELLS) // 2)
     assert json.dumps(BENCH).count('"chips": 4') == 1
 
