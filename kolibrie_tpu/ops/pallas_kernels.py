@@ -18,7 +18,7 @@ kernels.  This module provides the TPU-native kernel path:
   tie-break run as int32 boolean algebra in VMEM instead of a dozen
   separate XLA ops round-tripping every per-slot intermediate through
   HBM.  The lex ``searchsorted`` range computation itself stays an XLA
-  pre-pass (:func:`kolibrie_tpu.ops.wcoj.lex_range` — Mosaic has no
+  pre-pass (:func:`kolibrie_tpu.ops.wcoj.range_search` — Mosaic has no
   vector gather, so a binary search over HBM-resident columns cannot
   live in the kernel); row oracle: ``ops/wcoj.py::host_lex_probe``.
 - :func:`filter_mask` — fused pattern/constant compare over dictionary-ID

@@ -24,6 +24,14 @@ This module holds the shared primitives:
 - :func:`lex_range` — BOTH insertion points of each probe tuple in one
   fixed-trip loop (half the gathers and a quarter of the loop overhead of
   four separate ``lex_searchsorted`` calls; bit-identical results);
+- :func:`lex_range_sorted` — the same ``(lo, hi)``, bit for bit, from one
+  variadic ``lax.sort`` of the base rows and two tagged copies of every probe
+  tuple instead of a gather loop: the loop pays per probe and trip (a trip
+  gathers 2 x ncols x P elements: 40-100 ns a probe and trip on a v5e,
+  whatever the table's size), the sort per element (4-7 ns over N + 2P);
+- :func:`range_search_form` / :func:`range_search` — the rule that picks
+  the form of one call from its static shapes ``(N, P, ncols)``, and the
+  call a WCOJ level makes (``optimizer/device_engine.py`` ``eval_level``);
 - :func:`host_lex_range` — the numpy twin returning ``[lo, hi)`` ranges,
   exact for 3-key probes via a dense-rank packing (u64 cannot hold three
   u32 keys directly);
@@ -47,6 +55,9 @@ import numpy as np
 __all__ = [
     "lex_searchsorted",
     "lex_range",
+    "lex_range_sorted",
+    "range_search_form",
+    "range_search",
     "host_lex_range",
     "host_lex_probe",
 ]
@@ -157,6 +168,94 @@ def lex_range(cols, keys):
         0, n.bit_length() + 1, body, (z, f, z.copy(), f.copy())
     )
     return lo, hi
+
+
+def lex_range_sorted(cols, keys):
+    """:func:`lex_range` by sorting instead of gathering: same arguments,
+    the same ``(lo, hi)`` int32 arrays, bit for bit.
+
+    Every probe tuple goes into ONE variadic ``lax.sort`` twice, beside the
+    N base rows, under a last key that is tag and way back at once: the
+    left copy of probe ``i`` carries ``i`` (below every base row's ``P``),
+    the right copy ``P + 1 + i`` (above it).  Sorted by (key columns...,
+    that code), a left copy lands before the base rows equal to it and a
+    right copy after them, so a running count of base rows reads ``lo`` at
+    the left copy and ``hi`` at the right one.  A second, two-operand sort
+    by the code alone brings the counts back to probe order: the left
+    copies' first, the base rows' ``N`` in the middle, the right copies'
+    last.  Ties (equal probes, duplicate base rows) need no stable sort:
+    tied elements of one kind read the same count.  The comparisons are
+    the loop's own (unsigned, lexicographic), so sentinel probes, sentinel
+    padding and an all-padding base read as they do there.
+
+    N + 2P elements through two sorts, whatever ``N.bit_length() + 1``
+    trips would have gathered; :func:`range_search_form` says when that is
+    the cheaper form.  Traced inline, like :func:`lex_range`."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    n = int(cols[0].shape[0])
+    p = int(keys[0].shape[0])
+    if n == 0:
+        z = jnp.zeros(p, dtype=jnp.int32)
+        return z, z
+    code = jnp.concatenate(
+        [
+            jnp.arange(p, dtype=jnp.uint32),
+            jnp.full(n, p, dtype=jnp.uint32),
+            jnp.arange(p + 1, 2 * p + 1, dtype=jnp.uint32),
+        ]
+    )
+    merged = [jnp.concatenate([k, c, k]) for c, k in zip(cols, keys)]
+    scode = lax.sort(
+        (*merged, code), num_keys=len(merged) + 1, is_stable=False
+    )[-1]
+    below = jnp.cumsum((scode == p).astype(jnp.int32), dtype=jnp.int32)
+    _code, back = lax.sort((scode, below), num_keys=1, is_stable=False)
+    return back[:p], back[n + p :]
+
+
+# The rule's constants, from PR 35's gate (PERF.md section 6: one v5e,
+# 2026-09-29, jit call until ready, median of 15, ms; loop / sorted):
+#   (2^23, 1,048,576, 3)  2,569.5 / 69.2    (2^20, 65,536, 3)  77.6 / 6.1
+#   (2^23,   262,144, 2)    429.6 / 45.5    (2^20, 16,384, 2)  13.8 / 4.7
+#   (2^23,    65,536, 3)     88.1 / 55.6    (2^20,  8,192, 3)  10.1 / 5.9
+#   (2^23,    16,384, 2)     15.8 / 44.1    (2^20,  1,024, 3)   2.1 / 5.8
+#   (2^23,     4,096, 3)      8.6 / 59.6    (1,024, 65,536, 3) 28.6 / 1.4
+# A sort costs by element (4-7 ns, whatever P), the loop by probe and trip
+# (40-100 ns); they cross near N = 190 P.  The sort is taken from N = 64 P
+# down, where it won 2.9-37 times: at N = 128 P it would win 1.6 times, and
+# a sorted search costs the TPU compiler two more sort instructions (27-67 s
+# a search alone, where a loop compiles in under a second).  8,192 is the
+# fewest probes the gate saw a sort win at.
+_SORT_MIN_PROBES = 8192
+_SORT_ROWS_PER_PROBE = 64
+
+
+def range_search_form(n: int, p: int, ncols: int) -> str:
+    """Which form one range search takes: ``"sorted"`` or ``"loop"``, a pure
+    function of the call's static shapes (N base rows, P probe tuples,
+    ``ncols`` key columns), so one plan may take both and a template still
+    has one executable a capacity set.
+
+    The loop pays ``N.bit_length() + 1`` trips of 2 x ncols x P gathers;
+    the sort pays for N + 2P elements.  So the sort wins once the probes
+    are many and not too few beside the rows; the gate's two- and
+    three-column shapes cross at the same N / P, so ``ncols`` moves
+    nothing yet."""
+    if p >= _SORT_MIN_PROBES and n <= _SORT_ROWS_PER_PROBE * p:
+        return "sorted"
+    return "loop"
+
+
+def range_search(cols, keys):
+    """``(lo, hi)`` of each probe tuple in the sorted columns, in the form
+    :func:`range_search_form` picks for this call's shapes."""
+    n = int(cols[0].shape[0])
+    p = int(keys[0].shape[0])
+    if range_search_form(n, p, len(cols)) == "sorted":
+        return lex_range_sorted(cols, keys)
+    return lex_range(cols, keys)
 
 
 def _pack2(a: np.ndarray, b: np.ndarray) -> np.ndarray:

@@ -928,7 +928,7 @@ def _plan_body(
             # raw (possibly all-tombstoned) copies — the base slot is the
             # unique representative, made live by the delta via the
             # existence probe.
-            from kolibrie_tpu.ops.wcoj import lex_range
+            from kolibrie_tpu.ops.wcoj import range_search
 
             SENT = jnp.uint32(0xFFFFFFFF)
             wcols: Dict = {}
@@ -952,14 +952,16 @@ def _plan_body(
                             kt = tuple(keys)
                             bsort = tuple(bcols[p] for p in a.key_pos)
                             dsort = tuple(dcols[p] for p in a.key_pos)
-                            # fused lo+hi search: bit-identical to the former
-                            # left/right lex_searchsorted pairs, half the
-                            # gathers (shared by both the XLA and Pallas paths)
-                            bl, bh = lex_range(bsort, kt)
+                            # lo and hi of every probe tuple in one search,
+                            # by a gather loop or by one sort as the shapes
+                            # say (ops/wcoj.py range_search_form; the same
+                            # int32 arrays either way, shared by the XLA and
+                            # Pallas paths)
+                            bl, bh = range_search(bsort, kt)
                             dl, dh = delta_or_zeros(
                                 a.order_idx,
                                 (bl, bh),
-                                lambda dsort=dsort, kt=kt: lex_range(dsort, kt),
+                                lambda dsort=dsort, kt=kt: range_search(dsort, kt),
                             )
                         else:
                             # unbound accessor: the whole live prefix (padding
@@ -1069,12 +1071,12 @@ def _plan_body(
                         dsf = tuple(dcols[p] for p in a.key_pos) + (
                             dcols[a.val_pos],
                         )
-                        fl, fh = lex_range(bsf, fkeys)
+                        fl, fh = range_search(bsf, fkeys)
 
                         def delta_live(
                             dsf=dsf, fkeys=fkeys, del_pos=del_pos, fl=fl, fh=fh
                         ):
-                            dl2, dh2 = lex_range(dsf, fkeys)
+                            dl2, dh2 = range_search(dsf, fkeys)
                             # tombstoned copies inside [fl, fh): del_pos holds
                             # sorted base-row positions (sentinel-padded)
                             tl = jnp.searchsorted(del_pos, fl.astype(jnp.uint32))
@@ -1272,6 +1274,20 @@ def _classify_source(jit_before: int, cc_before: Dict[str, int]) -> str:
 # ---------------------------------------------------------------------------
 # Lowering: physical plan -> IR (+ host-side prep)
 # ---------------------------------------------------------------------------
+
+
+def _wcoj_specs(node):
+    """The ``WcojSpec`` nodes of a plan spec, in plan order."""
+    if isinstance(node, WcojSpec):
+        yield node
+    elif isinstance(node, (JoinSpec, AntiJoinSpec, LeftOuterSpec)):
+        yield from _wcoj_specs(node.left)
+        yield from _wcoj_specs(node.right)
+    elif isinstance(node, (FilterSpec, QuotedExpandSpec)):
+        yield from _wcoj_specs(node.child)
+    elif isinstance(node, UnionSpec):
+        for ch in node.children:
+            yield from _wcoj_specs(ch)
 
 
 class LoweredPlan:
@@ -2353,6 +2369,12 @@ class LoweredPlan:
         order_arrays = tuple(
             store.device_segment(name) for name in self.order_names
         )
+        # (base, delta) padded rows an order: with the capacities, all that
+        # the forms of this dispatch's WCOJ range searches depend on
+        self._seg_rows = tuple(
+            (int(bcols[0].shape[0]), int(dcols[0].shape[0]))
+            for bcols, dcols, _del_pos in order_arrays
+        )
         # per-ID masks grow with the dictionary; pad each to a power-of-two
         # capacity (False = "no match", the clamp-gather's existing
         # out-of-range verdict) so small mutation batches that mint new
@@ -2826,6 +2848,33 @@ class LoweredPlan:
             members * base_only, members * (len(self._tier_sites) - base_only)
         )
 
+    def _note_range_searches(self, members: int = 1) -> None:
+        """Count the WCOJ range searches of the dispatch just assembled by
+        the form the plan body traced them in: an accessor searches its
+        order's base once in ``probe`` (the level before's capacity wide,
+        where it has keys) and once in ``live`` (this level's), and its
+        delta as often where the tier holds something."""
+        from kolibrie_tpu.ops.wcoj import range_search_form
+        from kolibrie_tpu.query.template import note_range_searches
+
+        forms = {"sorted": 0, "loop": 0}
+        for node in _wcoj_specs(self.root):
+            pcap = 1
+            for lv in node.levels:
+                cap = self._join_caps[lv.join_idx]
+                for a in lv.accessors:
+                    nkeys = len(a.key_srcs)
+                    base, delta = self._seg_rows[a.order_idx]
+                    tiers = (base,) + (
+                        (delta,) if self._tiers_np[a.order_idx] else ()
+                    )
+                    for n in tiers:
+                        if nkeys:
+                            forms[range_search_form(n, pcap, nkeys)] += 1
+                        forms[range_search_form(n, cap, nkeys + 1)] += 1
+                pcap = cap
+        note_range_searches(members * forms["sorted"], members * forms["loop"])
+
     def _store_caps(self) -> None:
         """Publish join capacities to the per-db template cache.  Merge is
         a MONOTONIC max: the cache is shared by every constant variant of
@@ -2870,6 +2919,7 @@ class LoweredPlan:
                 )
             note_cap_occupancy("device", sum(self._join_caps), sum(counts_h))
             self._note_scan_tiers()
+            self._note_range_searches()
             overflow = [
                 i for i, c in enumerate(counts_h) if c > self._join_caps[i]
             ]
@@ -2917,27 +2967,16 @@ class LoweredPlan:
         """Per-level WCOJ instrumentation from the converged host-read
         counts: intermediate rows, cap occupancy, probe volume."""
 
-        def walk(node):
-            if isinstance(node, WcojSpec):
-                for lv in node.levels:
-                    if lv.join_idx >= len(counts_h):
-                        continue
-                    rows = counts_h[lv.join_idx]
-                    cap = self._join_caps[lv.join_idx]
-                    _WCOJ_LEVEL_ROWS.observe(rows)
-                    if cap > 0:
-                        _WCOJ_CAP_OCCUPANCY.observe(rows / cap)
-                    _WCOJ_PROBES.inc(cap * len(lv.accessors))
-            elif isinstance(node, (JoinSpec, AntiJoinSpec, LeftOuterSpec)):
-                walk(node.left)
-                walk(node.right)
-            elif isinstance(node, (FilterSpec, QuotedExpandSpec)):
-                walk(node.child)
-            elif isinstance(node, UnionSpec):
-                for ch in node.children:
-                    walk(ch)
-
-        walk(self.root)
+        for node in _wcoj_specs(self.root):
+            for lv in node.levels:
+                if lv.join_idx >= len(counts_h):
+                    continue
+                rows = counts_h[lv.join_idx]
+                cap = self._join_caps[lv.join_idx]
+                _WCOJ_LEVEL_ROWS.observe(rows)
+                if cap > 0:
+                    _WCOJ_CAP_OCCUPANCY.observe(rows / cap)
+                _WCOJ_PROBES.inc(cap * len(lv.accessors))
 
     def _advisor_sites(self) -> List[tuple]:
         """Observable operator sites for the stats advisor: a list of
@@ -3575,6 +3614,7 @@ def _converge_plan_batch(members: List[LoweredPlan], fp: str, max_attempts: int)
             "device", n * sum(caps), sum(int(np.sum(c)) for c in counts_b)
         )
         lp0._note_scan_tiers(n)
+        lp0._note_range_searches(n)
         over = [j for j, c in enumerate(maxc) if c > caps[j]]
         if not over:
             break

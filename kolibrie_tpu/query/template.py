@@ -52,6 +52,7 @@ __all__ = [
     "cap_advisor_enabled",
     "note_cap_occupancy",
     "note_scan_tiers",
+    "note_range_searches",
     "occupancy_pct",
 ]
 
@@ -283,6 +284,27 @@ def note_scan_tiers(base_only: int, two_tier: int) -> None:
     """One dispatch ran ``base_only`` + ``two_tier`` scans and accessors."""
     _SCAN_TIER.labels("base_only").inc(base_only)
     _SCAN_TIER.labels("two_tier").inc(two_tier)
+
+
+# how a dispatch's WCOJ range searches were made: static per template and
+# capacity set (ops/wcoj.py range_search_form reads shapes alone), counted on
+# the host per dispatch, so a run says how many of its searches took which
+# form at no device traffic
+_RANGE_SEARCH = metrics.counter(
+    "kolibrie_wcoj_range_search_total",
+    "range searches of WCOJ levels dispatched, by the form their shapes "
+    "select: one sort of base rows and probe tuples (sorted) or a "
+    "binary-search loop of column gathers (loop)",
+    labels=("form",),
+)
+_RANGE_SEARCH.labels("sorted")
+_RANGE_SEARCH.labels("loop")
+
+
+def note_range_searches(sorted_: int, loop: int) -> None:
+    """One dispatch made ``sorted_`` + ``loop`` WCOJ range searches."""
+    _RANGE_SEARCH.labels("sorted").inc(sorted_)
+    _RANGE_SEARCH.labels("loop").inc(loop)
 
 
 def cap_advisor_enabled() -> bool:

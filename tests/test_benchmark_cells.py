@@ -4,7 +4,8 @@ the clients of ``mesh_q7`` never share a constant (in ``lubm5.mesh4`` and in
 ``lubm5.batch8``, which sends the same traffic to one chip), the cell
 ``lubm5.mesh4`` is in with the entries PR 27 wrote for it, ``lubm5.batch8``
 as ISSUE 32 states it and ``lubm50.triangles`` (``lubm-50``, nothing cut) as
-ISSUE 34 does, every file a cell or a
+ISSUE 34 does, ISSUE 35's three range-search metrics are data files for the
+two triangles cells, every file a cell or a
 per-layer metric names is there, a program that lacks what a cell
 requires of it (``benchmark/requires``) is refused before anything starts,
 and ``run.py`` itself, started off the chip, prints no result and exits 3
@@ -12,7 +13,8 @@ and ``run.py`` itself, started off the chip, prints no result and exits 3
 
 Imports ``benchmark.harness`` (``traffic``, ``data``; numpy; the generators
 are found by name through ``data.load_module``) and, of the program,
-``kolibrie_tpu.obs.metrics``.
+``kolibrie_tpu.obs.metrics`` and the modules that register the families it
+reads (``core.store``, ``query.template``: neither imports JAX).
 """
 
 import hashlib
@@ -190,8 +192,8 @@ def test_benchmark_json_has_lubm_50_uncut_and_its_cell():
     # the traffic is lubm5.triangles', as it stands
     assert CELLS["lubm5.triangles"]["traffic"] == cell["traffic"]
     # its per-layer metrics: data files of readers that exist, listing this
-    # cell alone, at the end of the list
-    added = BENCH["per_layer"][-len(LUBM50_METRICS):]
+    # cell alone, in the order PR 34 appended them
+    added = [m for m in BENCH["per_layer"] if m["name"] in LUBM50_METRICS]
     assert [m["name"] for m in added] == list(LUBM50_METRICS)
     for m in added:
         kind, layer, moves = LUBM50_METRICS[m["name"]]
@@ -202,7 +204,7 @@ def test_benchmark_json_has_lubm_50_uncut_and_its_cell():
         assert os.path.exists(files.path("readers", kind + ".py"))
     # it is added to no list that was there; it reports what has no list
     for m in BENCH["per_layer"] + BENCH["end_to_end"]:
-        if m["name"] not in LUBM50_METRICS:
+        if m["name"] not in LUBM50_METRICS and m["name"] not in RANGE_SEARCH_METRICS:
             assert "lubm50.triangles" not in m.get("workloads", [])
     reported = {m["name"] for m in BENCH["end_to_end"] if "workloads" not in m}
     assert reported == {"cycle_ms", "setup_s"}
@@ -228,6 +230,73 @@ def test_a_new_metric_reads_its_family_and_nothing_of_a_program_without_it(name)
     want = 2e6 if name == "wcoj_probes_in_window" else 5e6 * args.get("scale", 1.0)
     assert reader.read(there, **args) == pytest.approx(want)
     lacking = {key: {"metrics.kolibrie_other_total": 1.0} for key in there}
+    assert reader.read(lacking, **args) is None
+
+
+RANGE_SEARCH_METRICS = {
+    # name: (reader kind, layer, source, the reader's arguments)
+    "sort_pct": ("trace_op_share", "kernels and XLA ops", "device_trace",
+                 {"match": "^sort", "level": "top"}),
+    "wcoj_sorted_searches_in_window": (
+        "counter_delta", "device dispatch", "program_counter",
+        {"prefix": 'metrics.kolibrie_wcoj_range_search_total{form="sorted"}',
+         "beside": "metrics.kolibrie_wcoj_range_search_total"}),
+    "wcoj_loop_searches_in_window": (
+        "counter_delta", "device dispatch", "program_counter",
+        {"prefix": 'metrics.kolibrie_wcoj_range_search_total{form="loop"}',
+         "beside": "metrics.kolibrie_wcoj_range_search_total"}),
+}
+TRIANGLES_CELLS = ["lubm5.triangles", "lubm50.triangles"]
+
+
+def test_the_range_search_metrics_are_the_last_entries_and_data_alone():
+    """ISSUE 35: three per-layer entries, appended, for the two triangles
+    cells; each a data file of a reader that was there."""
+    added = BENCH["per_layer"][-len(RANGE_SEARCH_METRICS):]
+    assert [m["name"] for m in added] == list(RANGE_SEARCH_METRICS)
+    for m in added:
+        kind, layer, source, args = RANGE_SEARCH_METRICS[m["name"]]
+        assert (m["layer"], m["moves"], m["workloads"], m["source"], m["unit"]) == (
+            layer, "cycle_ms", TRIANGLES_CELLS, source,
+            "%" if m["name"] == "sort_pct" else "count")
+        reader = files.read_json("layer_metrics", m["name"] + ".json")["reader"]
+        assert reader == {"kind": kind, **args}
+        assert os.path.exists(files.path("readers", kind + ".py"))
+    # no cell came with them, and no other list took a triangles cell in
+    assert [w["name"] for w in BENCH["workloads"]][-1] == "lubm50.triangles"
+    added_files = {name + ".json" for name in RANGE_SEARCH_METRICS}
+    assert added_files <= set(os.listdir(files.path("layer_metrics")))
+
+
+@pytest.mark.parametrize("name", sorted(RANGE_SEARCH_METRICS))
+def test_a_range_search_metric_reads_its_source_and_nothing_of_a_program_without_it(name):
+    """The readers run on the parent's checkout too: a program without the
+    counter reports neither count and nothing raises; a label that has not
+    grown yet reads 0 beside the one that has; a trace without a sort reads
+    a share of 0, and no trace reads nothing."""
+    from kolibrie_tpu.obs import metrics
+    from kolibrie_tpu.query import template  # noqa: F401  (registers the family)
+
+    kind, _layer, _source, args = RANGE_SEARCH_METRICS[name]
+    reader = files.load_module("readers", kind)
+    if kind == "trace_op_share":
+        trace = {"busy_s": 4.0, "top": {"sort.12": 1.0, "while.3": 2.0, "resort.1": 0.5}}
+        assert reader.read({"trace": trace}, **args) == pytest.approx(25.0)
+        trace["top"] = {"while.3": 4.0}
+        assert reader.read({"trace": trace}, **args) == 0.0
+        assert reader.read({}, **args) is None
+        return
+    family = args["beside"][len("metrics."):]
+    assert metrics.REGISTRY.get(family) is not None
+    with open(os.path.join(REPO, "docs", "OBSERVABILITY.md"), encoding="utf-8") as f:
+        assert f"`{family}`" in f.read()
+    other = args["beside"] + ('{form="loop"}' if "sorted" in name else '{form="sorted"}')
+    there = {"counters0": {args["prefix"]: 18.0, other: 4.0},
+             "counters1": {args["prefix"]: 54.0, other: 4.0}}
+    assert reader.read(there, **args) == pytest.approx(36.0)
+    never_grew = {key: {other: 4.0} for key in there}
+    assert reader.read(never_grew, **args) == 0.0
+    lacking = {key: {"metrics.kolibrie_wcoj_probes_total": 1.0} for key in there}
     assert reader.read(lacking, **args) is None
 
 

@@ -193,7 +193,11 @@ def test_whole_plan_lubm_q9(one_chip, lubm_db):
     low.calibrate_host()
     spec, args = low.build()
     with jax.enable_x64(True):
-        _compile(de._run_plan, one_chip, *args, lead=(spec, True))
+        compiled = _compile(de._run_plan, one_chip, *args, lead=(spec, True))
+    # the third level is 8,192 wide over 16,384-row orders, so its ``live``
+    # range searches take the sorted form (``ops/wcoj.py`` ``range_search_form``):
+    # the chip's compiler has seen both forms in one plan
+    assert low._join_caps[-1] >= 8192 and " sort(" in compiled.as_text()
 
 
 def test_whole_plan_batch_slot_class_8(one_chip, lubm_db):
@@ -240,7 +244,11 @@ def test_mesh_program_lubm_q7_four_chips(topo, mesh8):
     from kolibrie_tpu.parallel import sharded_serving as ss
     from kolibrie_tpu.query.executor import _plan_cache_entry
     from kolibrie_tpu.query.sparql_database import SparqlDatabase
+    from kolibrie_tpu.query.template import cap_advisor
 
+    # the advisor's high-water marks are the process's: a Q7 that another
+    # test file ran on this worker must not widen the counted capacities
+    cap_advisor.reset()
     config = bench_files.read_json("configs", "lubm-5-mesh4.json")
     data = bench_files.load_module("generators", config["generator"]).generate(
         config, 7, 1)

@@ -569,3 +569,73 @@ def test_cap_advisor_stats_surface():
     assert 'kolibrie_cap_retries_total{engine="device"}' in prom
     assert 'kolibrie_cap_retries_total{engine="sharded"}' in prom
     cap_advisor.reset()
+
+
+# ------------------------------------------- the sorted form of lex_range
+
+
+def _range_case(rng, n_cols, kind):
+    """``(cols, keys)`` of one range-search case: a base with runs of
+    duplicates and sentinel padding (``kind`` says how much of each) and
+    probes absent, present, repeated and holding the sentinel."""
+    cap, n_rows, p = {
+        "runs_and_padding": (64, 45, 40),
+        "no_padding": (32, 32, 17),
+        "all_padding": (16, 0, 9),
+        "one_probe": (64, 50, 1),
+        "probes_outnumber_rows": (8, 6, 200),
+        "one_row": (1, 1, 5),
+    }[kind]
+    cols = _sorted_cols(rng, n_cols, n_rows, cap, alphabet=5)
+    present = rng.integers(0, max(n_rows, 1), p)
+    keys = []
+    for c in range(n_cols):
+        k = cols[c][present].copy()  # a row of the base, padding included
+        absent = rng.random(p) < 0.3
+        k = np.where(absent, rng.integers(0, 7, p).astype(np.uint32), k)
+        keys.append(np.where(rng.random(p) < 0.1, SENT, k).astype(np.uint32))
+    if p > 3:
+        for k in keys:
+            k[1] = k[0]  # equal probes
+            k[2] = SENT  # a probe that is the padding's own tuple
+    return cols, tuple(keys)
+
+
+@pytest.mark.parametrize(
+    "kind",
+    ["runs_and_padding", "no_padding", "all_padding", "one_probe",
+     "probes_outnumber_rows", "one_row"],
+)
+@pytest.mark.parametrize("n_cols", [1, 2, 3])
+def test_lex_range_sorted_is_lex_range_bit_for_bit(n_cols, kind):
+    """One sort of base rows and tagged probe copies gives the gather
+    loop's ``(lo, hi)``, and the numpy twin's: same values, same dtype."""
+    import jax
+
+    from kolibrie_tpu.ops.wcoj import lex_range_sorted
+
+    rng = np.random.default_rng(35 + 7 * n_cols + len(kind))
+    for _trial in range(4):
+        cols, keys = _range_case(rng, n_cols, kind)
+        jcols = tuple(jnp.asarray(c) for c in cols)
+        jkeys = tuple(jnp.asarray(k) for k in keys)
+        lo, hi = lex_range(jcols, jkeys)
+        for x64 in (False, True):  # the plan body is traced under x64
+            with jax.enable_x64(x64):
+                slo, shi = jax.jit(lex_range_sorted)(jcols, jkeys)
+            assert slo.dtype == lo.dtype == jnp.int32 and shi.dtype == jnp.int32
+            np.testing.assert_array_equal(np.asarray(slo), np.asarray(lo))
+            np.testing.assert_array_equal(np.asarray(shi), np.asarray(hi))
+        hlo, hhi = host_lex_range(cols, keys)
+        np.testing.assert_array_equal(np.asarray(slo), hlo)
+        np.testing.assert_array_equal(np.asarray(shi), hhi)
+
+
+def test_lex_range_sorted_of_no_rows_is_zeros():
+    from kolibrie_tpu.ops.wcoj import lex_range_sorted
+
+    empty = (jnp.zeros(0, dtype=jnp.uint32),) * 2
+    keys = (jnp.arange(5, dtype=jnp.uint32),) * 2
+    for got, want in zip(lex_range_sorted(empty, keys), lex_range(empty, keys)):
+        assert got.dtype == want.dtype
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

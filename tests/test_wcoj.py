@@ -292,3 +292,244 @@ def test_host_fallback_joins_in_connected_order(monkeypatch):
     rows = execute_query_volcano(lubm.LUBM_Q2, db)
     members = 2 * lubm.DEPTS_PER_UNIV * lubm.STUDENTS_PER_DEPT  # memberOf scan
     assert rows and sizes and max(sizes) <= members, sizes
+
+
+# ------------------------------------------- range searches by shape (ISSUE 35)
+#
+# A level's range searches take one of two forms by their static shapes
+# (``ops/wcoj.py`` ``range_search_form``): the tests run the same requests at
+# capacities on both sides of the rule and hold both to the numpy twin.
+
+RDF_TYPE = "<http://www.w3.org/1999/02/22-rdf-syntax-ns#type>"
+EX = "http://example.org/"
+# the two cyclic LUBM queries' shapes: three typed variables and a triangle
+# of properties, Q9's closing edge from ?x to ?z, Q2's from ?x to ?y
+TYPED = "SELECT ?x ?y ?z WHERE { ?x a ex:@A@ . ?y a ex:B . ?z a ex:C . %s }"
+SHAPES = {
+    "q9": TYPED % "?x ex:p1 ?y . ?y ex:p2 ?z . ?x ex:p3 ?z",
+    "q2": TYPED % "?x ex:p1 ?z . ?z ex:p2 ?y . ?x ex:p3 ?y",
+}
+LOOP_CAP, SORTED_CAP = 1024, 16384
+SIDE_CAP = {"loop": LOOP_CAP, "sorted": SORTED_CAP}
+
+
+def _typed_db(seed=35, n_nodes=60, n_edges=900):
+    rng = np.random.default_rng(seed)
+    lines = set()
+    for k in range(n_nodes):
+        for cls in ("A", "B", "C"):
+            if rng.random() < 0.6:
+                lines.add(f"<{EX}n{k}> {RDF_TYPE} <{EX}{cls}> .")
+                if cls == "A":  # A2: as many members, other nodes: one plan
+                    lines.add(f"<{EX}n{(k + 1) % n_nodes}> {RDF_TYPE} <{EX}A2> .")
+    while len(lines) < n_edges:
+        a, b = rng.integers(0, n_nodes, 2)
+        p = ("p1", "p2", "p3")[int(rng.integers(0, 3))]
+        lines.add(f"<{EX}n{a}> <{EX}{p}> <{EX}n{b}> .")
+    db = SparqlDatabase()
+    db.store.delta_threshold = 4096  # keep the writes below in the delta
+    db.parse_ntriples("\n".join(sorted(lines)))
+    db.execution_mode = "device"
+    # a live delta and tombstones: every 9th base row deleted, and inserts
+    # few enough (under a sixteenth of the store) to stay in the delta
+    s, p, o = (c.copy() for c in db.store.columns())
+    for i in range(0, len(s), 9):
+        db.delete_triple(Triple(int(s[i]), int(p[i]), int(o[i])))
+    db.parse_ntriples("\n".join(
+        f"<{EX}n{a}> <{EX}{pp}> <{EX}n{(a * 7 + 3) % n_nodes}> ."
+        for a in range(0, n_nodes, 6) for pp in ("p1", "p2", "p3")))
+    db.store.compact()
+    assert len(db.store.delta_order("spo")) > 0
+    assert len(db.store.delta_del_positions("spo")) > 0
+    return db
+
+
+def _lower(db, sparql):
+    from kolibrie_tpu.optimizer import device_engine as de
+    from kolibrie_tpu.optimizer.engine import resolve_pattern
+    from kolibrie_tpu.optimizer.planner import Streamertail, build_logical_plan
+    from kolibrie_tpu.query.parser import parse_sparql_query
+
+    db.register_prefixes_from_query(sparql)
+    w = parse_sparql_query(sparql, db.prefixes).where
+    resolved = [resolve_pattern(db, p) for p in w.patterns]
+    logical = build_logical_plan(resolved, list(w.filters), [], w.values)
+    plan = Streamertail(db.get_or_build_stats()).find_best_plan(logical)
+    low = de.lower_plan(db, plan)
+    assert isinstance(low.root, de.WcojSpec), low.root
+    return low
+
+
+def _at_capacity(db, sparql, cap):
+    """The request's lowering with every level ``cap`` wide."""
+    low = _lower(db, sparql)
+    db.__dict__.setdefault("_device_cap_cache", {})[low.cap_key] = (
+        (cap,) * low.join_count)
+    return _lower(db, sparql)
+
+
+def _searches():
+    from kolibrie_tpu.query.template import _RANGE_SEARCH
+
+    return {f: _RANGE_SEARCH.labels(f).value for f in ("sorted", "loop")}
+
+
+def _spec_searches(low, every_tier=False):
+    """The range searches one dispatch of ``low`` makes, by form, counted
+    from its levels as ``eval_level`` makes them: per accessor and tier one
+    in ``probe`` where it has keys, one in ``live``."""
+    from kolibrie_tpu.ops.wcoj import range_search_form
+
+    out = {"sorted": 0, "loop": 0}
+    pcap = 1
+    for lv in low.root.levels:
+        cap = low._join_caps[lv.join_idx]
+        for a in lv.accessors:
+            base, delta = low._seg_rows[a.order_idx]
+            live_delta = every_tier or low._tiers_np[a.order_idx] > 0
+            for n in (base, delta) if live_delta else (base,):
+                if a.key_srcs:
+                    out[range_search_form(n, pcap, len(a.key_srcs))] += 1
+                out[range_search_form(n, cap, len(a.key_srcs) + 1)] += 1
+        pcap = cap
+    return out
+
+
+def _id_rows(table):
+    names = sorted(table)
+    return sorted(zip(*(table[v].tolist() for v in names)))
+
+
+@pytest.mark.parametrize(
+    "n,p,ncols,form",
+    [
+        # PR 35's gate, one v5e (PERF.md section 6): the shapes it timed
+        (2**20, 1024, 3, "loop"),
+        (2**20, 16384, 2, "sorted"),
+        (2**20, 65536, 3, "sorted"),
+        (2**23, 4096, 3, "loop"),
+        (2**23, 262144, 2, "sorted"),
+        (2**23, 1048576, 3, "sorted"),
+        (1024, 65536, 3, "sorted"),
+        # the gate's three further points: the sort won 1.6-1.7 times at
+        # N = 128 P and stays out (its two sort instructions cost the TPU
+        # compiler 27-67 s); it lost at N = 512 P
+        (2**23, 65536, 3, "loop"),
+        (2**20, 8192, 3, "loop"),
+        (2**23, 16384, 2, "loop"),
+        # a level's first probe, and the floor capacity over a small store
+        (2**23, 1, 2, "loop"),
+        (1024, 1024, 3, "loop"),
+    ],
+)
+def test_the_rule_picks_the_form_by_shape(n, p, ncols, form):
+    from kolibrie_tpu.ops.wcoj import range_search_form
+
+    assert range_search_form(n, p, ncols) == form
+
+
+@pytest.mark.parametrize("ncols", [1, 2, 3])
+def test_the_rule_is_monotone_in_the_probes(ncols):
+    """More probes never turn a sort back into a loop: the loop's cost
+    grows with P, the sort's hardly."""
+    from kolibrie_tpu.ops.wcoj import range_search_form
+
+    for n in (1, 1024, 2**17, 2**20, 2**23, 2**26):
+        forms = [range_search_form(n, 2**k, ncols) for k in range(0, 24)]
+        first = forms.index("sorted") if "sorted" in forms else len(forms)
+        assert forms == ["loop"] * first + ["sorted"] * (len(forms) - first)
+        assert forms[0] == "loop"  # a level's first probe is one tuple wide
+
+
+@pytest.mark.parametrize("side", ["loop", "sorted"])
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_typed_triangles_on_both_sides_of_the_rule(shape, side, monkeypatch):
+    """Q9- and Q2-shaped triangles over a store with a live delta and
+    tombstones: at 1,024-wide levels every search loops, at 16,384-wide
+    ones the levels' searches sort, and the rows are the numpy twin's and
+    the host path's either way; the counter grows by the spec's count."""
+    monkeypatch.setenv("KOLIBRIE_WCOJ", "force")
+    db = _typed_db()
+    sparql = PREFIX + SHAPES[shape].replace("@A@", "A")
+    low = _at_capacity(db, sparql, SIDE_CAP[side])
+    before = _searches()
+    got = _id_rows(low.execute())
+    grew = {f: v - before[f] for f, v in _searches().items()}
+    assert set(low._join_caps) == {SIDE_CAP[side]}
+    want = _spec_searches(low)
+    assert grew == want
+    accessors = sum(len(lv.accessors) for lv in low.root.levels)
+    assert sum(want.values()) >= 2 * accessors  # base and delta, every tier live
+    if side == "loop":
+        assert want["sorted"] == 0
+    else:
+        # all but the first level's one-tuple probes
+        first_probes = 2 * sum(bool(a.key_srcs) for a in low.root.levels[0].accessors)
+        assert want == {"sorted": sum(want.values()) - first_probes,
+                        "loop": first_probes}
+    assert got == _id_rows(low.host_execute()[0])
+    assert got and len(got) == len(_rows(db, sparql, "host"))
+
+
+def _sort_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "sort":
+            yield eqn
+        for sub in eqn.params.values():
+            for item in sub if isinstance(sub, (list, tuple)) else (sub,):
+                inner = getattr(item, "jaxpr", item)
+                if hasattr(inner, "eqns"):
+                    yield from _sort_eqns(inner)
+
+
+@pytest.mark.parametrize("side", ["loop", "sorted"])
+def test_the_traced_program_sorts_where_the_counter_says_so(side, monkeypatch):
+    """One variadic sort a sorted search (keys: its columns and the tag),
+    one two-operand sort back; a loop search traces none."""
+    import jax
+
+    from kolibrie_tpu.optimizer import device_engine as de
+
+    monkeypatch.setenv("KOLIBRIE_WCOJ", "force")
+    db = _typed_db()
+    low = _at_capacity(
+        db, PREFIX + SHAPES["q9"].replace("@A@", "A"), SIDE_CAP[side])
+    spec, args = low.build()
+    with jax.enable_x64(True):
+        jaxpr = jax.make_jaxpr(lambda *a: de._run_plan(spec, False, *a))(*args)
+    sorts = list(_sort_eqns(jaxpr.jaxpr))
+    merged = [e for e in sorts if e.params["num_keys"] >= 2]
+    back = [e for e in sorts if e.params["num_keys"] == 1]
+    want = _spec_searches(low, every_tier=True)  # both branches are traced
+    assert len(merged) == len(back) == want["sorted"]
+    assert (want["sorted"] == 0) == (side == "loop")
+    for e in merged:
+        assert len(e.invars) == e.params["num_keys"]  # columns..., tag: all keys
+
+
+def test_a_group_of_two_wcoj_members_builds_one_executable(monkeypatch):
+    """Two constants of one typed triangle in one dispatch: one batch
+    executable, each member's rows its own twin's, and the searches counted
+    once a live member."""
+    from kolibrie_tpu.optimizer import device_engine as de
+
+    monkeypatch.setenv("KOLIBRIE_WCOJ", "force")
+    db = _typed_db()
+    texts = [PREFIX + SHAPES["q9"].replace("@A@", a) for a in ("A", "A2")]
+    lows = [_at_capacity(db, t, SORTED_CAP) for t in texts]
+    assert lows[0].cap_key == lows[1].cap_key
+    programs0 = de.device_compile_stats()["run_plan_batch"]
+    before = _searches()
+    tables = de.execute_plan_batch(lows)
+    grew = {f: v - before[f] for f, v in _searches().items()}
+    assert de.device_compile_stats()["run_plan_batch"] - programs0 == 1
+    want = _spec_searches(lows[0])
+    assert want["sorted"] > 0
+    assert grew == {f: 2 * v for f, v in want.items()}
+    rows = [_id_rows(t) for t in tables]
+    for text, got in zip(texts, rows):
+        assert got == _id_rows(_lower(db, text).host_execute()[0])
+    assert rows[0] != rows[1] and all(rows)
+    # the same group again: nothing compiles
+    de.execute_plan_batch([_lower(db, t) for t in texts])
+    assert de.device_compile_stats()["run_plan_batch"] - programs0 == 1
