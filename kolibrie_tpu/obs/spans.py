@@ -200,6 +200,24 @@ def span(name: str, **attrs):
     return _SpanScope(name, attrs)
 
 
+def add_finished(name: str, start_s: float, end_s: float,
+                 attrs: Dict[str, Any]) -> None:
+    """Record a section someone else timed on the wall clock (JAX's compile
+    steps) as a finished child of whatever span is current on this thread.
+    Outside every trace there is no parent and nothing is recorded."""
+    if not runtime.enabled():
+        return
+    ctx = _ctx()
+    if ctx["trace_id"] is None:
+        return
+    stack = ctx["stack"]
+    sp = Span(ctx["trace_id"], stack[-1].span_id if stack else None, name, attrs)
+    sp.start_s = start_s
+    sp.dur_ms = (end_s - start_s) * 1000.0
+    with _ring_lock:
+        _ring.append(sp)
+
+
 # ------------------------------------------------------------------ baggage
 
 

@@ -80,6 +80,7 @@ from kolibrie_tpu.obs.spans import get_baggage as _get_baggage
 from kolibrie_tpu.optimizer import stats_advisor as _sa
 from kolibrie_tpu.obs.spans import span as _obs_span
 from kolibrie_tpu.ops import round_cap as _round_cap
+from kolibrie_tpu.query import compile_cache as _cc
 from kolibrie_tpu.resilience.deadline import check_deadline
 from kolibrie_tpu.resilience.faultinject import fault_point
 
@@ -445,12 +446,12 @@ def _build_traced(lowered, tag: int, operands: bool = True):
 def _enqueue_traced(entry, *args):
     """Call a jit entry point under ``device.enqueue``: Python dispatch and
     argument handling until the call returns (asynchronously), plus the trace
-    and the compile or cache load where the shape is new (``compiled=1``)."""
+    and the compile or cache load where the shape is new (``compiled=1``: the
+    call left a first-sight record, ``compile.*`` children beside it)."""
     with _obs_span("device.enqueue") as sp:
-        before = _jit_entries(entry) if sp is not None else 0
-        out = entry(*args)
+        out = _cc.call(entry, *args)
         if sp is not None:
-            sp.attrs["compiled"] = int(_jit_entries(entry) > before)
+            sp.attrs["compiled"] = int(_cc.last_sight() is not None)
     return out
 
 
@@ -1247,28 +1248,6 @@ def device_compile_stats() -> Dict[str, int]:
 
     out["run_interp"] = interp_compile_stats()
     return out
-
-
-def _cc_counters() -> Dict[str, int]:
-    """Persistent-compile-cache hit/miss tallies (zeros when the cache
-    module never activated — the deltas still classify correctly)."""
-    from kolibrie_tpu.query.compile_cache import counters
-
-    return counters()
-
-
-def _classify_source(jit_before: int, cc_before: Dict[str, int]) -> str:
-    """Classify a specialized dispatch after the fact: a jit-cache entry
-    appeared and every persistent-cache lookup hit disk → ``disk``;
-    otherwise (fresh XLA compile, or warm replay) → ``compiled``."""
-    if not 0 <= jit_before < _jit_entries(_run_plan):
-        return "compiled"
-    after = _cc_counters()
-    if after["hits"] > cc_before.get("hits", 0) and after[
-        "misses"
-    ] == cc_before.get("misses", 0):
-        return "disk"
-    return "compiled"
 
 
 # ---------------------------------------------------------------------------
@@ -3337,14 +3316,15 @@ class LoweredPlan:
                 _DISPATCH_LAT.labels(tpl).observe(_time.perf_counter() - t0)
                 check_deadline("device.execute.done")
                 return table
-        jit0 = device_compile_stats().get("run_plan", -1)
-        cc0 = _cc_counters()
         t0 = _time.perf_counter()
         with _obs_span("device.dispatch", template=tpl):
             parts = self.converge(self.run())
         _DISPATCH_LAT.labels(tpl).observe(_time.perf_counter() - t0)
         plan_interp.mark_compiled(self)
-        self.last_source = _classify_source(jit0, cc0)
+        sight = _cc.last_sight()  # of the run whose rows these are
+        self.last_source = (
+            "disk" if sight is not None and sight["outcome"] == "hit" else "compiled"
+        )
         t1 = _time.perf_counter()
         with _obs_span("device.collect"):
             table = self.to_table(*parts)

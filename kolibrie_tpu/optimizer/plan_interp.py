@@ -70,6 +70,7 @@ import jax.numpy as jnp
 from kolibrie_tpu.obs import analyze as _analyze
 from kolibrie_tpu.obs import metrics as _metrics
 from kolibrie_tpu.obs.spans import span as _obs_span
+from kolibrie_tpu.query import compile_cache as _cc
 
 __all__ = [
     "plan_interp_mode",
@@ -574,7 +575,8 @@ def _dispatch(lowered, prog: InterpProgram, args):
         fb = _bucket(f.shape[0], 8)
         u = jnp.concatenate([u, jnp.zeros(ub - u.shape[0], dtype=u.dtype)])
         f = jnp.concatenate([f, jnp.zeros(fb - f.shape[0], dtype=f.dtype)])
-        return _run_interp(
+        return _cc.call(
+            _run_interp,
             prog.n_ops,
             prog.cap,
             prog.n_slots,

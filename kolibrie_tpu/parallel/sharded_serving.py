@@ -79,6 +79,7 @@ from kolibrie_tpu.parallel.dist_join import (
 )
 from kolibrie_tpu.parallel.mesh import make_mesh
 from kolibrie_tpu.parallel.sharded_store import ShardedTripleStore, shard_of
+from kolibrie_tpu.query import compile_cache as _cc
 from kolibrie_tpu.query.template import cap_advisor, fingerprint_query
 from kolibrie_tpu.resilience.deadline import check_deadline
 from kolibrie_tpu.resilience.faultinject import fault_point
@@ -953,8 +954,7 @@ class ShardedDatabase:
         live = np.int32(len(group["execs"]))
         join_cap, bucket_cap = group["caps"]
         for attempt in range(8):
-            fn = _get_batched_fn(
-                self.mesh,
+            key = (
                 group["premises"],
                 exemplar.seed,
                 exemplar.steps,
@@ -965,9 +965,13 @@ class ShardedDatabase:
                 bucket_cap,
                 group["params"].shape[0],
             )
+            fn = _get_batched_fn(self.mesh, *key)
             with jax.enable_x64(True):
-                outs, valid, overflow, shard_stats = fn(
-                    state, group["masks"], group["params"], live
+                # the program's key and the mesh's size are what this layer
+                # holds to define the executable (a first sight's identity)
+                outs, valid, overflow, shard_stats = _cc.call(
+                    fn, state, group["masks"], group["params"], live,
+                    declared=("mesh", (self.mesh.devices.size, key)),
                 )
             if int(np.asarray(overflow)[0]) == 0:
                 break

@@ -33,6 +33,14 @@ re-exported as ``kolibrie_compile_cache_{hits,misses}_total`` so /stats
 and the bench can attribute a cold query to "disk hit" vs "real
 compile".
 
+Every executable the process builds or loads also leaves one **first-sight
+record** (:func:`_first_sight`): which function and entry point, seconds of
+tracing, lowering and backend compile or cache load, JAX's cache key, the
+entry's bytes on disk, and why a miss was a miss.  The records go to the
+counters below, to ``compile.*`` child spans, to ``/stats`` and to a bounded
+journal beside the pre-warm manifest that the next process on the same
+directory reads (docs/COMPILE_CACHE.md "First-sight records").
+
 The module also owns the **pre-warm manifest**: a small JSON file next
 to the cache recording, per template fingerprint, one representative
 query text and its cumulative hit count.  On startup the warmer
@@ -43,12 +51,16 @@ disk cache hot — zero compiles, zero disk misses.
 
 from __future__ import annotations
 
+import hashlib
 import json
+import logging
 import os
 import threading
+from collections import deque
 from typing import Dict, List, Optional
 
 from kolibrie_tpu.obs import metrics as _metrics
+from kolibrie_tpu.obs import spans as _spans
 
 __all__ = [
     "enable",
@@ -56,6 +68,11 @@ __all__ = [
     "cache_namespace",
     "stats",
     "counters",
+    "call",
+    "last_sight",
+    "records",
+    "journal_path",
+    "load_journal",
     "manifest_path",
     "load_manifest",
     "save_manifest",
@@ -83,17 +100,73 @@ _COMPILE_SECONDS = _metrics.counter(
 )
 _COMPILE_SECONDS.labels("compile")
 _COMPILE_SECONDS.labels("disk")
-_tls = threading.local()  # .hit: this thread's compile in flight hit disk
+# The closed sets behind the first-sight families' labels.  Every value is
+# registered here, as ``source``'s two are, so a family that counted nothing
+# reads 0 and its metric prints.
+ENTRIES = ("run_plan", "run_plan_batch", "run_interp", "mesh", "other")
+OUTCOMES = ("hit", "miss_new", "miss_entry_lost", "miss_key_moved", "uncached")
+
+_TRACE_SECONDS = _metrics.counter(
+    "kolibrie_device_trace_seconds_total",
+    "wall seconds tracing a jit's Python into a jaxpr on a shape's first "
+    "sight, by the entry point that declared the call",
+    labels=("entry",),
+)
+_LOWER_SECONDS = _metrics.counter(
+    "kolibrie_device_lower_seconds_total",
+    "wall seconds lowering a first sight's jaxpr to an MLIR module, by the "
+    "entry point that declared the call",
+    labels=("entry",),
+)
+_FIRST_SIGHT = _metrics.counter(
+    "kolibrie_compile_first_sight_total",
+    "executables built or loaded, by outcome: hit, miss_new, miss_entry_lost "
+    "(the journal has this key as written or hit), miss_key_moved (the "
+    "journal has this identity under another key), uncached (no key)",
+    labels=("outcome",),
+)
+_WRITTEN_BYTES = _metrics.counter(
+    "kolibrie_compile_cache_written_bytes_total",
+    "bytes of the persistent-cache entries found on disk after a miss's write",
+)
+_WRITE_FAILURES = _metrics.counter(
+    "kolibrie_compile_cache_write_failures_total",
+    "misses whose entry was not on disk after JAX wrote it (an entry over "
+    "jax_compilation_cache_max_size, an I/O error)",
+)
+_FOUND_BYTES = _metrics.gauge(
+    "kolibrie_compile_cache_found_bytes",
+    "bytes of entries the cache directory held when this process enabled it",
+)
+_FOUND_ENTRIES = _metrics.gauge(
+    "kolibrie_compile_cache_found_entries",
+    "entries the cache directory held when this process enabled it",
+)
+for _entry in ENTRIES:
+    _TRACE_SECONDS.labels(_entry)
+    _LOWER_SECONDS.labels(_entry)
+for _outcome in OUTCOMES:
+    _FIRST_SIGHT.labels(_outcome)
+
+# What the compiling thread has seen of the executable in flight: .sight (the
+# call a jit entry point declared), .traces and .lower (JAX's finished steps),
+# .key / .hit / .writing (the persistent cache's verdict).
+_tls = threading.local()
 
 _lock = threading.Lock()
 _active_dir: Optional[str] = None
-# where the pre-warm manifest lives: the configured root (the cache
-# directory itself when the environment placed it)
+# where the pre-warm manifest and the compile journal live: the configured
+# root (the cache directory itself when the environment placed it)
 _active_root: Optional[str] = None
 _listener_installed = False
 # raw event tallies, independent of the obs registry being enabled —
 # the restart regression test asserts on these
 _event_counts = {"hits": 0, "misses": 0}
+
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_SUFFIX = "-cache"  # jax._src.lru_cache names an entry <key>-cache
 
 
 def cache_namespace() -> str:
@@ -114,29 +187,373 @@ def _on_event(event: str, **kwargs) -> None:
 
 
 def _on_duration(event: str, duration_secs: float, **kwargs) -> None:
-    # jax records a hit's retrieval time inside the backend-compile step and
-    # that step's whole duration when it ends, both on the compiling thread
+    # jax records a hit's retrieval time inside the backend-compile step, on
+    # the compiling thread
     if event == "/jax/compilation_cache/cache_retrieval_time_sec":
         _tls.hit = True
-    elif event == "/jax/core/compile/backend_compile_duration":
-        source = "disk" if getattr(_tls, "hit", False) else "compile"
-        _tls.hit = False
-        _COMPILE_SECONDS.labels(source).inc(duration_secs)
+
+
+def _on_time_span(
+    event: str, start_time: float, end_time: float, fun_name: str = "", **kwargs
+) -> None:
+    # jax reports each step when it ends, on the compiling thread, on the wall
+    # clock: traces, lower, then the backend step that closes the record
+    if event == _TRACE_EVENT:
+        # by name, the newest: lowering traces hundreds of small jits (`add`,
+        # `less`) after the entry point's own trace has ended
+        _tls.__dict__.setdefault("traces", {})[fun_name] = (start_time, end_time)
+    elif event == _LOWER_EVENT:
+        _tls.lower = (start_time, end_time, fun_name)
+    elif event == _BACKEND_EVENT:
+        try:
+            _first_sight(fun_name, start_time, end_time)
+        # kolint: ignore[KL601] the listener runs inside JAX's compile step: a record that cannot be made must not fail the query it describes
+        except Exception:
+            pass
+
+
+class _KeyFilter(logging.Filter):
+    """Reads the persistent cache's key and verdict off JAX's own log lines
+    (``jax._src.compiler``, ``jax._src.compilation_cache``: debug level,
+    emitted on the compiling thread), which no monitoring event carries.  The
+    loggers are opened to DEBUG for it; the filter lets through only what they
+    would have emitted before, so nobody's log grows."""
+
+    def filter(self, record: logging.LogRecord) -> bool:
+        msg, args = record.msg, record.args
+        if isinstance(msg, str) and args:
+            if msg.startswith(
+                ("Persistent compilation cache hit", "PERSISTENT COMPILATION CACHE MISS")
+            ):
+                _tls.key = str(args[-1])
+            elif msg.startswith("Writing ") and "persistent compilation cache" in msg:
+                _tls.writing = True
+        logger = logging.getLogger(record.name)
+        return bool(logger.handlers) or (
+            record.levelno >= logger.parent.getEffectiveLevel()
+        )
 
 
 def _install_listener() -> None:
     global _listener_installed
-    if _listener_installed:
-        return
-    try:
-        from jax._src import monitoring
+    with _lock:
+        if _listener_installed:
+            return
+        try:
+            from jax._src import monitoring
 
-        monitoring.register_event_listener(_on_event)
-        monitoring.register_event_duration_secs_listener(_on_duration)
-        _listener_installed = True
-    # kolint: ignore[KL601] private-API drift: cache still works, only the counters go dark
+            monitoring.register_event_listener(_on_event)
+            monitoring.register_event_duration_secs_listener(_on_duration)
+            monitoring.register_event_time_span_listener(_on_time_span)
+            for name in ("jax._src.compiler", "jax._src.compilation_cache"):
+                logger = logging.getLogger(name)
+                logger.addFilter(_KeyFilter())
+                logger.setLevel(logging.DEBUG)
+            _listener_installed = True
+        # kolint: ignore[KL601] private-API drift: cache still works, only the counters go dark
+        except Exception:
+            pass
+
+
+# ---------------------------------------------------------------------------
+# First-sight records: one per executable built or loaded
+# ---------------------------------------------------------------------------
+
+
+class FirstSight:
+    """What a jit entry point hands the listener around its call, by
+    reference: the jit function's name, what it declared and the call's
+    arguments.  ``record`` is the first-sight record if the call built or
+    loaded an executable."""
+
+    __slots__ = ("fun", "declared", "args", "record")
+
+    def __init__(self, fun, declared, args):
+        self.fun = fun
+        self.declared = declared
+        self.args = args
+        self.record: Optional[dict] = None
+
+
+def call(fn, *args, declared=None):
+    """``fn(*args)`` for a jit entry point, so that an executable first seen
+    inside knows whose it is: the jit function's own name is the entry
+    (``_run_plan`` is ``run_plan``), or ``declared`` is ``(entry, static)``
+    for a program built at run time.  A warm dispatch pays the thread-local
+    assignment and nothing else: nothing is hashed unless a compile event
+    fires."""
+    # kolint: ignore[KL312] double-checked: the lock-free read is the warm dispatch's; _install_listener re-checks under the lock
+    if not _listener_installed:
+        _install_listener()
+    sight = _tls.sight = FirstSight(getattr(fn, "__name__", ""), declared, args)
+    try:
+        return fn(*args)
+    finally:
+        sight.args = None  # the thread must not pin the call's device arrays
+
+
+def last_sight() -> Optional[dict]:
+    """The first-sight record of this thread's last :func:`call`, or ``None``
+    where that call was warm."""
+    sight = getattr(_tls, "sight", None)
+    return sight.record if sight is not None else None
+
+
+def _identity(entry: str, fun: str, static, args) -> str:
+    """Hash of what THE PROGRAM holds to define an executable: the entry
+    point, the function, its static arguments (a ``PlanSpec``, a mesh
+    program's key: by ``repr``) and the other arguments' shapes and dtypes.
+    What it leaves out on purpose is everything only JAX's key holds, so that
+    an identity met under a new key says something outside the program's
+    notion moved (docs/COMPILE_CACHE.md)."""
+    import jax
+
+    leaves, treedef = jax.tree_util.tree_flatten(args)
+    sig = [
+        (tuple(x.shape), str(x.dtype))
+        if hasattr(x, "shape") and hasattr(x, "dtype")
+        else repr(x)
+        for x in leaves
+    ]
+    text = repr((entry, fun, static, str(treedef), sig))
+    return hashlib.sha256(text.encode()).hexdigest()[:32]
+
+
+def _declared_by(sight: Optional[FirstSight], fun: str):
+    """``(entry, static)`` where ``sight`` is the call of the jit ``fun`` and
+    has no record yet, else ``None`` (a jit of someone else's inside the
+    declared call, a compile after it returned)."""
+    if sight is None or sight.record is not None or sight.args is None:
+        return None
+    if sight.fun != fun:
+        return None
+    entry, static = sight.declared or (fun.lstrip("_"), None)
+    return (entry if entry in ENTRIES else "other"), static
+
+
+def _entry_file(key: str) -> Optional[str]:
+    try:
+        import jax
+        from jax._src import compilation_cache as jcc
+
+        root = getattr(jcc._cache, "_path", None) or jax.config.jax_compilation_cache_dir
+    # kolint: ignore[KL601] private-API drift: the record goes without the entry's bytes
     except Exception:
+        return None
+    return os.path.join(str(root), key + _CACHE_SUFFIX) if root else None
+
+
+def _first_sight(module: str, start: float, end: float) -> None:
+    """Close the record of the executable whose backend step just ended on
+    this thread (``module`` is JAX's ``jit(<fun>)``)."""
+    seen = _tls.__dict__
+    key = seen.pop("key", None)
+    hit = seen.pop("hit", False)
+    writing = seen.pop("writing", False)
+    fun = module[module.find("(") + 1 : -1] if module.endswith(")") else module
+    trace = seen.pop("traces", {}).get(fun)
+    lower = seen.pop("lower", None)
+    if lower is not None and lower[2] != module:
+        lower = None
+    _COMPILE_SECONDS.labels("disk" if hit else "compile").inc(end - start)
+
+    sight = seen.get("sight")
+    declared = _declared_by(sight, fun)
+    entry, identity = "other", fun
+    if declared is not None:
+        entry = declared[0]
+        identity = _identity(entry, fun, declared[1], sight.args)
+    path = _entry_file(key) if key else None
+    try:
+        size = os.path.getsize(path) if path else 0
+    except OSError:
+        size = 0
+    rec = {
+        "t": round(end, 3),
+        "pid": os.getpid(),
+        "fun": fun,
+        "entry": entry,
+        "identity": identity,
+        "key": key,
+        "trace_s": round(trace[1] - trace[0], 6) if trace else 0.0,
+        "lower_s": round(lower[1] - lower[0], 6) if lower else 0.0,
+        "backend_s": round(end - start, 6),
+        "bytes": size,
+    }
+    if hit:
+        outcome = "hit"
+    elif key is None:
+        outcome = "uncached"
+    else:
+        # skipped: JAX chose not to write (host callbacks, a threshold)
+        rec["write"] = ("ok" if size else "failed") if writing else "skipped"
+        if rec["write"] == "ok":
+            _WRITTEN_BYTES.inc(size)
+        elif rec["write"] == "failed":
+            _WRITE_FAILURES.inc()
+        outcome = _why_miss(rec)
+    rec["outcome"] = outcome
+    if declared is not None:
+        sight.record = rec
+    _TRACE_SECONDS.labels(entry).inc(rec["trace_s"])
+    _LOWER_SECONDS.labels(entry).inc(rec["lower_s"])
+    _FIRST_SIGHT.labels(outcome).inc()
+    attrs = {
+        "entry": entry,
+        "fun": fun,
+        "outcome": outcome,
+        # the hash's head: every key of one function starts with its name
+        "key": key.rsplit("-", 1)[-1][:16] if key else "",
+        "bytes": size,
+    }
+    for name, step in (
+        ("compile.trace", trace),
+        ("compile.lower", lower),
+        ("compile.backend", (start, end)),
+    ):
+        if step is not None:
+            _spans.add_finished(name, step[0], step[1], dict(attrs))
+    _journal_append(rec)
+
+
+# ---------------------------------------------------------------------------
+# Compile journal: the records, kept beside the manifest for the next process
+# ---------------------------------------------------------------------------
+
+_JOURNAL_NAME = "compile_journal.jsonl"
+_JOURNAL_MAX = 1024  # newest lines kept at a trim
+_JOURNAL_SLACK = 256  # appended lines between trims
+
+_journal_lock = threading.Lock()
+_records: deque = deque(maxlen=256)  # this process's, newest last; guarded by: _journal_lock
+_landed: set = set()  # keys the journal has as hit or written; guarded by: _journal_lock
+_key_of: Dict[str, str] = {}  # declared identity -> its newest key; guarded by: _journal_lock
+_journal_lines = 0  # lines in the file as last counted; guarded by: _journal_lock
+
+
+def journal_path(root: Optional[str] = None) -> Optional[str]:
+    """The journal lives beside the manifest, at the cache ROOT."""
+    base = root or _active_root
+    if base is None:
+        return None
+    return os.path.join(base, _JOURNAL_NAME)
+
+
+def _read_journal(path: Optional[str]) -> List[dict]:
+    if path is None or not os.path.isfile(path):
+        return []
+    out = []
+    try:
+        with open(path, "rb") as f:
+            for line in f:
+                try:
+                    rec = json.loads(line.decode("utf-8"))
+                except ValueError:
+                    continue
+                if isinstance(rec, dict) and isinstance(rec.get("fun"), str):
+                    out.append(rec)
+    except OSError:
+        return []
+    return out
+
+
+def load_journal(root: Optional[str] = None) -> List[dict]:
+    """The journal's newest records, oldest first.  A missing file reads as
+    empty and a torn or foreign line is skipped: the journal is advisory, as
+    the manifest is."""
+    return _read_journal(journal_path(root))[-_JOURNAL_MAX:]
+
+
+def records() -> List[dict]:
+    """This process's first-sight records, oldest first (the newest 256)."""
+    with _journal_lock:
+        return list(_records)
+
+
+def _index(rec: dict) -> None:  # kolint: holds[_journal_lock]
+    key = rec.get("key")
+    if not key:
+        return
+    if rec.get("outcome") == "hit" or rec.get("write") == "ok":
+        _landed.add(key)
+    if rec.get("entry") != "other" and rec.get("identity"):
+        _key_of[rec["identity"]] = key
+
+
+def _why_miss(rec: dict) -> str:
+    with _journal_lock:
+        if rec["key"] in _landed:
+            return "miss_entry_lost"
+        # a jit nobody declared has its name for an identity, and one name
+        # has a key a shape: only a declared identity can tell a key moved
+        was = _key_of.get(rec["identity"]) if rec["entry"] != "other" else None
+    if was is not None and was != rec["key"]:
+        rec["was"] = was
+        return "miss_key_moved"
+    return "miss_new"
+
+
+def _write_journal(path: str, recs: List[dict]) -> None:
+    from kolibrie_tpu.durability.fsio import atomic_write_bytes
+
+    atomic_write_bytes(
+        path, "".join(json.dumps(r, sort_keys=True) + "\n" for r in recs).encode()
+    )
+
+
+def _adopt_journal() -> None:
+    """Index what earlier processes on this root left (called when a root
+    becomes active) and trim the file to its bound."""
+    global _journal_lines
+    path = journal_path()
+    recs = _read_journal(path)
+    with _journal_lock:
+        _landed.clear()
+        _key_of.clear()
+        for rec in recs[-_JOURNAL_MAX:] + list(_records):
+            _index(rec)
+        if len(recs) > _JOURNAL_MAX:
+            recs = recs[-_JOURNAL_MAX:]
+            try:
+                _write_journal(path, recs)
+            except OSError:
+                pass
+        _journal_lines = len(recs)
+
+
+def _journal_append(rec: dict) -> None:
+    global _journal_lines
+    path = journal_path()
+    with _journal_lock:
+        _records.append(rec)
+        _index(rec)
+        if path is None:
+            return
+        try:
+            os.makedirs(os.path.dirname(path), exist_ok=True)
+            with open(path, "a", encoding="utf-8") as f:
+                f.write(json.dumps(rec, sort_keys=True) + "\n")
+            _journal_lines += 1
+            if _journal_lines >= _JOURNAL_MAX + _JOURNAL_SLACK:
+                recs = load_journal()
+                _write_journal(path, recs)
+                _journal_lines = len(recs)
+        except OSError:
+            pass  # the journal is advisory: a lost line costs the next miss its reason
+
+
+def _scan_entries(target: str):
+    """``(entries, bytes)`` of the persistent-cache entries in ``target``."""
+    entries = size = 0
+    try:
+        with os.scandir(target) as it:
+            for e in it:
+                if e.name.endswith(_CACHE_SUFFIX) and e.is_file():
+                    entries += 1
+                    size += e.stat().st_size
+    except OSError:
         pass
+    return entries, size
 
 
 def enable(
@@ -147,7 +564,7 @@ def enable(
     Where ``$JAX_COMPILATION_CACHE_DIR`` is set it wins over every
     argument: JAX already reads it, so no directory is set here and no
     sub-directory appended — the call only records it as active, drops
-    the two thresholds and installs the hit/miss listener.  Otherwise the
+    the two thresholds and installs the listeners.  Otherwise the
     resolution order is ``explicit_dir`` argument →
     ``$KOLIBRIE_COMPILE_CACHE_DIR`` → ``<data_dir>/compile_cache``, each
     namespaced by :func:`cache_namespace`.  Returns the active directory,
@@ -157,9 +574,8 @@ def enable(
     dispatches hit disk.
     """
     global _active_dir, _active_root
-    with _lock:
-        # compile seconds are counted with or without a cache directory
-        _install_listener()
+    # first sights are recorded with or without a cache directory
+    _install_listener()
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env_dir:
         root = target = os.path.abspath(env_dir)
@@ -184,6 +600,10 @@ def enable(
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         jax.config.update("jax_enable_compilation_cache", True)
         _active_dir, _active_root = target, root
+        entries, size = _scan_entries(target)
+        _FOUND_ENTRIES.set(entries)
+        _FOUND_BYTES.set(size)
+        _adopt_journal()
     return target
 
 
@@ -199,8 +619,9 @@ def counters() -> Dict[str, int]:
 
 
 def stats() -> dict:
-    """Inspection block for /stats: location, entry count, bytes, and
-    the hit/miss tallies."""
+    """Inspection block for /stats: location, entry count, bytes, the
+    hit/miss tallies, JAX's size bound, and this process's first-sight
+    records with the journal they were appended to."""
     out: dict = {
         "enabled": _active_dir is not None,
         "dir": _active_dir,
@@ -208,18 +629,12 @@ def stats() -> dict:
         "misses": _event_counts["misses"],
     }
     if _active_dir and os.path.isdir(_active_dir):
-        entries = 0
-        size = 0
-        try:
-            for name in os.listdir(_active_dir):
-                p = os.path.join(_active_dir, name)
-                if os.path.isfile(p):
-                    entries += 1
-                    size += os.path.getsize(p)
-        except OSError:
-            pass
-        out["entries"] = entries
-        out["bytes"] = size
+        out["entries"], out["bytes"] = _scan_entries(_active_dir)
+        import jax
+
+        out["max_size"] = jax.config.jax_compilation_cache_max_size
+    out["journal"] = journal_path()
+    out["records"] = records()
     return out
 
 
