@@ -38,6 +38,22 @@ row in a tile contributes ≥ 1 output, so the rows feeding outputs
 ``[t*T, (t+1)*T)`` span at most ``T`` consecutive compacted rows starting at
 ``row_start[t] = searchsorted(cum, t*T, 'right')``.
 
+What the pre-pass pays for
+--------------------------
+The kernel needs each left key's run in the sorted right keys
+(``low``, ``high``): two binary searches whose cost is left keys x trips
+(7-8 ns each on a v5e), which at a scan's template capacity was nearly all
+of a keyed lookup (PERF.md section 6, PR 39: 65,536 slots searched to place
+6-34 rows).  Where the caller passes a validity mask the searches run over
+the live extent of the left keys instead, in blocks of ``_SEARCH_BLOCK``
+under a traced trip count (:func:`_run_bounds`): a join pays for the keys it
+has, not for the slots its left side was compiled with, and the executable
+stays one a template.  Without a mask (``merge_join``,
+``ranked_merge_join_indices``) the extent is the static width: the same
+searches, every slot.  What is still paid at the compiled width: the
+stable ``argsort`` that compacts matched rows, the cumsum and the packed
+row table.
+
 Mosaic block constraints (and how the kernel scales past VMEM)
 --------------------------------------------------------------
 Mosaic requires output blocks with sublane dim a multiple of 8 — so each
@@ -83,6 +99,11 @@ _PALLAS_MAX_LEFT_ROWS = 393216
 # magnitude under the fault boundary.
 _CHUNK_OUT = 131072
 _CHUNK_ROWS = 256  # grid chunk height for elementwise kernels (128KB/col)
+# Left keys a trip of the prepass's run-bound searches covers where the
+# caller knows how far its left rows reach (_run_bounds).  Chosen by the gate
+# of PERF.md §6, PR 39, on a v5e: a block's fixed cost is small, so 1,024
+# ties 4,096 and 16,384 at 10,000 live rows and wins where 6-34 are live.
+_SEARCH_BLOCK = 1024
 
 
 def _interpret() -> bool:
@@ -240,9 +261,55 @@ def _merge_join_kernel(
         valid_out_ref[r, :] = valid[0, :]
 
 
-def _join_prepass(lkey_u, lval, rkey_u):
-    """Shared XLA pre-pass of both kernel drivers: searchsorted run bounds,
-    stable compaction of matched rows to the front, cumsum.  Returns
+def _run_bounds(lkey_u, rkey_u, extent):
+    """``(low, high)``: each left key's run in the sorted right keys.  With
+    an ``extent`` (a traced int32, one past the last left row that can
+    match) the searches cover the left keys in blocks of ``_SEARCH_BLOCK``
+    under a traced trip count and stop at the block that holds the extent:
+    rows past it keep ``low == high`` (no match), which is what their
+    sentinel key finds.  Without one the trip count is the static width:
+    one search over every slot."""
+
+    def search(keys):
+        return tuple(
+            jnp.searchsorted(rkey_u, keys, side=side).astype(jnp.int32)
+            for side in ("left", "right")
+        )
+
+    n = lkey_u.shape[0]
+    if extent is None:
+        return search(lkey_u)
+    block = min(_SEARCH_BLOCK, n)
+
+    def trip(i, bounds):
+        # a last block that would run off the end is clamped back by slice
+        # and update alike: its overlap is searched twice, to equal results
+        start = (i * jnp.int32(block),)
+        found = search(lax.dynamic_slice(lkey_u, start, (block,)))
+        return tuple(
+            lax.dynamic_update_slice(buf, x, start)
+            for buf, x in zip(bounds, found)
+        )
+
+    none = jnp.zeros(n, jnp.int32)
+    trips = lax.div(extent + jnp.int32(block - 1), jnp.int32(block))
+    return lax.fori_loop(jnp.int32(0), trips, trip, (none, none))
+
+
+def searched_keys(width: int, rows: Optional[int] = None) -> int:
+    """Left keys the run-bound searches of one prepass cover: the whole
+    ``width`` where the call gives no extent, else the blocks of
+    :func:`_run_bounds` up to the one that holds row ``rows``."""
+    if rows is None or not width:
+        return width
+    block = min(_SEARCH_BLOCK, width)
+    return -(-min(rows, width) // block) * block
+
+
+def _join_prepass(lkey_u, lval, rkey_u, extent=None):
+    """Shared XLA pre-pass of both kernel drivers: searchsorted run bounds
+    (over the left keys up to ``extent``, see :func:`_run_bounds`), stable
+    compaction of matched rows to the front, cumsum.  Returns
     ``(lkey_c, lval_c, low_c, cum, cumprev, total, total64)`` — the packed
     per-row columns (bitcast i32), the global output-offset prefix, the i32
     device total and the exact i64 match count."""
@@ -250,8 +317,7 @@ def _join_prepass(lkey_u, lval, rkey_u):
     def _bc(x):
         return lax.bitcast_convert_type(x.astype(jnp.uint32), jnp.int32)
 
-    low = jnp.searchsorted(rkey_u, lkey_u, side="left").astype(jnp.int32)
-    high = jnp.searchsorted(rkey_u, lkey_u, side="right").astype(jnp.int32)
+    low, high = _run_bounds(lkey_u, rkey_u, extent)
     counts = high - low
     with jax.enable_x64(True):
         total64 = jnp.sum(counts.astype(jnp.int64))
@@ -272,6 +338,7 @@ def _pallas_join_core(
     lval: jnp.ndarray,
     rkey_u: jnp.ndarray,
     cap: int,
+    extent: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Shared Pallas pipeline: returns ``(key, lval, pos, valid, total)``
     where ``pos`` is the matching RIGHT row index (int32) and outputs have
@@ -279,14 +346,15 @@ def _pallas_join_core(
     must be sorted ascending; ``lkey_u`` may be in any order (the merge-path
     partition runs over the cumsum of per-left-row match counts, which is
     monotone regardless of left key order).  ``total`` is an exact i64
-    match count.
+    match count.  ``extent`` bounds the prepass's searches
+    (:func:`_run_bounds`).
     """
     n_groups = max(1, -(-cap // (G * TILE)))
     n_tiles = n_groups * G
     cap = n_tiles * TILE
 
     lkey_c, lval_c, low_c, cum, cumprev, total, total64 = _join_prepass(
-        lkey_u, lval, rkey_u
+        lkey_u, lval, rkey_u, extent
     )
 
     # Merge-path partition: first compacted row feeding each output tile.
@@ -377,6 +445,7 @@ def _pallas_join_core_chunked(
     rkey_u: jnp.ndarray,
     cap: int,
     chunk_out: int,
+    extent: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Chunk-level merge-path driver: same tile kernel, bounded local windows.
 
@@ -405,7 +474,7 @@ def _pallas_join_core_chunked(
     l_win = nb_loc * BW  # local row window (covers chunk_out + 1 + W rows)
 
     lkey_c, lval_c, low_c, cum, cumprev, total, total64 = _join_prepass(
-        lkey_u, lval, rkey_u
+        lkey_u, lval, rkey_u, extent
     )
 
     # Packed table stays FLAT (the local slice is reshaped per chunk);
@@ -584,7 +653,9 @@ def merge_join_indices(
     binding columns afterwards.  ``rvalid_prefix`` must be a prefix mask
     (range-scan validity), which keeps the sentinel-masked right keys
     sorted; ``lvalid`` may have holes (left order is irrelevant — see
-    :func:`_pallas_join_core`).
+    :func:`_pallas_join_core`).  With ``lvalid`` the run-bound searches
+    stop at the block of the last live left row (:func:`_run_bounds`);
+    without it they cover every slot.
     """
     lkey_u = lkey.astype(jnp.uint32)
     rkey_u = rkey_sorted.astype(jnp.uint32)
@@ -598,6 +669,13 @@ def merge_join_indices(
     if ln == 0 or rn == 0:
         z = jnp.zeros(cap_r, jnp.int32)
         return z, z, jnp.zeros(cap_r, bool), jnp.int32(0)
+    # how far the left rows reach, where the caller says which are live: a
+    # scan's and a join's output are prefixes, so this is their row count
+    extent = None
+    if lvalid is not None:
+        extent = jnp.max(
+            jnp.where(lvalid, jnp.arange(1, ln + 1, dtype=jnp.int32), 0)
+        )
     if chunk_out is not None or ln > _PALLAS_MAX_LEFT_ROWS:
         if chunk_out is None and not pallas_chunked_enabled():
             from kolibrie_tpu.ops.device_join import join_indices_presorted
@@ -612,12 +690,13 @@ def merge_join_indices(
             rkey_u,
             cap_r,
             chunk_out or _CHUNK_OUT,
+            extent,
         )
         li_o, pos_o = li_o[:cap_r], pos_o[:cap_r]
         valid_o = valid_o[:cap_r]
     else:
         _, li_o, pos_o, valid_o, total = _pallas_join_core(
-            lkey_u, jnp.arange(ln, dtype=jnp.uint32), rkey_u, cap_r
+            lkey_u, jnp.arange(ln, dtype=jnp.uint32), rkey_u, cap_r, extent
         )
     li = lax.bitcast_convert_type(li_o, jnp.int32)
     li = jnp.where(valid_o, jnp.clip(li, 0, ln - 1), 0)

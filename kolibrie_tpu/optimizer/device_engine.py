@@ -1255,18 +1255,19 @@ def device_compile_stats() -> Dict[str, int]:
 # ---------------------------------------------------------------------------
 
 
-def _wcoj_specs(node):
-    """The ``WcojSpec`` nodes of a plan spec, in plan order."""
-    if isinstance(node, WcojSpec):
-        yield node
-    elif isinstance(node, (JoinSpec, AntiJoinSpec, LeftOuterSpec)):
-        yield from _wcoj_specs(node.left)
-        yield from _wcoj_specs(node.right)
+def _spec_nodes(node, kind):
+    """The nodes of class ``kind`` in a plan spec, in plan order (children
+    before the node that joins them)."""
+    if isinstance(node, (JoinSpec, AntiJoinSpec, LeftOuterSpec)):
+        yield from _spec_nodes(node.left, kind)
+        yield from _spec_nodes(node.right, kind)
     elif isinstance(node, (FilterSpec, QuotedExpandSpec)):
-        yield from _wcoj_specs(node.child)
+        yield from _spec_nodes(node.child, kind)
     elif isinstance(node, UnionSpec):
         for ch in node.children:
-            yield from _wcoj_specs(ch)
+            yield from _spec_nodes(ch, kind)
+    if isinstance(node, kind):
+        yield node
 
 
 class LoweredPlan:
@@ -2837,7 +2838,7 @@ class LoweredPlan:
         from kolibrie_tpu.query.template import note_range_searches
 
         forms = {"sorted": 0, "loop": 0}
-        for node in _wcoj_specs(self.root):
+        for node in _spec_nodes(self.root, WcojSpec):
             pcap = 1
             for lv in node.levels:
                 cap = self._join_caps[lv.join_idx]
@@ -2853,6 +2854,37 @@ class LoweredPlan:
                         forms[range_search_form(n, cap, nkeys + 1)] += 1
                 pcap = cap
         note_range_searches(members * forms["sorted"], members * forms["loop"])
+
+    def _note_join_searches(self, members) -> None:
+        """Count the run-bound searches of the dispatch just read back: for
+        each join that ran the Pallas prepass its left side's width, and the
+        keys its searches' blocks covered for the rows that side held: a
+        bare scan's range, a join's counted rows; the whole width where the
+        left child is neither or the join passes no validity.  ``members``:
+        one ``(scan_ranges, counts)`` a live member, numbers the host holds."""
+        from kolibrie_tpu.ops.pallas_kernels import (
+            pallas_enabled,
+            searched_keys,
+        )
+        from kolibrie_tpu.query.template import note_join_search_keys
+
+        if not pallas_enabled():
+            return
+        slots = searched = 0
+        for node in _spec_nodes(self.root, JoinSpec):
+            left = node.left
+            width = self._node_cap(left, self._scan_caps, self._join_caps)
+            slots += len(members) * width
+            for scan_ranges, counts in members:
+                rows = None
+                if not node.rsorted:
+                    pass  # ranked keys, no validity: every slot is searched
+                elif isinstance(left, ScanSpec):
+                    rows = int(scan_ranges[left.scan_idx, 1::2].sum())
+                elif isinstance(left, JoinSpec):
+                    rows = int(counts[left.join_idx])
+                searched += searched_keys(width, rows)
+        note_join_search_keys(slots, searched)
 
     def _store_caps(self) -> None:
         """Publish join capacities to the per-db template cache.  Merge is
@@ -2899,6 +2931,7 @@ class LoweredPlan:
             note_cap_occupancy("device", sum(self._join_caps), sum(counts_h))
             self._note_scan_tiers()
             self._note_range_searches()
+            self._note_join_searches([(self._scan_ranges_np, counts_h)])
             overflow = [
                 i for i, c in enumerate(counts_h) if c > self._join_caps[i]
             ]
@@ -2946,7 +2979,7 @@ class LoweredPlan:
         """Per-level WCOJ instrumentation from the converged host-read
         counts: intermediate rows, cap occupancy, probe volume."""
 
-        for node in _wcoj_specs(self.root):
+        for node in _spec_nodes(self.root, WcojSpec):
             for lv in node.levels:
                 if lv.join_idx >= len(counts_h):
                     continue
@@ -3595,6 +3628,12 @@ def _converge_plan_batch(members: List[LoweredPlan], fp: str, max_attempts: int)
         )
         lp0._note_scan_tiers(n)
         lp0._note_range_searches(n)
+        lp0._note_join_searches(
+            [
+                (lp._scan_ranges_np, [c[m] for c in counts_b])
+                for m, lp in enumerate(members)
+            ]
+        )
         over = [j for j, c in enumerate(maxc) if c > caps[j]]
         if not over:
             break

@@ -307,6 +307,28 @@ def note_range_searches(sorted_: int, loop: int) -> None:
     _RANGE_SEARCH.labels("loop").inc(loop)
 
 
+# what a merge join's run-bound searches were spared: per dispatch and join
+# that runs the Pallas prepass, its left side's compiled width and the keys
+# its blocks covered (ops/pallas_kernels.py _run_bounds), both from row
+# counts the host already holds
+_JOIN_SEARCH_KEYS = metrics.counter(
+    "kolibrie_join_search_keys_total",
+    "left keys of the merge joins' run-bound searches, summed over "
+    "dispatches: the slots the left sides were compiled with (slots) and "
+    "the keys the searches' blocks covered (searched)",
+    labels=("what",),
+)
+_JOIN_SEARCH_KEYS.labels("slots")
+_JOIN_SEARCH_KEYS.labels("searched")
+
+
+def note_join_search_keys(slots: int, searched: int) -> None:
+    """One dispatch's prepass joins were ``slots`` left slots wide and
+    searched ``searched`` of their keys."""
+    _JOIN_SEARCH_KEYS.labels("slots").inc(slots)
+    _JOIN_SEARCH_KEYS.labels("searched").inc(searched)
+
+
 def cap_advisor_enabled() -> bool:
     """``KOLIBRIE_CAP_ADVISOR=off`` (or ``0``) disables advice — retries
     fall back to the pre-advisor heuristics.  Observation continues either

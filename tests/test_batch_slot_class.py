@@ -245,6 +245,48 @@ def test_a_padded_slot_joins_nothing_and_is_not_read_back(lubm):
     assert collect["attrs"]["members"] == 3
 
 
+def _join_search_keys():
+    from kolibrie_tpu.query.template import _JOIN_SEARCH_KEYS
+
+    return {w: _JOIN_SEARCH_KEYS.labels(w).value for w in ("slots", "searched")}
+
+
+def test_three_members_through_the_blocked_prepass_answer_as_the_twin(
+        lubm, monkeypatch):
+    """ISSUE 39: under the Pallas join (interpreted here) a member's merge
+    joins search their live left keys in blocks under a traced trip count,
+    inside the live-member loop's traced trip count; every member's rows
+    are the numpy twin's and the reference's, and the dispatch counts each
+    member's prepass joins: their left widths, and the blocks their rows
+    reach (one block each: Q7's lefts hold 2-60 rows)."""
+    from kolibrie_tpu.ops.pallas_kernels import _SEARCH_BLOCK as B
+    from kolibrie_tpu.ops.pallas_kernels import pallas_enabled
+
+    monkeypatch.setenv("KOLIBRIE_PALLAS", "force")
+    assert pallas_enabled()
+    db, ref = lubm["db"], lubm["ref"]
+    texts = lubm["texts"][4:7]
+    members = [_lowered(db, t) for t in texts]
+    before = _join_search_keys()
+    tables = de.execute_plan_batch([low for _, low in members])
+    grew = {w: v - before[w] for w, v in _join_search_keys().items()}
+    low0 = members[0][1]
+    joins = list(de._spec_nodes(low0.root, de.JoinSpec))
+    assert len(joins) == 3 and all(j.rsorted for j in joins)
+    widths = [low0._node_cap(j.left, low0._scan_caps, low0._join_caps)
+              for j in joins]
+    # the scan of a department's professors holds a few rows: one block; the
+    # two join outputs are as wide as a block or narrower
+    assert widths[0] >= B >= widths[1] == widths[2]
+    assert grew == {"slots": 3 * sum(widths),
+                    "searched": 3 * (B + widths[1] + widths[2])}
+    for text, (q, low), table in zip(texts, members, tables):
+        twin = _lowered(db, text)[1].host_execute()[0]
+        assert _id_rows(table) == _id_rows(twin)
+        rows = executor._finish_select_table(db, q, table)
+        assert rows and _multiset(rows) == _multiset(ref.query(text))
+
+
 EX = "http://example.org/"
 
 
@@ -263,6 +305,33 @@ def _hub_db(spokes=3000):
     db.parse_ntriples("\n".join(lines))
     db.execution_mode = "device"
     return db
+
+
+def test_a_dispatch_counts_its_prepass_joins_slots_and_searched_keys(monkeypatch):
+    """ISSUE 39: ``kolibrie_join_search_keys_total``: a join whose left scan
+    holds 2 rows in 8,192 slots searches one block of its keys; the hub's
+    6,000 rows take the blocks that hold them, in the overflowing dispatch
+    and in its re-run; with the XLA join (no prepass) a dispatch adds nothing."""
+    from kolibrie_tpu.ops.pallas_kernels import _SEARCH_BLOCK as B
+
+    assert 8192 % B == 0  # the left scan is 8,192 slots wide
+    db = _hub_db(6000)
+
+    def grown(k):
+        low = _lowered(db, (
+            f"PREFIX ex: <{EX}>\n"
+            f"SELECT ?b ?c WHERE {{ ex:n{k} ex:p1 ?b . ?b ex:p2 ?c }}"))[1]
+        before = _join_search_keys()
+        table = low.execute()
+        assert _id_rows(table) == _id_rows(low.host_execute()[0])
+        return len(table["b"]), {
+            w: v - before[w] for w, v in _join_search_keys().items()}
+
+    assert grown(1) == (2, {"slots": 0, "searched": 0})
+    monkeypatch.setenv("KOLIBRIE_PALLAS", "force")
+    assert grown(1) == (2, {"slots": 8192, "searched": B})
+    assert grown(0) == (6000, {"slots": 2 * 8192,
+                               "searched": 2 * -(-6000 // B) * B})
 
 
 def test_an_overflow_in_one_member_reruns_the_group_once_for_every_size():

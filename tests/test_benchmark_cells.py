@@ -5,7 +5,8 @@ the clients of ``mesh_q7`` never share a constant (in ``lubm5.mesh4`` and in
 ``lubm5.mesh4`` is in with the entries PR 27 wrote for it, ``lubm5.batch8``
 as ISSUE 32 states it and ``lubm50.triangles`` (``lubm-50``, nothing cut) as
 ISSUE 34 does, ISSUE 35's three range-search metrics are data files for the
-two triangles cells, every file a cell or a
+two triangles cells, ``lubm50.lookups`` (``lookups`` against ``lubm-50``) and
+the two join-search metrics are in as ISSUE 39 states them, every file a cell or a
 per-layer metric names is there, a program that lacks what a cell
 requires of it (``benchmark/requires``) is refused before anything starts,
 and ``run.py`` itself, started off the chip, prints no result and exits 3
@@ -149,9 +150,10 @@ def test_benchmark_json_has_the_one_chip_cell_of_eight_clients():
             layer, "cycle_ms", ["lubm5.batch8"])
         assert files.read_json("layer_metrics", name + ".json")["reader"][
             "kind"] == kind
-    # no standing metric's list was edited to take the cell in
+    # no standing metric's list was edited to take the cell in (ISSUE 39's
+    # two came later, with the cell in the list they were born with)
     for m in BENCH["per_layer"]:
-        if m["name"] not in BATCH8_METRICS:
+        if m["name"] not in BATCH8_METRICS and m["name"] not in JOIN_SEARCH_METRICS:
             assert "lubm5.batch8" not in m.get("workloads", [])
 
 
@@ -170,7 +172,7 @@ def test_benchmark_json_has_lubm_50_uncut_and_its_cell():
     cell = CELLS["lubm50.triangles"]
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "lubm-50", "triangles", 1)
-    assert BENCH["workloads"][-1] == cell  # appended, nothing before it moved
+    assert BENCH["workloads"][5] == cell  # appended, nothing before it moved
     entry = BENCH["configs"][-1]
     assert (entry["name"], entry["file"], entry["reduced"]) == (
         "lubm-50", "benchmark/configs/lubm-50.json", [])
@@ -206,6 +208,8 @@ def test_benchmark_json_has_lubm_50_uncut_and_its_cell():
     for m in BENCH["per_layer"] + BENCH["end_to_end"]:
         if m["name"] not in LUBM50_METRICS and m["name"] not in RANGE_SEARCH_METRICS:
             assert "lubm50.triangles" not in m.get("workloads", [])
+        if m["name"] not in JOIN_SEARCH_METRICS:
+            assert "lubm50.lookups" not in m.get("workloads", [])
     reported = {m["name"] for m in BENCH["end_to_end"] if "workloads" not in m}
     assert reported == {"cycle_ms", "setup_s"}
 
@@ -267,7 +271,7 @@ def test_the_range_search_metrics_are_the_last_entries_and_data_alone():
         assert reader == {"kind": kind, **args}
         assert os.path.exists(files.path("readers", kind + ".py"))
     # no cell came with them, and no other list took a triangles cell in
-    assert [w["name"] for w in BENCH["workloads"]][-1] == "lubm50.triangles"
+    assert [w["name"] for w in BENCH["workloads"]][5] == "lubm50.triangles"
     added_files = {name + ".json" for name in RANGE_SEARCH_METRICS}
     assert added_files <= set(os.listdir(files.path("layer_metrics")))
 
@@ -304,6 +308,74 @@ def test_a_range_search_metric_reads_its_source_and_nothing_of_a_program_without
     assert reader.read(lacking, **args) is None
 
 
+JOIN_SEARCH_METRICS = {
+    # name: the label of kolibrie_join_search_keys_total it reads
+    "join_search_slots_in_window": "slots",
+    "join_search_keys_in_window": "searched",
+}
+JOIN_SEARCH_CELLS = ["lubm5.lookups", "employee100k.upstream", "lubm5.batch8",
+                     "lubm50.lookups"]
+
+
+def test_benchmark_json_has_the_lookups_against_lubm_50_as_its_last_cell():
+    """ISSUE 39: ``lookups`` as it stands against ``lubm-50`` as it stands,
+    one chip, a data file beside the others; two per-layer entries appended,
+    each a data file of a reader that was there; no standing list took the
+    cell in, so it reports ``cycle_ms``, ``setup_s`` and what has no list."""
+    cell = CELLS["lubm50.lookups"]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "lubm-50", "lookups", 1)
+    assert BENCH["workloads"][-1] == cell and len(cell["why"]) <= 200
+    assert files.read_json("workloads", "lubm50.lookups.json") == {"env": {}}
+    assert CELLS["lubm5.lookups"]["traffic"] == cell["traffic"]
+    assert CELLS["lubm50.triangles"]["config"] == cell["config"]
+    assert [c["name"] for c in BENCH["configs"]][-1] == "lubm-50"  # no new one
+    traffic = files.read_json("traffic", "lookups.json")
+    assert (traffic["loop"], traffic["clients"], traffic["warmup_cycles"],
+            traffic["deadline_ms"]) == ("closed", 1, 5, 900000)
+    assert [step["template"] for step in traffic["cycle"]] == [
+        "lubm_q1", "lubm_q3", "lubm_q4", "lubm_q7", "lubm_q8"]
+    added = BENCH["per_layer"][-len(JOIN_SEARCH_METRICS):]
+    assert [m["name"] for m in added] == list(JOIN_SEARCH_METRICS)
+    for m in added:
+        assert m == {"name": m["name"], "unit": "count", "better": "lower",
+                     "source": "program_counter", "layer": "kernels and XLA ops",
+                     "moves": "cycle_ms", "workloads": JOIN_SEARCH_CELLS}
+        reader = files.read_json("layer_metrics", m["name"] + ".json")["reader"]
+        assert reader == {
+            "kind": "counter_delta",
+            "prefix": 'metrics.kolibrie_join_search_keys_total{what="%s"}'
+                      % JOIN_SEARCH_METRICS[m["name"]],
+            "beside": "metrics.kolibrie_join_search_keys_total"}
+        assert os.path.exists(files.path("readers", "counter_delta.py"))
+    for m in BENCH["per_layer"] + BENCH["end_to_end"]:
+        if m["name"] not in JOIN_SEARCH_METRICS:
+            assert "lubm50.lookups" not in m.get("workloads", [])
+
+
+@pytest.mark.parametrize("name", sorted(JOIN_SEARCH_METRICS))
+def test_a_join_search_metric_reads_its_label_and_nothing_of_a_program_without_it(name):
+    """The readers run on the parent's checkout too: a program without the
+    family reports neither count and nothing raises; the program registers
+    both labels at import, so each has a line from the start."""
+    from kolibrie_tpu.obs import export, metrics
+    from kolibrie_tpu.query import template  # noqa: F401  (registers the family)
+
+    args = dict(files.read_json("layer_metrics", name + ".json")["reader"])
+    reader = files.load_module("readers", args.pop("kind"))
+    family = args["beside"][len("metrics."):]
+    assert metrics.REGISTRY.get(family) is not None
+    assert args["prefix"][len("metrics."):] + " " in export.render_prometheus()
+    with open(os.path.join(REPO, "docs", "OBSERVABILITY.md"), encoding="utf-8") as f:
+        assert f"`{family}`" in f.read()
+    other = args["beside"] + ('{what="searched"}' if "slots" in name else '{what="slots"}')
+    there = {"counters0": {args["prefix"]: 65536.0, other: 7.0},
+             "counters1": {args["prefix"]: 196608.0, other: 9.0}}
+    assert reader.read(there, **args) == pytest.approx(131072.0)
+    lacking = {key: {"metrics.kolibrie_device_join_rows_total": 1.0} for key in there}
+    assert reader.read(lacking, **args) is None
+
+
 @pytest.mark.parametrize("workload", sorted(CELLS))
 def test_every_file_a_cell_names_is_there(workload):
     cell = CELLS[workload]
@@ -332,7 +404,7 @@ def test_every_per_layer_metric_has_its_file_and_its_reader():
 
 def test_at_most_half_the_cells_take_four_chips():
     four = [w["name"] for w in BENCH["workloads"] if w["chips"] == 4]
-    assert four == ["lubm5.mesh4"] and len(CELLS) == 6
+    assert four == ["lubm5.mesh4"] and len(CELLS) == 7
     assert len(four) <= max(1, len(CELLS) // 2)
     assert json.dumps(BENCH).count('"chips": 4') == 1
 

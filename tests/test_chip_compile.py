@@ -112,6 +112,20 @@ def test_merge_join_128k(one_chip, x64):
                  cap=K128)
 
 
+def test_merge_join_blocked_searches_64k(one_chip):
+    """With validity masks, as ``_plan_body`` calls it: the run-bound
+    searches are a loop of blocks under a traced trip count (ISSUE 39)."""
+    n = 65536
+
+    def masked(lkey, rkey, lvalid, rvalid):
+        return pk.merge_join_indices(lkey, rkey, n, lvalid, rvalid)
+
+    with jax.enable_x64(True):
+        compiled = _compile(jax.jit(masked), one_chip, _u32(n), _u32(4 * n),
+                            _bool(n), _bool(4 * n))
+    assert "while" in compiled.as_text()
+
+
 def test_merge_join_single_launch_limit(one_chip):
     n = pk._PALLAS_MAX_LEFT_ROWS
     _compile(pk.merge_join_indices, one_chip, _u32(n), _u32(n), cap=n)

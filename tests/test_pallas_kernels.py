@@ -217,6 +217,112 @@ class TestChunkedMergeJoin:
         assert tot == exp
 
 
+def _oracle_indices(lk, rk, lvalid, rvalid, cap):
+    """numpy twin of ``merge_join_indices``: rows with a match first, in
+    left order, each followed by its run of right rows; the exact total."""
+    lk = np.where(lvalid, lk, np.uint32(0xFFFFFFFE))
+    rk = np.where(rvalid, rk, np.uint32(0xFFFFFFFF))
+    low = np.searchsorted(rk, lk, side="left")
+    counts = np.searchsorted(rk, lk, side="right") - low
+    rows = np.flatnonzero(counts)
+    li = np.repeat(rows, counts[rows])
+    ri = np.repeat(low[rows] - (np.cumsum(counts[rows]) - counts[rows]), counts[rows])
+    ri = ri + np.arange(len(li))
+    total = int(counts.sum())
+    cap_r = -(-cap // 1024) * 1024
+    out = np.zeros((2, cap_r), np.int32)
+    n = min(total, cap_r)
+    out[0, :n], out[1, :n] = li[:n], ri[:n]
+    return out[0], out[1], np.arange(cap_r) < total, total
+
+
+def _blocked_case(name):
+    """``(lk, rk, lvalid, rvalid, cap, chunk_out)`` of one case of
+    :func:`test_blocked_prepass_returns_the_unblocked_rows`."""
+    from kolibrie_tpu.ops.pallas_kernels import _SEARCH_BLOCK as B
+
+    rng = np.random.default_rng(39)
+    P, N = 2 * B + 1808, 3000  # the last block is clamped back into the width
+    rk = np.sort(rng.integers(0, 500, N).astype(np.uint32))
+    rvalid = np.arange(N) < N - 200
+    lk = rng.integers(0, 600, P).astype(np.uint32)
+    extents = {"extent_0": 0, "extent_1": 1, "extent_B-1": B - 1,
+               "extent_B": B, "extent_B+1": B + 1, "extent_full": P}
+    cap, chunk_out = 65536, None
+    if name in extents:
+        lvalid = np.arange(P) < extents[name]
+    elif name == "holes":
+        lvalid = (np.arange(P) < B + 900) & (rng.random(P) < 0.5)
+    elif name == "every_row_invalid":
+        lk, lvalid = lk[:1000], np.zeros(1000, bool)
+    elif name == "narrower_than_a_block":
+        lk, lvalid = lk[:1000], np.arange(1000) < 37
+    elif name == "runs_cross_a_block_edge":
+        # one left key over rows B-3 .. B+3, matching a run of 40 right rows
+        rk[1000:1040] = rk[1000]
+        rk = np.sort(rk)
+        lk[B - 3:B + 4] = rk[1000]
+        lvalid = np.arange(P) < B + 4
+    elif name == "largest_live_right_key":
+        lk[5] = lk[B] = rk[N - 201]  # the last live right row's key
+        lvalid = np.arange(P) < B + 1
+    elif name == "chunked_driver":
+        lvalid, cap, chunk_out = np.arange(P) < B + 77, 8192, 1024
+    elif name == "no_lvalid":
+        lvalid = None
+    else:
+        raise KeyError(name)
+    return lk, rk, lvalid, rvalid, cap, chunk_out
+
+
+@pytest.mark.parametrize("name", [
+    "extent_0", "extent_1", "extent_B-1", "extent_B", "extent_B+1",
+    "extent_full", "holes", "every_row_invalid", "narrower_than_a_block",
+    "runs_cross_a_block_edge", "largest_live_right_key", "chunked_driver",
+    "no_lvalid",
+])
+def test_blocked_prepass_returns_the_unblocked_rows(name):
+    """ISSUE 39: with a validity mask the run-bound searches stop at the
+    block of the last live left row; ``(li, ri, valid, total)`` stay what
+    the unblocked searches over every slot give, bit for bit, and what a
+    numpy twin gives.  Without a mask the call is the unblocked form."""
+    import jax
+    from kolibrie_tpu.ops.pallas_kernels import merge_join_indices
+
+    lk, rk, lvalid, rvalid, cap, chunk_out = _blocked_case(name)
+    dev = lambda x: None if x is None else jnp.asarray(x)
+    with jax.enable_x64(True):
+        got = merge_join_indices(
+            dev(lk), dev(rk), cap, dev(lvalid), dev(rvalid), chunk_out=chunk_out
+        )
+        everywhere = np.ones(len(lk), bool) if lvalid is None else lvalid
+        unblocked = merge_join_indices(
+            dev(np.where(everywhere, lk, np.uint32(0xFFFFFFFE))),
+            dev(np.where(rvalid, rk, np.uint32(0xFFFFFFFF))),
+            cap,
+            chunk_out=chunk_out,
+        )
+    want = _oracle_indices(lk, rk, everywhere, rvalid, cap)
+    assert want[3] > 0 or name in ("extent_0", "every_row_invalid")
+    for g, u, w in zip(got, unblocked, want):
+        assert g.dtype == u.dtype
+        assert np.array_equal(np.asarray(g), np.asarray(u))
+        assert np.array_equal(np.asarray(g), w)
+
+
+def test_searched_keys_counts_the_blocks_the_searches_cover():
+    from kolibrie_tpu.ops.pallas_kernels import _SEARCH_BLOCK as B, searched_keys
+
+    assert searched_keys(65536) == 65536  # no extent: every slot
+    assert searched_keys(65536, 0) == 0
+    assert searched_keys(65536, 6) == B
+    assert searched_keys(65536, B) == B
+    assert searched_keys(65536, B + 1) == 2 * B
+    assert searched_keys(65536, 10 ** 6) == 65536  # rows past the width
+    assert searched_keys(B // 4, 3) == B // 4  # a side narrower than a block
+    assert searched_keys(0, 0) == 0
+
+
 class TestFilterMask:
     def test_pattern_and_range(self):
         rng = np.random.default_rng(3)

@@ -533,3 +533,23 @@ def test_a_group_of_two_wcoj_members_builds_one_executable(monkeypatch):
     # the same group again: nothing compiles
     de.execute_plan_batch([_lower(db, t) for t in texts])
     assert de.device_compile_stats()["run_plan_batch"] - programs0 == 1
+
+
+def test_a_wcoj_plan_adds_nothing_to_the_join_search_counter(monkeypatch):
+    """ISSUE 39: ``kolibrie_join_search_keys_total`` counts the merge joins
+    that run the Pallas prepass; a plan that is one ``WcojSpec`` has none,
+    with the kernels on as well."""
+    from kolibrie_tpu.optimizer import device_engine as de
+    from kolibrie_tpu.query.template import _JOIN_SEARCH_KEYS
+
+    monkeypatch.setenv("KOLIBRIE_WCOJ", "force")
+    monkeypatch.setenv("KOLIBRIE_PALLAS", "force")
+    db = _typed_db()
+    low = _at_capacity(db, PREFIX + SHAPES["q9"].replace("@A@", "A"), LOOP_CAP)
+    assert not list(de._spec_nodes(low.root, de.JoinSpec))
+    before = [_JOIN_SEARCH_KEYS.labels(w).value for w in ("slots", "searched")]
+    searches0 = _searches()
+    assert _id_rows(low.execute()) == _id_rows(low.host_execute()[0])
+    assert _searches() != searches0  # the dispatch was counted
+    assert [_JOIN_SEARCH_KEYS.labels(w).value
+            for w in ("slots", "searched")] == before
