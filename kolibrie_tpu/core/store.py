@@ -325,6 +325,10 @@ class SortedOrder:
         return len(self.c0)
 
     def range0(self, v0: int) -> Tuple[int, int]:
+        # the key in the column's own dtype: numpy promotes a uint32 column
+        # searched with a Python int to int64, a copy of every row a call
+        # (72 ms over 7.9 M rows on a v5e's host: PERF.md section 6, PR 39)
+        v0 = _key_like(self.c0, v0)
         lo = int(np.searchsorted(self.c0, v0, side="left"))
         hi = int(np.searchsorted(self.c0, v0, side="right"))
         return lo, hi
@@ -338,6 +342,7 @@ class SortedOrder:
     def range012(self, v0: int, v1: int, v2: int) -> Tuple[int, int]:
         lo, hi = self.range01(v0, v1)
         sub = self.c2[lo:hi]
+        v2 = _key_like(sub, v2)
         l2 = int(np.searchsorted(sub, v2, side="left"))
         h2 = int(np.searchsorted(sub, v2, side="right"))
         return lo + l2, lo + h2
@@ -349,6 +354,13 @@ class SortedOrder:
             self.perm[1]: self.c1[lo:hi],
             self.perm[2]: self.c2[lo:hi],
         }
+
+
+def _key_like(col: np.ndarray, v):
+    """``v`` in ``col``'s integer dtype where it fits (an id that no term
+    has, -1, stays what it is and finds an empty range as before)."""
+    info = np.iinfo(col.dtype)
+    return col.dtype.type(v) if info.min <= v <= info.max else v
 
 
 def _updated_order(so: SortedOrder, ins_cols, del_cols) -> SortedOrder:

@@ -31,6 +31,10 @@ class _NoPattern:
 _NO_PATTERN = _NoPattern()
 
 
+def _index_scan_cost(rows: float, bound: int) -> float:
+    return max(rows * INDEX_SCAN_COST_PER_ROW / (BOUND_POSITION_DISCOUNT**bound), 0.1)
+
+
 class CostEstimator:
     """``learned`` is an optional advisor snapshot — operator-key →
     measured rows for the template being planned
@@ -143,6 +147,21 @@ class CostEstimator:
 
     # ---------------------------------------------------------------- costs
 
+    def ordering_cost(self, leaf) -> float:
+        """A join leaf's cost to the planner's greedy ordering.  Planning
+        reruns per constant binding while a template has one executable,
+        sized for its hottest instance (``LoweredPlan._calibration_counts``):
+        so a scan that binds its predicate and a subject or an object is
+        ordered by what the hottest key under that predicate holds, not by
+        the rows of the constant at hand, and every instance of a text gets
+        one order, whichever came first (docs/COMPILE_CACHE.md).  Any other
+        leaf at its estimated cost."""
+        pattern = getattr(leaf, "pattern", None)
+        rows = None if pattern is None else self.stats.hottest_key_rows(pattern)
+        if rows is None:
+            return self.estimate_cost(leaf)
+        return _index_scan_cost(rows, 2)
+
     def estimate_cost(self, op) -> float:
         if isinstance(op, P.PhysTableScan):
             return self.stats.total_triples * TABLE_SCAN_COST_PER_ROW
@@ -153,10 +172,7 @@ class CostEstimator:
                 if t.kind == "id"
             )
             rows = self.stats.pattern_cardinality(op.pattern)
-            return max(
-                rows * INDEX_SCAN_COST_PER_ROW / (BOUND_POSITION_DISCOUNT**bound),
-                0.1,
-            )
+            return _index_scan_cost(rows, bound)
         if isinstance(op, (P.PhysHashJoin, P.PhysMergeJoin)):
             cl, cr = self.cardinality(op.left), self.cardinality(op.right)
             child_cost = self.estimate_cost(op.left) + self.estimate_cost(op.right)

@@ -696,49 +696,93 @@ def _plan_body(
                 )
 
             def two_tier():
-                src_b = jnp.clip(lo_b + ar, 0, bcols[0].shape[0] - 1)
+                # Every output slot finds its source row (a gather); the
+                # delta rows and the tombstones, of which there are few,
+                # are the tables searched.  Scattering the window's rows to
+                # their ranks instead cost a sort a column at a wide scan
+                # (what the TPU compiler makes of a scatter past a million
+                # slots): three quarters of a template's compile time and
+                # of its executable's bytes, in a branch that a store
+                # without writes never takes (PERF.md section 6, PR 40).
                 ard = jnp.arange(dcap, dtype=jnp.int32)
                 src_d = jnp.clip(lo_d + ard, 0, dcap - 1)
                 ind = ard < n_d
-                # tombstone check: sorted membership of the base ROW
-                # POSITION (one u32 word) instead of matching a 96-bit triple
-                sbu = src_b.astype(jnp.uint32)
-                jd = jnp.clip(jnp.searchsorted(del_pos, sbu), 0, dcap - 1)
-                is_del = (del_pos[jd] == sbu) & inb
-                bvalid = inb & ~is_del
                 k0, k1 = node.key_pos
                 sent = jnp.uint64(0xFFFFFFFFFFFFFFFF)
-                bkey = (
-                    bcols[k0][src_b].astype(jnp.uint64) << jnp.uint64(32)
-                ) | (bcols[k1][src_b].astype(jnp.uint64))
-                # deleted rows KEEP their real key (preserves sortedness and
-                # the rank arithmetic); only rows beyond the window go
-                # sentinel
-                bkey = jnp.where(inb, bkey, sent)
-                dkey = (
-                    dcols[k0][src_d].astype(jnp.uint64) << jnp.uint64(32)
-                ) | (dcols[k1][src_d].astype(jnp.uint64))
-                dkey = jnp.where(ind, dkey, sent)
-                pos_b = (jnp.cumsum(bvalid.astype(jnp.int32)) - 1) + (
-                    jnp.searchsorted(dkey, bkey, side="left").astype(jnp.int32)
+
+                def packed(c0, c1, live):
+                    key = (c0.astype(jnp.uint64) << jnp.uint64(32)) | c1.astype(
+                        jnp.uint64
+                    )
+                    return jnp.where(live, key, sent)
+
+                # deleted rows KEEP their real key (the window stays
+                # sorted); only rows beyond the window go sentinel
+                bkey = packed(
+                    _base_window(bcols[k0], lo_b, cap),
+                    _base_window(bcols[k1], lo_b, cap),
+                    inb,
                 )
-                cdel = jnp.concatenate(
-                    [jnp.zeros(1, jnp.int32), jnp.cumsum(is_del.astype(jnp.int32))]
-                )
+                dkey = packed(dcols[k0][src_d], dcols[k1][src_d], ind)
+
+                def dead_before(pos):
+                    # tombstones (sorted base ROW POSITIONS, one u32 word)
+                    # at positions under ``pos``
+                    return jnp.searchsorted(
+                        del_pos, pos.astype(jnp.uint32), side="left"
+                    ).astype(jnp.int32)
+
+                t0 = dead_before(lo_b)
+                n_del = dead_before(lo_b + jnp.minimum(n_b, cap)) - t0
+                # a delta row's slot: the delta rows before it and the live
+                # base rows whose key is at most its own (base before delta
+                # on key ties)
                 ib = jnp.searchsorted(bkey, dkey, side="right").astype(jnp.int32)
-                pos_d = ard + ib - cdel[ib]
-                dst_b = jnp.where(bvalid, pos_b, cap)
-                dst_d = jnp.where(ind, pos_d, cap)
+                far = jnp.int32(np.iinfo(np.int32).max)
+                pos_d = jnp.where(
+                    ind, ard + ib - (dead_before(lo_b + ib) - t0), far
+                )
+                # a slot's source: the delta row whose slot it is, else the
+                # live base row of rank ``slot - delta rows before it``
+                k = jnp.searchsorted(pos_d, ar, side="right").astype(jnp.int32)
+                kd = jnp.clip(k - 1, 0, dcap - 1)
+                from_delta = (k > 0) & (pos_d[kd] == ar)
+                rank = ar - k
+                # live rows of the window before its t-th tombstone; the row
+                # of rank m lies past the tombstones that have at most m
+                in_win = (ard >= t0) & (ard < t0 + n_del)
+                live_before = jnp.where(
+                    ard < t0,
+                    -1,
+                    jnp.where(
+                        in_win,
+                        (del_pos - lo_b.astype(jnp.uint32)).astype(jnp.int32)
+                        - (ard - t0),
+                        far,
+                    ),
+                )
+                dead = (
+                    jnp.searchsorted(live_before, rank, side="right").astype(
+                        jnp.int32
+                    )
+                    - t0
+                )
+                src_b = jnp.clip(lo_b + rank + dead, 0, bcols[0].shape[0] - 1)
+                row_d = jnp.clip(lo_d + kd, 0, dcap - 1)
+                n_out = (n_b - n_del) + n_d
+                live = ar < n_out
                 return (
                     tuple(
-                        jnp.zeros(cap, dtype=jnp.uint32)
-                        .at[dst_b]
-                        .set(bcols[pos][src_b], mode="drop")
-                        .at[dst_d]
-                        .set(dcols[pos][src_d], mode="drop")
+                        jnp.where(
+                            live,
+                            jnp.where(
+                                from_delta, dcols[pos][row_d], bcols[pos][src_b]
+                            ),
+                            0,
+                        )
                         for pos in need
                     ),
-                    (n_b - cdel[-1]) + n_d,
+                    n_out,
                 )
 
             merged, n_live = lax.cond(
@@ -2293,20 +2337,56 @@ class LoweredPlan:
         return caps
 
     def _calibration_counts(self) -> Optional[List[int]]:
-        """Exact per-join counts of this variant from the numpy twin (no
-        device I/O), or ``None`` where an intermediate would pass
-        ``_CALIBRATE_ROW_LIMIT`` rows."""
+        """Exact per-join counts from the numpy twin (no device I/O): those
+        of this variant and, for each scan that binds its predicate and a
+        subject or an object, the most rows any one key of that scan gives
+        each join with the other constants as they are
+        (:meth:`host_execute`'s ``free_scan``), the larger of them join by
+        join.  So the template starts where its hottest instance in each
+        placeholder takes it, whichever instance came first.  ``None`` where
+        an intermediate of this variant would pass ``_CALIBRATE_ROW_LIMIT``
+        rows; a hot pass that would is left out (the overflow protocol keeps
+        what it exceeds exact)."""
         from kolibrie_tpu.ops.join import RowLimitExceeded
         from kolibrie_tpu.query.template import cap_calibrate_seconds
 
-        t0 = _time.perf_counter()
-        try:
-            _table, counts = self.host_execute(row_limit=_CALIBRATE_ROW_LIMIT)
-            outcome = "counted"
-        except RowLimitExceeded:
-            counts, outcome = None, "too_large"
-        cap_calibrate_seconds.labels(outcome).inc(_time.perf_counter() - t0)
+        def timed(outcome, free_scan=None):
+            t0 = _time.perf_counter()
+            try:
+                return self.host_execute(_CALIBRATE_ROW_LIMIT, free_scan)[1]
+            except RowLimitExceeded:
+                outcome = "too_large"
+                return None
+            finally:
+                cap_calibrate_seconds.labels(outcome).inc(
+                    _time.perf_counter() - t0
+                )
+
+        with _obs_span("device.calibrate") as sp:
+            counts = timed("counted")
+            keyed = self._keyed_scans() if counts is not None else []
+            if keyed:
+                own_stats = self.last_host_stats  # EXPLAIN's, not a pass's
+                for scan_idx in keyed:
+                    hot = timed("hot_key", scan_idx)
+                    if hot is not None:
+                        counts = [max(a, b) for a, b in zip(counts, hot)]
+                self.last_host_stats = own_stats
+            if sp is not None:
+                sp.attrs["hot_passes"] = len(keyed)
         return counts
+
+    def _keyed_scans(self) -> List[int]:
+        """The scans that bind their predicate and one of subject and
+        object, both known to the dictionary: where a template's instances
+        differ in rows by the key."""
+        return [
+            i
+            for i, (_order, consts) in enumerate(self.scan_descs)
+            if consts[1] is not None
+            and sum(c is not None for c in consts) == 2
+            and min(c for c in consts if c is not None) >= 0
+        ]
 
     def _template_scan_caps(self) -> Dict[int, int]:
         """Scan capacities are a TEMPLATE property: the largest key-group
@@ -2410,7 +2490,9 @@ class LoweredPlan:
     # ------------------------------------------------------- host evaluation
 
     def host_execute(
-        self, row_limit: Optional[int] = None
+        self,
+        row_limit: Optional[int] = None,
+        free_scan: Optional[int] = None,
     ) -> Tuple[BindingTable, List[int]]:
         """Evaluate the lowered IR with numpy — the executable-free reference
         semantics.  Returns (table, exact join counts).  Used to calibrate
@@ -2418,7 +2500,12 @@ class LoweredPlan:
         spec-semantics tests.
         With ``row_limit`` a scan, join or WCOJ level of more rows raises
         :class:`kolibrie_tpu.ops.join.RowLimitExceeded` before it is
-        materialized."""
+        materialized.
+        ``free_scan``: the calibration's hot-key pass.  That scan (one of
+        :meth:`_keyed_scans`) reads every row under its predicate, its bound
+        subject or object riding along as a column of its own, and a join
+        above it counts the largest group of that column: the most rows any
+        one key gives the join.  The table is then no answer to anything."""
         from kolibrie_tpu.ops.join import RowLimitExceeded
         from kolibrie_tpu.ops.join import join_indices as host_join_indices
 
@@ -2507,17 +2594,23 @@ class LoweredPlan:
 
         def eval_node(node) -> Dict[str, np.ndarray]:
             if isinstance(node, ScanSpec):
-                order_name, _consts = self.scan_descs[node.scan_idx]
+                order_name, consts = self.scan_descs[node.scan_idx]
                 order = self.db.store.order(order_name)
-                lo, n = (int(x) for x in scan_ranges[node.scan_idx])
+                if node.scan_idx == free_scan:
+                    canon = predicate_rows(order, consts[1])
+                    n = len(canon["p"])
+                else:
+                    lo, n = (int(x) for x in scan_ranges[node.scan_idx])
+                    canon = order.slice_rows(lo, lo + n)  # views: nothing read yet
                 check_rows(n)
-                canon = order.slice_rows(lo, lo + n)
                 raw = {0: canon["s"], 1: canon["p"], 2: canon["o"]}
                 mask = None
                 for a, b in node.eq_pairs:
                     m = raw[a] == raw[b]
                     mask = m if mask is None else (mask & m)
                 cols = {var: raw[pos] for var, pos in node.out_vars}
+                if node.scan_idx == free_scan:
+                    cols[_FREE_KEY] = raw[0 if consts[0] is not None else 2]
                 if mask is not None:
                     cols = {k: v[mask] for k, v in cols.items()}
                 hstats[f"scan{node.scan_idx}"] = (
@@ -2542,12 +2635,14 @@ class LoweredPlan:
                     len(next(iter(lcols.values()))),
                 )
                 li, ri = host_join_indices(lkey, rkey, max_rows=row_limit)
-                counts[node.join_idx] = len(li)
                 hstats[f"join{node.join_idx}"] = len(li)
                 out = {v: c[li] for v, c in lcols.items()}
                 for v, c in rcols.items():
                     if v not in out:
                         out[v] = c[ri]
+                counts[node.join_idx] = (
+                    _largest_group(out[_FREE_KEY]) if _FREE_KEY in out else len(li)
+                )
                 return out
             if isinstance(node, FilterSpec):
                 skey = f"filter{hseq['filter']}"
@@ -2886,6 +2981,20 @@ class LoweredPlan:
                 searched += searched_keys(width, rows)
         note_join_search_keys(slots, searched)
 
+    def _note_scan_occupancy(self, members) -> None:
+        """Count the scans of the dispatch just read back: the slots they
+        were compiled for (the template's scan capacities) and the rows
+        their ranges held, base and delta.  ``members``: one
+        ``_scan_ranges_np`` a live member, numbers the host holds."""
+        from kolibrie_tpu.query.template import note_scan_occupancy
+
+        scans = [node.scan_idx for node in _spec_nodes(self.root, ScanSpec)]
+        note_scan_occupancy(
+            "device",
+            len(members) * sum(self._scan_caps[i] for i in scans),
+            sum(int(ranges[scans, 1::2].sum()) for ranges in members),
+        )
+
     def _store_caps(self) -> None:
         """Publish join capacities to the per-db template cache.  Merge is
         a MONOTONIC max: the cache is shared by every constant variant of
@@ -2932,6 +3041,7 @@ class LoweredPlan:
             self._note_scan_tiers()
             self._note_range_searches()
             self._note_join_searches([(self._scan_ranges_np, counts_h)])
+            self._note_scan_occupancy([self._scan_ranges_np])
             overflow = [
                 i for i, c in enumerate(counts_h) if c > self._join_caps[i]
             ]
@@ -3473,6 +3583,36 @@ def template_scan_cap(db, order_name: str, n_bound: int) -> int:
     return cap + dcap
 
 
+# the column a freed scan's subject or object rides in through the twin's
+# joins (LoweredPlan.host_execute, free_scan): no variable can have this name
+_FREE_KEY = "\0key"
+
+
+def predicate_rows(order, predicate: int) -> Dict[str, np.ndarray]:
+    """Every row of sorted ``order`` under ``predicate``, as ``slice_rows``
+    gives a range: a slice where the order leads with the predicate, a
+    gather where the predicate is its second column (``spo``, ``ops``)."""
+    at = order.perm.index("p")
+    if at == 0:
+        p = order.c0.dtype.type(predicate)
+        return order.slice_rows(
+            int(np.searchsorted(order.c0, p, side="left")),
+            int(np.searchsorted(order.c0, p, side="right")),
+        )
+    col = (order.c0, order.c1, order.c2)[at]
+    rows = np.flatnonzero(col == col.dtype.type(predicate))
+    return {
+        name: c[rows] for name, c in zip(order.perm, (order.c0, order.c1, order.c2))
+    }
+
+
+def _largest_group(keys: np.ndarray) -> int:
+    """Rows of the value that ``keys`` holds most often; 0 of no rows."""
+    if not len(keys):
+        return 0
+    return int(np.unique(keys, return_counts=True)[1].max())
+
+
 def lower_plan(db, plan, anti_plans=(), union_groups=(), optional_plans=()) -> LoweredPlan:
     # resilience hooks: an injected compile fault raises DeviceFault (NOT
     # Unsupported — transient, counted by the circuit breaker, never
@@ -3634,6 +3774,7 @@ def _converge_plan_batch(members: List[LoweredPlan], fp: str, max_attempts: int)
                 for m, lp in enumerate(members)
             ]
         )
+        lp0._note_scan_occupancy([lp._scan_ranges_np for lp in members])
         over = [j for j, c in enumerate(maxc) if c > caps[j]]
         if not over:
             break

@@ -411,7 +411,7 @@ class Streamertail:
             )
             for i in remaining
         }
-        costs = {i: self.estimator.estimate_cost(phys[i]) for i in remaining}
+        costs = {i: self.estimator.ordering_cost(phys[i]) for i in remaining}
         start = min(remaining, key=lambda i: costs[i])
         remaining.remove(start)
         plan = phys[start]

@@ -2,7 +2,8 @@
 
 Parity: ``streamertail_optimizer/stats/database_stats.rs:18-105`` —
 ``gather_stats_fast``: ≤100k step-sampled triples, scaled-up per-term
-cardinality maps, and a join-selectivity cache.  Counting is vectorized
+cardinality maps (predicates counted over every row), and a
+join-selectivity cache.  Counting is vectorized
 (np.unique) rather than rayon-folded.
 """
 
@@ -46,21 +47,28 @@ class DatabaseStats:
         st.quoted_triple_count = len(getattr(db, "quoted", ()) or ())
         if n == 0:
             return st
+        # Predicates are counted over every row: there are few of them, a
+        # join order rests on each count, and a step sample of rows sorted by
+        # subject meets a class whose instances all carry the same k triples
+        # (k a divisor of the step) at one predicate every time: WatDiv's
+        # purchases read 1 row for ``purchaseDate`` in one seed and 300,000
+        # for ``purchaseFor``, the other way round in the next, and a
+        # template's join order with them (PERF.md section 6, PR 40).
+        up, cp = np.unique(p, return_counts=True)
         if n > SAMPLE_CAP:
             step = n // SAMPLE_CAP
             idx = np.arange(0, n, step)
             scale = n / len(idx)
-            s, p, o = s[idx], p[idx], o[idx]
+            s, o = s[idx], o[idx]
         else:
             scale = 1.0
         us, cs = np.unique(s, return_counts=True)
-        up, cp = np.unique(p, return_counts=True)
         uo, co = np.unique(o, return_counts=True)
         st.distinct_subjects = int(len(us) * scale) if scale > 1 else len(us)
         st.distinct_predicates = len(up)
         st.distinct_objects = int(len(uo) * scale) if scale > 1 else len(uo)
         st.subject_counts = dict(zip(us.tolist(), (cs * scale).tolist()))
-        st.predicate_counts = dict(zip(up.tolist(), (cp * scale).tolist()))
+        st.predicate_counts = dict(zip(up.tolist(), cp.astype(float).tolist()))
         st.object_counts = dict(zip(uo.tolist(), (co * scale).tolist()))
         return st
 
@@ -79,6 +87,21 @@ class DatabaseStats:
         if o.kind == "id":
             est = min(est, self.object_counts.get(o.value, 1.0))
         return max(est, 0.0)
+
+    def hottest_key_rows(self, pattern) -> "float | None":
+        """What a pattern that binds its predicate and one of subject and
+        object holds at the key with most rows under that predicate
+        (:func:`hottest_key_rows`): a number of the text's shape and the
+        store, not of the constant a request happened to carry.  ``None``
+        for any other pattern, and for stats with no database behind
+        them."""
+        s, p, o = pattern.subject, pattern.predicate, pattern.object
+        if p.kind != "id" or p.value is None or (s.kind == "id") == (o.kind == "id"):
+            return None
+        db = self.database()
+        if db is None:
+            return None
+        return float(hottest_key_rows(db, int(p.value), "s" if s.kind == "id" else "o"))
 
     def join_selectivity(self, card_left: float, card_right: float) -> float:
         """Crude independence assumption over the larger distinct-value side
@@ -133,3 +156,33 @@ class DatabaseStats:
                 if v - 1.0 <= 0:
                     setattr(self, attr, max(getattr(self, attr) - 1, 0))
         self.join_selectivity_cache.clear()
+
+
+def hottest_key_rows(db, predicate: int, key: str) -> int:
+    """Rows of the subject (``key`` "s") or object ("o") that holds most rows
+    under ``predicate`` in the frozen base segment: the largest ``(s, p)``
+    group of ``spo``, or ``(p, o)`` group of ``pos``, the orders a scan that
+    binds the pair reads.  One pass an order, kept per ``base_version`` on
+    the database as :func:`device_engine.template_scan_cap` keeps its own."""
+    store = db.store
+    cache = db.__dict__.setdefault("_hottest_key_rows_cache", {})
+    bv = store.base_version
+    table = cache.get((key, bv))
+    if table is None:
+        for stale in [k for k in cache if k[1] != bv]:
+            del cache[stale]
+        base = store.base_order("spo" if key == "s" else "pos")
+        rows = base.slice_rows(0, len(base))
+        p, k = rows["p"], rows[key]
+        table = {}
+        if len(p):
+            starts = np.flatnonzero(
+                np.r_[True, (p[1:] != p[:-1]) | (k[1:] != k[:-1])]
+            )
+            sizes = np.diff(np.r_[starts, len(p)])
+            preds, inverse = np.unique(p[starts], return_inverse=True)
+            most = np.zeros(len(preds), dtype=np.int64)
+            np.maximum.at(most, inverse, sizes)
+            table = dict(zip(preds.tolist(), most.tolist()))
+        cache[(key, bv)] = table
+    return table.get(predicate, 0)

@@ -51,6 +51,7 @@ __all__ = [
     "cap_advisor",
     "cap_advisor_enabled",
     "note_cap_occupancy",
+    "note_scan_occupancy",
     "note_scan_tiers",
     "note_range_searches",
     "occupancy_pct",
@@ -248,8 +249,9 @@ _JOIN_ROWS = metrics.counter(
 _CAP_SLOTS.labels("device")
 _JOIN_ROWS.labels("device")
 # what a template's calibrated start costs: wall seconds of the numpy twin
-# on a template's first sight, by whether it counted or gave up at the
-# row limit (then the first device run's counts calibrate)
+# on a template's first sight, by whether it counted this variant, counted
+# it with one scan at its predicate's hottest key (hot_key), or gave up at
+# the row limit (then the first device run's counts calibrate)
 cap_calibrate_seconds = metrics.counter(
     "kolibrie_cap_calibrate_seconds_total",
     "wall seconds of host calibration passes (a template's first sight "
@@ -257,6 +259,7 @@ cap_calibrate_seconds = metrics.counter(
     labels=("outcome",),
 )
 cap_calibrate_seconds.labels("counted")
+cap_calibrate_seconds.labels("hot_key")
 cap_calibrate_seconds.labels("too_large")
 
 
@@ -264,6 +267,32 @@ def note_cap_occupancy(engine: str, slots: int, rows: int) -> None:
     """One dispatch ran ``slots`` join slots and counted ``rows`` rows."""
     _CAP_SLOTS.labels(engine).inc(slots)
     _JOIN_ROWS.labels(engine).inc(rows)
+
+
+# what a template-wide scan capacity costs a variant: per dispatch, the slots
+# its scans were compiled for (ScanSpec.cap: the largest key-group of the
+# order's bound prefix, whichever constants the text names) and the rows
+# their ranges held (host values, read where the counts are read back)
+_SCAN_SLOTS = metrics.counter(
+    "kolibrie_device_scan_slots_total",
+    "slots the dispatched executables' scans were compiled for, summed "
+    "over dispatches, by engine",
+    labels=("engine",),
+)
+_SCAN_ROWS = metrics.counter(
+    "kolibrie_device_scan_rows_total",
+    "rows the dispatched scans' ranges held (base and delta), summed over "
+    "dispatches, by engine",
+    labels=("engine",),
+)
+_SCAN_SLOTS.labels("device")
+_SCAN_ROWS.labels("device")
+
+
+def note_scan_occupancy(engine: str, slots: int, rows: int) -> None:
+    """One dispatch's scans were ``slots`` wide and held ``rows`` rows."""
+    _SCAN_SLOTS.labels(engine).inc(slots)
+    _SCAN_ROWS.labels(engine).inc(rows)
 
 
 # how often an empty delta tier is not searched: per dispatch, one a scan

@@ -316,6 +316,9 @@ def test_a_dispatch_counts_its_prepass_joins_slots_and_searched_keys(monkeypatch
 
     assert 8192 % B == 0  # the left scan is 8,192 slots wide
     db = _hub_db(6000)
+    # the calibration's hot-key passes left out (ISSUE 40: they would size
+    # the template for the hub at its first sight), so that the hub overflows
+    monkeypatch.setattr(de.LoweredPlan, "_keyed_scans", lambda self: [])
 
     def grown(k):
         low = _lowered(db, (
@@ -334,8 +337,12 @@ def test_a_dispatch_counts_its_prepass_joins_slots_and_searched_keys(monkeypatch
                                "searched": 2 * -(-6000 // B) * B})
 
 
-def test_an_overflow_in_one_member_reruns_the_group_once_for_every_size():
+def test_an_overflow_in_one_member_reruns_the_group_once_for_every_size(monkeypatch):
     db = _hub_db()
+    # the first sight is the small variants' alone, as where a fan-out that
+    # no scan's hottest key shows exceeds them (ISSUE 40's hot-key passes
+    # would size the template for the hub at once)
+    monkeypatch.setattr(de.LoweredPlan, "_keyed_scans", lambda self: [])
 
     def text(k):
         return (f"PREFIX ex: <{EX}>\n"

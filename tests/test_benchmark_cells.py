@@ -6,7 +6,9 @@ the clients of ``mesh_q7`` never share a constant (in ``lubm5.mesh4`` and in
 as ISSUE 32 states it and ``lubm50.triangles`` (``lubm-50``, nothing cut) as
 ISSUE 34 does, ISSUE 35's three range-search metrics are data files for the
 two triangles cells, ``lubm50.lookups`` (``lookups`` against ``lubm-50``) and
-the two join-search metrics are in as ISSUE 39 states them, every file a cell or a
+the two join-search metrics are in as ISSUE 39 states them, ``watdiv-100``,
+``watdiv100.stars_snowflakes`` and the two scan metrics as ISSUE 40 does
+(eight cells of six configurations, one of four chips), every file a cell or a
 per-layer metric names is there, a program that lacks what a cell
 requires of it (``benchmark/requires``) is refused before anything starts,
 and ``run.py`` itself, started off the chip, prints no result and exits 3
@@ -173,7 +175,7 @@ def test_benchmark_json_has_lubm_50_uncut_and_its_cell():
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "lubm-50", "triangles", 1)
     assert BENCH["workloads"][5] == cell  # appended, nothing before it moved
-    entry = BENCH["configs"][-1]
+    entry = BENCH["configs"][4]
     assert (entry["name"], entry["file"], entry["reduced"]) == (
         "lubm-50", "benchmark/configs/lubm-50.json", [])
     assert "LUBM(50, seed), the largest the paper reports" in entry["source"]
@@ -208,7 +210,7 @@ def test_benchmark_json_has_lubm_50_uncut_and_its_cell():
     for m in BENCH["per_layer"] + BENCH["end_to_end"]:
         if m["name"] not in LUBM50_METRICS and m["name"] not in RANGE_SEARCH_METRICS:
             assert "lubm50.triangles" not in m.get("workloads", [])
-        if m["name"] not in JOIN_SEARCH_METRICS:
+        if m["name"] not in JOIN_SEARCH_METRICS and m["name"] not in SCAN_METRICS:
             assert "lubm50.lookups" not in m.get("workloads", [])
     reported = {m["name"] for m in BENCH["end_to_end"] if "workloads" not in m}
     assert reported == {"cycle_ms", "setup_s"}
@@ -317,7 +319,7 @@ JOIN_SEARCH_CELLS = ["lubm5.lookups", "employee100k.upstream", "lubm5.batch8",
                      "lubm50.lookups"]
 
 
-def test_benchmark_json_has_the_lookups_against_lubm_50_as_its_last_cell():
+def test_benchmark_json_has_the_lookups_against_lubm_50_as_its_seventh_cell():
     """ISSUE 39: ``lookups`` as it stands against ``lubm-50`` as it stands,
     one chip, a data file beside the others; two per-layer entries appended,
     each a data file of a reader that was there; no standing list took the
@@ -325,17 +327,17 @@ def test_benchmark_json_has_the_lookups_against_lubm_50_as_its_last_cell():
     cell = CELLS["lubm50.lookups"]
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "lubm-50", "lookups", 1)
-    assert BENCH["workloads"][-1] == cell and len(cell["why"]) <= 200
+    assert BENCH["workloads"][6] == cell and len(cell["why"]) <= 200
     assert files.read_json("workloads", "lubm50.lookups.json") == {"env": {}}
     assert CELLS["lubm5.lookups"]["traffic"] == cell["traffic"]
     assert CELLS["lubm50.triangles"]["config"] == cell["config"]
-    assert [c["name"] for c in BENCH["configs"]][-1] == "lubm-50"  # no new one
+    assert [c["name"] for c in BENCH["configs"]][4] == "lubm-50"  # no new one
     traffic = files.read_json("traffic", "lookups.json")
     assert (traffic["loop"], traffic["clients"], traffic["warmup_cycles"],
             traffic["deadline_ms"]) == ("closed", 1, 5, 900000)
     assert [step["template"] for step in traffic["cycle"]] == [
         "lubm_q1", "lubm_q3", "lubm_q4", "lubm_q7", "lubm_q8"]
-    added = BENCH["per_layer"][-len(JOIN_SEARCH_METRICS):]
+    added = BENCH["per_layer"][74:74 + len(JOIN_SEARCH_METRICS)]
     assert [m["name"] for m in added] == list(JOIN_SEARCH_METRICS)
     for m in added:
         assert m == {"name": m["name"], "unit": "count", "better": "lower",
@@ -349,7 +351,7 @@ def test_benchmark_json_has_the_lookups_against_lubm_50_as_its_last_cell():
             "beside": "metrics.kolibrie_join_search_keys_total"}
         assert os.path.exists(files.path("readers", "counter_delta.py"))
     for m in BENCH["per_layer"] + BENCH["end_to_end"]:
-        if m["name"] not in JOIN_SEARCH_METRICS:
+        if m["name"] not in JOIN_SEARCH_METRICS and m["name"] not in SCAN_METRICS:
             assert "lubm50.lookups" not in m.get("workloads", [])
 
 
@@ -374,6 +376,102 @@ def test_a_join_search_metric_reads_its_label_and_nothing_of_a_program_without_i
     assert reader.read(there, **args) == pytest.approx(131072.0)
     lacking = {key: {"metrics.kolibrie_device_join_rows_total": 1.0} for key in there}
     assert reader.read(lacking, **args) is None
+
+
+SCAN_METRICS = {
+    # name: (the family it reads, better)
+    "scan_slots_in_window": ("kolibrie_device_scan_slots_total", "lower"),
+    "scan_rows_in_window": ("kolibrie_device_scan_rows_total", "higher"),
+}
+SCAN_CELLS = ["watdiv100.stars_snowflakes", "lubm50.lookups", "lubm5.lookups"]
+# sha256 over the texts of the warm-up's 5 cycles and the window's first 8,
+# at scale factor 1, by seed: what one client of ``stars_snowflakes`` sends
+WATDIV_DIGESTS = {
+    0: "d7ac5664482398b203829819ac784adc2415a67d418addcb426649b52db0a102",
+    1: "6c492285a33f193be92b755e4f3d2ef10acaa383adc449c5cd12ac66eca630c2",
+    2: "b1241029da2de97e1f4b0379f9316f463f36a5ae71131b2e00ab7ddc1be23418",
+}
+
+
+def test_benchmark_json_has_watdiv_100_uncut_and_its_cell_as_the_last():
+    """ISSUE 40: one configuration, one cell of one chip, two per-layer
+    entries, all appended; every file new; no standing list took the cell
+    in, so it reports ``cycle_ms``, ``setup_s`` and what has no list."""
+    entry, cell = BENCH["configs"][-1], BENCH["workloads"][-1]
+    assert (entry["name"], entry["file"], entry["reduced"]) == (
+        "watdiv-100", "benchmark/configs/watdiv-100.json", [])
+    assert cell == {**cell, "name": "watdiv100.stars_snowflakes", "config": "watdiv-100",
+                    "traffic": "stars_snowflakes", "chips": 1}
+    assert set(cell) == {"name", "config", "traffic", "chips", "why"}
+    assert max(len(entry["source"]), len(entry["why"]), len(cell["why"])) <= 200
+    config = files.read_json("configs", "watdiv-100.json")
+    lubm = files.read_json("configs", "lubm-50.json")
+    assert config["source"] == entry["source"] and "scale factor 100" in entry["source"]
+    assert (config["scale_factor"], config["reduced"], config["chips"],
+            config["store_mode"], config["generator"]) == (100, {}, 1, "device", "watdiv")
+    for key in ("guarantees", "control"):  # lubm-50's, letter for letter
+        assert config[key] == lubm[key], key
+    assert sorted(config["domains"]) == [
+        "agegroup", "category", "city", "country", "retailer", "subgenre", "topic",
+        "user", "website"]
+    assert len(config["assumed"]) == 14 and all(config["assumed"])
+    assert files.read_json("workloads", cell["name"] + ".json") == {"env": {}}
+    templates = sorted(f for f in os.listdir(files.path("templates"))
+                       if f.startswith("watdiv_"))
+    assert templates == sorted(
+        f"watdiv_{kind}{k}.rq" for kind, n in (("L", 5), ("S", 7), ("F", 5), ("C", 3))
+        for k in range(1, n + 1))
+    need = files.read_json("requires", cell["name"] + ".json")
+    assert (need["module"], need["registers"]) == (
+        "kolibrie_tpu.query.template", "kolibrie_device_scan_slots_total")
+    added = BENCH["per_layer"][-len(SCAN_METRICS):]
+    assert [m["name"] for m in added] == list(SCAN_METRICS)
+    for m in added:
+        family, better = SCAN_METRICS[m["name"]]
+        assert m == {"name": m["name"], "unit": "count", "better": better,
+                     "source": "program_counter", "layer": "device dispatch",
+                     "moves": "cycle_ms", "workloads": SCAN_CELLS}
+        assert files.read_json("layer_metrics", m["name"] + ".json")["reader"] == {
+            "kind": "counter_delta",
+            "prefix": 'metrics.%s{engine="device"}' % family}
+    for m in BENCH["per_layer"] + BENCH["end_to_end"]:
+        if m["name"] not in SCAN_METRICS:
+            assert cell["name"] not in m.get("workloads", [])
+
+
+@pytest.mark.parametrize("name", sorted(SCAN_METRICS))
+def test_a_scan_metric_reads_its_family_and_nothing_of_a_program_without_it(name):
+    """The readers run on the parent's checkout too: a program without the
+    family reports neither count and nothing raises; the program registers
+    the ``device`` engine's line at import."""
+    from kolibrie_tpu.obs import export, metrics
+    from kolibrie_tpu.query import template  # noqa: F401  (registers the family)
+
+    args = dict(files.read_json("layer_metrics", name + ".json")["reader"])
+    reader = files.load_module("readers", args.pop("kind"))
+    assert metrics.REGISTRY.get(SCAN_METRICS[name][0]) is not None
+    assert args["prefix"][len("metrics."):] + " " in export.render_prometheus()
+    there = {"counters0": {args["prefix"]: 8388608.0, "metrics.kolibrie_other_total": 1.0},
+             "counters1": {args["prefix"]: 25165824.0, "metrics.kolibrie_other_total": 3.0}}
+    assert reader.read(there, **args) == pytest.approx(16777216.0)
+    lacking = {key: {"metrics.kolibrie_device_cap_slots_total": 1.0} for key in there}
+    assert reader.read(lacking, **args) is None
+
+
+@pytest.mark.parametrize("seed", sorted(WATDIV_DIGESTS))
+def test_one_client_of_stars_and_snowflakes_sends_these_texts(seed):
+    data = generated("watdiv100.stars_snowflakes", seed, 1)
+    traffic = Traffic("stars_snowflakes", data["domains"], seed)
+    assert traffic.clients == 1 and traffic.warmup_ramp == [1]
+    assert len(traffic.warmup_counts()) == 5
+    h = hashlib.sha256()
+    for stream, n in (("warmup", 5), ("window", 8)):
+        for k in range(n):
+            cycle = traffic.cycle(k, stream)
+            assert len(cycle) == 12 and len({text for _, text in cycle}) == 12
+            for name, text in cycle:
+                h.update(f"{stream}\0{k}\0{name}\0{text}\0".encode())
+    assert h.hexdigest() == WATDIV_DIGESTS[seed]
 
 
 @pytest.mark.parametrize("workload", sorted(CELLS))
@@ -404,7 +502,8 @@ def test_every_per_layer_metric_has_its_file_and_its_reader():
 
 def test_at_most_half_the_cells_take_four_chips():
     four = [w["name"] for w in BENCH["workloads"] if w["chips"] == 4]
-    assert four == ["lubm5.mesh4"] and len(CELLS) == 7
+    assert four == ["lubm5.mesh4"] and len(CELLS) == 8
+    assert len(BENCH["configs"]) == 6
     assert len(four) <= max(1, len(CELLS) // 2)
     assert json.dumps(BENCH).count('"chips": 4') == 1
 
@@ -415,7 +514,8 @@ def test_every_requirement_belongs_to_a_cell_and_names_a_documented_metric():
     with open(os.path.join(REPO, "docs", "OBSERVABILITY.md"), encoding="utf-8") as f:
         catalog = f.read()
     found = sorted(os.listdir(files.path("requires")))
-    assert found == ["lubm5.batch8.json", "lubm5.mesh4.json"]
+    assert found == ["lubm5.batch8.json", "lubm5.mesh4.json",
+                     "watdiv100.stars_snowflakes.json"]
     for name in found:
         assert name[:-len(".json")] in CELLS
         need = files.read_json("requires", name)
@@ -429,7 +529,7 @@ def test_every_requirement_belongs_to_a_cell_and_names_a_documented_metric():
 @pytest.mark.parametrize(
     "family", ["kolibrie_test_required_total"] + [
         files.read_json("requires", cell + ".json")["registers"]
-        for cell in ("lubm5.batch8", "lubm5.mesh4")])
+        for cell in ("lubm5.batch8", "lubm5.mesh4", "watdiv100.stars_snowflakes")])
 def test_a_program_without_the_required_metric_is_refused_at_once(
         tmp_path, monkeypatch, registered, family):
     """Each cell's own family too, asked of a program whose registry is
@@ -456,7 +556,8 @@ def test_a_program_without_the_required_metric_is_refused_at_once(
     harness._requires("a.cell.without.the.file", str(tmp_path))  # requires nothing
 
 
-@pytest.mark.parametrize("workload", ["employee100k.upstream", "lubm5.batch8"])
+@pytest.mark.parametrize("workload", ["employee100k.upstream", "lubm5.batch8",
+                                      "watdiv100.stars_snowflakes"])
 def test_off_the_chip_run_py_prints_no_result_and_exits_3(tmp_path, workload):
     """A number from a CPU run is never written as a result: without a TPU
     ``benchmark/run.py`` says on standard error what it found, prints

@@ -2,9 +2,12 @@
 
 The rule (``device_engine.fit_join_caps``): a join is compiled for
 ``min(heuristic, round_cap(max(H x count, FLOOR)))`` slots, the counts
-coming from the numpy twin on the template's first sight on a db.  After
-that the caps only grow (the overflow protocol, max-merged), so a template
-keeps one executable across its constants.  What must hold: answers stay
+coming from the numpy twin on the template's first sight on a db: this
+variant's, and the variant's with each scan that binds a subject or an
+object at its predicate's hottest key (ISSUE 40), so that the first instance
+does not decide them.  After that the caps only grow (the overflow protocol,
+max-merged: what a fan-out the hot keys do not show still exceeds), so a
+template keeps one executable across its constants.  What must hold: answers stay
 exact whatever the caps, a template's variants neither retry nor recompile
 once its first request is through, and the counters that say how full the
 slots ran add up.
@@ -127,6 +130,30 @@ def skewed_db(big=6000, small=10) -> SparqlDatabase:
     return db
 
 
+def uniform_db(depts=600, members=10) -> SparqlDatabase:
+    """``depts`` departments of ``members`` each, "small" and "big" among
+    them: no key is hotter than another, and the store is large enough that
+    the heuristic passes the floor."""
+    lines = []
+    names = ["small", "big"] + [f"d{k}" for k in range(depts - 2)]
+    for i in range(depts * members):
+        e = f"<http://example.org/e{i}>"
+        lines.append(f'{e} <http://example.org/dept> "{names[i % depts]}" .')
+        lines.append(f'{e} <http://example.org/salary> "{i % 97}" .')
+    db = SparqlDatabase()
+    db.parse_ntriples("\n".join(lines))
+    db.execution_mode = "device"
+    return db
+
+
+@pytest.fixture
+def first_sight_alone(monkeypatch):
+    """The calibration's hot-key passes left out, as where they pass the row
+    limit: a template is then sized by its first instance, and a larger one
+    meets the overflow protocol."""
+    monkeypatch.setattr(de.LoweredPlan, "_keyed_scans", lambda self: [])
+
+
 def dept_query(dept: str) -> str:
     return PREFIX + (
         f'SELECT ?e ?s WHERE {{ ?e ex:dept "{dept}" . ?e ex:salary ?s }}'
@@ -138,7 +165,7 @@ def cached_caps(db):
     return caps
 
 
-def test_larger_variant_overflows_once_and_caps_never_shrink():
+def test_larger_variant_overflows_once_and_caps_never_shrink(first_sight_alone):
     cap_advisor.reset()
     db = skewed_db()
     compiled0 = de.device_compile_stats()["run_plan"]
@@ -162,7 +189,8 @@ def test_larger_variant_overflows_once_and_caps_never_shrink():
     assert de.device_compile_stats()["run_plan"] == compiled
 
 
-def test_host_pass_too_large_tightens_once_from_the_first_run(monkeypatch):
+def test_host_pass_too_large_tightens_once_from_the_first_run(
+        monkeypatch, first_sight_alone):
     """The fallback: where the numpy twin gives up at the row limit the
     first dispatch runs at the heuristic, its counts tighten the caps once,
     and from then on they only grow."""
@@ -190,9 +218,9 @@ def test_advice_replaces_the_heuristic_on_a_fresh_db():
     converged to, not from the larger of that and the heuristic."""
     cap_advisor.reset()
     q = dept_query("small")
-    first = skewed_db()
+    first = uniform_db()
     device_rows(first, q)
-    fresh = skewed_db()
+    fresh = uniform_db()
     with obs_analyze.capture() as cap:
         assert device_rows(fresh, q) == host_rows(fresh, q)
     assert cap.last("device")["caps"] == [de._CAP_FLOOR]
@@ -217,6 +245,179 @@ def test_explain_calibration_publishes_rule_caps():
     heuristic = lowered._heuristic_join_caps(lowered._template_scan_caps())
     assert lowered._join_caps == de.fit_join_caps(heuristic, counts)
     assert lowered._join_caps[0] >= 6000
+
+
+def test_the_hot_keys_capacity_is_there_from_the_first_instance():
+    """ISSUE 40: the small department comes first and the template is sized
+    for the large one all the same: no overflow, one executable."""
+    cap_advisor.reset()
+    db = skewed_db()
+    compiled0 = de.device_compile_stats()["run_plan"]
+    retries0 = retries()
+    hot0 = counter('kolibrie_cap_calibrate_seconds_total{outcome="hot_key"}')
+    assert device_rows(db, dept_query("small")) == host_rows(db, dept_query("small"))
+    assert cached_caps(db) == (16384,)  # the heuristic: under H x 6,000 rounded
+    compiled = de.device_compile_stats()["run_plan"]
+    assert compiled - compiled0 <= 1  # (another test may have built it)
+    assert counter('kolibrie_cap_calibrate_seconds_total{outcome="hot_key"}') > hot0
+    for dept in ("big", "small", "big"):
+        rows = device_rows(db, dept_query(dept))
+        assert len(rows) == (6000 if dept == "big" else 10)
+        assert rows == host_rows(db, dept_query(dept))
+    assert cached_caps(db) == (16384,) and retries() == retries0
+    assert de.device_compile_stats()["run_plan"] == compiled
+    # the large department first: the same capacities, the same executable
+    cap_advisor.reset()
+    other = skewed_db()
+    assert len(device_rows(other, dept_query("big"))) == 6000
+    assert cached_caps(other) == (16384,)
+    assert de.device_compile_stats()["run_plan"] == compiled
+
+
+def test_a_fan_out_that_no_scans_rows_show_is_counted_at_the_join():
+    """Two departments of ten members: neither key holds more rows under
+    ``ex:dept``, but the members of one have 600 salaries each.  The pass
+    counts the join's largest group, so the first instance of the other
+    department sizes the template for this one."""
+    cap_advisor.reset()
+    lines = []
+    for i in range(20):
+        e = f"<http://example.org/e{i}>"
+        lines.append(f'{e} <http://example.org/dept> "{"big" if i < 10 else "small"}" .')
+        for k in range(600 if i < 10 else 1):
+            lines.append(f'{e} <http://example.org/salary> "{i}-{k}" .')
+    db = SparqlDatabase()
+    db.parse_ntriples("\n".join(lines))
+    db.execution_mode = "device"
+    retries0 = retries()
+    assert len(device_rows(db, dept_query("small"))) == 10
+    (cap,) = cached_caps(db)
+    assert cap >= 6000
+    assert device_rows(db, dept_query("big")) == host_rows(db, dept_query("big"))
+    assert cached_caps(db) == (cap,) and retries() == retries0
+
+
+def test_a_product_of_two_hot_keys_overflows_once_after_the_hot_passes():
+    """Two placeholders in one text are freed one at a time: the hot passes
+    run, and the pair of hot keys, which neither pass counts, still passes
+    the capacity they left.  The protocol's retry keeps the answer exact:
+    one re-run, then nothing."""
+    cap_advisor.reset()
+    lines = []
+    for a in range(62):  # 60 members of "big", 2 of "small"
+        dept = "big" if a < 60 else "small"
+        lines.append(f'<http://example.org/a{a}> <http://example.org/dept> "{dept}" .')
+        for k in range(40 if a < 60 else 3):
+            b = f"<http://example.org/b{a}-{k}>"
+            lines.append(f"<http://example.org/a{a}> <http://example.org/knows> {b} .")
+            lines.append(f'{b} <http://example.org/team> "{dept}" .')
+    db = SparqlDatabase()
+    db.parse_ntriples("\n".join(lines))
+    db.execution_mode = "device"
+
+    def q(dept, team):
+        return PREFIX + (
+            f'SELECT ?a ?b WHERE {{ ?a ex:dept "{dept}" . ?a ex:knows ?b . '
+            f'?b ex:team "{team}" }}'
+        )
+
+    retries0 = retries()
+    hot0 = counter('kolibrie_cap_calibrate_seconds_total{outcome="hot_key"}')
+    assert len(device_rows(db, q("small", "small"))) == 6
+    assert counter('kolibrie_cap_calibrate_seconds_total{outcome="hot_key"}') > hot0
+    assert retries() == retries0
+    caps = cached_caps(db)
+    # a department freed with the team as it is counts 6 rows at the last
+    # join, a team freed with the department as it is 6: the floor
+    assert caps[-1] == de._CAP_FLOOR
+    both = device_rows(db, q("big", "big"))
+    assert len(both) == 2400 and both == host_rows(db, q("big", "big"))
+    assert retries() == retries0 + 1
+    raised = cached_caps(db)
+    assert raised[-1] >= 2400 and all(r >= c for r, c in zip(raised, caps))
+    for dept, team in (("big", "big"), ("small", "small"), ("big", "small")):
+        assert device_rows(db, q(dept, team)) == host_rows(db, q(dept, team))
+    assert cached_caps(db) == raised and retries() == retries0 + 1
+
+
+def test_a_hot_pass_over_the_row_limit_is_left_out_and_the_first_instance_sizes(
+        monkeypatch):
+    """The freed scan reads every row under its predicate; where that passes
+    the row limit the pass is dropped, counted as ``too_large``, and the
+    template is its first instance's: the large department then overflows
+    once and is answered exactly."""
+    cap_advisor.reset()
+    lines = []
+    for i in range(6010):  # 6,000 of "big", half of them with a salary
+        e = f"<http://example.org/e{i}>"
+        lines.append(f'{e} <http://example.org/dept> "{"big" if i < 6000 else "small"}" .')
+        if i >= 3000:
+            lines.append(f'{e} <http://example.org/salary> "{i % 97}" .')
+    db = SparqlDatabase()
+    db.parse_ntriples("\n".join(lines))
+    db.execution_mode = "device"
+    # the instance's own pass reads 10 and 3,010 rows, the freed scan 6,010
+    monkeypatch.setattr(de, "_CALIBRATE_ROW_LIMIT", 4000)
+    retries0 = retries()
+    large0 = counter('kolibrie_cap_calibrate_seconds_total{outcome="too_large"}')
+    hot0 = counter('kolibrie_cap_calibrate_seconds_total{outcome="hot_key"}')
+    assert device_rows(db, dept_query("small")) == host_rows(db, dept_query("small"))
+    assert counter('kolibrie_cap_calibrate_seconds_total{outcome="too_large"}') > large0
+    assert counter('kolibrie_cap_calibrate_seconds_total{outcome="hot_key"}') == hot0
+    assert cached_caps(db) == (de._CAP_FLOOR,) and retries() == retries0
+    big = device_rows(db, dept_query("big"))
+    assert len(big) == 3000 and big == host_rows(db, dept_query("big"))
+    assert retries() == retries0 + 1
+    assert cached_caps(db)[0] >= 3000
+
+
+def test_a_keyed_scan_is_ordered_by_its_predicates_hottest_key():
+    """The planner runs per constant binding; a text has one executable.  A
+    scan that binds its predicate and a subject or an object is ordered by
+    the rows of the hottest key under the predicate, so the small and the
+    large department plan one order: the large one's."""
+    from kolibrie_tpu.optimizer.engine import resolve_pattern
+    from kolibrie_tpu.optimizer.planner import Streamertail, build_logical_plan
+    from kolibrie_tpu.optimizer.stats import hottest_key_rows
+    from kolibrie_tpu.query.parser import parse_sparql_query
+
+    lines = []
+    for i in range(6010):
+        dept = "big" if i < 6000 else "small"
+        lines.append(f'<http://example.org/a{i}> <http://example.org/dept> "{dept}" .')
+    for i in range(5950, 6010):
+        lines.append(f"<http://example.org/a{i}> <http://example.org/knows> "
+                     f"<http://example.org/b{i}> .")
+        lines.append(f'<http://example.org/b{i}> <http://example.org/team> "t{i % 7}" .')
+    db = SparqlDatabase()
+    db.parse_ntriples("\n".join(lines))
+
+    def leaves(node):
+        if hasattr(node, "left"):
+            return leaves(node.left) + leaves(node.right)
+        return [db.dictionary.decode(node.pattern.predicate.value).rpartition("/")[2]]
+
+    def order(dept, cost=None):
+        q = parse_sparql_query(PREFIX + (
+            f'SELECT ?a WHERE {{ ?a ex:dept "{dept}" . ?a ex:knows ?b . ?b ex:team ?t }}'))
+        resolved = [resolve_pattern(db, p) for p in q.where.patterns]
+        planner = Streamertail(db.get_or_build_stats())
+        if cost is not None:
+            planner.estimator.ordering_cost = cost(planner.estimator)
+        return leaves(planner.find_best_plan(build_logical_plan(resolved, [], [], None)))
+
+    def by_the_constant(estimator):  # what the ordering read before
+        return estimator.estimate_cost
+
+    assert order("small", by_the_constant) == ["dept", "knows", "team"]
+    assert order("big", by_the_constant) == ["knows", "team", "dept"]
+    assert order("small") == order("big") == ["knows", "team", "dept"]
+    dept, team = (resolve_pattern(db, p).predicate.value for p in (
+        parse_sparql_query(PREFIX + "SELECT ?a WHERE { ?a ex:dept ?d . ?a ex:team ?t }")
+        .where.patterns))
+    assert hottest_key_rows(db, dept, "o") == 6000
+    assert hottest_key_rows(db, team, "o") == 9 and hottest_key_rows(db, team, "s") == 1
+    assert hottest_key_rows(db, 10**9, "o") == 0  # no such predicate
 
 
 # ------------------------------------------------------------ (c) the rule
@@ -263,7 +464,7 @@ def test_capacity_rule_is_elementwise():
 
 def test_occupancy_counters_are_rows_over_slots():
     cap_advisor.reset()
-    db = skewed_db(big=3000, small=50)
+    db = uniform_db(depts=60, members=50)
     slots0 = counter('kolibrie_device_cap_slots_total{engine="device"}')
     rows0 = counter('kolibrie_device_join_rows_total{engine="device"}')
     n = 3

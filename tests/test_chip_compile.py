@@ -214,6 +214,31 @@ def test_whole_plan_lubm_q9(one_chip, lubm_db):
     assert low._join_caps[-1] >= 8192 and " sort(" in compiled.as_text()
 
 
+def test_a_merge_join_plan_holds_no_sort(one_chip, lubm_db):
+    """ISSUE 40: a template of scans and Pallas merge joins compiles without
+    a ``sort``: the prepass compacts its matched rows by a search and a
+    scan's two-tier branch gathers its rows (a sort compiled for 17-29 s on
+    the chip's host, one a join and two a column of a wide scan's scatter:
+    PERF.md section 6, PR 40).  The Mosaic kernel and both branches of the
+    scans' conditional are in the program."""
+    from examples import lubm
+    from kolibrie_tpu.optimizer import device_engine as de
+
+    low = _lower_bgp(
+        lubm_db,
+        f"PREFIX ub: <{lubm.UB}>\n"
+        "SELECT ?x ?y ?c WHERE { "
+        "?x ub:memberOf <http://www.Department0.University0.edu> . "
+        "?x ub:advisor ?y . ?y ub:teacherOf ?c }")
+    spec, args = low.build()
+    joins = list(de._spec_nodes(spec.root, de.JoinSpec))
+    assert len(joins) == 2 and all(j.rsorted for j in joins)
+    with jax.enable_x64(True):
+        compiled = _compile(de._run_plan, one_chip, *args, lead=(spec, True))
+    text = compiled.as_text()
+    assert " conditional(" in text and " sort(" not in text
+
+
 def test_whole_plan_batch_slot_class_8(one_chip, lubm_db):
     """The one-chip group program (``_run_plan_batch``: the live-member loop
     whose body is the solo plan body) for a class of 8: the Pallas

@@ -497,7 +497,8 @@ def test_the_first_sight_metrics_stand_where_they_were_appended_and_are_data_alo
         assert reader == {"kind": "counter_at_open", **args}
     # no cell or configuration came with them
     assert [w["name"] for w in bench["workloads"]][5] == "lubm50.triangles"
-    assert len(bench["configs"]) == 5
+    assert [c["name"] for c in bench["configs"]][:5] == [
+        "lubm-5", "employee-100k", "lubm-5-mesh4", "lubm-5-clients8", "lubm-50"]
 
 
 @pytest.fixture(scope="module")

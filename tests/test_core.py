@@ -339,3 +339,33 @@ class TestRules:
         eq = FilterCondition("x", "=", 42)
         assert eq.evaluate(42)
         assert not eq.evaluate(41)
+
+
+@pytest.mark.parametrize("key", [0, 7, 999, 1000, -1, 2**32, np.int64(7), np.uint32(7)])
+def test_a_range_search_takes_its_key_in_the_columns_own_dtype(key, monkeypatch):
+    """ISSUE 40 (ROADMAP A11): ``range0`` and ``range012`` search a uint32
+    column with a uint32 key, so numpy does not promote (copy) the column a
+    call; a key no uint32 holds finds what it found before: nothing."""
+    from kolibrie_tpu.core import store as store_mod
+
+    rng = np.random.default_rng(11)
+    c0 = np.sort(rng.integers(0, 1000, 5000).astype(np.uint32))
+    c2 = rng.integers(0, 1000, 5000).astype(np.uint32)
+    order = np.lexsort((c2, np.zeros(5000), c0))
+    so = store_mod.SortedOrder.from_parts(
+        ("s", "p", "o"), c0, np.zeros(5000, np.uint32), c2[order],
+        c0.astype(np.uint64) << np.uint64(32))
+    wide = c0.astype(np.int64)
+    want = (int(np.searchsorted(wide, int(key), "left")),
+            int(np.searchsorted(wide, int(key), "right")))
+    seen = []
+    real = np.searchsorted
+    monkeypatch.setattr(
+        store_mod.np, "searchsorted",
+        lambda a, v, side="left": seen.append(np.asarray(v).dtype) or real(a, v, side=side))
+    assert so.range0(key) == want
+    if 0 <= int(key) < 2**32:
+        assert set(seen) == {np.dtype(np.uint32)}
+        lo, hi = so.range012(key, 0, int(so.c2[want[0]]) if want[1] > want[0] else 5)
+        rows = np.flatnonzero((c0 == key) & (so.c2 == (so.c2[want[0]] if want[1] > want[0] else 5)))
+        assert (hi - lo) == len(rows)

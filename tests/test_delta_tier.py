@@ -264,3 +264,51 @@ def test_the_batch_keeps_a_conditional(delta):
         single = _lower(db, q)
         assert _rows(table) == _rows(single.execute()) == _rows(
             single.host_execute()[0])
+
+
+@pytest.mark.parametrize("seed", [3, 5, 8, 13])
+def test_a_scan_merges_many_writes_in_key_order(seed):
+    """A live delta of many rows and tombstones, some at the window's edges:
+    a scan's slots hold the live rows of base and delta in the order's key
+    order with the live ones a prefix (what a merge join to its right relies
+    on), each slot finding its source row (ISSUE 40: a gather, no scatter)."""
+    rng = np.random.default_rng(seed)
+    db = _graph_db(seed=seed, n_nodes=11, n_edges=300)
+    db.store.delta_threshold = 60  # the same 64 delta slots, room for 41 writes
+    bv = db.store.base_version
+    s, p, o = (c.copy() for c in db.store.columns())
+    p1 = db.encode_term_str(f"<{EX}p1>")
+    mine = np.flatnonzero(p == p1)
+    # the first and the last row of p1's run, and some between
+    victims = {int(mine[0]), int(mine[-1]), *rng.choice(mine, 9).tolist()}
+    for v in sorted(victims):
+        db.delete_triple(Triple(int(s[v]), int(p[v]), int(o[v])))
+    for _batch in range(2):  # each under a sixteenth of the store: incremental
+        db.parse_ntriples("\n".join(
+            _edge(int(a), pred, int(b))
+            for a, b, pred in zip(rng.integers(0, 40, 15), rng.integers(0, 40, 15),
+                                  rng.choice(["p1", "p2"], 15))))
+        db.store.compact()
+    assert db.store.base_version == bv
+    assert len(db.store.delta_del_positions("spo")) >= 9
+    for sparql in ("SELECT ?a ?b WHERE { ?a ex:p1 ?b }",
+                   "SELECT ?b WHERE { ex:n3 ex:p1 ?b }",
+                   "SELECT ?a WHERE { ?a ex:p1 ex:n4 }"):
+        low = _lower(db, PREFIX + sparql)
+        assert isinstance(low.root, de.ScanSpec)
+        cols, valid, _counts, _stats = low.run()
+        valid = np.asarray(valid)
+        n = int(valid.sum())
+        assert valid[:n].all()
+        got = [np.asarray(c)[:n].tolist() for c in cols]
+        want = low.host_execute()[0]
+        names = list(low.out_vars)
+        assert sorted(zip(*got)) == sorted(zip(*(want[v].tolist() for v in names)))
+        (k0, k1) = low.root.key_pos
+        key = [(row[0],) + tuple(row[1:]) for row in zip(*got)]
+        by_pos = {pos: i for i, (_v, pos) in enumerate(low.root.out_vars)}
+        if k0 in by_pos:  # the merge key's first column is an output: sorted
+            first = [row[by_pos[k0]] for row in key]
+            assert first == sorted(first)
+    _run(db, PREFIX + "SELECT ?a ?c WHERE { ?a ex:p1 ?b . ?b ex:p2 ?c }")
+    _run(db, PREFIX + "SELECT ?b ?c WHERE { ex:n1 ex:p1 ?b . ?b ex:p2 ?c }")

@@ -333,6 +333,30 @@ class TestDatabaseStats:
         # scaled-up predicate count lands near the true total
         assert abs(st.predicate_counts[7] - n) / n < 0.01
 
+    @pytest.mark.parametrize("k", [2, 3, 4])
+    def test_predicates_are_counted_over_every_row(self, k):
+        """ISSUE 40: every subject carries the same k predicates, k a divisor
+        of the sampling step, so a step sample of the subject-sorted rows
+        meets one of them every time and the others never (WatDiv's purchases:
+        ``purchaseDate`` 1 row, ``purchaseFor`` twice its own): predicates
+        are counted over all rows; subjects and objects stay sampled."""
+        import numpy as np
+
+        from kolibrie_tpu.optimizer.stats import SAMPLE_CAP, DatabaseStats
+        from kolibrie_tpu.query.sparql_database import SparqlDatabase
+
+        n_subjects = SAMPLE_CAP * 12 // k
+        s = np.repeat(np.arange(n_subjects, dtype=np.uint32), k)
+        p = np.tile(np.arange(100, 100 + k, dtype=np.uint32), n_subjects)
+        o = np.arange(len(s), dtype=np.uint32)
+        assert (len(s) // SAMPLE_CAP) % k == 0  # the step the sample takes
+        db = SparqlDatabase()
+        db.store.add_batch(s, p, o)
+        st = DatabaseStats.gather_stats_fast(db)
+        assert st.distinct_predicates == k
+        assert st.predicate_counts == {100 + i: float(n_subjects) for i in range(k)}
+        assert len(st.subject_counts) <= SAMPLE_CAP + 1  # still a sample
+
     def test_join_selectivity_cached_per_predicate(self):
         from kolibrie_tpu.optimizer.stats import DatabaseStats
         from kolibrie_tpu.query.sparql_database import SparqlDatabase

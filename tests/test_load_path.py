@@ -298,7 +298,10 @@ def test_lubm_50_at_one_university_answers_as_the_reference(server, generated):
         got = _post(base, "/store/load", body)
         sid = got["store_id"]
     assert got["triples"] == len(generated["s"])
+    # growth, not the value: a worker runs many files in one process, and a
+    # file before this one may have degraded requests on purpose
     on_device0 = _metric('kolibrie_query_seconds_count{path="device"}')
+    degraded0 = _metric('kolibrie_query_seconds_count{path="degraded"}')
     sent = 0
     for traffic_name in ("triangles", "lookups"):
         traffic = Traffic(traffic_name, generated["domains"], SEED)
@@ -311,7 +314,7 @@ def test_lubm_50_at_one_university_answers_as_the_reference(server, generated):
             sent += 1
     assert sent == 7
     assert _metric('kolibrie_query_seconds_count{path="device"}') - on_device0 == sent
-    assert _metric('kolibrie_query_seconds_count{path="degraded"}') == 0
+    assert _metric('kolibrie_query_seconds_count{path="degraded"}') == degraded0
     # what the cell's new metrics read: the base segments this store holds
     store = _store_of(httpd, sid)
     slots = round_cap(len(generated["s"]))

@@ -310,6 +310,46 @@ def test_blocked_prepass_returns_the_unblocked_rows(name):
         assert np.array_equal(np.asarray(g), w)
 
 
+@pytest.mark.parametrize("name", [
+    "none_matched", "one_matched", "every_row_matched", "narrower_than_a_block",
+    "a_block_of_matches_exactly", "one_past_a_block", "last_block_clamped_back",
+])
+def test_matched_first_is_the_stable_argsorts_head(name):
+    """ISSUE 40: the compaction of the matched left rows runs block by block
+    up to the last matched row (no sort, which the TPU compiler is slowest
+    at): its first ``n_matched`` entries are those of ``argsort(counts == 0,
+    stable=True)``, every later entry some row's index, for the caller to
+    mask."""
+    import jax
+    from kolibrie_tpu.ops.pallas_kernels import _SEARCH_BLOCK as B, _matched_first
+
+    rng = np.random.default_rng(40)
+    n = 2 * B + 1808
+    counts = np.zeros(n, np.int32)
+    if name == "one_matched":
+        counts[n - 1] = 7
+    elif name == "every_row_matched":
+        counts[:] = rng.integers(1, 5, n)
+    elif name == "narrower_than_a_block":
+        n = 1000
+        counts = (rng.random(n) < 0.3).astype(np.int32) * 3
+    elif name == "a_block_of_matches_exactly":
+        counts[rng.choice(n, B, replace=False)] = 2
+    elif name == "one_past_a_block":
+        counts[rng.choice(n, B + 1, replace=False)] = 1
+    elif name == "last_block_clamped_back":
+        counts[rng.choice(n, 2 * B + 5, replace=False)] = 1
+    with jax.enable_x64(True):
+        order, n_matched = _matched_first(jnp.asarray(counts))
+    assert order.dtype == jnp.int32 and order.shape == (n,)
+    want = np.argsort(counts == 0, kind="stable")
+    live = int((counts > 0).sum())
+    assert int(n_matched) == live
+    order = np.asarray(order)
+    assert np.array_equal(order[:live], want[:live])
+    assert order.min() >= 0 and order.max() < n
+
+
 def test_searched_keys_counts_the_blocks_the_searches_cover():
     from kolibrie_tpu.ops.pallas_kernels import _SEARCH_BLOCK as B, searched_keys
 
