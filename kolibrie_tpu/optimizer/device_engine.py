@@ -78,6 +78,7 @@ from kolibrie_tpu.obs import analyze as _analyze
 from kolibrie_tpu.obs import metrics as _obs_metrics
 from kolibrie_tpu.obs.spans import get_baggage as _get_baggage
 from kolibrie_tpu.optimizer import stats_advisor as _sa
+from kolibrie_tpu.optimizer.stats import hottest_key_rows
 from kolibrie_tpu.obs.spans import span as _obs_span
 from kolibrie_tpu.ops import round_cap as _round_cap
 from kolibrie_tpu.query import compile_cache as _cc
@@ -1500,15 +1501,19 @@ class LoweredPlan:
         # stable key for the db-level capacity caches.  TEMPLATE-level on
         # purpose: constants live in the parameter vectors (the spec tree
         # only carries param indices), and the scan descriptors contribute
-        # only their (order, bound-position) shape — so every constant
-        # variant of one query template shares capacities, which is what
-        # keeps the assembled PlanSpec (a static jit argument) bit-identical
-        # across variants: ONE compile per template.
+        # their (order, bound-position) shape and the predicate they name,
+        # which a scan's capacity is read from (template_scan_cap) — so
+        # every constant variant of one text shares capacities, which is
+        # what keeps the assembled PlanSpec (a static jit argument)
+        # bit-identical across variants: ONE compile per template; and two
+        # texts of one shape that name different predicates, whose scans
+        # are compiled for different widths, keep their join capacities and
+        # their calibration apart.
         self.cap_key = (
             self.root,
             self.out_vars,
             tuple(
-                (name, tuple(c is not None for c in consts))
+                (name, tuple(c is not None for c in consts), consts[1])
                 for name, consts in self.scan_descs
             ),
         )
@@ -2390,15 +2395,18 @@ class LoweredPlan:
 
     def _template_scan_caps(self) -> Dict[int, int]:
         """Scan capacities are a TEMPLATE property: the largest key-group
-        of the order's bound-column prefix bounds the live range for ANY
-        constant, so every variant assembles the same ScanSpec.cap (the
-        variant's true range rides in the traced scalars)."""
+        of the order's bound-column prefix, among the rows under the
+        predicate the scan names where it names one, bounds the live range
+        for ANY other constant, so every variant of a text assembles the
+        same ScanSpec.cap (the variant's true range rides in the traced
+        scalars)."""
         return {
             i: _round_cap(
                 template_scan_cap(
                     self.db,
                     name,
                     sum(c is not None for c in consts),
+                    consts[1],
                 )
             )
             for i, (name, consts) in enumerate(self.scan_descs)
@@ -2983,7 +2991,8 @@ class LoweredPlan:
 
     def _note_scan_occupancy(self, members) -> None:
         """Count the scans of the dispatch just read back: the slots they
-        were compiled for (the template's scan capacities) and the rows
+        were compiled for (the template's scan capacities, each as wide as
+        the predicate its scan names) and the rows
         their ranges held, base and delta.  ``members``: one
         ``_scan_ranges_np`` a live member, numbers the host holds."""
         from kolibrie_tpu.query.template import note_scan_occupancy
@@ -3545,16 +3554,27 @@ def numeric_filter_mask(vals: np.ndarray, op: str, const: float) -> np.ndarray:
     return m & ~np.isnan(vals)
 
 
-def template_scan_cap(db, order_name: str, n_bound: int) -> int:
+def template_scan_cap(
+    db, order_name: str, n_bound: int, predicate: Optional[int] = None
+) -> int:
     """Upper bound on ANY constant-variant's merged (base + delta) range
     for a scan whose ``order_name`` prefix binds ``n_bound`` columns: the
     largest key-group of that prefix in the FROZEN base segment plus the
-    fixed delta device capacity.  This is what makes ``ScanSpec.cap`` a
-    property of the TEMPLATE rather than of one variant's constants
-    (shape-stable compilation) — and because the base is frozen at
-    ``base_version``, the calibration survives every incremental mutation
-    batch.  O(base) to compute, cached per (order, prefix, base_version)
-    on the database."""
+    fixed delta device capacity.  Where the scan names its ``predicate``
+    (a dictionary id, -1 for a term the dictionary does not know) beside
+    at most one of subject and object, the group is the largest AMONG THE
+    ROWS UNDER THAT PREDICATE (:func:`stats.hottest_key_rows`, the table
+    the planner orders keyed scans by): the predicate's own base rows, or
+    those of its hottest subject or object.  Every instance of a text names
+    the same predicates, so ``ScanSpec.cap`` stays a property of the
+    TEMPLATE rather than of one variant's other constants (shape-stable
+    compilation), and a scan is as wide as the predicate it names, not as
+    the store's largest.  Without a predicate (a variable one, a WCOJ
+    accessor) the group is the largest over the whole store.  Because the
+    base is frozen at ``base_version`` and the delta tier holds at most
+    ``delta_device_cap`` rows in all, the bound survives every incremental
+    mutation batch.  O(base) to compute, cached per ``base_version`` on the
+    database."""
     store = db.store
     dcap = store.delta_device_cap
     base = store.base_order(order_name)
@@ -3563,6 +3583,9 @@ def template_scan_cap(db, order_name: str, n_bound: int) -> int:
         return dcap
     if n_bound <= 0:
         return nb + dcap
+    if predicate is not None:
+        other = [c for c in base.perm[:n_bound] if c != "p"]
+        return hottest_key_rows(db, predicate, other[0] if other else "p") + dcap
     cache = db.__dict__.setdefault("_device_group_cap_cache", {})
     bv = store.base_version
     key = (order_name, n_bound, bv)
@@ -3638,8 +3661,11 @@ def execute_plan_batch(
     first member's, and the program runs the live rows only.  Returns one
     host table per input, each identical to that plan's own ``execute()``.
 
-    Every member must have lowered to the same template (equal assembled
-    spec — guaranteed when they share a fingerprint); members with string
+    Every member must have lowered to the same template: equal assembled
+    spec, which members of one fingerprint have, the predicates their scans
+    are sized by being part of it (two texts of one shape that name
+    different predicates are two fingerprints, and a group whose specs
+    differ is refused here as ``Unsupported``); members with string
     masks must carry identical patterns, and VALUES templates are not
     batchable (their rows are per-variant constants outside the parameter
     ABI).  Capacities, occupancy and the readback see the live members

@@ -162,8 +162,11 @@ def hottest_key_rows(db, predicate: int, key: str) -> int:
     """Rows of the subject (``key`` "s") or object ("o") that holds most rows
     under ``predicate`` in the frozen base segment: the largest ``(s, p)``
     group of ``spo``, or ``(p, o)`` group of ``pos``, the orders a scan that
-    binds the pair reads.  One pass an order, kept per ``base_version`` on
-    the database as :func:`device_engine.template_scan_cap` keeps its own."""
+    binds the pair reads; with ``key`` "p", every base row under the
+    predicate.  0 for a predicate the base does not hold.  One pass a key,
+    kept per ``base_version`` on the database: the planner's ordering cost
+    (:meth:`DatabaseStats.hottest_key_rows`) and a scan's compiled width
+    (:func:`device_engine.template_scan_cap`) read this one table."""
     store = db.store
     cache = db.__dict__.setdefault("_hottest_key_rows_cache", {})
     bv = store.base_version
@@ -171,11 +174,13 @@ def hottest_key_rows(db, predicate: int, key: str) -> int:
     if table is None:
         for stale in [k for k in cache if k[1] != bv]:
             del cache[stale]
-        base = store.base_order("spo" if key == "s" else "pos")
+        base = store.base_order("pos" if key == "o" else "spo")
         rows = base.slice_rows(0, len(base))
-        p, k = rows["p"], rows[key]
-        table = {}
-        if len(p):
+        p = rows["p"]
+        if key == "p":
+            preds, most = np.unique(p, return_counts=True)
+        elif len(p):
+            k = rows[key]
             starts = np.flatnonzero(
                 np.r_[True, (p[1:] != p[:-1]) | (k[1:] != k[:-1])]
             )
@@ -183,6 +188,8 @@ def hottest_key_rows(db, predicate: int, key: str) -> int:
             preds, inverse = np.unique(p[starts], return_inverse=True)
             most = np.zeros(len(preds), dtype=np.int64)
             np.maximum.at(most, inverse, sizes)
-            table = dict(zip(preds.tolist(), most.tolist()))
+        else:
+            preds = most = p
+        table = dict(zip(preds.tolist(), most.tolist()))
         cache[(key, bv)] = table
     return table.get(predicate, 0)

@@ -1007,7 +1007,11 @@ def _plan_cache_entry(db, sparql: str):
       text: the thousand constant-variants of one query shape share a
       single cache entry (and, downstream, a single jit executable —
       the lowered program carries its constants in a traced parameter
-      vector);
+      vector).  What a template shares is every constant but the
+      predicates its scanned patterns name: a scan is compiled for the
+      rows under its predicate, so those are structure in the
+      fingerprint (and parameters too), and a text that names other
+      predicates is another template with capacities of its own;
     - within a template, the physical plan + device-lowered program live
       in per-state slots keyed by (store BASE version, UDF registry,
       execution mode), so e.g. host/device alternation keeps BOTH

@@ -15,6 +15,10 @@ comparable parameter tuples.
 Structure-relevant scalars stay in the fingerprint:
 
 * variable / alias names, operators, DISTINCT, GROUP BY keys;
+* the predicate a scanned triple pattern names (it is in ``params`` as
+  well): a scan is compiled for the rows under its predicate, so the
+  capacities, the compiled group and the advisor's record that a
+  fingerprint keys belong to one set of predicates;
 * whether a string literal parses as a number (the lowering pass branches
   on that when it sits on one side of a comparison);
 * for ordered+limited queries, the power-of-two bucket of
@@ -36,9 +40,12 @@ from typing import Any, Dict, List, Optional, Tuple
 from kolibrie_tpu.obs import metrics
 from kolibrie_tpu.query.ast import (
     CombinedQuery,
+    DeleteClause,
+    InsertClause,
     IriRef,
     NumberLit,
     PatternTerm,
+    PatternTriple,
     SelectQuery,
     StringLit,
     ValuesClause,
@@ -83,6 +90,12 @@ def _k_bucket(n: int, lo: int = 8) -> int:
     return c
 
 
+def _ser_terms(triple: PatternTriple, params: List[Any]) -> tuple:
+    return tuple(
+        _ser(t, params) for t in (triple.subject, triple.predicate, triple.object)
+    )
+
+
 def _ser(node: Any, params: List[Any]) -> Any:
     """Serialize ``node`` into a hashable structure, appending constant
     leaves to ``params`` and emitting typed placeholders in their place."""
@@ -104,6 +117,25 @@ def _ser(node: Any, params: List[Any]) -> Any:
             return ("pq", _ser(s, params), _ser(p, params), _ser(o, params))
         params.append(node.value)
         return ("#pt",)
+    if isinstance(node, PatternTriple):
+        # the predicate a pattern names stays a parameter and is structure
+        # too: its scan is compiled for the rows under that predicate
+        # (device_engine.template_scan_cap), so two texts of one shape that
+        # name different predicates are two templates
+        named = node.predicate.value if node.predicate.kind == "term" else None
+        return ("triple", _ser_terms(node, params), named)
+    if isinstance(node, (InsertClause, DeleteClause)):
+        # triples written or removed, never scanned: their predicates size
+        # nothing and every term stays a parameter
+        return (
+            type(node).__name__,
+            tuple(_ser_terms(tr, params) for tr in node.triples),
+            tuple(
+                (f.name, _ser(getattr(node, f.name), params))
+                for f in dataclasses.fields(node)
+                if f.name != "triples"
+            ),
+        )
     if isinstance(node, ValuesClause):
         rows = tuple(
             tuple("U" if c is None else "#vc" for c in row) for row in node.rows
@@ -271,11 +303,13 @@ def note_cap_occupancy(engine: str, slots: int, rows: int) -> None:
 
 # what a template-wide scan capacity costs a variant: per dispatch, the slots
 # its scans were compiled for (ScanSpec.cap: the largest key-group of the
-# order's bound prefix, whichever constants the text names) and the rows
-# their ranges held (host values, read where the counts are read back)
+# order's bound prefix among the rows under the predicate the scan names,
+# whichever other constants the text carries) and the rows their ranges
+# held (host values, read where the counts are read back)
 _SCAN_SLOTS = metrics.counter(
     "kolibrie_device_scan_slots_total",
-    "slots the dispatched executables' scans were compiled for, summed "
+    "slots the dispatched executables' scans were compiled for (each scan "
+    "as wide as the hottest key under the predicate it names), summed "
     "over dispatches, by engine",
     labels=("engine",),
 )
@@ -375,14 +409,15 @@ class CapAdvisor:
     initial capacity choice.
 
     The engines' own capacity caches are deliberately narrow — the device
-    engine's ``_device_cap_cache`` lives on one db object and its
-    ``cap_key`` embeds scan-cap buckets that MOVE when store growth
-    crosses a power-of-two key-group boundary, and the sharded server
+    engine's ``_device_cap_cache`` lives on one db object, and the sharded
+    server
     pins caps per ``(fingerprint, base_version)``, dropping them on every
     mutation.  Each of those invalidations used to restart the
     double-and-retry ladder from the static defaults.  This advisor keys
     only on the template fingerprint (which already folds the
-    WCOJ/interp/Pallas routing modes), merges observations as a monotonic
+    WCOJ/interp/Pallas routing modes and the predicates the scanned
+    patterns name, so one record holds one predicate set's join
+    capacities), merges observations as a monotonic
     elementwise maximum, and survives db churn and base-version bumps —
     so a warm process re-dispatches at the high-water mark and retries
     stay at zero.

@@ -11,6 +11,11 @@ template keeps one executable across its constants.  What must hold: answers sta
 exact whatever the caps, a template's variants neither retry nor recompile
 once its first request is through, and the counters that say how full the
 slots ran add up.
+
+A scan's capacity follows the predicate it names (ISSUE 41,
+``device_engine.template_scan_cap``): the largest key-group of its order's
+bound prefix among the rows under that predicate, over the whole store only
+where the predicate is a variable; section (e).
 """
 
 import pytest
@@ -19,6 +24,7 @@ import kolibrie_tpu.optimizer.device_engine as de
 from benchmark.harness import data as bench_files
 from kolibrie_tpu.obs import analyze as obs_analyze
 from kolibrie_tpu.obs import export as obs_export
+from kolibrie_tpu.optimizer.stats import hottest_key_rows
 from kolibrie_tpu.query.executor import execute_query_volcano
 from kolibrie_tpu.query.sparql_database import SparqlDatabase
 from kolibrie_tpu.query.template import cap_advisor
@@ -47,6 +53,19 @@ def host_rows(db, q):
 
 def device_rows(db, q):
     return sorted(map(tuple, execute_query_volcano(q, db)))
+
+
+def lowered(db, q):
+    from kolibrie_tpu.optimizer.engine import resolve_pattern
+    from kolibrie_tpu.optimizer.planner import Streamertail, build_logical_plan
+    from kolibrie_tpu.query.parser import parse_sparql_query
+
+    w = parse_sparql_query(q).where
+    resolved = [resolve_pattern(db, p) for p in w.patterns]
+    plan = Streamertail(db.get_or_build_stats()).find_best_plan(
+        build_logical_plan(resolved, list(w.filters), [], None)
+    )
+    return de.lower_plan(db, plan)
 
 
 # ------------------------------------------------- (a) LUBM(1), every constant
@@ -229,22 +248,12 @@ def test_advice_replaces_the_heuristic_on_a_fresh_db():
 def test_explain_calibration_publishes_rule_caps():
     """``calibrate_host`` (EXPLAIN, the planner's exploration) sizes by the
     same rule: headroom and floor, never the bare counts."""
-    from kolibrie_tpu.optimizer.engine import resolve_pattern
-    from kolibrie_tpu.optimizer.planner import Streamertail, build_logical_plan
-    from kolibrie_tpu.query.parser import parse_sparql_query
-
-    db = skewed_db()
-    q = parse_sparql_query(dept_query("big"))
-    resolved = [resolve_pattern(db, p) for p in q.where.patterns]
-    plan = Streamertail(db.get_or_build_stats()).find_best_plan(
-        build_logical_plan(resolved, [], [], None)
-    )
-    lowered = de.lower_plan(db, plan)
-    counts = lowered.calibrate_host()
+    low = lowered(skewed_db(), dept_query("big"))
+    counts = low.calibrate_host()
     assert counts == [6000]
-    heuristic = lowered._heuristic_join_caps(lowered._template_scan_caps())
-    assert lowered._join_caps == de.fit_join_caps(heuristic, counts)
-    assert lowered._join_caps[0] >= 6000
+    heuristic = low._heuristic_join_caps(low._template_scan_caps())
+    assert low._join_caps == de.fit_join_caps(heuristic, counts)
+    assert low._join_caps[0] >= 6000
 
 
 def test_the_hot_keys_capacity_is_there_from_the_first_instance():
@@ -490,3 +499,143 @@ def test_calibration_seconds_are_counted_once_a_template():
     assert first > before
     execute_query_volcano(dept_query("big"), db)
     assert counter(family) == first
+
+
+# ------------------- (e) a scan is as wide as the predicate it names
+
+
+BIG, SMALL = 16000, 60  # rows under ex:big and ex:small: over a hundredfold apart
+
+
+def two_predicates_db() -> SparqlDatabase:
+    """Every subject holds a row under ``ex:big``, the first 60 one under
+    ``ex:small`` and one under ``ex:other`` too; half of each predicate's
+    rows share the object "hot"."""
+    lines = []
+    for i in range(BIG):
+        e = f"<http://example.org/e{i}>"
+        lines.append(f'{e} <http://example.org/big> "{"hot" if i % 2 else f"g{i % 30}"}" .')
+        if i < SMALL:
+            lines.append(f'{e} <http://example.org/small> "{"hot" if i % 2 else f"s{i % 10}"}" .')
+            lines.append(f'{e} <http://example.org/other> "o{i}" .')
+    db = SparqlDatabase()
+    db.parse_ntriples("\n".join(lines))
+    db.execution_mode = "device"
+    return db
+
+
+def scan_caps(db, q) -> dict:
+    """``ScanSpec.cap`` of the assembled spec, by the name of the predicate
+    each scan binds (``None``: a variable, "?": not in the dictionary)."""
+    low = lowered(db, q)
+    spec, _ = low.build(operands=False)
+    out = {}
+    for node in de._spec_nodes(spec.root, de.ScanSpec):
+        pid = low.scan_descs[node.scan_idx][1][1]
+        name = pid if pid is None else (
+            "?" if pid < 0 else db.dictionary.decode(pid).rpartition("/")[2]
+        )
+        out[name] = node.cap
+    return out
+
+
+def pid(db, name) -> int:
+    return db.dictionary.lookup(f"http://example.org/{name}")
+
+
+def wide(db, rows) -> int:
+    return de._round_cap(rows + db.store.delta_device_cap)
+
+
+def case_predicate_alone(db):
+    caps = scan_caps(db, PREFIX + "SELECT ?e ?a ?b WHERE { ?e ex:big ?a . ?e ex:small ?b }")
+    assert caps == {"big": wide(db, BIG), "small": wide(db, SMALL)}
+    assert caps["big"] >= 4 * caps["small"]
+    for name, rows in (("big", BIG), ("small", SMALL)):
+        assert hottest_key_rows(db, pid(db, name), "p") == rows
+        assert caps[name] >= rows + db.store.delta_device_cap
+
+
+def case_predicate_and_a_key(db):
+    by_object = PREFIX + 'SELECT ?e WHERE {{ ?e ex:big "{}" . ?e ex:small "{}" }}'
+    want = {"big": wide(db, BIG // 2), "small": wide(db, SMALL // 2)}
+    assert hottest_key_rows(db, pid(db, "big"), "o") == BIG // 2
+    assert hottest_key_rows(db, pid(db, "small"), "o") == SMALL // 2
+    # the hottest object under each predicate, whichever object the text names
+    for a, b in (("hot", "hot"), ("g4", "s2"), ("no such", "s4")):
+        assert scan_caps(db, by_object.format(a, b)) == want
+    by_subject = PREFIX + "SELECT ?a WHERE { ex:e7 ex:big ?a . ex:e9 ex:small ?a }"
+    assert hottest_key_rows(db, pid(db, "big"), "s") == 1
+    assert scan_caps(db, by_subject) == {"big": wide(db, 1), "small": wide(db, 1)}
+
+
+def case_a_variable_predicate_keeps_the_stores_group(db):
+    # nothing names a predicate: the largest subject, the largest object
+    for q, order, rows in (
+        ("SELECT ?p ?a WHERE { ex:e7 ?p ?a }", "spo", 3),
+        ('SELECT ?e ?p WHERE { ?e ?p "hot" }', "osp", BIG // 2 + SMALL // 2),
+    ):
+        caps = scan_caps(db, PREFIX + q)
+        assert caps == {None: de._round_cap(de.template_scan_cap(db, order, 1))}
+        assert caps[None] == wide(db, rows)
+
+
+def case_an_unknown_predicate_reads_the_empty_table(db):
+    q = PREFIX + "SELECT ?e ?a ?b WHERE { ?e ex:nosuch ?a . ?e ex:small ?b }"
+    assert hottest_key_rows(db, -1, "p") == hottest_key_rows(db, 10**9, "s") == 0
+    assert scan_caps(db, q) == {"?": wide(db, 0), "small": wide(db, SMALL)}
+    assert device_rows(db, q) == host_rows(db, q) == []
+
+
+def case_delta_rows_under_the_small_predicate_fit_the_same_executable(db):
+    q = PREFIX + "SELECT ?e ?a ?b WHERE { ?e ex:big ?a . ?e ex:small ?b }"
+    assert len(device_rows(db, q)) == SMALL
+    compiled, tried, version = (
+        de.device_compile_stats()["run_plan"], retries(), db.store.base_version)
+    extra = 900  # fifteen times the predicate's base rows, under the delta's capacity
+    assert SMALL + extra <= db.store.delta_device_cap < wide(db, SMALL)
+    db.parse_ntriples("\n".join(
+        f'<http://example.org/e{i}> <http://example.org/small> "late" .'
+        for i in range(SMALL, SMALL + extra)))
+    rows = device_rows(db, q)
+    assert len(rows) == SMALL + extra and rows == host_rows(db, q)
+    assert db.store.base_version == version
+    assert (de.device_compile_stats()["run_plan"], retries()) == (compiled, tried)
+
+
+def case_two_texts_of_one_shape_are_two_templates(db):
+    from kolibrie_tpu.query.executor import _plan_cache_entry, execute_queries_batched
+
+    text = PREFIX + 'SELECT ?e ?n WHERE {{ ?e ex:{} "{}" . ?e ex:other ?n }}'
+    qa, qa2, qb = text.format("big", "hot"), text.format("big", "g4"), text.format("small", "hot")
+    fps = [_plan_cache_entry(db, q)[0]["fp"] for q in (qa, qa2, qb)]
+    assert fps[0] == fps[1] != fps[2]
+    lows = [lowered(db, q) for q in (qa, qa2, qb)]
+    assert lows[0].cap_key == lows[1].cap_key != lows[2].cap_key
+    batches = 'kolibrie_device_batch_dispatch_total'
+    before = counter(batches)
+    want = [host_rows(db, q) for q in (qa, qb)]
+    got = execute_queries_batched(db, [qa, qb])
+    assert [sorted(map(tuple, rows)) for rows in got] == want
+    assert counter(batches) == before  # no group: each rode a dispatch of its own
+    assert len(db.__dict__["_device_cap_cache"]) == 2  # a record a predicate set
+    assert len(cap_advisor.stats()["templates"]) == 2
+    with pytest.raises(de.Unsupported):  # and a group made by hand is refused
+        de.execute_plan_batch([lows[0], lows[2]])
+    # two instances of one text do ride one dispatch, as before
+    got = execute_queries_batched(db, [qa, qa2])
+    assert [sorted(map(tuple, rows)) for rows in got] == [want[0], host_rows(db, qa2)]
+    assert counter(batches) == before + 1
+
+
+@pytest.mark.parametrize("case", [
+    case_predicate_alone,
+    case_predicate_and_a_key,
+    case_a_variable_predicate_keeps_the_stores_group,
+    case_an_unknown_predicate_reads_the_empty_table,
+    case_delta_rows_under_the_small_predicate_fit_the_same_executable,
+    case_two_texts_of_one_shape_are_two_templates,
+], ids=lambda case: case.__name__[5:])
+def test_a_scan_is_as_wide_as_the_predicate_it_names(case):
+    cap_advisor.reset()
+    case(two_predicates_db())

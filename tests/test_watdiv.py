@@ -17,7 +17,8 @@ factors on the CPU.
   instance of the whole domain overflows a capacity or compiles a plan of
   another join order;
 - (d) the two scan counters against the slots and rows of one plan, counted
-  by hand from the generator's columns.
+  by hand from the generator's columns: a scan is as wide as the predicate it
+  names (ISSUE 41), and over the cell's cycle every template keeps one spec.
 """
 
 import json
@@ -38,7 +39,10 @@ from benchmark.reference.sparql_subset import Reference  # noqa: E402
 from kolibrie_tpu.frontends import http_server  # noqa: E402
 from kolibrie_tpu.obs import export as obs_export  # noqa: E402
 from kolibrie_tpu.ops import round_cap  # noqa: E402
+from benchmark.harness.traffic import Traffic  # noqa: E402
 from kolibrie_tpu.optimizer.device_engine import (  # noqa: E402
+    ScanSpec,
+    _spec_nodes,
     device_compile_stats,
     predicate_rows,
 )
@@ -292,6 +296,52 @@ def test_a_predicates_rows_are_found_in_either_order(server, skewed, two_stores)
     assert len(predicate_rows(db.store.order("spo"), 2**31 - 1)["s"]) == 0
 
 
+def _lower(db, text):
+    from kolibrie_tpu.optimizer.device_engine import lower_plan
+    from kolibrie_tpu.optimizer.engine import resolve_pattern
+    from kolibrie_tpu.optimizer.planner import Streamertail, build_logical_plan
+    from kolibrie_tpu.query.parser import parse_sparql_query
+
+    db.register_prefixes_from_query(text)
+    w = parse_sparql_query(text, db.prefixes).where
+    resolved = [resolve_pattern(db, p) for p in w.patterns]
+    plan = Streamertail(db.get_or_build_stats()).find_best_plan(
+        build_logical_plan(resolved, list(w.filters), [], None))
+    return lower_plan(db, plan)
+
+
+def test_a_template_assembles_one_spec_and_its_scans_run_a_quarter_full(
+        server, skewed, two_stores):
+    """ISSUE 41: a scan is compiled for the rows under the predicate it names,
+    so over eight cycles of the cell's traffic each of the twelve templates
+    still assembles one ``PlanSpec`` whatever the instance, no scan's range
+    and no join's count (the numpy twin's) passes its capacity, and the
+    cycle's scans hold over a quarter of their slots (under 2 % where every
+    predicate-only scan is as wide as ``wsdbm:friendOf``)."""
+    httpd, _base = server
+    db = httpd.RequestHandlerClass.state.stores[two_stores[0]["cold_first"]].db
+    traffic = Traffic("stars_snowflakes", skewed["domains"], SEED)
+    specs, slots, rows = {}, 0, 0
+    for k in range(8):
+        for name, text in traffic.cycle(k):
+            low = _lower(db, text)
+            spec, _ = low.build(operands=False)
+            specs.setdefault(name, set()).add(spec)
+            counts = low.host_execute()[1]
+            assert all(c <= cap for c, cap in zip(counts, low._join_caps)), text
+            scans = [node.scan_idx for node in _spec_nodes(spec.root, ScanSpec)]
+            held = low._scan_ranges_np[scans, 1::2].sum(axis=1)
+            caps = np.array([low._scan_caps[i] for i in scans])
+            assert (held <= caps).all(), text
+            slots += int(caps.sum())
+            rows += int(held.sum())
+    assert {name: len(seen) for name, seen in specs.items()} == {
+        "watdiv_" + t: 1 for t in TEMPLATES if t[0] in "SF"}
+    friend_of = round_cap(int(np.bincount(skewed["p"]).max()) + db.store.delta_device_cap)
+    # the 49 scans of a cycle that bind their predicate alone, at that width
+    assert rows / slots > 0.25 and rows / (8 * 49 * friend_of) < 0.02, (rows, slots)
+
+
 # ------------------------------------------------- (d) the two scan counters
 
 
@@ -304,10 +354,15 @@ def test_the_scan_counters_read_the_slots_and_rows_of_one_plan(server, generated
     store = httpd.RequestHandlerClass.state.stores[sid].db.store
     dcap = store.delta_device_cap
     # a scan is compiled for the largest key-group of its order's bound
-    # prefix, whichever predicate its text names
-    widest_p = np.bincount(p).max()
-    widest_po = np.unique((p << 32) | o, return_counts=True)[1].max()
-    slots = 2 * round_cap(widest_p + dcap) + 2 * round_cap(widest_po + dcap)
+    # prefix among the rows under the predicate it names (ISSUE 41): the
+    # predicate's rows, or those of its hottest object
+    slots = sum(round_cap(int((p == _pid(generated, name)).sum()) + dcap)
+                for name in ("dc:Location", "wsdbm:gender"))
+    slots += sum(round_cap(int(np.unique(o[p == _pid(generated, name)],
+                                         return_counts=True)[1].max()) + dcap)
+                 for name in ("sorg:nationality", "rdf:type"))
+    widest_p = np.bincount(p).max()  # wsdbm:friendOf, which S2 does not name
+    assert slots < round_cap(widest_p + dcap)
     country = generated["domains"]["country"][3]
     cid = generated["terms"].index(f"<{country}>")
     role = generated["terms"].index(f"<{watdiv.NAMESPACES['wsdbm']}Role2>")
