@@ -72,6 +72,7 @@ __all__ = [
     "template_scan_cap",
 ]
 
+import threading as _threading
 import time as _time
 
 from kolibrie_tpu.obs import analyze as _analyze
@@ -116,6 +117,10 @@ _DISPATCH_LAT = _obs_metrics.histogram(
 _COLLECT_LAT = _obs_metrics.histogram(
     "kolibrie_device_collect_seconds",
     "device→host result materialization time",
+)
+_AGGREGATE_LAT = _obs_metrics.histogram(
+    "kolibrie_device_aggregate_seconds",
+    "device GROUP BY time: sort, segment reduction and the groups' readback",
 )
 _DEVICE_BATCH_SIZE = _obs_metrics.histogram(
     "kolibrie_device_batch_size",
@@ -1288,6 +1293,7 @@ def device_compile_stats() -> Dict[str, int]:
     out = {
         "run_plan": _jit_entries(_run_plan),
         "run_plan_batch": _jit_entries(_run_plan_batch),
+        "segment_aggregate": _jit_entries(_segment_aggregate),
     }
     from kolibrie_tpu.optimizer.plan_interp import interp_compile_stats
 
@@ -2346,19 +2352,46 @@ class LoweredPlan:
         of this variant and, for each scan that binds its predicate and a
         subject or an object, the most rows any one key of that scan gives
         each join with the other constants as they are
-        (:meth:`host_execute`'s ``free_scan``), the larger of them join by
-        join.  So the template starts where its hottest instance in each
-        placeholder takes it, whichever instance came first.  ``None`` where
+        (:meth:`host_execute`'s ``free_scan``), then, where several scans
+        are keyed, the most rows any one combination of their keys gives
+        (a text with two placeholders has an instance hot in both, which no
+        pass that frees one of them counts), the larger of them join by
+        join.  So the template starts where its hottest instance
+        takes it, whichever instance came first.  ``None`` where
         an intermediate of this variant would pass ``_CALIBRATE_ROW_LIMIT``
-        rows; a hot pass that would is left out (the overflow protocol keeps
-        what it exceeds exact)."""
+        rows; a hot pass that would counts the join at which it would without
+        materializing it (:func:`_freed_join_rows`) and ends there, or is
+        left out where it cannot (the overflow protocol keeps what it exceeds
+        exact).
+
+        Where the dispatch ends in an aggregation (``_stage``) every pass
+        counts its groups too, the same way: ``_calibrated_groups`` is the
+        most groups of this variant or of any one key (combination of keys)
+        of a pass, ``None`` where the variant's own pass gave up."""
         from kolibrie_tpu.ops.join import RowLimitExceeded
         from kolibrie_tpu.query.template import cap_calibrate_seconds
 
-        def timed(outcome, free_scan=None):
+        stage = self._stage
+        groups = 0
+
+        def timed(outcome, free_scan=()):
+            nonlocal groups
             t0 = _time.perf_counter()
             try:
-                return self.host_execute(_CALIBRATE_ROW_LIMIT, free_scan)[1]
+                table, counts = self.host_execute(_CALIBRATE_ROW_LIMIT, free_scan)
+                if stage is not None and stage.group_by:
+                    found = _most_groups(
+                        _freed_columns(table),
+                        [table[g] for g in stage.group_by],
+                    )
+                    groups = max(groups, found)
+                return counts
+            except _HotPassCut as cut:
+                # counted up to the join it could not materialize; a topmost
+                # join's rows bound its groups
+                if stage is not None and cut.rows is not None:
+                    groups = max(groups, cut.rows)
+                return cut.counts
             except RowLimitExceeded:
                 outcome = "too_large"
                 return None
@@ -2370,16 +2403,64 @@ class LoweredPlan:
         with _obs_span("device.calibrate") as sp:
             counts = timed("counted")
             keyed = self._keyed_scans() if counts is not None else []
-            if keyed:
+            passes = [(i,) for i in keyed]
+            if len(keyed) > 1:
+                passes.append(tuple(keyed))
+            if passes:
                 own_stats = self.last_host_stats  # EXPLAIN's, not a pass's
-                for scan_idx in keyed:
-                    hot = timed("hot_key", scan_idx)
+                for free in passes:
+                    hot = timed("hot_key", free)
                     if hot is not None:
                         counts = [max(a, b) for a, b in zip(counts, hot)]
                 self.last_host_stats = own_stats
             if sp is not None:
-                sp.attrs["hot_passes"] = len(keyed)
+                sp.attrs["hot_passes"] = len(passes)
+        if stage is not None and counts is not None:
+            # SPARQL: without GROUP BY one group, of no rows too
+            self._calibrated_groups = max(groups, 1)
         return counts
+
+    def _calibrate_group_cap(self) -> None:
+        """Where the dispatch ends in an aggregation and this db holds no
+        group capacity for the template yet, publish the one it starts from
+        (:func:`aggregate_table` reads it), in the join capacities' order of
+        preference: the process-wide advisor's for the fingerprint, else the
+        groups the numpy twin counted with headroom (beside the joins, in
+        :meth:`_calibration_counts`, where :meth:`build` just ran it; in a
+        pass of its own where the joins' capacities came from elsewhere), by
+        the one rule (:func:`fit_join_caps`) under the table's width.  Where
+        the twin gave up nothing is published: the aggregation starts at the
+        floor and its retry sizes the template."""
+        stage = self._stage
+        if stage is None:
+            return
+        cache = self.db.__dict__.setdefault("_device_group_cap_cache", {})
+        key = (self.cap_key, stage.key)
+        if key in cache:
+            return
+        from kolibrie_tpu.query.template import cap_advisor
+
+        fp = _get_baggage("template", "unknown")
+        advised = (
+            cap_advisor.advise_groups("device", fp) if fp != "unknown" else None
+        )
+        slots = self._node_cap(self.root, self._scan_caps, self._join_caps)
+        ceiling = group_cap_ceiling(slots)
+        if advised is not None:
+            cache[key] = advised
+        elif ceiling <= _CAP_FLOOR:
+            cache[key] = ceiling  # nothing the rule could tighten
+        else:
+            if self._calibrated_groups is None:
+                self._calibration_counts()
+            if self._calibrated_groups is None:
+                return
+            cache[key] = fit_join_caps([ceiling], [self._calibrated_groups])[0]
+        # the template's first sight compiles two executables, the plan's and
+        # the aggregation's: the second beside the first, not after it
+        compile_aggregation_ahead(
+            self.db, slots, len(self.out_vars), stage, min(cache[key], ceiling)
+        )
 
     def _keyed_scans(self) -> List[int]:
         """The scans that bind their predicate and one of subject and
@@ -2420,10 +2501,12 @@ class LoweredPlan:
         self._refresh_masks()
         scan_ranges = self._scan_ranges()
         scan_caps = self._template_scan_caps()
+        self._calibrated_groups = None
         join_caps = self._initial_join_caps(scan_caps)
         self._scan_ranges_np = scan_ranges
         self._scan_caps = scan_caps
         self._join_caps = join_caps
+        self._calibrate_group_cap()
         return self._assemble(tag, operands)
 
     def _assemble(self, tag: int, operands: bool = True):
@@ -2500,7 +2583,7 @@ class LoweredPlan:
     def host_execute(
         self,
         row_limit: Optional[int] = None,
-        free_scan: Optional[int] = None,
+        free_scan: Sequence[int] = (),
     ) -> Tuple[BindingTable, List[int]]:
         """Evaluate the lowered IR with numpy — the executable-free reference
         semantics.  Returns (table, exact join counts).  Used to calibrate
@@ -2509,11 +2592,13 @@ class LoweredPlan:
         With ``row_limit`` a scan, join or WCOJ level of more rows raises
         :class:`kolibrie_tpu.ops.join.RowLimitExceeded` before it is
         materialized.
-        ``free_scan``: the calibration's hot-key pass.  That scan (one of
-        :meth:`_keyed_scans`) reads every row under its predicate, its bound
-        subject or object riding along as a column of its own, and a join
-        above it counts the largest group of that column: the most rows any
-        one key gives the join.  The table is then no answer to anything."""
+        ``free_scan``: the calibration's hot-key pass, the indices of one
+        scan or of several.  Such a scan (one of :meth:`_keyed_scans`) reads every row
+        under its predicate, its bound subject or object riding along as a
+        column of its own, and a join above it counts the largest group of
+        the freed columns it holds: the most rows any one key (any one
+        combination of keys) gives the join.  The table is then no answer to
+        anything."""
         from kolibrie_tpu.ops.join import RowLimitExceeded
         from kolibrie_tpu.ops.join import join_indices as host_join_indices
 
@@ -2604,7 +2689,7 @@ class LoweredPlan:
             if isinstance(node, ScanSpec):
                 order_name, consts = self.scan_descs[node.scan_idx]
                 order = self.db.store.order(order_name)
-                if node.scan_idx == free_scan:
+                if node.scan_idx in free_scan:
                     canon = predicate_rows(order, consts[1])
                     n = len(canon["p"])
                 else:
@@ -2617,8 +2702,10 @@ class LoweredPlan:
                     m = raw[a] == raw[b]
                     mask = m if mask is None else (mask & m)
                 cols = {var: raw[pos] for var, pos in node.out_vars}
-                if node.scan_idx == free_scan:
-                    cols[_FREE_KEY] = raw[0 if consts[0] is not None else 2]
+                if node.scan_idx in free_scan:
+                    cols[f"{_FREE_KEY}{node.scan_idx}"] = raw[
+                        0 if consts[0] is not None else 2
+                    ]
                 if mask is not None:
                     cols = {k: v[mask] for k, v in cols.items()}
                 hstats[f"scan{node.scan_idx}"] = (
@@ -2642,14 +2729,26 @@ class LoweredPlan:
                     list(node.key_vars),
                     len(next(iter(lcols.values()))),
                 )
-                li, ri = host_join_indices(lkey, rkey, max_rows=row_limit)
+                try:
+                    li, ri = host_join_indices(lkey, rkey, max_rows=row_limit)
+                except RowLimitExceeded:
+                    # a hot pass too large to materialize still counts this
+                    # join, and ends there
+                    most = _freed_join_rows(lcols, rcols, lkey, rkey)
+                    if most is None:
+                        raise
+                    counts[node.join_idx] = most
+                    raise _HotPassCut(
+                        counts, most if node is _top_join(self.root) else None
+                    ) from None
                 hstats[f"join{node.join_idx}"] = len(li)
                 out = {v: c[li] for v, c in lcols.items()}
                 for v, c in rcols.items():
                     if v not in out:
                         out[v] = c[ri]
+                freed = _freed_columns(out)
                 counts[node.join_idx] = (
-                    _largest_group(out[_FREE_KEY]) if _FREE_KEY in out else len(li)
+                    _largest_group(*freed) if freed else len(li)
                 )
                 return out
             if isinstance(node, FilterSpec):
@@ -3215,6 +3314,25 @@ class LoweredPlan:
                 fp, actuals, version=self.db.store.version_key()
             )
 
+    def _aggregate(self, stage, fp, out_cols, valid):
+        """The dispatch's last stage under ``device.aggregate``: the plan's
+        device-resident columns through :func:`aggregate_table` at the
+        template's group capacity.  Returns ``(table, rows)``: one row a
+        group, and the rows the plan produced."""
+        from kolibrie_tpu.query.template import cap_advisor
+
+        _note_fetch("aggregate")
+        with _obs_span("device.aggregate") as sp:
+            table, rows, cap = aggregate_table(
+                self.db, out_cols, valid, stage, self.cap_key
+            )
+            if sp is not None:
+                groups = len(next(iter(table.values()))) if table else 0
+                sp.attrs.update(groups=groups, cap=cap, rows=rows)
+        if fp != "unknown":
+            cap_advisor.observe_groups("device", fp, cap)
+        return table, rows
+
     def to_table(self, out_cols, valid) -> BindingTable:
         _note_fetch("to_table")
         valid_h = np.asarray(valid)
@@ -3429,9 +3547,18 @@ class LoweredPlan:
     # whose executable loaded from the persistent compilation cache).
     # Plan-cache slots surface this as `source`.
     last_source: Optional[str] = None
+    # the aggregation the dispatch in flight ends in (execute's argument),
+    # and the groups a calibration of this build counted for it
+    _stage: Optional["AggregateStage"] = None
+    _calibrated_groups: Optional[int] = None
 
-    def execute(self) -> BindingTable:
-        """Run to completion with capacity validation; returns a host table."""
+    def execute(self, stage: Optional["AggregateStage"] = None) -> BindingTable:
+        """Run to completion with capacity validation; returns a host table:
+        the plan's rows or, with ``stage``, one row a group -- the rows then
+        stay on the device and the aggregation (``device.aggregate``: sort,
+        segment reduction, the groups' readback) takes the place of the
+        readback (``device.collect``)."""
+        self._stage = stage
         # deadline check BEFORE the dispatch (don't start device work the
         # client stopped waiting for) and a fault point that can inject
         # kernel latency / simulated device OOM for the chaos tests
@@ -3446,7 +3573,9 @@ class LoweredPlan:
         # filter suffix runs per member (optimizer/mqo.py, docs/MQO.md)
         from kolibrie_tpu.optimizer import mqo as _mqo
 
-        if _mqo.mqo_mode() != "off":
+        # the shared-prefix and interpreter routes hand back host rows: an
+        # aggregation takes the specialized executable's device columns
+        if stage is None and _mqo.mqo_mode() != "off":
             t0 = _time.perf_counter()
             table = _mqo.try_shared_execute(self)
             if table is not None:
@@ -3460,7 +3589,7 @@ class LoweredPlan:
         # the interpreter declines falls through to the specialized path
         from kolibrie_tpu.optimizer import plan_interp
 
-        if plan_interp.should_interp(self):
+        if stage is None and plan_interp.should_interp(self):
             t0 = _time.perf_counter()
             table = plan_interp.interp_execute(self)
             if table is not None:
@@ -3478,10 +3607,14 @@ class LoweredPlan:
             "disk" if sight is not None and sight["outcome"] == "hit" else "compiled"
         )
         t1 = _time.perf_counter()
-        with _obs_span("device.collect"):
-            table = self.to_table(*parts)
-        _COLLECT_LAT.observe(_time.perf_counter() - t1)
-        nrows = len(next(iter(table.values()))) if table else 0
+        if stage is None:
+            with _obs_span("device.collect"):
+                table = self.to_table(*parts)
+            _COLLECT_LAT.observe(_time.perf_counter() - t1)
+            nrows = len(next(iter(table.values()))) if table else 0
+        else:
+            table, nrows = self._aggregate(stage, tpl, *parts)
+            _AGGREGATE_LAT.observe(_time.perf_counter() - t1)
         self._advise(None, rows=nrows)
         cap = _analyze.active()
         if cap is not None:
@@ -3629,11 +3762,86 @@ def predicate_rows(order, predicate: int) -> Dict[str, np.ndarray]:
     }
 
 
-def _largest_group(keys: np.ndarray) -> int:
-    """Rows of the value that ``keys`` holds most often; 0 of no rows."""
-    if not len(keys):
+class _HotPassCut(Exception):
+    """A hot pass of the twin met a join too large to materialize and
+    counted it without (:func:`_freed_join_rows`).  ``counts``: the pass's
+    join counts up to and with that join, 0 above it.  ``rows``: that
+    count where the join is the plan's topmost (only filters above it, so
+    no group of the freed keys holds more rows, nor more groups), else
+    ``None``."""
+
+    def __init__(self, counts: List[int], rows: Optional[int]):
+        super().__init__(rows)
+        self.counts, self.rows = counts, rows
+
+
+def _top_join(node):
+    """The join whose rows, filtered, are the plan's; ``None`` where the
+    root is no chain of filters over a join."""
+    while isinstance(node, FilterSpec):
+        node = node.child
+    return node if isinstance(node, JoinSpec) else None
+
+
+def _freed_join_rows(lcols, rcols, lkey, rkey) -> Optional[int]:
+    """The most rows one combination of freed keys gives a join, counted
+    without materializing it: each row of the side that carries the freed
+    columns matches as many rows as the other side holds under its key, and
+    the matches are summed by freed tuple.  ``None`` where neither side or
+    both sides carry freed columns (the pass then gives up, as before)."""
+    freed_l, freed_r = _freed_columns(lcols), _freed_columns(rcols)
+    if bool(freed_l) == bool(freed_r):
+        return None
+    freed = freed_l or freed_r
+    own, other = (lkey, rkey) if freed_l else (rkey, lkey)
+    other = np.sort(other)
+    matches = np.searchsorted(other, own, "right") - np.searchsorted(other, own, "left")
+    order = np.lexsort(freed[::-1])
+    starts = np.flatnonzero(_tuple_starts(freed, order))
+    return int(np.add.reduceat(matches[order], starts).max()) if len(own) else 0
+
+
+def _freed_columns(table) -> List[np.ndarray]:
+    """The columns of a twin's table that freed scans' keys ride in."""
+    return [col for name, col in table.items() if name.startswith(_FREE_KEY)]
+
+
+def _tuple_starts(cols, order: np.ndarray) -> np.ndarray:
+    """Mask, over rows taken in ``order`` (sorted by ``cols``), of the rows
+    whose tuple of ``cols`` differs from the row before: each run's first."""
+    starts = np.zeros(len(order), dtype=bool)
+    starts[:1] = True
+    for col in cols:
+        col = col[order]
+        starts[1:] |= col[1:] != col[:-1]
+    return starts
+
+
+def _largest_group(*keys: np.ndarray) -> int:
+    """Rows of the value that ``keys`` holds most often (of the tuple of
+    values that several columns hold most often together); 0 of no rows."""
+    n = len(keys[0])
+    if not n:
         return 0
-    return int(np.unique(keys, return_counts=True)[1].max())
+    if len(keys) == 1:
+        return int(np.unique(keys[0], return_counts=True)[1].max())
+    starts = _tuple_starts(keys, np.lexsort(keys[::-1]))
+    return int(np.diff(np.append(np.flatnonzero(starts), n)).max())
+
+
+def _most_groups(freed, keys) -> int:
+    """The most distinct tuples of the columns ``keys`` that the rows of one
+    tuple of the columns ``freed`` hold (of the whole table where no column
+    is freed); 0 of no rows."""
+    cols = list(freed) + list(keys)
+    if not cols or not len(cols[0]):
+        return 0
+    order = np.lexsort(cols[::-1])
+    new_freed = _tuple_starts(freed, order)
+    new_key = new_freed | _tuple_starts(keys, order)
+    if not freed:
+        return int(new_key.sum())
+    return int(np.bincount(np.cumsum(new_freed)[new_key] - 1).max())
 
 
 def lower_plan(db, plan, anti_plans=(), union_groups=(), optional_plans=()) -> LoweredPlan:
@@ -3864,8 +4072,9 @@ def _segment_aggregate(cols, valid, numf, gpos, funcs, apos, distincts, cap):
     aggregate names (COUNT/SUM/AVG/MIN/MAX/SAMPLE); ``apos``: per-aggregate
     value column position (or -1 for COUNT(*)); ``distincts``: per-aggregate
     DISTINCT flag (honored for COUNT — host parity: other funcs ignore it).
-    Returns (group id cols, f64-or-id agg arrays, n_groups) with static
-    length ``cap`` — readback is O(groups), not O(rows)."""
+    Returns (group id cols, f64-or-id agg arrays, n_groups, n_rows) with
+    static length ``cap`` — readback is O(group capacity), not O(rows);
+    ``n_rows`` counts the valid rows the sort carried among its slots."""
     import jax.numpy as jnp
     from jax import lax
 
@@ -3985,27 +4194,57 @@ def _segment_aggregate(cols, valid, numf, gpos, funcs, apos, distincts, cap):
             )
             agg_out.append(jnp.where(cnt == 0, jnp.nan, maxs))
 
-    return tuple(group_cols), tuple(agg_out), n_groups
+    return tuple(group_cols), tuple(agg_out), n_groups, jnp.sum(valid)
 
 
 _DEVICE_AGG_FUNCS = ("COUNT", "SUM", "AVG", "MIN", "MAX", "SAMPLE")
 
 
-def try_device_execute_aggregated(
-    db, plan, q, lowered: Optional[LoweredPlan] = None
-) -> Optional[BindingTable]:
-    """Plan execution + GROUP BY/aggregation entirely on device; readback is
-    one row per GROUP.  ``None`` → host fallback (plan or aggregate shape
-    not expressible: GROUP_CONCAT, DISTINCT on non-COUNT aggregates,
-    expression group keys).  Any number of group variables (multi-operand
-    key sort), COUNT(DISTINCT ?v), and SAMPLE run on device.  ``lowered``:
-    caller-supplied device lowering of ``plan`` (avoids lowering the same
-    plan twice when the caller also owns the fallback path)."""
+@dataclass(frozen=True)
+class AggregateStage:
+    """A SELECT's GROUP BY and aggregates over a plan's output columns, as
+    the last stage of the plan's dispatch (:meth:`LoweredPlan.execute`) and
+    of the distributed executor's: which columns key the groups, which
+    aggregate reads which column.  ``key`` is what the segment aggregation
+    is compiled for beside the table's width and the group capacity."""
+
+    group_by: tuple  # the group variables' names
+    aliases: tuple  # each aggregate's result name
+    gpos: tuple  # the group columns' positions among the plan's out_vars
+    funcs: tuple  # COUNT / SUM / AVG / MIN / MAX / SAMPLE
+    apos: tuple  # the aggregated column's position, -1 for COUNT(*)
+    distincts: tuple
+
+    @property
+    def key(self) -> tuple:
+        return (self.gpos, self.funcs, self.apos, self.distincts)
+
+    @property
+    def reads_numbers(self) -> bool:
+        """Whether an aggregate reads its column's numeric value (COUNT and
+        SAMPLE read term ids alone)."""
+        return any(
+            f in ("SUM", "AVG", "MIN", "MAX") for f in self.funcs
+        )
+
+
+def aggregate_stage(out_vars, q) -> Optional[AggregateStage]:
+    """The aggregation of SELECT ``q`` over a table of ``out_vars``, or
+    ``None`` where the device declines the shape (GROUP_CONCAT, DISTINCT on
+    an aggregate other than COUNT, an expression in the SELECT list, a
+    group key or an aggregated variable the plan does not bind): the host
+    then aggregates the plan's rows."""
     agg_items = [i for i in q.select if i.kind == "agg"]
     if not agg_items and not q.group_by:
         return None
     if any(i.kind == "expr" for i in q.select):
         return None  # host semantics drop exprs in agg queries; stay exact
+    out_vars = tuple(out_vars)
+    gpos, funcs, apos = [], [], []
+    for g in q.group_by:
+        if g not in out_vars:
+            return None
+        gpos.append(out_vars.index(g))
     for item in agg_items:
         a = item.agg
         if a.func not in _DEVICE_AGG_FUNCS:
@@ -4013,22 +4252,6 @@ def try_device_execute_aggregated(
         if a.distinct and a.func != "COUNT":
             # host parity: DISTINCT only changes COUNT semantics there
             return None
-    if lowered is None:
-        try:
-            lowered = lower_plan(db, plan)
-        except Unsupported:
-            return None
-    if not lowered.const_ok():
-        return None  # empty result; let the host path aggregate nothing
-    out_vars = lowered.out_vars
-    gpos = []
-    for g in q.group_by:
-        if g not in out_vars:
-            return None
-        gpos.append(out_vars.index(g))
-    funcs, apos = [], []
-    for item in agg_items:
-        a = item.agg
         if a.var is None:
             apos.append(-1)
         elif a.var in out_vars:
@@ -4036,12 +4259,39 @@ def try_device_execute_aggregated(
         else:
             return None
         funcs.append(a.func)
-
-    with jax.enable_x64(True):
-        out_cols, valid = lowered.converge(lowered.run())
-    return aggregate_table(
-        db, tuple(out_cols), valid, q.group_by, agg_items, gpos, funcs, apos
+    return AggregateStage(
+        tuple(q.group_by),
+        tuple(i.agg.alias for i in agg_items),
+        tuple(gpos),
+        tuple(funcs),
+        tuple(apos),
+        tuple(bool(i.agg.distinct) for i in agg_items),
     )
+
+
+def try_device_execute_aggregated(
+    db, plan, q, lowered: Optional[LoweredPlan] = None
+) -> Optional[BindingTable]:
+    """Plan execution + GROUP BY/aggregation entirely on device; readback is
+    one row per GROUP.  The aggregation is the last stage of the plan's own
+    dispatch: :meth:`LoweredPlan.execute` with the query's
+    :class:`AggregateStage`, so an aggregate request leaves the spans,
+    histograms, counters and advisor observations every dispatch leaves,
+    and ``device.aggregate`` in place of ``device.collect``.  ``None`` →
+    host fallback (:func:`aggregate_stage` declined the shape, the plan
+    does not lower, or a constant guard empties the result: the host path
+    aggregates nothing).  ``lowered``: caller-supplied device lowering of
+    ``plan`` (avoids lowering the same plan twice when the caller also owns
+    the fallback path)."""
+    if lowered is None:
+        try:
+            lowered = lower_plan(db, plan)
+        except Unsupported:
+            return None
+    stage = aggregate_stage(lowered.out_vars, q)
+    if stage is None or not lowered.const_ok():
+        return None
+    return lowered.execute(stage)
 
 
 def host_quoted_table(db):
@@ -4151,46 +4401,131 @@ def device_numf(db):
     return arr
 
 
-def aggregate_table(
-    db, cols, valid, group_by, agg_items, gpos, funcs, apos
-) -> BindingTable:
-    """Shared aggregate tail: run :func:`_segment_aggregate` with the
-    capacity-retry protocol and decode the per-group results into a host
-    table.  The ONE definition of aggregate readback semantics — used by
-    the single-chip engine and the distributed query executor."""
-    from kolibrie_tpu.query.executor import _encode_numbers
+# aggregations being compiled ahead of their first call, by what they are
+# compiled for; ``None`` once the call has waited for one
+_AHEAD: Dict[tuple, Optional[_threading.Thread]] = {}
 
-    cap = 1024
+
+def _aggregation_shapes(db, slots: int, ncols: int, stage: "AggregateStage"):
+    """The operands of :func:`_segment_aggregate` for a table ``slots`` wide,
+    as shapes: what :func:`aggregate_table` will call it with."""
+    import jax.numpy as jnp
+
+    numf = _round_cap(len(db.numeric_values()), 1024) if stage.reads_numbers else 1
+    return (
+        (jax.ShapeDtypeStruct((slots,), jnp.uint32),) * ncols,
+        jax.ShapeDtypeStruct((slots,), jnp.bool_),
+        jax.ShapeDtypeStruct((numf,), jnp.float64),
+    )
+
+
+def compile_aggregation_ahead(db, slots, ncols, stage, cap) -> None:
+    """Start compiling the aggregation that a template's first dispatch will
+    end in, on a thread of its own, while the caller compiles and runs the
+    plan: the two executables of an aggregate template's first sight cost
+    the longer of their compiles, not the sum (each a third to a half of a
+    minute at a million slots: ``PERF.md`` section 6, PR 42).
+    :func:`aggregate_table` waits for the thread and its call then finds the
+    executable: JAX keeps what a lowering compiled, and the persistent
+    compilation cache holds the entry besides, so without a cache directory
+    nothing is started (a process that caches nothing is not one whose
+    first answers are waited for)."""
+    sig = (slots, ncols, stage.key, cap)
+    if _cc.enabled_dir() is None or sig in _AHEAD:
+        return
+
+    def ahead(cols, valid, numf):
+        return _segment_aggregate.lower(
+            cols, valid, numf, stage.gpos, stage.funcs, stage.apos,
+            stage.distincts, cap,
+        ).compile()
+
+    # the first-sight record is the entry point's, whichever thread compiled
+    ahead.__name__ = _segment_aggregate.__name__
+    shapes = _aggregation_shapes(db, slots, ncols, stage)
+
+    def work():
+        with jax.enable_x64(True):
+            _cc.call(ahead, *shapes)
+
+    thread = _threading.Thread(
+        target=work, name="kolibrie-aggregate-compile", daemon=True
+    )
+    _AHEAD[sig] = thread
+    thread.start()
+
+
+def group_cap_ceiling(slots: int) -> int:
+    """No table has more groups than slots: the most a group capacity is
+    ever compiled for."""
+    return _round_cap(max(int(slots), 1))
+
+
+def aggregate_table(
+    db, cols, valid, stage: AggregateStage, cap_key
+) -> Tuple[BindingTable, int, int]:
+    """Shared aggregate tail: run :func:`_segment_aggregate` at the
+    template's group capacity and decode the per-group results into a host
+    table.  The ONE definition of aggregate readback semantics — used by
+    the single-chip engine and the distributed query executor.
+
+    The group capacity is a static argument of the compiled aggregation, so
+    it is the template's and not the request's: the one this db holds under
+    ``(cap_key, stage.key)`` (what an earlier request fitted in, or what
+    :meth:`LoweredPlan.build` counted or was advised on the template's first
+    sight), else the floor.  Groups beyond it run the aggregation again at
+    a capacity that holds them (a counted retry), and what a run fitted in
+    is remembered, so the next request of the template starts there.
+    Returns ``(table, rows, capacity)``: the valid rows the aggregation
+    sorted and the capacity it converged at."""
+    from kolibrie_tpu.query.executor import _encode_numbers
+    from kolibrie_tpu.query.template import note_aggregate, note_aggregate_retry
+
+    import jax.numpy as jnp
+
+    slots = int(valid.shape[0])
+    ceiling = group_cap_ceiling(slots)
+    cache = db.__dict__.setdefault("_device_group_cap_cache", {})
+    key = (cap_key, stage.key)
+    cap = min(cache.get(key, _CAP_FLOOR), ceiling)
     with jax.enable_x64(True):
-        numf_dev = device_numf(db)
-        for _attempt in range(8):
-            gcols, aggs, n_groups = _segment_aggregate(
+        numf_dev = (
+            device_numf(db) if stage.reads_numbers else jnp.zeros(1, jnp.float64)
+        )
+        while True:
+            sig = (slots, len(cols), stage.key, cap)
+            if _AHEAD.get(sig) is not None:  # being compiled ahead: wait
+                _AHEAD[sig].join()
+                _AHEAD[sig] = None
+            gcols, aggs, n_groups, n_rows = _cc.call(
+                _segment_aggregate,
                 tuple(cols),
                 valid,
                 numf_dev,
-                tuple(gpos),
-                tuple(funcs),
-                tuple(apos),
-                tuple(bool(i.agg.distinct) for i in agg_items),
+                stage.gpos,
+                stage.funcs,
+                stage.apos,
+                stage.distincts,
                 cap,
             )
-            ng = int(n_groups)
+            ng, rows = int(n_groups), int(n_rows)
+            note_aggregate(slots, rows, cap, min(ng, cap))
             if ng <= cap:
                 break
-            cap = _round_cap(2 * ng)
-        else:
-            raise RuntimeError("group capacity failed to converge")
+            note_aggregate_retry()
+            cap = min(_round_cap(2 * ng), ceiling)
+    cache[key] = max(cap, cache.get(key, 0))
     table: BindingTable = {}
-    for g, col in zip(group_by, gcols):
+    for g, col in zip(stage.group_by, gcols):
         table[g] = np.asarray(col)[:ng].astype(np.uint32)
     enc = db.dictionary.encode
-    for item, arr in zip(agg_items, aggs):
-        if item.agg.func == "SAMPLE":
+    for func, alias, arr in zip(stage.funcs, stage.aliases, aggs):
+        if func == "SAMPLE":
             # the aggregate IS a term id, not a numeric result
-            table[item.agg.alias] = np.asarray(arr)[:ng].astype(np.uint32)
+            table[alias] = np.asarray(arr)[:ng].astype(np.uint32)
         else:
-            table[item.agg.alias] = _encode_numbers(enc, np.asarray(arr)[:ng])
-    return table
+            table[alias] = _encode_numbers(enc, np.asarray(arr)[:ng])
+    return table, rows, cap
 
 
 # ---------------------------------------------------------------------------

@@ -1279,7 +1279,10 @@ class DistQueryExecutor:
         into the single-chip device segment aggregator (same program the
         engine uses — one definition of aggregate semantics); readback is
         one row per group."""
-        from kolibrie_tpu.optimizer.device_engine import aggregate_table
+        from kolibrie_tpu.optimizer.device_engine import (
+            aggregate_stage,
+            aggregate_table,
+        )
         from kolibrie_tpu.query.executor import (
             _apply_limit_offset,
             _order_table,
@@ -1290,21 +1293,12 @@ class DistQueryExecutor:
         outs, valid, _total, _nan = self.run_device()
         flat_cols = tuple(jnp.reshape(c, (-1,)) for c in outs)
         flat_valid = jnp.reshape(valid, (-1,))
-        gpos = [self.out_vars.index(g) for g in q.group_by]
-        funcs, apos = [], []
-        for item in self.agg_items:
-            a = item.agg
-            funcs.append(a.func)
-            apos.append(-1 if a.var is None else self.out_vars.index(a.var))
-        table = aggregate_table(
-            self.db,
-            flat_cols,
-            flat_valid,
-            q.group_by,
-            self.agg_items,
-            gpos,
-            funcs,
-            apos,
+        # the shapes __init__ let through are the ones the stage takes; the
+        # group capacity is kept a shape of query (its output columns and
+        # aggregates), as the single-chip engine keeps it a template
+        stage = aggregate_stage(self.out_vars, q)
+        table, _rows, _cap = aggregate_table(
+            self.db, flat_cols, flat_valid, stage, ("dist", self.out_vars)
         )
         table = _order_table(self.db, table, q.order_by)
         rows = format_results(self.db, table, q, sort_rows=not q.order_by)

@@ -103,7 +103,10 @@ _COMPILE_SECONDS.labels("disk")
 # The closed sets behind the first-sight families' labels.  Every value is
 # registered here, as ``source``'s two are, so a family that counted nothing
 # reads 0 and its metric prints.
-ENTRIES = ("run_plan", "run_plan_batch", "run_interp", "mesh", "other")
+ENTRIES = (
+    "run_plan", "run_plan_batch", "run_interp", "segment_aggregate", "mesh",
+    "other",
+)
 OUTCOMES = ("hit", "miss_new", "miss_entry_lost", "miss_key_moved", "uncached")
 
 _TRACE_SECONDS = _metrics.counter(

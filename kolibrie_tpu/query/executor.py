@@ -50,6 +50,7 @@ from kolibrie_tpu.optimizer.stats_advisor import (
     set_current_fp as _sa_set_current_fp,
 )
 from kolibrie_tpu.query.parser import parse_combined_query
+from kolibrie_tpu.query.template import note_aggregate_tier
 from kolibrie_tpu.resilience.breaker import breaker_board
 from kolibrie_tpu.resilience.deadline import check_deadline
 from kolibrie_tpu.resilience.errors import DeadlineExceeded, is_device_fault
@@ -403,6 +404,7 @@ def eval_select_to_table(
             db, q, use_optimizer, cache_entry=cache_entry
         )
         if table is not None:
+            note_aggregate_tier("device")
             if q.distinct:
                 table = unique_table(table)
             return table
@@ -421,6 +423,9 @@ def eval_select_to_table(
         capture=cache_entry,
     )
     if q.group_by or any(i.kind == "agg" for i in q.select):
+        # the plan's rows came to the host (from the device where the store
+        # is served there: query.execute's path says so, not this counter)
+        note_aggregate_tier("host")
         table = _group_and_aggregate_table(db, table, q)
     else:
         if not q.select_all():

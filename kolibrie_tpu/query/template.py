@@ -59,6 +59,8 @@ __all__ = [
     "cap_advisor_enabled",
     "note_cap_occupancy",
     "note_scan_occupancy",
+    "note_aggregate",
+    "note_aggregate_tier",
     "note_scan_tiers",
     "note_range_searches",
     "occupancy_pct",
@@ -329,6 +331,67 @@ def note_scan_occupancy(engine: str, slots: int, rows: int) -> None:
     _SCAN_ROWS.labels(engine).inc(rows)
 
 
+# what a GROUP BY on the device costs and holds: per run of the segment
+# aggregation (optimizer/device_engine.py aggregate_table), the width of the
+# plan's table it sorts and the rows of it that are valid, the group capacity
+# it was compiled for and the groups it returned; a run whose groups passed
+# the capacity is run again at a larger one and counted as a retry
+_AGG_SLOTS = metrics.counter(
+    "kolibrie_device_aggregate_slots_total",
+    "compiled width of the tables the device aggregations sorted, summed "
+    "over their runs",
+)
+_AGG_ROWS = metrics.counter(
+    "kolibrie_device_aggregate_rows_total",
+    "valid rows of the tables the device aggregations sorted, summed over "
+    "their runs",
+)
+_GROUP_SLOTS = metrics.counter(
+    "kolibrie_device_group_slots_total",
+    "group capacity the device aggregations were compiled for, summed over "
+    "their runs",
+)
+_GROUPS = metrics.counter(
+    "kolibrie_device_groups_total",
+    "groups the device aggregations returned, summed over their runs",
+)
+_AGG_CAP_RETRIES = metrics.counter(
+    "kolibrie_aggregate_cap_retries_total",
+    "device aggregations run again because the groups passed the group "
+    "capacity (a template's group capacity is counted on its first sight, "
+    "so this stays 0 in steady state)",
+)
+# where a request's GROUP BY and aggregates ran: fused behind the plan on the
+# device, or over the plan's rows on the host (a shape the device declines,
+# a store served from the host); query.execute's path says where the request
+# was routed, not this
+_AGG_TIER = metrics.counter(
+    "kolibrie_aggregate_total",
+    "SELECTs with GROUP BY or an aggregate, by where the aggregation ran",
+    labels=("tier",),
+)
+_AGG_TIER.labels("device")
+_AGG_TIER.labels("host")
+
+
+def note_aggregate(slots: int, rows: int, group_slots: int, groups: int) -> None:
+    """One run of the device aggregation sorted ``slots`` slots holding
+    ``rows`` rows into ``groups`` groups of ``group_slots`` compiled."""
+    _AGG_SLOTS.inc(slots)
+    _AGG_ROWS.inc(rows)
+    _GROUP_SLOTS.inc(group_slots)
+    _GROUPS.inc(groups)
+
+
+def note_aggregate_retry() -> None:
+    _AGG_CAP_RETRIES.inc()
+
+
+def note_aggregate_tier(tier: str) -> None:
+    """One SELECT's GROUP BY ran on ``tier`` (``device`` or ``host``)."""
+    _AGG_TIER.labels(tier).inc()
+
+
 # how often an empty delta tier is not searched: per dispatch, one a scan
 # and one a WCOJ accessor, by the branch the plan body takes for its order
 # (optimizer/device_engine.py _plan_body: the same host entries it uploads)
@@ -467,6 +530,25 @@ class CapAdvisor:
             if base_version is not None:
                 rec["base_version"] = int(base_version)
 
+    def advise_groups(self, engine: str, fp: str) -> Optional[int]:
+        """The template's group capacity (an aggregate template's one
+        capacity beside its joins'), or ``None`` when cold or disabled."""
+        if not cap_advisor_enabled():
+            return None
+        with self._lock:
+            rec = self._entries.get((engine, fp))
+            return None if rec is None else rec.get("group_cap")
+
+    def observe_groups(self, engine: str, fp: str, cap: int) -> None:
+        """Record the group capacity an aggregation ran within (monotonic
+        max, kept with the join capacities of the same template)."""
+        with self._lock:
+            rec = self._entries.setdefault(
+                (engine, fp),
+                {"caps": (), "retries": 0, "base_version": None},
+            )
+            rec["group_cap"] = max(int(cap), rec.get("group_cap", 0))
+
     def observe_retry(self, engine: str, fp: str, n: int = 1) -> None:
         """Count an overflow-driven doubled-cap re-dispatch (the waste the
         advisor is eliminating)."""
@@ -501,6 +583,8 @@ class CapAdvisor:
                         "hwm": max(rec["caps"]) if rec["caps"] else 0,
                         "retries": rec["retries"],
                         "base_version": rec["base_version"],
+                        # an aggregate template's one capacity more
+                        "group_cap": rec.get("group_cap"),
                     }
                     for (eng, fp), rec in self._entries.items()
                 },

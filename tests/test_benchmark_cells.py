@@ -7,8 +7,9 @@ as ISSUE 32 states it and ``lubm50.triangles`` (``lubm-50``, nothing cut) as
 ISSUE 34 does, ISSUE 35's three range-search metrics are data files for the
 two triangles cells, ``lubm50.lookups`` (``lookups`` against ``lubm-50``) and
 the two join-search metrics are in as ISSUE 39 states them, ``watdiv-100``,
-``watdiv100.stars_snowflakes`` and the two scan metrics as ISSUE 40 does
-(eight cells of six configurations, one of four chips), every file a cell or a
+``watdiv100.stars_snowflakes`` and the two scan metrics as ISSUE 40 does,
+``bsbm-10m``, ``bsbm10m.bi_counts`` and the seven aggregate metrics as ISSUE
+42 does (nine cells of seven configurations, one of four chips), every file a cell or a
 per-layer metric names is there, a program that lacks what a cell
 requires of it (``benchmark/requires``) is refused before anything starts,
 and ``run.py`` itself, started off the chip, prints no result and exits 3
@@ -393,11 +394,12 @@ WATDIV_DIGESTS = {
 }
 
 
-def test_benchmark_json_has_watdiv_100_uncut_and_its_cell_as_the_last():
+def test_benchmark_json_has_watdiv_100_uncut_and_its_cell_as_the_eighth():
     """ISSUE 40: one configuration, one cell of one chip, two per-layer
     entries, all appended; every file new; no standing list took the cell
-    in, so it reports ``cycle_ms``, ``setup_s`` and what has no list."""
-    entry, cell = BENCH["configs"][-1], BENCH["workloads"][-1]
+    in, so it reports ``cycle_ms``, ``setup_s`` and what has no list.
+    (ISSUE 42 appended its own behind them.)"""
+    entry, cell = BENCH["configs"][5], BENCH["workloads"][7]
     assert (entry["name"], entry["file"], entry["reduced"]) == (
         "watdiv-100", "benchmark/configs/watdiv-100.json", [])
     assert cell == {**cell, "name": "watdiv100.stars_snowflakes", "config": "watdiv-100",
@@ -424,7 +426,7 @@ def test_benchmark_json_has_watdiv_100_uncut_and_its_cell_as_the_last():
     need = files.read_json("requires", cell["name"] + ".json")
     assert (need["module"], need["registers"]) == (
         "kolibrie_tpu.query.template", "kolibrie_device_scan_slots_total")
-    added = BENCH["per_layer"][-len(SCAN_METRICS):]
+    added = BENCH["per_layer"][76:76 + len(SCAN_METRICS)]
     assert [m["name"] for m in added] == list(SCAN_METRICS)
     for m in added:
         family, better = SCAN_METRICS[m["name"]]
@@ -474,6 +476,144 @@ def test_one_client_of_stars_and_snowflakes_sends_these_texts(seed):
     assert h.hexdigest() == WATDIV_DIGESTS[seed]
 
 
+AGGREGATE_METRICS = {
+    # name: (reader kind, reader arguments, unit, better, source, layer)
+    "aggregate_ms": (
+        "span_total", {"spans": ["device.aggregate"]},
+        "ms", "lower", "program_span", "device dispatch"),
+    "aggregate_slots_in_window": (
+        "counter_delta", {"prefix": "metrics.kolibrie_device_aggregate_slots_total"},
+        "count", "lower", "program_counter", "device dispatch"),
+    "aggregate_rows_in_window": (
+        "counter_delta", {"prefix": "metrics.kolibrie_device_aggregate_rows_total"},
+        "count", "higher", "program_counter", "device dispatch"),
+    "group_slots_in_window": (
+        "counter_delta", {"prefix": "metrics.kolibrie_device_group_slots_total"},
+        "count", "lower", "program_counter", "device dispatch"),
+    "groups_in_window": (
+        "counter_delta", {"prefix": "metrics.kolibrie_device_groups_total"},
+        "count", "higher", "program_counter", "device dispatch"),
+    "aggregate_retries_in_window": (
+        "counter_delta", {"prefix": "metrics.kolibrie_aggregate_cap_retries_total"},
+        "count", "lower", "program_counter", "device dispatch"),
+    "host_aggregates_in_window": (
+        "counter_delta", {"prefix": 'metrics.kolibrie_aggregate_total{tier="host"}',
+                          "beside": "metrics.kolibrie_aggregate_total"},
+        "count", "lower", "program_counter", "executor: decode and format"),
+}
+# sha256 over the texts of the warm-up's 5 cycles and the window's first 8,
+# at 200 products, by seed: what one client of ``bi_counts`` sends
+BSBM_DIGESTS = {
+    0: "a5432a265084d9e188a557cb93ef71e83af47334b73cdc9350cd3789ca6b22b9",
+    1: "bf105825b9eb4d552c0ac09b1376861d7d863b88ee451c0324d217c45681a04a",
+    2: "fcc45400243f45bad3721007fb447e896a1e6206cd4847f81f729bb711be3634",
+}
+
+
+def test_benchmark_json_has_bsbm_10m_and_its_cell_as_the_last():
+    """ISSUE 42: one configuration, one cell of one chip, seven per-layer
+    entries that list this cell alone, all appended; every file new; no
+    standing list took the cell in, so it reports ``cycle_ms``, ``setup_s``
+    and what has no list."""
+    entry, cell = BENCH["configs"][-1], BENCH["workloads"][-1]
+    assert (entry["name"], entry["file"], entry["reduced"]) == (
+        "bsbm-10m", "benchmark/configs/bsbm-10m.json", ["queries", "top_k"])
+    assert cell == {**cell, "name": "bsbm10m.bi_counts", "config": "bsbm-10m",
+                    "traffic": "bi_counts", "chips": 1}
+    assert set(cell) == {"name", "config", "traffic", "chips", "why"}
+    assert max(len(entry["source"]), len(entry["why"]), len(cell["why"])) <= 200
+    config = files.read_json("configs", "bsbm-10m.json")
+    lubm = files.read_json("configs", "lubm-50.json")
+    assert config["source"] == entry["source"]
+    for words in ("BSBM V3.1", "Business Intelligence use case", "BI Q1, Q2, Q5"):
+        assert words in entry["source"]
+    assert (config["products"], config["chips"], config["store_mode"],
+            config["generator"]) == (28480, 1, "device", "bsbm")
+    assert sorted(config["reduced"]) == ["queries", "top_k"] and all(
+        len(why) > 100 for why in config["reduced"].values())
+    for key in ("guarantees", "control"):  # lubm-50's, letter for letter
+        assert config[key] == lubm[key], key
+    assert sorted(config["domains"]) == ["country1", "country2", "product", "producttype"]
+    assert len(config["assumed"]) == 11 and all(config["assumed"])
+    assert files.read_json("workloads", cell["name"] + ".json") == {"env": {}}
+    templates = sorted(f for f in os.listdir(files.path("templates"))
+                       if f.startswith("bsbm_"))
+    assert templates == ["bsbm_bi_q1.rq", "bsbm_bi_q2.rq", "bsbm_bi_q5.rq"]
+    need = files.read_json("requires", cell["name"] + ".json")
+    assert (need["module"], need["registers"]) == (
+        "kolibrie_tpu.query.template", "kolibrie_device_aggregate_slots_total")
+    added = BENCH["per_layer"][-len(AGGREGATE_METRICS):]
+    assert [m["name"] for m in added] == list(AGGREGATE_METRICS)
+    for m in added:
+        kind, args, unit, better, source, layer = AGGREGATE_METRICS[m["name"]]
+        assert m == {"name": m["name"], "unit": unit, "better": better,
+                     "source": source, "layer": layer, "moves": "cycle_ms",
+                     "workloads": [cell["name"]]}
+        assert files.read_json("layer_metrics", m["name"] + ".json")["reader"] == {
+            "kind": kind, **args}
+        assert os.path.exists(files.path("readers", kind + ".py"))
+    for m in BENCH["per_layer"] + BENCH["end_to_end"]:
+        if m["name"] not in AGGREGATE_METRICS:
+            assert cell["name"] not in m.get("workloads", [])
+    # the ninth cell came behind the eight, which stand as they stood
+    assert [w["name"] for w in BENCH["workloads"]][:8] == [
+        "lubm5.triangles", "lubm5.lookups", "employee100k.upstream", "lubm5.mesh4",
+        "lubm5.batch8", "lubm50.triangles", "lubm50.lookups",
+        "watdiv100.stars_snowflakes"]
+
+
+@pytest.mark.parametrize("name", sorted(AGGREGATE_METRICS))
+def test_an_aggregate_metric_reads_its_source_and_nothing_of_a_program_without_it(name):
+    """The readers run on the parent's checkout too: a program without the
+    family (or the span) reports nothing and nothing raises; the tier's
+    ``host`` line is registered at import, and reads 0 beside the family
+    where it has not grown."""
+    from kolibrie_tpu.obs import export, metrics
+    from kolibrie_tpu.query import template  # noqa: F401  (registers the families)
+
+    kind, args = AGGREGATE_METRICS[name][:2]
+    reader = files.load_module("readers", kind)
+    if kind == "span_total":
+        span = {"name": "device.aggregate", "dur_ms": 7.5, "span_id": "a", "parent_id": ""}
+        other = {"name": "device.collect", "dur_ms": 2.0, "span_id": "b", "parent_id": ""}
+        ctx = {"cycles": [{"trace_ids": ["t0", "t1"]}],
+               "spans_by_trace": {"t0": [span, other], "t1": [span]}}
+        assert reader.read(ctx, **args) == pytest.approx(15.0)
+        ctx["spans_by_trace"] = {"t0": [other], "t1": [other]}
+        assert reader.read(ctx, **args) is None  # the parent opens no such span
+        return
+    family = args["prefix"][len("metrics."):].partition("{")[0]
+    assert metrics.REGISTRY.get(family) is not None
+    assert args["prefix"][len("metrics."):] + " " in export.render_prometheus()
+    there = {"counters0": {args["prefix"]: 1048576.0, "metrics.kolibrie_other_total": 1.0},
+             "counters1": {args["prefix"]: 3145728.0, "metrics.kolibrie_other_total": 3.0}}
+    assert reader.read(there, **args) == pytest.approx(2097152.0)
+    lacking = {key: {"metrics.kolibrie_device_scan_slots_total": 1.0} for key in there}
+    assert reader.read(lacking, **args) is None
+    if "beside" in args:
+        device = 'metrics.kolibrie_aggregate_total{tier="device"}'
+        never_grew = {key: {device: 4.0} for key in there}
+        assert reader.read(never_grew, **args) == 0.0
+
+
+@pytest.mark.parametrize("seed", sorted(BSBM_DIGESTS))
+def test_one_client_of_bi_counts_sends_these_texts(seed):
+    data = generated("bsbm10m.bi_counts", seed, 2)
+    traffic = Traffic("bi_counts", data["domains"], seed)
+    assert traffic.clients == 1 and traffic.warmup_ramp == [1]
+    assert len(traffic.warmup_counts()) == 5
+    h = hashlib.sha256()
+    for stream, n in (("warmup", 5), ("window", 8)):
+        for k in range(n):
+            cycle = traffic.cycle(k, stream)
+            assert [name for name, _ in cycle] == [
+                "bsbm_bi_q1", "bsbm_bi_q2", "bsbm_bi_q5"]
+            assert not any("@" in text.split("WHERE")[1] for _, text in cycle)
+            for name, text in cycle:
+                h.update(f"{stream}\0{k}\0{name}\0{text}\0".encode())
+    assert h.hexdigest() == BSBM_DIGESTS[seed]
+
+
 @pytest.mark.parametrize("workload", sorted(CELLS))
 def test_every_file_a_cell_names_is_there(workload):
     cell = CELLS[workload]
@@ -502,8 +642,8 @@ def test_every_per_layer_metric_has_its_file_and_its_reader():
 
 def test_at_most_half_the_cells_take_four_chips():
     four = [w["name"] for w in BENCH["workloads"] if w["chips"] == 4]
-    assert four == ["lubm5.mesh4"] and len(CELLS) == 8
-    assert len(BENCH["configs"]) == 6
+    assert four == ["lubm5.mesh4"] and len(CELLS) == 9
+    assert len(BENCH["configs"]) == 7
     assert len(four) <= max(1, len(CELLS) // 2)
     assert json.dumps(BENCH).count('"chips": 4') == 1
 
@@ -514,8 +654,8 @@ def test_every_requirement_belongs_to_a_cell_and_names_a_documented_metric():
     with open(os.path.join(REPO, "docs", "OBSERVABILITY.md"), encoding="utf-8") as f:
         catalog = f.read()
     found = sorted(os.listdir(files.path("requires")))
-    assert found == ["lubm5.batch8.json", "lubm5.mesh4.json",
-                     "watdiv100.stars_snowflakes.json"]
+    assert found == ["bsbm10m.bi_counts.json", "lubm5.batch8.json",
+                     "lubm5.mesh4.json", "watdiv100.stars_snowflakes.json"]
     for name in found:
         assert name[:-len(".json")] in CELLS
         need = files.read_json("requires", name)
@@ -529,7 +669,8 @@ def test_every_requirement_belongs_to_a_cell_and_names_a_documented_metric():
 @pytest.mark.parametrize(
     "family", ["kolibrie_test_required_total"] + [
         files.read_json("requires", cell + ".json")["registers"]
-        for cell in ("lubm5.batch8", "lubm5.mesh4", "watdiv100.stars_snowflakes")])
+        for cell in ("lubm5.batch8", "lubm5.mesh4", "watdiv100.stars_snowflakes",
+                     "bsbm10m.bi_counts")])
 def test_a_program_without_the_required_metric_is_refused_at_once(
         tmp_path, monkeypatch, registered, family):
     """Each cell's own family too, asked of a program whose registry is
@@ -557,7 +698,8 @@ def test_a_program_without_the_required_metric_is_refused_at_once(
 
 
 @pytest.mark.parametrize("workload", ["employee100k.upstream", "lubm5.batch8",
-                                      "watdiv100.stars_snowflakes"])
+                                      "watdiv100.stars_snowflakes",
+                                      "bsbm10m.bi_counts"])
 def test_off_the_chip_run_py_prints_no_result_and_exits_3(tmp_path, workload):
     """A number from a CPU run is never written as a result: without a TPU
     ``benchmark/run.py`` says on standard error what it found, prints

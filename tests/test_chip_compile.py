@@ -169,6 +169,26 @@ def test_tag_combine(one_chip):
 # --------------------------------------------------------------- whole plan
 
 
+def test_segment_aggregate_at_the_bsbm_cells_widths(one_chip):
+    """The aggregation that ends BI Q5's dispatch at 10 M triples (ISSUE 42):
+    four columns 2,097,152 slots wide, COUNT(?review) GROUP BY ?country
+    ?product into 1,048,576 group slots: one multi-operand sort and the
+    scatter-reductions, no kernel of ours."""
+    from kolibrie_tpu.optimizer.device_engine import _segment_aggregate
+
+    n, cap = 1 << 21, 1 << 20
+    f64 = jax.ShapeDtypeStruct((1,), jnp.float64)
+    with jax.enable_x64(True):
+        compiled = _compile(
+            _segment_aggregate, one_chip, (_u32(n),) * 4, _bool(n), f64,
+            want_kernel=False, gpos=(0, 1), funcs=("COUNT",), apos=(2,),
+            distincts=(False,), cap=cap)
+    text = compiled.as_text()
+    assert "sort" in text and "scatter" in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 30  # a sixteenth of the chip
+
+
 @pytest.fixture(scope="module")
 def lubm_db():
     """A small real device-mode store; its argument tree gives the whole
