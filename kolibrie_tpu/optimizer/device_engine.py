@@ -167,11 +167,17 @@ class Unsupported(Exception):
 
 # ---------------------------------------------------------------------------
 # The capacity rule (docs/COMPILE_CACHE.md "Capacity protocol").  A join or
-# WCOJ level is compiled for the rows the template has been seen to produce,
-# with headroom, and never for more than the inputs' capacities suggest: the
-# search loops, the compaction sorts and the readback all cost slots, not
-# rows.  Overflow (a variant with more than _CAP_HEADROOM x the calibrated
-# rows) is the protocol's business, not the rule's.
+# WCOJ level is compiled for the rows the template has been seen to produce
+# and never for more than the inputs' capacities suggest: the search loops,
+# the compaction sorts, the gathers and the readback all cost slots, not
+# rows.  The rule has two arms.  A count that is one instance's (or the most
+# of the instances some passes saw) gets headroom, _CAP_HEADROOM x, for the
+# instances not yet seen.  A count that is a CEILING, the most rows any
+# instance of the text gives the join on the store as it stands (the
+# calibration says which: LoweredPlan._calibration_counts), gets none: there
+# is no instance left to leave room for.  Overflow (a variant with more than
+# the headroom, a store that grew past a ceiling) is the protocol's business,
+# not the rule's.
 # ---------------------------------------------------------------------------
 _CAP_HEADROOM = 4
 _CAP_FLOOR = 1024
@@ -182,14 +188,25 @@ _CAP_FLOOR = 1024
 _CALIBRATE_ROW_LIMIT = 8_000_000
 
 
-def fit_join_caps(heuristic: Sequence[int], counts: Sequence[int]) -> List[int]:
+def fit_join_caps(
+    heuristic: Sequence[int],
+    counts: Sequence[int],
+    ceilings: Sequence[bool] = (),
+) -> List[int]:
     """THE capacity rule, per join and per WCOJ level:
-    ``min(heuristic, round_cap(max(H x count, FLOOR)))``.  Every path that
-    sizes a join from counts (the calibrated start, the tighten-once
-    fallback, ``calibrate_host``) goes through here."""
+    ``min(heuristic, round_cap(max(H x count, FLOOR)))``, and where
+    ``ceilings[i]`` says that no instance of the text can pass ``counts[i]``
+    the same without the ``H``.  Every path that sizes a join from counts
+    (the calibrated start, the tighten-once fallback, ``calibrate_host``)
+    goes through here; one that holds a single instance's counts passes no
+    ``ceilings`` and keeps the headroom."""
+    ceilings = tuple(ceilings) or (False,) * len(counts)
     return [
-        min(int(h), _round_cap(max(_CAP_HEADROOM * int(c), _CAP_FLOOR)))
-        for h, c in zip(heuristic, counts)
+        min(
+            int(h),
+            _round_cap(max((1 if top else _CAP_HEADROOM) * int(c), _CAP_FLOOR)),
+        )
+        for h, c, top in zip(heuristic, counts, ceilings)
     ]
 
 
@@ -2316,9 +2333,11 @@ class LoweredPlan:
         (every variant shares it: one executable a template); the
         process-wide advisor's high-water mark for the fingerprint (a
         fresh db, or a ``cap_key`` that moved with store growth); else a
-        calibrated start, the rows this variant produces with headroom
-        (:func:`fit_join_caps`), counted by the numpy twin before the
-        first executable is chosen.  The baggage fingerprint is "unknown"
+        calibrated start (:func:`fit_join_caps`), counted by the numpy twin
+        before the first executable is chosen: the most rows any instance
+        of the text gives a join where the twin could count that (a
+        ceiling: :meth:`_calibration_counts`), the rows it saw with
+        headroom elsewhere.  The baggage fingerprint is "unknown"
         for direct engine construction (tests, EXPLAIN): no advice then,
         so unrelated callers never cross-pollinate."""
         cache = self.db.__dict__.setdefault("_device_cap_cache", {})
@@ -2326,7 +2345,7 @@ class LoweredPlan:
         if cached is not None and len(cached) == self.join_count:
             return list(cached)
         heuristic = self._heuristic_join_caps(scan_caps)
-        from kolibrie_tpu.query.template import cap_advisor
+        from kolibrie_tpu.query.template import cap_advisor, note_calibrated_caps
 
         fp = _get_baggage("template", "unknown")
         if fp != "unknown":
@@ -2335,19 +2354,21 @@ class LoweredPlan:
                 return list(advised)
         if max(heuristic, default=0) <= _CAP_FLOOR:
             return heuristic  # nothing the rule could tighten
-        counts = self._calibration_counts()
-        if counts is None:
+        counted = self._calibration_counts()
+        if counted is None:
             # the host pass would be too large: run once at the heuristic
             # and let converge() tighten from the counts that run reads
             self.db.__dict__.setdefault("_device_cap_provisional", set()).add(
                 self.cap_key
             )
             return heuristic
-        caps = fit_join_caps(heuristic, counts)
+        counts, ceilings = counted
+        caps = fit_join_caps(heuristic, counts, ceilings)
+        note_calibrated_caps("device", sum(ceilings), len(ceilings) - sum(ceilings))
         cache[self.cap_key] = tuple(caps)
         return caps
 
-    def _calibration_counts(self) -> Optional[List[int]]:
+    def _calibration_counts(self) -> Optional[Tuple[List[int], List[bool]]]:
         """Exact per-join counts from the numpy twin (no device I/O): those
         of this variant and, for each scan that binds its predicate and a
         subject or an object, the most rows any one key of that scan gives
@@ -2364,18 +2385,31 @@ class LoweredPlan:
         left out where it cannot (the overflow protocol keeps what it exceeds
         exact).
 
+        Beside each count, whether it is a CEILING, the most rows any
+        instance of the text gives that join on the store as it stands:
+        every parameter read beneath the join is the key of a keyed scan
+        (:meth:`_freed_beneath`), and a pass that freed all of those counted
+        the join, by its largest group (the variant's own pass where nothing
+        beneath reads a parameter).  The joins a cut or left-out pass did
+        not count, and whatever sits over a parameter no pass frees, are one
+        instance's and say so.
+
         Where the dispatch ends in an aggregation (``_stage``) every pass
         counts its groups too, the same way: ``_calibrated_groups`` is the
         most groups of this variant or of any one key (combination of keys)
-        of a pass, ``None`` where the variant's own pass gave up."""
+        of a pass, ``None`` where the variant's own pass gave up;
+        ``_groups_are_ceiling`` where every pass ran to its end and nothing
+        under the aggregation reads a parameter they do not free."""
         from kolibrie_tpu.ops.join import RowLimitExceeded
         from kolibrie_tpu.query.template import cap_calibrate_seconds
 
         stage = self._stage
         groups = 0
+        finished: List[Tuple[tuple, Sequence[int]]] = []  # (freed, joins counted)
+        complete = True
 
         def timed(outcome, free_scan=()):
-            nonlocal groups
+            nonlocal groups, complete
             t0 = _time.perf_counter()
             try:
                 table, counts = self.host_execute(_CALIBRATE_ROW_LIMIT, free_scan)
@@ -2385,15 +2419,19 @@ class LoweredPlan:
                         [table[g] for g in stage.group_by],
                     )
                     groups = max(groups, found)
+                finished.append((free_scan, range(self.join_count)))
                 return counts
             except _HotPassCut as cut:
                 # counted up to the join it could not materialize; a topmost
                 # join's rows bound its groups
                 if stage is not None and cut.rows is not None:
                     groups = max(groups, cut.rows)
+                finished.append((free_scan, cut.reached))
+                complete = False
                 return cut.counts
             except RowLimitExceeded:
                 outcome = "too_large"
+                complete = False
                 return None
             finally:
                 cap_calibrate_seconds.labels(outcome).inc(
@@ -2415,10 +2453,74 @@ class LoweredPlan:
                 self.last_host_stats = own_stats
             if sp is not None:
                 sp.attrs["hot_passes"] = len(passes)
-        if stage is not None and counts is not None:
+            if counts is None:
+                return None
+            beneath, under_root = self._freed_beneath(keyed)
+            ceilings = [
+                beneath.get(j) is not None
+                and any(
+                    beneath[j] <= set(free) and j in counted
+                    for free, counted in finished
+                )
+                for j in range(self.join_count)
+            ]
+            if sp is not None:
+                sp.attrs["ceilings"] = sum(ceilings)
+        if stage is not None:
             # SPARQL: without GROUP BY one group, of no rows too
             self._calibrated_groups = max(groups, 1)
-        return counts
+            self._groups_are_ceiling = complete and under_root is not None
+        return counts, ceilings
+
+    def _freed_beneath(self, keyed: Sequence[int]):
+        """What the hot passes have to free for a count to hold for every
+        instance of the text: by join index, and for the root, the scans of
+        ``keyed`` beneath it, or ``None`` where something beneath reads a
+        parameter that no pass frees: a scan that binds a subject or an
+        object without being keyed, a FILTER that compares with a constant
+        (the parameter vectors' or a mask's), a VALUES table, a quoted
+        pattern's inner constant, a WCOJ.  The predicate a scan names is no
+        parameter: it is structure in ``cap_key``.  A branch that only takes
+        rows away or fills them in (MINUS, OPTIONAL, UNION) counts whole
+        passes, not groups of a key: with a parameter in it nothing above is
+        a ceiling, and an OPTIONAL's own count is one only over no
+        parameter at all.  A join index that is missing (a WCOJ level's) is
+        no ceiling either."""
+        beneath: Dict[int, Optional[frozenset]] = {}
+        nothing: frozenset = frozenset()
+
+        def both(a, b):
+            return None if a is None or b is None else a | b
+
+        def walk(node) -> Optional[frozenset]:
+            if isinstance(node, ScanSpec):
+                if node.scan_idx in keyed:
+                    return frozenset((node.scan_idx,))
+                consts = self.scan_descs[node.scan_idx][1]
+                return nothing if consts[0] is None and consts[2] is None else None
+            if isinstance(node, JoinSpec):
+                beneath[node.join_idx] = both(walk(node.left), walk(node.right))
+                return beneath[node.join_idx]
+            if isinstance(node, FilterSpec):
+                below = walk(node.child)
+                return None if _reads_a_constant(node.expr) else below
+            if isinstance(node, QuotedExpandSpec):
+                below = walk(node.child)
+                return None if node.const_checks else below
+            if isinstance(node, (AntiJoinSpec, LeftOuterSpec)):
+                left, branch = walk(node.left), walk(node.right)
+                if isinstance(node, LeftOuterSpec):
+                    whole = both(left, branch)
+                    beneath[node.join_idx] = whole if whole == nothing else None
+                return left if branch == nothing else None
+            if isinstance(node, UnionSpec):
+                parts = [walk(ch) for ch in node.children]
+                return nothing if all(p == nothing for p in parts) else None
+            if isinstance(node, (ValuesSpec, WcojSpec)):
+                return None
+            raise TypeError(node)
+
+        return beneath, walk(self.root)
 
     def _calibrate_group_cap(self) -> None:
         """Where the dispatch ends in an aggregation and this db holds no
@@ -2438,7 +2540,7 @@ class LoweredPlan:
         key = (self.cap_key, stage.key)
         if key in cache:
             return
-        from kolibrie_tpu.query.template import cap_advisor
+        from kolibrie_tpu.query.template import cap_advisor, note_calibrated_caps
 
         fp = _get_baggage("template", "unknown")
         advised = (
@@ -2455,7 +2557,11 @@ class LoweredPlan:
                 self._calibration_counts()
             if self._calibrated_groups is None:
                 return
-            cache[key] = fit_join_caps([ceiling], [self._calibrated_groups])[0]
+            top = self._groups_are_ceiling
+            (cache[key],) = fit_join_caps(
+                [ceiling], [self._calibrated_groups], [top]
+            )
+            note_calibrated_caps("device", int(top), int(not top))
         # the template's first sight compiles two executables, the plan's and
         # the aggregation's: the second beside the first, not after it
         compile_aggregation_ahead(
@@ -2609,6 +2715,7 @@ class LoweredPlan:
         scan_ranges = self._host_scan_ranges()
         numf = self.db.numeric_values() if self.need_numf else None
         counts: List[int] = [0] * self.join_count
+        reached: List[int] = []  # the joins counted so far (a cut pass's)
         # numpy twin of _plan_body's analyze stats: same keys, same
         # pre-order sequence numbering for index-less nodes — the
         # EXPLAIN ANALYZE oracle tests assert exact agreement
@@ -2739,7 +2846,9 @@ class LoweredPlan:
                         raise
                     counts[node.join_idx] = most
                     raise _HotPassCut(
-                        counts, most if node is _top_join(self.root) else None
+                        counts,
+                        reached + [node.join_idx],
+                        most if node is _top_join(self.root) else None,
                     ) from None
                 hstats[f"join{node.join_idx}"] = len(li)
                 out = {v: c[li] for v, c in lcols.items()}
@@ -2750,6 +2859,7 @@ class LoweredPlan:
                 counts[node.join_idx] = (
                     _largest_group(*freed) if freed else len(li)
                 )
+                reached.append(node.join_idx)
                 return out
             if isinstance(node, FilterSpec):
                 skey = f"filter{hseq['filter']}"
@@ -2811,6 +2921,7 @@ class LoweredPlan:
                 rcols = eval_node(node.right)
                 ln = len(next(iter(lcols.values())))
                 rn = len(next(iter(rcols.values())))
+                reached.append(node.join_idx)
                 if ln == 0 or rn == 0:
                     counts[node.join_idx] = 0
                     hstats[f"optional{node.join_idx}"] = ln
@@ -3551,6 +3662,7 @@ class LoweredPlan:
     # and the groups a calibration of this build counted for it
     _stage: Optional["AggregateStage"] = None
     _calibrated_groups: Optional[int] = None
+    _groups_are_ceiling = False
 
     def execute(self, stage: Optional["AggregateStage"] = None) -> BindingTable:
         """Run to completion with capacity validation; returns a host table:
@@ -3765,14 +3877,24 @@ def predicate_rows(order, predicate: int) -> Dict[str, np.ndarray]:
 class _HotPassCut(Exception):
     """A hot pass of the twin met a join too large to materialize and
     counted it without (:func:`_freed_join_rows`).  ``counts``: the pass's
-    join counts up to and with that join, 0 above it.  ``rows``: that
+    join counts up to and with that join, 0 above it.  ``reached``: the
+    indices of the joins it counted, that one last.  ``rows``: that
     count where the join is the plan's topmost (only filters above it, so
     no group of the freed keys holds more rows, nor more groups), else
     ``None``."""
 
-    def __init__(self, counts: List[int], rows: Optional[int]):
+    def __init__(self, counts: List[int], reached: List[int], rows: Optional[int]):
         super().__init__(rows)
-        self.counts, self.rows = counts, rows
+        self.counts, self.reached, self.rows = counts, reached, rows
+
+
+def _reads_a_constant(expr) -> bool:
+    """Whether a FILTER's expression compares with a constant of the text:
+    one that rides in the parameter vectors, or one a mask was computed
+    from.  Neither is part of ``cap_key``."""
+    if isinstance(expr, BoolNode):
+        return any(_reads_a_constant(a) for a in expr.args)
+    return isinstance(expr, (IdCmp, NumConstCmp, MaskRef, StrMaskRef))
 
 
 def _top_join(node):

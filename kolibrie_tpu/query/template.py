@@ -296,6 +296,27 @@ cap_calibrate_seconds.labels("counted")
 cap_calibrate_seconds.labels("hot_key")
 cap_calibrate_seconds.labels("too_large")
 
+# which arm of the capacity rule a template's first sight took, join by join
+# (group tables under the same two kinds): "ceiling" where the calibration
+# counted the most rows any instance of the text can give and the capacity
+# is that count's, "headroom" where the count is the instances' it saw and
+# the capacity is H times it (optimizer/device_engine.py fit_join_caps)
+_CALIBRATED_JOINS = metrics.counter(
+    "kolibrie_cap_calibrated_joins_total",
+    "joins, WCOJ levels and group tables the host calibration sized on a "
+    "template's first sight, by engine and by the rule's arm",
+    labels=("engine", "kind"),
+)
+_CALIBRATED_JOINS.labels("device", "ceiling")
+_CALIBRATED_JOINS.labels("device", "headroom")
+
+
+def note_calibrated_caps(engine: str, ceilings: int, headroom: int) -> None:
+    """A first sight sized ``ceilings`` capacities at counts no instance can
+    pass and ``headroom`` at counts with room over them."""
+    _CALIBRATED_JOINS.labels(engine, "ceiling").inc(ceilings)
+    _CALIBRATED_JOINS.labels(engine, "headroom").inc(headroom)
+
 
 def note_cap_occupancy(engine: str, slots: int, rows: int) -> None:
     """One dispatch ran ``slots`` join slots and counted ``rows`` rows."""

@@ -13,7 +13,9 @@
   against hottest first gives one capacity set, one plan executable and one
   aggregation executable, after which no instance re-runs the aggregation;
   a pair of hot countries, which no pass that frees one of them counts, is
-  counted by the pass that frees both;
+  counted by the pass that frees both; every type, pair of countries and
+  product runs from the capacities the first request calibrated, joins and
+  group tables at the counts no instance can pass (ISSUE 44);
 - (d) an aggregate request leaves ``device.dispatch`` and
   ``device.aggregate``; a shape the device declines grows ``tier="host"``;
   the counters read the slots, rows, group slots and groups of one request;
@@ -370,6 +372,62 @@ def test_a_pair_of_hot_countries_is_counted_by_the_pass_that_frees_both(
     assert sorted(map(tuple, _ask(base, stores["cold_first"], us_us))) == sorted(
         map(tuple, ref.query(us_us)))
     assert 'kolibrie_cap_retries_total{engine="device"}' not in _grew(before)
+
+
+KINDS = tuple(f'kolibrie_cap_calibrated_joins_total{{engine="device",kind="{kind}"}}'
+              for kind in ("ceiling", "headroom"))
+
+
+def _kinds():
+    return tuple(_metric(name) for name in KINDS)
+
+
+def _kinds_since(before):
+    return tuple(int(now - then) for now, then in zip(_kinds(), before))
+
+
+def test_every_instance_of_every_domain_runs_at_the_ceilings(server, generated, skewed):
+    """ISSUE 44: a count that no instance can pass is compiled for as it is.
+    At 300 products every type, every pair of countries and every product
+    gives the reference's rows from the capacities the first request
+    calibrated: no join capacity passed, no aggregation run twice.  BI Q5's
+    three joins and its group table and BI Q1's six joins are ceilings (two
+    of Q5's joins read no placeholder at all, the others are counted by the
+    passes that free the placeholders); BI Q2's join is one, its group table
+    sits over ``FILTER(?otherProduct != <product>)``, which no pass frees."""
+    _httpd, base = server
+    cap_advisor.reset()
+    sid = _load(base, generated)
+    ref = Reference(generated["terms"], generated["s"], generated["p"], generated["o"])
+    before, sized = _counters(), {}
+    for template, domains in TEMPLATES.items():
+        kinds0 = _kinds()
+        n = len(generated["domains"][domains[0]])
+        assert n == {"bsbm_bi_q1": 100, "bsbm_bi_q2": 300, "bsbm_bi_q5": 36}[template]
+        for k in range(n):
+            text = _instance(generated, template, k)
+            assert sorted(map(tuple, _ask(base, sid, text))) == sorted(
+                map(tuple, ref.query(text))), (template, k)
+            if k == 0:
+                sized[template] = _kinds_since(kinds0)
+        assert _kinds_since(kinds0) == sized[template]  # the first sight's alone
+    grew = _grew(before)
+    assert grew['kolibrie_aggregate_total{tier="device"}'] == 436
+    assert "kolibrie_aggregate_cap_retries_total" not in grew
+    assert 'kolibrie_cap_retries_total{engine="device"}' not in grew
+    assert 'kolibrie_aggregate_total{tier="host"}' not in grew
+    # joins and then the group table, where it is wider than the floor: Q2's
+    # join holds under 1,024 rows here, and a table of 1,024 slots has no
+    # more groups whatever the rule says
+    assert sized == {"bsbm_bi_q1": (7, 0), "bsbm_bi_q2": (1, 0), "bsbm_bi_q5": (4, 0)}
+    # at 2,000 products Q2's join passes the floor and the rule sizes its
+    # group table: with headroom, the join beneath it without
+    cap_advisor.reset()
+    sid = _load(base, skewed)
+    kinds0 = _kinds()
+    text = _instance(skewed, "bsbm_bi_q2", 0)
+    assert len(_ask(base, sid, text)) > 0
+    assert _kinds_since(kinds0) == (1, 1)
 
 
 # ------------------------------------- (d) spans, tiers and the counters

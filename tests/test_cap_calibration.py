@@ -5,9 +5,14 @@ The rule (``device_engine.fit_join_caps``): a join is compiled for
 coming from the numpy twin on the template's first sight on a db: this
 variant's, and the variant's with each scan that binds a subject or an
 object at its predicate's hottest key (ISSUE 40), so that the first instance
-does not decide them.  After that the caps only grow (the overflow protocol,
-max-merged: what a fan-out the hot keys do not show still exceeds), so a
-template keeps one executable across its constants.  What must hold: answers stay
+does not decide them.  Where such a count is a ceiling, the most rows any
+instance of the text can give the join on the store as it stands, the rule
+leaves the ``H`` out (ISSUE 44, section (f)): a count is one where every
+parameter beneath the join is a keyed scan's and a pass that freed them all
+counted it.  After that the caps only grow (the overflow protocol,
+max-merged: what a store that grew past a ceiling, or an instance past the
+headroom, still exceeds), so a template keeps one executable across its
+constants.  What must hold: answers stay
 exact whatever the caps, a template's variants neither retry nor recompile
 once its first request is through, and the counters that say how full the
 slots ran add up.
@@ -57,16 +62,23 @@ def device_rows(db, q):
 
 
 def lowered(db, q):
+    """The lowering ``eval_where`` and ``_try_device_aggregate`` give a text,
+    with the aggregation its dispatch would end in."""
     from kolibrie_tpu.optimizer.engine import resolve_pattern
     from kolibrie_tpu.optimizer.planner import Streamertail, build_logical_plan
     from kolibrie_tpu.query.parser import parse_sparql_query
+    from kolibrie_tpu.query.subquery_inline import inline_subqueries
 
-    w = parse_sparql_query(q).where
+    query = parse_sparql_query(q)
+    w = inline_subqueries(query.where)
     resolved = [resolve_pattern(db, p) for p in w.patterns]
     plan = Streamertail(db.get_or_build_stats()).find_best_plan(
-        build_logical_plan(resolved, list(w.filters), [], None)
+        build_logical_plan(resolved, list(w.filters), [], w.values)
     )
-    return de.lower_plan(db, plan)
+    low = de.lower_plan(db, plan)
+    if query.group_by or any(i.kind == "agg" for i in query.select):
+        low._stage = de.aggregate_stage(low.out_vars, query)
+    return low
 
 
 # ------------------------------------------------- (a) LUBM(1), every constant
@@ -160,6 +172,36 @@ def uniform_db(depts=600, members=10) -> SparqlDatabase:
         e = f"<http://example.org/e{i}>"
         lines.append(f'{e} <http://example.org/dept> "{names[i % depts]}" .')
         lines.append(f'{e} <http://example.org/salary> "{i % 97}" .')
+    db = SparqlDatabase()
+    db.parse_ntriples("\n".join(lines))
+    db.execution_mode = "device"
+    return db
+
+
+def tagged_db() -> SparqlDatabase:
+    """60 items share 50 tags, 2 share 3 others; every item has a name."""
+    lines = []
+    for a in range(62):
+        for k in range(50 if a < 60 else 3):
+            tag = f"big{k}" if a < 60 else f"small{k}"
+            lines.append(f"<http://example.org/a{a}> <http://example.org/tag> "
+                         f"<http://example.org/{tag}> .")
+        lines.append(f'<http://example.org/a{a}> <http://example.org/name> "n{a}" .')
+    db = SparqlDatabase()
+    db.parse_ntriples("\n".join(lines))
+    db.execution_mode = "device"
+    return db
+
+
+def half_salaried_db() -> SparqlDatabase:
+    """6,000 members of "big" and 10 of "small"; the second half of "big" and
+    "small" have a salary."""
+    lines = []
+    for i in range(6010):
+        e = f"<http://example.org/e{i}>"
+        lines.append(f'{e} <http://example.org/dept> "{"big" if i < 6000 else "small"}" .')
+        if i >= 3000:
+            lines.append(f'{e} <http://example.org/salary> "{i % 97}" .')
     db = SparqlDatabase()
     db.parse_ntriples("\n".join(lines))
     db.execution_mode = "device"
@@ -266,7 +308,7 @@ def test_the_hot_keys_capacity_is_there_from_the_first_instance():
     retries0 = retries()
     hot0 = counter('kolibrie_cap_calibrate_seconds_total{outcome="hot_key"}')
     assert device_rows(db, dept_query("small")) == host_rows(db, dept_query("small"))
-    assert cached_caps(db) == (16384,)  # the heuristic: under H x 6,000 rounded
+    assert cached_caps(db) == (8192,)  # the large one's 6,000 rows, a ceiling: no H
     compiled = de.device_compile_stats()["run_plan"]
     assert compiled - compiled0 <= 1  # (another test may have built it)
     assert counter('kolibrie_cap_calibrate_seconds_total{outcome="hot_key"}') > hot0
@@ -274,13 +316,13 @@ def test_the_hot_keys_capacity_is_there_from_the_first_instance():
         rows = device_rows(db, dept_query(dept))
         assert len(rows) == (6000 if dept == "big" else 10)
         assert rows == host_rows(db, dept_query(dept))
-    assert cached_caps(db) == (16384,) and retries() == retries0
+    assert cached_caps(db) == (8192,) and retries() == retries0
     assert de.device_compile_stats()["run_plan"] == compiled
     # the large department first: the same capacities, the same executable
     cap_advisor.reset()
     other = skewed_db()
     assert len(device_rows(other, dept_query("big"))) == 6000
-    assert cached_caps(other) == (16384,)
+    assert cached_caps(other) == (8192,)
     assert de.device_compile_stats()["run_plan"] == compiled
 
 
@@ -302,7 +344,7 @@ def test_a_fan_out_that_no_scans_rows_show_is_counted_at_the_join():
     retries0 = retries()
     assert len(device_rows(db, dept_query("small"))) == 10
     (cap,) = cached_caps(db)
-    assert cap >= 6000
+    assert cap == de._round_cap(6000)  # the ten members' 600 salaries each, and no more
     assert device_rows(db, dept_query("big")) == host_rows(db, dept_query("big"))
     assert cached_caps(db) == (cap,) and retries() == retries0
 
@@ -340,8 +382,8 @@ def test_a_product_of_two_hot_keys_is_counted_by_the_pass_that_frees_both():
     caps = cached_caps(db)
     # a department freed with the team as it is counts 6 rows at the last
     # join, a team freed with the department as it is 6; both freed, the pair
-    # of "big" and "big" counts 2,400
-    assert caps[-1] == de._round_cap(de._CAP_HEADROOM * 2400)
+    # of "big" and "big" counts 2,400: no pair of this store has more
+    assert caps[-1] == de._round_cap(2400)
     both = device_rows(db, q("big", "big"))
     assert len(both) == 2400 and both == host_rows(db, q("big", "big"))
     for dept, team in (("big", "big"), ("small", "small"), ("big", "small")):
@@ -358,15 +400,7 @@ def test_a_hot_pass_whose_join_passes_the_row_limit_counts_it_unmaterialized(
     ends there.  The template starts at its hottest key; where the join is
     the plan's topmost its rows bound the groups of an aggregation too."""
     cap_advisor.reset()
-    lines = []
-    for a in range(62):  # 60 items share 50 tags, 2 share 3 others
-        for k in range(50 if a < 60 else 3):
-            tag = f"big{k}" if a < 60 else f"small{k}"
-            lines.append(f"<http://example.org/a{a}> <http://example.org/tag> "
-                         f"<http://example.org/{tag}> .")
-    db = SparqlDatabase()
-    db.parse_ntriples("\n".join(lines))
-    db.execution_mode = "device"
+    db = tagged_db()
     # a scan reads 3,006 rows, the instance's own pass joins 6, the freed one
     # 50 x 60 x 60 + 3 x 2 x 2
     monkeypatch.setattr(de, "_CALIBRATE_ROW_LIMIT", 4000)
@@ -382,17 +416,18 @@ def test_a_hot_pass_whose_join_passes_the_row_limit_counts_it_unmaterialized(
     large0 = counter('kolibrie_cap_calibrate_seconds_total{outcome="too_large"}')
     assert device_rows(db, q("a61")) == host_rows(db, q("a61"))
     assert counter('kolibrie_cap_calibrate_seconds_total{outcome="too_large"}') == large0
-    # an item of the 60 joins 50 tags x 60 items: the rule's 16,384 under the
-    # heuristic's ceiling, twice the wider scan (the instance's own 6 rows
-    # alone leave the floor)
-    cap = 2 * de._round_cap(3006 + db.store.delta_device_cap)
-    assert de._CAP_FLOOR < cap < de._round_cap(de._CAP_HEADROOM * 3000)
+    # an item of the 60 joins 50 tags x 60 items, counted exactly though never
+    # materialized: a ceiling, under the heuristic's twice the wider scan (the
+    # instance's own 6 rows alone leave the floor)
+    cap = de._round_cap(3000)
+    assert de._CAP_FLOOR < cap < 2 * de._round_cap(3006 + db.store.delta_device_cap)
     assert cached_caps(db) == (cap,)
     big = device_rows(db, q("a7"))
     assert len(big) == (60 if grouped else 3000) and big == host_rows(db, q("a7"))
     assert cached_caps(db) == (cap,) and retries() == retries0
     assert counter("kolibrie_aggregate_cap_retries_total") == agg0
-    if grouped:  # no group of the freed key holds more groups than rows
+    if grouped:  # no group of the freed key holds more groups than rows: a
+        # bound from a pass that was cut, so with headroom, under the table's width
         (group_cap,) = db.__dict__["_device_group_cap_cache"].values()
         assert group_cap == cap
 
@@ -422,15 +457,7 @@ def test_a_hot_pass_over_the_row_limit_is_left_out_and_the_first_instance_sizes(
     template is its first instance's: the large department then overflows
     once and is answered exactly."""
     cap_advisor.reset()
-    lines = []
-    for i in range(6010):  # 6,000 of "big", half of them with a salary
-        e = f"<http://example.org/e{i}>"
-        lines.append(f'{e} <http://example.org/dept> "{"big" if i < 6000 else "small"}" .')
-        if i >= 3000:
-            lines.append(f'{e} <http://example.org/salary> "{i % 97}" .')
-    db = SparqlDatabase()
-    db.parse_ntriples("\n".join(lines))
-    db.execution_mode = "device"
+    db = half_salaried_db()
     # the instance's own pass reads 10 and 3,010 rows, the freed scan 6,010
     monkeypatch.setattr(de, "_CALIBRATE_ROW_LIMIT", 4000)
     retries0 = retries()
@@ -502,36 +529,63 @@ def is_pow2(n: int) -> bool:
     return n > 0 and n & (n - 1) == 0
 
 
+HEADROOM_CASES = [
+    (2_097_152, 28, 1024),  # LUBM Q4's last join
+    (2_097_152, 12_065, 65_536),  # LUBM Q8's
+    (65_536, 100, 1024),  # LUBM Q2, level z
+    (65_536, 1_548, 8_192),  # LUBM Q2, level x
+    (65_536, 25_000, 65_536),  # one instance's 25,000: the heuristic stays
+    (65_536, 6_164, 32_768),  # the nested SELECT
+    (65_536, 0, 1024),  # nothing counted: the floor
+    (65_536, 256, 1024),  # H x count exactly at the floor
+    (65_536, 257, 2048),  # one row over
+    (65_536, 10**9, 65_536),  # never above the heuristic
+    (256, 3, 256),  # a small store: the heuristic is under the floor
+    (128, 10**6, 128),
+]
+CEILING_CASES = [
+    (2_097_152, 284_800, 524_288),  # BI Q5's joins: every review
+    (1_048_576, 12_202, 16_384),  # LUBM(5) Q8's
+    (65_536, 25_000, 32_768),  # the employee join: no constant at all
+    (65_536, 4096, 4096),  # at a power of two: that power
+    (65_536, 4095, 4096),  # just under
+    (65_536, 4097, 8192),  # just over
+    (65_536, 28, 1024),  # under the floor: the floor
+    (65_536, 0, 1024),
+    (65_536, 1024, 1024),  # the headroom's arm leaves the floor at 257 rows
+    (16_384, 20_000, 16_384),  # clipped by the heuristic (the protocol's then)
+    (256, 3, 256),
+]
+
+
 @pytest.mark.parametrize(
-    "heuristic,count,expected",
-    [
-        (2_097_152, 28, 1024),  # LUBM Q4's last join
-        (2_097_152, 12_065, 65_536),  # LUBM Q8's
-        (65_536, 100, 1024),  # LUBM Q2, level z
-        (65_536, 1_548, 8_192),  # LUBM Q2, level x
-        (65_536, 25_000, 65_536),  # the employee join: the heuristic stays
-        (65_536, 6_164, 32_768),  # the nested SELECT
-        (65_536, 0, 1024),  # nothing counted: the floor
-        (65_536, 256, 1024),  # H x count exactly at the floor
-        (65_536, 257, 2048),  # one row over
-        (65_536, 10**9, 65_536),  # never above the heuristic
-        (256, 3, 256),  # a small store: the heuristic is under the floor
-        (128, 10**6, 128),
-    ],
+    "heuristic,count,expected,ceiling",
+    [case + (False,) for case in HEADROOM_CASES]
+    + [case + (True,) for case in CEILING_CASES],
 )
-def test_capacity_rule(heuristic, count, expected):
-    (cap,) = de.fit_join_caps([heuristic], [count])
+def test_capacity_rule(heuristic, count, expected, ceiling):
+    (cap,) = de.fit_join_caps([heuristic], [count], [ceiling])
     assert cap == expected
     assert cap <= heuristic
     assert cap >= min(de._CAP_FLOOR, heuristic)
     assert is_pow2(cap)
-    # headroom wherever the heuristic leaves room for it
-    assert cap >= min(heuristic, de._CAP_HEADROOM * count)
+    headroom = de.fit_join_caps([heuristic], [count])
+    if ceiling:
+        assert cap >= min(heuristic, count)  # the ceiling fits
+        assert cap < 2 * max(count, de._CAP_FLOOR)  # nothing beyond the rounding
+        assert cap <= headroom[0]
+    else:
+        assert headroom == [cap]  # no flags: the headroom's arm
+        # headroom wherever the heuristic leaves room for it
+        assert cap >= min(heuristic, de._CAP_HEADROOM * count)
 
 
 def test_capacity_rule_is_elementwise():
     caps = de.fit_join_caps([131_072, 524_288, 1_048_576], [4, 51, 12_065])
     assert caps == [1024, 1024, 65_536]
+    mixed = de.fit_join_caps(
+        [131_072, 524_288, 1_048_576], [4, 12_065, 12_065], [True, True, False])
+    assert mixed == [1024, 16_384, 65_536]
 
 
 # ------------------------------------------------------- (d) the counters
@@ -705,3 +759,286 @@ def case_two_texts_of_one_shape_are_two_templates(db):
 def test_a_scan_is_as_wide_as_the_predicate_it_names(case):
     cap_advisor.reset()
     case(two_predicates_db())
+
+
+# ------------------- (f) a count that no instance can pass needs no headroom
+
+
+KINDS = ("ceiling", "headroom")
+
+
+def calibrated_kinds():
+    return tuple(
+        counter(f'kolibrie_cap_calibrated_joins_total{{engine="device",kind="{kind}"}}')
+        for kind in KINDS)
+
+
+def kinds_since(before):
+    return tuple(int(now - then) for now, then in zip(calibrated_kinds(), before))
+
+
+def staffed_db(sizes=(3000, 400, 10, 1)) -> SparqlDatabase:
+    """Departments "d0", "d1", ... of the given sizes; every member has a
+    salary and a boss, and carries one tag the others of its department share."""
+    lines, i = [], 0
+    for d, size in enumerate(sizes):
+        for _ in range(size):
+            e = f"<http://example.org/e{i}>"
+            lines.append(f'{e} <http://example.org/dept> "d{d}" .')
+            lines.append(f'{e} <http://example.org/salary> "{i % 97}" .')
+            lines.append(f"{e} <http://example.org/boss> <http://example.org/e{i // 10}> .")
+            i += 1
+    db = SparqlDatabase()
+    db.parse_ntriples("\n".join(lines))
+    db.execution_mode = "device"
+    return db
+
+
+def staff_query(dept: str, tail: str = "") -> str:
+    return PREFIX + (
+        f'SELECT ?e ?s ?b WHERE {{ ?e ex:dept "{dept}" . ?e ex:salary ?s . '
+        f"?e ex:boss ?b {tail}}}")
+
+
+def test_every_instance_runs_at_the_ceiling_with_no_retry_and_one_executable():
+    """The smallest department comes first; the template is compiled for the
+    largest one's rows as they are, and every department of the store runs
+    there: no instance is left for a headroom to wait for."""
+    cap_advisor.reset()
+    sizes = (3000, 400, 10, 1)
+    db = staffed_db(sizes)
+    kinds0, retries0 = calibrated_kinds(), retries()
+    compiled0 = de.device_compile_stats()["run_plan"]
+    for d in reversed(range(len(sizes))):
+        rows = device_rows(db, staff_query(f"d{d}"))
+        assert len(rows) == sizes[d] and rows == host_rows(db, staff_query(f"d{d}"))
+        assert cached_caps(db) == (de._round_cap(3000),) * 2
+    assert device_rows(db, staff_query("no such")) == []
+    assert kinds_since(kinds0) == (2, 0)  # both joins, on the first sight alone
+    assert retries() == retries0
+    assert de.device_compile_stats()["run_plan"] - compiled0 == 1
+    low = lowered(db, staff_query("d3"))
+    assert low._calibration_counts() == ([3000, 3000], [True, True])
+    # the headroom's arm would have asked for four times the slots
+    assert de.fit_join_caps([10**9] * 2, [3000, 3000]) == [16384, 16384]
+
+
+def case_a_parameterised_filter(monkeypatch):
+    """The planner leaves a FILTER over the joins, where it takes nothing
+    from their counts; beneath a join (a branch's, pushed here by hand) the
+    rows that reach the join depend on its constant, which no pass frees."""
+    import dataclasses
+
+    low = lowered(staffed_db(), staff_query("d2", ". FILTER(?s > 50) "))
+    top = low.root.child
+    assert isinstance(low.root, de.FilterSpec) and isinstance(top.left, de.JoinSpec)
+    assert low._calibration_counts() == ([3000, 3000], [True, True])
+    low.root = dataclasses.replace(top, left=de.FilterSpec(top.left, low.root.expr))
+    counts, ceilings = low._calibration_counts()
+    assert ceilings[top.left.join_idx] and not ceilings[top.join_idx]
+    for tail in (". FILTER(?e != ex:e3) ", '. FILTER(REGEX(?s, "^4")) '):
+        low = lowered(staffed_db(sizes=(40, 2)), staff_query("d1", tail))
+        top = low.root.child
+        low.root = dataclasses.replace(top, left=de.FilterSpec(top.left, low.root.expr))
+        assert not low._calibration_counts()[1][top.join_idx], tail
+    # a comparison of two variables reads no constant
+    assert not de._reads_a_constant(de.NumCmp("<", "a", "b"))
+    assert de._reads_a_constant(de.BoolNode("or", (
+        de.NumCmp("<", "a", "b"), de.BoolNode("not", (de.IdCmp("=", "a", 0),)))))
+
+
+def case_a_cut_hot_pass(monkeypatch):
+    """The pass that frees the item is cut at the first join, which it counts
+    without materializing it, exactly: a ceiling.  The join above it the pass
+    never reached: the instance's own 6 rows, with headroom."""
+    db = tagged_db()
+    q = PREFIX + "SELECT ?o ?t ?n WHERE {{ ex:{} ex:tag ?t . ?o ex:tag ?t . ?o ex:name ?n }}"
+    assert lowered(db, q.format("a61"))._calibration_counts() == (
+        [3000, 3000], [True, True])
+    monkeypatch.setattr(de, "_CALIBRATE_ROW_LIMIT", 4000)
+    assert lowered(db, q.format("a61"))._calibration_counts() == ([3000, 6], [True, False])
+    kinds0, retries0 = calibrated_kinds(), retries()
+    assert device_rows(db, q.format("a61")) == host_rows(db, q.format("a61"))
+    assert kinds_since(kinds0) == (1, 1)
+    assert cached_caps(db) == (de._round_cap(3000), de._CAP_FLOOR)
+    # what the headroom could not hold the protocol does, once
+    big = device_rows(db, q.format("a7"))
+    assert len(big) == 3000 and big == host_rows(db, q.format("a7"))
+    assert retries() == retries0 + 1
+
+
+def case_a_left_out_hot_pass(monkeypatch):
+    """The freed scan alone passes the row limit: no pass counted anything
+    for the other departments, and the count is this instance's."""
+    db = half_salaried_db()
+    # the instance's own pass reads 10 and 3,010 rows, the freed scan 6,010
+    monkeypatch.setattr(de, "_CALIBRATE_ROW_LIMIT", 4000)
+    low = lowered(db, dept_query("small"))
+    assert low._keyed_scans() == [0]
+    assert low._calibration_counts() == ([10], [False])
+    monkeypatch.setattr(de, "_CALIBRATE_ROW_LIMIT", 8_000_000)
+    assert low._calibration_counts() == ([3000], [True])
+
+
+def case_a_scan_bound_but_not_keyed(monkeypatch):
+    """A subject with no predicate beside it, and a constant the dictionary
+    does not know: neither is a keyed scan's, so no pass frees it."""
+    db = staffed_db()
+    q = PREFIX + "SELECT ?p ?s WHERE {{ ex:{} ?p ?o . ?x ex:boss ?o . ?x ex:salary ?s }}"
+    low = lowered(db, q.format("e7"))
+    assert low._keyed_scans() == []
+    counts, ceilings = low._calibration_counts()
+    assert ceilings == [False, False] and max(counts) == 10
+    low = lowered(db, staff_query("no such"))
+    assert low._keyed_scans() == []
+    assert low._calibration_counts() == ([0, 0], [False, False])
+    # the predicate alone is structure: nothing to free, a ceiling by its own count
+    low = lowered(db, PREFIX + "SELECT ?e ?s ?b WHERE { ?e ex:salary ?s . ?e ex:boss ?b }")
+    assert low._calibration_counts() == ([3411], [True])
+
+
+def case_a_wcoj_level(monkeypatch):
+    """A WCOJ level's constants ride in the parameter vector and its count
+    follows the accessor that leads: the levels keep the rule they had."""
+    db = staffed_db()
+    low = lowered(db, PREFIX + (
+        "SELECT ?a ?b ?c WHERE { ?a ex:boss ?b . ?b ex:boss ?c . ?c ex:boss ?a }"))
+    assert isinstance(low.root, de.WcojSpec)
+    counts, ceilings = low._calibration_counts()
+    assert len(counts) == 3 and ceilings == [False] * 3
+    kinds0 = calibrated_kinds()
+    q = PREFIX + "SELECT ?a ?b ?c WHERE { ?a ex:boss ?b . ?b ex:boss ?c . ?c ex:boss ?a }"
+    assert device_rows(db, q) == host_rows(db, q)
+    assert kinds_since(kinds0) == (0, 3)
+
+
+def case_a_branch_with_a_parameter(monkeypatch):
+    """OPTIONAL, MINUS and UNION count whole passes, not groups of a key: an
+    OPTIONAL's own count is a ceiling only over no parameter at all, and a
+    branch that reads one leaves nothing above it a ceiling."""
+    db = staffed_db()
+    kinds0 = calibrated_kinds()
+    for body, kinds in (
+        ("?e ex:dept ?d . OPTIONAL { ?e ex:boss ?b }", (1, 0)),
+        ('?e ex:dept "d2" . OPTIONAL { ?e ex:boss ?b }', (0, 1)),
+        ('?e ex:dept "d2" . ?e ex:salary ?b . MINUS { ?e ex:boss ex:e301 }', (1, 0)),
+        ('{ ?e ex:dept "d2" } UNION { ?e ex:dept "d3" } . ?e ex:boss ?b', (0, 1)),
+        ("{ ?e ex:salary ?b } UNION { ?e ex:boss ?b } . ?e ex:dept ?d", (1, 0)),
+    ):
+        q = PREFIX + f"SELECT ?e ?b WHERE {{ {body} }}"
+        assert device_rows(db, q) == host_rows(db, q), body
+        assert kinds_since(kinds0) == kinds, body
+        kinds0 = calibrated_kinds()
+    # and above a MINUS whose branch names a constant, by hand: the planner
+    # composes MINUS over the whole group, so no join of its own sits there
+    low = lowered(db, staff_query("d2"))
+    inner = low.root.left
+    branch = de.ScanSpec(0, 0, (("e", 0),), (), 0)
+    low.root = de.JoinSpec(
+        de.AntiJoinSpec(inner, branch, ("e",)), low.root.right, ("e",), 1, 0)
+    assert low._calibration_counts()[1] == [True, False]
+
+
+@pytest.mark.parametrize("case", [
+    case_a_parameterised_filter,
+    case_a_cut_hot_pass,
+    case_a_left_out_hot_pass,
+    case_a_scan_bound_but_not_keyed,
+    case_a_wcoj_level,
+    case_a_branch_with_a_parameter,
+], ids=lambda case: case.__name__[5:])
+def test_a_count_is_no_ceiling_above(case, monkeypatch):
+    cap_advisor.reset()
+    case(monkeypatch)
+
+
+def test_a_group_table_keeps_its_headroom_under_a_parameterised_filter():
+    """BI Q2's shape: the join under the FILTER is a ceiling, the groups over
+    it depend on the FILTER's constant, which no pass frees."""
+    cap_advisor.reset()
+    db = skewed_db()
+    text = PREFIX + (
+        'SELECT ?s (COUNT(?e) AS ?n) WHERE {{ ?e ex:dept "{}" . ?e ex:salary ?s {}}} '
+        "GROUP BY ?s")
+    retries0, agg0 = retries(), counter("kolibrie_aggregate_cap_retries_total")
+    for tail, kinds in (("", (2, 0)), (". FILTER(?e != ex:e3) ", (1, 1))):
+        kinds0 = calibrated_kinds()
+        for dept, groups in (("small", 10), ("big", 97)):
+            q = text.format(dept, tail)
+            rows = device_rows(db, q)
+            assert len(rows) == groups and rows == host_rows(db, q)
+        assert kinds_since(kinds0) == kinds, tail  # the join, then the group table
+    assert set(db.__dict__["_device_group_cap_cache"].values()) == {de._CAP_FLOOR}
+    assert retries() == retries0
+    assert counter("kolibrie_aggregate_cap_retries_total") == agg0
+
+
+def test_a_write_that_passes_a_ceiling_costs_one_retry():
+    """A ceiling is the store's at first sight, and ``_device_cap_cache`` is
+    not dropped on a write: the department that grows past it meets the
+    overflow protocol once, is answered exactly, and runs at the doubled
+    capacity from then on, as an instance past the headroom always did."""
+    cap_advisor.reset()
+    db = skewed_db(big=1500, small=10)
+    assert device_rows(db, dept_query("small")) == host_rows(db, dept_query("small"))
+    assert cached_caps(db) == (2048,)  # 1,500 rows, no headroom
+    retries0 = retries()
+    db.parse_ntriples("\n".join(
+        f'<http://example.org/e{i}> <http://example.org/dept> "big" .\n'
+        f'<http://example.org/e{i}> <http://example.org/salary> "{i % 97}" .'
+        for i in range(20_000, 20_600)))
+    big = device_rows(db, dept_query("big"))
+    assert len(big) == 2100 and big == host_rows(db, dept_query("big"))
+    assert retries() == retries0 + 1
+    assert cached_caps(db) == (de._round_cap(2 * 2100),)
+    for dept in ("small", "big"):
+        assert device_rows(db, dept_query(dept)) == host_rows(db, dept_query(dept))
+    assert retries() == retries0 + 1
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("config,traffic,sample", [
+    ("bsbm-10m", "bi_counts", 2000),  # 584 types, 100 pairs, 2,000 of 28,480 products
+    ("watdiv-100", "stars_snowflakes", 2000),
+    ("lubm-5", "lookups", 0),
+    ("lubm-50", "lookups", 0),
+    ("employee-100k", "upstream", 0),
+])
+def test_no_instance_passes_a_ceiling_at_full_scale(config, traffic, sample):
+    """That a ceiling is one, on the CPU, twin alone (minutes and gigabytes: not
+    tier-1): every instance of every domain of a cell's texts, at the
+    configuration's own scale, counts no join and no group table over the
+    ceiling its template's first sight calibrated.  ``CHANGES.md``, PR 44, has
+    the numbers."""
+    seed = 2**31 + 7
+    spec = bench_files.read_json("configs", config + ".json")
+    data = bench_files.load_module("generators", spec["generator"]).generate(spec, seed, None)
+    db = SparqlDatabase()
+    for text in bench_files.ntriples_chunks(data):
+        db.parse_ntriples(text)
+    db.execution_mode = "device"
+    for step in bench_files.read_json("traffic", traffic + ".json")["cycle"]:
+        text = bench_files.template_text(step["template"])
+        domains = [(name, data["domains"][how["draw"]])
+                   for name, how in step.get("constants", {}).items()]
+        n = len(domains[0][1]) if domains else 1
+        picks = range(n) if not sample or n <= sample else sorted(
+            np.random.default_rng(seed).choice(n, sample, replace=False).tolist())
+        sights = {}  # a template's first sight, by cap_key
+        for k in picks:
+            instance = text
+            for name, values in domains:
+                instance = instance.replace(f"@{name}@", values[k])
+            low = lowered(db, instance)
+            if low.cap_key not in sights:
+                counts, ceilings = low._calibration_counts()
+                sights[low.cap_key] = (
+                    counts, ceilings, low._calibrated_groups, low._groups_are_ceiling)
+            counts, ceilings, groups, groups_top = sights[low.cap_key]
+            table, own = low.host_execute()
+            over = [j for j, top in enumerate(ceilings) if top and own[j] > counts[j]]
+            assert not over, (step["template"], k, over, own, counts)
+            if low._stage is not None and groups_top and low._stage.group_by:
+                assert de._most_groups(
+                    [], [table[g] for g in low._stage.group_by]) <= groups
+        assert len(sights) == 1, step["template"]  # one template a text
