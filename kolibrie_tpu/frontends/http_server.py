@@ -95,6 +95,13 @@ _BATCH_REQS = obs_metrics.counter(
 _BATCH_DISPATCHES = obs_metrics.counter(
     "kolibrie_batcher_dispatches_total", "batch dispatches drained"
 )
+_BATCH_DISPATCH_START = obs_metrics.counter(
+    "kolibrie_batcher_dispatch_start_total",
+    "batch dispatches by what their leader found when it arrived: "
+    "arrival = the dispatch lock was free and the group left at once, "
+    "handoff = it had queued behind a holder and left when that released",
+    labels=("at",),
+)
 _BATCH_DEDUP = obs_metrics.counter(
     "kolibrie_batcher_dedup_hits_total",
     "in-flight identical-text queries answered by one execution",
@@ -329,32 +336,82 @@ class _BatchRequest:
         self.trace_id = trace_id
 
 
+class _DispatchLock:
+    """One store's database lock.  To every holder it is a plain mutex
+    (``with``, ``acquire``/``release``); what it adds is the batcher's
+    queue, :meth:`acquire_unless`: wait for the lock OR for an event that
+    a holder sets before it releases, whichever comes first.  A release
+    wakes every waiter, so one whose request rode the dispatch that just
+    ended returns at once instead of contending with the next leader."""
+
+    def __init__(self):
+        self._cond = threading.Condition(threading.Lock())
+        self._held = False
+
+    def acquire(self, blocking: bool = True) -> bool:
+        with self._cond:
+            if blocking:
+                self._cond.wait_for(lambda: not self._held)
+            elif self._held:
+                return False
+            self._held = True
+            return True
+
+    def acquire_unless(
+        self, done: threading.Event, timeout: Optional[float]
+    ) -> bool:
+        """Block until the lock is ours (True), or until ``done`` is set
+        or ``timeout`` seconds (None: no limit) have passed (False)."""
+        with self._cond:
+            self._cond.wait_for(
+                lambda: done.is_set() or not self._held, timeout
+            )
+            if done.is_set() or self._held:
+                return False
+            self._held = True
+            return True
+
+    def release(self) -> None:
+        with self._cond:
+            self._held = False
+            self._cond.notify_all()
+
+    def __enter__(self) -> "_DispatchLock":
+        self.acquire()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.release()
+
+
 class TemplateBatcher:
     """Serving-side micro-batcher over one persistent store.
 
-    Handler threads call :meth:`submit`; requests that land within the
-    batching window ride one dispatch.  Inside a dispatch, identical
-    query texts are deduplicated (one execution, shared result) and
-    same-template queries ride one device program
+    Handler threads call :meth:`submit`.  A request that finds
+    ``dispatch_lock`` free takes it and leaves at once, with whatever
+    else is pending in that instant; one that finds it held queues, and
+    whoever gets the lock when the holder releases drains the whole
+    pending list (leader election), so everything that queued behind a
+    dispatch in flight rides the next one as one group.  A group forms
+    only while the server is busy, which is the only time grouping pays;
+    nothing waits for company that has not arrived.  Inside a dispatch,
+    identical query texts are deduplicated (one execution, shared result)
+    and same-template queries ride one device program
     (``execute_queries_batched``: a loop over the group's live members in
     the slot class of its size) — under load, N constant-variants of one
     query shape cost one device call, not N, and no group size compiles
     a program of its own.
 
-    The first waiter whose window expires claims ``dispatch_lock`` and
-    drains the whole pending list (leader election); followers just wait
-    on their request event.  All database access — dispatch, loads,
-    stats — serializes on ``dispatch_lock``, so the engine itself never
-    sees concurrency."""
+    Followers wait for the lock or their own result, whichever comes
+    first, and never past their own deadline.  All database access —
+    dispatch, loads, stats — serializes on ``dispatch_lock``, so the
+    engine itself never sees concurrency."""
 
-    def __init__(
-        self, db, window_ms: float = 5.0, max_queue_depth: int = MAX_QUEUE_DEPTH
-    ):
+    def __init__(self, db, max_queue_depth: int = MAX_QUEUE_DEPTH):
         self.db = db
-        self.window = window_ms / 1000.0
         self.max_queue_depth = max_queue_depth
         self.lock = threading.Lock()  # guards pending + counters
-        self.dispatch_lock = threading.Lock()  # serializes db access
+        self.dispatch_lock = _DispatchLock()  # serializes db access
         self.pending: List[_BatchRequest] = []  # guarded by: lock
         self.requests = 0  # guarded by: lock
         self.dispatches = 0  # guarded by: lock
@@ -389,35 +446,44 @@ class TemplateBatcher:
                 _BATCH_SHED.labels("queue_full").inc()
                 raise Overloaded(
                     f"store queue full ({len(self.pending)} pending)",
-                    retry_after_s=max(self.window * 4, 0.05),
+                    retry_after_s=0.05,
                 )
             self.pending.append(req)
             self.requests += 1
         _BATCH_REQS.inc()
-        # collect followers for one window, then elect a dispatcher; loop
-        # covers the race where a drain happened between append and wait
-        while not req.done.wait(timeout=self.window):
-            if req.deadline is not None and req.deadline.expired():
-                # a waiter never blocks past its deadline: drop out even
-                # if a leader is mid-dispatch (its result goes unread)
+        # idle server: leave on arrival.  Busy: queue behind the holder and
+        # leave the moment it releases, unless its dispatch carried us.
+        at = None
+        if self.dispatch_lock.acquire(blocking=False):
+            at = "arrival"
+        elif self.dispatch_lock.acquire_unless(
+            req.done,
+            None if req.deadline is None else req.deadline.remaining(),
+        ):
+            at = "handoff"
+        if at is not None:
+            # a request is in ``pending`` until a leader drains it, and that
+            # leader sets ``done`` before it releases: ours is in this batch,
+            # or an earlier dispatch answered it between append and acquire
+            try:
                 with self.lock:
-                    if req in self.pending:
-                        self.pending.remove(req)
-                    self.shed_deadline += 1
-                _BATCH_SHED.labels("deadline").inc()
-                raise DeadlineExceeded(
-                    "deadline exceeded at batcher.queue", site="batcher.queue"
-                )
-            if self.dispatch_lock.acquire(blocking=False):
-                try:
-                    with self.lock:
-                        batch, self.pending = self.pending, []
-                    if batch:
-                        self._run_batch(batch)
-                finally:
-                    self.dispatch_lock.release()
-            if req.done.is_set():
-                break
+                    batch, self.pending = self.pending, []
+                if batch:
+                    self._run_batch(batch)
+                    _BATCH_DISPATCH_START.labels(at).inc()
+            finally:
+                self.dispatch_lock.release()
+        elif not req.done.is_set():
+            # a waiter never blocks past its deadline: drop out even
+            # if a leader is mid-dispatch (its result goes unread)
+            with self.lock:
+                if req in self.pending:
+                    self.pending.remove(req)
+                self.shed_deadline += 1
+            _BATCH_SHED.labels("deadline").inc()
+            raise DeadlineExceeded(
+                "deadline exceeded at batcher.queue", site="batcher.queue"
+            )
         if req.error is not None:
             raise req.error
         return req.result
@@ -1216,7 +1282,7 @@ class KolibrieHandler(BaseHTTPRequestHandler):
         """Query a persistent store through the template batcher:
         {"store_id", "sparql"} → {"data", "execution_time_ms"}.  In-flight
         identical queries are answered by one execution; same-template
-        variants within the batching window share one device dispatch.
+        variants that queued behind one dispatch share the next.
 
         ``?explain=analyze`` is the one-off debug variant: the query runs
         SOLO under the dispatch lock with an analyze capture active, and

@@ -184,7 +184,7 @@ metrics.register_collector(_collect_compile_cache)
 
 _queue_depth_gauge = metrics.gauge(
     "kolibrie_batcher_queue_depth",
-    "requests pending in a store's batching window",
+    "requests pending in a store's batcher (queued behind a dispatch)",
     labels=("store",),
 )
 _rsp_sessions_gauge = metrics.gauge(

@@ -9,7 +9,8 @@ two triangles cells, ``lubm50.lookups`` (``lookups`` against ``lubm-50``) and
 the two join-search metrics are in as ISSUE 39 states them, ``watdiv-100``,
 ``watdiv100.stars_snowflakes`` and the two scan metrics as ISSUE 40 does,
 ``bsbm-10m``, ``bsbm10m.bi_counts`` and the seven aggregate metrics as ISSUE
-42 does (nine cells of seven configurations, one of four chips), every file a cell or a
+42 does (nine cells of seven configurations, one of four chips), ISSUE 45's
+three counts of the micro-batcher are data files for every cell, every file a cell or a
 per-layer metric names is there, a program that lacks what a cell
 requires of it (``benchmark/requires``) is refused before anything starts,
 and ``run.py`` itself, started off the chip, prints no result and exits 3
@@ -542,7 +543,7 @@ def test_benchmark_json_has_bsbm_10m_and_its_cell_as_the_last():
     need = files.read_json("requires", cell["name"] + ".json")
     assert (need["module"], need["registers"]) == (
         "kolibrie_tpu.query.template", "kolibrie_device_aggregate_slots_total")
-    added = BENCH["per_layer"][-len(AGGREGATE_METRICS):]
+    added = BENCH["per_layer"][78:78 + len(AGGREGATE_METRICS)]
     assert [m["name"] for m in added] == list(AGGREGATE_METRICS)
     for m in added:
         kind, args, unit, better, source, layer = AGGREGATE_METRICS[m["name"]]
@@ -719,3 +720,41 @@ def test_off_the_chip_run_py_prints_no_result_and_exits_3(tmp_path, workload):
     assert proc.returncode == 3, proc.stderr[-2000:]
     assert proc.stdout.strip() == ""
     assert "needs 1 TPU chip(s)" in proc.stderr
+
+
+BATCHER_COUNTS = {
+    # name: (reader arguments, better)
+    "batcher_arrival_starts_in_window": (
+        {"prefix": 'metrics.kolibrie_batcher_dispatch_start_total{at="arrival"}',
+         "beside": "metrics.kolibrie_batcher_dispatch_start_total"}, "higher"),
+    "batcher_dispatches_in_window": (
+        {"prefix": "metrics.kolibrie_batcher_dispatches_total"}, "lower"),
+    "batcher_requests_in_window": (
+        {"prefix": "metrics.kolibrie_batcher_requests_total"}, "higher"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(BATCHER_COUNTS))
+def test_a_batcher_count_is_a_data_file_that_every_cell_reports(name):
+    """ISSUE 45: three entries appended behind ISSUE 42's seven, no list of
+    cells (every cell has a batcher), each a file of ``counter_delta``; a
+    program without the family (the parent has no ``dispatch_start``) reports
+    nothing and nothing raises, one whose dispatches all left by hand-off
+    reads 0 arrivals.  ``tests/test_batcher_dispatch.py`` reads them off the
+    program's own registry."""
+    args, better = BATCHER_COUNTS[name]
+    added = BENCH["per_layer"][85:88]
+    assert [m["name"] for m in added] == list(BATCHER_COUNTS)
+    assert added[sorted(BATCHER_COUNTS).index(name)] == {
+        "name": name, "unit": "count", "better": better, "source": "program_counter",
+        "layer": "micro-batcher", "moves": "cycle_ms"}
+    assert files.read_json("layer_metrics", name + ".json") == {
+        "reader": {"kind": "counter_delta", **args}}
+    reader = files.load_module("readers", "counter_delta")
+    there = {"counters0": {args["prefix"]: 7.0}, "counters1": {args["prefix"]: 107.0}}
+    assert reader.read(there, **args) == pytest.approx(100.0)
+    lacking = {key: {"metrics.kolibrie_batcher_shed_total": 1.0} for key in there}
+    assert reader.read(lacking, **args) is None
+    if "beside" in args:
+        handoff = 'metrics.kolibrie_batcher_dispatch_start_total{at="handoff"}'
+        assert reader.read({key: {handoff: 4.0} for key in there}, **args) == 0.0
