@@ -27,8 +27,8 @@ KL902  a learned-state ``*Advisor`` class keyed on the template
        fingerprint lets an off-mode process replay a plan the advisor
        tuned (or vice versa) from a shared cache/manifest; the plan and
        the key disagree (docs/OPTIMIZER.md).  Advisors whose module has
-       no mode function escape — state that is always-on (CapAdvisor's
-       capacity high-water marks) cannot desync a fingerprint.
+       no mode function escape — state that is always-on cannot desync a
+       fingerprint.
        Participation is checked across the analyzed file set, so run
        kolint over the package root, not a single file.
 """

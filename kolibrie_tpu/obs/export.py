@@ -27,6 +27,7 @@ from kolibrie_tpu.obs.promtext import render_prometheus  # noqa: F401
 
 # Satellite: module-scope imports — previously re-imported inside
 # TemplateBatcher.stats() on every /stats poll.
+from kolibrie_tpu.optimizer import caps
 from kolibrie_tpu.optimizer.device_engine import device_compile_stats
 from kolibrie_tpu.query.executor import plan_cache_info
 from kolibrie_tpu.resilience.breaker import breaker_board
@@ -85,6 +86,10 @@ def store_stats(batcher) -> dict:
             # shard count, per-shard occupancy, imbalance, last cap hit —
             # the degraded-routing signals (docs/SHARDING.md)
             out["sharding"] = sharded.stats()
+        # per template of this store: the join capacities it is compiled
+        # for, provisional or settled, and its group capacities
+        # (optimizer/caps.py; retries are /metrics' kolibrie_cap_retries_total)
+        out["capacities"] = caps.of(batcher.db).stats()
     out["device_compiles"] = device_compile_stats()
     from kolibrie_tpu.optimizer import mqo
 
@@ -138,18 +143,13 @@ def build_stats(state) -> dict:
     warmer = getattr(state, "prewarmer", None)
     if warmer is not None:
         compile_tail["prewarm"] = warmer.stats()
-    # capacity-advisor block: per-template current caps / high-water mark /
-    # retry counts (process-wide — the advisor spans stores and survives
-    # base-version churn; "is steady state really zero-retry" dashboard)
     from kolibrie_tpu.optimizer.stats_advisor import stats_advisor
-    from kolibrie_tpu.query.template import cap_advisor
 
     out = {
         "stores": {sid: store_stats(b) for sid, b in stores.items()},
         "rsp_sessions": len(sessions),
         "resilience": resilience,
         "compile_tail": compile_tail,
-        "cap_advisor": cap_advisor.stats(),
         # feedback-optimizer block: per-template learned-key counts,
         # plan generation, replans and drift state (docs/OPTIMIZER.md)
         "stats_advisor": stats_advisor.stats(),

@@ -78,7 +78,10 @@ import time as _time
 from kolibrie_tpu.obs import analyze as _analyze
 from kolibrie_tpu.obs import metrics as _obs_metrics
 from kolibrie_tpu.obs.spans import get_baggage as _get_baggage
+from kolibrie_tpu.optimizer import caps as _caps
 from kolibrie_tpu.optimizer import stats_advisor as _sa
+from kolibrie_tpu.optimizer.caps import CAP_FLOOR as _CAP_FLOOR
+from kolibrie_tpu.optimizer.caps import fit_join_caps, group_cap_ceiling
 from kolibrie_tpu.optimizer.stats import hottest_key_rows
 from kolibrie_tpu.obs.spans import span as _obs_span
 from kolibrie_tpu.ops import round_cap as _round_cap
@@ -165,49 +168,11 @@ class Unsupported(Exception):
     """Plan construct the device path cannot express (host fallback)."""
 
 
-# ---------------------------------------------------------------------------
-# The capacity rule (docs/COMPILE_CACHE.md "Capacity protocol").  A join or
-# WCOJ level is compiled for the rows the template has been seen to produce
-# and never for more than the inputs' capacities suggest: the search loops,
-# the compaction sorts, the gathers and the readback all cost slots, not
-# rows.  The rule has two arms.  A count that is one instance's (or the most
-# of the instances some passes saw) gets headroom, _CAP_HEADROOM x, for the
-# instances not yet seen.  A count that is a CEILING, the most rows any
-# instance of the text gives the join on the store as it stands (the
-# calibration says which: LoweredPlan._calibration_counts), gets none: there
-# is no instance left to leave room for.  Overflow (a variant with more than
-# the headroom, a store that grew past a ceiling) is the protocol's business,
-# not the rule's.
-# ---------------------------------------------------------------------------
-_CAP_HEADROOM = 4
-_CAP_FLOOR = 1024
 # The numpy twin gives up past this many rows in one scan, join or WCOJ
 # level (the guard of dist_query's calibration): materializing more on the
 # host just to size device buffers costs the memory static capacities exist
 # to avoid.  The first device run's counts calibrate instead.
 _CALIBRATE_ROW_LIMIT = 8_000_000
-
-
-def fit_join_caps(
-    heuristic: Sequence[int],
-    counts: Sequence[int],
-    ceilings: Sequence[bool] = (),
-) -> List[int]:
-    """THE capacity rule, per join and per WCOJ level:
-    ``min(heuristic, round_cap(max(H x count, FLOOR)))``, and where
-    ``ceilings[i]`` says that no instance of the text can pass ``counts[i]``
-    the same without the ``H``.  Every path that sizes a join from counts
-    (the calibrated start, the tighten-once fallback, ``calibrate_host``)
-    goes through here; one that holds a single instance's counts passes no
-    ``ceilings`` and keeps the headroom."""
-    ceilings = tuple(ceilings) or (False,) * len(counts)
-    return [
-        min(
-            int(h),
-            _round_cap(max((1 if top else _CAP_HEADROOM) * int(c), _CAP_FLOOR)),
-        )
-        for h, c, top in zip(heuristic, counts, ceilings)
-    ]
 
 
 # ---------------------------------------------------------------------------
@@ -2328,44 +2293,34 @@ class LoweredPlan:
         return caps
 
     def _initial_join_caps(self, scan_caps) -> List[int]:
-        """Join and WCOJ-level capacities for this dispatch, in order of
-        preference: what the template already converged to on this db
-        (every variant shares it: one executable a template); the
-        process-wide advisor's high-water mark for the fingerprint (a
-        fresh db, or a ``cap_key`` that moved with store growth); else a
+        """Join and WCOJ-level capacities for this dispatch: what the
+        template already holds on this db (every variant shares it: one
+        executable a template); else, its first sight on this store, a
         calibrated start (:func:`fit_join_caps`), counted by the numpy twin
         before the first executable is chosen: the most rows any instance
         of the text gives a join where the twin could count that (a
         ceiling: :meth:`_calibration_counts`), the rows it saw with
-        headroom elsewhere.  The baggage fingerprint is "unknown"
-        for direct engine construction (tests, EXPLAIN): no advice then,
-        so unrelated callers never cross-pollinate."""
-        cache = self.db.__dict__.setdefault("_device_cap_cache", {})
-        cached = cache.get(self.cap_key)
-        if cached is not None and len(cached) == self.join_count:
-            return list(cached)
+        headroom elsewhere."""
+        store = _caps.of(self.db)
+        held = store.joins.get(self.cap_key, self.join_count)
+        if held is not None:
+            return list(held)
+        store.templates.setdefault(self.cap_key, _get_baggage("template", "unknown"))
         heuristic = self._heuristic_join_caps(scan_caps)
-        from kolibrie_tpu.query.template import cap_advisor, note_calibrated_caps
-
-        fp = _get_baggage("template", "unknown")
-        if fp != "unknown":
-            advised = cap_advisor.advise("device", fp)
-            if advised is not None and len(advised) == len(heuristic):
-                return list(advised)
         if max(heuristic, default=0) <= _CAP_FLOOR:
             return heuristic  # nothing the rule could tighten
+        from kolibrie_tpu.query.template import note_calibrated_caps
+
         counted = self._calibration_counts()
         if counted is None:
             # the host pass would be too large: run once at the heuristic
-            # and let converge() tighten from the counts that run reads
-            self.db.__dict__.setdefault("_device_cap_provisional", set()).add(
-                self.cap_key
-            )
+            # and let the run that fits tighten from the counts it reads
+            store.joins.start(self.cap_key, heuristic, provisional=True)
             return heuristic
         counts, ceilings = counted
         caps = fit_join_caps(heuristic, counts, ceilings)
         note_calibrated_caps("device", sum(ceilings), len(ceilings) - sum(ceilings))
-        cache[self.cap_key] = tuple(caps)
+        store.joins.start(self.cap_key, caps)
         return caps
 
     def _calibration_counts(self) -> Optional[Tuple[List[int], List[bool]]]:
@@ -2525,47 +2480,39 @@ class LoweredPlan:
     def _calibrate_group_cap(self) -> None:
         """Where the dispatch ends in an aggregation and this db holds no
         group capacity for the template yet, publish the one it starts from
-        (:func:`aggregate_table` reads it), in the join capacities' order of
-        preference: the process-wide advisor's for the fingerprint, else the
-        groups the numpy twin counted with headroom (beside the joins, in
+        (:func:`aggregate_table` reads it): the groups the numpy twin
+        counted with headroom (beside the joins, in
         :meth:`_calibration_counts`, where :meth:`build` just ran it; in a
-        pass of its own where the joins' capacities came from elsewhere), by
+        pass of its own where the joins' capacities were held already), by
         the one rule (:func:`fit_join_caps`) under the table's width.  Where
         the twin gave up nothing is published: the aggregation starts at the
         floor and its retry sizes the template."""
         stage = self._stage
         if stage is None:
             return
-        cache = self.db.__dict__.setdefault("_device_group_cap_cache", {})
+        groups = _caps.of(self.db).groups
         key = (self.cap_key, stage.key)
-        if key in cache:
+        if groups.get(key) is not None:
             return
-        from kolibrie_tpu.query.template import cap_advisor, note_calibrated_caps
+        from kolibrie_tpu.query.template import note_calibrated_caps
 
-        fp = _get_baggage("template", "unknown")
-        advised = (
-            cap_advisor.advise_groups("device", fp) if fp != "unknown" else None
-        )
         slots = self._node_cap(self.root, self._scan_caps, self._join_caps)
         ceiling = group_cap_ceiling(slots)
-        if advised is not None:
-            cache[key] = advised
-        elif ceiling <= _CAP_FLOOR:
-            cache[key] = ceiling  # nothing the rule could tighten
+        if ceiling <= _CAP_FLOOR:
+            cap = ceiling  # nothing the rule could tighten
         else:
             if self._calibrated_groups is None:
                 self._calibration_counts()
             if self._calibrated_groups is None:
                 return
             top = self._groups_are_ceiling
-            (cache[key],) = fit_join_caps(
-                [ceiling], [self._calibrated_groups], [top]
-            )
+            (cap,) = fit_join_caps([ceiling], [self._calibrated_groups], [top])
             note_calibrated_caps("device", int(top), int(not top))
+        groups.start(key, [cap])
         # the template's first sight compiles two executables, the plan's and
         # the aggregation's: the second beside the first, not after it
         compile_aggregation_ahead(
-            self.db, slots, len(self.out_vars), stage, min(cache[key], ceiling)
+            self.db, slots, len(self.out_vars), stage, cap
         )
 
     def _keyed_scans(self) -> List[int]:
@@ -3215,94 +3162,50 @@ class LoweredPlan:
         )
 
     def _store_caps(self) -> None:
-        """Publish join capacities to the per-db template cache.  Merge is
-        a MONOTONIC max: the cache is shared by every constant variant of
-        the template, and shrinking a cap for one variant would recompile
-        (and possibly overflow) the next."""
-        cache = self.db.__dict__.setdefault("_device_cap_cache", {})
-        prev = cache.get(self.cap_key)
-        caps = tuple(self._join_caps)
-        if prev is not None and len(prev) == len(caps):
-            caps = tuple(max(a, b) for a, b in zip(prev, caps))
-        cache[self.cap_key] = caps
-        self._join_caps = list(caps)
-
-    def converge(self, out, max_attempts: int = 12):
-        """Validate join counts against the capacities ``out`` ran with;
-        re-run with doubled capacities until everything fits (the one
-        overflow protocol shared by every consumer).  Returns
-        ``(out_cols, valid)`` — readback of the counts happens here.
-
-        Every overflow retry and every converged capacity vector is fed to
-        the process-wide :class:`kolibrie_tpu.query.template.CapAdvisor`
-        under the current template fingerprint, so future engines for the
-        same template — on a fresh db, after a ``cap_key`` change from
-        store growth, or post-restart-within-process — start from the
-        high-water mark instead of re-walking the doubling ladder."""
-        from kolibrie_tpu.query.template import (
-            cap_advisor,
-            cap_retry_seconds,
-            note_cap_occupancy,
+        """Publish this dispatch's join capacities to the db's template
+        memory (a monotonic merge) and run with what it then holds."""
+        self._join_caps = list(
+            _caps.of(self.db).joins.merge(self.cap_key, self._join_caps)
         )
 
-        fp = _get_baggage("template", "unknown")
-        t_retry = None
-        for attempt in range(max_attempts):
-            out_cols, valid, counts, stats = out
-            self._last_stats = stats  # device-resident; fetched only on analyze
-            counts_h = _read_counts(out, counts, attempt, int)
+    def converge(self, out):
+        """Validate join counts against the capacities ``out`` ran with;
+        re-run with grown capacities until everything fits (the one overflow
+        loop, :func:`caps.run_until_fits`).  Returns ``(out_cols, valid)``
+        — readback of the counts happens here."""
+        from kolibrie_tpu.query.template import (
+            cap_retry_seconds,
+            note_cap_occupancy,
+            note_cap_retry,
+        )
+
+        def run(attempt):
+            ran = out if attempt == 0 else self.run()
+            self._last_stats = ran[3]  # device-resident; fetched only on analyze
+            counts_h = _read_counts(ran, ran[2], attempt, int)
             _note_fetch("converge.counts")
-            if t_retry is not None:
-                cap_retry_seconds.labels("device").inc(
-                    _time.perf_counter() - t_retry
-                )
-            note_cap_occupancy("device", sum(self._join_caps), sum(counts_h))
+            return ran, self._join_caps, counts_h
+
+        def tally(counts_h, caps):
+            note_cap_occupancy("device", sum(caps), sum(counts_h))
             self._note_scan_tiers()
             self._note_range_searches()
             self._note_join_searches([(self._scan_ranges_np, counts_h)])
             self._note_scan_occupancy([self._scan_ranges_np])
-            overflow = [
-                i for i, c in enumerate(counts_h) if c > self._join_caps[i]
-            ]
-            if not overflow:
-                self._last_counts = counts_h
-                published = self._publish_converged(counts_h)
-                self._emit_wcoj_obs(counts_h)
-                self._advise(counts_h)
-                if fp != "unknown":
-                    cap_advisor.observe(
-                        "device",
-                        fp,
-                        published,
-                        base_version=getattr(
-                            self.db.store, "base_version", None
-                        ),
-                    )
-                return out_cols, valid
-            if fp != "unknown":
-                cap_advisor.observe_retry("device", fp)
-            for i in overflow:
-                self._join_caps[i] = _round_cap(2 * counts_h[i])
-            self._store_caps()
-            t_retry = _time.perf_counter()
-            out = self.run()
-        raise RuntimeError("device plan capacities failed to converge")
+            return counts_h
 
-    def _publish_converged(self, counts_h: List[int]) -> Tuple[int, ...]:
-        """Publish the capacities a run converged at; returns them.  Where
-        that run started from the heuristic because the host calibration
-        was too large, its counts tighten the template's caps, once (the
-        next dispatch takes the smaller executable; ``_join_caps`` stays
-        what this one ran with); everywhere else the merge stays the
-        monotonic max of :meth:`_store_caps`."""
-        provisional = self.db.__dict__.get("_device_cap_provisional")
-        if provisional and self.cap_key in provisional:
-            provisional.discard(self.cap_key)
-            caps = tuple(fit_join_caps(self._join_caps, counts_h))
-            self.db.__dict__["_device_cap_cache"][self.cap_key] = caps
-            return caps
-        self._store_caps()
-        return tuple(self._join_caps)
+        (out_cols, valid, _counts, _stats), _ran_with, counts_h = _caps.run_until_fits(
+            _caps.of(self.db).joins,
+            self.cap_key,
+            run,
+            tally,
+            retried=lambda: note_cap_retry("device"),
+            rerun_seconds=cap_retry_seconds.labels("device").inc,
+        )
+        self._last_counts = counts_h
+        self._emit_wcoj_obs(counts_h)
+        self._advise(counts_h)
+        return out_cols, valid
 
     def _emit_wcoj_obs(self, counts_h: List[int]) -> None:
         """Per-level WCOJ instrumentation from the converged host-read
@@ -3425,13 +3328,11 @@ class LoweredPlan:
                 fp, actuals, version=self.db.store.version_key()
             )
 
-    def _aggregate(self, stage, fp, out_cols, valid):
+    def _aggregate(self, stage, out_cols, valid):
         """The dispatch's last stage under ``device.aggregate``: the plan's
         device-resident columns through :func:`aggregate_table` at the
         template's group capacity.  Returns ``(table, rows)``: one row a
         group, and the rows the plan produced."""
-        from kolibrie_tpu.query.template import cap_advisor
-
         _note_fetch("aggregate")
         with _obs_span("device.aggregate") as sp:
             table, rows, cap = aggregate_table(
@@ -3440,8 +3341,6 @@ class LoweredPlan:
             if sp is not None:
                 groups = len(next(iter(table.values()))) if table else 0
                 sp.attrs.update(groups=groups, cap=cap, rows=rows)
-        if fp != "unknown":
-            cap_advisor.observe_groups("device", fp, cap)
         return table, rows
 
     def to_table(self, out_cols, valid) -> BindingTable:
@@ -3725,7 +3624,7 @@ class LoweredPlan:
             _COLLECT_LAT.observe(_time.perf_counter() - t1)
             nrows = len(next(iter(table.values()))) if table else 0
         else:
-            table, nrows = self._aggregate(stage, tpl, *parts)
+            table, nrows = self._aggregate(stage, *parts)
             _AGGREGATE_LAT.observe(_time.perf_counter() - t1)
         self._advise(None, rows=nrows)
         cap = _analyze.active()
@@ -3831,24 +3730,20 @@ def template_scan_cap(
     if predicate is not None:
         other = [c for c in base.perm[:n_bound] if c != "p"]
         return hottest_key_rows(db, predicate, other[0] if other else "p") + dcap
-    cache = db.__dict__.setdefault("_device_group_cap_cache", {})
-    bv = store.base_version
-    key = (order_name, n_bound, bv)
-    hit = cache.get(key)
-    if hit is not None:
-        return hit + dcap
-    for stale in [k for k in cache if k[2] != bv]:
-        del cache[stale]
-    rows = base.slice_rows(0, nb)
-    change = np.zeros(nb, dtype=bool)
-    change[0] = True
-    for c in base.perm[:n_bound]:
-        col = rows[c]
-        change[1:] |= col[1:] != col[:-1]
-    bounds = np.append(np.flatnonzero(change), nb)
-    cap = int(np.max(np.diff(bounds)))
-    cache[key] = cap
-    return cap + dcap
+
+    def count() -> int:
+        rows = base.slice_rows(0, nb)
+        change = np.zeros(nb, dtype=bool)
+        change[0] = True
+        for c in base.perm[:n_bound]:
+            col = rows[c]
+            change[1:] |= col[1:] != col[:-1]
+        bounds = np.append(np.flatnonzero(change), nb)
+        return int(np.max(np.diff(bounds)))
+
+    return dcap + _caps.of(db).largest_key_group(
+        order_name, n_bound, store.base_version, count
+    )
 
 
 # the column a freed scan's subject or object rides in through the twin's
@@ -3981,9 +3876,7 @@ def lower_plan(db, plan, anti_plans=(), union_groups=(), optional_plans=()) -> L
     return lowered
 
 
-def execute_plan_batch(
-    lowereds: List[LoweredPlan], max_attempts: int = 12
-) -> List[BindingTable]:
+def execute_plan_batch(lowereds: List[LoweredPlan]) -> List[BindingTable]:
     """Run MANY constant-variants of ONE plan template as a single device
     dispatch (:func:`_run_plan_batch`): the members' scan ranges and packed
     parameter vectors are the first rows of matrices as long as the slot
@@ -4028,7 +3921,7 @@ def execute_plan_batch(
     members = [lowereds[i] for i in live]
     t0 = _time.perf_counter()
     with _obs_span("device.dispatch", template=tpl, batch=len(members)):
-        blocks, bstats = _converge_plan_batch(members, tpl, max_attempts)
+        blocks, bstats = _converge_plan_batch(members)
     _DISPATCH_LAT.labels(tpl).observe(_time.perf_counter() - t0)
     cap = _analyze.active()
     if cap is not None:
@@ -4053,20 +3946,20 @@ def execute_plan_batch(
     return results
 
 
-def _converge_plan_batch(members: List[LoweredPlan], fp: str, max_attempts: int):
+def _converge_plan_batch(members: List[LoweredPlan]):
     """Dispatch the group in the slot class of its size until every live
-    member's join counts fit the template's capacities: one overflow
-    doubles the shared cap for everyone and re-runs the group (one
-    executable a capacity set, whatever the group).  Returns the live
-    members' row blocks and the ``[slots]`` stats, device-resident."""
+    member's join counts fit the template's capacities: one overflow grows
+    the shared capacity for everyone and re-runs the group (one executable
+    a capacity set, whatever the group).  Returns the live members' row
+    blocks and the ``[slots]`` stats, device-resident."""
     import jax.numpy as jnp
 
     from kolibrie_tpu.ops import slot_class
     from kolibrie_tpu.ops.pallas_kernels import pallas_enabled
     from kolibrie_tpu.query.template import (
-        cap_advisor,
         cap_retry_seconds,
         note_cap_occupancy,
+        note_cap_retry,
     )
 
     lp0 = members[0]
@@ -4079,8 +3972,7 @@ def _converge_plan_batch(members: List[LoweredPlan], fp: str, max_attempts: int)
         mat[:n] = live_rows
         return mat
 
-    t_retry = None
-    for attempt in range(max_attempts):
+    def run(attempt):
         spec0, base_args = _build_traced(lp0, 0)
         for lp in members[1:]:
             spec, _ = _build_traced(lp, 0, operands=False)
@@ -4111,14 +4003,9 @@ def _converge_plan_batch(members: List[LoweredPlan], fp: str, max_attempts: int)
                 quoted,
                 params_b,
             )
-        blocks, counts, bstats = out
-        caps = lp0._join_caps
-        counts_b = _read_counts(out, counts, attempt, np.asarray)
-        if t_retry is not None:
-            cap_retry_seconds.labels("device").inc(
-                _time.perf_counter() - t_retry
-            )
-        maxc = [int(np.max(c)) for c in counts_b]
+        return out, lp0._join_caps, _read_counts(out, out[1], attempt, np.asarray)
+
+    def tally(counts_b, caps):
         note_cap_occupancy(
             "device", n * sum(caps), sum(int(np.sum(c)) for c in counts_b)
         )
@@ -4131,24 +4018,16 @@ def _converge_plan_batch(members: List[LoweredPlan], fp: str, max_attempts: int)
             ]
         )
         lp0._note_scan_occupancy([lp._scan_ranges_np for lp in members])
-        over = [j for j, c in enumerate(maxc) if c > caps[j]]
-        if not over:
-            break
-        if fp != "unknown":
-            cap_advisor.observe_retry("device", fp)
-        for j in over:
-            lp0._join_caps[j] = _round_cap(2 * maxc[j])
-        lp0._store_caps()
-        t_retry = _time.perf_counter()
-    else:
-        raise RuntimeError("batched plan capacities failed to converge")
-    if fp != "unknown":
-        cap_advisor.observe(
-            "device",
-            fp,
-            tuple(lp0._join_caps),
-            base_version=getattr(lp0.db.store, "base_version", None),
-        )
+        return [int(np.max(c)) for c in counts_b]
+
+    (blocks, _counts, bstats), _ran_with, _most = _caps.run_until_fits(
+        _caps.of(lp0.db).joins,
+        lp0.cap_key,
+        run,
+        tally,
+        retried=lambda: note_cap_retry("device"),
+        rerun_seconds=cap_retry_seconds.labels("device").inc,
+    )
     _BATCH_DISPATCHES.inc()
     _BATCH_MEMBERS.inc(n)
     _BATCH_MEMBER_SLOTS.inc(slots)
@@ -4445,7 +4324,7 @@ def device_quoted(db):
     mutation."""
     import jax.numpy as jnp
 
-    cache = db.__dict__.get("_device_qt_cache")
+    cache = db.__dict__.get("_operand_qt_cache")
     n = len(db.quoted)
     if cache is not None and cache[0] == n:
         return cache[1]
@@ -4456,7 +4335,7 @@ def device_quoted(db):
         jnp.asarray(_pad_pow2(qp, 0)),
         jnp.asarray(_pad_pow2(qo, 0)),
     )
-    db.__dict__["_device_qt_cache"] = (n, arrs)
+    db.__dict__["_operand_qt_cache"] = (n, arrs)
     return arrs
 
 
@@ -4473,7 +4352,7 @@ def device_string_ranks(db):
 
     n_d = len(db.dictionary.id_to_str)
     n_q = len(db.quoted)
-    cache = db.__dict__.get("_device_strrank_cache")
+    cache = db.__dict__.get("_operand_strrank_cache")
     if cache is not None and cache[0] == (n_d, n_q):
         return cache[1]
     dec = db.decode_term
@@ -4494,7 +4373,7 @@ def device_string_ranks(db):
                 )
             ),
         )
-    db.__dict__["_device_strrank_cache"] = ((n_d, n_q), arrs)
+    db.__dict__["_operand_strrank_cache"] = ((n_d, n_q), arrs)
     return arrs
 
 
@@ -4510,7 +4389,7 @@ def device_numf(db):
     """
     import jax.numpy as jnp
 
-    cache = db.__dict__.get("_device_numf_cache")
+    cache = db.__dict__.get("_operand_numf_cache")
     vals = db.numeric_values()
     n = len(vals)
     if cache is not None and cache[0] == n:
@@ -4519,7 +4398,7 @@ def device_numf(db):
     padded[:n] = vals
     with jax.enable_x64(True):
         arr = jnp.asarray(padded, dtype=jnp.float64)
-    db.__dict__["_device_numf_cache"] = (n, arr)
+    db.__dict__["_operand_numf_cache"] = (n, arr)
     return arr
 
 
@@ -4577,12 +4456,6 @@ def compile_aggregation_ahead(db, slots, ncols, stage, cap) -> None:
     thread.start()
 
 
-def group_cap_ceiling(slots: int) -> int:
-    """No table has more groups than slots: the most a group capacity is
-    ever compiled for."""
-    return _round_cap(max(int(slots), 1))
-
-
 def aggregate_table(
     db, cols, valid, stage: AggregateStage, cap_key
 ) -> Tuple[BindingTable, int, int]:
@@ -4594,8 +4467,8 @@ def aggregate_table(
     The group capacity is a static argument of the compiled aggregation, so
     it is the template's and not the request's: the one this db holds under
     ``(cap_key, stage.key)`` (what an earlier request fitted in, or what
-    :meth:`LoweredPlan.build` counted or was advised on the template's first
-    sight), else the floor.  Groups beyond it run the aggregation again at
+    :meth:`LoweredPlan.build` counted on the template's first sight), else
+    the floor.  Groups beyond it run the aggregation again at
     a capacity that holds them (a counted retry), and what a run fitted in
     is remembered, so the next request of the template starts there.
     Returns ``(table, rows, capacity)``: the valid rows the aggregation
@@ -4607,36 +4480,43 @@ def aggregate_table(
 
     slots = int(valid.shape[0])
     ceiling = group_cap_ceiling(slots)
-    cache = db.__dict__.setdefault("_device_group_cap_cache", {})
     key = (cap_key, stage.key)
-    cap = min(cache.get(key, _CAP_FLOOR), ceiling)
+
+    def run(_attempt):
+        cap = min(_caps.of(db).group_cap(key) or _CAP_FLOOR, ceiling)
+        sig = (slots, len(cols), stage.key, cap)
+        if _AHEAD.get(sig) is not None:  # being compiled ahead: wait
+            _AHEAD[sig].join()
+            _AHEAD[sig] = None
+        out = _cc.call(
+            _segment_aggregate,
+            tuple(cols),
+            valid,
+            numf_dev,
+            stage.gpos,
+            stage.funcs,
+            stage.apos,
+            stage.distincts,
+            cap,
+        )
+        return out, (cap,), (int(out[2]), int(out[3]))
+
+    def tally(read, caps):
+        note_aggregate(slots, read[1], caps[0], min(read[0], caps[0]))
+        return read[:1]
+
     with jax.enable_x64(True):
         numf_dev = (
             device_numf(db) if stage.reads_numbers else jnp.zeros(1, jnp.float64)
         )
-        while True:
-            sig = (slots, len(cols), stage.key, cap)
-            if _AHEAD.get(sig) is not None:  # being compiled ahead: wait
-                _AHEAD[sig].join()
-                _AHEAD[sig] = None
-            gcols, aggs, n_groups, n_rows = _cc.call(
-                _segment_aggregate,
-                tuple(cols),
-                valid,
-                numf_dev,
-                stage.gpos,
-                stage.funcs,
-                stage.apos,
-                stage.distincts,
-                cap,
-            )
-            ng, rows = int(n_groups), int(n_rows)
-            note_aggregate(slots, rows, cap, min(ng, cap))
-            if ng <= cap:
-                break
-            note_aggregate_retry()
-            cap = min(_round_cap(2 * ng), ceiling)
-    cache[key] = max(cap, cache.get(key, 0))
+        (gcols, aggs, _ng, n_rows), (cap,), (ng,) = _caps.run_until_fits(
+            _caps.of(db).groups,
+            key,
+            run,
+            tally,
+            retried=note_aggregate_retry,
+            ceiling=ceiling,
+        )
     table: BindingTable = {}
     for g, col in zip(stage.group_by, gcols):
         table[g] = np.asarray(col)[:ng].astype(np.uint32)
@@ -4647,7 +4527,7 @@ def aggregate_table(
             table[alias] = np.asarray(arr)[:ng].astype(np.uint32)
         else:
             table[alias] = _encode_numbers(enc, np.asarray(arr)[:ng])
-    return table, rows, cap
+    return table, int(n_rows), cap
 
 
 # ---------------------------------------------------------------------------

@@ -423,7 +423,8 @@ def _eval_prefix_device(lowered, pfx: _Prefix) -> Optional[dict]:
     ``_run_interp`` entry as full-plan interpretation — prefix sharing
     adds zero compiles.  Shares the capacity-doubling protocol."""
     from kolibrie_tpu.optimizer import plan_interp as pi
-    from kolibrie_tpu.optimizer.device_engine import _note_fetch, _round_cap
+    from kolibrie_tpu.optimizer.caps import grown_cap
+    from kolibrie_tpu.optimizer.device_engine import _note_fetch
 
     for _attempt in range(12):
         args = lowered.build(tag=0)[1]
@@ -464,7 +465,7 @@ def _eval_prefix_device(lowered, pfx: _Prefix) -> Optional[dict]:
                 for v in lowered.out_vars
             }
         for i in overflow:
-            lowered._join_caps[i] = _round_cap(2 * counts_h[i])
+            lowered._join_caps[i] = grown_cap(counts_h[i])
         lowered._store_caps()
     raise RuntimeError("mqo prefix capacities failed to converge")
 

@@ -599,7 +599,8 @@ def interp_execute(lowered, max_attempts: int = 12):
     through to the specialized path).  Shares the capacity protocol:
     overflow doubles the template's join caps via ``_store_caps`` — caps
     learned here pre-calibrate the eventual specialized compile."""
-    from kolibrie_tpu.optimizer.device_engine import _note_fetch, _round_cap
+    from kolibrie_tpu.optimizer.caps import grown_cap
+    from kolibrie_tpu.optimizer.device_engine import _note_fetch
 
     if not lowered.const_ok():
         return lowered.empty_table()
@@ -661,7 +662,7 @@ def interp_execute(lowered, max_attempts: int = 12):
                 )
             return table
         for i in overflow:
-            lowered._join_caps[i] = _round_cap(2 * counts_h[i])
+            lowered._join_caps[i] = grown_cap(counts_h[i])
         lowered._store_caps()
     raise RuntimeError("interpreter plan capacities failed to converge")
 

@@ -13,8 +13,7 @@ Every device dispatch already host-reads its per-join match counts in
 ``converge()`` and computes its scan ranges host-side, so per-operator
 *actuals* are free on the warm path; EXPLAIN ANALYZE captures add the
 full operator map.  This module is the loop closure: a process-wide
-:class:`StatsAdvisor` (same shape as
-:class:`kolibrie_tpu.query.template.CapAdvisor`) persists
+:class:`StatsAdvisor` persists
 estimated-vs-actual rows per ``(template fingerprint, operator key)``,
 hands the learned values back to the planner/cost model, and bumps a
 per-template *plan generation* when the actuals drift past the estimates

@@ -945,7 +945,7 @@ class DistQueryExecutor:
         walk is abandoned at the first step that exceeds the best so far,
         so a plan that would join millions of rows is never materialised.
         The two capacities follow the single-device rule
-        (``device_engine.fit_join_caps``), each from its own count: the
+        (``caps.fit_join_caps``), each from its own count: the
         largest per-shard join step (for the batched body the seed's rows
         a shard among them: it compacts them into ``join_cap`` slots) and
         the largest (source, destination) exchange bucket, never above the
@@ -954,7 +954,7 @@ class DistQueryExecutor:
         more than 4x the counted rows.  Where nothing can be counted
         (every walk past ``_CALIBRATE_ROW_LIMIT``) the most-constants
         seed and the heuristic stand (``source`` "constants")."""
-        from kolibrie_tpu.optimizer.device_engine import fit_join_caps
+        from kolibrie_tpu.optimizer.caps import fit_join_caps
 
         heuristic = round_cap(
             4 * max(1, -(-len(self.db.store) // self.n)), 256

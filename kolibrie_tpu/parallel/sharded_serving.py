@@ -80,7 +80,7 @@ from kolibrie_tpu.parallel.dist_join import (
 from kolibrie_tpu.parallel.mesh import make_mesh
 from kolibrie_tpu.parallel.sharded_store import ShardedTripleStore, shard_of
 from kolibrie_tpu.query import compile_cache as _cc
-from kolibrie_tpu.query.template import cap_advisor, fingerprint_query
+from kolibrie_tpu.query.template import fingerprint_query, note_cap_retry
 from kolibrie_tpu.resilience.deadline import check_deadline
 from kolibrie_tpu.resilience.faultinject import fault_point
 from kolibrie_tpu.reasoner.device_fixpoint import Unsupported
@@ -836,16 +836,6 @@ class ShardedDatabase:
             raise
         if plan is None:
             _SHARD_PLANS.labels(exemplar.plan_source).inc()
-            # capacities never start below the advisor's process-wide
-            # high-water mark (mutation workloads bump the base version
-            # constantly), so steady state re-dispatches without a single
-            # doubled-cap retry
-            advised = cap_advisor.advise("sharded", fp)
-            if advised is not None and len(advised) == 2:
-                exemplar.join_cap = max(exemplar.join_cap, int(advised[0]))
-                exemplar.bucket_cap = max(
-                    exemplar.bucket_cap, int(advised[1])
-                )
         if (
             exemplar.agg_items
             or exemplar.query.group_by
@@ -982,7 +972,7 @@ class ShardedDatabase:
             self.stats_counters["cap_hits"] += 1
             self.stats_counters["last_cap_hit"] = time.time()
             _SHARD_CAP_HITS.inc()
-            cap_advisor.observe_retry("sharded", fp)
+            note_cap_retry("sharded")
         else:
             raise RuntimeError("sharded batch capacities failed to converge")
         group["caps"] = (join_cap, bucket_cap)
@@ -1056,9 +1046,6 @@ class ShardedDatabase:
         join_cap, bucket_cap = group["caps"]
         bv = self._sig[0]
         self._plans[(fp, bv)] = (exemplar.seed, join_cap, bucket_cap)
-        cap_advisor.observe(
-            "sharded", fp, (join_cap, bucket_cap), base_version=bv
-        )
         occ_total = int(self._subj.occupancy().sum())
         n_scans = 1 + len(exemplar.steps)
         _SHARD_ROWS.inc(occ_total * n_scans * live)

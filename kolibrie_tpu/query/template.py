@@ -17,8 +17,8 @@ Structure-relevant scalars stay in the fingerprint:
 * variable / alias names, operators, DISTINCT, GROUP BY keys;
 * the predicate a scanned triple pattern names (it is in ``params`` as
   well): a scan is compiled for the rows under its predicate, so the
-  capacities, the compiled group and the advisor's record that a
-  fingerprint keys belong to one set of predicates;
+  capacities and the compiled group that a fingerprint keys belong to one
+  set of predicates;
 * whether a string literal parses as a number (the lowering pass branches
   on that when it sits on one side of a comparison);
 * for ordered+limited queries, the power-of-two bucket of
@@ -33,9 +33,7 @@ from __future__ import annotations
 import dataclasses
 import enum
 import hashlib
-import os
-import threading
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, List, Tuple
 
 from kolibrie_tpu.obs import metrics
 from kolibrie_tpu.query.ast import (
@@ -54,10 +52,8 @@ from kolibrie_tpu.query.ast import (
 __all__ = [
     "fingerprint_query",
     "template_key",
-    "CapAdvisor",
-    "cap_advisor",
-    "cap_advisor_enabled",
     "note_cap_occupancy",
+    "note_cap_retry",
     "note_scan_occupancy",
     "note_aggregate",
     "note_aggregate_tier",
@@ -69,7 +65,7 @@ __all__ = [
 
 def occupancy_pct(rows: int, cap: int) -> float:
     """How full a template-cap slot ran: ``rows / cap`` as a percentage.
-    The EXPLAIN ANALYZE renderer and the cap advisor's telemetry share
+    The EXPLAIN ANALYZE renderer and the capacity telemetry share
     this so 'occupancy' means one thing everywhere.  A non-positive cap
     (degenerate/elided slot) reads as 0 rather than dividing by zero."""
     if cap <= 0:
@@ -196,9 +192,8 @@ def template_key(cq: CombinedQuery) -> Tuple[Any, Tuple[Any, ...]]:
     routing decision is sticky per cached slot (its source state, its
     learned caps), so a mode flip must land in a fresh fingerprint.
     ``KOLIBRIE_PALLAS`` is the third member: the kernel-vs-XLA routing is
-    a static argument of the compiled plan body, and the cap advisor keys
-    its high-water marks on the fingerprint — a mode flip must replan AND
-    re-learn in a fresh slot, never replay a stale one.  ``KOLIBRIE_MQO``
+    a static argument of the compiled plan body — a mode flip must replan
+    in a fresh slot, never replay a stale one.  ``KOLIBRIE_MQO``
     is the fourth: shared-prefix routing changes which engine produces a
     template's rows, so a mode flip must land in a fresh fingerprint
     (``off`` reproduces pre-MQO behavior bit-for-bit, docs/MQO.md).
@@ -243,21 +238,29 @@ def fingerprint_query(cq: CombinedQuery) -> Tuple[str, Tuple[Any, ...]]:
 
 
 # ---------------------------------------------------------------------------
-# capacity advisor
+# capacity protocol counters (optimizer/caps.py holds the protocol itself)
 # ---------------------------------------------------------------------------
 
 _CAP_RETRIES = metrics.counter(
     "kolibrie_cap_retries_total",
-    "doubled-capacity retried dispatches (overflow → re-run); the cap "
-    "advisor exists to hold this at zero in steady state",
+    "doubled-capacity retried dispatches (overflow → re-run); a template's "
+    "capacities are remembered on its store, so this stays zero in steady "
+    "state",
     labels=("engine",),
 )
 # pre-create both engine series so a zero-retry steady state is visible
 # in /metrics as an explicit 0, not an absent family
 _CAP_RETRIES.labels("device")
 _CAP_RETRIES.labels("sharded")
+
+
+def note_cap_retry(engine: str) -> None:
+    """One dispatch of ``engine`` overflowed a capacity and runs again."""
+    _CAP_RETRIES.labels(engine).inc()
+
+
 # what those re-runs cost: build-to-counts wall time of each re-dispatch,
-# incremented by the overflow protocol itself (LoweredPlan.converge)
+# incremented by the overflow loop itself (optimizer/caps.py run_until_fits)
 cap_retry_seconds = metrics.counter(
     "kolibrie_cap_retry_seconds_total",
     "wall seconds spent in doubled-capacity re-runs (build to count "
@@ -267,7 +270,7 @@ cap_retry_seconds = metrics.counter(
 cap_retry_seconds.labels("device")  # the mesh path counts retries, not yet their seconds
 # how the capacity rule engages: per dispatch, the join and WCOJ-level
 # slots its executable was compiled for and the rows the counts read back
-# (rows / slots is the occupancy; optimizer/device_engine.py fit_join_caps)
+# (rows / slots is the occupancy; optimizer/caps.py fit_join_caps)
 _CAP_SLOTS = metrics.counter(
     "kolibrie_device_cap_slots_total",
     "join and WCOJ-level slots the dispatched executables were compiled "
@@ -474,151 +477,3 @@ def note_join_search_keys(slots: int, searched: int) -> None:
     searched ``searched`` of their keys."""
     _JOIN_SEARCH_KEYS.labels("slots").inc(slots)
     _JOIN_SEARCH_KEYS.labels("searched").inc(searched)
-
-
-def cap_advisor_enabled() -> bool:
-    """``KOLIBRIE_CAP_ADVISOR=off`` (or ``0``) disables advice — retries
-    fall back to the pre-advisor heuristics.  Observation continues either
-    way, so flipping the flag on after a warm-up period works."""
-    return os.environ.get("KOLIBRIE_CAP_ADVISOR", "").strip().lower() not in (
-        "off",
-        "0",
-        "false",
-    )
-
-
-class CapAdvisor:
-    """Process-wide per-``(engine, template-fingerprint)`` capacity
-    advisor: the feedback loop between the overflow-retry protocols and
-    initial capacity choice.
-
-    The engines' own capacity caches are deliberately narrow — the device
-    engine's ``_device_cap_cache`` lives on one db object, and the sharded
-    server
-    pins caps per ``(fingerprint, base_version)``, dropping them on every
-    mutation.  Each of those invalidations used to restart the
-    double-and-retry ladder from the static defaults.  This advisor keys
-    only on the template fingerprint (which already folds the
-    WCOJ/interp/Pallas routing modes and the predicates the scanned
-    patterns name, so one record holds one predicate set's join
-    capacities), merges observations as a monotonic
-    elementwise maximum, and survives db churn and base-version bumps —
-    so a warm process re-dispatches at the high-water mark and retries
-    stay at zero.
-
-    ``caps`` tuples are engine-opaque: the device engine stores its
-    per-join capacity vector, the sharded server ``(join_cap,
-    bucket_cap)``.  Entries whose tuple length changes (a replan under a
-    different mode lands on a different fingerprint, so this is
-    defensive) are replaced rather than merged.  Thread-safe; bounded by
-    the upstream plan-template caches (~64 fingerprints per engine).
-    """
-
-    def __init__(self) -> None:
-        self._lock = threading.Lock()
-        self._entries: Dict[Tuple[str, str], Dict[str, Any]] = {}
-
-    def advise(self, engine: str, fp: str) -> Optional[Tuple[int, ...]]:
-        """High-water-mark caps for a template, or ``None`` when cold or
-        disabled (callers keep their heuristic defaults)."""
-        if not cap_advisor_enabled():
-            return None
-        with self._lock:
-            rec = self._entries.get((engine, fp))
-            return None if rec is None else rec["caps"]
-
-    def observe(
-        self,
-        engine: str,
-        fp: str,
-        caps: Tuple[int, ...],
-        base_version: Optional[int] = None,
-    ) -> None:
-        """Record a successfully converged capacity vector (monotonic
-        elementwise max merge)."""
-        caps = tuple(int(c) for c in caps)
-        with self._lock:
-            rec = self._entries.get((engine, fp))
-            if rec is None:
-                rec = {"caps": caps, "retries": 0, "base_version": None}
-                self._entries[(engine, fp)] = rec
-            elif len(rec["caps"]) == len(caps):
-                rec["caps"] = tuple(
-                    max(a, b) for a, b in zip(rec["caps"], caps)
-                )
-            else:
-                rec["caps"] = caps
-            if base_version is not None:
-                rec["base_version"] = int(base_version)
-
-    def advise_groups(self, engine: str, fp: str) -> Optional[int]:
-        """The template's group capacity (an aggregate template's one
-        capacity beside its joins'), or ``None`` when cold or disabled."""
-        if not cap_advisor_enabled():
-            return None
-        with self._lock:
-            rec = self._entries.get((engine, fp))
-            return None if rec is None else rec.get("group_cap")
-
-    def observe_groups(self, engine: str, fp: str, cap: int) -> None:
-        """Record the group capacity an aggregation ran within (monotonic
-        max, kept with the join capacities of the same template)."""
-        with self._lock:
-            rec = self._entries.setdefault(
-                (engine, fp),
-                {"caps": (), "retries": 0, "base_version": None},
-            )
-            rec["group_cap"] = max(int(cap), rec.get("group_cap", 0))
-
-    def observe_retry(self, engine: str, fp: str, n: int = 1) -> None:
-        """Count an overflow-driven doubled-cap re-dispatch (the waste the
-        advisor is eliminating)."""
-        _CAP_RETRIES.labels(engine).inc(n)
-        with self._lock:
-            rec = self._entries.setdefault(
-                (engine, fp),
-                {"caps": (), "retries": 0, "base_version": None},
-            )
-            rec["retries"] += n
-
-    def retries(self, engine: Optional[str] = None) -> int:
-        """Total observed retries (optionally for one engine) — the
-        steady-state-zero signal the chaos suite asserts on."""
-        with self._lock:
-            return sum(
-                rec["retries"]
-                for (eng, _fp), rec in self._entries.items()
-                if engine is None or eng == engine
-            )
-
-    def stats(self) -> dict:
-        """The ``/stats`` block: per-template current caps, high-water
-        mark and retry counts (bounded by the plan-template caches, so
-        per-template detail belongs here, not in /metrics labels)."""
-        with self._lock:
-            return {
-                "enabled": cap_advisor_enabled(),
-                "templates": {
-                    f"{eng}:{fp}": {
-                        "caps": list(rec["caps"]),
-                        "hwm": max(rec["caps"]) if rec["caps"] else 0,
-                        "retries": rec["retries"],
-                        "base_version": rec["base_version"],
-                        # an aggregate template's one capacity more
-                        "group_cap": rec.get("group_cap"),
-                    }
-                    for (eng, fp), rec in self._entries.items()
-                },
-                "retries_total": sum(
-                    rec["retries"] for rec in self._entries.values()
-                ),
-            }
-
-    def reset(self) -> None:
-        """Drop all learned state (test isolation)."""
-        with self._lock:
-            self._entries.clear()
-
-
-#: the process-wide singleton every engine feeds and consults
-cap_advisor = CapAdvisor()

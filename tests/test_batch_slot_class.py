@@ -230,7 +230,7 @@ def test_a_padded_slot_joins_nothing_and_is_not_read_back(lubm):
     fetches0 = de.fetch_counters().get("batch.rows", 0)
     prog_spans.clear()
     lows = [_lowered(db, t)[1] for t in texts]
-    live_blocks, _ = de._converge_plan_batch(lows, "unknown", 12)
+    live_blocks, _ = de._converge_plan_batch(lows)
     assert len(live_blocks) == 3
     tables = de.execute_plan_batch([_lowered(db, t)[1] for t in texts])
     assert [len(next(iter(t.values()))) > 0 for t in tables] == [True] * 3

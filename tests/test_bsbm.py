@@ -42,8 +42,8 @@ from benchmark.reference.sparql_subset import Reference  # noqa: E402
 from kolibrie_tpu.frontends import http_server  # noqa: E402
 from kolibrie_tpu.obs import export as obs_export  # noqa: E402
 from kolibrie_tpu.obs import spans as obs_spans  # noqa: E402
+from kolibrie_tpu.optimizer import caps as capacities  # noqa: E402
 from kolibrie_tpu.optimizer import device_engine as de  # noqa: E402
-from kolibrie_tpu.query.template import cap_advisor  # noqa: E402
 
 SEED = 2**31 + 42
 CONFIG = bench_files.read_json("configs", "bsbm-10m.json")
@@ -255,6 +255,12 @@ def _ask(base, sid, text, trace_id=None):
     return json.loads(_ask_body(base, sid, text, trace_id))["data"]
 
 
+def _capacities(base, sid):
+    """The store's block of ``/stats``: a record a template it has seen."""
+    with urllib.request.urlopen(base + "/stats", timeout=60) as resp:
+        return json.load(resp)["stores"][sid]["capacities"]["templates"]
+
+
 def _instance(data, template, index):
     text = bench_files.template_text(template)
     for domain in TEMPLATES[template]:
@@ -325,18 +331,19 @@ def test_the_first_instance_does_not_decide_a_templates_group_capacity(
     caps, compiled0 = {}, de.device_compile_stats()
     before = _counters()
     for order, first in (("cold_first", cold), ("hot_first", hot)):
-        cap_advisor.reset()  # as a fresh process: the advisor spans stores
         sid = stores[order]
+        known = _capacities(base, sid)  # the templates before this one
         assert len(_ask(base, sid, _instance(skewed, template, first))) == groups[first]
-        (entry,) = cap_advisor.stats()["templates"].values()
-        caps[order] = (entry["caps"], entry["group_cap"])
+        (entry,) = [e for e in _capacities(base, sid) if e not in known]
+        assert entry["provisional"] is False
+        caps[order] = (entry["caps"], entry["group_caps"])
         for k in groups:  # then every instance picked
             assert len(_ask(base, sid, _instance(skewed, template, k))) == groups[k]
-        (entry,) = cap_advisor.stats()["templates"].values()
-        assert entry["retries"] == 0 and (entry["caps"], entry["group_cap"]) == caps[order]
+        (entry,) = [e for e in _capacities(base, sid) if e not in known]
+        assert (entry["caps"], entry["group_caps"]) == caps[order]
     # one capacity set whichever came first: one plan and one aggregation compiled
     assert caps["cold_first"] == caps["hot_first"]
-    assert caps["cold_first"][1] >= groups[hot]
+    assert caps["cold_first"][1][0] >= groups[hot]
     compiled = de.device_compile_stats()
     assert compiled["run_plan"] - compiled0["run_plan"] <= 1
     assert compiled["segment_aggregate"] - compiled0["segment_aggregate"] <= 1
@@ -361,12 +368,16 @@ def test_a_pair_of_hot_countries_is_counted_by_the_pass_that_frees_both(
         text = _instance(skewed, "bsbm_bi_q1", k)
         rows.append(sum(int(r[1]) for r in ref.query(text)))
     assert rows[0] > 16 * max(rows[1], 1)
-    cap_advisor.reset()
     before = _counters()
     at_at = _instance(skewed, "bsbm_bi_q1", len(pair) - 1)
     assert sorted(map(tuple, _ask(base, stores["cold_first"], at_at))) == sorted(
         map(tuple, ref.query(at_at)))
-    (entry,) = cap_advisor.stats()["templates"].values()
+    from kolibrie_tpu.query.executor import _plan_cache_entry
+
+    httpd, _base = server
+    db = httpd.RequestHandlerClass.state.stores[stores["cold_first"]].db
+    fp = _plan_cache_entry(db, at_at)[0]["fp"]
+    (entry,) = [e for e in _capacities(base, stores["cold_first"]) if e["template"] == fp]
     assert max(entry["caps"]) >= rows[0]  # the hot pair's rows fit what AT-AT compiled
     us_us = _instance(skewed, "bsbm_bi_q1", us)
     assert sorted(map(tuple, _ask(base, stores["cold_first"], us_us))) == sorted(
@@ -396,7 +407,6 @@ def test_every_instance_of_every_domain_runs_at_the_ceilings(server, generated, 
     passes that free the placeholders); BI Q2's join is one, its group table
     sits over ``FILTER(?otherProduct != <product>)``, which no pass frees."""
     _httpd, base = server
-    cap_advisor.reset()
     sid = _load(base, generated)
     ref = Reference(generated["terms"], generated["s"], generated["p"], generated["o"])
     before, sized = _counters(), {}
@@ -422,7 +432,6 @@ def test_every_instance_of_every_domain_runs_at_the_ceilings(server, generated, 
     assert sized == {"bsbm_bi_q1": (7, 0), "bsbm_bi_q2": (1, 0), "bsbm_bi_q5": (4, 0)}
     # at 2,000 products Q2's join passes the floor and the rule sizes its
     # group table: with headroom, the join beneath it without
-    cap_advisor.reset()
     sid = _load(base, skewed)
     kinds0 = _kinds()
     text = _instance(skewed, "bsbm_bi_q2", 0)
@@ -454,7 +463,7 @@ def test_an_aggregate_request_leaves_dispatch_and_aggregate_spans(server, genera
     # the counters are that request's: the table the aggregation sorted (the
     # last join's capacity), its valid rows, the group capacity, the groups
     store = httpd.RequestHandlerClass.state.stores[sid]
-    (join_caps,) = [caps for key, caps in store.db.__dict__["_device_cap_cache"].items()]
+    ((_key, join_caps),) = capacities.of(store.db).joins.items()
     assert grew == {
         "kolibrie_device_aggregate_slots_total": join_caps[-1],
         "kolibrie_device_aggregate_rows_total": attrs["rows"],

@@ -27,13 +27,13 @@ import numpy as np
 import pytest
 
 import kolibrie_tpu.optimizer.device_engine as de
+from kolibrie_tpu.optimizer import caps as capacities
 from benchmark.harness import data as bench_files
 from kolibrie_tpu.obs import analyze as obs_analyze
 from kolibrie_tpu.obs import export as obs_export
 from kolibrie_tpu.optimizer.stats import hottest_key_rows
 from kolibrie_tpu.query.executor import execute_query_volcano
 from kolibrie_tpu.query.sparql_database import SparqlDatabase
-from kolibrie_tpu.query.template import cap_advisor
 
 PREFIX = "PREFIX ex: <http://example.org/>\n"
 
@@ -88,7 +88,6 @@ def lowered(db, q):
 def lubm1():
     """LUBM(1, seed 1) in UBA's shape, as the benchmark generates it: large
     enough that the heuristic caps pass FLOOR and the rule engages."""
-    cap_advisor.reset()
     config = bench_files.read_json("configs", "lubm-5.json")
     data = bench_files.load_module("generators", config["generator"]).generate(
         config, 1, 1
@@ -141,7 +140,7 @@ def test_lubm_caps_follow_the_counts_not_the_scans(lubm1):
     # a department has a few dozen professors: every join at the floor,
     # where the inputs' capacities alone asked for 2^15 and more
     assert rec["caps"] == [de._CAP_FLOOR] * len(rec["caps"]), rec
-    assert max(rec["counts"]) < de._CAP_FLOOR // de._CAP_HEADROOM
+    assert max(rec["counts"]) < de._CAP_FLOOR // capacities.CAP_HEADROOM
 
 
 # ------------------------------------- (b) overflow once, then monotonic
@@ -223,12 +222,11 @@ def dept_query(dept: str) -> str:
 
 
 def cached_caps(db):
-    (caps,) = db.__dict__["_device_cap_cache"].values()
+    ((_key, caps),) = capacities.of(db).joins.items()
     return caps
 
 
 def test_larger_variant_overflows_once_and_caps_never_shrink(first_sight_alone):
-    cap_advisor.reset()
     db = skewed_db()
     compiled0 = de.device_compile_stats()["run_plan"]
     retries0 = retries()
@@ -256,7 +254,6 @@ def test_host_pass_too_large_tightens_once_from_the_first_run(
     """The fallback: where the numpy twin gives up at the row limit the
     first dispatch runs at the heuristic, its counts tighten the caps once,
     and from then on they only grow."""
-    cap_advisor.reset()
     monkeypatch.setattr(de, "_CALIBRATE_ROW_LIMIT", 5)
     db = skewed_db()
     q = dept_query("small")
@@ -265,7 +262,7 @@ def test_host_pass_too_large_tightens_once_from_the_first_run(
     heuristic = cap.last("device")["caps"]
     assert heuristic[0] > de._CAP_FLOOR
     assert cached_caps(db) == (de._CAP_FLOOR,)
-    assert not db.__dict__["_device_cap_provisional"]
+    assert capacities.of(db).stats()["templates"][0]["provisional"] is False
     retries0 = retries()
     big = dept_query("big")
     assert device_rows(db, big) == host_rows(db, big)
@@ -275,17 +272,26 @@ def test_host_pass_too_large_tightens_once_from_the_first_run(
     assert cached_caps(db) == (16384,)
 
 
-def test_advice_replaces_the_heuristic_on_a_fresh_db():
-    """A second db of the same template starts from what the first
-    converged to, not from the larger of that and the heuristic."""
-    cap_advisor.reset()
+def test_a_second_store_in_the_process_is_sized_from_its_own_counts():
+    """ISSUE 46 (b): capacities are remembered on the store they were counted
+    on.  A second database in the same process, a tenth of the first's rows
+    under the same template, calibrates for itself: its own hottest key, a
+    ceiling, and not the first store's 8,192."""
     q = dept_query("small")
-    first = uniform_db()
+    first = skewed_db()
     device_rows(first, q)
-    fresh = uniform_db()
+    assert cached_caps(first) == (8192,)
+    tenth = skewed_db(big=600, small=1)
+    calibrated0 = counter(
+        'kolibrie_cap_calibrated_joins_total{engine="device",kind="ceiling"}')
     with obs_analyze.capture() as cap:
-        assert device_rows(fresh, q) == host_rows(fresh, q)
+        assert device_rows(tenth, q) == host_rows(tenth, q)
     assert cap.last("device")["caps"] == [de._CAP_FLOOR]
+    assert cached_caps(tenth) == (de._CAP_FLOOR,) and cached_caps(first) == (8192,)
+    assert counter(
+        'kolibrie_cap_calibrated_joins_total{engine="device",kind="ceiling"}'
+    ) == calibrated0 + 1
+    assert len(device_rows(tenth, dept_query("big"))) == 600
 
 
 def test_explain_calibration_publishes_rule_caps():
@@ -302,7 +308,6 @@ def test_explain_calibration_publishes_rule_caps():
 def test_the_hot_keys_capacity_is_there_from_the_first_instance():
     """ISSUE 40: the small department comes first and the template is sized
     for the large one all the same: no overflow, one executable."""
-    cap_advisor.reset()
     db = skewed_db()
     compiled0 = de.device_compile_stats()["run_plan"]
     retries0 = retries()
@@ -319,7 +324,6 @@ def test_the_hot_keys_capacity_is_there_from_the_first_instance():
     assert cached_caps(db) == (8192,) and retries() == retries0
     assert de.device_compile_stats()["run_plan"] == compiled
     # the large department first: the same capacities, the same executable
-    cap_advisor.reset()
     other = skewed_db()
     assert len(device_rows(other, dept_query("big"))) == 6000
     assert cached_caps(other) == (8192,)
@@ -331,7 +335,6 @@ def test_a_fan_out_that_no_scans_rows_show_is_counted_at_the_join():
     ``ex:dept``, but the members of one have 600 salaries each.  The pass
     counts the join's largest group, so the first instance of the other
     department sizes the template for this one."""
-    cap_advisor.reset()
     lines = []
     for i in range(20):
         e = f"<http://example.org/e{i}>"
@@ -355,7 +358,6 @@ def test_a_product_of_two_hot_keys_is_counted_by_the_pass_that_frees_both():
     is the largest group of the combination of the two freed columns.  The
     template starts where its hottest pair takes it: no re-run, one capacity
     set whichever pair came first."""
-    cap_advisor.reset()
     lines = []
     for a in range(62):  # 60 members of "big", 2 of "small"
         dept = "big" if a < 60 else "small"
@@ -399,7 +401,6 @@ def test_a_hot_pass_whose_join_passes_the_row_limit_counts_it_unmaterialized(
     its sides' keys, the matches of each freed row summed by freed key, and
     ends there.  The template starts at its hottest key; where the join is
     the plan's topmost its rows bound the groups of an aggregation too."""
-    cap_advisor.reset()
     db = tagged_db()
     # a scan reads 3,006 rows, the instance's own pass joins 6, the freed one
     # 50 x 60 x 60 + 3 x 2 x 2
@@ -428,7 +429,7 @@ def test_a_hot_pass_whose_join_passes_the_row_limit_counts_it_unmaterialized(
     assert counter("kolibrie_aggregate_cap_retries_total") == agg0
     if grouped:  # no group of the freed key holds more groups than rows: a
         # bound from a pass that was cut, so with headroom, under the table's width
-        (group_cap,) = db.__dict__["_device_group_cap_cache"].values()
+        ((_key, (group_cap,)),) = capacities.of(db).groups.items()
         assert group_cap == cap
 
 
@@ -456,7 +457,6 @@ def test_a_hot_pass_over_the_row_limit_is_left_out_and_the_first_instance_sizes(
     the row limit the pass is dropped, counted as ``too_large``, and the
     template is its first instance's: the large department then overflows
     once and is answered exactly."""
-    cap_advisor.reset()
     db = half_salaried_db()
     # the instance's own pass reads 10 and 3,010 rows, the freed scan 6,010
     monkeypatch.setattr(de, "_CALIBRATE_ROW_LIMIT", 4000)
@@ -577,7 +577,7 @@ def test_capacity_rule(heuristic, count, expected, ceiling):
     else:
         assert headroom == [cap]  # no flags: the headroom's arm
         # headroom wherever the heuristic leaves room for it
-        assert cap >= min(heuristic, de._CAP_HEADROOM * count)
+        assert cap >= min(heuristic, capacities.CAP_HEADROOM * count)
 
 
 def test_capacity_rule_is_elementwise():
@@ -592,7 +592,6 @@ def test_capacity_rule_is_elementwise():
 
 
 def test_occupancy_counters_are_rows_over_slots():
-    cap_advisor.reset()
     db = uniform_db(depts=60, members=50)
     slots0 = counter('kolibrie_device_cap_slots_total{engine="device"}')
     rows0 = counter('kolibrie_device_join_rows_total{engine="device"}')
@@ -610,7 +609,6 @@ def test_occupancy_counters_are_rows_over_slots():
 
 
 def test_calibration_seconds_are_counted_once_a_template():
-    cap_advisor.reset()
     family = 'kolibrie_cap_calibrate_seconds_total{outcome="counted"}'
     db = skewed_db(big=3000, small=50)
     before = counter(family)
@@ -738,8 +736,8 @@ def case_two_texts_of_one_shape_are_two_templates(db):
     got = execute_queries_batched(db, [qa, qb])
     assert [sorted(map(tuple, rows)) for rows in got] == want
     assert counter(batches) == before  # no group: each rode a dispatch of its own
-    assert len(db.__dict__["_device_cap_cache"]) == 2  # a record a predicate set
-    assert len(cap_advisor.stats()["templates"]) == 2
+    assert len(capacities.of(db).joins) == 2  # a record a predicate set
+    assert len(capacities.of(db).stats()["templates"]) == 2
     with pytest.raises(de.Unsupported):  # and a group made by hand is refused
         de.execute_plan_batch([lows[0], lows[2]])
     # two instances of one text do ride one dispatch, as before
@@ -757,7 +755,6 @@ def case_two_texts_of_one_shape_are_two_templates(db):
     case_two_texts_of_one_shape_are_two_templates,
 ], ids=lambda case: case.__name__[5:])
 def test_a_scan_is_as_wide_as_the_predicate_it_names(case):
-    cap_advisor.reset()
     case(two_predicates_db())
 
 
@@ -804,7 +801,6 @@ def test_every_instance_runs_at_the_ceiling_with_no_retry_and_one_executable():
     """The smallest department comes first; the template is compiled for the
     largest one's rows as they are, and every department of the store runs
     there: no instance is left for a headroom to wait for."""
-    cap_advisor.reset()
     sizes = (3000, 400, 10, 1)
     db = staffed_db(sizes)
     kinds0, retries0 = calibrated_kinds(), retries()
@@ -948,14 +944,12 @@ def case_a_branch_with_a_parameter(monkeypatch):
     case_a_branch_with_a_parameter,
 ], ids=lambda case: case.__name__[5:])
 def test_a_count_is_no_ceiling_above(case, monkeypatch):
-    cap_advisor.reset()
     case(monkeypatch)
 
 
 def test_a_group_table_keeps_its_headroom_under_a_parameterised_filter():
     """BI Q2's shape: the join under the FILTER is a ceiling, the groups over
     it depend on the FILTER's constant, which no pass frees."""
-    cap_advisor.reset()
     db = skewed_db()
     text = PREFIX + (
         'SELECT ?s (COUNT(?e) AS ?n) WHERE {{ ?e ex:dept "{}" . ?e ex:salary ?s {}}} '
@@ -968,17 +962,16 @@ def test_a_group_table_keeps_its_headroom_under_a_parameterised_filter():
             rows = device_rows(db, q)
             assert len(rows) == groups and rows == host_rows(db, q)
         assert kinds_since(kinds0) == kinds, tail  # the join, then the group table
-    assert set(db.__dict__["_device_group_cap_cache"].values()) == {de._CAP_FLOOR}
+    assert {c for _k, c in capacities.of(db).groups.items()} == {(de._CAP_FLOOR,)}
     assert retries() == retries0
     assert counter("kolibrie_aggregate_cap_retries_total") == agg0
 
 
 def test_a_write_that_passes_a_ceiling_costs_one_retry():
-    """A ceiling is the store's at first sight, and ``_device_cap_cache`` is
+    """A ceiling is the store's at first sight, and the store's capacities are
     not dropped on a write: the department that grows past it meets the
     overflow protocol once, is answered exactly, and runs at the doubled
     capacity from then on, as an instance past the headroom always did."""
-    cap_advisor.reset()
     db = skewed_db(big=1500, small=10)
     assert device_rows(db, dept_query("small")) == host_rows(db, dept_query("small"))
     assert cached_caps(db) == (2048,)  # 1,500 rows, no headroom
@@ -1042,3 +1035,105 @@ def test_no_instance_passes_a_ceiling_at_full_scale(config, traffic, sample):
                 assert de._most_groups(
                     [], [table[g] for g in low._stage.group_by]) <= groups
         assert len(sights) == 1, step["template"]  # one template a text
+
+
+# ------------------------- (g) one module: the memory and the loop (ISSUE 46)
+
+
+def test_an_aggregate_template_then_a_store_wide_scan_and_a_wcoj_query_on_one_store():
+    """A GROUP BY's capacity and the largest key-group of an order's prefix
+    are two tables of the store's memory, each under keys of its own: the
+    first sight of a scan with a variable predicate, or of a WCOJ accessor,
+    prunes the second by ``base_version`` and never reads the first.  (They
+    shared one dict once, and the pruning indexed the aggregate's
+    two-element key: ``IndexError``.)"""
+    db = staffed_db((300, 40))
+    agg = PREFIX + (
+        'SELECT ?s (COUNT(?e) AS ?n) WHERE { ?e ex:dept "d0" . ?e ex:salary ?s } '
+        "GROUP BY ?s")
+    assert device_rows(db, agg) == host_rows(db, agg)
+    memory = capacities.of(db)
+    assert len(memory.groups) == 1
+    by_subject = PREFIX + "SELECT ?p ?a WHERE { ex:e7 ?p ?a }"
+    assert scan_caps(db, by_subject) == {None: wide(db, 3)}
+    assert device_rows(db, by_subject) == host_rows(db, by_subject)
+    triangle = PREFIX + (
+        "SELECT ?a ?b ?c WHERE { ?a ex:boss ?b . ?b ex:boss ?c . ?c ex:boss ?a }")
+    assert isinstance(lowered(db, triangle).root, de.WcojSpec)
+    assert device_rows(db, triangle) == host_rows(db, triangle)
+    assert len(memory.groups) == 1 and device_rows(db, agg) == host_rows(db, agg)
+    # a write that rebuilds the base drops the key-groups and nothing else
+    held = dict(memory.joins.items())
+    version = db.store.base_version
+    assert memory.largest_key_group("spo", 1, version, count=None) == 3
+    assert memory.largest_key_group("spo", 1, version + 1, count=lambda: 7) == 7
+    assert dict(memory.joins.items()) == held and len(memory.groups) == 1
+
+
+def _one_join(dept: str) -> str:
+    return PREFIX + f'SELECT ?e ?s WHERE {{ ?e ex:dept "{dept}" . ?e ex:salary ?s }}'
+
+
+def _lone_plan(db):
+    q = _one_join("d0")
+    return (lambda: device_rows(db, q)), [host_rows(db, q)], "joins"
+
+
+def _group_of_three(db):
+    from kolibrie_tpu.query.executor import execute_queries_batched
+
+    qs = [_one_join(d) for d in ("d0", "d1", "d2")]
+    return (
+        lambda: [sorted(map(tuple, rows)) for rows in execute_queries_batched(db, qs)],
+        [[host_rows(db, q) for q in qs]],
+        "joins",
+    )
+
+
+def _aggregation(db):
+    q = PREFIX + (
+        'SELECT ?e (COUNT(?s) AS ?n) WHERE { ?e ex:dept "d0" . ?e ex:salary ?s } '
+        "GROUP BY ?e")
+    return (lambda: device_rows(db, q)), [host_rows(db, q)], "groups"
+
+
+@pytest.mark.parametrize("caller", [_lone_plan, _group_of_three, _aggregation],
+                         ids=lambda caller: caller.__name__[1:])
+def test_the_one_overflow_loop_serves_its_three_callers_alike(caller, monkeypatch):
+    """``caps.run_until_fits`` under ``LoweredPlan.converge``, the group's
+    dispatch and ``aggregate_table``: each starts from a capacity that the
+    3,000 rows (the most of the group's members; the 3,000 groups) overflow.
+    One counted retry, the same grown capacity, stored once."""
+    db = staffed_db((3000, 400, 10))
+    run, (want,), table = caller(db)
+    assert run() == want  # the first sight: calibrated, compiled, no retry
+    memory = capacities.of(db)
+    ((join_key, _held),) = memory.joins.items()
+    grown = capacities.grown_cap(3000)
+    if table == "groups":  # a table as wide as the other callers' joins grow to
+        memory.joins.start(join_key, [grown])
+        ((key, _held),) = memory.groups.items()
+        memory.groups.start(key, [de._CAP_FLOOR])
+    else:
+        key = join_key
+        memory.joins.start(key, [de._CAP_FLOOR])
+    stored, merge = [], capacities.Remembered.merge
+
+    def spy(self, k, caps):
+        before = self.get(k)
+        after = merge(self, k, caps)
+        if after != before:
+            stored.append((k, after))
+        return after
+
+    monkeypatch.setattr(capacities.Remembered, "merge", spy)
+    before = retries(), counter("kolibrie_aggregate_cap_retries_total")
+    assert run() == want
+    after = retries(), counter("kolibrie_aggregate_cap_retries_total")
+    counted = (0, 1) if table == "groups" else (1, 0)
+    assert tuple(b - a for a, b in zip(before, after)) == counted
+    assert stored == [(key, (grown,))]
+    assert getattr(memory, table).get(key) == (grown,)
+    assert run() == want and after == (
+        retries(), counter("kolibrie_aggregate_cap_retries_total"))
+    assert stored == [(key, (grown,))]

@@ -303,11 +303,7 @@ def test_mesh_program_lubm_q7_four_chips(topo, mesh8):
     from kolibrie_tpu.parallel import sharded_serving as ss
     from kolibrie_tpu.query.executor import _plan_cache_entry
     from kolibrie_tpu.query.sparql_database import SparqlDatabase
-    from kolibrie_tpu.query.template import cap_advisor
 
-    # the advisor's high-water marks are the process's: a Q7 that another
-    # test file ran on this worker must not widen the counted capacities
-    cap_advisor.reset()
     config = bench_files.read_json("configs", "lubm-5-mesh4.json")
     data = bench_files.load_module("generators", config["generator"]).generate(
         config, 7, 1)

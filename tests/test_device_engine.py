@@ -204,9 +204,9 @@ def test_capacity_doubling_converges():
     lowered = lower_plan(db, plan)
     lowered.build()
     # sabotage the cap cache with a too-small value
-    db._device_cap_cache[lowered.cap_key] = tuple(
-        128 for _ in range(lowered.join_count)
-    )
+    from kolibrie_tpu.optimizer import caps
+
+    caps.of(db).joins.start(lowered.cap_key, [128] * lowered.join_count)
     lowered2 = lower_plan(db, plan)
     table = lowered2.execute()
     assert len(next(iter(table.values()))) == 500

@@ -15,6 +15,7 @@ from kolibrie_tpu.obs import export as obs_export
 from kolibrie_tpu.obs import metrics as obs_metrics
 from kolibrie_tpu.obs import runtime as obs_runtime
 from kolibrie_tpu.obs import spans as obs_spans
+from kolibrie_tpu.optimizer import caps
 
 # ------------------------------------------------------------------ helpers
 
@@ -520,17 +521,15 @@ def test_device_dispatch_child_spans():
 
 def test_cap_overflow_repeats_children_and_counts_seconds():
     from kolibrie_tpu import execute_query_volcano
-    from kolibrie_tpu.query.template import cap_advisor
 
     family = 'kolibrie_cap_retry_seconds_total{engine="device"}'
     db = graph_db()
     rows = execute_query_volcano(JOIN_Q, db)
     # forget what the first run learned: the next starts from a capacity
     # its 40 matches overflow, and has to re-run with a doubled one
-    caps = db.__dict__["_device_cap_cache"]
-    for key in caps:
-        caps[key] = tuple(8 for _ in caps[key])
-    cap_advisor.reset()
+    joins = caps.of(db).joins
+    for key, held in joins.items():
+        joins.start(key, [8] * len(held))
     obs_spans.clear()
     before = counter_values("kolibrie_cap_retry_seconds_total")[family]
     assert execute_query_volcano(JOIN_Q, db) == rows

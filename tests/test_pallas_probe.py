@@ -32,7 +32,6 @@ from kolibrie_tpu.ops.wcoj import (
 )
 from kolibrie_tpu.query.executor import execute_query_volcano
 from kolibrie_tpu.query.sparql_database import SparqlDatabase
-from kolibrie_tpu.query.template import cap_advisor
 
 import jax.numpy as jnp
 
@@ -488,87 +487,77 @@ def test_pallas_mode_flip_replans(monkeypatch):
     assert after != base, "mode flip replayed the cached executable"
 
 
-# ----------------------------------------------------------- cap advisor
+# ------------------------------------------- capacities, warm and in /stats
 
 
-def test_cap_advisor_zero_retries_when_warm(monkeypatch):
-    """The chaos-mutation scenario the advisor exists for: a dense cyclic
-    workload whose per-level candidate totals exceed the optimistic
-    heuristic start walks the double-and-retry ladder once (cold), after
-    which EVERY re-dispatch — fresh db objects (cap-cache churn), store
-    mutations (base-version bumps) — starts at the high-water mark and
-    retries stay at zero.  Disabling the advisor re-walks the ladder on
-    the same workload, pinning the causality."""
+def _device_retries() -> float:
+    from kolibrie_tpu.obs import export as obs_export
+
+    name = 'kolibrie_cap_retries_total{engine="device"}'
+    for line in obs_export.render_prometheus().splitlines():
+        if line.startswith(name):
+            return float(line.rpartition(" ")[2])
+    raise KeyError(name)
+
+
+def test_a_warm_database_redispatches_with_zero_retries(monkeypatch):
+    """A dense cyclic workload whose per-level candidate totals exceed the
+    optimistic heuristic start walks the double-and-retry ladder once
+    (cold); what it converged to is remembered on the store, so EVERY
+    re-dispatch, store mutations (base-version bumps) between them or not,
+    starts there and ``kolibrie_cap_retries_total{engine="device"}`` stays
+    where the first dispatch left it."""
     monkeypatch.setenv("KOLIBRIE_WCOJ", "auto")
-    monkeypatch.delenv("KOLIBRIE_CAP_ADVISOR", raising=False)
-    cap_advisor.reset()
     rng = np.random.default_rng(5)
-
-    def build():
-        db, lines = _graph_db(rng, 40, 1500)
-        return db
-
-    db1 = build()
-    rows1 = _rows(db1, TRI_Q, "device")
-    cold = cap_advisor.retries("device")
-    assert cold > 0, "workload must actually exercise the retry ladder"
-    # fresh db: the per-db cap cache is gone, the advisor is not
-    rng = np.random.default_rng(5)
-    db2 = build()
-    before = cap_advisor.retries("device")
-    rows2 = _rows(db2, TRI_Q, "device")
-    assert cap_advisor.retries("device") == before, (
-        "warm advisor must eliminate doubled-cap retried dispatches"
-    )
-    assert rows1 == rows2
-    # mutation churn on the live db: deletes + re-adds bump versions;
-    # re-dispatch must stay retry-free
-    db2.parse_ntriples(
+    db, _lines = _graph_db(rng, 40, 1500)
+    cold0 = _device_retries()
+    rows = _rows(db, TRI_Q, "device")
+    warm = _device_retries()
+    assert warm > cold0, "workload must actually exercise the retry ladder"
+    assert _rows(db, TRI_Q, "device") == rows
+    assert _device_retries() == warm, "a warm store must not retry"
+    # mutation churn on the live db: re-dispatch must stay retry-free
+    db.parse_ntriples(
         "\n".join(
             f"<http://example.org/n{i}> <http://example.org/p2> "
             f"<http://example.org/n{(i + 1) % 40}> ."
             for i in range(20)
         )
     )
-    before = cap_advisor.retries("device")
-    _rows(db2, TRI_Q, "device")
-    assert cap_advisor.retries("device") == before
-    # control: same fresh-db dispatch with advice disabled re-walks the
-    # ladder (observation continues, so the counter still moves)
-    monkeypatch.setenv("KOLIBRIE_CAP_ADVISOR", "off")
-    rng = np.random.default_rng(5)
-    db3 = build()
-    before = cap_advisor.retries("device")
-    rows3 = _rows(db3, TRI_Q, "device")
-    assert cap_advisor.retries("device") > before, (
-        "disabled advisor should fall back to the retry ladder"
-    )
-    assert rows3 == rows1
+    _rows(db, TRI_Q, "device")
+    assert _device_retries() == warm
 
 
-def test_cap_advisor_stats_surface():
-    """The /stats payload carries the advisor block and /metrics carries
-    the retry counter family (pre-created engine series)."""
+def test_capacities_stats_surface(monkeypatch):
+    """The /stats payload carries, store by store, the capacities block fed
+    from that database's one capacity store, and /metrics carries the
+    retry counter family (pre-created engine series)."""
+    from kolibrie_tpu.frontends.http_server import TemplateBatcher
     from kolibrie_tpu.obs import export as obs_export
+    from kolibrie_tpu.optimizer import caps
 
-    cap_advisor.reset()
-    cap_advisor.observe("device", "fp-test", (256, 1024), base_version=3)
-    cap_advisor.observe_retry("device", "fp-test")
-    stats = cap_advisor.stats()
-    assert stats["enabled"] is True
-    rec = stats["templates"]["device:fp-test"]
-    assert rec["caps"] == [256, 1024]
-    assert rec["hwm"] == 1024
-    assert rec["retries"] == 1
-    assert rec["base_version"] == 3
-    assert stats["retries_total"] == 1
+    monkeypatch.setenv("KOLIBRIE_WCOJ", "auto")
+    rng = np.random.default_rng(5)
+    db, _lines = _graph_db(rng, 40, 1500)
+    assert TemplateBatcher(db).stats()["capacities"] == {"templates": []}
+    _rows(db, TRI_Q, "device")
+    block = TemplateBatcher(db).stats()["capacities"]
+    assert block == caps.of(db).stats()
+    (rec,) = block["templates"]
+    ((_key, held),) = caps.of(db).joins.items()
+    assert rec["caps"] == list(held) and len(held) == 3  # a WCOJ level a variable
+    assert rec["provisional"] is False and rec["group_caps"] == []
+    assert len(rec["template"]) == 40  # the fingerprint it was dispatched under
+    assert "enabled" not in block
+    # a second store's block is its own
+    assert TemplateBatcher(SparqlDatabase()).stats()["capacities"] == {"templates": []}
     # monotonic elementwise-max merge
-    cap_advisor.observe("device", "fp-test", (512, 512))
-    assert cap_advisor.advise("device", "fp-test") == (512, 1024)
+    key = _key
+    caps.of(db).joins.merge(key, [1, 1 << 30, 1])
+    assert caps.of(db).joins.get(key) == (held[0], 1 << 30, held[2])
     prom = obs_export.render_prometheus()
     assert 'kolibrie_cap_retries_total{engine="device"}' in prom
     assert 'kolibrie_cap_retries_total{engine="sharded"}' in prom
-    cap_advisor.reset()
 
 
 # ------------------------------------------- the sorted form of lex_range

@@ -29,7 +29,6 @@ from kolibrie_tpu.query.executor import (
     execute_query_volcano,
 )
 from kolibrie_tpu.query.sparql_database import SparqlDatabase
-from kolibrie_tpu.query.template import cap_advisor
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "examples"))
 import lubm  # noqa: E402
@@ -955,9 +954,6 @@ def test_a_constant_over_four_times_the_count_retries_doubled(mesh8, hot):
     )
     small, other = text.format("small"), text.format(hot)
     fp = _fp(db, small)
-    # the advisor's process-wide high-water mark would start this store's
-    # capacities where the other case's left them
-    cap_advisor.reset()
     assert sh.execute_batch(fp, [(0, small)])[0] == execute_query_volcano(
         small, db
     )

@@ -46,7 +46,6 @@ from kolibrie_tpu.optimizer.device_engine import (  # noqa: E402
     device_compile_stats,
     predicate_rows,
 )
-from kolibrie_tpu.query.template import cap_advisor  # noqa: E402
 
 SEED = 2**31 + 40
 CONFIG = bench_files.read_json("configs", "watdiv-100.json")
@@ -168,6 +167,12 @@ def _ask(base, sid, text):
                                         "deadline_ms": 900_000})["data"]
 
 
+def _capacities(base, sid):
+    """The store's block of ``/stats``: a record a template it has seen."""
+    with urllib.request.urlopen(base + "/stats", timeout=60) as resp:
+        return json.load(resp)["stores"][sid]["capacities"]["templates"]
+
+
 def _instance(data, template, index):
     text = bench_files.template_text("watdiv_" + template)
     domain = TEMPLATES[template]
@@ -262,16 +267,18 @@ def test_the_first_instance_does_not_decide_a_templates_capacities(
         assert rows[hot] > 1024 >= 4 * rows[cold] > 0
     caps = {}
     compiled0 = device_compile_stats()["run_plan"]
+    retries0 = _metric('kolibrie_cap_retries_total{engine="device"}')
     for order, first in (("cold_first", cold), ("hot_first", hot)):
-        cap_advisor.reset()  # as a fresh process: the advisor spans stores
         sid = stores[order]
+        known = _capacities(base, sid)  # the templates before this one
         assert len(_ask(base, sid, _instance(skewed, template, first))) == rows[first]
-        (entry,) = cap_advisor.stats()["templates"].values()
+        (entry,) = [e for e in _capacities(base, sid) if e not in known]
         caps[order] = entry["caps"]
         for k in range(len(domain)):  # then every instance of the class
             assert len(_ask(base, sid, _instance(skewed, template, k))) == rows[k]
-        (entry,) = cap_advisor.stats()["templates"].values()
-        assert entry["retries"] == 0 and entry["caps"] == caps[order], order
+        (entry,) = [e for e in _capacities(base, sid) if e not in known]
+        assert entry["caps"] == caps[order], order
+    assert _metric('kolibrie_cap_retries_total{engine="device"}') == retries0
     # one capacity set and one join order whichever came first: one executable
     assert caps["cold_first"] == caps["hot_first"]
     assert device_compile_stats()["run_plan"] - compiled0 <= 1

@@ -363,8 +363,9 @@ def _lower(db, sparql):
 def _at_capacity(db, sparql, cap):
     """The request's lowering with every level ``cap`` wide."""
     low = _lower(db, sparql)
-    db.__dict__.setdefault("_device_cap_cache", {})[low.cap_key] = (
-        (cap,) * low.join_count)
+    from kolibrie_tpu.optimizer import caps
+
+    caps.of(db).joins.start(low.cap_key, [cap] * low.join_count)
     return _lower(db, sparql)
 
 
