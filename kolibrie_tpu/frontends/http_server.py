@@ -102,6 +102,24 @@ _BATCH_DISPATCH_START = obs_metrics.counter(
     "handoff = it had queued behind a holder and left when that released",
     labels=("at",),
 )
+_BATCH_DISPATCH_TEMPLATES = obs_metrics.counter(
+    "kolibrie_batcher_dispatch_templates_total",
+    "distinct template fingerprints the batch dispatches carried, summed: "
+    "over kolibrie_batcher_dispatches_total the mean templates a dispatch "
+    "(1 while no two templates ever met under one hold of the lock)",
+)
+_BATCH_DISPATCH_PROGRAMS = obs_metrics.counter(
+    "kolibrie_batcher_dispatch_programs_total",
+    "device programs the batch dispatches ran: group = one program a "
+    "template group served as one (on one chip two or more members), "
+    "solo = one a request served alone, a singleton behind the groups of "
+    "its dispatch or a lone request",
+    labels=("kind",),
+)
+# both kinds have a line from the start, so a window in which one never
+# grew reads 0 and not nothing
+_BATCH_GROUP_PROGRAMS = _BATCH_DISPATCH_PROGRAMS.labels("group")
+_BATCH_SOLO_PROGRAMS = _BATCH_DISPATCH_PROGRAMS.labels("solo")
 _BATCH_DEDUP = obs_metrics.counter(
     "kolibrie_batcher_dedup_hits_total",
     "in-flight identical-text queries answered by one execution",
@@ -503,6 +521,7 @@ class TemplateBatcher:
 
     def _run_batch(self, batch: List[_BatchRequest]) -> None:  # kolint: holds[dispatch_lock]
         from kolibrie_tpu.query.executor import (
+            dispatch_programs,
             execute_queries_batched,
             execute_query_volcano,
         )
@@ -510,10 +529,25 @@ class TemplateBatcher:
         texts = [r.text for r in batch]
         uniq = list(dict.fromkeys(texts))
         start = time.perf_counter()
+        ran = dispatch_programs(self.db)
+
+        def composition():
+            """What the dispatch carried and ran: its distinct texts by
+            template fingerprint (as the executor's parse cache knows them
+            once they have run) and its group and solo programs."""
+            parse_cache = self.db.__dict__.get("_plan_cache", {})
+            by_fp: Dict[str, List[str]] = {}
+            for text in uniq:
+                fp = (parse_cache.get(text) or {}).get("fp") or "unparsed"
+                by_fp.setdefault(fp, []).append(text)
+            group, solo = dispatch_programs(self.db)
+            return by_fp, group - ran[0], solo - ran[1]
+
         # the dispatch span lands in the LEADER's trace (followers' spans
-        # would need span links, which this tracer doesn't model); solo
+        # would need span links, which this tracer doesn't model), and so do
+        # the spans of every group and every singleton it serves; solo
         # retries below re-enter each member's own captured trace
-        with span("batcher.dispatch", batch=len(batch), uniq=len(uniq)):
+        with span("batcher.dispatch", batch=len(batch), uniq=len(uniq)) as sp:
             try:
                 with deadline_scope(self._batch_deadline(batch)):
                     by_text = dict(
@@ -533,20 +567,21 @@ class TemplateBatcher:
                     except Exception as e:
                         r.error = e
                     r.done.set()
-                self._count(batch, texts, uniq, time.perf_counter() - start)
+                self._count(
+                    batch, texts, uniq, composition(), time.perf_counter() - start
+                )
                 return
+            carried = composition()
+            if sp is not None:  # what only the execution tells
+                sp.attrs.update(templates=len(carried[0]), programs=carried[1:])
         for r in batch:
             r.result = by_text[r.text]
             r.done.set()
-        self._count(batch, texts, uniq, time.perf_counter() - start)
+        self._count(batch, texts, uniq, carried, time.perf_counter() - start)
 
-    def _count(self, batch, texts, uniq, elapsed: float) -> None:  # kolint: holds[dispatch_lock]
+    def _count(self, batch, texts, uniq, carried, elapsed: float) -> None:  # kolint: holds[dispatch_lock]
         ms = elapsed * 1000.0
-        parse_cache = self.db.__dict__.get("_plan_cache", {})
-        by_fp: Dict[str, List[str]] = {}
-        for text in uniq:
-            ent = parse_cache.get(text)
-            by_fp.setdefault((ent or {}).get("fp") or "unparsed", []).append(text)
+        by_fp, group, solo = carried
         with self.lock:
             self.dispatches += 1
             self.dedup_hits += len(texts) - len(uniq)
@@ -567,6 +602,9 @@ class TemplateBatcher:
         _BATCH_QUEUE_AT_DISPATCH.observe(len(batch))
         _BATCH_DISTINCT_TEMPLATES.observe(len(by_fp))
         _BATCH_DISPATCHES.inc()
+        _BATCH_DISPATCH_TEMPLATES.inc(len(by_fp))
+        _BATCH_GROUP_PROGRAMS.inc(group)
+        _BATCH_SOLO_PROGRAMS.inc(solo)
         _BATCH_DEDUP.inc(len(texts) - len(uniq))
         _BATCH_SIZE.observe(len(batch))
         for fp in by_fp:

@@ -979,6 +979,7 @@ def _plan_caches(db):
             "evictions": 0,
             "batched": 0,
             "batch_groups": 0,
+            "solo_tail": 0,
         }
         db.__dict__["_plan_cache_stats"] = stats
     return parse, templates, stats
@@ -1257,6 +1258,7 @@ def plan_cache_info(db) -> dict:
         "evictions": stats["evictions"],
         "batched": stats["batched"],
         "batch_groups": stats["batch_groups"],
+        "solo_tail": stats["solo_tail"],
         "sticky_failures": sticky,
         "sentinel_expiries": stats.get("sentinel_expiries", 0),
         "advisor_replans": stats.get("advisor_replans", 0),
@@ -1556,11 +1558,38 @@ def execute_queries_batched(db, queries: List[str]) -> List[Rows]:
             fp = _solo_prefix_fp(db, queries[i])
             if fp is not None:
                 transient_fps.append(fp)
+
+    def solo_tail():
+        for i in pending:
+            results[i] = execute_query_volcano(queries[i], db)
+
     with _mqo.transient_scope(db, transient_fps):
-        for i, text in enumerate(queries):
-            if results[i] is None:
-                results[i] = execute_query_volcano(text, db)
+        if pending and len(queries) > 1:
+            # one span around the singletons that run behind other members
+            # of the same dispatch (its groups, or one another): what a mixed
+            # dispatch adds to a request that would have left alone.  A lone
+            # request opens none
+            with span(
+                "executor.solo_tail",
+                members=len(pending),
+                grouped=len(queries) - len(pending),
+            ):
+                solo_tail()
+        else:
+            solo_tail()
+    stats["solo_tail"] += len(pending)
     return results
+
+
+def dispatch_programs(db) -> Tuple[int, int]:
+    """What ``execute_queries_batched`` has run on ``db`` so far: ``(group
+    programs, solo programs)``: one group program a template group served
+    as one (on one chip two or more members, on the mesh one or more), one
+    solo program a query that went through :func:`execute_query_volcano`, a
+    lone request's too.  The micro-batcher reads it around a dispatch, under
+    the lock that serializes the store."""
+    _, _, stats = _plan_caches(db)
+    return stats["batch_groups"], stats["solo_tail"]
 
 
 def _solo_prefix_fp(db, text: str) -> Optional[str]:

@@ -9,8 +9,11 @@ two triangles cells, ``lubm50.lookups`` (``lookups`` against ``lubm-50``) and
 the two join-search metrics are in as ISSUE 39 states them, ``watdiv-100``,
 ``watdiv100.stars_snowflakes`` and the two scan metrics as ISSUE 40 does,
 ``bsbm-10m``, ``bsbm10m.bi_counts`` and the seven aggregate metrics as ISSUE
-42 does (nine cells of seven configurations, one of four chips), ISSUE 45's
-three counts of the micro-batcher are data files for every cell, every file a cell or a
+42 does, ISSUE 45's three counts of the micro-batcher are data files for
+every cell, ``lubm-50-clients8``, ``lubm50.mix8`` and the four metrics of a
+dispatch's composition as ISSUE 47 does (ten cells of eight configurations,
+one of four chips; every cell and metric is found by its name, so that the
+next one appended breaks none of these), every file a cell or a
 per-layer metric names is there, a program that lacks what a cell
 requires of it (``benchmark/requires``) is refused before anything starts,
 and ``run.py`` itself, started off the chip, prints no result and exits 3
@@ -41,6 +44,19 @@ CELLS = {w["name"]: w for w in BENCH["workloads"]}
 ONE_CLIENT = {"lubm5.triangles": 1, "lubm5.lookups": 1, "employee100k.upstream": 25000}
 DIGESTS = files.read_json("data", "traffic_digests.json")["digests"]
 MESH4 = files.read_json("data", "lubm5.mesh4.entries.json")
+CONFIGS = {c["name"]: c for c in BENCH["configs"]}
+CELL_ORDER = [w["name"] for w in BENCH["workloads"]]
+CONFIG_ORDER = [c["name"] for c in BENCH["configs"]]
+
+
+def per_layer_run(names):
+    """The ``per_layer`` entries of ``names``, found by the first one's name:
+    they stand together, in the order they were appended."""
+    listed = [m["name"] for m in BENCH["per_layer"]]
+    at = listed.index(list(names)[0])
+    added = BENCH["per_layer"][at:at + len(names)]
+    assert [m["name"] for m in added] == list(names)
+    return added
 
 
 def generated(workload, seed, scale):
@@ -130,7 +146,9 @@ def test_benchmark_json_has_the_one_chip_cell_of_eight_clients():
     cell = CELLS["lubm5.batch8"]
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "lubm-5-clients8", "mesh_q7", 1)
-    assert BENCH["workloads"][4] == cell  # appended, nothing before it moved
+    # appended, nothing before it moved
+    assert CELL_ORDER[:CELL_ORDER.index(cell["name"])] == [
+        "lubm5.triangles", "lubm5.lookups", "employee100k.upstream", "lubm5.mesh4"]
     config = files.read_json("configs", "lubm-5-clients8.json")
     one_client = files.read_json("configs", "lubm-5.json")
     assert (config["chips"], config["universities"], config["store_mode"]) == (
@@ -176,8 +194,10 @@ def test_benchmark_json_has_lubm_50_uncut_and_its_cell():
     cell = CELLS["lubm50.triangles"]
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "lubm-50", "triangles", 1)
-    assert BENCH["workloads"][5] == cell  # appended, nothing before it moved
-    entry = BENCH["configs"][4]
+    # appended, nothing before it moved
+    assert CELL_ORDER[CELL_ORDER.index(cell["name"]) - 1] == "lubm5.batch8"
+    entry = CONFIGS["lubm-50"]
+    assert CONFIG_ORDER[CONFIG_ORDER.index("lubm-50") - 1] == "lubm-5-clients8"
     assert (entry["name"], entry["file"], entry["reduced"]) == (
         "lubm-50", "benchmark/configs/lubm-50.json", [])
     assert "LUBM(50, seed), the largest the paper reports" in entry["source"]
@@ -257,15 +277,14 @@ RANGE_SEARCH_METRICS = {
 TRIANGLES_CELLS = ["lubm5.triangles", "lubm50.triangles"]
 
 
-def test_the_range_search_metrics_are_the_last_entries_and_data_alone():
+def test_the_range_search_metrics_stand_together_and_are_data_alone():
     """ISSUE 35: three per-layer entries, appended, for the two triangles
     cells; each a data file of a reader that was there.  (ISSUE 38 appended
     its seven behind them: tests/test_compile_first_sight.py.)"""
+    added = per_layer_run(RANGE_SEARCH_METRICS)
+    # behind ISSUE 34's last: nothing before them moved
     names = [m["name"] for m in BENCH["per_layer"]]
-    at = names.index("sort_pct")
-    added = BENCH["per_layer"][at:at + len(RANGE_SEARCH_METRICS)]
-    assert [m["name"] for m in added] == list(RANGE_SEARCH_METRICS)
-    assert at == 64  # where ISSUE 35 left them: nothing before them moved
+    assert names[names.index("sort_pct") - 1] == "wcoj_probes_in_window"
     for m in added:
         kind, layer, source, args = RANGE_SEARCH_METRICS[m["name"]]
         assert (m["layer"], m["moves"], m["workloads"], m["source"], m["unit"]) == (
@@ -275,7 +294,7 @@ def test_the_range_search_metrics_are_the_last_entries_and_data_alone():
         assert reader == {"kind": kind, **args}
         assert os.path.exists(files.path("readers", kind + ".py"))
     # no cell came with them, and no other list took a triangles cell in
-    assert [w["name"] for w in BENCH["workloads"]][5] == "lubm50.triangles"
+    assert CELL_ORDER[CELL_ORDER.index("lubm50.triangles") - 1] == "lubm5.batch8"
     added_files = {name + ".json" for name in RANGE_SEARCH_METRICS}
     assert added_files <= set(os.listdir(files.path("layer_metrics")))
 
@@ -321,7 +340,7 @@ JOIN_SEARCH_CELLS = ["lubm5.lookups", "employee100k.upstream", "lubm5.batch8",
                      "lubm50.lookups"]
 
 
-def test_benchmark_json_has_the_lookups_against_lubm_50_as_its_seventh_cell():
+def test_benchmark_json_has_the_lookups_against_lubm_50_behind_its_triangles():
     """ISSUE 39: ``lookups`` as it stands against ``lubm-50`` as it stands,
     one chip, a data file beside the others; two per-layer entries appended,
     each a data file of a reader that was there; no standing list took the
@@ -329,18 +348,19 @@ def test_benchmark_json_has_the_lookups_against_lubm_50_as_its_seventh_cell():
     cell = CELLS["lubm50.lookups"]
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "lubm-50", "lookups", 1)
-    assert BENCH["workloads"][6] == cell and len(cell["why"]) <= 200
+    assert CELL_ORDER[CELL_ORDER.index(cell["name"]) - 1] == "lubm50.triangles"
+    assert len(cell["why"]) <= 200
     assert files.read_json("workloads", "lubm50.lookups.json") == {"env": {}}
     assert CELLS["lubm5.lookups"]["traffic"] == cell["traffic"]
     assert CELLS["lubm50.triangles"]["config"] == cell["config"]
-    assert [c["name"] for c in BENCH["configs"]][4] == "lubm-50"  # no new one
+    # no configuration came with it: lubm-50's next is ISSUE 40's
+    assert CONFIG_ORDER[CONFIG_ORDER.index("lubm-50") + 1] == "watdiv-100"
     traffic = files.read_json("traffic", "lookups.json")
     assert (traffic["loop"], traffic["clients"], traffic["warmup_cycles"],
             traffic["deadline_ms"]) == ("closed", 1, 5, 900000)
     assert [step["template"] for step in traffic["cycle"]] == [
         "lubm_q1", "lubm_q3", "lubm_q4", "lubm_q7", "lubm_q8"]
-    added = BENCH["per_layer"][74:74 + len(JOIN_SEARCH_METRICS)]
-    assert [m["name"] for m in added] == list(JOIN_SEARCH_METRICS)
+    added = per_layer_run(JOIN_SEARCH_METRICS)
     for m in added:
         assert m == {"name": m["name"], "unit": "count", "better": "lower",
                      "source": "program_counter", "layer": "kernels and XLA ops",
@@ -395,12 +415,13 @@ WATDIV_DIGESTS = {
 }
 
 
-def test_benchmark_json_has_watdiv_100_uncut_and_its_cell_as_the_eighth():
+def test_benchmark_json_has_watdiv_100_uncut_and_its_cell():
     """ISSUE 40: one configuration, one cell of one chip, two per-layer
     entries, all appended; every file new; no standing list took the cell
     in, so it reports ``cycle_ms``, ``setup_s`` and what has no list.
     (ISSUE 42 appended its own behind them.)"""
-    entry, cell = BENCH["configs"][5], BENCH["workloads"][7]
+    entry, cell = CONFIGS["watdiv-100"], CELLS["watdiv100.stars_snowflakes"]
+    assert CELL_ORDER[CELL_ORDER.index(cell["name"]) - 1] == "lubm50.lookups"
     assert (entry["name"], entry["file"], entry["reduced"]) == (
         "watdiv-100", "benchmark/configs/watdiv-100.json", [])
     assert cell == {**cell, "name": "watdiv100.stars_snowflakes", "config": "watdiv-100",
@@ -427,8 +448,7 @@ def test_benchmark_json_has_watdiv_100_uncut_and_its_cell_as_the_eighth():
     need = files.read_json("requires", cell["name"] + ".json")
     assert (need["module"], need["registers"]) == (
         "kolibrie_tpu.query.template", "kolibrie_device_scan_slots_total")
-    added = BENCH["per_layer"][76:76 + len(SCAN_METRICS)]
-    assert [m["name"] for m in added] == list(SCAN_METRICS)
+    added = per_layer_run(SCAN_METRICS)
     for m in added:
         family, better = SCAN_METRICS[m["name"]]
         assert m == {"name": m["name"], "unit": "count", "better": better,
@@ -511,12 +531,12 @@ BSBM_DIGESTS = {
 }
 
 
-def test_benchmark_json_has_bsbm_10m_and_its_cell_as_the_last():
+def test_benchmark_json_has_bsbm_10m_and_its_cell():
     """ISSUE 42: one configuration, one cell of one chip, seven per-layer
     entries that list this cell alone, all appended; every file new; no
     standing list took the cell in, so it reports ``cycle_ms``, ``setup_s``
     and what has no list."""
-    entry, cell = BENCH["configs"][-1], BENCH["workloads"][-1]
+    entry, cell = CONFIGS["bsbm-10m"], CELLS["bsbm10m.bi_counts"]
     assert (entry["name"], entry["file"], entry["reduced"]) == (
         "bsbm-10m", "benchmark/configs/bsbm-10m.json", ["queries", "top_k"])
     assert cell == {**cell, "name": "bsbm10m.bi_counts", "config": "bsbm-10m",
@@ -543,8 +563,7 @@ def test_benchmark_json_has_bsbm_10m_and_its_cell_as_the_last():
     need = files.read_json("requires", cell["name"] + ".json")
     assert (need["module"], need["registers"]) == (
         "kolibrie_tpu.query.template", "kolibrie_device_aggregate_slots_total")
-    added = BENCH["per_layer"][78:78 + len(AGGREGATE_METRICS)]
-    assert [m["name"] for m in added] == list(AGGREGATE_METRICS)
+    added = per_layer_run(AGGREGATE_METRICS)
     for m in added:
         kind, args, unit, better, source, layer = AGGREGATE_METRICS[m["name"]]
         assert m == {"name": m["name"], "unit": unit, "better": better,
@@ -557,7 +576,7 @@ def test_benchmark_json_has_bsbm_10m_and_its_cell_as_the_last():
         if m["name"] not in AGGREGATE_METRICS:
             assert cell["name"] not in m.get("workloads", [])
     # the ninth cell came behind the eight, which stand as they stood
-    assert [w["name"] for w in BENCH["workloads"]][:8] == [
+    assert CELL_ORDER[:CELL_ORDER.index(cell["name"])] == [
         "lubm5.triangles", "lubm5.lookups", "employee100k.upstream", "lubm5.mesh4",
         "lubm5.batch8", "lubm50.triangles", "lubm50.lookups",
         "watdiv100.stars_snowflakes"]
@@ -643,8 +662,8 @@ def test_every_per_layer_metric_has_its_file_and_its_reader():
 
 def test_at_most_half_the_cells_take_four_chips():
     four = [w["name"] for w in BENCH["workloads"] if w["chips"] == 4]
-    assert four == ["lubm5.mesh4"] and len(CELLS) == 9
-    assert len(BENCH["configs"]) == 7
+    assert four == ["lubm5.mesh4"] and len(CELLS) == 10
+    assert len(BENCH["configs"]) == 8
     assert len(four) <= max(1, len(CELLS) // 2)
     assert json.dumps(BENCH).count('"chips": 4') == 1
 
@@ -700,7 +719,7 @@ def test_a_program_without_the_required_metric_is_refused_at_once(
 
 @pytest.mark.parametrize("workload", ["employee100k.upstream", "lubm5.batch8",
                                       "watdiv100.stars_snowflakes",
-                                      "bsbm10m.bi_counts"])
+                                      "bsbm10m.bi_counts", "lubm50.mix8"])
 def test_off_the_chip_run_py_prints_no_result_and_exits_3(tmp_path, workload):
     """A number from a CPU run is never written as a result: without a TPU
     ``benchmark/run.py`` says on standard error what it found, prints
@@ -743,8 +762,9 @@ def test_a_batcher_count_is_a_data_file_that_every_cell_reports(name):
     reads 0 arrivals.  ``tests/test_batcher_dispatch.py`` reads them off the
     program's own registry."""
     args, better = BATCHER_COUNTS[name]
-    added = BENCH["per_layer"][85:88]
-    assert [m["name"] for m in added] == list(BATCHER_COUNTS)
+    added = per_layer_run(BATCHER_COUNTS)
+    names = [m["name"] for m in BENCH["per_layer"]]
+    assert names[names.index(added[0]["name"]) - 1] == "host_aggregates_in_window"
     assert added[sorted(BATCHER_COUNTS).index(name)] == {
         "name": name, "unit": "count", "better": better, "source": "program_counter",
         "layer": "micro-batcher", "moves": "cycle_ms"}
@@ -758,3 +778,153 @@ def test_a_batcher_count_is_a_data_file_that_every_cell_reports(name):
     if "beside" in args:
         handoff = 'metrics.kolibrie_batcher_dispatch_start_total{at="handoff"}'
         assert reader.read({key: {handoff: 4.0} for key in there}, **args) == 0.0
+
+
+# ---- ISSUE 47: LUBM(50) asked by eight workers running the lookup mix
+
+MIX8_METRICS = {
+    # name: (reader arguments, layer, source, unit, the cells it lists)
+    "batcher_templates_in_window": (
+        {"kind": "counter_delta",
+         "prefix": "metrics.kolibrie_batcher_dispatch_templates_total"},
+        "micro-batcher", "program_counter", "count", None),
+    "batcher_group_programs_in_window": (
+        {"kind": "counter_delta",
+         "prefix": 'metrics.kolibrie_batcher_dispatch_programs_total{kind="group"}',
+         "beside": "metrics.kolibrie_batcher_dispatch_programs_total"},
+        "micro-batcher", "program_counter", "count", None),
+    "batcher_solo_programs_in_window": (
+        {"kind": "counter_delta",
+         "prefix": 'metrics.kolibrie_batcher_dispatch_programs_total{kind="solo"}',
+         "beside": "metrics.kolibrie_batcher_dispatch_programs_total"},
+        "micro-batcher", "program_counter", "count", None),
+    "solo_tail_ms": (
+        {"kind": "span_total", "spans": ["executor.solo_tail"]},
+        "one-chip batch", "program_span", "ms", ["lubm50.mix8"]),
+}
+
+
+def test_benchmark_json_has_lubm_50_asked_by_eight_workers_and_its_cell():
+    """One configuration, one traffic mix, one cell of one chip and four
+    per-layer entries, all appended; every file new; no standing list took
+    the cell in, so it reports ``cycle_ms``, ``setup_s`` and what has no
+    list, and ``solo_tail_ms``, which was born with it."""
+    entry, cell = CONFIGS["lubm-50-clients8"], CELLS["lubm50.mix8"]
+    assert CONFIG_ORDER[CONFIG_ORDER.index(entry["name"]) - 1] == "bsbm-10m"
+    assert CELL_ORDER[CELL_ORDER.index(cell["name"]) - 1] == "bsbm10m.bi_counts"
+    assert (entry["file"], entry["reduced"]) == (
+        "benchmark/configs/lubm-50-clients8.json", [])
+    assert cell == {**cell, "config": "lubm-50-clients8",
+                    "traffic": "lookups_clients8", "chips": 1}
+    assert set(cell) == {"name", "config", "traffic", "chips", "why"}
+    assert max(len(entry["source"]), len(entry["why"]), len(cell["why"])) <= 200
+    for words in ("LUBM(50", "Q1, Q3, Q4, Q7, Q8", "BSBM", "4, 8, 64"):
+        assert words in entry["source"]
+    assert len({c["source"] for c in BENCH["configs"]}) == len(BENCH["configs"])
+    config = files.read_json("configs", "lubm-50-clients8.json")
+    one_client = files.read_json("configs", "lubm-50.json")
+    small = files.read_json("configs", "lubm-5-clients8.json")
+    assert config["source"] == entry["source"]
+    assert (config["universities"], config["reduced"], config["chips"],
+            config["store_mode"]) == (50, {}, 1, "device")
+    assert "layout" not in config  # one chip holds the whole store
+    # lubm-50's deployment asked by 8 clients: the same data, control and
+    # assumptions, its guarantees and the one a dispatch must not break
+    for key in ("generator", "universities", "control", "reduced"):
+        assert config[key] == one_client[key], key
+    assert config["guarantees"].items() >= one_client["guarantees"].items()
+    assert set(config["guarantees"]) == set(small["guarantees"])
+    served = config["guarantees"]["served_in_a_group"]
+    assert "its own text" in served and "other templates' groups" in served
+    assert config["assumed"][:-1] == one_client["assumed"]
+    assert "BSBM's" in config["assumed"][-1]
+    assert files.read_json("workloads", "lubm50.mix8.json") == {"env": {}}
+    assert not os.path.exists(files.path("requires", "lubm50.mix8.json"))
+    # the traffic: lookups' cycle, letter for letter, sent by eight
+    traffic = files.read_json("traffic", "lookups_clients8.json")
+    assert traffic["cycle"] == files.read_json("traffic", "lookups.json")["cycle"]
+    assert {k: v for k, v in traffic.items() if k != "cycle"} == {
+        "loop": "closed", "clients": 8, "deadline_ms": 900000,
+        "warmup_ramp": [1, 2, 4, 8], "warmup_cycles": 2, "trace_min_seconds": 10}
+    # its per-layer entries: data files of readers that were there
+    added = per_layer_run(MIX8_METRICS)
+    names = [m["name"] for m in BENCH["per_layer"]]
+    assert names[names.index(added[0]["name"]) - 1] == "batcher_requests_in_window"
+    for m in added:
+        args, layer, source, unit, cells = MIX8_METRICS[m["name"]]
+        want = {"name": m["name"], "unit": unit, "better": "lower",
+                "source": source, "layer": layer, "moves": "cycle_ms"}
+        assert m == (want if cells is None else {**want, "workloads": cells})
+        assert files.read_json("layer_metrics", m["name"] + ".json") == {
+            "reader": args}
+        assert os.path.exists(files.path("readers", args["kind"] + ".py"))
+    for m in BENCH["per_layer"] + BENCH["end_to_end"]:
+        if m["name"] != "solo_tail_ms":
+            assert cell["name"] not in m.get("workloads", [])
+
+
+@pytest.mark.parametrize("clients", [1, 2, 8])
+def test_no_two_clients_of_the_mix_ever_draw_one_constant(clients, monkeypatch):
+    """At eight universities, the fewest that give each of eight clients a
+    university of its own (the cell has 50): no two clients ever send one
+    text, in any step, however far they drift apart."""
+    config = files.read_json("configs", "lubm-50-clients8.json")
+    domains = files.load_module("generators", config["generator"]).generate(
+        config, 2**31 + 47, 8)["domains"]
+    assert len(domains["university"]) == 8 and len(domains["department"]) >= 8 * 15
+    spec = files.read_json("traffic", "lookups_clients8.json")
+    monkeypatch.setattr(
+        files, "read_json",
+        lambda *parts: dict(spec, clients=clients, warmup_ramp=[clients]))
+    traffic = Traffic("lookups_clients8", domains, 2**31 + 47)
+    assert traffic.clients == clients
+    for stream in ("window", "warmup"):
+        for step, (_template, _text, constants) in enumerate(traffic.steps):
+            (rule,) = constants.values()
+            domain = domains[rule["draw"]]
+            own = [{traffic.cycle(k, stream, c)[step][1]
+                    for k in range(2 * len(domain) // clients + 2)}
+                   for c in range(clients)]
+            assert sum(len(o) for o in own) == len(set().union(*own)) == len(domain)
+
+
+@pytest.mark.parametrize("name", sorted(MIX8_METRICS))
+def test_a_dispatch_composition_metric_reads_its_family_and_nothing_of_a_program_without_it(name):
+    """The readers run on the parent's checkout too: a program without the
+    families (or the span) reports nothing and nothing raises; both kinds of
+    program are registered at import, so each has a line from the start and
+    one that never grew reads 0."""
+    from kolibrie_tpu.frontends import http_server  # noqa: F401  (registers them)
+    from kolibrie_tpu.obs import export, metrics
+
+    args = dict(MIX8_METRICS[name][0])
+    reader = files.load_module("readers", args.pop("kind"))
+    with open(os.path.join(REPO, "docs", "OBSERVABILITY.md"), encoding="utf-8") as f:
+        catalog = f.read()
+    if "spans" in args:
+        assert "`executor.solo_tail`" in catalog
+        span = {"name": "executor.solo_tail", "dur_ms": 30.5, "span_id": "a",
+                "parent_id": ""}
+        other = {"name": "executor.batch", "dur_ms": 2.0, "span_id": "b",
+                 "parent_id": ""}
+        ctx = {"cycles": [{"trace_ids": ["t0", "t1"]}],
+               "spans_by_trace": {"t0": [span, other], "t1": [span]}}
+        assert reader.read(ctx, **args) == pytest.approx(61.0)
+        ctx["spans_by_trace"] = {"t0": [other], "t1": [other]}
+        assert reader.read(ctx, **args) is None  # the parent opens no such span
+        return
+    family = args["prefix"][len("metrics."):].partition("{")[0]
+    assert metrics.REGISTRY.get(family) is not None and f"`{family}`" in catalog
+    assert args["prefix"][len("metrics."):] + " " in export.render_prometheus()
+    there = {"counters0": {args["prefix"]: 40.0, "metrics.kolibrie_other_total": 1.0},
+             "counters1": {args["prefix"]: 100.0, "metrics.kolibrie_other_total": 3.0}}
+    assert reader.read(there, **args) == pytest.approx(60.0)
+    # the parent has the batcher's older counters and neither new family
+    lacking = {key: {"metrics.kolibrie_batcher_dispatches_total": 9.0,
+                     'metrics.kolibrie_batcher_dispatch_start_total{at="arrival"}': 9.0}
+               for key in there}
+    assert reader.read(lacking, **args) is None
+    if "beside" in args:
+        other_kind = args["beside"] + (
+            '{kind="solo"}' if "group" in name else '{kind="group"}')
+        assert reader.read({key: {other_kind: 4.0} for key in there}, **args) == 0.0
