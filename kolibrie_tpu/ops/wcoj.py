@@ -32,6 +32,11 @@ This module holds the shared primitives:
 - :func:`range_search_form` / :func:`range_search` — the rule that picks
   the form of one call from its static shapes ``(N, P, ncols)``, and the
   call a WCOJ level makes (``optimizer/device_engine.py`` ``eval_level``);
+- :func:`key_window` — the rows of the order that a search's constant
+  leading keys select, as one sorted slice: an accessor that names a
+  predicate searches those W rows (its hottest key's, a template property:
+  ``WcojAccessor.window``), so N above is W for it, and the sort's
+  elements W + 2P, not the padded order's N + 2P;
 - :func:`host_lex_range` — the numpy twin returning ``[lo, hi)`` ranges,
   exact for 3-key probes via a dense-rank packing (u64 cannot hold three
   u32 keys directly);
@@ -57,6 +62,7 @@ __all__ = [
     "lex_range",
     "lex_range_sorted",
     "range_search_form",
+    "key_window",
     "range_search",
     "host_lex_range",
     "host_lex_probe",
@@ -228,13 +234,21 @@ def lex_range_sorted(cols, keys):
 # a sorted search costs the TPU compiler two more sort instructions (27-67 s
 # a search alone, where a loop compiles in under a second).  8,192 is the
 # fewest probes the gate saw a sort win at.
+# Since PR 48 N is the rows searched: the window of the order that the
+# accessor's constant keys select (key_window), where it has one, so a
+# search sits further inside the rule than it did (LUBM(50)'s sorted
+# searches at N = 0.125-8 P where they were at 8-32 P; LUBM(5)'s Q2 takes
+# its third level's three, 8,192 probes over windows of 16,384-65,536 rows,
+# to the sort).  The constants stand as PR 35's gate set them: PR 48's
+# gate at window-sized shapes is in PERF.md section 6 and docs/JOINS.md.
 _SORT_MIN_PROBES = 8192
 _SORT_ROWS_PER_PROBE = 64
 
 
 def range_search_form(n: int, p: int, ncols: int) -> str:
     """Which form one range search takes: ``"sorted"`` or ``"loop"``, a pure
-    function of the call's static shapes (N base rows, P probe tuples,
+    function of the call's static shapes (N rows searched: the accessor's
+    window where it has one, else the padded segment; P probe tuples;
     ``ncols`` key columns), so one plan may take both and a template still
     has one executable a capacity set.
 
@@ -248,11 +262,50 @@ def range_search_form(n: int, p: int, ncols: int) -> str:
     return "loop"
 
 
-def range_search(cols, keys):
+def key_window(cols, consts, rows: int):
+    """Where the rows that lead with ``consts`` lie in the sorted columns:
+    ``(start, window)``, ``window`` the ``rows``-long slice of every column
+    that begins at ``start``, one ``dynamic_slice`` each, so still sorted.
+
+    ``consts`` are scalars, one for each of the first ``len(consts)``
+    columns; the rows equal to them are contiguous, and ``start`` is where
+    they begin (the count of rows below the constants: one pass over the
+    leading columns, no loop), pulled back to ``N - rows`` where the slice
+    would run past the padded end.  Rows of the slice before or after the
+    constants' own are the neighbouring keys', which sort before and after
+    every tuple that leads with the constants; so a search of the slice for
+    such a tuple, plus ``start``, is the search of the whole columns, bit
+    for bit, as long as the constants' rows are no more than ``rows``: the
+    caller's business (``device_engine`` sizes ``rows`` by the hottest key
+    of the frozen base the columns were cut from)."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    n = int(cols[0].shape[0])
+    below = jnp.zeros(n, dtype=bool)
+    eq = jnp.ones(n, dtype=bool)
+    for c, k in zip(cols, consts):
+        below = below | (eq & (c < k))
+        eq = eq & (c == k)
+    start = jnp.minimum(jnp.sum(below, dtype=jnp.int32), jnp.int32(n - rows))
+    return start, tuple(lax.dynamic_slice(c, (start,), (rows,)) for c in cols)
+
+
+def range_search(cols, keys, lead=(), rows: int = 0):
     """``(lo, hi)`` of each probe tuple in the sorted columns, in the form
-    :func:`range_search_form` picks for this call's shapes."""
+    :func:`range_search_form` picks for this call's shapes.
+
+    Where the first ``len(lead)`` keys of every probe tuple are the scalars
+    ``lead`` and ``rows`` is given and under the columns' length, the search
+    runs over the :func:`key_window` of that many rows and its positions
+    are moved back by the window's start: the same arrays, from ``rows``
+    base rows where the whole columns have N."""
     n = int(cols[0].shape[0])
     p = int(keys[0].shape[0])
+    if lead and 0 < rows < n:
+        start, cols = key_window(cols, lead, rows)
+        lo, hi = range_search(cols, keys)
+        return lo + start, hi + start
     if range_search_form(n, p, len(cols)) == "sorted":
         return lex_range_sorted(cols, keys)
     return lex_range(cols, keys)

@@ -41,7 +41,7 @@ and cached per plan shape on the database.
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from functools import partial
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -237,12 +237,41 @@ class WcojAccessor:
     ``('u', param_idx)`` reads the traced uint32 parameter vector (query
     constants, incl. the never-an-ID sentinel for unknown terms),
     ``('v', var)`` reads an already-eliminated variable's column.
-    ``key_pos``/``val_pos`` are canonical column positions (0=s 1=p 2=o)."""
+    ``key_pos``/``val_pos`` are canonical column positions (0=s 1=p 2=o).
+
+    ``window``: the base rows a range search of this accessor runs over,
+    where its leading keys are constants (:attr:`lead`): the hottest group
+    of those keys in the frozen base, on the capacity ladder
+    (``LoweredPlan._template_window_caps``; a template property, as
+    ``ScanSpec.cap`` is).  0: no constant leads, or its rows are the order's
+    padded length anyway, and the searches run over the whole order."""
 
     order_idx: int
     key_srcs: tuple
     key_pos: tuple
     val_pos: int
+    window: int = 0
+
+    @property
+    def lead(self) -> int:
+        """How many of the keys, from the first, are constants of the text:
+        the same value for every probe tuple."""
+        n = 0
+        for src in self.key_srcs:
+            if src[0] != "u":
+                break
+            n += 1
+        return n
+
+    @property
+    def lead_predicate(self) -> Optional[int]:
+        """The parameter index of the predicate among the leading constants
+        (the key at canonical position 1), ``None`` where none names one:
+        what the window's width is read by."""
+        for src, pos in zip(self.key_srcs[: self.lead], self.key_pos):
+            if pos == 1:
+                return src[1]
+        return None
 
 
 @dataclass(frozen=True)
@@ -966,6 +995,11 @@ def _plan_body(
             SENT = jnp.uint32(0xFFFFFFFF)
             wcols: Dict = {}
             wvalid = jnp.ones(1, dtype=bool)
+            def lead_of(a):
+                # the constants that lead the accessor's keys: what its
+                # window of the order is found by (ops/wcoj.py key_window)
+                return tuple(uparams[src[1]] for src in a.key_srcs[: a.lead])
+
             def eval_level(lv, wcols, wvalid):
                 with jax.named_scope("probe"):
                     pcap = wvalid.shape[0]
@@ -989,8 +1023,9 @@ def _plan_body(
                             # by a gather loop or by one sort as the shapes
                             # say (ops/wcoj.py range_search_form; the same
                             # int32 arrays either way, shared by the XLA and
-                            # Pallas paths)
-                            bl, bh = range_search(bsort, kt)
+                            # Pallas paths), over the rows its constant keys
+                            # select where the accessor has a window
+                            bl, bh = range_search(bsort, kt, lead_of(a), a.window)
                             dl, dh = delta_or_zeros(
                                 a.order_idx,
                                 (bl, bh),
@@ -1104,7 +1139,7 @@ def _plan_body(
                         dsf = tuple(dcols[p] for p in a.key_pos) + (
                             dcols[a.val_pos],
                         )
-                        fl, fh = range_search(bsf, fkeys)
+                        fl, fh = range_search(bsf, fkeys, lead_of(a), a.window)
 
                         def delta_live(
                             dsf=dsf, fkeys=fkeys, del_pos=del_pos, fl=fl, fh=fh
@@ -1505,6 +1540,17 @@ class LoweredPlan:
                 for name, consts in self.scan_descs
             ),
         )
+        named = tuple(
+            self.u_params[a.lead_predicate]
+            for node in _spec_nodes(self.root, WcojSpec)
+            for lv in node.levels
+            for a in lv.accessors
+            if a.lead_predicate is not None
+        )
+        if named:
+            # the predicates that lead a WCOJ accessor's keys size its
+            # window (_template_window_caps) as a scan's sizes the scan
+            self.cap_key += (named,)
         # pre-actuals worthiness signal for the MQO layer: the planner's
         # leaf-scan cardinality bound (optimizer/mqo.py, docs/MQO.md)
         from kolibrie_tpu.optimizer.planner import estimated_prefix_rows
@@ -1601,12 +1647,7 @@ class LoweredPlan:
                             lv.join_idx,
                             lv.cap,
                             tuple(
-                                WcojAccessor(
-                                    remap[a.order_idx],
-                                    a.key_srcs,
-                                    a.key_pos,
-                                    a.val_pos,
-                                )
+                                replace(a, order_idx=remap[a.order_idx])
                                 for a in lv.accessors
                             ),
                         )
@@ -1688,19 +1729,26 @@ class LoweredPlan:
     }
 
     @staticmethod
-    def _order_for(bound: frozenset, sorted_pos: int) -> Optional[str]:
-        """Sort order whose prefix matches the bound positions AND whose next
-        column is ``sorted_pos`` — i.e. a range scan from it presents that
-        column sorted (enabling the sort-free merge join)."""
+    def _orders_for(bound: frozenset, sorted_pos: int) -> List[str]:
+        """Sort orders whose prefix matches the bound positions AND whose next
+        column is ``sorted_pos`` — i.e. a range scan from one presents that
+        column sorted (enabling the sort-free merge join) — in the store's
+        order of orders."""
         from kolibrie_tpu.core.store import ColumnarTripleStore
 
         pos_of = {"s": 0, "p": 1, "o": 2}
         k = len(bound)
-        for name, perm in ColumnarTripleStore._ORDER_PERMS.items():
-            idxs = [pos_of[c] for c in perm]
-            if frozenset(idxs[:k]) == bound and idxs[k] == sorted_pos:
-                return name
-        return None
+        return [
+            name
+            for name, perm in ColumnarTripleStore._ORDER_PERMS.items()
+            if frozenset(pos_of[c] for c in perm[:k]) == bound
+            and pos_of[perm[k]] == sorted_pos
+        ]
+
+    @classmethod
+    def _order_for(cls, bound: frozenset, sorted_pos: int) -> Optional[str]:
+        """The first of :meth:`_orders_for`, ``None`` where there is none."""
+        return next(iter(cls._orders_for(bound, sorted_pos)), None)
 
     @staticmethod
     def _merge_key_pos(order_name: str, n_bound: int) -> tuple:
@@ -1817,9 +1865,18 @@ class LoweredPlan:
                     for i, s in enumerate(row)
                     if s[0] == "u" or (s[0] == "v" and s[1] in eliminated)
                 )
-                order_name = self._order_for(bound, val_pos)
-                if order_name is None:  # can't happen for |bound| <= 2
+                covering = self._orders_for(bound, val_pos)
+                if not covering:  # can't happen for |bound| <= 2
                     raise Unsupported("no covering order for WCOJ accessor")
+
+                def constants_first(name):
+                    # the text's constants before the eliminated variables:
+                    # the rows a search can match are then one window of
+                    # the order (WcojAccessor.window)
+                    perm = ColumnarTripleStore._ORDER_PERMS[name]
+                    return [row[pos_of[c]][0] != "u" for c in perm[: len(bound)]]
+
+                order_name = min(covering, key=constants_first)
                 perm = ColumnarTripleStore._ORDER_PERMS[order_name]
                 key_pos = tuple(pos_of[c] for c in perm[: len(bound)])
                 accessors.append(
@@ -2210,7 +2267,10 @@ class LoweredPlan:
                         lv.var,
                         lv.join_idx,
                         join_caps[lv.join_idx],
-                        lv.accessors,
+                        tuple(
+                            replace(a, window=self._window_caps[lv.join_idx, i])
+                            for i, a in enumerate(lv.accessors)
+                        ),
                     )
                     for lv in node.levels
                 )
@@ -2546,6 +2606,38 @@ class LoweredPlan:
             for i, (name, consts) in enumerate(self.scan_descs)
         }
 
+    def _template_window_caps(self) -> Dict[Tuple[int, int], int]:
+        """By ``(level's join_idx, accessor's place in it)``, the base rows
+        that accessor's range searches run over (``WcojAccessor.window``): a
+        TEMPLATE property like a scan's capacity and read from the same
+        table, the most rows any instance of the text has under the
+        accessor's leading constants in the frozen base
+        (:func:`base_key_group_rows`), on the capacity ladder.  0 where no
+        constant leads or the ladder's step is the order's padded length:
+        the whole order is searched.  Computed each build, so it follows
+        ``base_version`` as ``ScanSpec.cap`` does."""
+        store = self.db.store
+        out: Dict[Tuple[int, int], int] = {}
+        for node in _spec_nodes(self.root, WcojSpec):
+            for lv in node.levels:
+                for i, a in enumerate(lv.accessors):
+                    out[lv.join_idx, i] = 0
+                    if not a.lead:
+                        continue
+                    name = self.order_names[a.order_idx]
+                    named = a.lead_predicate
+                    rows = _round_cap(
+                        base_key_group_rows(
+                            self.db,
+                            name,
+                            a.lead,
+                            None if named is None else self.u_params[named],
+                        )
+                    )
+                    if rows < _round_cap(len(store.base_order(name))):
+                        out[lv.join_idx, i] = rows
+        return out
+
     def build(self, tag: int = 0, operands: bool = True) -> Tuple[PlanSpec, tuple]:
         """Assemble (spec, array_args) for the current store/capacities;
         without ``operands`` the spec alone, ``(spec, None)``, and nothing
@@ -2554,6 +2646,7 @@ class LoweredPlan:
         self._refresh_masks()
         scan_ranges = self._scan_ranges()
         scan_caps = self._template_scan_caps()
+        self._window_caps = self._template_window_caps()
         self._calibrated_groups = None
         join_caps = self._initial_join_caps(scan_caps)
         self._scan_ranges_np = scan_ranges
@@ -3088,32 +3181,50 @@ class LoweredPlan:
             members * base_only, members * (len(self._tier_sites) - base_only)
         )
 
-    def _note_range_searches(self, members: int = 1) -> None:
-        """Count the WCOJ range searches of the dispatch just assembled by
-        the form the plan body traced them in: an accessor searches its
-        order's base once in ``probe`` (the level before's capacity wide,
-        where it has keys) and once in ``live`` (this level's), and its
-        delta as often where the tier holds something."""
-        from kolibrie_tpu.ops.wcoj import range_search_form
-        from kolibrie_tpu.query.template import note_range_searches
-
-        forms = {"sorted": 0, "loop": 0}
+    def _range_searches(self):
+        """The WCOJ range searches of the dispatch just assembled, as the
+        plan body traced them: ``(rows, probes, ncols, extent)`` each.  An
+        accessor searches its order's base once in ``probe`` (the level
+        before's capacity wide, where it has keys) and once in ``live``
+        (this level's), over its window where it has one
+        (``WcojAccessor.window``: extent ``"window"``, else ``"order"``),
+        and its delta (``"delta"``), whole, as often where the tier holds
+        something."""
         for node in _spec_nodes(self.root, WcojSpec):
             pcap = 1
             for lv in node.levels:
                 cap = self._join_caps[lv.join_idx]
-                for a in lv.accessors:
+                for i, a in enumerate(lv.accessors):
                     nkeys = len(a.key_srcs)
                     base, delta = self._seg_rows[a.order_idx]
-                    tiers = (base,) + (
-                        (delta,) if self._tiers_np[a.order_idx] else ()
-                    )
-                    for n in tiers:
+                    window = self._window_caps[lv.join_idx, i]
+                    tiers = [(window or base, "window" if window else "order")]
+                    if self._tiers_np[a.order_idx]:
+                        tiers.append((delta, "delta"))
+                    for n, extent in tiers:
                         if nkeys:
-                            forms[range_search_form(n, pcap, nkeys)] += 1
-                        forms[range_search_form(n, cap, nkeys + 1)] += 1
+                            yield n, pcap, nkeys, extent
+                        yield n, cap, nkeys + 1, extent
                 pcap = cap
+
+    def _note_range_searches(self, members: int = 1) -> None:
+        """Count :meth:`_range_searches` by the form the rule gives each
+        (:func:`range_search_form` reads the rows searched: the window's),
+        and the base rows they ran over by whether a window was taken (the
+        delta's searches have one width, the tier's, and are left out)."""
+        from kolibrie_tpu.ops.wcoj import range_search_form
+        from kolibrie_tpu.query.template import (
+            note_range_search_rows,
+            note_range_searches,
+        )
+
+        forms = {"sorted": 0, "loop": 0}
+        rows = {"window": 0, "order": 0, "delta": 0}
+        for n, p, ncols, extent in self._range_searches():
+            forms[range_search_form(n, p, ncols)] += 1
+            rows[extent] += n
         note_range_searches(members * forms["sorted"], members * forms["loop"])
+        note_range_search_rows(members * rows["window"], members * rows["order"])
 
     def _note_join_searches(self, members) -> None:
         """Count the run-bound searches of the dispatch just read back: for
@@ -3500,10 +3611,14 @@ class LoweredPlan:
                         else ""
                     )
                     cap = jcaps[lv.join_idx] if jcaps else "?"
+                    wcaps = getattr(self, "_window_caps", {})
                     accs = ", ".join(
                         f"{self.order_names[a.order_idx]}"
                         f"/k{len(a.key_srcs)}"
-                        for a in lv.accessors
+                        # the base rows its searches run over, where a
+                        # constant selects a window of the order
+                        + (f"/w{w}" if (w := wcaps.get((lv.join_idx, i))) else "")
+                        for i, a in enumerate(lv.accessors)
                     )
                     act = ""
                     ck = f"wcoj{lv.join_idx}:cand"
@@ -3698,38 +3813,29 @@ def numeric_filter_mask(vals: np.ndarray, op: str, const: float) -> np.ndarray:
     return m & ~np.isnan(vals)
 
 
-def template_scan_cap(
+def base_key_group_rows(
     db, order_name: str, n_bound: int, predicate: Optional[int] = None
 ) -> int:
-    """Upper bound on ANY constant-variant's merged (base + delta) range
-    for a scan whose ``order_name`` prefix binds ``n_bound`` columns: the
-    largest key-group of that prefix in the FROZEN base segment plus the
-    fixed delta device capacity.  Where the scan names its ``predicate``
-    (a dictionary id, -1 for a term the dictionary does not know) beside
-    at most one of subject and object, the group is the largest AMONG THE
-    ROWS UNDER THAT PREDICATE (:func:`stats.hottest_key_rows`, the table
-    the planner orders keyed scans by): the predicate's own base rows, or
-    those of its hottest subject or object.  Every instance of a text names
-    the same predicates, so ``ScanSpec.cap`` stays a property of the
-    TEMPLATE rather than of one variant's other constants (shape-stable
-    compilation), and a scan is as wide as the predicate it names, not as
-    the store's largest.  Without a predicate (a variable one, a WCOJ
-    accessor) the group is the largest over the whole store.  Because the
-    base is frozen at ``base_version`` and the delta tier holds at most
-    ``delta_device_cap`` rows in all, the bound survives every incremental
-    mutation batch.  O(base) to compute, cached per ``base_version`` on the
+    """The most rows any one key of ``order_name``'s first ``n_bound``
+    columns holds in the FROZEN base segment: what bounds a scan's base
+    range (:func:`template_scan_cap`) and a WCOJ accessor's window
+    (``LoweredPlan._template_window_caps``) for ANY constant variant.
+    Where ``predicate`` is named (a dictionary id; one the dictionary does
+    not know holds 0 rows) beside at most one of subject and object, the
+    group is the largest AMONG THE ROWS UNDER THAT PREDICATE
+    (:func:`stats.hottest_key_rows`, the table the planner orders keyed
+    scans by): the predicate's own base rows, or those of its hottest
+    subject or object.  Without one, the largest group over the whole
+    store: O(base) to compute, cached per ``base_version`` on the
     database."""
     store = db.store
-    dcap = store.delta_device_cap
     base = store.base_order(order_name)
     nb = len(base)
-    if nb == 0:
-        return dcap
-    if n_bound <= 0:
-        return nb + dcap
+    if nb == 0 or n_bound <= 0:
+        return nb
     if predicate is not None:
         other = [c for c in base.perm[:n_bound] if c != "p"]
-        return hottest_key_rows(db, predicate, other[0] if other else "p") + dcap
+        return hottest_key_rows(db, predicate, other[0] if other else "p")
 
     def count() -> int:
         rows = base.slice_rows(0, nb)
@@ -3741,8 +3847,29 @@ def template_scan_cap(
         bounds = np.append(np.flatnonzero(change), nb)
         return int(np.max(np.diff(bounds)))
 
-    return dcap + _caps.of(db).largest_key_group(
+    return _caps.of(db).largest_key_group(
         order_name, n_bound, store.base_version, count
+    )
+
+
+def template_scan_cap(
+    db, order_name: str, n_bound: int, predicate: Optional[int] = None
+) -> int:
+    """Upper bound on ANY constant-variant's merged (base + delta) range
+    for a scan whose ``order_name`` prefix binds ``n_bound`` columns: the
+    largest key-group of that prefix in the FROZEN base segment
+    (:func:`base_key_group_rows`: among the rows under the ``predicate``
+    the scan names, where it names one) plus the fixed delta device
+    capacity.  Every instance of a text names the same predicates, so
+    ``ScanSpec.cap`` stays a property of the TEMPLATE rather than of one
+    variant's other constants (shape-stable compilation), and a scan is as
+    wide as the predicate it names, not as the store's largest.  Because
+    the base is frozen at ``base_version`` and the delta tier holds at most
+    ``delta_device_cap`` rows in all, the bound survives every incremental
+    mutation batch."""
+    return (
+        base_key_group_rows(db, order_name, n_bound, predicate)
+        + db.store.delta_device_cap
     )
 
 

@@ -59,6 +59,7 @@ __all__ = [
     "note_aggregate_tier",
     "note_scan_tiers",
     "note_range_searches",
+    "note_range_search_rows",
     "occupancy_pct",
 ]
 
@@ -455,6 +456,28 @@ def note_range_searches(sorted_: int, loop: int) -> None:
     """One dispatch made ``sorted_`` + ``loop`` WCOJ range searches."""
     _RANGE_SEARCH.labels("sorted").inc(sorted_)
     _RANGE_SEARCH.labels("loop").inc(loop)
+
+
+# the base rows those searches ran over: an accessor whose leading keys are
+# constants of the text searches the window of its order that they select
+# (device_engine WcojAccessor.window), any other the whole padded order
+_RANGE_SEARCH_ROWS = metrics.counter(
+    "kolibrie_wcoj_range_search_rows_total",
+    "base rows the range searches of WCOJ levels ran over, summed over "
+    "dispatches, by whether the search took the window of the order that "
+    "the accessor's leading constants select (window) or the whole padded "
+    "order (order)",
+    labels=("extent",),
+)
+_RANGE_SEARCH_ROWS.labels("window")
+_RANGE_SEARCH_ROWS.labels("order")
+
+
+def note_range_search_rows(window: int, order: int) -> None:
+    """One dispatch's WCOJ range searches ran over ``window`` rows of
+    windows and ``order`` rows of whole padded orders."""
+    _RANGE_SEARCH_ROWS.labels("window").inc(window)
+    _RANGE_SEARCH_ROWS.labels("order").inc(order)
 
 
 # what a merge join's run-bound searches were spared: per dispatch and join

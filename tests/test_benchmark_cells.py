@@ -230,7 +230,7 @@ def test_benchmark_json_has_lubm_50_uncut_and_its_cell():
         assert os.path.exists(files.path("readers", kind + ".py"))
     # it is added to no list that was there; it reports what has no list
     for m in BENCH["per_layer"] + BENCH["end_to_end"]:
-        if m["name"] not in LUBM50_METRICS and m["name"] not in RANGE_SEARCH_METRICS:
+        if m["name"] not in {*LUBM50_METRICS, *RANGE_SEARCH_METRICS, *SEARCH_ROWS_METRICS}:
             assert "lubm50.triangles" not in m.get("workloads", [])
         if m["name"] not in JOIN_SEARCH_METRICS and m["name"] not in SCAN_METRICS:
             assert "lubm50.lookups" not in m.get("workloads", [])
@@ -928,3 +928,57 @@ def test_a_dispatch_composition_metric_reads_its_family_and_nothing_of_a_program
         other_kind = args["beside"] + (
             '{kind="solo"}' if "group" in name else '{kind="group"}')
         assert reader.read({key: {other_kind: 4.0} for key in there}, **args) == 0.0
+
+
+SEARCH_ROWS_FAMILY = "metrics.kolibrie_wcoj_range_search_rows_total"
+SEARCH_ROWS_METRICS = {
+    # name: the reader's arguments (ISSUE 48)
+    "wcoj_search_rows_in_window": {"prefix": SEARCH_ROWS_FAMILY},
+    "wcoj_order_wide_rows_in_window": {
+        "prefix": SEARCH_ROWS_FAMILY + '{extent="order"}', "beside": SEARCH_ROWS_FAMILY},
+}
+
+
+def test_the_search_rows_metrics_are_data_alone_for_the_triangles_cells():
+    """ISSUE 48: two per-layer entries, appended behind ISSUE 47's last, for
+    the two triangles cells; each a data file of ``counter_delta``; no cell,
+    no configuration and no reader came with them."""
+    added = per_layer_run(SEARCH_ROWS_METRICS)
+    assert [m["name"] for m in BENCH["per_layer"][-2:]] == list(SEARCH_ROWS_METRICS)
+    assert BENCH["per_layer"][-3]["name"] == "solo_tail_ms"
+    for m in added:
+        assert m == {"name": m["name"], "unit": "rows", "better": "lower",
+                     "source": "program_counter", "layer": "device dispatch",
+                     "moves": "cycle_ms", "workloads": TRIANGLES_CELLS}
+        reader = files.read_json("layer_metrics", m["name"] + ".json")["reader"]
+        assert reader == {"kind": "counter_delta", **SEARCH_ROWS_METRICS[m["name"]]}
+    assert CELL_ORDER[-1] == "lubm50.mix8" and CONFIG_ORDER[-1] == "lubm-50-clients8"
+
+
+@pytest.mark.parametrize("name", sorted(SEARCH_ROWS_METRICS))
+def test_a_search_rows_metric_reads_its_extents_and_nothing_of_a_program_without_them(name):
+    """The readers run on the parent's checkout too, which has the searches'
+    counter by form and none of their rows: it reports neither metric and
+    nothing raises.  ``wcoj_search_rows_in_window`` sums both extents;
+    ``wcoj_order_wide_rows_in_window`` reads the whole orders' alone, 0 where
+    only windows were searched (a label without growth has no line)."""
+    from kolibrie_tpu.obs import metrics
+    from kolibrie_tpu.query import template  # noqa: F401  (registers the family)
+
+    args = SEARCH_ROWS_METRICS[name]
+    reader = files.load_module("readers", "counter_delta")
+    family = SEARCH_ROWS_FAMILY[len("metrics."):]
+    assert metrics.REGISTRY.get(family) is not None
+    with open(os.path.join(REPO, "docs", "OBSERVABILITY.md"), encoding="utf-8") as f:
+        assert f"`{family}`" in f.read()
+    window, order = (SEARCH_ROWS_FAMILY + '{extent="%s"}' % e for e in ("window", "order"))
+    both = {"counters0": {window: 100.0, order: 7.0},
+            "counters1": {window: 2_476_100.0, order: 1_048_583.0}}
+    assert reader.read(both, **args) == pytest.approx(
+        1_048_576.0 if "order" in name else 3_524_576.0)
+    windows_only = {"counters0": {window: 100.0}, "counters1": {window: 2_476_100.0}}
+    assert reader.read(windows_only, **args) == pytest.approx(
+        0.0 if "order" in name else 2_476_000.0)
+    parent = {key: {'metrics.kolibrie_wcoj_range_search_total{form="sorted"}': 15.0}
+              for key in both}
+    assert reader.read(parent, **args) is None

@@ -231,7 +231,13 @@ def test_whole_plan_lubm_q9(one_chip, lubm_db):
     # the third level is 8,192 wide over 16,384-row orders, so its ``live``
     # range searches take the sorted form (``ops/wcoj.py`` ``range_search_form``):
     # the chip's compiler has seen both forms in one plan
-    assert low._join_caps[-1] >= 8192 and " sort(" in compiled.as_text()
+    text = compiled.as_text()
+    assert low._join_caps[-1] >= 8192 and " sort(" in text
+    # every accessor names a predicate, so every search runs over the window
+    # its constants select (ISSUE 48): the slices are in the program
+    windows = [a.window for lv in spec.root.levels for a in lv.accessors]
+    assert all(0 < w < low._seg_rows[0][0] for w in windows)
+    assert " dynamic-slice(" in text
 
 
 def test_a_merge_join_plan_holds_no_sort(one_chip, lubm_db):

@@ -378,17 +378,18 @@ def _searches():
 def _spec_searches(low, every_tier=False):
     """The range searches one dispatch of ``low`` makes, by form, counted
     from its levels as ``eval_level`` makes them: per accessor and tier one
-    in ``probe`` where it has keys, one in ``live``."""
+    in ``probe`` where it has keys, one in ``live``; the base's over the
+    accessor's window where the assembled spec gives it one."""
     from kolibrie_tpu.ops.wcoj import range_search_form
 
     out = {"sorted": 0, "loop": 0}
     pcap = 1
-    for lv in low.root.levels:
+    for lv in low.build()[0].root.levels:
         cap = low._join_caps[lv.join_idx]
         for a in lv.accessors:
             base, delta = low._seg_rows[a.order_idx]
             live_delta = every_tier or low._tiers_np[a.order_idx] > 0
-            for n in (base, delta) if live_delta else (base,):
+            for n in (a.window or base, delta) if live_delta else (a.window or base,):
                 if a.key_srcs:
                     out[range_search_form(n, pcap, len(a.key_srcs))] += 1
                 out[range_search_form(n, cap, len(a.key_srcs) + 1)] += 1
@@ -554,3 +555,292 @@ def test_a_wcoj_plan_adds_nothing_to_the_join_search_counter(monkeypatch):
     assert _searches() != searches0  # the dispatch was counted
     assert [_JOIN_SEARCH_KEYS.labels(w).value
             for w in ("slots", "searched")] == before
+
+
+# ------------------------------------- a search over its key's window (ISSUE 48)
+#
+# Every probe tuple of an accessor leads with the text's constants, so the
+# rows it can match are one window of the order.  ``range_search`` with
+# ``lead`` and ``rows`` searches that window alone and must give the whole
+# order's ``(lo, hi)``, bit for bit, in both forms.
+
+SENT32 = 0xFFFFFFFF
+WINDOW_ROWS = 1024
+
+
+def _window_columns(pad):
+    """Three sorted columns, ``pad`` sentinel rows at their end.  Under the
+    first column: 3 opens the order (500 rows), 10 lies in the middle (700
+    rows, 300 of them under a second key of 5), 15 is not there, 20 is the
+    last real key (500 rows, so with little padding a 1,024-row window that
+    began at its first row would run past the end).  Every group begins and
+    ends with a row written three times."""
+    rng = np.random.default_rng(48)
+    groups = [(3, 500), (7, 900), (10, 700), (12, 396), (20, 500)]
+    c0 = np.concatenate([np.full(n, k) for k, n in groups])
+    c1 = rng.integers(1, 9, len(c0))
+    c1[(c0 == 10).nonzero()[0][:300]] = 5
+    c2 = rng.integers(1, 5000, len(c0))
+    rows = np.stack([c0, c1, c2], axis=1)
+    rows = rows[np.lexsort((c2, c1, c0))]
+    for k, _n in groups:
+        at = np.flatnonzero(rows[:, 0] == k)
+        rows[at[:3]] = rows[at[0]]
+        rows[at[-3:]] = rows[at[-1]]
+    cols = [np.concatenate([rows[:, j], np.full(pad, SENT32)]).astype(np.uint32)
+            for j in range(3)]
+    return cols
+
+
+def _window_probes(cols, lead, p, ncols):
+    """``p`` tuples that lead with ``lead``: rows that are there (each
+    group's first and last, written three times, among them), values
+    between rows, below every row, above every row, and the sentinel."""
+    rng = np.random.default_rng(len(lead) * 1000 + p)
+    keys = [np.full(p, k, dtype=np.uint32) for k in lead]
+    match = np.ones(len(cols[0]), dtype=bool)
+    for c, k in zip(cols, lead):
+        match &= c == np.uint32(k)
+    there = np.flatnonzero(match)
+    for j in range(len(lead), ncols):
+        col = rng.integers(0, 5002, p).astype(np.uint32)
+        col[:4] = (0, 1, SENT32 - 1, SENT32)
+        keys.append(col)
+    if len(there):
+        take = np.concatenate([there[:3], there[-3:], rng.choice(there, p // 2)])
+        for j in range(len(lead), ncols):
+            keys[j][4:4 + len(take)] = cols[j][take]
+    return keys
+
+
+@pytest.mark.parametrize("form", ["loop", "sorted"])
+@pytest.mark.parametrize("ncols", [2, 3])
+@pytest.mark.parametrize(
+    "case,pad,lead",
+    [
+        ("at the column's start", 1100, (3,)),
+        ("in the middle", 1100, (10,)),
+        ("ending at the padding", 1100, (20,)),
+        ("clamped: the window would pass the end", 100, (20,)),
+        ("no padding at all", 0, (20,)),
+        ("a constant with no rows", 1100, (15,)),
+        ("a constant below every row", 1100, (1,)),
+        ("a sentinel constant: the padding block", 1100, (SENT32,)),
+        ("a sentinel constant, no padding", 0, (SENT32,)),
+        ("two leading constants", 1100, (10, 5)),
+        ("two leading constants, the second not there", 1100, (10, 99)),
+        ("two leading constants, clamped", 100, (20, 8)),
+    ],
+)
+def test_a_search_of_the_window_is_the_search_of_the_order(case, pad, lead, ncols, form):
+    import jax
+    import jax.numpy as jnp
+
+    from kolibrie_tpu.ops import wcoj
+
+    if len(lead) >= ncols and lead[-1] == 99:
+        pytest.skip("nothing left to search under both constants")
+    p = 8192 if form == "sorted" else 64
+    cols = _window_columns(pad)[:ncols]
+    n = len(cols[0])
+    assert wcoj.range_search_form(WINDOW_ROWS, p, ncols) == form
+    in_window = np.ones(n, dtype=bool)
+    for c, k in zip(cols, lead):
+        in_window &= c == np.uint32(k)
+    # the padding is no key's rows: a sentinel constant's window may be cut
+    # short of it (1,100 rows of padding), and only a tuple of sentinels
+    # alone, which ``sent`` masks in every level, could tell
+    sentinel = lead[0] == SENT32
+    assert sentinel or in_window.sum() <= WINDOW_ROWS
+    assert WINDOW_ROWS < n
+    keys = _window_probes(cols, lead, p, ncols)
+    dcols = tuple(jnp.asarray(c) for c in cols)
+    dkeys = tuple(jnp.asarray(k) for k in keys)
+    consts = tuple(jnp.uint32(k) for k in lead)
+    windowed = jax.jit(
+        lambda c, k, ld: wcoj.range_search(c, k, ld, WINDOW_ROWS))(dcols, dkeys, consts)
+    whole = jax.jit(wcoj.lex_range)(dcols, dkeys)
+    host = wcoj.host_lex_range(cols, keys)
+    told = np.ones(p, dtype=bool)
+    if sentinel:
+        told = ~np.all([k == np.uint32(SENT32) for k in keys], axis=0)
+        assert 0 < told.sum() < p
+    for got, want, twin in zip(windowed, whole, host):
+        assert got.dtype == want.dtype == jnp.int32
+        assert np.array_equal(np.asarray(got)[told], np.asarray(want)[told]), case
+        assert np.array_equal(np.asarray(got)[told], twin[told]), case
+    # the window really was cut: its start is where the constants' rows begin,
+    # pulled back where the slice would pass the padded end
+    start, window = jax.jit(
+        lambda c, ld: wcoj.key_window(c, ld, WINDOW_ROWS))(dcols, consts)
+    below = np.zeros(n, dtype=bool)
+    eq = np.ones(n, dtype=bool)
+    for c, k in zip(cols, lead):
+        below |= eq & (c < np.uint32(k))
+        eq &= c == np.uint32(k)
+    assert int(start) == min(int(below.sum()), n - WINDOW_ROWS)
+    for w, c in zip(window, cols):
+        assert np.array_equal(np.asarray(w), c[int(start):int(start) + WINDOW_ROWS])
+    if "clamped" in case:
+        assert int(below.sum()) + WINDOW_ROWS > n
+
+
+def test_a_search_without_a_window_is_the_one_it_was():
+    """No constant leads, no rows given, or as many as the columns hold:
+    ``range_search`` is the plain search, and traces no slice."""
+    import jax
+    import jax.numpy as jnp
+
+    from kolibrie_tpu.ops import wcoj
+
+    cols = tuple(jnp.asarray(c) for c in _window_columns(1100))
+    keys = tuple(jnp.asarray(k) for k in _window_probes(_window_columns(1100), (10,), 64, 3))
+    n = int(cols[0].shape[0])
+    plain = str(jax.make_jaxpr(wcoj.range_search)(cols, keys))
+    for lead, rows in (((), WINDOW_ROWS), ((jnp.uint32(10),), 0), ((jnp.uint32(10),), n)):
+        text = str(jax.make_jaxpr(
+            lambda c, k, ld, rows=rows: wcoj.range_search(c, k, ld, rows))(cols, keys, lead))
+        assert "dynamic_slice" not in text
+        assert text.count("while") == plain.count("while")
+    cut = str(jax.make_jaxpr(
+        lambda c, k, ld: wcoj.range_search(c, k, ld, WINDOW_ROWS))(
+            cols, keys, (jnp.uint32(10),)))
+    assert cut.count("dynamic_slice") >= 3
+
+
+def _search_rows():
+    from kolibrie_tpu.query.template import _RANGE_SEARCH_ROWS
+
+    return {e: _RANGE_SEARCH_ROWS.labels(e).value for e in ("window", "order")}
+
+
+@pytest.mark.parametrize("side", ["loop", "sorted"])
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_typed_triangles_search_their_windows(shape, side, monkeypatch):
+    """Q9- and Q2-shaped triangles over a store with a live delta and every
+    ninth base row tombstoned, so inside every window: each accessor names a
+    predicate, so each carries a window and reads ``pos`` or ``pso`` (the
+    constants lead); the rows and the level counts are the numpy twin's, which
+    knows no window; the rows counter grows by the windows' rows and by no
+    whole order's."""
+    from kolibrie_tpu.optimizer import device_engine as de
+
+    monkeypatch.setenv("KOLIBRIE_WCOJ", "force")
+    db = _typed_db()
+    sparql = PREFIX + SHAPES[shape].replace("@A@", "A")
+    low = _at_capacity(db, sparql, SIDE_CAP[side])
+    before = _search_rows()
+    got = _id_rows(low.execute())
+    grew = {e: v - before[e] for e, v in _search_rows().items()}
+    table, counts = low.host_execute()
+    assert got == _id_rows(table) and got
+    assert low._last_counts == counts
+    assert set(low.order_names) <= {"pos", "pso"}
+    spec, _args = low.build()
+    accessors = [a for lv in spec.root.levels for a in lv.accessors]
+    base_rows = low._seg_rows[0][0]
+    for a in accessors:
+        assert a.lead >= 1 and a.key_srcs[0][0] == "u"
+        assert 0 < a.window < base_rows
+        assert a.window == de._round_cap(a.window)
+    searched = [(n, extent) for n, _p, _k, extent in low._range_searches()]
+    assert {extent for _n, extent in searched} == {"window", "delta"}
+    assert grew == {
+        "window": sum(n for n, extent in searched if extent == "window"),
+        "order": 0,
+    }
+    # the lowered tree carries no width: the template's, as ScanSpec.cap is
+    assert all(a.window == 0 for lv in low.root.levels for a in lv.accessors)
+
+
+def test_a_window_is_as_wide_as_its_hottest_key(monkeypatch):
+    """``(?x a ex:A)`` leads with predicate and class: its window holds the
+    hottest class's rows, whichever class the instance names, so two
+    instances share one assembled spec; ``(?x ex:p1 ?y)`` leads with the
+    predicate alone: its window holds every row under it."""
+    from kolibrie_tpu.optimizer import device_engine as de
+    from kolibrie_tpu.optimizer.stats import hottest_key_rows
+
+    monkeypatch.setenv("KOLIBRIE_WCOJ", "force")
+    db = _typed_db()
+    lows = [_at_capacity(db, PREFIX + SHAPES["q9"].replace("@A@", a), LOOP_CAP)
+            for a in ("A", "A2")]
+    specs = [low.build()[0] for low in lows]
+    assert specs[0] == specs[1] and lows[0].u_params != lows[1].u_params
+    rdf_type = db.dictionary.lookup(RDF_TYPE.strip("<>"))
+    p1 = db.dictionary.lookup(EX + "p1")
+    wide = {}
+    for lv in specs[0].root.levels:
+        for a in lv.accessors:
+            named = lows[0].u_params[a.lead_predicate]
+            wide.setdefault((named, a.lead), set()).add(a.window)
+    assert wide[rdf_type, 2] == {de._round_cap(hottest_key_rows(db, rdf_type, "o"))}
+    assert wide[p1, 1] == {de._round_cap(hottest_key_rows(db, p1, "p"))}
+
+
+def test_an_unknown_constant_selects_the_padding_and_no_row(monkeypatch):
+    """A class the dictionary does not know rides as the sentinel: the
+    accessor's window starts at the padding block, ``sent`` zeroes its
+    counts, and the answer is the twin's: empty, every level 0."""
+    monkeypatch.setenv("KOLIBRIE_WCOJ", "force")
+    db = _typed_db()
+    low = _at_capacity(
+        db, PREFIX + SHAPES["q2"].replace("@A@", "NoSuchClass"), LOOP_CAP)
+    assert SENT32 in low.u_params
+    assert low._template_window_caps()  # the windows are there all the same
+    assert _id_rows(low.execute()) == []
+    assert low._last_counts == low.host_execute()[1]
+
+
+def test_a_variable_predicate_keeps_the_whole_order(monkeypatch):
+    """A triangle that names no predicate has no constant to lead with: no
+    accessor carries a window, the searches run over the orders as they did
+    and the rows counter says so."""
+    monkeypatch.setenv("KOLIBRIE_WCOJ", "force")
+    db = _typed_db()
+    low = _lower(db, PREFIX + "SELECT ?x ?y ?z WHERE "
+                 "{ ?x ?p ?y . ?y ?p ?z . ?z ?p ?x }")
+    before = _search_rows()
+    assert _id_rows(low.execute()) == _id_rows(low.host_execute()[0])
+    grew = {e: v - before[e] for e, v in _search_rows().items()}
+    assert set(low._window_caps.values()) == {0}
+    assert grew["window"] == 0 and grew["order"] > 0
+    assert low.cap_key[2:] == ((),)  # and nothing of it in the capacities' key
+
+
+def test_a_base_that_outgrows_a_window_is_compiled_for_the_new_one(monkeypatch):
+    """The overflow path.  A window is computed at every build from the
+    frozen base it will search (``_template_window_caps`` beside
+    ``_template_scan_caps``), so a compaction that puts more rows under a
+    predicate than the window the plan last ran with moves ``base_version``
+    and the next dispatch of the SAME lowered plan assembles a wider window:
+    one more executable, never a search of a window cut short."""
+    from kolibrie_tpu.optimizer import device_engine as de
+
+    monkeypatch.setenv("KOLIBRIE_WCOJ", "force")
+    db = _typed_db()
+    sparql = PREFIX + SHAPES["q9"].replace("@A@", "A")
+    low = _at_capacity(db, sparql, LOOP_CAP)
+    assert _id_rows(low.execute()) == _id_rows(low.host_execute()[0])
+    was = dict(low._window_caps)
+    version = db.store.base_version
+    programs = de.device_compile_stats()["run_plan"]
+    # 700 more ex:p1 rows, among them triangles' closing edges, folded into
+    # the base: p1's rows no longer fit the 512 its window had
+    db.store.delta_threshold = 64
+    db.parse_ntriples("\n".join(
+        f"<{EX}n{a % 60}> <{EX}p1> <{EX}n{(a * 11 + a // 60) % 60}> ."
+        for a in range(700)))
+    db.store.compact()
+    assert db.store.base_version != version
+    assert len(db.store.delta_order("spo")) == 0
+    got = _id_rows(low.execute())
+    grown = [k for k, w in low._window_caps.items() if w > was[k]]
+    assert grown  # (a window follows its base: the tombstones' went with them)
+    p1 = db.dictionary.lookup(EX + "p1")
+    under_p1 = int((db.store.base_order("pso").c0 == p1).sum())
+    assert under_p1 > max(was.values())
+    assert max(low._window_caps.values()) >= under_p1
+    assert de.device_compile_stats()["run_plan"] == programs + 1
+    assert got == _id_rows(low.host_execute()[0])
+    assert len(got) == len(_rows(db, sparql, "host")) and got
