@@ -978,7 +978,7 @@ class ColumnarTripleStore:
         canon = {so.perm[0]: so.c0, so.perm[1]: so.c1, so.perm[2]: so.c2}
         return canon["s"], canon["p"], canon["o"]
 
-    def device_segment(self, name: str):
+    def device_segment(self, name: str, uploaded=None):
         """Two-tier device mirror of one sort order:
         ``(base_cols, delta_cols, del_pos)`` where
 
@@ -993,6 +993,8 @@ class ColumnarTripleStore:
         Shapes are a function of ``(base cap, delta cap)`` only, so
         mutation batches under the delta threshold never change compiled
         plan shapes: per-batch host→device traffic is O(delta_cap).
+        ``uploaded``, where given, is called once for each transfer this
+        call issues (a base's, a delta's): none where both are held.
         """
         self.compact()
         base = self._device_segments.get(name)
@@ -1027,6 +1029,8 @@ class ColumnarTripleStore:
                 )
             )
             self._device_segments[name] = base
+            if uploaded is not None:
+                uploaded()
             if _H2D_BYTES is not None:
                 _H2D_BYTES.labels("base").inc(3 * cap * 4)
             _add_seconds(_H2D_SECONDS, "base", t0)
@@ -1058,6 +1062,8 @@ class ColumnarTripleStore:
             ds, dp, do2, dl = jax.block_until_ready(jax.device_put(cols))
             delta = ((ds, dp, do2), dl)
             self._device_delta[name] = delta
+            if uploaded is not None:
+                uploaded()
             if _H2D_BYTES is not None:
                 _H2D_BYTES.labels("delta").inc(4 * dcap * 4)
             _add_seconds(_H2D_SECONDS, "delta", t0)

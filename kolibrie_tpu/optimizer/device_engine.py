@@ -441,6 +441,61 @@ def fetch_counters() -> Dict[str, int]:
     return dict(_FETCHES)
 
 
+# Host→device upload audit, the twin of the one above: every device array a
+# build makes is made by :func:`_upload` (an explicit transfer) or
+# :func:`_device_zeros` (a device computation), so a test can pin what a
+# warmed dispatch sends up before its program: nothing.  What a request does
+# change (scan ranges, tiers, the two parameter vectors) travels with the jit
+# call as numpy arguments and is no array of the build's.
+_BUILD_PUTS = _obs_metrics.counter(
+    "kolibrie_device_build_puts_total",
+    "device arrays the builds of device dispatches made before their "
+    "programs: explicit host-to-device transfers (transfer: an order's, a "
+    "table's or a mask's upload) and device computations (compute)",
+    labels=("what",),
+)
+_PUTS: Dict[str, int] = {"transfer": 0, "compute": 0}
+for _what in _PUTS:
+    _BUILD_PUTS.labels(_what)
+
+
+def _note_put(what: str) -> None:
+    _PUTS[what] += 1
+    _BUILD_PUTS.labels(what).inc()
+
+
+_note_transfer = partial(_note_put, "transfer")
+
+
+def build_put_counters() -> Dict[str, int]:
+    return dict(_PUTS)
+
+
+def _upload(host, dtype=None):
+    """One explicit host→device transfer of ``host``, counted."""
+    import jax.numpy as jnp
+
+    _note_transfer()
+    return jnp.asarray(host, dtype=dtype)
+
+
+# the placeholders a template that reads no number and names no quoted
+# triple is called with, by numpy dtype: constants, made at first use and kept
+_DEVICE_ZEROS: Dict[type, object] = {}
+
+
+def _device_zeros(dtype):
+    """The process's one ``zeros(1, dtype)`` on the device."""
+    import jax.numpy as jnp
+
+    arr = _DEVICE_ZEROS.get(dtype)
+    if arr is None:
+        _note_put("compute")
+        with jax.enable_x64(True):
+            arr = _DEVICE_ZEROS[dtype] = jnp.zeros(1, dtype=dtype)
+    return arr
+
+
 # The children of ``device.dispatch``, shared by the solo and the group
 # path so both split the same way (docs/OBSERVABILITY.md "Span taxonomy").
 
@@ -2656,15 +2711,14 @@ class LoweredPlan:
         return self._assemble(tag, operands)
 
     def _assemble(self, tag: int, operands: bool = True):
-        import jax.numpy as jnp
-
         store = self.db.store
         root = self._with_caps(self.root, self._scan_caps, self._join_caps)
         spec = PlanSpec(root, self.out_vars, tuple(self.order_names), tag)
         if not operands:
             return spec, None
         order_arrays = tuple(
-            store.device_segment(name) for name in self.order_names
+            store.device_segment(name, _note_transfer)
+            for name in self.order_names
         )
         # (base, delta) padded rows an order: with the capacities, all that
         # the forms of this dispatch's WCOJ range searches depend on
@@ -2676,17 +2730,11 @@ class LoweredPlan:
         # capacity (False = "no match", the clamp-gather's existing
         # out-of-range verdict) so small mutation batches that mint new
         # dictionary IDs re-upload without changing operand shapes
-        masks = tuple(
-            jnp.asarray(_pad_pow2(m, False)) for m in self.mask_arrays
-        )
+        masks = tuple(_upload(_pad_pow2(m, False)) for m in self.mask_arrays)
         values = tuple(
-            tuple(jnp.asarray(c) for c in cols) for cols in self.values_tables
+            tuple(_upload(c) for c in cols) for cols in self.values_tables
         )
-        if self.need_numf:
-            numf = self._device_numf()
-        else:
-            numf = jnp.zeros(1, dtype=jnp.float32)
-        scalars = jnp.asarray(self._scan_ranges_np)
+        numf = self._device_numf() if self.need_numf else _device_zeros(np.float32)
         # what each order's delta tier holds right now (rows + tombstones),
         # read with the segments above so both are one delta epoch's: the
         # plan body reads the base alone where an entry is 0
@@ -2697,29 +2745,37 @@ class LoweredPlan:
             ],
             dtype=np.int32,
         )
-        tiers = jnp.asarray(self._tiers_np)
         quoted = (
             device_quoted(self.db)
             if self.need_quoted
-            else tuple(jnp.zeros(1, dtype=jnp.uint32) for _ in range(4))
+            else (_device_zeros(np.uint32),) * 4
         )
-        params = self.device_params()
+        # the scan ranges, the tiers and the parameter vectors are the numpy
+        # arrays the build holds: the jit call transfers its host arguments
+        # itself, so the build issues no transfer for them
         return spec, (
-            order_arrays, scalars, tiers, masks, values, numf, quoted, params
+            order_arrays,
+            self._scan_ranges_np,
+            self._tiers_np,
+            masks,
+            values,
+            numf,
+            quoted,
+            self.host_params(),
         )
 
-    def device_params(self):
+    def host_params(self):
         """Pack the query constants as the (uparams, fparams) traced
         operands — the parameter-vector ABI: one uint32 slot per term-id
         constant site and one f64 slot per numeric comparand site, in
         lowering traversal order (padded to length >= 1 so empty templates
-        keep a stable operand shape)."""
-        import jax.numpy as jnp
-
-        u = np.asarray(self.u_params or [0], dtype=np.uint32)
-        f = np.asarray(self.f_params or [0.0], dtype=np.float64)
-        with jax.enable_x64(True):
-            return (jnp.asarray(u), jnp.asarray(f, dtype=jnp.float64))
+        keep a stable operand shape).  Numpy vectors: they go up with the
+        jit call, which must run under ``enable_x64`` for ``f`` to stay
+        float64."""
+        return (
+            np.asarray(self.u_params or [0], dtype=np.uint32),
+            np.asarray(self.f_params or [0.0], dtype=np.float64),
+        )
 
     def _device_numf(self):
         return device_numf(self.db)
@@ -4079,7 +4135,6 @@ def _converge_plan_batch(members: List[LoweredPlan]):
     the shared capacity for everyone and re-runs the group (one executable
     a capacity set, whatever the group).  Returns the live members' row
     blocks and the ``[slots]`` stats, device-resident."""
-    import jax.numpy as jnp
 
     from kolibrie_tpu.ops import slot_class
     from kolibrie_tpu.ops.pallas_kernels import pallas_enabled
@@ -4107,28 +4162,27 @@ def _converge_plan_batch(members: List[LoweredPlan]):
                 raise Unsupported(
                     "batch members lowered to different templates"
                 )
+        # the first member's own scan ranges and parameters are numpy arrays
+        # its build held anyway; the group's three matrices go up with the
+        # call as the solo path's vectors do
         order_arrays, _sc, tiers, masks, values, numf, quoted, _pp = base_args
         with jax.enable_x64(True):
-            params_b = (
-                jnp.asarray(rows(lambda lp: lp.u_params or [0], np.uint32)),
-                jnp.asarray(
-                    rows(lambda lp: lp.f_params or [0.0], np.float64),
-                    dtype=jnp.float64,
-                ),
-            )
             out = _enqueue_traced(
                 _run_plan_batch,
                 spec0,
                 pallas_enabled(),
                 order_arrays,
-                jnp.asarray(rows(lambda lp: lp._scan_ranges_np, np.int32)),
+                rows(lambda lp: lp._scan_ranges_np, np.int32),
                 np.int32(n),
                 tiers,
                 masks,
                 values,
                 numf,
                 quoted,
-                params_b,
+                (
+                    rows(lambda lp: lp.u_params or [0], np.uint32),
+                    rows(lambda lp: lp.f_params or [0.0], np.float64),
+                ),
             )
         return out, lp0._join_caps, _read_counts(out, out[1], attempt, np.asarray)
 
@@ -4449,7 +4503,6 @@ def device_quoted(db):
     Padded to a power-of-two row count with extra sentinel rows (all-ones
     qid stays sorted-last and never matches) for shape stability under
     mutation."""
-    import jax.numpy as jnp
 
     cache = db.__dict__.get("_operand_qt_cache")
     n = len(db.quoted)
@@ -4457,10 +4510,10 @@ def device_quoted(db):
         return cache[1]
     qid, qs, qp, qo = host_quoted_table(db)
     arrs = (
-        jnp.asarray(_pad_pow2(qid, 0xFFFFFFFF)),
-        jnp.asarray(_pad_pow2(qs, 0)),
-        jnp.asarray(_pad_pow2(qp, 0)),
-        jnp.asarray(_pad_pow2(qo, 0)),
+        _upload(_pad_pow2(qid, 0xFFFFFFFF)),
+        _upload(_pad_pow2(qs, 0)),
+        _upload(_pad_pow2(qp, 0)),
+        _upload(_pad_pow2(qo, 0)),
     )
     db.__dict__["_operand_qt_cache"] = (n, arrs)
     return arrs
@@ -4514,7 +4567,6 @@ def device_numf(db):
     re-uploads the table without changing the operand SHAPE — small
     mutation batches keep riding the compiled plan instead of retracing.
     """
-    import jax.numpy as jnp
 
     cache = db.__dict__.get("_operand_numf_cache")
     vals = db.numeric_values()
@@ -4524,7 +4576,7 @@ def device_numf(db):
     padded = np.full(_round_cap(n, 1024), np.nan)
     padded[:n] = vals
     with jax.enable_x64(True):
-        arr = jnp.asarray(padded, dtype=jnp.float64)
+        arr = _upload(padded, np.float64)
     db.__dict__["_operand_numf_cache"] = (n, arr)
     return arr
 
@@ -4603,8 +4655,6 @@ def aggregate_table(
     from kolibrie_tpu.query.executor import _encode_numbers
     from kolibrie_tpu.query.template import note_aggregate, note_aggregate_retry
 
-    import jax.numpy as jnp
-
     slots = int(valid.shape[0])
     ceiling = group_cap_ceiling(slots)
     key = (cap_key, stage.key)
@@ -4634,7 +4684,7 @@ def aggregate_table(
 
     with jax.enable_x64(True):
         numf_dev = (
-            device_numf(db) if stage.reads_numbers else jnp.zeros(1, jnp.float64)
+            device_numf(db) if stage.reads_numbers else _device_zeros(np.float64)
         )
         (gcols, aggs, _ng, n_rows), (cap,), (ng,) = _caps.run_until_fits(
             _caps.of(db).groups,

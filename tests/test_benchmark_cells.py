@@ -46,6 +46,9 @@ DIGESTS = files.read_json("data", "traffic_digests.json")["digests"]
 MESH4 = files.read_json("data", "lubm5.mesh4.entries.json")
 CONFIGS = {c["name"]: c for c in BENCH["configs"]}
 CELL_ORDER = [w["name"] for w in BENCH["workloads"]]
+# appended after every cell it lists and born with them in its list (ISSUE
+# 49): no standing list was edited to take a cell in
+BUILD_PUTS = "build_puts_in_window"
 CONFIG_ORDER = [c["name"] for c in BENCH["configs"]]
 
 
@@ -175,7 +178,7 @@ def test_benchmark_json_has_the_one_chip_cell_of_eight_clients():
     # no standing metric's list was edited to take the cell in (ISSUE 39's
     # two came later, with the cell in the list they were born with)
     for m in BENCH["per_layer"]:
-        if m["name"] not in BATCH8_METRICS and m["name"] not in JOIN_SEARCH_METRICS:
+        if m["name"] not in {*BATCH8_METRICS, *JOIN_SEARCH_METRICS, BUILD_PUTS}:
             assert "lubm5.batch8" not in m.get("workloads", [])
 
 
@@ -230,9 +233,10 @@ def test_benchmark_json_has_lubm_50_uncut_and_its_cell():
         assert os.path.exists(files.path("readers", kind + ".py"))
     # it is added to no list that was there; it reports what has no list
     for m in BENCH["per_layer"] + BENCH["end_to_end"]:
-        if m["name"] not in {*LUBM50_METRICS, *RANGE_SEARCH_METRICS, *SEARCH_ROWS_METRICS}:
+        if m["name"] not in {*LUBM50_METRICS, *RANGE_SEARCH_METRICS, *SEARCH_ROWS_METRICS,
+                             BUILD_PUTS}:
             assert "lubm50.triangles" not in m.get("workloads", [])
-        if m["name"] not in JOIN_SEARCH_METRICS and m["name"] not in SCAN_METRICS:
+        if m["name"] not in {*JOIN_SEARCH_METRICS, *SCAN_METRICS, BUILD_PUTS}:
             assert "lubm50.lookups" not in m.get("workloads", [])
     reported = {m["name"] for m in BENCH["end_to_end"] if "workloads" not in m}
     assert reported == {"cycle_ms", "setup_s"}
@@ -373,7 +377,7 @@ def test_benchmark_json_has_the_lookups_against_lubm_50_behind_its_triangles():
             "beside": "metrics.kolibrie_join_search_keys_total"}
         assert os.path.exists(files.path("readers", "counter_delta.py"))
     for m in BENCH["per_layer"] + BENCH["end_to_end"]:
-        if m["name"] not in JOIN_SEARCH_METRICS and m["name"] not in SCAN_METRICS:
+        if m["name"] not in {*JOIN_SEARCH_METRICS, *SCAN_METRICS, BUILD_PUTS}:
             assert "lubm50.lookups" not in m.get("workloads", [])
 
 
@@ -458,7 +462,7 @@ def test_benchmark_json_has_watdiv_100_uncut_and_its_cell():
             "kind": "counter_delta",
             "prefix": 'metrics.%s{engine="device"}' % family}
     for m in BENCH["per_layer"] + BENCH["end_to_end"]:
-        if m["name"] not in SCAN_METRICS:
+        if m["name"] not in {*SCAN_METRICS, BUILD_PUTS}:
             assert cell["name"] not in m.get("workloads", [])
 
 
@@ -573,7 +577,7 @@ def test_benchmark_json_has_bsbm_10m_and_its_cell():
             "kind": kind, **args}
         assert os.path.exists(files.path("readers", kind + ".py"))
     for m in BENCH["per_layer"] + BENCH["end_to_end"]:
-        if m["name"] not in AGGREGATE_METRICS:
+        if m["name"] not in {*AGGREGATE_METRICS, BUILD_PUTS}:
             assert cell["name"] not in m.get("workloads", [])
     # the ninth cell came behind the eight, which stand as they stood
     assert CELL_ORDER[:CELL_ORDER.index(cell["name"])] == [
@@ -859,7 +863,7 @@ def test_benchmark_json_has_lubm_50_asked_by_eight_workers_and_its_cell():
             "reader": args}
         assert os.path.exists(files.path("readers", args["kind"] + ".py"))
     for m in BENCH["per_layer"] + BENCH["end_to_end"]:
-        if m["name"] != "solo_tail_ms":
+        if m["name"] not in ("solo_tail_ms", BUILD_PUTS):
             assert cell["name"] not in m.get("workloads", [])
 
 
@@ -944,8 +948,8 @@ def test_the_search_rows_metrics_are_data_alone_for_the_triangles_cells():
     the two triangles cells; each a data file of ``counter_delta``; no cell,
     no configuration and no reader came with them."""
     added = per_layer_run(SEARCH_ROWS_METRICS)
-    assert [m["name"] for m in BENCH["per_layer"][-2:]] == list(SEARCH_ROWS_METRICS)
-    assert BENCH["per_layer"][-3]["name"] == "solo_tail_ms"
+    names = [m["name"] for m in BENCH["per_layer"]]
+    assert names[names.index(added[0]["name"]) - 1] == "solo_tail_ms"
     for m in added:
         assert m == {"name": m["name"], "unit": "rows", "better": "lower",
                      "source": "program_counter", "layer": "device dispatch",
@@ -982,3 +986,53 @@ def test_a_search_rows_metric_reads_its_extents_and_nothing_of_a_program_without
     parent = {key: {'metrics.kolibrie_wcoj_range_search_total{form="sorted"}': 15.0}
               for key in both}
     assert reader.read(parent, **args) is None
+
+
+BUILD_PUTS_FAMILY = "metrics.kolibrie_device_build_puts_total"
+ONE_CHIP_CELLS = [w["name"] for w in BENCH["workloads"] if w["chips"] == 1]
+
+
+def test_the_build_puts_metric_is_data_alone_for_the_one_chip_cells():
+    """ISSUE 49: one per-layer entry, appended behind ISSUE 48's two, for the
+    nine cells of one chip (the mesh's groups make no ``LoweredPlan``); a data
+    file of ``counter_delta`` on the family, both kinds summed; no cell, no
+    configuration and no reader came with it."""
+    (m,) = per_layer_run([BUILD_PUTS])
+    assert BENCH["per_layer"][-1] is m
+    assert BENCH["per_layer"][-2]["name"] == "wcoj_order_wide_rows_in_window"
+    assert len(ONE_CHIP_CELLS) == 9 and "lubm5.mesh4" not in ONE_CHIP_CELLS
+    assert m == {"name": BUILD_PUTS, "unit": "count", "better": "lower",
+                 "source": "program_counter", "layer": "device dispatch",
+                 "moves": "cycle_ms", "workloads": ONE_CHIP_CELLS}
+    assert files.read_json("layer_metrics", BUILD_PUTS + ".json") == {
+        "reader": {"kind": "counter_delta", "prefix": BUILD_PUTS_FAMILY}}
+    assert CELL_ORDER[-1] == "lubm50.mix8" and CONFIG_ORDER[-1] == "lubm-50-clients8"
+
+
+@pytest.mark.parametrize("program", ["change", "first_uploads", "parent"])
+def test_the_build_puts_metric_reads_both_kinds_and_nothing_of_a_program_without_them(
+        program):
+    """The reader runs on the parent's checkout too, which has no such
+    family: it reports nothing there and nothing raises.  Both label
+    children exist from import, so a window in which no build made a device
+    array reads 0, not nothing."""
+    from kolibrie_tpu.obs import metrics
+    from kolibrie_tpu.optimizer import device_engine  # noqa: F401  (registers the family)
+
+    reader = files.load_module("readers", "counter_delta")
+    family = BUILD_PUTS_FAMILY[len("metrics."):]
+    assert metrics.REGISTRY.get(family) is not None
+    with open(os.path.join(REPO, "docs", "OBSERVABILITY.md"), encoding="utf-8") as f:
+        assert f"`{family}`" in f.read()
+    transfer, compute = (BUILD_PUTS_FAMILY + '{what="%s"}' % w
+                         for w in ("transfer", "compute"))
+    ctx, want = {
+        "change": ({"counters0": {transfer: 12.0, compute: 3.0},
+                    "counters1": {transfer: 12.0, compute: 3.0}}, 0.0),
+        "first_uploads": ({"counters0": {transfer: 12.0, compute: 3.0},
+                           "counters1": {transfer: 18.0, compute: 4.0}}, 7.0),
+        "parent": ({key: {"metrics.kolibrie_batcher_requests_total": 500.0}
+                    for key in ("counters0", "counters1")}, None),
+    }[program]
+    got = reader.read(ctx, prefix=BUILD_PUTS_FAMILY)
+    assert got is None if want is None else got == pytest.approx(want)
