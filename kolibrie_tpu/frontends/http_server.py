@@ -171,8 +171,10 @@ _BATCH_DISPATCH_LAT = obs_metrics.histogram(
 )
 _SHARDED_ATTACH_ERRORS = obs_metrics.counter(
     "kolibrie_shard_attach_errors_total",
-    "sharded-serving attach/refresh attempts that failed (store keeps "
-    "serving single-device — the degraded path)",
+    "sharded-serving attach/refresh attempts that failed, by the "
+    "exception's type (store keeps serving single-device — the degraded "
+    "path)",
+    labels=("reason",),
 )
 _READS_SHED_CATCHING_UP = obs_metrics.counter(
     "kolibrie_reads_shed_catching_up_total",
@@ -223,21 +225,28 @@ def _parsed_term_to_str(term) -> str:
     return term
 
 
-def _maybe_attach_sharded(db) -> None:
-    """Attach (or refresh) the mesh serving layer for one store when
-    KOLIBRIE_SHARDED is on.  Never fails the surrounding request: a
+def _maybe_attach_sharded(db, refresh: bool = True) -> None:
+    """Attach the mesh serving layer for one store when KOLIBRIE_SHARDED
+    is on, and with ``refresh`` bring its mirrors up to date (recovery and
+    a follower's bootstrap: before the store serves).  A write passes
+    ``refresh=False``: the mirrors are stale until the first read that
+    needs them, which partitions once whatever the writes before it
+    (``ShardedDatabase.refresh``).  Never fails the surrounding request: a
     single-device runtime, or an attach/refresh fault, leaves the store
-    serving on the single-device path (that IS the degraded mode)."""
+    serving on the single-device path (that IS the degraded mode), counted
+    and logged with its reason: a deployment has no mesh proof to say that
+    7.9 M rows were never partitioned."""
     if not SHARDED_SERVING:
         return
     try:
         from kolibrie_tpu.parallel.sharded_serving import attach_sharded
 
         sh = attach_sharded(db)
-        if sh is not None:
+        if sh is not None and refresh:
             sh.refresh()
-    except Exception:
-        _SHARDED_ATTACH_ERRORS.inc()
+    except Exception as e:
+        _SHARDED_ATTACH_ERRORS.labels(type(e).__name__).inc()
+        _log.error("sharded attach failed", reason=type(e).__name__, error=str(e))
 
 
 def _load_rdf_into(db, data: str, fmt: str) -> int:
@@ -1288,10 +1297,10 @@ class KolibrieHandler(BaseHTTPRequestHandler):
                     t0 = time.perf_counter()
                     n = _load_rdf_into(batcher.db, req.get("rdf") or "", fmt)
                     add_load_seconds("parse", t0)
-                    # eager mirror upload while we already hold the lock:
-                    # the first query after a load pays dispatch, not
-                    # partitioning
-                    _maybe_attach_sharded(batcher.db)
+                    # the mesh layer is attached, its mirrors left stale:
+                    # the first read partitions the base once, whatever the
+                    # number of chunks a bulk load came in
+                    _maybe_attach_sharded(batcher.db, refresh=False)
             except Exception as e:
                 raise BadRequest(f"RDF parse error: {e}") from e
             # the exact deduplicated count: folds the batch into the sorted

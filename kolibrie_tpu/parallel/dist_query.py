@@ -148,6 +148,11 @@ def _largest(step_rows, buckets) -> Tuple[int, int]:
     )
 
 
+# the column of a freed walk's table that holds each row's key (times the
+# mesh size: the base of its (key, shard) pairs); no variable is named so
+_FREED_KEY = "\x00key"
+
+
 class _Uncounted(Exception):
     """A host chain walk given up: over the best plan so far, or past
     ``_CALIBRATE_ROW_LIMIT``."""
@@ -887,8 +892,14 @@ class DistQueryExecutor:
         self.store = store
         self.batched = batched
         self.plan_source = "pinned"  # where the seed came from
+        # the most rows of the main chain's answer on one shard, where this
+        # lowering counted them (the mesh serving layer sizes what it
+        # brings to the host from it)
+        self.final_rows: Optional[int] = None
+        self.final_is_ceiling = False  # counted over every key of the seed
         if seed is None or join_cap is None or bucket_cap is None:
             counted = self._counted_plan_cached(plans, seed)
+            self.final_rows, self.final_is_ceiling = counted[4:6]
             if seed is None:
                 seed, self.plan_source = counted[:2]
             if join_cap is None:
@@ -906,7 +917,7 @@ class DistQueryExecutor:
     # the host memory the static-capacity design exists to avoid.
     _CALIBRATE_ROW_LIMIT = 8_000_000
 
-    def _counted_plan_cached(self, plans, pinned) -> Tuple[int, str, int, int]:
+    def _counted_plan_cached(self, plans, pinned) -> tuple:
         """Per-database memo of :meth:`_counted_plan` keyed on (query
         shape, the body it sizes, mesh size), valid for ONE store version:
         one-shot ``execute_query_distributed`` calls of a repeated query
@@ -934,9 +945,11 @@ class DistQueryExecutor:
             cache["plans"][key] = plan
         return plan
 
-    def _counted_plan(self, plans, pinned) -> Tuple[int, str, int, int]:
-        """``(seed, source, join_cap, bucket_cap)`` from ONE host count of
-        what the mesh program will count.  The candidate seeds (the
+    def _counted_plan(self, plans, pinned) -> tuple:
+        """``(seed, source, join_cap, bucket_cap, final rows, whether they
+        are a ceiling)`` from ONE host count of what the mesh program will
+        count; the final rows are the main chain's answer on its fullest
+        shard, ``None`` where nothing could be counted.  The candidate seeds (the
         pinned one alone where a template has one) are walked in the
         order of their constant scans' sizes — range counts on the
         store's sorted orders — each through the step order
@@ -950,8 +963,10 @@ class DistQueryExecutor:
         a shard among them: it compacts them into ``join_cap`` slots) and
         the largest (source, destination) exchange bucket, never above the
         store-size
-        heuristic; the overflow/retry protocol backstops a constant with
-        more than 4x the counted rows.  Where nothing can be counted
+        heuristic; for the batched body the counts are those of the seed
+        premise's hottest key and take no headroom
+        (:meth:`_hottest_key_counts`), and the overflow/retry protocol
+        backstops what no count saw.  Where nothing can be counted
         (every walk past ``_CALIBRATE_ROW_LIMIT``) the most-constants
         seed and the heuristic stand (``source`` "constants")."""
         from kolibrie_tpu.optimizer.caps import fit_join_caps
@@ -992,13 +1007,61 @@ class DistQueryExecutor:
             fallback = pinned if pinned is not None else _most_constants(
                 self.premises
             )
-            return fallback, "constants", heuristic, heuristic
+            return fallback, "constants", heuristic, heuristic, None, False
+        final = int(np.bincount(shard, minlength=1).max()) if len(shard) else 0
+        counts = [max(step, cstep), max(bucket, cbucket)]
+        # The batched body serves every instance of a template with the
+        # capacities of its first sight, so they are counted for the
+        # template's HOTTEST instance and not for the one that came first:
+        # one more walk of the chosen chain with the seed premise's key
+        # freed, each count the most any one key gives on any one shard.
+        # No instance of the seed's key passes it, so it takes no headroom
+        # (``fit_join_caps``' ceiling), and a template's capacities no
+        # longer move with the seed of the data (Q8's ``join_cap`` read
+        # 131,072 or 262,144, and a cycle 2.5 or 5.1 s, by which
+        # university came first: PERF.md section 6, PR 50).  Another
+        # parameter of the text than the seed's key keeps the overflow
+        # protocol behind it, as a hot walk past the row limit keeps the
+        # first instance's counts and their headroom.
+        hot = self._hottest_key_counts(seed, plans[seed]) if self.batched else None
+        if hot is not None:
+            counts = [max(a, b) for a, b in zip(counts, hot[:2])]
+            final = max(final, hot[2])
         join_cap, bucket_cap = fit_join_caps(
-            [heuristic, heuristic], [max(step, cstep), max(bucket, cbucket)]
+            [heuristic, heuristic], counts, [hot is not None] * 2
         )
-        return seed, "counted", join_cap, bucket_cap
+        return seed, "counted", join_cap, bucket_cap, final, hot is not None
 
-    def _count_chain(self, premises, seed, steps, limit=None):
+    def _hottest_key_counts(self, seed, steps) -> Optional[Tuple[int, int, int]]:
+        """``(largest join step, largest bucket, most final rows)`` of the
+        main chain over every key of the seed premise, each on its fullest
+        shard: the chain walked once with that key freed
+        (:meth:`_count_chain` with ``free``).  ``None`` where the seed
+        premise is not a keyed scan (a predicate with a subject or an
+        object), where clauses follow the chain, or where the walk would
+        pass ``_CALIBRATE_ROW_LIMIT``."""
+        consts = self.premises[seed].consts
+        keyed = [pos for pos in (0, 2) if consts[pos] is not None]
+        if (
+            consts[1] is None
+            or len(keyed) != 1
+            or self.union_specs
+            or self.optional_specs
+            or self.anti
+        ):
+            return None
+        try:
+            step_rows, buckets, _table, per_key_shard = self._count_chain(
+                self.premises, seed, steps, free=keyed[0]
+            )
+        except _Uncounted:
+            return None
+        final = int(np.bincount(per_key_shard, minlength=1).max()) if len(
+            per_key_shard
+        ) else 0
+        return (*_largest(step_rows, buckets), final)
+
+    def _count_chain(self, premises, seed, steps, limit=None, free=None):
         """Host twin of one premise chain as the mesh program runs it:
         ``(each join step's rows per shard, each exchange's largest
         bucket, final table, final rows' shards)`` — the same walk for
@@ -1019,7 +1082,14 @@ class DistQueryExecutor:
         exchange; the batched body elides the exchange of a step whose
         key the rows are already partitioned by.  Counted before anything
         is materialised: a step over ``limit`` on some shard or a join
-        past ``_CALIBRATE_ROW_LIMIT`` raises :class:`_Uncounted`."""
+        past ``_CALIBRATE_ROW_LIMIT`` raises :class:`_Uncounted`.
+
+        With ``free`` (the position, subject 0 or object 2, of the seed
+        premise's key) the seed scans every key of its predicate and each
+        row carries its key along: "a shard" then reads "a shard of one
+        key" throughout, so each count is the most ANY instance of that
+        key gives, and the last value returned numbers the final rows'
+        (key, shard) pairs."""
         st = self.db.store
         n = self.n
 
@@ -1030,16 +1100,36 @@ class DistQueryExecutor:
                 m &= scan[a] == scan[b]
             return {v: scan[pos][m] for v, pos in prem.vars}, scan[0][m]
 
-        table, subj = table_of(premises[seed])
-        shard = shard_of(subj, n)
+        if free is None:
+            table, subj = table_of(premises[seed])
+            shard = shard_of(subj, n)
+        else:
+            prem = premises[seed]
+            scan = st.match(
+                *(None if pos == free else c for pos, c in enumerate(prem.consts))
+            )
+            m = np.ones(len(scan[0]), dtype=bool)
+            for a, b in prem.eq_pairs:
+                m &= scan[a] == scan[b]
+            table = {v: scan[pos][m] for v, pos in prem.vars}
+            _keys, key_of_row = np.unique(scan[free][m], return_inverse=True)
+            # the key rides with the rows through every join; a row's
+            # "shard" is its place among the (key, shard) pairs
+            table[_FREED_KEY] = key_of_row.astype(np.int64) * n
+            shard = shard_of(scan[0][m], n)
         exchanged = (
             exchanged_steps(premises, seed, steps, n)
             if self.batched
             else (n > 1,) * len(steps)
         )
         step_rows, buckets = [], []
+
+        def place():
+            """Each row's shard, or with ``free`` its (key, shard) pair."""
+            return shard if free is None else table[_FREED_KEY] + shard
+
         if self.batched:
-            seed_rows = np.bincount(shard, minlength=n).astype(np.int64)
+            seed_rows = np.bincount(place(), minlength=n).astype(np.int64)
             if limit is not None and seed_rows.max() > limit:
                 raise _Uncounted
             step_rows.append(seed_rows)
@@ -1049,7 +1139,7 @@ class DistQueryExecutor:
             if routed:
                 dest = shard_of(lk, n)
                 buckets.append(
-                    int(np.bincount(shard * n + dest, minlength=1).max())
+                    int(np.bincount(place() * n + dest, minlength=1).max())
                 )
                 shard = dest
             order = np.argsort(rk, kind="stable")
@@ -1064,7 +1154,7 @@ class DistQueryExecutor:
             else:
                 matched = counts
             per_shard = np.bincount(
-                shard, weights=matched, minlength=n
+                place(), weights=matched, minlength=n
             ).astype(np.int64)
             total = int(counts.sum())
             if (
@@ -1090,7 +1180,7 @@ class DistQueryExecutor:
                     keep &= new_table[v] == c[ri]
             table = {v: c[keep] for v, c in new_table.items()}
             shard = shard[li][keep]
-        return step_rows, buckets, table, shard
+        return step_rows, buckets, table, place()
 
     def _count_clauses(self, table, shard) -> Tuple[int, int]:
         """The clause stages' share of the two counts, from the main

@@ -15,9 +15,14 @@ relations + delta-only transfer, arXiv:2311.02206):
    ``mix32(key) % n`` into per-shard ``[n, base_cap]`` blocks — uploaded once
    per ``base_version``.  Mutation batches under ``delta_threshold`` re-upload
    only the O(delta) add blocks and tombstone positions; the combined view is
-   reassembled on device (:func:`_assemble`), so shapes — and therefore every
-   compiled serving program — survive sustained insert/delete traffic with
-   ZERO recompiles.
+   reassembled on device (:func:`_assemble`) and each mirror's rows sorted
+   by its join key (:func:`_get_sort_fn`: the right side of every join step
+   of every template, so no serving program sorts a mirror), so shapes — and
+   therefore every compiled serving program — survive sustained
+   insert/delete traffic with ZERO recompiles.  Nothing partitions for a
+   write: the mirrors go stale and the first read that needs them brings
+   them up to date, once (:meth:`ShardedDatabase.refresh`), so a bulk load
+   of *k* chunks re-partitions the base once.
 2. **One dispatch per template group, one executable per template.**
    Same-template queries differ only in constants (``query/template.py``);
    the batched program moves those constants into a traced
@@ -25,21 +30,23 @@ relations + delta-only transfer, arXiv:2311.02206):
    members beside it as a traced scalar, replicated over the mesh.  A loop
    INSIDE one ``shard_map`` body runs the live members only — per member:
    shard-local seed scan compacted to ``join_cap`` rows, fixed-cap
-   ``all_to_all`` binding-table exchange, local joins, replicated filter
-   masks — so a dispatch costs what its live members cost, and a group
+   ``all_to_all`` binding-table exchange, local joins against the sorted
+   mirrors, replicated filter masks, the final rows compacted to
+   ``out_cap`` — so a dispatch costs what its live members cost, and a group
    of one is a group like any other: under
    an attached mesh the executor sends every request of a supported shape
    here (no device holds the whole store in the deployment this stands
    for).  ``slots`` is a class (a power of two, not below 8), not the
-   group size, so a template has one executable per capacity pair for
+   group size, so a template has one executable per capacity set for
    every group up to the class.  A member's plan — the seed premise, the
-   step order it gives and the two capacities — is counted once on the
+   step order it gives and the three capacities — is counted once on the
    host from the store's sorted orders, on the template's first sight,
    as the rows THIS body will count (``DistQueryExecutor._counted_plan``
    with ``batched``), and pinned with the template: the members of every
-   later group are lowered with it.  The host merge pulls the live
-   members' rows only and is deterministic and identical to the solo
-   path (``_finish_select_table``).
+   later group are lowered with it.  The host merge brings a dispatch's
+   answers down in one transfer, decodes the rows the program counted and
+   is deterministic and identical to the solo path
+   (``_finish_select_table``).
 3. **Cross-cutting layers ride the shard hop.**  Deadlines are checked before
    dispatch (``shard.dispatch`` is also a fault-injection site), per-template
    breakers gate the group in the executor, per-shard span children and
@@ -57,6 +64,7 @@ from __future__ import annotations
 import threading
 import time
 import weakref
+from concurrent.futures import ThreadPoolExecutor
 from functools import lru_cache, partial
 from typing import Dict, List, Optional, Tuple
 
@@ -76,10 +84,12 @@ from kolibrie_tpu.parallel.dist_join import (
     _RPAD32 as _JRPAD,
     _dist_check_vma,
     compact,
+    prefix_count,
 )
 from kolibrie_tpu.parallel.mesh import make_mesh
 from kolibrie_tpu.parallel.sharded_store import ShardedTripleStore, shard_of
 from kolibrie_tpu.query import compile_cache as _cc
+from kolibrie_tpu.optimizer.caps import fit_join_caps
 from kolibrie_tpu.query.template import fingerprint_query, note_cap_retry
 from kolibrie_tpu.resilience.deadline import check_deadline
 from kolibrie_tpu.resilience.faultinject import fault_point
@@ -163,6 +173,38 @@ _SHARD_JOIN_ROWS = _m.counter(
     "shard the key matches of each join step and the rows each exchange "
     "delivered (over cap_slots_total: the mesh path's occupancy)",
 )
+_SHARD_XROWS = _m.counter(
+    "kolibrie_shard_exchange_rows_total",
+    "Rows the mesh programs' all_to_all exchanges delivered, per live "
+    "member and shard (the exchange<k> columns of the shard_stats block, "
+    "which join_rows_total lumps with the key matches)",
+)
+_SHARD_XSLOTS = _m.counter(
+    "kolibrie_shard_exchange_slots_total",
+    "Slots the exchanges' receive buffers were compiled for: live members "
+    "x exchanges x shards x shards x bucket_cap (exchange_rows_total over "
+    "this is what an exchange really carries)",
+)
+_SHARD_MERGED_ROWS = _m.counter(
+    "kolibrie_shard_merged_rows_total",
+    "Live rows the host merge decoded: the live members' final rows, "
+    "summed over the shards",
+)
+_SHARD_MERGED_BYTES = _m.counter(
+    "kolibrie_shard_merged_bytes_total",
+    "Bytes the host merge's transfers brought down, padding included "
+    "(merged_rows_total x a row's width x 4 over this is its occupancy)",
+)
+_SHARD_BASE_REBUILDS = _m.counter(
+    "kolibrie_shard_base_rebuilds_total",
+    "Re-partitions of the frozen base into the two hash mirrors (one a "
+    "bulk load, whatever its chunk count; one a compaction)",
+)
+_SHARD_PARTITION_SECONDS = _m.counter(
+    "kolibrie_shard_partition_seconds_total",
+    "Seconds ShardedDatabase.refresh spent bringing the mirrors up to "
+    "date: base re-partitions, delta blocks, the sorted sides",
+)
 _SHARD_FALLBACKS = _m.counter(
     "kolibrie_shard_fallback_total",
     "Template groups the mesh path declined",
@@ -171,6 +213,10 @@ _SHARD_FALLBACKS = _m.counter(
 _SHARD_DISPATCH_LAT = _m.histogram(
     "kolibrie_shard_dispatch_seconds", "Mesh dispatch latency (one group)"
 )
+
+# a template's running sums in ``ShardedDatabase.stats()``: its dispatches,
+# their live members, the seconds of ``shard.build`` / ``.wait`` / ``.merge``
+_TOOK = ("dispatches", "members", "build_s", "wait_s", "merge_s")
 
 # ------------------------------------------------- compile-surface tracking
 # One entry per distinct batched program / assemble shape ever built — the
@@ -215,8 +261,8 @@ def _join_presorted(lkey, lvalid, rsorted, order, cap):
     """:func:`dist_join.local_join_u32` against a PRE-sorted right side:
     identical ``(li, ri, valid, total)`` contract, minus the per-call
     ``argsort`` — the batched body joins every live member of the loop
-    against the same resident mirror, so the sort is loop-invariant and
-    hoisted to once per dispatch.  ``total`` counts UNFILTERED key matches
+    against the same resident mirror, sorted once a refresh of the mirrors
+    (:func:`_get_sort_fn`).  ``total`` counts UNFILTERED key matches
     (the side premise's constant filters apply post-join): that is the
     number the host count sizes ``join_cap`` from
     (``DistQueryExecutor._count_chain`` with ``batched``) and the overflow
@@ -226,7 +272,7 @@ def _join_presorted(lkey, lvalid, rsorted, order, cap):
     lo = jnp.searchsorted(rsorted, lk, side="left")
     hi = jnp.searchsorted(rsorted, lk, side="right")
     counts = (hi - lo).astype(jnp.int32)
-    cum = jnp.cumsum(counts)
+    cum = prefix_count(counts)
     total = cum[-1]
     idx = jnp.arange(cap, dtype=jnp.int32)
     row = jnp.searchsorted(cum, idx, side="right")
@@ -256,6 +302,7 @@ def _batched_body(
     axis,
     join_cap,
     bucket_cap,
+    out_cap,
 ):
     """One template group in one mesh program: a loop over the first
     ``live`` rows of the ``[slots, n_slots]`` constant matrix, each member
@@ -265,7 +312,10 @@ def _batched_body(
     compacts its seed scan to ``join_cap`` rows (:func:`dist_join.compact`)
     before the first step, so its binding table is never wider than
     ``max(join_cap, n * bucket_cap)``: the seed's mask and one prefix count
-    of it are all a member does at the shard's width.  ``live`` is a
+    of it are all a member does at the shard's width.  A member's final
+    rows are compacted once more, into the first of ``out_cap`` slots a
+    shard, so what the host merge brings down is ``out_cap`` wide and not
+    ``join_cap``, and it decodes the counted rows alone.  ``live`` is a
     traced scalar,
     replicated over the mesh: every shard runs the same trips, so the
     ``all_to_all`` / ``psum`` inside the loop stay matched, and a group of
@@ -276,7 +326,9 @@ def _batched_body(
     of the template shares the executable too."""
     from kolibrie_tpu.parallel.dist_query import exchanged_steps
 
-    fs, fp, fo, fv, gs, gp, go, gv = (a[0] for a in state)
+    fs, fp, fo, fv, gs, gp, go, gv, fsorted, forder, gsorted, gorder = (
+        a[0] for a in state
+    )
     masks = tuple(masks)
     fcols = (fs, fp, fo)
     # Exchange elision (a trace-time decision; the program cache key
@@ -286,25 +338,16 @@ def _batched_body(
     # exchange nothing.
     exchanged = exchanged_steps(premises, seed, steps, n)
 
-    # Hoisted per-step side sorts: every member joins against the same
-    # resident mirror, so the right-side argsort is loop-invariant —
-    # sort once per dispatch, not once per member.  The side premise's
-    # constant filters (which DO vary per member) apply post-join at the
-    # matched rows instead of pre-masking the sort input.
-    sides = []
-    for (j, kv, kpos, extra) in steps:
-        if kpos == 0:
-            side_cols, side_valid, side_key = fcols, fv, fs
-        else:
-            side_cols, side_valid, side_key = (gs, gp, go), gv, go
-        rk = jnp.where(side_valid, side_key.astype(jnp.uint32), _JRPAD)
-        # lax.sort carries the values through the sort instead of
-        # argsort-then-gather: XLA:CPU fuses the ``rk[order]`` gather into
-        # the consuming searchsorted incorrectly under shard_map (observed
-        # as phantom join matches), and the fused form is also slower.
-        iota = jnp.arange(rk.shape[0], dtype=jnp.int32)
-        rsorted, order = lax.sort((rk, iota), num_keys=1)
-        sides.append((side_cols, order, rsorted))
+    # Every member of every dispatch joins against the same resident
+    # mirror, so its sort by the join key is not the program's: the state
+    # brings each mirror's sorted keys and their rows (``_get_sort_fn``,
+    # once a refresh).  The side premise's constant filters (which DO vary
+    # per member) apply post-join at the matched rows instead of
+    # pre-masking the sort input.
+    sides = [
+        (fcols, forder, fsorted) if kpos == 0 else ((gs, gp, go), gorder, gsorted)
+        for (_j, _kv, kpos, _extra) in steps
+    ]
 
     def scan_param(prem, cols, valid, prm):
         m = valid
@@ -386,8 +429,12 @@ def _batched_body(
                     m = masks[f.mask_idx]
                     valid = valid & m[jnp.minimum(col, m.shape[0] - 1)]
             svec.append(jnp.sum(valid).astype(jnp.int32))
-            outs = tuple(jnp.where(valid, table[v], 0) for v in out_vars)
-        return outs, valid, ov, jnp.stack(svec)
+        with jax.named_scope("emit"):
+            outs, _ok, dropped = compact(
+                tuple(table[v] for v in out_vars), valid, out_cap
+            )
+            ov = ov + lax.psum(dropped.astype(jnp.int32), axis)
+        return outs, ov, jnp.stack(svec)
 
     # The live-member loop.  The carry is typed from one member's outputs
     # (shapes, dtypes and which of them vary over the mesh axis), so the
@@ -404,20 +451,19 @@ def _batched_body(
             one(lax.dynamic_index_in_dim(params, i, 0, keepdims=False)),
         )
 
-    outs, valid, ovs, svecs = lax.fori_loop(
+    outs, ovs, svecs = lax.fori_loop(
         0, live, member, jax.tree.map(buffer, jax.eval_shape(one, params[0]))
     )
     overflow = jnp.sum(ovs)  # each member's ov is already a global psum
     return (
         tuple(o[:, None] for o in outs),
-        valid[:, None],
         overflow[None],
         svecs[:, None, :],
     )
 
 
 # Memoized program factory (the sanctioned jit-factory pattern) — the key
-# is the template's constant-free shape, its capacity pair and the slot
+# is the template's constant-free shape, its capacities and the slot
 # class, so constant-variants, mutation epochs and every group size up to
 # ``slots`` share one executable (the class is ``ops.slot_class``, the
 # one-chip batch's rule too).
@@ -425,7 +471,7 @@ def _batched_body(
 @lru_cache(maxsize=64)
 def _get_batched_fn(
     mesh, premises, seed, steps, filters, out_vars, n_masks, join_cap,
-    bucket_cap, slots,
+    bucket_cap, out_cap, slots,
 ):
     _compile_stats["batched_programs"] += 1
     axis = mesh.axis_names[0]
@@ -441,6 +487,7 @@ def _get_batched_fn(
         axis=axis,
         join_cap=join_cap,
         bucket_cap=bucket_cap,
+        out_cap=out_cap,
     )
     spec = P(axis, None)
     bspec = P(None, axis, None)
@@ -449,21 +496,42 @@ def _get_batched_fn(
             body,
             mesh=mesh,
             check_vma=_dist_check_vma(),
-            in_specs=((spec,) * 8, (P(),) * n_masks, P(), P()),
-            out_specs=((bspec,) * len(out_vars), bspec, P(axis), bspec),
+            in_specs=((spec,) * 12, (P(),) * n_masks, P(), P()),
+            out_specs=((bspec,) * len(out_vars), P(axis), bspec),
         )
     )
 
 
-@jax.jit
-def _member_rows(arrays, i):
-    """Row ``i`` of each ``[slots, ...]`` output.  The host merge pulls the
-    live members' rows one by one, so the transfer follows the live
-    members as the loop does (a whole slot class is 300 MB at LUBM(5)'s
-    capacities, whatever the group).  ``i`` is traced: one executable a
-    capacity pair."""
-    return jax.tree.map(
-        lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False), arrays
+@lru_cache(maxsize=8)
+def _get_sort_fn(mesh):
+    """A mirror's rows by their join key, shard by shard: ``(sorted keys,
+    the rows they came from)``, invalid rows last.  Every join step of
+    every template searches one of the two mirrors by its key, and a mirror
+    moves only with the store, so it is sorted once a refresh and not once
+    a dispatch (until PR 50 each program sorted for itself: 19 s of a
+    program's 20 s compile at 2 M rows a shard, and 4 % of
+    ``lubm5.mesh4``'s busy time).  One executable a mirror width."""
+    axis = mesh.axis_names[0]
+
+    def body(key, valid):
+        rk = jnp.where(valid[0], key[0].astype(jnp.uint32), _JRPAD)
+        # lax.sort carries the rows through the sort instead of
+        # argsort-then-gather: XLA:CPU fuses an ``rk[order]`` gather into
+        # a consuming searchsorted incorrectly under shard_map (observed
+        # as phantom join matches), and the fused form is also slower.
+        iota = jnp.arange(rk.shape[0], dtype=jnp.int32)
+        rsorted, order = lax.sort((rk, iota), num_keys=1)
+        return rsorted[None], order[None]
+
+    spec = P(axis, None)
+    return jax.jit(
+        jax.shard_map(
+            body,
+            mesh=mesh,
+            check_vma=_dist_check_vma(),
+            in_specs=(spec, spec),
+            out_specs=(spec, spec),
+        )
     )
 
 
@@ -610,16 +678,22 @@ class ShardedDatabase:
         self.lock = threading.RLock()
         self._subj = _HashMirror(0)
         self._obj = _HashMirror(2)
+        # (sorted keys, their rows) of each mirror's view, as of the last
+        # refresh: the right side of every join step
+        self._subj_sorted = None  # guarded by: lock
+        self._obj_sorted = None  # guarded by: lock
         self.view: Optional[ShardedTripleStore] = None  # guarded by: lock
         self._sig = None  # guarded by: lock
         self._base_ref = None  # guarded by: lock
         self._base_cap_s = 0
         self._base_cap_o = 0
         self._delta_cap = 0
-        # (fingerprint, base version) -> (seed, join_cap, bucket_cap): the
-        # plan a template got on its first sight, and the capacities that
-        # held since
-        self._plans: Dict[tuple, Tuple[int, int, int]] = {}  # guarded by: lock
+        # (fingerprint, base version) -> (seed, join_cap, bucket_cap,
+        # out_cap): the plan a template got on its first sight, and the
+        # capacities that held since
+        self._plans: Dict[tuple, Tuple[int, int, int, int]] = {}  # guarded by: lock
+        # fingerprint -> what its dispatches took so far, by ``_TOOK``
+        self._by_template: Dict[str, dict] = {}  # guarded by: lock
         self.stats_counters = {
             "base_rebuilds": 0,
             "delta_refreshes": 0,
@@ -644,8 +718,17 @@ class ShardedDatabase:
         Base blocks re-partition only when ``base_version`` moved (or the
         base arrays were swapped by ``restore()``); otherwise only the
         O(delta) add/tombstone blocks re-upload.  Returns True when any
-        device state moved."""
+        device state moved.
+
+        Nothing calls this for a write: the mirrors are stale from the
+        moment the store moves until the first read that needs them, and
+        every read starts here (:meth:`execute_batch`), so a read sees
+        every triple the store holds, and a bulk load of *k* chunks, each
+        of which folds into the base, re-partitions the base once and not
+        *k* times.  Recovery and a follower's bootstrap call it before
+        they serve."""
         with self.lock:
+            t0 = time.perf_counter()
             st = self.db.store
             sig = st.segment_signature()
             anchor = st.base_rows("spo")[0]
@@ -675,13 +758,22 @@ class ShardedDatabase:
                 self._base_cap_s = _cap_for(bs)
                 self._base_cap_o = _cap_for(bo)
                 self._delta_cap = int(st.delta_device_cap)
-                self._subj.rebuild_base(
-                    (bs, bp, bo), self.n, self._base_cap_s, sharding
-                )
-                self._obj.rebuild_base(
-                    (bs, bp, bo), self.n, self._base_cap_o, sharding
-                )
+                with span(
+                    "shard.partition_base",
+                    rows=len(bs),
+                    cap_subj=self._base_cap_s,
+                    cap_obj=self._base_cap_o,
+                ):
+                    self._subj.rebuild_base(
+                        (bs, bp, bo), self.n, self._base_cap_s, sharding
+                    )
+                    self._obj.rebuild_base(
+                        (bs, bp, bo), self.n, self._base_cap_o, sharding
+                    )
+                # the one place a rebuild is counted: ``stats()`` and
+                # ``/metrics`` read the same event
                 self.stats_counters["base_rebuilds"] += 1
+                _SHARD_BASE_REBUILDS.inc()
             adds = st.delta_rows("spo")
             dels = st.delta_del_positions("spo")
             for mirror, bcap in (
@@ -709,13 +801,31 @@ class ShardedDatabase:
                 self.view = view
             self.view.by_subj, self.view.by_subj_valid = self._subj.assemble()
             self.view.by_obj, self.view.by_obj_valid = self._obj.assemble()
-            # two-tier probe index: base pack survives delta refreshes
-            self.view.refresh_subj_index(
-                base_end=self._base_cap_s,
-                base_valid=self._subj.base_valid,
-                del_pos=self._subj.del_pos,
-                base_unchanged=not base_changed,
-            )
+            # each mirror's rows by its join key, the two beside one
+            # another: two widths are two executables, and on a machine's
+            # first sight of them the sorts compile at once, not one after
+            # the other (compiling releases the interpreter lock)
+            sort = _get_sort_fn(self.mesh)
+            with ThreadPoolExecutor(2) as pool:
+                self._subj_sorted, self._obj_sorted = pool.map(
+                    lambda side: sort(*side),
+                    (
+                        (self.view.by_subj[0], self.view.by_subj_valid),
+                        (self.view.by_obj[2], self.view.by_obj_valid),
+                    ),
+                )
+            if self.view.subj_index_parts is not None:
+                # a consumer of the probe index has asked for one on this
+                # view (``dist_join.dist_bgp_join_count``): keep it two-tier,
+                # the base pack surviving delta refreshes.  No served
+                # template reads it, so a view nobody asked builds none
+                # (``ensure_subj_index`` builds on demand)
+                self.view.refresh_subj_index(
+                    base_end=self._base_cap_s,
+                    base_valid=self._subj.base_valid,
+                    del_pos=self._subj.del_pos,
+                    base_unchanged=not base_changed,
+                )
             self._sig = sig
             self._base_ref = weakref.ref(anchor)
             self.stats_counters["delta_refreshes"] += 1
@@ -725,6 +835,7 @@ class ShardedDatabase:
             _SHARD_IMBALANCE.set(imb)
             for sh in range(self.n):
                 _SHARD_OCCUPANCY.labels(str(sh)).set(int(occ[sh]))
+            _SHARD_PARTITION_SECONDS.inc(time.perf_counter() - t0)
             return True
 
     # ------------------------------------------------------------ execution
@@ -741,7 +852,7 @@ class ShardedDatabase:
         dispatched as a group of one through :meth:`execute_batch`, so it
         lowers and jits the very executable that every later request of
         the template runs, alone or in a group (one per template and
-        capacity pair), and the capacities settle here too — with the
+        capacity set), and the capacities settle here too — with the
         persistent compilation cache enabled the XLA work is a disk load
         on every process after the first.  Returns False (instead of
         raising) for templates the mesh lowering declines: the warmer
@@ -784,15 +895,24 @@ class ShardedDatabase:
             ):
                 with span("shard.build"):
                     group = self._build_group(fp, items)
+                t1 = time.perf_counter()
                 fault_point("shard.dispatch")
                 with span(
                     "shard.wait", slots=group["params"].shape[0], live=live
                 ) as sp:
                     device_out = self._run_group(fp, group, sp)
+                t2 = time.perf_counter()
                 with span("shard.merge"):
                     results = self._merge_group(fp, items, group, device_out)
-            _SHARD_DISPATCH_LAT.observe(time.perf_counter() - t0)
+            t3 = time.perf_counter()
+            _SHARD_DISPATCH_LAT.observe(t3 - t0)
             self._count_dispatch(fp, group)
+            # the three spans' seconds by template, for ``stats()``: a
+            # dispatch of several templates is one trace, and the spans'
+            # ring keeps a window's last requests only
+            took = self._by_template.setdefault(fp, dict.fromkeys(_TOOK, 0))
+            for key, more in zip(_TOOK, (1, live, t1 - t0, t2 - t1, t3 - t2)):
+                took[key] += more
             return results
 
     def _decline(self, reason: str) -> None:  # kolint: holds[lock]
@@ -812,10 +932,10 @@ class ShardedDatabase:
         # The plan is the template's, not the member's: on a template's
         # first sight (or after a base-version bump dropped the pin) the
         # first member's constants are counted on the host — the seed, its
-        # step order and both capacities, for the joins ``_batched_body``
-        # runs — and every member of this and every later group is
-        # lowered with that plan, so the group shares one shape and the
-        # template one executable a capacity pair.
+        # step order and the capacities, for the joins ``_batched_body``
+        # runs and the rows it emits — and every member of this and every
+        # later group is lowered with that plan, so the group shares one
+        # shape and the template one executable a capacity set.
         plan = self._pinned_plan(fp)
         kw = (
             {"seed": plan[0], "join_cap": plan[1], "bucket_cap": plan[2]}
@@ -836,6 +956,16 @@ class ShardedDatabase:
             raise
         if plan is None:
             _SHARD_PLANS.labels(exemplar.plan_source).inc()
+            # a member's final rows sit in a table of ``join_cap`` slots a
+            # shard; what comes to the host is the counted rows' capacity
+            # by the one rule, never wider than that table
+            out_cap = exemplar.join_cap
+            if exemplar.final_rows is not None:
+                (out_cap,) = fit_join_caps(
+                    [out_cap], [exemplar.final_rows], [exemplar.final_is_ceiling]
+                )
+        else:
+            out_cap = plan[3]
         if (
             exemplar.agg_items
             or exemplar.query.group_by
@@ -925,7 +1055,7 @@ class ShardedDatabase:
             "premises": param_premises,
             "params": params,
             "masks": masks,
-            "caps": (exemplar.join_cap, exemplar.bucket_cap),
+            "caps": (exemplar.join_cap, exemplar.bucket_cap, out_cap),
         }
 
     def _run_group(self, fp: str, group: dict, sp):  # kolint: holds[lock]
@@ -940,9 +1070,11 @@ class ShardedDatabase:
             self.view.by_subj_valid,
             *self.view.by_obj,
             self.view.by_obj_valid,
+            *self._subj_sorted,
+            *self._obj_sorted,
         )
         live = np.int32(len(group["execs"]))
-        join_cap, bucket_cap = group["caps"]
+        join_cap, bucket_cap, out_cap = group["caps"]
         for attempt in range(8):
             key = (
                 group["premises"],
@@ -953,44 +1085,48 @@ class ShardedDatabase:
                 len(group["masks"]),
                 join_cap,
                 bucket_cap,
+                out_cap,
                 group["params"].shape[0],
             )
             fn = _get_batched_fn(self.mesh, *key)
             with jax.enable_x64(True):
                 # the program's key and the mesh's size are what this layer
                 # holds to define the executable (a first sight's identity)
-                outs, valid, overflow, shard_stats = _cc.call(
+                outs, overflow, shard_stats = _cc.call(
                     fn, state, group["masks"], group["params"], live,
                     declared=("mesh", (self.mesh.devices.size, key)),
                 )
             if int(np.asarray(overflow)[0]) == 0:
                 break
             if sp is not None:
-                sp.attrs[f"retry{attempt}"] = [join_cap, bucket_cap]
+                sp.attrs[f"retry{attempt}"] = [join_cap, bucket_cap, out_cap]
             join_cap *= 2
             bucket_cap *= 2
+            out_cap = min(2 * out_cap, join_cap)
             self.stats_counters["cap_hits"] += 1
             self.stats_counters["last_cap_hit"] = time.time()
             _SHARD_CAP_HITS.inc()
             note_cap_retry("sharded")
         else:
             raise RuntimeError("sharded batch capacities failed to converge")
-        group["caps"] = (join_cap, bucket_cap)
-        return jax.block_until_ready((outs, valid, shard_stats))
+        group["caps"] = (join_cap, bucket_cap, out_cap)
+        return jax.block_until_ready((outs, shard_stats))
 
     def _merge_group(self, fp: str, items, group: dict, device_out):  # kolint: holds[lock]
-        """Host side of a dispatch after the program: the analyze
-        records, per live member its rows to the host and the post-pass of
-        the solo path, then the per-shard span children."""
+        """Host side of a dispatch after the program: one transfer of the
+        dispatch's answers (``out_cap`` slots a member and shard, with the
+        per-operator counts beside them), the analyze records, per live
+        member the rows its shards counted and the post-pass of the solo
+        path, then the per-shard span children."""
         from kolibrie_tpu.query.executor import _finish_select_table
 
         execs = group["execs"]
         exemplar, live = execs[0], len(execs)
-        outs, valid, shard_stats = device_out
         # the per-operator counts ride the result transfer (about 1 KB a
         # dispatch): the occupancy counters read them on every dispatch,
         # an analyze capture records them per member
-        stats_np = group["stats"] = np.asarray(shard_stats)[:live]
+        outs, stats_all = jax.device_get(device_out)
+        stats_np = group["stats"] = stats_all[:live]
         cap_rec = _analyze.active()
         if cap_rec is not None:
             stat_names = ["seed"]
@@ -1013,22 +1149,24 @@ class ShardedDatabase:
                     },
                     caps=list(group["caps"]),
                 )
-        # host merge: per member, identical post-pass to the solo path
+        # host merge: per member its shards' counted rows (the program
+        # compacted them to the front of their ``out_cap`` slots, in the
+        # table's order), then the post-pass of the solo path
+        final = stats_np[:, :, -1]
         results: Dict[int, List[List[str]]] = {}
-        per_shard = np.zeros(self.n, dtype=np.int64)
         for r, ((idx, _text), ex) in enumerate(zip(items, execs)):
-            out_r, valid_r = jax.device_get(
-                _member_rows((outs, valid), np.int32(r))
-            )
-            per_shard += valid_r.sum(axis=1)
-            v = valid_r.ravel()
             table = {
-                var: out_r[k].ravel()[v].astype(np.uint32)
+                var: np.concatenate(
+                    [outs[k][r, sh, : final[r, sh]] for sh in range(self.n)]
+                )
                 for k, var in enumerate(exemplar.out_vars)
             }
             results[idx] = _finish_select_table(self.db, ex.query, table)
+        _SHARD_MERGED_ROWS.inc(int(final.sum()))
+        _SHARD_MERGED_BYTES.inc(sum(o.nbytes for o in outs) + stats_all.nbytes)
         # per-shard span children: surviving rows per shard across the
         # group (observable imbalance of THIS dispatch)
+        per_shard = final.sum(axis=0)
         for sh in range(self.n):
             with span("shard.partition", shard=sh, rows=int(per_shard[sh])):
                 pass
@@ -1043,9 +1181,9 @@ class ShardedDatabase:
         from kolibrie_tpu.parallel.dist_query import exchanged_steps
 
         exemplar, live = group["execs"][0], len(group["execs"])
-        join_cap, bucket_cap = group["caps"]
+        join_cap, bucket_cap, out_cap = group["caps"]
         bv = self._sig[0]
-        self._plans[(fp, bv)] = (exemplar.seed, join_cap, bucket_cap)
+        self._plans[(fp, bv)] = (exemplar.seed, join_cap, bucket_cap, out_cap)
         occ_total = int(self._subj.occupancy().sum())
         n_scans = 1 + len(exemplar.steps)
         _SHARD_ROWS.inc(occ_total * n_scans * live)
@@ -1073,6 +1211,8 @@ class ShardedDatabase:
         _SHARD_SEED_ROWS.inc(int(group["stats"][:, :, 0].sum()))
         counted = group["stats"][:, :, 1:-1].reshape(live, self.n, -1, 3)
         _SHARD_JOIN_ROWS.inc(int(counted[..., :2].sum()))
+        _SHARD_XROWS.inc(int(counted[..., 0].sum()))
+        _SHARD_XSLOTS.inc(live * sum(exchanged) * self.n * self.n * bucket_cap)
         _SHARD_DISPATCH.labels("lone" if live == 1 else "batched").inc()
         _SHARD_QUERIES.inc(live)
         _SHARD_MEMBER_SLOTS.inc(group["params"].shape[0])
@@ -1096,6 +1236,14 @@ class ShardedDatabase:
                 "delta_cap": self._delta_cap,
             }
             out.update(self.stats_counters)
+            # each pinned template's seed premise and capacities, and what
+            # its dispatches took, span by span
+            out["plans"] = {
+                fp: list(plan) for (fp, _bv), plan in self._plans.items()
+            }
+            out["by_template"] = {
+                fp: dict(took) for fp, took in self._by_template.items()
+            }
             if self._subj.base_counts is not None:
                 occ = self._subj.occupancy()
                 mean = float(occ.mean()) if len(occ) else 0.0

@@ -11,8 +11,10 @@ the two join-search metrics are in as ISSUE 39 states them, ``watdiv-100``,
 ``bsbm-10m``, ``bsbm10m.bi_counts`` and the seven aggregate metrics as ISSUE
 42 does, ISSUE 45's three counts of the micro-batcher are data files for
 every cell, ``lubm-50-clients8``, ``lubm50.mix8`` and the four metrics of a
-dispatch's composition as ISSUE 47 does (ten cells of eight configurations,
-one of four chips; every cell and metric is found by its name, so that the
+dispatch's composition as ISSUE 47 does, ``lubm-50-mesh4``, ``lubm50.mesh4``,
+its ``requires`` row and the six metrics of the mesh's merge, exchanges and
+partition as ISSUE 50 does (eleven cells of nine configurations, two of four
+chips; every cell and metric is found by its name, so that the
 next one appended breaks none of these), every file a cell or a
 per-layer metric names is there, a program that lacks what a cell
 requires of it (``benchmark/requires``) is refused before anything starts,
@@ -666,10 +668,10 @@ def test_every_per_layer_metric_has_its_file_and_its_reader():
 
 def test_at_most_half_the_cells_take_four_chips():
     four = [w["name"] for w in BENCH["workloads"] if w["chips"] == 4]
-    assert four == ["lubm5.mesh4"] and len(CELLS) == 10
-    assert len(BENCH["configs"]) == 8
+    assert four == ["lubm5.mesh4", "lubm50.mesh4"] and len(CELLS) == 11
+    assert len(BENCH["configs"]) == 9
     assert len(four) <= max(1, len(CELLS) // 2)
-    assert json.dumps(BENCH).count('"chips": 4') == 1
+    assert json.dumps(BENCH).count('"chips": 4') == 2
 
 
 # ---- what a cell requires of the program (``benchmark/requires``)
@@ -679,7 +681,8 @@ def test_every_requirement_belongs_to_a_cell_and_names_a_documented_metric():
         catalog = f.read()
     found = sorted(os.listdir(files.path("requires")))
     assert found == ["bsbm10m.bi_counts.json", "lubm5.batch8.json",
-                     "lubm5.mesh4.json", "watdiv100.stars_snowflakes.json"]
+                     "lubm5.mesh4.json", "lubm50.mesh4.json",
+                     "watdiv100.stars_snowflakes.json"]
     for name in found:
         assert name[:-len(".json")] in CELLS
         need = files.read_json("requires", name)
@@ -694,7 +697,7 @@ def test_every_requirement_belongs_to_a_cell_and_names_a_documented_metric():
     "family", ["kolibrie_test_required_total"] + [
         files.read_json("requires", cell + ".json")["registers"]
         for cell in ("lubm5.batch8", "lubm5.mesh4", "watdiv100.stars_snowflakes",
-                     "bsbm10m.bi_counts")])
+                     "bsbm10m.bi_counts", "lubm50.mesh4")])
 def test_a_program_without_the_required_metric_is_refused_at_once(
         tmp_path, monkeypatch, registered, family):
     """Each cell's own family too, asked of a program whose registry is
@@ -956,7 +959,7 @@ def test_the_search_rows_metrics_are_data_alone_for_the_triangles_cells():
                      "moves": "cycle_ms", "workloads": TRIANGLES_CELLS}
         reader = files.read_json("layer_metrics", m["name"] + ".json")["reader"]
         assert reader == {"kind": "counter_delta", **SEARCH_ROWS_METRICS[m["name"]]}
-    assert CELL_ORDER[-1] == "lubm50.mix8" and CONFIG_ORDER[-1] == "lubm-50-clients8"
+    assert CELL_ORDER[-2] == "lubm50.mix8" and CONFIG_ORDER[-2] == "lubm-50-clients8"
 
 
 @pytest.mark.parametrize("name", sorted(SEARCH_ROWS_METRICS))
@@ -998,15 +1001,16 @@ def test_the_build_puts_metric_is_data_alone_for_the_one_chip_cells():
     file of ``counter_delta`` on the family, both kinds summed; no cell, no
     configuration and no reader came with it."""
     (m,) = per_layer_run([BUILD_PUTS])
-    assert BENCH["per_layer"][-1] is m
-    assert BENCH["per_layer"][-2]["name"] == "wcoj_order_wide_rows_in_window"
+    at = BENCH["per_layer"].index(m)
+    assert BENCH["per_layer"][at - 1]["name"] == "wcoj_order_wide_rows_in_window"
+    assert BENCH["per_layer"][at + 1]["name"] == "shard_merged_rows_in_window"
     assert len(ONE_CHIP_CELLS) == 9 and "lubm5.mesh4" not in ONE_CHIP_CELLS
     assert m == {"name": BUILD_PUTS, "unit": "count", "better": "lower",
                  "source": "program_counter", "layer": "device dispatch",
                  "moves": "cycle_ms", "workloads": ONE_CHIP_CELLS}
     assert files.read_json("layer_metrics", BUILD_PUTS + ".json") == {
         "reader": {"kind": "counter_delta", "prefix": BUILD_PUTS_FAMILY}}
-    assert CELL_ORDER[-1] == "lubm50.mix8" and CONFIG_ORDER[-1] == "lubm-50-clients8"
+    assert CELL_ORDER[-2] == "lubm50.mix8" and CONFIG_ORDER[-2] == "lubm-50-clients8"
 
 
 @pytest.mark.parametrize("program", ["change", "first_uploads", "parent"])
@@ -1036,3 +1040,147 @@ def test_the_build_puts_metric_reads_both_kinds_and_nothing_of_a_program_without
     }[program]
     got = reader.read(ctx, prefix=BUILD_PUTS_FAMILY)
     assert got is None if want is None else got == pytest.approx(want)
+
+
+# ---- ISSUE 50: LUBM(50) over four chips, the five-query lookup mix
+
+MESH_CELLS = ["lubm5.mesh4", "lubm50.mesh4"]
+MESH_MIX_METRICS = {
+    "shard_merged_rows_in_window": (
+        "rows", "higher", "cycle_ms",
+        {"kind": "counter_delta",
+         "prefix": "metrics.kolibrie_shard_merged_rows_total"}),
+    "shard_merged_mb_in_window": (
+        "MB", "lower", "cycle_ms",
+        {"kind": "counter_delta",
+         "prefix": "metrics.kolibrie_shard_merged_bytes_total", "scale": 1e-06}),
+    "shard_exchange_rows_in_window": (
+        "rows", "higher", "cycle_ms",
+        {"kind": "counter_delta",
+         "prefix": "metrics.kolibrie_shard_exchange_rows_total"}),
+    "shard_exchange_slots_in_window": (
+        "slots", "lower", "cycle_ms",
+        {"kind": "counter_delta",
+         "prefix": "metrics.kolibrie_shard_exchange_slots_total"}),
+    "mesh_base_rebuilds_in_setup": (
+        "count", "lower", "setup_s",
+        {"kind": "counter_at_open",
+         "prefixes": ["metrics.kolibrie_shard_base_rebuilds_total"]}),
+    "setup_mesh_partition_s": (
+        "s", "lower", "setup_s",
+        {"kind": "counter_at_open",
+         "prefixes": ["metrics.kolibrie_shard_partition_seconds_total"]}),
+}
+
+
+def test_benchmark_json_has_lubm_50_over_four_chips_and_its_cell():
+    """One configuration and one cell of four chips, appended last; the
+    traffic is ``lubm50.mix8``'s file, letter for letter; the configuration
+    is ``lubm-50-clients8``'s deployment laid out as ``lubm-5-mesh4``'s, with
+    the mesh's share an equality; no standing list took the cell in."""
+    entry, cell = CONFIGS["lubm-50-mesh4"], CELLS["lubm50.mesh4"]
+    assert CONFIG_ORDER[-1] == entry["name"] and CELL_ORDER[-1] == cell["name"]
+    assert (entry["file"], entry["reduced"]) == (
+        "benchmark/configs/lubm-50-mesh4.json", ["universities", "pod"])
+    assert cell == {**cell, "config": "lubm-50-mesh4",
+                    "traffic": "lookups_clients8", "chips": 4}
+    assert CELLS["lubm50.mix8"]["traffic"] == cell["traffic"]
+    assert set(cell) == {"name", "config", "traffic", "chips", "why"}
+    assert set(entry) == {"name", "source", "file", "reduced", "why"}
+    assert max(len(entry["source"]), len(entry["why"]), len(cell["why"])) <= 200
+    for words in ("LUBM(50, seed)", "Q1, Q3, Q4, Q7, Q8", "BSBM", "8 ",
+                  "BASELINE.md configuration 5", "4 chips"):
+        assert words in entry["source"], words
+    for words in ("8 free clients", "Q1, Q3, Q4, Q7, Q8", "1.98 M rows a shard",
+                  "10,000-row", "host merge", "lubm50.mix8", "lubm5.mesh4"):
+        assert words in cell["why"], words
+    assert len({c["source"] for c in BENCH["configs"]}) == len(BENCH["configs"])
+    config = files.read_json("configs", "lubm-50-mesh4.json")
+    one_chip = files.read_json("configs", "lubm-50-clients8.json")
+    small = files.read_json("configs", "lubm-5-mesh4.json")
+    assert config["source"] == entry["source"]
+    assert (config["universities"], config["chips"], config["store_mode"]) == (
+        50, 4, "device")
+    for key in ("generator", "universities", "control", "assumed", "store_mode"):
+        assert config[key] == one_chip[key], key
+    assert config["guarantees"].items() >= one_chip["guarantees"].items()
+    assert "served_in_a_group" in config["guarantees"]
+    assert sorted(config["reduced"]) == sorted(entry["reduced"])
+    assert "1,000 -> 50" in config["reduced"]["universities"]
+    assert "not by memory" in config["reduced"]["universities"]
+    assert "eighth" in config["reduced"]["universities"]
+    assert "v5e-8" in config["reduced"]["pod"] and "4 chips" in config["reduced"]["pod"]
+    # the layout is lubm-5-mesh4's, brought up to what the program does
+    assert set(config["layout"]) >= set(small["layout"])
+    assert config["layout"]["partitions"] == small["layout"]["partitions"] == 4
+    assert config["layout"]["exchange"] == small["layout"]["exchange"]
+    assert config["layout"]["served_by_the_mesh_at_least"] == 1.0
+    assert "serves nothing in a correct run" in config["layout"]["full_copy"]
+    assert "once" in config["layout"]["partitioned_when"]
+    assert files.read_json("workloads", "lubm50.mesh4.json") == {
+        "env": {"KOLIBRIE_SHARDED": "1"}} == files.read_json(
+            "workloads", "lubm5.mesh4.json")
+    # no standing list took the cell in: it reports what has no list and
+    # the six metrics born with it
+    listed = [m["name"] for m in BENCH["per_layer"]
+              if "lubm50.mesh4" in m.get("workloads", [])]
+    assert listed == list(MESH_MIX_METRICS)
+    assert "lubm50.mesh4" not in next(
+        m for m in BENCH["end_to_end"] if m["name"] == "latency_p95_ms")["workloads"]
+
+
+def test_the_cell_requires_the_merge_counters_of_the_program():
+    from kolibrie_tpu.obs import metrics
+    from kolibrie_tpu.parallel import sharded_serving  # noqa: F401
+
+    need = files.read_json("requires", "lubm50.mesh4.json")
+    assert (need["module"], need["registers"]) == (
+        "kolibrie_tpu.parallel.sharded_serving",
+        "kolibrie_shard_merged_rows_total")
+    assert metrics.REGISTRY.get(need["registers"]) is not None
+    for words in ("39", "re-partition", "before 7.9 M triples are generated"):
+        assert words in need["why"], words
+
+
+def test_the_six_mesh_metrics_are_data_alone_for_the_two_mesh_cells():
+    added = per_layer_run(MESH_MIX_METRICS)
+    assert BENCH["per_layer"][-len(added):] == added
+    with open(os.path.join(REPO, "docs", "OBSERVABILITY.md"), encoding="utf-8") as f:
+        catalog = f.read()
+    for m in added:
+        unit, better, moves, reader = MESH_MIX_METRICS[m["name"]]
+        assert m == {"name": m["name"], "unit": unit, "better": better,
+                     "source": "program_counter", "layer": "mesh serving",
+                     "moves": moves, "workloads": MESH_CELLS}
+        assert files.read_json("layer_metrics", m["name"] + ".json") == {
+            "reader": reader}
+        family = (reader.get("prefix") or reader["prefixes"][0])[len("metrics."):]
+        assert f"`{family}`" in catalog
+
+
+@pytest.mark.parametrize("name", sorted(MESH_MIX_METRICS))
+def test_a_mesh_mix_metric_reads_its_counter_and_nothing_of_a_program_without_it(
+        name):
+    """The readers run on the parent's checkout too, which registers none of
+    the six families: it reports none of the metrics and nothing raises."""
+    reader_args = dict(MESH_MIX_METRICS[name][3])
+    reader = files.load_module("readers", reader_args.pop("kind"))
+    key = reader_args.get("prefix") or reader_args["prefixes"][0]
+    scale = reader_args.get("scale", 1.0)
+    change = {"counters0": {key: 3.0}, "counters1": {key: 10.0}}
+    want = 3.0 if "at_open" in MESH_MIX_METRICS[name][3]["kind"] else 7.0
+    assert reader.read(change, **reader_args) == pytest.approx(want * scale)
+    parent = {"counters0": {"metrics.kolibrie_shard_queries_total": 3.0},
+              "counters1": {"metrics.kolibrie_shard_queries_total": 9.0}}
+    assert reader.read(parent, **reader_args) is None
+
+
+def test_the_selftest_counts_two_of_eleven_cells_on_four_chips(capsys):
+    from benchmark.harness import selftest
+
+    problems = []
+    selftest.check_files(problems)
+    assert problems == []
+    four = [w["name"] for w in BENCH["workloads"] if w["chips"] == 4]
+    assert (len(four), len(BENCH["workloads"])) == (2, 11)
+    assert len(four) <= max(1, len(BENCH["workloads"]) // 2) == 5

@@ -295,13 +295,16 @@ def test_whole_plan_batch_slot_class_8(one_chip, lubm_db):
     assert "while" in compiled.as_text()
 
 
-def test_mesh_program_lubm_q7_four_chips(topo, mesh8):
-    """The mesh serving program (``sharded_serving._batched_body``: the
-    live-member loop with its ``all_to_all`` inside) for the four chips
-    of the described host, at the widths the cell ``lubm5.mesh4`` runs:
-    262,144-slot shards, the plan and the capacities the host count gives
-    Q7 (from the professor, 1,024 · 1,024), slot class 8.  The template's
-    lowering comes from LUBM(1) of the cell's generator on CPU devices."""
+def _mesh_program(topo, config_name, template, domain, subj, obj, caps=None):
+    """One template's mesh serving program (``sharded_serving._batched_body``:
+    the live-member loop with its ``all_to_all`` inside) lowered for the four
+    chips of the described host with shards ``subj`` and ``obj`` slots wide
+    (base blocks + delta blocks), each mirror's sorted keys and rows beside
+    it in the state, slot class 8.  The template's lowering, plan and
+    capacities come from LUBM(1) of the configuration's generator on CPU
+    devices (a department's or university's counts do not grow with the
+    store; ``caps`` where the cell's hottest key at full scale gives wider
+    ones than LUBM(1)'s).  ``(executor, LUBM(1)'s capacities, lowered)``."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from benchmark.harness import data as bench_files
@@ -310,7 +313,7 @@ def test_mesh_program_lubm_q7_four_chips(topo, mesh8):
     from kolibrie_tpu.query.executor import _plan_cache_entry
     from kolibrie_tpu.query.sparql_database import SparqlDatabase
 
-    config = bench_files.read_json("configs", "lubm-5-mesh4.json")
+    config = bench_files.read_json("configs", config_name + ".json")
     data = bench_files.load_module("generators", config["generator"]).generate(
         config, 7, 1)
     db = SparqlDatabase()
@@ -320,20 +323,18 @@ def test_mesh_program_lubm_q7_four_chips(topo, mesh8):
     db.execution_mode = "host"
     sh = ss.attach_sharded(db, make_mesh(4))
     sh.refresh()
-    text = bench_files.template_text("lubm_q7").replace(
-        "@department@", data["domains"]["department"][0])
+    text = bench_files.template_text(template).replace(
+        "@%s@" % domain, data["domains"][domain][0])
     db.register_prefixes_from_query(text)
     fp = _plan_cache_entry(db, text)[0]["fp"]
     with sh.lock:
         group = sh._build_group(fp, [(0, text)])
     ex = group["execs"][0]
-    assert ex.plan_source == "counted" and group["caps"] == (1024, 1024)
-    assert ex.premises[ex.seed].consts[0] is not None  # the professor
-    assert any(kv != "x" for (_j, kv, _kp, _e) in ex.steps)  # an exchange
+    assert ex.plan_source == "counted"
     mesh = Mesh(np.array(topo.devices).reshape(4), (sh.axis,))
     fn = ss._get_batched_fn(
         mesh, group["premises"], ex.seed, ex.steps, ex.filters, ex.out_vars,
-        len(group["masks"]), *group["caps"], ss._slot_class(1),
+        len(group["masks"]), *(caps or group["caps"]), ss._slot_class(1),
     )
     rows = NamedSharding(mesh, P(sh.axis, None))
     everywhere = NamedSharding(mesh, P())
@@ -341,17 +342,56 @@ def test_mesh_program_lubm_q7_four_chips(topo, mesh8):
     def shape(a, sharding, dims=None):
         return jax.ShapeDtypeStruct(dims or a.shape, a.dtype, sharding=sharding)
 
-    shard = (4, 262144 + 1024)  # base blocks + delta blocks
     state = (*sh.view.by_subj, sh.view.by_subj_valid,
-             *sh.view.by_obj, sh.view.by_obj_valid)
+             *sh.view.by_obj, sh.view.by_obj_valid,
+             *sh._subj_sorted, *sh._obj_sorted)
+    dims = [(4, subj)] * 4 + [(4, obj)] * 4 + [(4, subj)] * 2 + [(4, obj)] * 2
     with jax.enable_x64(True):
-        compiled = fn.lower(
-            tuple(shape(a, rows, shard) for a in state),
+        lowered = fn.lower(
+            tuple(shape(a, rows, d) for a, d in zip(state, dims)),
             tuple(shape(m, everywhere) for m in group["masks"]),
             shape(group["params"], everywhere),
             jax.ShapeDtypeStruct((), np.int32, sharding=everywhere),
-        ).compile()
+        )
+    return ex, group["caps"], lowered
+
+
+def _compiled_for_four_chips(lowered):
+    with jax.enable_x64(True):
+        compiled = lowered.compile()
     text = compiled.as_text()
     assert " all-to-all(" in text and " while(" in text
     per_chip = compiled.memory_analysis()
     assert per_chip.output_size_in_bytes + per_chip.temp_size_in_bytes < 2**30
+
+
+def test_mesh_program_lubm_q7_four_chips(topo, mesh8):
+    """At the widths the cell ``lubm5.mesh4`` runs: 262,144-slot shards, the
+    plan and the capacities the host count gives Q7 (from the professor,
+    1,024 · 1,024 · 1,024)."""
+    ex, caps, lowered = _mesh_program(
+        topo, "lubm-5-mesh4", "lubm_q7", "department",
+        262144 + 1024, 262144 + 1024)
+    assert caps == (1024, 1024, 1024)
+    assert ex.premises[ex.seed].consts[0] is not None  # the professor
+    assert any(kv != "x" for (_j, kv, _kp, _e) in ex.steps)  # an exchange
+    _compiled_for_four_chips(lowered)
+
+
+def test_mesh_program_lubm_q8_at_the_widths_of_lubm50_mesh4(topo, mesh8):
+    """At the widths the cell ``lubm50.mesh4`` runs (ISSUE 50): 2,097,152-slot
+    subject shards and 4,194,304-slot object shards, the capacities the host
+    count gives the hottest of fifty universities there (65,536 · 2,048 ·
+    4,096; LUBM(1)'s one university counts half).  The program sorts no
+    mirror and takes no prefix sum over one flat (until PR 50 each was most
+    of a 20 s compile), and exchanges between its join steps."""
+    _ex, (join_cap, _bucket_cap, out_cap), lowered = _mesh_program(
+        topo, "lubm-50-mesh4", "lubm_q8", "university",
+        2097152 + 1024, 4194304 + 1024, caps=(65536, 2048, 4096))
+    assert 16384 <= join_cap <= 65536 and 1024 <= out_cap < join_cap
+    hlo = lowered.as_text()
+    for wide in ("2098176", "4195328"):
+        assert not any(wide in line for line in hlo.splitlines()
+                       if "stablehlo.sort" in line or "cumsum" in line
+                       or "reduce_window" in line), wide
+    _compiled_for_four_chips(lowered)

@@ -173,12 +173,18 @@ def test_group_runs_its_live_members_in_one_executable(sharded_db, size):
     # the program itself: rows past the live count were never written
     with sh.lock:
         group = sh._build_group(fp, items)
-        outs, valid, _stats = sh._run_group(fp, group, None)
+        outs, stats = sh._run_group(fp, group, None)
     assert group["params"].shape[0] == 8
-    valid = np.asarray(valid)
-    assert valid[:size].any(axis=(1, 2)).all()
-    assert not valid[size:].any()
+    final = np.asarray(stats)[:, :, -1]  # a member's rows, shard by shard
+    assert (final[:size].sum(axis=1) > 0).all()
+    assert not final[size:].any()
     assert all(not np.asarray(o)[size:].any() for o in outs)
+    # a member's rows stand at the front of its slots on every shard
+    for o in map(np.asarray, outs):
+        for r in range(size):
+            for s in range(sh.n):
+                assert o[r, s, : final[r, s]].all()
+                assert not o[r, s, final[r, s]:].any()
     assert sharded_compile_stats()["batched_programs"] == programs
 
 
@@ -336,9 +342,13 @@ def test_mutation_batches_cause_zero_recompiles(mesh8):
     texts = _template_group(db, 3)
     execute_queries_batched(db, texts)  # prime: compile once
     before = sharded_compile_stats()
-    base_builds = sh.view.subj_index_base_builds
+    # no served template reads the probe index, so a view builds none until
+    # a consumer asks (``dist_join.dist_bgp_join_count``); from then on the
+    # refreshes keep it, two-tier
+    assert sh.view.subj_index_parts is None
+    sh.view.ensure_subj_index()
     d = db.dictionary
-    for r in range(4):
+    for r in range(5):
         s = np.array(
             [d.encode(f"http://zr/{r}-{k}") for k in range(6)], dtype=np.uint32
         )
@@ -349,6 +359,8 @@ def test_mutation_batches_cause_zero_recompiles(mesh8):
         )
         db.store.add_batch(s, p, o)
         execute_queries_batched(db, texts)
+        if r == 0:  # the first refresh after it was asked packs the base
+            base_builds = sh.view.subj_index_base_builds
     assert sharded_compile_stats() == before
     # satellite: the per-shard probe index must NOT full-repack per batch
     assert sh.view.subj_index_base_builds == base_builds
@@ -671,14 +683,14 @@ def test_q7_starts_from_the_professor_and_settles_in_one_program(uba):
     got = sh.execute_batch(fp, [(0, texts[0])])
     oracle = execute_query_volcano(texts[0], db)
     assert got[0] == oracle and len(oracle) > 0
-    seed, join_cap, bucket_cap = _plan_of(sh, fp)
+    seed, join_cap, bucket_cap, out_cap = _plan_of(sh, fp)
     with sh.lock:
         exemplar = sh._build_group(fp, [(0, texts[0])])["execs"][0]
     # the premise with the professor as its subject: 2-4 rows, where the
     # most-constants rule starts from every student
     assert exemplar.premises[seed].consts[0] is not None
     assert [j for j, *_ in exemplar.steps] == [1, 2, 0]
-    assert (join_cap, bucket_cap) == (1024, 1024)
+    assert (join_cap, bucket_cap, out_cap) == (1024, 1024, 1024)
     assert sharded_compile_stats()["batched_programs"] == programs + 1
     assert sh.stats_counters["cap_hits"] == hits
     assert _metric('kolibrie_shard_plan_total{source="counted"}') == counted + 1
@@ -688,7 +700,7 @@ def test_q7_starts_from_the_professor_and_settles_in_one_program(uba):
     assert [got[i] for i, _ in items] == [
         execute_query_volcano(t, db) for _, t in items
     ]
-    assert _plan_of(sh, fp) == (seed, 1024, 1024)
+    assert _plan_of(sh, fp) == (seed, 1024, 1024, 1024)
     assert sharded_compile_stats()["batched_programs"] == programs + 1
     assert sh.stats_counters["cap_hits"] == hits
     assert _metric('kolibrie_shard_plan_total{source="counted"}') == counted + 1
@@ -748,13 +760,15 @@ def test_the_host_counts_what_the_program_counts(uba, body, seed):
         state = (
             *sh.view.by_subj, sh.view.by_subj_valid,
             *sh.view.by_obj, sh.view.by_obj_valid,
+            *sh._subj_sorted, *sh._obj_sorted,
         )
         fn = _get_batched_fn(
             sh.mesh, group["premises"], ex.seed, ex.steps, ex.filters,
-            ex.out_vars, len(group["masks"]), join_cap, bucket_cap, 8,
+            ex.out_vars, len(group["masks"]), join_cap, bucket_cap,
+            join_cap, 8,
         )
         with jax.enable_x64(True):
-            _outs, _valid, overflow, stats = fn(
+            _outs, overflow, stats = fn(
                 state, group["masks"], group["params"], np.int32(1)
             )
         return int(np.asarray(overflow)[0]) > 0, np.asarray(stats)[0]
@@ -817,12 +831,31 @@ def _eqns(jaxpr, inside=()):
             yield from _eqns(sub, inside + (eqn.primitive.name,))
 
 
+@pytest.mark.parametrize("width", [1, 4095, 4096, 5000, 66560])
+def test_prefix_count_in_blocks_is_the_flat_cumsum(width):
+    """``dist_join.prefix_count`` sums a wide mask (or counts) in blocks of
+    1,024 with the blocks' totals beneath them: the numbers of a flat
+    ``cumsum`` at every width, a multiple of the block or not."""
+    import jax
+
+    from kolibrie_tpu.parallel.dist_join import prefix_count
+
+    rng = np.random.default_rng(width)
+    mask = rng.random(width) < 0.3
+    counts = rng.integers(0, 7, width).astype(np.int32)
+    for x in (mask, counts):
+        got = np.asarray(jax.jit(prefix_count)(x))
+        assert got.dtype == np.int32
+        assert got.tolist() == np.cumsum(x.astype(np.int64)).tolist()
+
+
 def test_nothing_in_the_member_loop_indexes_at_the_shards_width(uba):
     """Inside the live-member loop of Q7's mesh program no gather or
     scatter takes indices, and no search (a nested ``while``) carries
-    keys, of a mirror block's width, and one sort or prefix sum at most
-    runs there: the seed's compaction.  A member's cost follows its
-    capacities, not the shard."""
+    keys, of a mirror block's width, no sort runs there at all (the
+    mirrors come sorted with the state) and one prefix sum over as many
+    elements as a block has: the seed's compaction, in blocks.  A member's
+    cost follows its capacities, not the shard."""
     import jax
 
     from kolibrie_tpu.parallel.sharded_serving import _get_batched_fn
@@ -832,18 +865,19 @@ def test_nothing_in_the_member_loop_indexes_at_the_shards_width(uba):
     with sh.lock:
         group = sh._build_group(fp, [(0, texts[0])])
     ex = group["execs"][0]
-    join_cap, bucket_cap = group["caps"]
+    join_cap, bucket_cap, out_cap = group["caps"]
     wide = {sh._base_cap_s + sh._delta_cap, sh._base_cap_o + sh._delta_cap}
     assert not wide & {join_cap, bucket_cap, sh.n * bucket_cap}
     assert min(wide) > sh.n * bucket_cap
     state = (
         *sh.view.by_subj, sh.view.by_subj_valid,
         *sh.view.by_obj, sh.view.by_obj_valid,
+        *sh._subj_sorted, *sh._obj_sorted,
     )
     assert {a.shape[1] for a in state} == wide
     fn = _get_batched_fn(
         sh.mesh, group["premises"], ex.seed, ex.steps, ex.filters,
-        ex.out_vars, len(group["masks"]), join_cap, bucket_cap, 8,
+        ex.out_vars, len(group["masks"]), join_cap, bucket_cap, out_cap, 8,
     )
     with jax.enable_x64(True):
         program = jax.make_jaxpr(fn)(
@@ -852,6 +886,9 @@ def test_nothing_in_the_member_loop_indexes_at_the_shards_width(uba):
 
     def is_wide(var):
         return bool(wide & set(getattr(var.aval, "shape", ())))
+
+    def holds_a_block(var):
+        return int(np.prod(getattr(var.aval, "shape", ()))) >= min(wide)
 
     loops = [
         eqn
@@ -880,12 +917,18 @@ def test_nothing_in_the_member_loop_indexes_at_the_shards_width(uba):
                 else eqn.params["cond_nconsts"] + eqn.params["body_nconsts"]
             )
             assert not any(is_wide(v) for v in eqn.invars[consts:]), eqn
-        elif name == "sort" or name.startswith(("cum", "reduce_window")):
-            seen["wide_order"] += any(is_wide(v) for v in eqn.invars)
+        elif name.startswith(("cum", "reduce_window")):
+            seen["wide_order"] += any(holds_a_block(v) for v in eqn.invars)
+        if name == "sort":  # an exchange's buckets, at a table's width
+            assert not any(holds_a_block(v) for v in eqn.invars), eqn
     # the walk met the member's gathers and searches, and the one
     # shard-wide prefix count is the compaction's
     assert seen["gather"] > 0 and seen["search"] > 0
     assert seen["wide_order"] == 1
+    assert not any(
+        eqn.primitive.name == "sort" and any(map(holds_a_block, eqn.invars))
+        for eqn, _ in _eqns(program.jaxpr)
+    )
 
 
 def test_members_that_alone_would_seed_differently_share_one_plan(uba):
@@ -928,16 +971,20 @@ def test_members_that_alone_would_seed_differently_share_one_plan(uba):
 
 
 @pytest.mark.parametrize("hot", ["big", "mid"])
-def test_a_constant_over_four_times_the_count_retries_doubled(mesh8, hot):
-    # the smaller capacity is sound only because the overflow retry stands
-    # behind it: <small> calibrates the floor; <big> counts 12 times it in
-    # its joins and 3,000 seed rows on its subject's shard; of <mid> only
-    # the SEED overflows ``join_cap`` (1,500 rows on one shard, 10 of which
-    # join anything)
+def test_a_first_sight_counts_the_hottest_key_and_a_write_past_it_retries_doubled(
+    mesh8, hot
+):
+    # <small> comes first, and the template's capacities are those of its
+    # hottest key all the same (PR 50: one more walk of the chain with the
+    # seed's key freed, no headroom over it): <big> has 4,000 seed rows on
+    # its subject's shard and four times as many answers, of <mid> only the
+    # seed is large (1,500 rows on one shard, 10 of which join anything).
+    # The overflow retry stands behind a store that moves: a write under
+    # the delta threshold carries <big> past its ceiling
     db = SparqlDatabase()
     ex = "http://example.org/"
     lines = [f"<{ex}small> <{ex}p1> <{ex}y0> ."]
-    for i in range(3000):
+    for i in range(4000):
         lines.append(f"<{ex}big> <{ex}p1> <{ex}y{i}> .")
         for j in range(4):
             lines.append(f"<{ex}y{i}> <{ex}p2> <{ex}z{i}_{j}> .")
@@ -957,22 +1004,37 @@ def test_a_constant_over_four_times_the_count_retries_doubled(mesh8, hot):
     assert sh.execute_batch(fp, [(0, small)])[0] == execute_query_volcano(
         small, db
     )
-    seed, join_cap, bucket_cap = _plan_of(sh, fp)
-    assert (join_cap, bucket_cap) == (1024, 1024)
+    seed, join_cap, bucket_cap, out_cap = _plan_of(sh, fp)
+    # <big>'s 4,000 seed rows on one shard and its 16,000 answers over
+    # eight; <small> alone would have read the floor, 1,024 each
+    assert (join_cap, out_cap) == (4096, 4096) and bucket_cap >= 1024
     hits = sh.stats_counters["cap_hits"]
+    programs = sharded_compile_stats()["batched_programs"]
     got = sh.execute_batch(fp, [(0, other)])[0]
     assert got == execute_query_volcano(other, db)
+    assert len(got) == (16000 if hot == "big" else 10)
+    # no retry, no program: the first sight had counted this key
+    assert sh.stats_counters["cap_hits"] == hits
+    assert sharded_compile_stats()["batched_programs"] == programs
+    assert _plan_of(sh, fp) == (seed, join_cap, bucket_cap, out_cap)
+    # a write under the delta threshold (the base and the pin stand) puts
+    # 150 more rows under <big>: 4,150 seed rows overflow 4,096 slots
+    d = db.dictionary
+    more = np.array(
+        [d.encode(f"{ex}late{i}") for i in range(150)], dtype=np.uint32
+    )
+    db.store.add_batch(
+        np.full(150, d.encode(f"{ex}big"), dtype=np.uint32),
+        np.full(150, d.encode(f"{ex}p1"), dtype=np.uint32),
+        more,
+    )
+    big = text.format("big")
+    got = sh.execute_batch(fp, [(0, big)])[0]
+    assert got == execute_query_volcano(big, db) and len(got) == 16000
+    # one retry, every capacity doubled (the answer's no wider than the join's)
+    assert sh.stats_counters["cap_hits"] == hits + 1
     grown = _plan_of(sh, fp)
-    if hot == "big":
-        assert len(got) == 12000
-        assert sh.stats_counters["cap_hits"] > hits
-        assert grown[0] == seed and grown[1] >= 2 * join_cap
-        assert grown[1] * 8 >= 12000
-    else:
-        # one retry, both capacities doubled, the oracle's rows
-        assert len(got) == 10
-        assert sh.stats_counters["cap_hits"] == hits + 1
-        assert grown == (seed, 2 * join_cap, 2 * bucket_cap)
+    assert grown == (seed, 2 * join_cap, 2 * bucket_cap, 2 * out_cap)
     # the capacities that held stay: the small constant builds no program
     programs = sharded_compile_stats()["batched_programs"]
     assert sh.execute_batch(fp, [(0, small)])[0] == execute_query_volcano(
@@ -1011,11 +1073,14 @@ def test_plan_and_occupancy_counters(uba, source, monkeypatch):
     assert [got[i] for i, _ in items] == [
         execute_query_volcano(t, db) for _, t in items
     ]
-    seed, join_cap, bucket_cap = _plan_of(sh, fp)
+    seed, join_cap, bucket_cap, out_cap = _plan_of(sh, fp)
     with sh.lock:
         exemplar = sh._build_group(fp, items[:1])["execs"][0]
     assert exemplar.seed == seed
     assert (seed == 0) == (source == "constants")
+    # what comes to the host: the counted answer's capacity, and the whole
+    # table's where nothing could be counted
+    assert out_cap == (1024 if source == "counted" else join_cap)
     assert grew["plans"] == grew["all_plans"] == 1
     assert grew["slots"] == _slots(sh, exemplar, 3, join_cap, bucket_cap)
     recs = [r for r in cap.records if r["kind"] == "sharded"]
@@ -1067,7 +1132,7 @@ def test_batched_analyze_matches_oracle(sharded_db):
         # the subject-keyed star join is co-partitioned: exchange elided,
         # its stats slot honestly reads zero
         assert rec["operators"]["exchange0"] == 0
-        assert len(rec["caps"]) == 2
+        assert len(rec["caps"]) == 3  # join, bucket, out
 
 
 def test_trace_id_reaches_shard_spans(sharded_server):
