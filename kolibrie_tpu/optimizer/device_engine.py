@@ -1045,7 +1045,8 @@ def _plan_body(
             # raw (possibly all-tombstoned) copies — the base slot is the
             # unique representative, made live by the delta via the
             # existence probe.
-            from kolibrie_tpu.ops.wcoj import range_search
+            from kolibrie_tpu.ops.prefix import prefix_count
+            from kolibrie_tpu.ops.wcoj import range_search, slot_rows
 
             SENT = jnp.uint32(0xFFFFFFFF)
             wcols: Dict = {}
@@ -1118,13 +1119,12 @@ def _plan_body(
                     stats[f"wcoj{lv.join_idx}:cand"] = total
                 with jax.named_scope("expand"):
                     cap = lv.cap
-                    cum = jnp.cumsum(cnt)
+                    cum = prefix_count(cnt)
                     slot = jnp.arange(cap, dtype=jnp.int32)
-                    row = jnp.searchsorted(cum, slot, side="right").astype(
-                        jnp.int32
-                    )
+                    # a scatter and a prefix count, no search (ops/wcoj.py)
+                    row = slot_rows(cum, cap)
                     row_c = jnp.clip(row, 0, pcap - 1)
-                    kk = slot - (cum[row_c] - cnt[row_c])
+                    kk = slot - (cum - cnt)[row_c]
                     in_range = slot.astype(jnp.int64) < total
                     ch = choice[row_c]
                     # per-accessor slot operands (XLA gathers — shared by both

@@ -238,6 +238,19 @@ def test_whole_plan_lubm_q9(one_chip, lubm_db):
     windows = [a.window for lv in spec.root.levels for a in lv.accessors]
     assert all(0 < w < low._seg_rows[0][0] for w in windows)
     assert " dynamic-slice(" in text
+    # a level maps its slots to rows by one scatter and a prefix count
+    # (ISSUE 51): no instruction traced under a level's ``expand`` scope is a
+    # loop or a sort in the chip's program, while ``probe`` and ``live``
+    # keep their searches' loops
+    levels = len(spec.root.levels)
+    scoped = {scope: [ln for ln in text.splitlines() if f"/{scope}/" in ln]
+              for scope in ("expand", "probe", "live")}
+    assert len({ln.split("/expand/")[0].rsplit("/", 1)[-1]
+                for ln in scoped["expand"]}) == levels  # wcoj0.L0 .. L2
+    for scope, lines in scoped.items():
+        loops = [ln for ln in lines if " while(" in ln]
+        assert bool(loops) == (scope != "expand"), (scope, loops[:2])
+    assert not [ln for ln in scoped["expand"] if " sort(" in ln]
 
 
 def test_a_merge_join_plan_holds_no_sort(one_chip, lubm_db):

@@ -473,15 +473,23 @@ def test_typed_triangles_on_both_sides_of_the_rule(shape, side, monkeypatch):
     assert got and len(got) == len(_rows(db, sparql, "host"))
 
 
-def _sort_eqns(jaxpr):
+def _scoped_eqns(jaxpr, scope, inside=False):
+    """Every equation traced under the named scope ``scope``, sub-jaxprs
+    (a jitted helper's body, a loop's, a branch's) included."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "sort":
+        here = inside or scope in str(eqn.source_info.name_stack).split("/")
+        if here:
             yield eqn
         for sub in eqn.params.values():
             for item in sub if isinstance(sub, (list, tuple)) else (sub,):
                 inner = getattr(item, "jaxpr", item)
                 if hasattr(inner, "eqns"):
-                    yield from _sort_eqns(inner)
+                    yield from _scoped_eqns(inner, scope, here)
+
+
+def _sort_eqns(jaxpr):
+    return (e for e in _scoped_eqns(jaxpr, "", inside=True)
+            if e.primitive.name == "sort")
 
 
 @pytest.mark.parametrize("side", ["loop", "sorted"])
@@ -844,3 +852,171 @@ def test_a_base_that_outgrows_a_window_is_compiled_for_the_new_one(monkeypatch):
     assert de.device_compile_stats()["run_plan"] == programs + 1
     assert got == _id_rows(low.host_execute()[0])
     assert len(got) == len(_rows(db, sparql, "host")) and got
+
+
+# --------------------------- a level's slot -> row map without a search (ISSUE 51)
+#
+# ``expand`` asks, for each of a level's ``cap`` output slots, which probe row
+# it expands: ``searchsorted(cumsum(cnt), arange(cap), "right")``.
+# ``ops/wcoj.py`` ``slot_rows`` answers with one scatter-add and one prefix
+# count; the numpy twin keeps ``np.searchsorted`` and is the reference here.
+
+INT32_MAX = 2**31 - 1
+
+
+def _counts(case):
+    """``(cnt, cap)`` of one case: int64 counts, each within int32."""
+    rng = np.random.default_rng(51)
+    if case == "all_zero":
+        return np.zeros(37, np.int64), 64
+    if case == "one_row_holds_everything":
+        c = np.zeros(29, np.int64)
+        c[11] = 50
+        return c, 64
+    if case == "zero_rows_at_the_start_in_runs_and_at_the_end":
+        return np.array([0, 0, 0, 4, 0, 0, 1, 1, 0, 9, 0, 0, 0, 2, 0, 0]), 32
+    if case == "total_is_cap":
+        return np.array([3, 0, 5, 8, 0, 16]), 32
+    if case == "total_past_cap":
+        return np.array([3, 0, 5, 8, 0, 16, 40, 0, 7]), 32
+    if case == "total_past_cap_in_the_first_row":
+        return np.array([100, 1, 0, 2]), 32
+    if case == "cum_wraps_once":
+        # int32 totals: 5, 7, negative from the third row on
+        return np.array([5, 2, INT32_MAX, 3, 0, 11]), 64
+    if case == "p_is_one":
+        return np.array([19]), 48
+    if case == "p_is_one_and_empty":
+        return np.array([0]), 48
+    if case == "more_rows_than_slots":
+        c = (rng.random(700) < 0.05).astype(np.int64) * rng.integers(1, 4, 700)
+        return c, 96
+    if case == "fewer_rows_than_slots":
+        return rng.integers(0, 40, 50), 2048
+    if case == "summed_in_blocks":  # cap + 1 and P past four blocks of 1,024
+        c = rng.geometric(0.4, 5000).astype(np.int64)
+        c[rng.random(5000) < 0.4] = 0
+        c[[17, 4000]] = 2500
+        return c, 16384
+    if case == "summed_in_blocks_total_past_cap":
+        return rng.integers(0, 9, 6000), 8192
+    raise AssertionError(case)
+
+
+SLOT_CASES = [
+    "all_zero", "one_row_holds_everything",
+    "zero_rows_at_the_start_in_runs_and_at_the_end", "total_is_cap",
+    "total_past_cap", "total_past_cap_in_the_first_row", "cum_wraps_once",
+    "p_is_one", "p_is_one_and_empty", "more_rows_than_slots",
+    "fewer_rows_than_slots", "summed_in_blocks", "summed_in_blocks_total_past_cap",
+]
+
+
+@pytest.mark.parametrize("x64", [False, True], ids=["x32", "x64"])
+@pytest.mark.parametrize("case", SLOT_CASES)
+def test_slot_rows_is_the_search_of_the_running_total(case, x64):
+    """Every slot under ``cap`` names the row ``np.searchsorted`` names over
+    the true (unwrapped) running total, whatever lies past ``cap``; an
+    int32 total that wrapped faults nothing."""
+    import jax
+    import jax.numpy as jnp
+
+    from kolibrie_tpu.ops.wcoj import slot_rows
+
+    cnt, cap = _counts(case)
+    assert cnt.max(initial=0) <= INT32_MAX
+    true_cum = np.cumsum(cnt.astype(np.int64))
+    want = np.searchsorted(true_cum, np.arange(cap), side="right")
+    with jax.enable_x64(x64):
+        cum = jnp.cumsum(jnp.asarray(cnt.astype(np.int32)))
+        assert cum.dtype == jnp.int32
+        if case == "cum_wraps_once":
+            assert int(cum[2]) < 0 and int(true_cum[-1]) > cap
+        got = jax.jit(slot_rows, static_argnums=1)(cum, cap)
+        assert got.dtype == jnp.int32 and got.shape == (cap,)
+        if true_cum[-1] <= INT32_MAX:  # the parent's own expression agrees
+            loop = jnp.searchsorted(cum, jnp.arange(cap, dtype=jnp.int32), side="right")
+            assert np.array_equal(np.asarray(loop), want)
+    assert np.array_equal(np.asarray(got), want)
+    # what ``expand`` reads next: slots under the total lie inside their row
+    total = min(int(true_cum[-1]), cap)
+    rows = np.asarray(got)[:total]
+    assert (cnt[rows] > 0).all()
+    assert (np.arange(total) < true_cum[rows]).all()
+    assert (np.arange(total) >= true_cum[rows] - cnt[rows]).all()
+
+
+@pytest.mark.parametrize("width", [1, 600, 4096, 9000])
+def test_the_running_total_in_blocks_wraps_as_the_flat_one(width):
+    """``expand`` takes ``cum`` from the blocked prefix count (a flat
+    ``cumsum`` of 524,288 counts is 7 s of the TPU compiler's time, the
+    blocked one 0.4): int32 addition wraps the same in any grouping, so
+    the array is the flat one's bit for bit, past int32 too."""
+    import jax
+    import jax.numpy as jnp
+
+    from kolibrie_tpu.ops.prefix import prefix_count
+
+    rng = np.random.default_rng(width)
+    cnt = rng.integers(0, 2**22, width).astype(np.int32)
+    with jax.enable_x64(True):
+        got = jax.jit(prefix_count)(jnp.asarray(cnt))
+        flat = jnp.cumsum(jnp.asarray(cnt))
+        assert got.dtype == flat.dtype == jnp.int32
+    assert np.array_equal(np.asarray(got), np.asarray(flat))
+    assert np.array_equal(np.asarray(got),
+                          np.cumsum(cnt.astype(np.int64)).astype(np.int32))
+    assert width < 4096 or int(np.cumsum(cnt.astype(np.int64))[-1]) > INT32_MAX
+
+
+def _plan_jaxpr(low):
+    import jax
+
+    from kolibrie_tpu.optimizer import device_engine as de
+
+    spec, args = low.build()
+    run = de._run_plan.__wrapped__  # the jit keeps its first trace of a spec
+    with jax.enable_x64(True):
+        return jax.make_jaxpr(lambda *a: run(spec, False, *a))(*args).jaxpr
+
+
+def _searching_slot_rows(cum, cap):
+    """The parent's form of ``slot_rows``: a binary search a slot."""
+    import jax.numpy as jnp
+
+    slot = jnp.arange(cap, dtype=jnp.int32)
+    return jnp.searchsorted(cum, slot, side="right").astype(jnp.int32)
+
+
+@pytest.mark.parametrize("side", ["loop", "sorted"])
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_expand_holds_no_loop_and_no_sort(shape, side, monkeypatch):
+    """The traced ``expand`` scope of every level is loop-free and
+    sort-free, and the plan holds one ``while`` a level fewer than with the
+    search a slot in its place: the other scopes are as they were."""
+    from kolibrie_tpu.ops import wcoj
+
+    monkeypatch.setenv("KOLIBRIE_WCOJ", "force")
+    db = _typed_db()
+    low = _at_capacity(db, PREFIX + SHAPES[shape].replace("@A@", "A"), SIDE_CAP[side])
+    levels = len(low.root.levels)
+    assert levels == 3
+
+    def prims(jaxpr, scope):
+        # a loop of known trips is traced as ``scan`` and lowered as ``while``
+        return ["while" if e.primitive.name == "scan" else e.primitive.name
+                for e in _scoped_eqns(jaxpr, scope)]
+
+    now = _plan_jaxpr(low)
+    in_expand = prims(now, "expand")
+    assert "scatter-add" in in_expand and "gather" in in_expand
+    assert not {"while", "sort"} & set(in_expand)
+    monkeypatch.setattr(wcoj, "slot_rows", _searching_slot_rows)
+    before = _plan_jaxpr(low)
+    assert prims(before, "expand").count("while") == levels  # the walk sees one
+    whole = "wcoj0"
+    assert (prims(before, whole).count("while") - prims(now, whole).count("while")
+            == levels)
+    for scope in ("probe", "live", "dedup"):
+        assert prims(before, scope) == prims(now, scope)
+    assert prims(before, whole).count("sort") == prims(now, whole).count("sort")
